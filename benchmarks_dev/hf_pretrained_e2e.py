@@ -1,4 +1,4 @@
-"""End-to-end from_pretrained demonstration on a REAL artifact (VERDICT r03 #3).
+"""End-to-end from_pretrained demonstration on a REAL artifact.
 
 Round-trips the trained 300M glaive export through the HF checkpoint
 layer, then fine-tunes from it, proving the

@@ -1,4 +1,4 @@
-"""int8 KV cache A/B at FIXED KV HBM (VERDICT r03 #5).
+"""int8 KV cache A/B at FIXED KV HBM.
 
 The claim to prove (or honestly demote): halving KV bytes buys double
 the decode slots, which buys throughput. Both arms get the SAME KV pool

@@ -111,11 +111,5 @@ def load_exported_model(directory: str) -> Tuple[dict, Config]:
     directory = os.path.abspath(directory)
     with open(os.path.join(directory, "config.json")) as f:
         cfg = Config.from_dict(json.load(f))
-    model_dir = os.path.join(directory, "model")
-    if not os.path.isfile(os.path.join(model_dir, "MANIFEST.json")):
-        # Legacy export written by the old Orbax backend.
-        import orbax.checkpoint as ocp
-
-        return ocp.StandardCheckpointer().restore(model_dir), cfg
-    params = load_pytree(model_dir)
+    params = load_pytree(os.path.join(directory, "model"))
     return params, cfg

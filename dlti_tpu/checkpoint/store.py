@@ -265,6 +265,10 @@ class _Writer:
                     "checkpoint save at step %s FAILED after retries: %s",
                     getattr(pending, "step", "?"), e)
             finally:
+                # Drop the payload before blocking in get(): an idle
+                # writer would otherwise pin the last save's bytes (the
+                # whole state, again) in host memory until the next one.
+                pending = None
                 self._q.task_done()
                 if self._q.unfinished_tasks == 0:
                     self._idle.set()

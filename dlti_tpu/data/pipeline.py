@@ -68,9 +68,9 @@ def pack_sequences(
     Only the last ``open_rows`` rows are candidates for placement, keeping
     packing O(docs * open_rows) instead of O(docs * rows) — at corpus scale
     (the reference dataset is 136k docs, train.ipynb:50) unbounded first-fit
-    is billions of Python iterations. When the native runtime is built the
-    assignment loop runs in C++ (``native/packer.cc``) with a vectorized
-    numpy scatter; the pure-Python path below is the fallback and oracle.
+    is billions of Python iterations. The assignment loop runs in C++
+    (``native/packer.cc``, built on first use) with a vectorized numpy
+    scatter; the pure-Python path below is the fallback and oracle.
     """
     from dlti_tpu.utils.native import load_native_runtime
 
@@ -80,7 +80,7 @@ def pack_sequences(
     seqs = [s for s in seqs if s]
 
     native = load_native_runtime()
-    if native is not None and hasattr(native, "dlti_pack_assign") and seqs:
+    if native is not None and seqs:
         return _pack_sequences_native(native, seqs, seq_len, pad_id, open_rows)
 
     rows: List[List[int]] = []

@@ -1,8 +1,8 @@
 """Bounded background batch prefetcher — the training half of the
 host-latency-hiding layer.
 
-The r03 MFU ladder (results/mfu_investigation_r03.json) amortized *dispatch*
-with ``steps_per_sync``, but the host work between compiled windows — batch
+``steps_per_sync`` amortizes *dispatch*, but the host work between
+compiled windows — batch
 gather/pack/stack in ``TokenBatchDataset._gather`` plus the host→device
 transfer — still sat on the critical path: the device idles while Python
 stacks numpy rows. This module runs that work on a background thread,

@@ -273,6 +273,14 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         os.execvpe(cmd[0], list(cmd), env)  # never returns
     if args.num_processes <= 0:
         p.error("--num-processes N or --coordinator-from-slurm required")
+    if args.num_processes > 1:
+        # Several local JAX processes share a host only on the CPU
+        # backend; on a TPU host this exits here, before any spawn.
+        from dlti_tpu.utils.platform import refuse_multiprocess_on_tpu
+
+        refuse_multiprocess_on_tpu(
+            f"scripts/launch.py --num-processes {args.num_processes}"
+            + (" --elastic" if args.elastic else ""))
     if args.elastic:
         from dlti_tpu.training.elastic import ElasticLauncher
 

@@ -31,7 +31,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from dlti_tpu.config import LoRAConfig, ModelConfig
 from dlti_tpu.models.lora import LoRADense
-from dlti_tpu.ops.attention import reference_attention
+from dlti_tpu.ops.attention import reference_attention, resolve_paged_decode
 from dlti_tpu.ops.rope import (
     apply_rope, assert_rope_table_covers, rope_frequencies,
 )
@@ -149,16 +149,13 @@ class LlamaAttention(nn.Module):
             nb, blk_size = cache["k"].shape[0], cache["k"].shape[1]
             slots = slot_mapping(cache["block_tables"], positions, blk_size, nb)
             new_cache = paged_update(cache, k, v, slots)
-            impl = getattr(cfg, "paged_attention_impl", "auto")
-            # Under a TP mesh the pool is kv_head-sharded; pallas_call has
-            # no SPMD partitioning rules (GSPMD would all-gather the whole
-            # pool), so TP serving uses the sharded-einsum gather path.
-            tp_sharded = (self.mesh is not None
-                          and self.mesh.shape.get("tensor", 1) > 1)
-            use_kernel = s == 1 and not tp_sharded and (
-                impl == "kernel"
-                or (impl == "auto" and jax.default_backend() == "tpu")
-            )
+            # Decode steps only (s == 1): prefill attends over the
+            # gathered window on the XLA path.
+            path, _ = resolve_paged_decode(
+                cfg.paged_attention_impl,
+                tp_sharded=(self.mesh is not None
+                            and self.mesh.shape.get("tensor", 1) > 1))
+            use_kernel = s == 1 and path != "xla"
             if use_kernel:
                 # Pallas kernel: reads K/V blocks in place via the block
                 # table (no O(batch*max_len) gather); decode steps only.
@@ -172,10 +169,7 @@ class LlamaAttention(nn.Module):
                     k_scale=new_cache.get("k_scale"),
                     v_scale=new_cache.get("v_scale"),
                     window=cfg.sliding_window,
-                    # == "cpu", not != "tpu": interpret must never flip
-                    # on for a real accelerator whose backend carries a
-                    # plugin name (see ops/attention.py's flash gate).
-                    interpret=jax.default_backend() == "cpu",
+                    interpret=path == "pallas-interpret",
                 ).astype(q.dtype)
             else:
                 ck, cv = paged_gather(new_cache, cache["block_tables"])
@@ -225,7 +219,7 @@ class LlamaAttention(nn.Module):
                     q, k, v, causal=True, segment_ids=segment_ids,
                     impl=cfg.attention_impl,
                     block_q=cfg.flash_block_q, block_kv=cfg.flash_block_kv,
-                    window=window,
+                    window=window, mesh=self.mesh,
                 )
             else:
                 out = reference_attention(q, k, v, causal=True,
@@ -377,7 +371,7 @@ class LlamaModel(nn.Module):
             # class, seq 512 > table 128) — it would silently clamp.
             # Keep every table-sizing branch >= max(positions) + 1.
             table_len = max(cfg.max_seq_len, s)
-            # Trace-time enforcement of the invariant above (ADVICE r05):
+            # Trace-time enforcement of the invariant above:
             # positions here are bounded by the static sequence length
             # (arange(s) by default; packed per-doc positions < s), so an
             # under-sized table fails the trace instead of silently

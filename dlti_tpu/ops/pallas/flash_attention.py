@@ -665,11 +665,23 @@ def flash_attention(
     """Flash attention entry. q: (b, sq, h, d); k/v: (b, skv, h_kv, d).
 
     GQA runs natively in the kernel (each kv head's query group shares its
-    K/V tile — nothing is repeated in HBM). ``window`` enables
+    K/V tile — nothing is repeated in HBM). ``block_q`` is the number of
+    query ROWS per tile, counted across the group: the kernels flatten the
+    ``group = h // h_kv`` query heads of one kv head into the row
+    dimension, so a tile covers ``block_q // group`` positions of each.
+    That keeps the (rows, block_kv) f32 score tile and its twins — which
+    is what fills VMEM — the same size for MHA and GQA models. Sized per
+    head instead, Mistral's group of 4 at 512 asks for 2048-row tiles, and
+    the backward kernels then run out of VMEM on a v5e as soon as the
+    sequence spans more than one tile (seq 2048 and 8192, windowed;
+    jax 0.9.0 / libtpu 0.0.34). ``window`` enables
     Mistral-style sliding-window attention with whole-block skipping
     outside the band. ``segment_ids`` (b, s) enables packed-sequence
     masking with whole-block skipping of segment-disjoint tiles; id 0 is
     padding (such tokens attend to nothing and produce zero output).
     """
+    group = q.shape[2] // k.shape[2]
+    # Per-head positions per tile, a multiple of the 8-row sublane tile.
+    block_q = max(8, block_q // group // 8 * 8)
     return _flash_attention_core(q, k, v, segment_ids, causal, block_q,
                                  block_kv, window or 0, interpret)

@@ -52,8 +52,7 @@ def _decode_kernel(seq_lens_ref, block_tables_ref, q_ref, k_ref, v_ref, *rest,
         # (s *= k_scale, p *= v_scale) — no dequantized K/V tile is ever
         # materialized. The scale tile's minor dim is kv_heads (< the
         # 128-lane Mosaic tile): Mosaic pads it, costing a few KB of
-        # VMEM per block against the 64+ KB int8 payload — validated on
-        # hardware (results/int8_kv_7b.json).
+        # VMEM per block against the 64+ KB int8 payload.
         ks_ref, vs_ref, o_ref, m_scratch, l_scratch, acc_scratch = rest
     else:
         (o_ref, m_scratch, l_scratch, acc_scratch), ks_ref, vs_ref = rest, None, None

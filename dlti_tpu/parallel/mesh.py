@@ -98,19 +98,13 @@ def initialize_multihost(
     MASTER_ADDR/LOCAL_RANK env contract (``train_deepspeed_zero1.py:120-121``,
     ``train.ipynb:640-647``). With no args, JAX auto-detects cluster env
     (GKE/GCE metadata, SLURM, or MEGASCALE vars)."""
-    import os
-
-    if (os.environ.get("JAX_PLATFORMS") == "cpu"
-            or getattr(jax.config, "jax_platforms", None) == "cpu"):
-        # Multi-process CPU (the gloo test/dev path): this jax's CPU
-        # client builds with NO cross-process collectives by default, and
-        # every multi-process computation then fails with "Multiprocess
+    if jax.config.jax_platforms == "cpu":
+        # Multi-process CPU (the gloo test/dev path): the CPU client
+        # builds with NO cross-process collectives by default, and every
+        # multi-process computation then fails with "Multiprocess
         # computations aren't implemented on the CPU backend". Select the
         # gloo TCP implementation before the backend initializes.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:
-            pass  # older jax without the flag: gloo was the default
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,

@@ -193,7 +193,7 @@ def pipeline_forward(
     # (same fix as models/llama.py: positions >= table length hit
     # jnp.take's NaN fill and training silently NaNs).
     table_len = max(cfg.max_seq_len, s)
-    # Trace-time guard (ADVICE r05): apply_rope clip-gathers, so an
+    # Trace-time guard: apply_rope clip-gathers, so an
     # under-sized table would silently clamp angles — fail the trace here
     # where the max position (< s) is statically known.
     from dlti_tpu.ops.rope import assert_rope_table_covers

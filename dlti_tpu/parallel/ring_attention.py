@@ -243,17 +243,9 @@ def ring_attention(
     # XLA inserts the k/v gathers, numerics and gradients are exact by
     # construction (no nested manual region at all). The flat path below
     # keeps the true ring schedule.
-    # No try/except here: if a jax upgrade changes this introspection
-    # API, fail LOUD — silently assuming "not nested" would route PP x SP
-    # into the known-broken nested manual ring (wrong gradients).
-    am = jax.sharding.get_abstract_mesh()
-    nested = (am is not None and not am.empty
-              and any(ty == jax.sharding.AxisType.Manual
-                      and am.shape[name] > 1
-                      for name, ty in zip(am.axis_names, am.axis_types)))
-    if nested:
-        from dlti_tpu.ops.attention import reference_attention
+    from dlti_tpu.ops.attention import in_manual_region, reference_attention
 
+    if in_manual_region():
         return reference_attention(
             q, k, v, causal=causal, segment_ids=segment_ids,
             q_positions=positions, kv_positions=positions, window=window,

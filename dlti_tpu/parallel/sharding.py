@@ -35,6 +35,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dlti_tpu.config import Config, ZeROStage
 from dlti_tpu.training.state import TrainState
+from dlti_tpu.utils.logging import get_logger
 
 # ----------------------------------------------------------------------
 # Tensor-parallel rules: param-name regex -> (dim sharded by 'tensor')
@@ -183,11 +184,8 @@ def _zero_opt_leaf_pspec(shape: tuple, axis: str, size: int) -> P:
 
 def _host_memory_kind(mesh: Mesh) -> Optional[str]:
     """"pinned_host" when the backend exposes it, else None (no offload)."""
-    try:
-        kinds = {m.kind for m in mesh.devices.flat[0].addressable_memories()}
-        return "pinned_host" if "pinned_host" in kinds else None
-    except Exception:
-        return None
+    kinds = {m.kind for m in mesh.devices.flat[0].addressable_memories()}
+    return "pinned_host" if "pinned_host" in kinds else None
 
 
 def param_shardings(params: Any, cfg: Config, mesh: Mesh) -> Any:
@@ -548,8 +546,18 @@ def _supports_host_compute_inputs(mesh: Mesh) -> bool:
             # Rows sized to the axis so the shard is never ragged.
             probe(P(ax), 8 * mesh.shape[ax])
         ok = True
-    except Exception:
+    except Exception as e:  # noqa: BLE001 — the compiler's refusal IS the answer
+        # Said out loud: it decides between in-step weight streaming and
+        # boundary transfers, and a refusal on a chip is worth reading.
+        get_logger().warning(
+            "host-memory operands in compiled programs: refused on %s mesh "
+            "%s (%s: %s); offload falls back to boundary transfers",
+            key[0], dict(mesh.shape), type(e).__name__, str(e)[:300])
         ok = False
+    else:
+        get_logger().info(
+            "host-memory operands in compiled programs: supported on %s "
+            "mesh %s", key[0], dict(mesh.shape))
     _HOST_COMPUTE_PROBE_CACHE[key] = ok
     return ok
 

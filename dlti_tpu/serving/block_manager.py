@@ -4,9 +4,10 @@ The bookkeeping half of the paged cache (device half:
 ``dlti_tpu.ops.kv_cache``) — the role vLLM's C++/Python BlockManager plays in
 the stack the reference claims but doesn't ship (``README.md:10``).
 
-When the native runtime library has been built (``native/``), allocation is
-delegated to the C++ core via ctypes; otherwise a pure-Python free-list is
-used. Both implement the same contract and are covered by the same tests.
+Allocation is delegated to the C++ core via ctypes (``native/``, built from
+source on first use — ``dlti_tpu.utils.native``); where that cannot be
+built a pure-Python free-list serves. Both implement the same contract and
+are covered by the same tests.
 
 Physical block 0 is reserved as a trash block: inactive decode slots write
 their (ignored) K/V there, so the compiled decode step never needs a branch
@@ -33,10 +34,6 @@ class BlockManager:
         self._native = load_native_runtime()
         if self._native is not None:
             self._handle = self._native.dlti_allocator_create(num_blocks)
-            # Older prebuilt libraries predate the checked-free ABI; they
-            # keep the legacy (unguarded) free path.
-            self._checked_free = hasattr(self._native,
-                                         "dlti_allocator_free_checked")
         else:
             self._handle = None
             # Block 0 reserved; LIFO free list for cache locality.
@@ -93,15 +90,12 @@ class BlockManager:
             import ctypes
 
             arr = (ctypes.c_int32 * len(blocks))(*blocks)
-            if self._checked_free:
-                ok = self._native.dlti_allocator_free_checked(
-                    self._handle, len(blocks), arr)
-                if not ok:
-                    raise ValueError(
-                        f"invalid or double free in {blocks} (native "
-                        "allocator rejected the batch; no block was freed)")
-            else:
-                self._native.dlti_allocator_free(self._handle, len(blocks), arr)
+            ok = self._native.dlti_allocator_free_checked(
+                self._handle, len(blocks), arr)
+            if not ok:
+                raise ValueError(
+                    f"invalid or double free in {blocks} (native "
+                    "allocator rejected the batch; no block was freed)")
             return
         # Validate the whole batch first (including intra-batch
         # duplicates) so a raise frees nothing.

@@ -370,9 +370,9 @@ class AsyncEngine:
                     return
             # Step OUTSIDE the lock: one step is a compiled-program call
             # (>1 s at large steps_per_sync), and holding the lock across
-            # it serializes every HTTP submit against the device — the
-            # measured 54-66% slot occupancy under load vs 94% offline
-            # (results/int8_kv_7b.json). Concurrent engine.submit() only
+            # it serializes every HTTP submit against the device, which
+            # shows as low slot occupancy under load. Concurrent
+            # engine.submit() only
             # appends to the waiting deque (GIL-atomic) and touches its
             # own stats key; admission consumes the deque at one point
             # inside step(), so a racing submit lands this step or next.

@@ -15,6 +15,7 @@ One class drives what the reference spreads across four scripts
 
 from __future__ import annotations
 
+import json
 import os
 import sys
 import time
@@ -25,6 +26,7 @@ import numpy as np
 
 from dlti_tpu.config import Config
 from dlti_tpu.models import LlamaForCausalLM, count_params
+from dlti_tpu.ops.attention import resolve_flash
 # Submodule imports (not the package) so that `dlti_tpu.parallel` ->
 # `training.state` -> `dlti_tpu.training` (which re-exports Trainer) does
 # not cycle back into the half-initialized parallel package.
@@ -50,12 +52,13 @@ from dlti_tpu.utils.experiment import experiment_name_from_config
 from dlti_tpu.utils.logging import StepTimer, get_logger, is_main_process
 from dlti_tpu.utils.metrics import (
     MetricsRecord,
+    chip_peak_flops,
     compute_mfu,
-    detect_chip_peak_flops,
     device_peak_memory,
     print_metrics_summary,
     save_training_metrics,
 )
+from dlti_tpu.utils.platform import device_facts
 
 
 def _batch_compatible(a: dict, b: dict) -> bool:
@@ -72,8 +75,8 @@ def _batch_compatible(a: dict, b: dict) -> bool:
 
 def _validate_pipeline_config(cfg: Config) -> None:
     """Reject strategy combinations the GPipe path does not implement —
-    loudly, at construction, instead of silently mis-sharding (VERDICT r02
-    weak #2: PP must be reachable from the production Trainer)."""
+    loudly, at construction, instead of silently mis-sharding (PP is
+    reachable from the production Trainer, so it must refuse here)."""
     par = cfg.parallel
     illegal = []
     # The whole ZeRO family composes as of r05. ZeRO-1: optimizer state
@@ -169,6 +172,29 @@ def _validate_pipeline_config(cfg: Config) -> None:
         raise ValueError("grad_accum_steps must be >= 1 under pipe")
 
 
+def _release_superseded(old_leaves: list, state) -> None:
+    """Free the single-device arrays a mesh placement made copies of.
+
+    Dropping the Python references is not enough: Flax keeps the init
+    scope (and through it every initialised array) reachable behind
+    ``nn.remat``, so the whole unsharded tree stayed on the default device
+    for the run, next to that device's own shard — found on four v5e chips
+    (``chip_smoke.py --chips 4``, PR 21), where it would not fit at full
+    depth. A buffer the placed state still uses (a same-device put may
+    hand the source back) is left alone.
+    """
+    in_use = set()
+    for leaf in jax.tree_util.tree_leaves(state):
+        if isinstance(leaf, jax.Array):
+            in_use.update(s.data.unsafe_buffer_pointer()
+                          for s in leaf.addressable_shards)
+    for leaf in old_leaves:
+        if (isinstance(leaf, jax.Array) and not leaf.is_deleted()
+                and len(leaf.sharding.device_set) == 1
+                and leaf.unsafe_buffer_pointer() not in in_use):
+            leaf.delete()
+
+
 class Trainer:
     def __init__(self, cfg: Config, model: Optional[LlamaForCausalLM] = None,
                  base_params: Optional[dict] = None):
@@ -211,6 +237,31 @@ class Trainer:
         # disabled placeholder keeps every enter() site a one-attribute-
         # read no-op (methods outside the loop transition through it too).
         self._ledger = GoodputLedger(enabled=False)
+        self._log_build()
+
+    def _log_build(self) -> None:
+        """One line, once per trainer: where it runs and what the flash
+        site resolved to (chip_smoke.py and operators read it)."""
+        cfg = self.cfg
+        if cfg.parallel.sequence > 1:
+            flash = ("xla", "ring attention over the 'sequence' mesh axis")
+        else:
+            flash = resolve_flash(
+                cfg.model.attention_impl, seq_q=cfg.data.max_seq_len,
+                seq_kv=cfg.data.max_seq_len,
+                head_dim=cfg.model.resolved_head_dim)
+        self.logger.info("trainer build: %s", json.dumps({
+            **device_facts(),
+            "mesh": ({ax: n for ax, n in self.mesh.shape.items() if n > 1}
+                     if self.mesh is not None else {}),
+            "model_layers": cfg.model.num_layers,
+            "compute_dtype": cfg.model.dtype,
+            "param_dtype": cfg.model.param_dtype,
+            "frozen_base": (cfg.train.quantize_frozen_base
+                            or cfg.model.param_dtype),
+            "flash": flash[0],
+            "flash_reason": flash[1],
+        }, sort_keys=True))
 
     # ------------------------------------------------------------------
     def init_state(self, rng: Optional[jax.Array] = None) -> TrainState:
@@ -246,6 +297,8 @@ class Trainer:
             # so quantizing a 7B tree never holds both copies in HBM.
             state = state.replace(
                 params=quantize_params_int8(state.params, donate=True))
+        # The state as initialised: whole, on the default device.
+        unplaced = jax.tree_util.tree_leaves(state)
         if self.mesh is not None and self.cfg.parallel.pipe > 1:
             # Pipeline layout: layers_{i} subtrees stack with a leading
             # layer dim, sharded over 'pipe'; embed/norm/head + optimizer
@@ -307,6 +360,8 @@ class Trainer:
             ))
         elif self.mesh is not None:
             state = shard_train_state(state, self.cfg, self.mesh)
+        if self.mesh is not None:
+            _release_superseded(unplaced, state)
         return state
 
     def _build_step(self, state: TrainState):
@@ -651,8 +706,8 @@ class Trainer:
         # Constants for the per-step MFU/throughput fields (same terms
         # _final_metrics uses for the run-level record). The ledger needs
         # them too: its MFU gauge is the /metrics twin of the steplog's.
-        peak_flops = (detect_chip_peak_flops()
-                      if (steplog is not None or ledger.enabled) else 0.0)
+        # None on the CPU backend: the MFU fields are then null / unset.
+        peak_flops = chip_peak_flops()
         n_for_flops = (cfg.model.num_active_params()
                        if cfg.model.num_experts > 0 else total)
 
@@ -1050,15 +1105,17 @@ class Trainer:
                                   / max(jax.device_count(), 1)
                                   if dt > 0 else 0.0)
                     peak_gb, peak_src = device_peak_memory()
+                    mfu_step = compute_mfu(tok_s_chip, n_for_flops,
+                                           peak_flops,
+                                           trainable_params=trainable)
                     steplog.log_step(
                         global_step,
                         loss=losses[-1],
                         grad_norm=float(m["grad_norm"]),
                         lr=schedule_lr(cfg.optimizer, global_step),
                         tokens_per_second_per_chip=round(tok_s_chip, 2),
-                        mfu_percent=round(compute_mfu(
-                            tok_s_chip, n_for_flops, peak_flops,
-                            trainable_params=trainable), 4),
+                        mfu_percent=(None if mfu_step is None
+                                     else round(mfu_step, 4)),
                         peak_memory_gb=round(peak_gb, 4),
                         peak_memory_source=peak_src,
                         step_time_s=round(dt, 6),
@@ -1577,6 +1634,10 @@ class Trainer:
                 100 * ledger.goodput_fraction(totals), sum(totals.values()),
                 ", ".join(f"{k} {v:.1f}s" for k, v in top))
         if is_main_process():
+            # Every local chip, not their sum: a mesh that left a chip
+            # empty shows here (absent on the CPU backend — no stats).
+            self.logger.info("device memory: %s", json.dumps(
+                memledger_mod.device_bytes_in_use(), sort_keys=True))
             print_metrics_summary(record)
             save_training_metrics(record, csv_path=cfg.train.metrics_csv)
         return state, record
@@ -1677,7 +1738,7 @@ class Trainer:
         tok_s_chip = (
             timer.steps_per_second * tokens_per_step / max(jax.device_count(), 1)
         )
-        peak_flops = detect_chip_peak_flops()
+        peak_flops = chip_peak_flops()
         # MoE: FLOPs/token follow the k *routed* experts, not all E.
         n_for_flops = (cfg.model.num_active_params()
                        if cfg.model.num_experts > 0 else total)

@@ -15,9 +15,30 @@ from contextlib import contextmanager
 
 
 def is_main_process() -> bool:
+    """True on the process that logs and writes run-level records.
+
+    A process that never joined ``jax.distributed`` is alone and is the
+    main one by definition — answering that must not initialise a backend
+    (importing a package creates loggers, and a process that merely
+    imports must not take the chip). Only a joined process asks JAX for
+    its index, and it holds a backend anyway.
+    """
     import jax
 
+    if not jax.distributed.is_initialized():
+        return True
     return jax.process_index() == 0
+
+
+class _MainProcessFilter(logging.Filter):
+    """INFO and below from the main process only; warnings from all.
+
+    Evaluated per record, not at logger creation: module-scope loggers are
+    created at import, before the launcher env has been turned into a
+    ``jax.distributed`` membership."""
+
+    def filter(self, record: logging.LogRecord) -> bool:
+        return record.levelno >= logging.WARNING or is_main_process()
 
 
 def get_logger(name: str = "dlti_tpu") -> logging.Logger:
@@ -27,8 +48,9 @@ def get_logger(name: str = "dlti_tpu") -> logging.Logger:
         handler.setFormatter(
             logging.Formatter("%(asctime)s %(levelname)s %(name)s: %(message)s")
         )
+        handler.addFilter(_MainProcessFilter())
         logger.addHandler(handler)
-        logger.setLevel(logging.INFO if is_main_process() else logging.WARNING)
+        logger.setLevel(logging.INFO)
         logger.propagate = False
     return logger
 

@@ -62,18 +62,6 @@ int32_t dlti_allocator_allocate(void* handle, int32_t n, int32_t* out) {
   return 1;
 }
 
-void dlti_allocator_free(void* handle, int32_t n, const int32_t* blocks) {
-  auto* a = static_cast<Allocator*>(handle);
-  std::lock_guard<std::mutex> lock(a->mu);
-  for (int32_t i = 0; i < n; ++i) {
-    int32_t b = blocks[i];
-    if (b >= 1 && b < a->num_blocks) {
-      a->free_list.push_back(b);
-      a->live[b] = 0;
-    }
-  }
-}
-
 // Guarded free: O(1) live-flag check per block. Returns 1 and frees the
 // whole batch, or returns 0 and frees NOTHING if any id is out of range,
 // not currently allocated (double free), or duplicated within the batch —

@@ -29,9 +29,9 @@ if os.path.isdir(os.path.join(_repo_root, "dlti_tpu")):
     sys.path.insert(0, _repo_root)
 del _repo_root
 
-from dlti_tpu.utils.platform import honor_platform_env
+from dlti_tpu.utils.platform import enable_compilation_cache
 
-honor_platform_env()
+enable_compilation_cache()
 
 
 def parse_args():
@@ -74,10 +74,10 @@ def build_engine(spec: dict):
         model_cfg = cfg.model
         lora_cfg = cfg.lora if cfg.lora.enabled else None
     else:
-        from dlti_tpu.config import MODEL_PRESETS
+        from dlti_tpu.config import resolve_model
         from dlti_tpu.models import LlamaForCausalLM
 
-        model_cfg = MODEL_PRESETS[spec["model_preset"]]
+        model_cfg = resolve_model(spec["model_preset"])
         lora_cfg = None
         model = LlamaForCausalLM(model_cfg, None)
         params = model.init(jax.random.PRNGKey(0),

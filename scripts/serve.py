@@ -32,9 +32,9 @@ if os.path.isdir(os.path.join(_repo_root, "dlti_tpu")):
     sys.path.insert(0, _repo_root)
 del _repo_root
 
-from dlti_tpu.utils.platform import honor_platform_env
+from dlti_tpu.utils.platform import enable_compilation_cache
 
-honor_platform_env()
+enable_compilation_cache()
 
 
 def parse_args():
@@ -43,7 +43,8 @@ def parse_args():
     p.add_argument("--model-dir", default=None,
                    help="consolidated export dir (scripts/train.py --export-dir)")
     p.add_argument("--random-init", default=None, metavar="PRESET",
-                   help="serve a random-weight model preset (smoke/bench)")
+                   help="serve a random-weight model preset (smoke/bench); "
+                        "'PRESET:layers=N' cuts its depth")
     p.add_argument("--tokenizer", default="meta-llama/Llama-2-7b-hf")
     p.add_argument("--host", default="127.0.0.1")
     p.add_argument("--port", type=int, default=8000)
@@ -392,6 +393,13 @@ def main() -> None:
     args = parse_args()
     if not args.model_dir and not args.random_init:
         raise SystemExit("need --model-dir or --random-init PRESET")
+    if args.fleet_workers > 0:
+        # Worker processes share a host only on the CPU backend; on a TPU
+        # host this exits here, before any weight is built or worker spawned.
+        from dlti_tpu.utils.platform import refuse_multiprocess_on_tpu
+
+        refuse_multiprocess_on_tpu(
+            f"scripts/serve.py --fleet-workers {args.fleet_workers}")
 
     import jax
     import jax.numpy as jnp
@@ -421,10 +429,10 @@ def main() -> None:
         print(f"loaded export {args.model_dir} "
               f"(layers={model_cfg.num_layers}, hidden={model_cfg.hidden_size})")
     else:
-        from dlti_tpu.config import MODEL_PRESETS
+        from dlti_tpu.config import resolve_model
         from dlti_tpu.models import LlamaForCausalLM
 
-        model_cfg = MODEL_PRESETS[args.random_init]
+        model_cfg = resolve_model(args.random_init)
         lora_cfg = None
         model = LlamaForCausalLM(model_cfg, None)
         params = model.init(jax.random.PRNGKey(0),

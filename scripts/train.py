@@ -43,9 +43,9 @@ if os.path.isdir(os.path.join(_repo_root, "dlti_tpu")):
     sys.path.insert(0, _repo_root)
 del _repo_root
 
-from dlti_tpu.utils.platform import honor_platform_env
+from dlti_tpu.utils.platform import enable_compilation_cache
 
-honor_platform_env()
+enable_compilation_cache()
 
 
 def parse_args():
@@ -54,7 +54,8 @@ def parse_args():
     p.add_argument("--preset", default="baseline",
                    help="strategy: baseline | zero1 | zero2 | zero3")
     p.add_argument("--model", default="llama2_7b",
-                   help="model preset name (see dlti_tpu.config.MODEL_PRESETS)")
+                   help="model preset name (see dlti_tpu.config."
+                        "MODEL_PRESETS); 'NAME:layers=N' cuts its depth")
     p.add_argument("--dataset-path", "--dataset_path", default="./data/glaive_code_full",
                    help="HF save_to_disk dir, JSONL with a `text` field, or plain-text file")
     p.add_argument("--output-dir", "--output_dir", default="./checkpoints/run")

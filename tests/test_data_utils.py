@@ -142,10 +142,9 @@ def test_native_packer_matches_python_oracle(monkeypatch):
     from dlti_tpu.data.pipeline import pack_sequences
     from dlti_tpu.utils import native as native_mod
 
-    if native_mod.load_native_runtime() is None or not hasattr(
-            native_mod.load_native_runtime(), "dlti_pack_assign"):
+    if native_mod.load_native_runtime() is None:
         import pytest
-        pytest.skip("native runtime not built")
+        pytest.skip("native runtime could not be built here")
 
     rng = np.random.default_rng(0)
     seqs = [list(map(int, rng.integers(1, 100, rng.integers(1, 40))))

@@ -198,3 +198,46 @@ def test_flash_windowed_grid_with_segments_and_gqa(rng):
     g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g_fa, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
+
+
+@pytest.mark.parametrize("axes", [dict(fsdp=4), dict(data=2, tensor=2),
+                                  dict(fsdp=2, tensor=2, expert=2)])
+def test_flash_under_a_mesh_runs_per_shard_and_matches_reference(axes):
+    """A Mosaic kernel cannot be partitioned by GSPMD (on the chip the
+    sharded train step failed to lower), so under a mesh the dispatcher
+    runs it per shard in a shard_map: batch rows over data/fsdp, heads over
+    tensor. Loss and every gradient — including that of a REPLICATED weight
+    upstream of the kernel, the case a wrong shard_map transpose corrupts —
+    must equal the unsharded reference."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from dlti_tpu.config import ParallelConfig
+    from dlti_tpu.ops.attention import multi_head_attention
+    from dlti_tpu.parallel.mesh import build_mesh
+
+    mesh = build_mesh(ParallelConfig(**axes))
+    b, s, h, kv, d = 4, 256, 8, 4, 128
+    key = jax.random.PRNGKey(0)
+    q = jax.random.normal(key, (b, s, h, d))
+    k = jax.random.normal(jax.random.fold_in(key, 1), (b, s, kv, d))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (b, s, kv, d))
+    w = jax.random.normal(jax.random.fold_in(key, 3), (d, d)) * 0.1
+
+    def loss(attn):
+        return lambda q, k, v, w: jnp.sum(jnp.tanh(attn(q @ w, k, v)) ** 2)
+
+    sharded = loss(lambda q, k, v: multi_head_attention(
+        q, k, v, causal=True, impl="flash", block_q=128, block_kv=128,
+        window=96, mesh=mesh))
+    plain = loss(lambda q, k, v: reference_attention(
+        q, k, v, causal=True, window=96))
+    rows = NamedSharding(mesh, P(("data", "fsdp"), None, None, None))
+    got_l, got_g = jax.jit(jax.value_and_grad(sharded, argnums=(0, 1, 2, 3)))(
+        *(jax.device_put(x, rows) for x in (q, k, v)),
+        jax.device_put(w, NamedSharding(mesh, P())))
+    want_l, want_g = jax.jit(jax.value_and_grad(plain, argnums=(0, 1, 2, 3)))(
+        q, k, v, w)
+    np.testing.assert_allclose(got_l, want_l, rtol=1e-4)
+    for got, want in zip(got_g, want_g):
+        np.testing.assert_allclose(got, want, rtol=1e-3,
+                                   atol=1e-4 * float(jnp.max(jnp.abs(want))))

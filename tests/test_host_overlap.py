@@ -349,12 +349,7 @@ def test_decode_state_upload_counters_exposed(tiny_params):
 
 def test_block_manager_double_free_raises():
     from dlti_tpu.serving.block_manager import BlockManager
-    from dlti_tpu.utils.native import load_native_runtime
 
-    native = load_native_runtime()
-    if native is not None and not hasattr(native,
-                                          "dlti_allocator_free_checked"):
-        pytest.skip("prebuilt native runtime predates checked free")
     bm = BlockManager(num_blocks=16, block_size=8)
     blocks = bm.allocate(4)
     bm.free(blocks[:2])

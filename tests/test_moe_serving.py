@@ -1,4 +1,4 @@
-"""MoE (Mixtral-style) models through the serving engine (VERDICT r03 #9).
+"""MoE (Mixtral-style) models through the serving engine.
 
 The engine needs no MoE-specific decode path by construction: MoEMLP is a
 drop-in for LlamaMLP inside LlamaBlock (static top-k dispatch, fixed

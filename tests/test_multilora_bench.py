@@ -1,49 +1,18 @@
-"""CI smoke for the multi-LoRA A/B microbench (satellite of the
-multi-LoRA serving PR), mirroring tests/test_disagg_bench.py: the
-artifact generator behind ``results/multilora_cpu.json`` must stay
-runnable, and its equivalence claim must hold on a cold run — every
-request's tokens byte-identical between the shared-base engine and the
-per-adapter merged engines, with a genuinely heterogeneous batch on the
-measured path. Throughput numbers are properties of the committed
-artifact (quiet machine), not of this noisy smoke run, so the smoke pins
-shape + equivalence + the weight-bytes arithmetic, not the margins."""
+"""Pin for the multi-LoRA A/B microbench's committed artifact
+(``results/multilora_cpu.json``, satellite of the multi-LoRA serving PR):
+every request's tokens byte-identical between the shared-base engine and
+the per-adapter merged engines, with a genuinely heterogeneous batch on
+the measured path, and the weight-bytes arithmetic.
+
+The artifact's generator (``benchmarks_dev/multilora_ab.py``) was never
+committed — ``.gitignore`` listed ``benchmarks_dev/`` until PR 21 — so the
+smoke test that re-ran it is gone with it; the pin on the artifact stays.
+Equivalence itself is tier-1 in ``tests/test_adapters.py``."""
 
 import json
 import os
-import subprocess
-import sys
-
-import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(REPO, "benchmarks_dev", "multilora_ab.py")
-
-
-@pytest.mark.slow
-def test_multilora_ab_bench_smoke(tmp_path):
-    out = tmp_path / "multilora_cpu.json"
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable, BENCH, "--adapters", "4", "--requests", "12",
-         "--max-tokens", "8", "--json-out", str(out)],
-        env=env, capture_output=True, text=True, timeout=600, cwd=REPO)
-    assert proc.returncode == 0, proc.stderr[-1500:]
-    report = json.loads(out.read_text())
-
-    # The bench itself asserts equivalence before writing; the report
-    # must record it, and the batch must have been truly heterogeneous.
-    assert report["outputs_equal"] is True
-    assert report["max_concurrent_adapters"] >= 4
-    # The consolidation arithmetic: one base + a small pool beats N full
-    # merged copies, and the ledger-visible numbers are self-consistent.
-    sw, mw = report["shared"]["weight_bytes"], report["merged"]["weight_bytes"]
-    assert sw["total"] == sw["base"] + sw["adapter_pool"]
-    assert mw["total"] == mw["per_replica"] * report["adapters"]
-    assert sw["total"] < mw["total"]
-    assert report["shared"]["pool"]["loads"] == report["adapters"]
-    for key in ("benchmark", "platform", "adapters", "rank",
-                "weight_bytes_saving_frac", "shared", "merged"):
-        assert key in report, key
 
 
 def test_committed_artifact_meets_the_bar():

@@ -2,8 +2,8 @@
 
 Grads flow only to the LoRA factors, so the frozen base may rest in HBM as
 weight-only int8 (``TrainConfig.quantize_frozen_base``) — the lever that
-frees ~half the base-weight HBM for activation saving at 7B (the measured
-MFU wall, results/mfu_investigation_r02.json). Contracts under test:
+frees ~half the base-weight HBM for activation saving at 7B. Contracts
+under test:
 
 * quant leaves partition into the frozen subset; only LoRA trains
 * the int8-frozen loss trajectory tracks the bf16 trajectory closely

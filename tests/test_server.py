@@ -288,7 +288,7 @@ def test_n_choices(live_server):
 
 
 def test_n_choices_submit_fault_cancels_submitted(live_server):
-    """ADVICE r05 orphan-burn fix: when a submit raises mid-loop for
+    """Orphan-burn fix: when a submit raises mid-loop for
     n > 1, every already-submitted choice gets cancel_requested set —
     they must not decode to max_tokens into queues nobody reads."""
     host, port = live_server
@@ -449,21 +449,16 @@ def test_loadgen_against_live_server(id_tok_server):
 
 
 def test_native_allocator_contract(tmp_path):
-    """C++ allocator obeys the same contract as the Python fallback."""
-    import os
+    """C++ allocator obeys the same contract as the Python fallback; the
+    loader builds it from native/*.cc when it is missing or stale."""
     from dlti_tpu.utils import native as native_mod
 
-    so = native_mod._lib_path()
-    if not os.path.exists(so):
-        r = subprocess.run(["make", "-C", os.path.dirname(so)],
-                           capture_output=True)
-        if r.returncode != 0:
-            pytest.skip("native toolchain unavailable")
     # Fresh load (bypass module cache).
     native_mod._TRIED = False
     native_mod._LIB = None
     lib = native_mod.load_native_runtime()
-    assert lib is not None
+    if lib is None:
+        pytest.skip("native toolchain unavailable")
 
     from dlti_tpu.serving import BlockManager
 
