@@ -19,9 +19,10 @@ seq 512, bf16 base, remat — the one cell the driver has a record of.
 throughput (ZeRO-2, same model, one V100-SXM2-32GB, ~2.93 it/s at micro-bs
 1 x seq 512, i.e. ~1500 tok/s; BASELINE.md).
 
-Env overrides: BENCH_MODEL (a model spec, ``dlti_tpu.config.resolve_model``),
-BENCH_BS, BENCH_SEQ, BENCH_STEPS, BENCH_QUANT ("" | "int8"), BENCH_REMAT
-(a remat policy or "none"), BENCH_SYNC (optimizer steps per compiled call).
+Env overrides: BENCH_MODEL (a preset name; ``DLTI_MODEL_LAYERS=N`` keeps its
+first N layers, ``dlti_tpu.config.resolve_model``), BENCH_BS, BENCH_SEQ,
+BENCH_STEPS, BENCH_QUANT ("" | "int8"), BENCH_REMAT (a remat policy or
+"none"), BENCH_SYNC (optimizer steps per compiled call).
 """
 
 from __future__ import annotations

@@ -14,10 +14,12 @@ weights are seeded random):
  -> concurrent /v1/completions + one repeated greedy prompt -> SIGTERM
 
 and judges outcomes, not exit codes (see ``_check_train`` / ``_check_serve``).
-The last line of stdout is one JSON object, ``{"ok": true, "device":
-{"platform": "tpu", "kind": ..., "count": 1}, ...}``; the exit code is 0
-only then. The times in it are set-up facts of this run (compile, load,
-save), not performance claims.
+Stdout ends with two lines, each one JSON object: the summary (model, depth,
+dtypes, which attention path each leg took, per-leg outcome and times), then
+the result, exactly ``{"ok": true, "device": {"platform": "tpu", "kind": ...,
+"count": 1}}`` with the device as the first child's JAX reports it; the exit
+code is 0 only when ``ok`` is true. The times in the summary are set-up facts
+of this run (compile, load, save), not performance claims.
 
 This process never imports jax or a jax-importing package: a chip belongs
 to one process, so every leg is a child running the real CLI, one after the
@@ -26,11 +28,15 @@ first child reports its platform) the run stops at once with a one-line
 reason, a non-zero code and no result line.
 
     python chip_smoke.py                   # one chip, the contract run
+    python chip_smoke.py --legs serve_full_depth
+                                           # one chip: serve all 32 layers
+                                           # (random init, no export) in
+                                           # the planned pool
     python chip_smoke.py --chips 4         # four-chip host: FSDP and TP
                                            # training, --tensor 4 and
                                            # --replicas 4 serving, and the
                                            # multi-process entry points'
-                                           # refusal
+                                           # refusal (--legs picks some)
     python chip_smoke.py --cpu-rehearsal   # llama_tiny on the CPU backend,
                                            # to debug this script only;
                                            # says platform: cpu
@@ -68,9 +74,15 @@ VOCAB = 32000
 # the weights twice over in host memory (tree + store payload, 29 GB) on
 # top of the ~14 GB the TPU runtime maps per process, on a 45 GiB host.
 DEFAULT_DEPTH = 8
+# Depth of the four-chip legs: the depth they were run at (PR 21). Each of
+# the six legs pays its own process start and cold compiles — the FSDP
+# train step alone compiled for 94 s at 2 layers — and a four-chip call is
+# charged four times over: 10 minutes at this depth.
+DEFAULT_DEPTH_4CHIPS = 2
 TIME_LIMIT_S = 1140  # the contract allows 1200 s, compilation included
 
 _children: list = []
+_device: dict = {}  # as the first child that reached JAX reported it
 
 
 class SmokeFailure(Exception):
@@ -85,13 +97,18 @@ class NoAccelerator(SmokeFailure):
 # Children: spawn, wait, stop
 # ----------------------------------------------------------------------
 
-def _spawn(name: str, cmd: list):
-    """Start a child in its own process group, output to a log file."""
+def _spawn(name: str, cmd: list, layers: int = 0):
+    """Start a child in its own process group, output to a log file.
+    ``layers`` cuts the depth of the model preset the child names
+    (``DLTI_MODEL_LAYERS``, dlti_tpu/config.py); 0 leaves it whole."""
     log_path = os.path.join(OUT, "logs", f"{name}.log")
     os.makedirs(os.path.dirname(log_path), exist_ok=True)
     log = open(log_path, "wb")
-    proc = subprocess.Popen(cmd, cwd=HERE,
-                            env={**os.environ, "PYTHONUNBUFFERED": "1"},
+    env = {**os.environ, "PYTHONUNBUFFERED": "1"}
+    env.pop("DLTI_MODEL_LAYERS", None)
+    if layers:
+        env["DLTI_MODEL_LAYERS"] = str(layers)
+    proc = subprocess.Popen(cmd, cwd=HERE, env=env,
                             stdout=log, stderr=subprocess.STDOUT,
                             start_new_session=True)
     log.close()  # the child holds its own descriptor
@@ -159,7 +176,20 @@ def _build_facts(log_path: str, role: str) -> list:
     return facts
 
 
+def _logged_json(log_path: str, marker: str) -> dict:
+    """The JSON object after the last ``marker`` in a child's log."""
+    found = {}
+    for line in _read(log_path).splitlines():
+        at = line.find(marker)
+        if at >= 0:
+            found = json.loads(line[at + len(marker):])
+    return found
+
+
 def _require_platform(facts: dict, want: str) -> None:
+    if not _device:
+        _device.update(platform=facts["platform"], kind=facts["device_kind"],
+                       count=facts["device_count"])
     if facts["platform"] != want:
         raise NoAccelerator(
             f"no accelerator: the first child reports platform "
@@ -206,7 +236,7 @@ def _train_leg(name: str, cfg: dict, extra: list, deadline: float,
     out_dir = os.path.join(OUT, name)
     step_log = os.path.join(out_dir, "steps.jsonl")
     export_dir = os.path.join(out_dir, "export")
-    cmd = [PY, "scripts/train.py", "--model", cfg["model_spec"],
+    cmd = [PY, "scripts/train.py", "--model", cfg["model"],
            "--tokenizer", "byte", "--lora-r", "16",
            "--max-seq-len", str(cfg["seq_len"]),
            "--dataset-path", os.path.join(OUT, "data"),
@@ -219,7 +249,7 @@ def _train_leg(name: str, cfg: dict, extra: list, deadline: float,
            "--export-dir", export_dir,
            "--metrics-csv", os.path.join(out_dir, "metrics.csv"), *extra]
     t0 = time.monotonic()
-    proc, log_path = _spawn(name, cmd)
+    proc, log_path = _spawn(name, cmd, cfg["cut_layers"])
     marks = {}
 
     def watch():
@@ -306,14 +336,24 @@ def _check_train(name, cfg, facts, step_log, out_dir, export_dir, log_path,
         raise SmokeFailure(f"{name}: no committed checkpoint in {ckpt_root}")
     if not os.path.isfile(os.path.join(export_dir, "model", "MANIFEST.json")):
         raise SmokeFailure(f"{name}: the export has no MANIFEST.json")
-    per_device = {}
-    for line in _read(log_path).splitlines():
-        at = line.find("device memory: ")
-        if at >= 0:
-            per_device = json.loads(line[at + len("device memory: "):])
+    per_device = _logged_json(log_path, "device memory: ")
+    at_init = _logged_json(log_path, "device memory after init: ")
     if on_chip:
         _require_all_chips_used(name, per_device, facts["device_count"],
                                 "peak_bytes_in_use")
+        _require_all_chips_used(name, at_init, facts["device_count"],
+                                "bytes_in_use", min_bytes=1)
+        # The state is born sharded: while it was built no chip held a
+        # multiple of what it ends up holding (init-then-shard put the
+        # whole tree on chip 0: 4.1x its share at FSDP x4, 3.2x at
+        # DP 2 x TP 2; the margin is for the initialiser's f32 scratch).
+        over = {d: s for d, s in at_init.items()
+                if s["peak_bytes_in_use"]
+                > 2.5 * s["bytes_in_use"] + (64 << 20)}
+        if over and facts["device_count"] > 1:
+            raise SmokeFailure(
+                f"{name}: while the state was built a chip held far more "
+                f"than its share: {json.dumps(over)}")
     return {
         "ok": True, "facts": facts, "export_dir": export_dir,
         "first_loss": round(rows[0]["loss"], 4),
@@ -323,6 +363,10 @@ def _check_train(name, cfg, facts, step_log, out_dir, export_dir, log_path,
         "per_device_peak_gb": {
             d: round(s.get("peak_bytes_in_use", 0) / 2**30, 3)
             for d, s in per_device.items()},
+        "per_device_after_init_gb": {
+            d: {"in_use": round(s.get("bytes_in_use", 0) / 2**30, 3),
+                "peak": round(s.get("peak_bytes_in_use", 0) / 2**30, 3)}
+            for d, s in at_init.items()},
     }
 
 
@@ -385,19 +429,22 @@ def _complete(port: int, prompt: str, max_tokens: int) -> dict:
     return {"tokens": lp["tokens"], "logprobs": lp["token_logprobs"]}
 
 
-def _serve_leg(name: str, cfg: dict, model_dir: str, extra: list,
+def _serve_leg(name: str, cfg: dict, source: list, extra: list,
                deadline: float, want_platform: str,
                expect_engines: int = 1, expect_decode: str = "pallas") -> dict:
+    """``source``: ``["--model-dir", export]`` or ``["--random-init",
+    preset]`` (then ``cfg["cut_layers"]`` cuts the preset's depth)."""
     port = _free_port()
     # Pool: scripts/memory_plan.py --model mistral_7b --serving plans bf16
     # weights (13.49 GiB) + a 0.5 GiB pool = 256 blocks x 16 tokens x
     # 128 KiB/token to 13.99 GiB of the chip's 16.
-    cmd = [PY, "scripts/serve.py", "--model-dir", model_dir,
+    cmd = [PY, "scripts/serve.py", *source,
            "--tokenizer", "byte", "--port", str(port),
            "--max-seqs", "8", "--block-size", "16",
            "--num-blocks", "256", "--max-model-len", "256", *extra]
     t0 = time.monotonic()
-    proc, log_path = _spawn(name, cmd)
+    proc, log_path = _spawn(
+        name, cmd, cfg["cut_layers"] if source[0] == "--random-init" else 0)
     try:
         while True:  # ready = weights loaded + decode programs compiled
             if proc.poll() is not None:
@@ -407,6 +454,8 @@ def _serve_leg(name: str, cfg: dict, model_dir: str, extra: list,
             if time.monotonic() > deadline:
                 raise SmokeFailure(f"{name}: server not ready in time: "
                                    f"{_tail(log_path, 5)}")
+            for facts in _build_facts(log_path, "engine")[:1]:
+                _require_platform(facts, want_platform)  # before compiling
             try:
                 if _http(port, "/health", timeout=2.0)[0] == 200:
                     break
@@ -581,10 +630,17 @@ def _prepare(cfg: dict, deadline: float) -> None:
         raise SmokeFailure(f"prepare_dataset.py failed: {_tail(log_path)}")
 
 
+# Legs by --chips; the contract run is the first two of the one-chip set.
+LEGS = {1: ("train", "serve", "serve_full_depth"),
+        4: ("train_fsdp4", "train_tp2", "serve_tensor4", "serve_replicas4",
+            "refusals")}
+
+
 def _run(args) -> dict:
     rehearsal = args.cpu_rehearsal
     want = "cpu" if rehearsal else "tpu"
-    layers = args.layers or (2 if rehearsal else DEFAULT_DEPTH)
+    layers = args.layers or (2 if rehearsal else DEFAULT_DEPTH_4CHIPS
+                             if args.chips == 4 else DEFAULT_DEPTH)
     if rehearsal:
         cfg = dict(model="llama_tiny", published_depth=2, vocab=512,
                    seq_len=128, micro_batch=2, steps=4, examples=128,
@@ -594,8 +650,7 @@ def _run(args) -> dict:
                    seq_len=512, micro_batch=2, steps=4, examples=128,
                    max_tokens=24)
     cfg["layers"] = layers
-    cfg["model_spec"] = (cfg["model"] if layers == cfg["published_depth"]
-                         else f"{cfg['model']}:layers={layers}")
+    cfg["cut_layers"] = 0 if layers == cfg["published_depth"] else layers
     t_start = time.monotonic()
     deadline = t_start + TIME_LIMIT_S * (3 if args.chips == 4 else 1)
     cache_before = _cache_entries()
@@ -611,18 +666,37 @@ def _run(args) -> dict:
     try:
         _prepare(cfg, deadline)
         if args.chips == 1:
-            legs["train"] = _train_leg(
-                "train", cfg, ["--preset", "baseline", "--num-devices", "1"],
-                deadline, want, gate_platform=True)
-            legs["serve"] = _serve_leg("serve", cfg,
-                                       legs["train"]["export_dir"], [],
-                                       deadline, want,
-                                       expect_decode=("pallas" if not rehearsal
-                                                      else "xla"))
+            if "train" in args.legs:
+                legs["train"] = _train_leg(
+                    "train", cfg,
+                    ["--preset", "baseline", "--num-devices", "1"],
+                    deadline, want, gate_platform=True)
+            if "serve" in args.legs:
+                legs["serve"] = _serve_leg(
+                    "serve", cfg,
+                    ["--model-dir", legs["train"]["export_dir"]],
+                    [], deadline, want,
+                    expect_decode="pallas" if not rehearsal else "xla")
+            if "serve_full_depth" in args.legs:
+                # Every published layer in the planned pool, weights random
+                # from a seed in the server itself: the trainer's export
+                # at this depth does not fit the one-chip host's memory
+                # (DEFAULT_DEPTH), serving it does not need one.
+                full = {**cfg, "layers": cfg["published_depth"],
+                        "cut_layers": 0}
+                legs["serve_full_depth"] = {
+                    **_serve_leg(
+                        "serve_full_depth", full,
+                        ["--random-init", cfg["model"]], [], deadline, want,
+                        expect_decode="pallas" if not rehearsal else "xla"),
+                    "depth": full["layers"],
+                    "weights": "random init in the server, no export"}
         else:
             # A four-chip call costs four times over, so one failed leg
             # does not stop the others: each reports for itself.
             def leg(name, fn, *a, **kw):
+                if name not in args.legs:
+                    return
                 try:
                     legs[name] = fn(name, *a, **kw)
                 except NoAccelerator:
@@ -635,15 +709,16 @@ def _run(args) -> dict:
                 deadline, want, gate_platform=True)
             leg("train_tp2", _train_leg, cfg,
                 ["--preset", "baseline", "--tensor", "2"],
-                deadline, want, gate_platform=False)
+                deadline, want, gate_platform="train_fsdp4" not in legs)
             export = next((legs[n]["export_dir"]
                            for n in ("train_fsdp4", "train_tp2")
-                           if legs[n]["ok"]), os.path.join(OUT, "no_export"))
+                           if legs.get(n, {}).get("ok")),
+                          os.path.join(OUT, "no_export"))
             # llama_tiny has 2 kv heads: the rehearsal shards them 2-way.
-            leg("serve_tensor4", _serve_leg, cfg, export,
+            leg("serve_tensor4", _serve_leg, cfg, ["--model-dir", export],
                 ["--tensor", "2" if rehearsal else "4"], deadline,
                 want, expect_decode="xla")
-            leg("serve_replicas4", _serve_leg, cfg, export,
+            leg("serve_replicas4", _serve_leg, cfg, ["--model-dir", export],
                 ["--replicas", "4"], deadline, want, expect_engines=4,
                 expect_decode="pallas" if not rehearsal else "xla")
             if not rehearsal:
@@ -652,18 +727,19 @@ def _run(args) -> dict:
             if failed:
                 raise SmokeFailure("; ".join(
                     f"{n}: {e[:300]}" for n, e in failed.items()))
-        first = next(iter(legs.values()))["facts"]
-        summary["device"] = {"platform": first["platform"],
-                             "kind": first["device_kind"],
-                             "count": first["device_count"]}
+        train_facts = [v["facts"] for k, v in legs.items()
+                       if k.startswith("train")]
         serve_facts = [v["facts"] for k, v in legs.items()
                        if k.startswith("serve")]
-        summary["dtypes"] = {
-            "compute": first["compute_dtype"],
-            "params": first["param_dtype"],
-            "frozen_base": first["frozen_base"],
-            "kv_cache": serve_facts[0]["kv_cache_dtype"],
-        }
+        summary["dtypes"] = {}
+        if train_facts:
+            summary["dtypes"].update(
+                compute=train_facts[0]["compute_dtype"],
+                params=train_facts[0]["param_dtype"],
+                frozen_base=train_facts[0]["frozen_base"])
+        if serve_facts:
+            summary["dtypes"]["kv_cache"] = serve_facts[0]["kv_cache_dtype"]
+            summary["block_allocator"] = serve_facts[0]["block_allocator"]
         summary["attention"] = {
             **{k: v["facts"]["flash"] for k, v in legs.items()
                if k.startswith("train")},
@@ -671,7 +747,6 @@ def _run(args) -> dict:
                    "decode": v["facts"]["paged_decode"]}
                for k, v in legs.items() if k.startswith("serve")},
         }
-        summary["block_allocator"] = serve_facts[0]["block_allocator"]
         summary["ok"] = all(leg["ok"] for leg in legs.values())
     except NoAccelerator:
         raise
@@ -682,6 +757,7 @@ def _run(args) -> dict:
         for leg in legs.values():
             leg.pop("facts", None)
             leg.pop("export_dir", None)
+        summary["device"] = dict(_device) or None
         summary["wall_s"] = round(time.monotonic() - t_start, 1)
         summary["compile_cache"] = {
             "dir": _cache_dir(), "entries_before": cache_before,
@@ -695,12 +771,25 @@ def main() -> int:
                    help="1 = the contract run; 4 = the four-chip legs")
     p.add_argument("--layers", type=int, default=0,
                    help=f"run this many whole layers of the model (default "
-                        f"{DEFAULT_DEPTH} of {PUBLISHED_DEPTH}); widths are "
+                        f"{DEFAULT_DEPTH} of {PUBLISHED_DEPTH}, "
+                        f"{DEFAULT_DEPTH_4CHIPS} with --chips 4); widths are "
                         f"never cut")
+    p.add_argument("--legs", default="", type=lambda v: v.split(","),
+                   help="run only these legs, comma-separated: of "
+                        f"{', '.join(LEGS[1])} on one chip (default: the "
+                        f"first two, the contract run), of "
+                        f"{', '.join(LEGS[4])} with --chips 4 (default: all)")
     p.add_argument("--cpu-rehearsal", action="store_true",
                    help="run llama_tiny on the CPU backend to debug this "
                         "script; the result says platform: cpu")
     args = p.parse_args()
+    if args.legs == [""]:
+        args.legs = list(LEGS[args.chips][:2] if args.chips == 1
+                         else LEGS[args.chips])
+    if set(args.legs) - set(LEGS[args.chips]) or (
+            "serve" in args.legs and "train" not in args.legs):
+        p.error(f"--legs with --chips {args.chips} takes "
+                f"{', '.join(LEGS[args.chips])} (serve needs train's export)")
     if not os.path.isfile(os.path.join(HERE, "scripts", "train.py")):
         print("chip_smoke.py: not in a checkout of the repo (no "
               "scripts/train.py next to it)", file=sys.stderr)
@@ -728,6 +817,9 @@ def main() -> int:
         print(f"chip_smoke.py: FAILED: {summary.get('error')}",
               file=sys.stderr)
     print(json.dumps(summary))
+    if _device:  # the result: these keys and no others, the last line
+        print(json.dumps({"ok": summary["ok"], "device": _device}))
+    sys.stdout.flush()
     return 0 if summary["ok"] else 1
 
 

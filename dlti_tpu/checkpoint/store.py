@@ -139,11 +139,12 @@ def _leaf_entries(state: Any) -> Tuple[List[dict], List[bytes]]:
              if isinstance(leaf, jax.Array) and not leaf.is_fully_addressable]
     consolidated: dict = {}
     if cross:
-        from jax.sharding import GSPMDSharding
+        from jax.sharding import NamedSharding, PartitionSpec
 
+        # Cross-process leaves are mesh-placed (NamedSharding): the same
+        # mesh, replicated.
         ins = [leaves_with_path[i][1] for i in cross]
-        reps = [GSPMDSharding.get_replicated(x.sharding._device_assignment)
-                for x in ins]
+        reps = [NamedSharding(x.sharding.mesh, PartitionSpec()) for x in ins]
         outs = jax.jit(lambda xs: xs, out_shardings=reps)(ins)
         for i, out in zip(cross, outs):
             consolidated[i] = np.asarray(out.addressable_data(0))

@@ -23,6 +23,7 @@ from __future__ import annotations
 import dataclasses
 import enum
 import json
+import os
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
@@ -86,6 +87,12 @@ class ModelConfig:
     # recompute forward: stride k removes 1/k of it.
     remat_stride: int = 1
     attention_impl: str = "auto"  # "auto" | "reference" | "flash"
+    # Flash kernel tiles. flash_block_q counts query ROWS across the GQA
+    # group (the kernel flattens a kv head's query heads into the row
+    # dimension), so a tile covers flash_block_q // group positions of each
+    # head, rounded down to a power of two: 512 positions for MHA, 128 for
+    # Mistral's group of 4. The (rows, block_kv) f32 score tile — what
+    # fills VMEM — is then the same size for every model.
     flash_block_q: int = 512
     flash_block_kv: int = 512
     # Packed batches: an upper bound on any packed document's token count
@@ -986,28 +993,27 @@ MODEL_PRESETS: dict = {
 }
 
 
-def resolve_model(spec: str) -> ModelConfig:
-    """``NAME`` or ``NAME:layers=N`` → the preset, optionally cut in depth.
+def resolve_model(name: str) -> ModelConfig:
+    """A preset by name: every place a user names a model
+    (``scripts/train.py --model``, ``scripts/serve.py --random-init``).
 
-    Depth is the one cut a model spec accepts: a configuration too deep
-    for the chip (or for a time limit) keeps every published width and
-    drops whole layers, and whoever does so says the depth it ran. Every
-    place a user names a model (``scripts/train.py --model``,
-    ``scripts/serve.py --random-init``) goes through here.
+    ``DLTI_MODEL_LAYERS=N`` in the environment keeps the preset's first N
+    layers and every width. It is not a user option: ``chip_smoke.py`` and
+    ``bench.py`` set it for runs that must fit a time limit or a small
+    host, and say the depth they ran (the trainer's and the engine's build
+    lines carry ``model_layers``). It goes when full depth fits there.
     """
-    name, _, cut = spec.partition(":")
     if name not in MODEL_PRESETS:
         raise ValueError(
             f"unknown model {name!r}; presets: {sorted(MODEL_PRESETS)}")
     cfg = MODEL_PRESETS[name]
+    cut = os.environ.get("DLTI_MODEL_LAYERS", "")
     if cut:
-        key, _, val = cut.partition("=")
-        if key != "layers" or not val.isdigit() \
-                or not 1 <= int(val) <= cfg.num_layers:
+        if not cut.isdigit() or not 1 <= int(cut) <= cfg.num_layers:
             raise ValueError(
-                f"bad model spec {spec!r}: the only cut is depth, "
-                f"'{name}:layers=N' with 1 <= N <= {cfg.num_layers}")
-        cfg = dataclasses.replace(cfg, num_layers=int(val))
+                f"DLTI_MODEL_LAYERS={cut!r}: {name} takes 1.."
+                f"{cfg.num_layers} whole layers")
+        cfg = dataclasses.replace(cfg, num_layers=int(cut))
     return cfg
 
 

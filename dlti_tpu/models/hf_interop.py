@@ -280,7 +280,8 @@ def hf_state_dict_from_params(params: Mapping[str, Any],
     return sd
 
 
-def graft_base_params(params: Dict[str, Any], base: Mapping[str, Any]) -> Dict[str, Any]:
+def graft_base_params(params: Dict[str, Any], base: Mapping[str, Any],
+                      place=None) -> Dict[str, Any]:
     """Overlay loaded base weights onto a freshly-initialized param tree.
 
     Leaves present in ``base`` replace the initialized values (with a shape
@@ -289,8 +290,15 @@ def graft_base_params(params: Dict[str, Any], base: Mapping[str, Any]) -> Dict[s
     semantics (``training/train_baseline.py:122-140``). Base leaves with no
     counterpart in the model tree are an architecture mismatch and raise
     (mirroring :func:`params_from_hf_state_dict`'s unconsumed-key check).
+
+    ``place(base_leaf, model_leaf)`` puts one base leaf where its model
+    leaf lives (a sharded trainer passes one, so a leaf goes straight to
+    its shards); the default casts it on the default device.
     """
     dropped: list = []
+    if place is None:
+        def place(b, p):
+            return jnp.asarray(b).astype(p.dtype)
 
     def _graft(p, b, path):
         if not isinstance(p, Mapping):
@@ -298,7 +306,7 @@ def graft_base_params(params: Dict[str, Any], base: Mapping[str, Any]) -> Dict[s
                 raise ValueError(
                     f"{'.'.join(path)}: checkpoint shape {tuple(b.shape)} != "
                     f"model shape {tuple(p.shape)} (wrong ModelConfig?)")
-            return jnp.asarray(b).astype(p.dtype)
+            return place(b, p)
         for k in b:
             if k not in p:
                 dropped.append(".".join(path + (k,)))

@@ -22,6 +22,7 @@ updates so the whole engine stays inside one compiled program.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -31,7 +32,10 @@ from jax.ad_checkpoint import checkpoint_name
 
 from dlti_tpu.config import LoRAConfig, ModelConfig
 from dlti_tpu.models.lora import LoRADense
-from dlti_tpu.ops.attention import reference_attention, resolve_paged_decode
+from dlti_tpu.ops.attention import (
+    multi_head_attention, reference_attention, resolve_flash,
+    resolve_paged_decode,
+)
 from dlti_tpu.ops.rope import (
     apply_rope, assert_rope_table_covers, rope_frequencies,
 )
@@ -212,19 +216,23 @@ class LlamaAttention(nn.Module):
                                  window=cfg.sliding_window)
         else:
             window = self._effective_window(segment_ids)
-            if cfg.attention_impl in ("flash", "auto"):
-                from dlti_tpu.ops.attention import multi_head_attention
-
-                out = multi_head_attention(
-                    q, k, v, causal=True, segment_ids=segment_ids,
-                    impl=cfg.attention_impl,
-                    block_q=cfg.flash_block_q, block_kv=cfg.flash_block_kv,
-                    window=window, mesh=self.mesh,
-                )
+            attend = functools.partial(
+                multi_head_attention, causal=True, impl=cfg.attention_impl,
+                block_q=cfg.flash_block_q, block_kv=cfg.flash_block_kv,
+                window=window)
+            path, _ = resolve_flash(cfg.attention_impl, seq_q=s, seq_kv=s,
+                                    head_dim=hd)
+            if path == "xla":
+                out = attend(q, k, v, segment_ids=segment_ids)
             else:
-                out = reference_attention(q, k, v, causal=True,
-                                          segment_ids=segment_ids,
-                                          window=window)
+                # A Pallas kernel: GSPMD cannot partition it, so under a
+                # mesh every device runs it on its own rows and heads.
+                from dlti_tpu.parallel.ring_attention import (
+                    per_shard_attention,
+                )
+
+                out = per_shard_attention(attend, q, k, v, self.mesh,
+                                          segment_ids)
 
         # Remat seam: with remat_policy="save_attn_out", the backward reuses
         # this (b, s, h*d) tensor instead of re-running the whole attention

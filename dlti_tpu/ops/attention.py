@@ -172,21 +172,9 @@ def resolve_paged_decode(impl: str, *, tp_sharded: bool) -> tuple:
     return "pallas", "auto on TPU"
 
 
-def in_manual_region() -> bool:
-    """True while tracing inside a ``shard_map`` (e.g. a pipeline stage).
-
-    No try/except around the introspection: if a jax upgrade changes it,
-    fail loud — silently answering "not nested" would route callers into a
-    nested manual region (wrong gradients on this jax)."""
-    am = jax.sharding.get_abstract_mesh()
-    return (am is not None and not am.empty
-            and any(ty == jax.sharding.AxisType.Manual and am.shape[name] > 1
-                    for name, ty in zip(am.axis_names, am.axis_types)))
-
-
 @functools.partial(
     jax.jit, static_argnames=("causal", "impl", "block_q", "block_kv",
-                              "window", "mesh")
+                              "window")
 )
 def multi_head_attention(
     q: jnp.ndarray,
@@ -199,7 +187,6 @@ def multi_head_attention(
     block_q: int = 512,
     block_kv: int = 512,
     window: int | None = None,
-    mesh=None,
 ) -> jnp.ndarray:
     """Dispatching attention entry point used by the model.
 
@@ -208,13 +195,8 @@ def multi_head_attention(
     kernel), and so does a sliding ``window`` (flash skips whole blocks
     outside the band).
 
-    ``mesh``: the training mesh, when there is one. A Mosaic kernel cannot
-    be partitioned by GSPMD ("Mosaic kernels cannot be automatically
-    partitioned"), so under a mesh the kernel runs per shard inside a
-    ``shard_map``: batch rows over the ('data', 'fsdp') axes — attention
-    never mixes rows — and heads over 'tensor' when both head counts
-    divide (each shard keeps whole GQA groups). Inside an enclosing manual
-    region (a pipeline stage) the call is left as it is.
+    A Mosaic kernel has no GSPMD partitioning rule: under a mesh the caller
+    runs this per shard (``parallel.ring_attention.per_shard_attention``).
     """
     path, _ = resolve_flash(impl, seq_q=q.shape[1], seq_kv=k.shape[1],
                             head_dim=q.shape[3], causal=causal)
@@ -223,22 +205,7 @@ def multi_head_attention(
                                    segment_ids=segment_ids, window=window)
     from dlti_tpu.ops.pallas.flash_attention import flash_attention
 
-    kernel = functools.partial(
-        flash_attention, causal=causal, block_q=block_q, block_kv=block_kv,
-        window=window, interpret=path == "pallas-interpret")
-    if mesh is None or mesh.size == 1 or in_manual_region():
-        return kernel(q, k, v, segment_ids=segment_ids)
-
-    from jax.sharding import PartitionSpec as P
-
-    tp = mesh.shape.get("tensor", 1)
-    heads = "tensor" if (tp > 1 and q.shape[2] % tp == 0
-                         and k.shape[2] % tp == 0) else None
-    spec = P(("data", "fsdp"), None, heads, None)
-    packed = () if segment_ids is None else (segment_ids,)
-    return jax.shard_map(
-        lambda q, k, v, *seg: kernel(q, k, v,
-                                     segment_ids=seg[0] if seg else None),
-        mesh=mesh,
-        in_specs=(spec, spec, spec) + (P(("data", "fsdp"), None),) * len(packed),
-        out_specs=spec, check_vma=False)(q, k, v, *packed)
+    return flash_attention(
+        q, k, v, segment_ids=segment_ids, causal=causal, block_q=block_q,
+        block_kv=block_kv, window=window,
+        interpret=path == "pallas-interpret")

@@ -681,7 +681,11 @@ def flash_attention(
     padding (such tokens attend to nothing and produce zero output).
     """
     group = q.shape[2] // k.shape[2]
-    # Per-head positions per tile, a multiple of the 8-row sublane tile.
-    block_q = max(8, block_q // group // 8 * 8)
+    # Per-head positions per tile: the largest power of two that keeps the
+    # tile within block_q rows (floor 8, the sublane tile), so it divides
+    # every 128-aligned sequence whatever the group (7 -> 64, not 72 with
+    # a padded last tile).
+    if group > 1:
+        block_q = max(8, 1 << (max(1, block_q // group).bit_length() - 1))
     return _flash_attention_core(q, k, v, segment_ids, causal, block_q,
                                  block_kv, window or 0, interpret)

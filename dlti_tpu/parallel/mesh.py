@@ -29,6 +29,20 @@ from jax.sharding import Mesh
 from dlti_tpu.config import ParallelConfig
 
 MESH_AXES = ("data", "fsdp", "tensor", "sequence", "pipe", "expert")
+# The axes batch rows shard over (ZeRO-3 shards rows over 'fsdp' as well).
+BATCH_AXES = ("data", "fsdp")
+
+
+def in_manual_region() -> bool:
+    """True while tracing inside a ``shard_map`` (e.g. a pipeline stage).
+
+    No try/except around the introspection: if a jax upgrade changes it,
+    fail loud — silently answering "not nested" would route callers into a
+    nested manual region (wrong gradients on this jax)."""
+    am = jax.sharding.get_abstract_mesh()
+    return (am is not None and not am.empty
+            and any(ty == jax.sharding.AxisType.Manual and am.shape[name] > 1
+                    for name, ty in zip(am.axis_names, am.axis_types)))
 
 
 def build_mesh(cfg: ParallelConfig, devices: Optional[Sequence] = None) -> Mesh:

@@ -43,7 +43,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from dlti_tpu.ops.attention import repeat_kv
+from dlti_tpu.ops.attention import reference_attention, repeat_kv
+from dlti_tpu.parallel.mesh import BATCH_AXES, in_manual_region
 
 # Finite stand-in for -inf. Keeps every exp()/max() total (no inf-inf=NaN
 # corner) while exp(NEG_INF - anything_finite) underflows to exactly 0.
@@ -173,6 +174,43 @@ def ring_attention_local(
     return out.astype(q.dtype)
 
 
+def _qkv_spec(mesh: Mesh, q, k, seq_axis=None, batch_axes: tuple = BATCH_AXES,
+              head_axis: str = "tensor") -> P:
+    """Layout of (b, s, heads, d) attention operands over the mesh: rows
+    over the batch axes, heads over ``head_axis`` only when both head
+    counts divide (each shard keeps whole GQA groups)."""
+    tp = mesh.shape.get(head_axis, 1)
+    h_ax = head_axis if (tp > 1 and q.shape[2] % tp == 0
+                         and k.shape[2] % tp == 0) else None
+    return P(batch_axes, seq_axis, h_ax, None)
+
+
+def per_shard_attention(attend, q, k, v, mesh: Optional[Mesh],
+                        segment_ids=None) -> jnp.ndarray:
+    """Run ``attend(q, k, v, segment_ids=)`` on each device's own rows and
+    heads: the way a Pallas kernel runs under a mesh.
+
+    A Mosaic kernel cannot be partitioned by GSPMD ("Mosaic kernels cannot
+    be automatically partitioned. Please wrap the call in a shard_map" —
+    what sharded training answered on the v5e), and self-attention never
+    mixes batch rows or heads, so each shard computes its own: rows over
+    the batch axes, heads over 'tensor' (:func:`_qkv_spec`); the sequence
+    stays whole. Inside an enclosing manual region (a pipeline stage) the
+    call is left as it is — a nested shard_map is untrainable on this jax —
+    and the trainer refuses that combination on a TPU
+    (``training.trainer._validate_pipeline_config``).
+    """
+    if mesh is None or mesh.size == 1 or in_manual_region():
+        return attend(q, k, v, segment_ids=segment_ids)
+    spec = _qkv_spec(mesh, q, k)
+    packed = () if segment_ids is None else (segment_ids,)
+    return jax.shard_map(
+        lambda q, k, v, *seg: attend(q, k, v,
+                                     segment_ids=seg[0] if seg else None),
+        mesh=mesh, in_specs=(spec, spec, spec) + (P(BATCH_AXES, None),) * len(packed),
+        out_specs=spec, check_vma=False)(q, k, v, *packed)
+
+
 def ring_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -184,7 +222,7 @@ def ring_attention(
     causal: bool = True,
     window: Optional[int] = None,
     seq_axis: str = "sequence",
-    batch_axes: tuple = ("data", "fsdp"),
+    batch_axes: tuple = BATCH_AXES,
     head_axis: str = "tensor",
 ) -> jnp.ndarray:
     """Global-view ring attention entry point (callable inside ``jit``).
@@ -206,8 +244,6 @@ def ring_attention(
     """
     n = mesh.shape[seq_axis]
     if n == 1:
-        from dlti_tpu.ops.attention import reference_attention
-
         return reference_attention(
             q, k, v, causal=causal, segment_ids=segment_ids,
             q_positions=positions, kv_positions=positions, window=window,
@@ -224,10 +260,7 @@ def ring_attention(
     else:
         positions = jnp.broadcast_to(positions.astype(jnp.int32), (b, s))
 
-    h, hk = q.shape[2], k.shape[2]
-    tp = mesh.shape.get(head_axis, 1)
-    h_ax = head_axis if (tp > 1 and h % tp == 0 and hk % tp == 0) else None
-    spec = P(batch_axes, seq_axis, h_ax, None)
+    spec = _qkv_spec(mesh, q, k, seq_axis, batch_axes, head_axis)
     pos_spec = P(batch_axes, seq_axis)
 
     # Inside an enclosing shard_map (PP x SP: the pipe schedule is manual
@@ -243,8 +276,6 @@ def ring_attention(
     # XLA inserts the k/v gathers, numerics and gradients are exact by
     # construction (no nested manual region at all). The flat path below
     # keeps the true ring schedule.
-    from dlti_tpu.ops.attention import in_manual_region, reference_attention
-
     if in_manual_region():
         return reference_attention(
             q, k, v, causal=causal, segment_ids=segment_ids,

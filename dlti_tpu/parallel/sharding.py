@@ -34,6 +34,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dlti_tpu.config import Config, ZeROStage
+from dlti_tpu.parallel.mesh import BATCH_AXES
 from dlti_tpu.training.state import TrainState
 from dlti_tpu.utils.logging import get_logger
 
@@ -256,7 +257,7 @@ def batch_pspec(cfg: Config) -> P:
     """Batch layout for (accum, micro_bs, seq): batch over data+fsdp,
     sequence over the SP axis."""
     seq_axis = "sequence" if cfg.parallel.sequence > 1 else None
-    return P(None, ("data", "fsdp"), seq_axis)
+    return P(None, BATCH_AXES, seq_axis)
 
 
 def make_global_batch(batch: dict, cfg: Config, mesh: Mesh) -> dict:
@@ -383,7 +384,7 @@ def make_sharded_train_step(
 
     def activation_constraint(input_ids):
         return jax.lax.with_sharding_constraint(
-            input_ids, NamedSharding(mesh, P(("data", "fsdp"),
+            input_ids, NamedSharding(mesh, P(BATCH_AXES,
                                              "sequence" if cfg.parallel.sequence > 1 else None))
         )
 

@@ -31,7 +31,7 @@ from dlti_tpu.ops.attention import resolve_flash
 # `training.state` -> `dlti_tpu.training` (which re-exports Trainer) does
 # not cycle back into the half-initialized parallel package.
 from dlti_tpu.parallel.mesh import build_mesh
-from dlti_tpu.parallel.sharding import make_sharded_train_step, shard_train_state
+from dlti_tpu.parallel.sharding import make_sharded_train_step
 from dlti_tpu.telemetry import (
     AnomalyWatchdog, FlightRecorder, GoodputLedger, Heartbeat,
     StepLogWriter, TimeSeriesSampler, build_slo_tracker, configure_tracer,
@@ -138,6 +138,23 @@ def _validate_pipeline_config(cfg: Config) -> None:
     # stride-th block keeping its activations (pipeline_forward); a
     # non-dividing stride warns in make_pipeline_train_step and falls
     # back to full remat.
+    # The Pallas flash kernel on a TPU composes with pipe only while no
+    # other axis is sharded: GSPMD cannot partition a Mosaic call over the
+    # auto axes of a stage, and the per-shard wrapper the flat path uses
+    # (per_shard_attention) would be a nested shard_map, untrainable on
+    # this jax. Interpreted (CPU) and reference attention partition like
+    # any jnp code. Under sequence > 1 the ring delegates to the reference.
+    auto_axes = [f"{ax}={getattr(par, ax)}"
+                 for ax in ("data", "fsdp", "tensor", "expert")
+                 if getattr(par, ax) > 1]
+    if auto_axes and par.sequence == 1 and resolve_flash(
+            cfg.model.attention_impl, seq_q=cfg.data.max_seq_len,
+            seq_kv=cfg.data.max_seq_len,
+            head_dim=cfg.model.resolved_head_dim)[0] == "pallas":
+        illegal.append(
+            f"{', '.join(auto_axes)} with the Pallas flash kernel on a TPU "
+            "(a Mosaic kernel cannot be partitioned inside a pipeline "
+            "stage; set model.attention_impl='reference')")
     import jax as _jax
 
     if _jax.process_count() > 1:
@@ -170,29 +187,6 @@ def _validate_pipeline_config(cfg: Config) -> None:
             "hosts, pipe stages process-local)")
     if cfg.train.grad_accum_steps < 1:
         raise ValueError("grad_accum_steps must be >= 1 under pipe")
-
-
-def _release_superseded(old_leaves: list, state) -> None:
-    """Free the single-device arrays a mesh placement made copies of.
-
-    Dropping the Python references is not enough: Flax keeps the init
-    scope (and through it every initialised array) reachable behind
-    ``nn.remat``, so the whole unsharded tree stayed on the default device
-    for the run, next to that device's own shard — found on four v5e chips
-    (``chip_smoke.py --chips 4``, PR 21), where it would not fit at full
-    depth. A buffer the placed state still uses (a same-device put may
-    hand the source back) is left alone.
-    """
-    in_use = set()
-    for leaf in jax.tree_util.tree_leaves(state):
-        if isinstance(leaf, jax.Array):
-            in_use.update(s.data.unsafe_buffer_pointer()
-                          for s in leaf.addressable_shards)
-    for leaf in old_leaves:
-        if (isinstance(leaf, jax.Array) and not leaf.is_deleted()
-                and len(leaf.sharding.device_set) == 1
-                and leaf.unsafe_buffer_pointer() not in in_use):
-            leaf.delete()
 
 
 class Trainer:
@@ -265,104 +259,154 @@ class Trainer:
 
     # ------------------------------------------------------------------
     def init_state(self, rng: Optional[jax.Array] = None) -> TrainState:
+        """A fresh state, where it will live.
+
+        One device: initialised in place, leaf by leaf. Under a mesh the
+        state is born sharded — a compiled initialiser whose outputs carry
+        their final shardings — so no device ever holds more than its own
+        share: on a four-chip host the whole 7B tree on chip 0 next to
+        chip 0's shard would not fit (``chip_smoke.py --chips 4`` reads the
+        per-device peak from the "device memory after init" line).
+        """
         rng = rng if rng is not None else jax.random.PRNGKey(self.cfg.train.seed)
-        state = create_train_state(
-            rng,
-            self.model,
-            self.tx,
-            (self.cfg.train.micro_batch_size, self.cfg.data.max_seq_len),
-            lora_enabled=self.cfg.lora.enabled,
-            fp16_initial_scale=(
-                float(2 ** self.cfg.train.fp16_initial_scale_power)
-                if self.cfg.train.fp16 else None),
-            fp16_hysteresis=self.cfg.train.fp16_hysteresis,
+        cfg = self.cfg
+        quantize = cfg.train.quantize_frozen_base
+        if quantize and quantize != "int8":
+            raise ValueError(
+                f"unknown quantize_frozen_base={quantize!r} (only 'int8')")
+        if quantize and not cfg.lora.enabled:
+            raise ValueError(
+                "quantize_frozen_base requires LoRA: it compresses the "
+                "frozen base params, and a full fine-tune has none")
+        from dlti_tpu.models.quantization import quantize_params_int8
+
+        def fresh(rng):
+            return create_train_state(
+                rng, self.model, self.tx,
+                (cfg.train.micro_batch_size, cfg.data.max_seq_len),
+                lora_enabled=cfg.lora.enabled,
+                fp16_initial_scale=(
+                    float(2 ** cfg.train.fp16_initial_scale_power)
+                    if cfg.train.fp16 else None),
+                fp16_hysteresis=cfg.train.fp16_hysteresis)
+
+        if self.mesh is None:
+            state = fresh(rng)
+            if self.base_params is not None:
+                from dlti_tpu.models import graft_base_params
+
+                state = state.replace(params=graft_base_params(
+                    state.params, self.base_params))
+            if quantize:
+                # donate=True retires each bf16 source as its int8 twin
+                # lands, so quantizing a 7B tree never holds both in HBM.
+                state = state.replace(
+                    params=quantize_params_int8(state.params, donate=True))
+            return state
+
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from dlti_tpu.parallel.sharding import (
+            launder_transfer_created, place_on_mesh, state_shardings,
         )
+
+        repl = NamedSharding(self.mesh, P())
+        piped = cfg.parallel.pipe > 1
+        offload = cfg.parallel.offload_params or cfg.parallel.offload_optimizer
+
+        def in_hbm(shardings):
+            # The initialisers compute in device memory; leaves that rest
+            # in pinned host memory move there at the end, shard by shard.
+            return jax.tree_util.tree_map(
+                lambda s: NamedSharding(self.mesh, s.spec), shardings)
+
+        # Flat layout first (the layout a base checkpoint grafts onto);
+        # int8 and the pipeline's stacked layout follow, sharded to sharded.
+        state = jax.jit(
+            fresh, in_shardings=repl,
+            out_shardings=in_hbm(state_shardings(
+                jax.eval_shape(fresh, rng), cfg, self.mesh)))(rng)
         if self.base_params is not None:
             from dlti_tpu.models import graft_base_params
 
-            state = state.replace(
-                params=graft_base_params(state.params, self.base_params))
-        if self.cfg.train.quantize_frozen_base:
-            if self.cfg.train.quantize_frozen_base != "int8":
-                raise ValueError(
-                    f"unknown quantize_frozen_base="
-                    f"{self.cfg.train.quantize_frozen_base!r} (only 'int8')")
-            if not self.cfg.lora.enabled:
-                raise ValueError(
-                    "quantize_frozen_base requires LoRA: it compresses the "
-                    "frozen base params, and a full fine-tune has none")
-            from dlti_tpu.models.quantization import quantize_params_int8
+            # place_on_mesh + launder: see sharding.place_on_mesh (multi-
+            # process placement without broadcasts, safe to donate).
+            state = state.replace(params=launder_transfer_created(
+                graft_base_params(
+                    state.params, self.base_params,
+                    place=lambda b, p: place_on_mesh(
+                        b.astype(p.dtype) if isinstance(b, jax.Array)
+                        else np.asarray(b, dtype=p.dtype), p.sharding))))
 
-            # donate=True retires each bf16 source as its int8 twin lands,
-            # so quantizing a 7B tree never holds both copies in HBM.
-            state = state.replace(
-                params=quantize_params_int8(state.params, donate=True))
-        # The state as initialised: whole, on the default device.
-        unplaced = jax.tree_util.tree_leaves(state)
-        if self.mesh is not None and self.cfg.parallel.pipe > 1:
-            # Pipeline layout: layers_{i} subtrees stack with a leading
-            # layer dim, sharded over 'pipe'; embed/norm/head + optimizer
-            # state replicate (they are a few percent of params/FLOPs).
-            from jax.sharding import NamedSharding, PartitionSpec as P
+        def finish(state):
+            if quantize:
+                state = state.replace(
+                    params=quantize_params_int8(state.params))
+            if piped:
+                from dlti_tpu.parallel.pipeline import to_pipeline_state
 
-            from dlti_tpu.parallel.pipeline import (
-                pipeline_param_shardings, to_pipeline_state,
-            )
+                state = to_pipeline_state(state, cfg.model.num_layers)
+            return state
 
-            state = to_pipeline_state(state, self.cfg.model.num_layers)
-            repl = NamedSharding(self.mesh, P())
-            # opt_state_shardings is shape-based, so it applies to the
-            # stacked trainable tree unchanged: ZeRO-1/2 x PP shard Adam
-            # moments over 'data', ZeRO-3 x PP over 'fsdp' (the update
-            # runs under GSPMD outside the pipeline's shard_map); stage
-            # NONE (or a size-1 axis) falls out replicated.
-            from dlti_tpu.parallel.sharding import opt_state_shardings
-
-            param_sh = pipeline_param_shardings(state.params, self.mesh)
-            if self.cfg.parallel.offload_params:
-                # PP x param host-offload (boundary-transfer mode, the
-                # flat path's fallback semantics): FROZEN base leaves
-                # rest in pinned host memory between steps; trainable
-                # (LoRA) leaves stay device-resident. _build_step moves
-                # the frozen tree HBM-ward per step and splices the
-                # still-valid host copies back after.
-                from dlti_tpu.parallel.sharding import _host_memory_kind
-                from dlti_tpu.training.state import (
-                    combine_params, partition_params,
-                )
-
-                kind = _host_memory_kind(self.mesh)
-                if kind is not None:
-                    trainable_sh, frozen_sh = partition_params(
-                        param_sh, self.cfg.lora.enabled)
-                    frozen_sh = jax.tree_util.tree_map(
-                        lambda s: NamedSharding(self.mesh, s.spec,
-                                                memory_kind=kind),
-                        frozen_sh)
-                    param_sh = combine_params(trainable_sh, frozen_sh)
-            from dlti_tpu.parallel.sharding import (
-                launder_transfer_created, place_on_mesh,
-            )
-
-            # place_on_mesh, not device_put: multi-process placement of a
-            # replicated-init state assembles local shards instead of
-            # broadcasting every value; the launder makes the products
-            # safe to donate (see sharding.place_on_mesh /
-            # launder_transfer_created).
-            state = launder_transfer_created(state.replace(
-                params=jax.tree_util.tree_map(
-                    place_on_mesh, state.params, param_sh),
-                opt_state=jax.tree_util.tree_map(
-                    place_on_mesh, state.opt_state,
-                    opt_state_shardings(state.opt_state, self.cfg,
-                                        self.mesh)),
-                step=place_on_mesh(state.step, repl),
-            ))
-        elif self.mesh is not None:
-            state = shard_train_state(state, self.cfg, self.mesh)
-        if self.mesh is not None:
-            _release_superseded(unplaced, state)
+        if quantize or piped:
+            state = jax.jit(
+                finish, donate_argnums=0,
+                out_shardings=in_hbm(self._resting_shardings(
+                    jax.eval_shape(finish, state))))(state)
+        if offload:
+            state = jax.device_put(state, self._resting_shardings(state))
         return state
+
+    def _resting_shardings(self, state) -> TrainState:
+        """Where each leaf of ``state`` (arrays or their shapes) rests
+        between steps under this trainer's mesh."""
+        from dlti_tpu.parallel.sharding import (
+            opt_state_shardings, state_shardings,
+        )
+
+        if self.cfg.parallel.pipe == 1:
+            return state_shardings(state, self.cfg, self.mesh)
+        # Pipeline layout: layers_{i} subtrees stack with a leading layer
+        # dim, sharded over 'pipe'; embed/norm/head + optimizer state
+        # replicate (they are a few percent of params/FLOPs).
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from dlti_tpu.parallel.pipeline import pipeline_param_shardings
+
+        repl = NamedSharding(self.mesh, P())
+        param_sh = pipeline_param_shardings(state.params, self.mesh)
+        if self.cfg.parallel.offload_params:
+            # PP x param host-offload (boundary-transfer mode, the flat
+            # path's fallback semantics): FROZEN base leaves rest in
+            # pinned host memory between steps; trainable (LoRA) leaves
+            # stay device-resident. _build_step moves the frozen tree
+            # HBM-ward per step and splices the still-valid host copies
+            # back after.
+            from dlti_tpu.parallel.sharding import _host_memory_kind
+            from dlti_tpu.training.state import (
+                combine_params, partition_params,
+            )
+
+            kind = _host_memory_kind(self.mesh)
+            if kind is not None:
+                trainable_sh, frozen_sh = partition_params(
+                    param_sh, self.cfg.lora.enabled)
+                frozen_sh = jax.tree_util.tree_map(
+                    lambda s: NamedSharding(self.mesh, s.spec,
+                                            memory_kind=kind),
+                    frozen_sh)
+                param_sh = combine_params(trainable_sh, frozen_sh)
+        # opt_state_shardings is shape-based, so it applies to the stacked
+        # trainable tree unchanged: ZeRO-1/2 x PP shard Adam moments over
+        # 'data', ZeRO-3 x PP over 'fsdp' (the update runs under GSPMD
+        # outside the pipeline's shard_map); stage NONE (or a size-1 axis)
+        # falls out replicated.
+        return state.replace(
+            step=repl, params=param_sh,
+            opt_state=opt_state_shardings(state.opt_state, self.cfg,
+                                          self.mesh),
+            scaler=(jax.tree_util.tree_map(lambda _: repl, state.scaler)
+                    if state.scaler is not None else None))
 
     def _build_step(self, state: TrainState):
         if self.mesh is not None and self.cfg.parallel.pipe > 1:
@@ -441,6 +485,13 @@ class Trainer:
         ledger = self._ledger = GoodputLedger(
             enabled=cfg.telemetry.goodput_ledger)
         state = state or self.init_state()
+        if is_main_process():
+            # Every local chip's in-use and peak bytes once the state is
+            # placed: a device that held more than its share on the way
+            # shows as a peak above the others' (empty on CPU: no stats).
+            jax.block_until_ready(state)
+            self.logger.info("device memory after init: %s", json.dumps(
+                memledger_mod.device_bytes_in_use(), sort_keys=True))
         resume = cfg.checkpoint.resume if resume is None else resume
 
         # Memory ledger (telemetry.memledger): owners registered as

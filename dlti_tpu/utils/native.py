@@ -40,8 +40,8 @@ def _up_to_date(sources: list) -> bool:
 
 
 def _build() -> bool:
-    """Compile ``native/*.cc`` into the library (same flags as
-    ``native/Makefile``). One builder at a time: concurrent first users
+    """Compile ``native/*.cc`` into the library — the one build recipe
+    there is. One builder at a time: concurrent first users
     (test subprocesses, fleet workers) queue on a file lock and find the
     result; the output lands under its final name by rename, so a reader
     never maps a half-written file."""

@@ -43,8 +43,7 @@ def parse_args():
     p.add_argument("--model-dir", default=None,
                    help="consolidated export dir (scripts/train.py --export-dir)")
     p.add_argument("--random-init", default=None, metavar="PRESET",
-                   help="serve a random-weight model preset (smoke/bench); "
-                        "'PRESET:layers=N' cuts its depth")
+                   help="serve a random-weight model preset (smoke/bench)")
     p.add_argument("--tokenizer", default="meta-llama/Llama-2-7b-hf")
     p.add_argument("--host", default="127.0.0.1")
     p.add_argument("--port", type=int, default=8000)
@@ -437,7 +436,8 @@ def main() -> None:
         model = LlamaForCausalLM(model_cfg, None)
         params = model.init(jax.random.PRNGKey(0),
                             jnp.zeros((1, 8), jnp.int32))["params"]
-        print(f"random-initialized preset {args.random_init}")
+        print(f"random-initialized preset {args.random_init} "
+              f"(layers={model_cfg.num_layers})")
 
     tiered = args.prefix_host_blocks > 0 or (
         args.prefix_disk_blocks > 0 and args.prefix_disk_dir)

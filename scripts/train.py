@@ -54,8 +54,7 @@ def parse_args():
     p.add_argument("--preset", default="baseline",
                    help="strategy: baseline | zero1 | zero2 | zero3")
     p.add_argument("--model", default="llama2_7b",
-                   help="model preset name (see dlti_tpu.config."
-                        "MODEL_PRESETS); 'NAME:layers=N' cuts its depth")
+                   help="model preset name (see dlti_tpu.config.MODEL_PRESETS)")
     p.add_argument("--dataset-path", "--dataset_path", default="./data/glaive_code_full",
                    help="HF save_to_disk dir, JSONL with a `text` field, or plain-text file")
     p.add_argument("--output-dir", "--output_dir", default="./checkpoints/run")

@@ -52,14 +52,17 @@ def test_bad_env_config_emits_json():
     assert "BENCH_QUANT" in _error_line(proc, lines)["error"]
 
 
-def test_unknown_model_spec_emits_json():
-    """There is one configuration and no ladder of fallbacks: a model
-    spec that does not resolve is a failure (exit 2), not a reason to try
-    another model."""
-    proc, lines = _run({"JAX_PLATFORMS": "cpu",
-                        "BENCH_MODEL": "mistral_7b:hidden=8"})
+def test_unknown_model_or_depth_emits_json():
+    """There is one configuration and no ladder of fallbacks: a model that
+    does not resolve, or a depth cut it cannot take, is a failure (exit 2),
+    not a reason to try another model."""
+    proc, lines = _run({"JAX_PLATFORMS": "cpu", "BENCH_MODEL": "mistral_9b"})
     assert proc.returncode == 2, proc.stderr[-500:]
-    assert "only cut is depth" in _error_line(proc, lines)["error"]
+    assert "unknown model" in _error_line(proc, lines)["error"]
+    proc, lines = _run({"JAX_PLATFORMS": "cpu", "BENCH_MODEL": "mistral_7b",
+                        "DLTI_MODEL_LAYERS": "33"})
+    assert proc.returncode == 2, proc.stderr[-500:]
+    assert "whole layers" in _error_line(proc, lines)["error"]
 
 
 def test_cpu_backend_is_a_failure_not_a_fallback():

@@ -12,6 +12,7 @@ import subprocess
 import sys
 import types
 
+import numpy as np
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -170,59 +171,134 @@ def test_kernel_selection_rule(monkeypatch):
         resolve_flash("flash", **aligned)
 
 
-def test_model_spec_cuts_depth_and_nothing_else():
+def test_depth_cut_is_an_env_var_and_cuts_nothing_else(monkeypatch):
     import dataclasses
 
     from dlti_tpu.config import MODEL_PRESETS, preset, resolve_model
 
     full = MODEL_PRESETS["mistral_7b"]
-    cut = resolve_model("mistral_7b:layers=8")
-    assert cut == dataclasses.replace(full, num_layers=8)
     assert resolve_model("mistral_7b") is full
-    assert preset("baseline", model="mistral_7b:layers=8").model == cut
-    for bad in ("mistral_7b:hidden_size=64", "mistral_7b:layers=0",
-                "mistral_7b:layers=33", "mistral_7b:layers=x", "nope"):
-        with pytest.raises(ValueError):
-            resolve_model(bad)
+    monkeypatch.setenv("DLTI_MODEL_LAYERS", "8")
+    cut = resolve_model("mistral_7b")
+    assert cut == dataclasses.replace(full, num_layers=8)
+    assert preset("baseline", model="mistral_7b").model == cut
+    for bad in ("0", "33", "x", "-1"):
+        monkeypatch.setenv("DLTI_MODEL_LAYERS", bad)
+        with pytest.raises(ValueError, match="whole layers"):
+            resolve_model("mistral_7b")
+    with pytest.raises(ValueError, match="unknown model"):
+        resolve_model("mistral_7b:layers=8")
 
 
-def test_sharded_trainer_frees_the_unsharded_tree(tmp_path):
-    """On four chips the whole initialised tree stayed on device 0 next to
-    device 0's shard (Flax keeps the init scope alive behind nn.remat):
-    after Trainer.init_state under a mesh no whole copy of a sharded leaf
-    may be left on one device."""
-    import jax
-
+def _fsdp_cfg(tmp_path, **train):
     from dlti_tpu.config import (
         CheckpointConfig, Config, DataConfig, MODEL_PRESETS, ParallelConfig,
         TrainConfig, ZeROStage,
     )
-    from dlti_tpu.training import Trainer
 
-    cfg = Config(
+    return Config(
         model=MODEL_PRESETS["llama_debug"],  # remat on, like the 7B presets
         parallel=ParallelConfig(zero_stage=ZeROStage.ZERO3, fsdp=4),
         data=DataConfig(max_seq_len=128, tokenizer="byte"),
         checkpoint=CheckpointConfig(save_strategy="no",
                                     output_dir=str(tmp_path)),
-        train=TrainConfig(micro_batch_size=4, grad_accum_steps=1))
-    state = Trainer(cfg).init_state()
-    placed, sharded_kinds = set(), set()
-    for leaf in jax.tree_util.tree_leaves(state):
-        for shard in leaf.addressable_shards:
-            placed.add(shard.data.unsafe_buffer_pointer())
+        train=TrainConfig(micro_batch_size=4, grad_accum_steps=1, **train))
+
+
+def test_sharded_trainer_initialises_sharded(tmp_path):
+    """On four chips the whole initialised tree sat on device 0 next to
+    device 0's shard (init-then-shard; Flax keeps an eager init scope alive
+    behind nn.remat). Under a mesh the state is now the output of one
+    compiled initialiser: every leaf has its resting sharding, equals what
+    a single device initialises from the same seed, and no whole
+    single-device copy of a sharded leaf exists afterwards."""
+    import dataclasses
+
+    import jax
+
+    from dlti_tpu.config import ParallelConfig
+    from dlti_tpu.parallel.sharding import state_shardings
+    from dlti_tpu.training import Trainer
+
+    cfg = _fsdp_cfg(tmp_path)
+    before = {id(a) for a in jax.live_arrays()}
+    trainer = Trainer(cfg)
+    state = trainer.init_state()
+    want = state_shardings(state, cfg, trainer.mesh)
+    sharded_kinds = set()
+    for leaf, sh in zip(jax.tree_util.tree_leaves(state),
+                        jax.tree_util.tree_leaves(want)):
+        assert leaf.sharding.is_equivalent_to(sh, leaf.ndim)
         if not leaf.is_fully_replicated:
             sharded_kinds.add((leaf.shape, leaf.dtype))
     assert sharded_kinds  # the embedding and the head, at least
-    # A whole single-device copy of a leaf that was sharded, not backing
-    # any placed shard: the initialised original, still alive. (A
-    # replicated leaf's source legitimately backs device 0's replica.)
-    leftovers = [
-        (arr.shape, arr.dtype) for arr in jax.live_arrays()
-        if len(arr.sharding.device_set) == 1
-        and (arr.shape, arr.dtype) in sharded_kinds
-        and arr.unsafe_buffer_pointer() not in placed]
+    leftovers = [(a.shape, a.dtype) for a in jax.live_arrays()
+                 if id(a) not in before
+                 and len(a.sharding.device_set) == 1
+                 and (a.shape, a.dtype) in sharded_kinds]
     assert not leftovers, leftovers
+    single = Trainer(cfg.replace(parallel=ParallelConfig())).init_state()
+    for got, ref in zip(jax.tree_util.tree_leaves(state.params),
+                        jax.tree_util.tree_leaves(single.params)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
+def test_sharded_trainer_leaves_the_callers_base_params_alone(tmp_path):
+    """Base weights the caller handed in are placed leaf by leaf onto their
+    shards and stay the caller's: still alive afterwards (device arrays
+    included), and a second init_state grafts them again. With an int8
+    frozen base the quantized leaves come out sharded too."""
+    import jax
+    import jax.numpy as jnp
+
+    from dlti_tpu.training import Trainer
+
+    cfg = _fsdp_cfg(tmp_path)
+    donor = Trainer(cfg).init_state(jax.random.PRNGKey(7)).params
+    embed = np.asarray(donor["model"]["embed_tokens"])
+    norm = jnp.asarray(np.asarray(donor["model"]["final_norm"]["scale"]) + 1)
+    base = {"model": {"embed_tokens": embed, "final_norm": {"scale": norm}}}
+    trainer = Trainer(cfg, base_params=base)
+    for _ in range(2):
+        params = trainer.init_state().params
+        got = params["model"]["embed_tokens"]
+        assert not got.is_fully_replicated
+        np.testing.assert_array_equal(np.asarray(got), embed)
+        np.testing.assert_array_equal(
+            np.asarray(params["model"]["final_norm"]["scale"]),
+            np.asarray(norm))
+    assert not norm.is_deleted()
+
+    q = Trainer(_fsdp_cfg(tmp_path, quantize_frozen_base="int8"),
+                base_params=base).init_state().params
+    node = q["model"]["embed_tokens"]
+    assert node["q"].dtype == jnp.int8 and not node["q"].is_fully_replicated
+
+
+def test_pipe_refuses_the_tpu_flash_kernel_with_sharded_axes(monkeypatch):
+    """Inside a pipeline stage the kernel cannot be wrapped per shard and
+    GSPMD cannot partition a Mosaic call: refused at construction on a TPU,
+    allowed where the kernel is interpreted or absent."""
+    import dataclasses
+
+    import jax
+
+    from dlti_tpu.config import MODEL_PRESETS, preset
+    from dlti_tpu.training.trainer import _validate_pipeline_config
+
+    cfg = preset("baseline", model=MODEL_PRESETS["llama_tiny"])
+    cfg = cfg.replace(
+        parallel=dataclasses.replace(cfg.parallel, pipe=2, data=2),
+        data=dataclasses.replace(cfg.data, max_seq_len=128),
+        model=dataclasses.replace(cfg.model, attention_impl="flash"))
+    _validate_pipeline_config(cfg)  # CPU: interpreted, partitions fine
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="Pallas flash kernel on a TPU"):
+        _validate_pipeline_config(cfg)
+    _validate_pipeline_config(cfg.replace(parallel=dataclasses.replace(
+        cfg.parallel, data=1)))  # pipe alone: nothing to partition
+    _validate_pipeline_config(cfg.replace(model=dataclasses.replace(
+        cfg.model, attention_impl="reference")))
 
 
 # ----------------------------------------------------------------------
@@ -272,16 +348,22 @@ def test_chip_smoke_cpu_rehearsal_runs_the_whole_path():
     completions -> SIGTERM, through chip_smoke.py's own checks, at
     llama_tiny size. Says platform: cpu, so it can never pass for a chip
     run."""
-    proc = _py(_RUN_SMOKE.format(path=SMOKE, argv=["--cpu-rehearsal"]),
+    argv = ["--cpu-rehearsal", "--layers", "1",  # the depth cut, too
+            "--legs", "train,serve,serve_full_depth"]
+    proc = _py(_RUN_SMOKE.format(path=SMOKE, argv=argv),
                {"JAX_PLATFORMS": "cpu"}, timeout=600, drop=("XLA_FLAGS",))
     assert proc.returncode == 0, (proc.stdout[-1500:], proc.stderr[-1500:])
     lines = [l for l in proc.stdout.splitlines() if l.strip()]
     assert lines[-1] == "PARENT_LOADED []"
-    out = json.loads(lines[-2])
+    # The result is the script's last line: these keys and no others.
+    device = {"platform": "cpu", "kind": "cpu", "count": 1}
+    assert json.loads(lines[-2]) == {"ok": True, "device": device}
+    out = json.loads(lines[-3])  # the summary, one line before it
     assert out["ok"] is True
-    assert out["device"] == {"platform": "cpu", "kind": "cpu", "count": 1}
-    assert out["model"] == "llama_tiny" and out["depth"] == 2
-    assert set(out["legs"]) == {"train", "serve"}
+    assert out["device"] == device
+    assert out["model"] == "llama_tiny" and out["depth"] == 1
+    assert set(out["legs"]) == {"train", "serve", "serve_full_depth"}
+    assert out["legs"]["serve_full_depth"]["depth"] == 2  # as published
     assert all(leg["ok"] for leg in out["legs"].values())
     assert out["legs"]["train"]["committed_checkpoints"]
     assert out["legs"]["serve"]["decode_steps"] > 0

@@ -1,5 +1,7 @@
 """Pallas flash attention vs XLA reference (interpret mode on CPU)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -204,9 +206,9 @@ def test_flash_windowed_grid_with_segments_and_gqa(rng):
                                   dict(fsdp=2, tensor=2, expert=2)])
 def test_flash_under_a_mesh_runs_per_shard_and_matches_reference(axes):
     """A Mosaic kernel cannot be partitioned by GSPMD (on the chip the
-    sharded train step failed to lower), so under a mesh the dispatcher
-    runs it per shard in a shard_map: batch rows over data/fsdp, heads over
-    tensor. Loss and every gradient — including that of a REPLICATED weight
+    sharded train step failed to lower), so under a mesh the model runs
+    it per shard (``per_shard_attention``): batch rows over data/fsdp, heads
+    over tensor. Loss and every gradient — including that of a REPLICATED weight
     upstream of the kernel, the case a wrong shard_map transpose corrupts —
     must equal the unsharded reference."""
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -214,6 +216,7 @@ def test_flash_under_a_mesh_runs_per_shard_and_matches_reference(axes):
     from dlti_tpu.config import ParallelConfig
     from dlti_tpu.ops.attention import multi_head_attention
     from dlti_tpu.parallel.mesh import build_mesh
+    from dlti_tpu.parallel.ring_attention import per_shard_attention
 
     mesh = build_mesh(ParallelConfig(**axes))
     b, s, h, kv, d = 4, 256, 8, 4, 128
@@ -226,9 +229,10 @@ def test_flash_under_a_mesh_runs_per_shard_and_matches_reference(axes):
     def loss(attn):
         return lambda q, k, v, w: jnp.sum(jnp.tanh(attn(q @ w, k, v)) ** 2)
 
-    sharded = loss(lambda q, k, v: multi_head_attention(
-        q, k, v, causal=True, impl="flash", block_q=128, block_kv=128,
-        window=96, mesh=mesh))
+    sharded = loss(lambda q, k, v: per_shard_attention(
+        functools.partial(multi_head_attention, causal=True, impl="flash",
+                          block_q=128, block_kv=128, window=96),
+        q, k, v, mesh))
     plain = loss(lambda q, k, v: reference_attention(
         q, k, v, causal=True, window=96))
     rows = NamedSharding(mesh, P(("data", "fsdp"), None, None, None))
@@ -241,3 +245,21 @@ def test_flash_under_a_mesh_runs_per_shard_and_matches_reference(axes):
     for got, want in zip(got_g, want_g):
         np.testing.assert_allclose(got, want, rtol=1e-3,
                                    atol=1e-4 * float(jnp.max(jnp.abs(want))))
+
+
+def test_gqa_tile_is_a_power_of_two_share_of_block_q(monkeypatch):
+    """block_q counts rows across the GQA group; each head's share is
+    floored to a power of two so it divides any 128-aligned sequence
+    (group 7 -> 64 positions, never 72 with a padded last tile)."""
+    import importlib
+
+    fa = importlib.import_module("dlti_tpu.ops.pallas.flash_attention")
+    seen = []
+    monkeypatch.setattr(
+        fa, "_flash_attention_core",
+        lambda q, k, v, seg, causal, block_q, *rest: seen.append(block_q))
+    for heads, kv_heads in ((8, 8), (32, 8), (28, 4), (64, 1)):
+        fa.flash_attention(jnp.zeros((1, 128, heads, 128)),
+                           jnp.zeros((1, 128, kv_heads, 128)),
+                           jnp.zeros((1, 128, kv_heads, 128)), block_q=512)
+    assert seen == [512, 128, 64, 8]
