@@ -189,7 +189,7 @@ def paged_decode_attention(
     kernel = functools.partial(_decode_kernel, scale=scale,
                                block_size=block_size, window=window or 0,
                                quantized=quantized)
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -204,6 +204,10 @@ def paged_decode_attention(
         ),
         out_shape=out_struct((batch, kv_heads, hpg, head_dim), q.dtype, q),
         interpret=interpret,
+        # The name under which a device trace shows this kernel
+        # (benchmark/lib/span_rules.json finds it by it); the scope round
+        # the call keeps the model's own scopes out of that name.
+        name="dlti_paged_attention_decode",
         cost_estimate=pl.CostEstimate(
             flops=int(2 * 2 * batch * num_heads * max_blocks * block_size
                       * head_dim),
@@ -212,6 +216,8 @@ def paged_decode_attention(
                 * k_pool.dtype.itemsize + 2 * q.size * q.dtype.itemsize),
             transcendentals=batch * num_heads * max_blocks * block_size,
         ),
-    )(seq_lens, bt, *operands)
+    )
+    with jax.named_scope("dlti_paged_attention_decode"):
+        out = call(seq_lens, bt, *operands)
 
     return out.reshape(batch, 1, num_heads, head_dim)

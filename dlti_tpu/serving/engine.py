@@ -1017,6 +1017,12 @@ class InferenceEngine:
                       # cohort retirement drains slots faster than
                       # admission refills them).
                       "decode_slot_steps": 0,
+                      # Tokens of context the decode steps attended over:
+                      # each round adds the sum of its active slots'
+                      # seq_len times its steps, so decode_context_tokens /
+                      # decode_steps is the mean context one step reads
+                      # (what the paged-attention kernel's bytes follow).
+                      "decode_context_tokens": 0,
                       "prefix_cached_tokens": 0,
                       # Tokens whose KV came back from a LOWER tier (host
                       # or disk) via a restore scatter instead of either
@@ -1041,12 +1047,9 @@ class InferenceEngine:
                       "numeric_faults": 0,
                       # Headroom-aware memory control (telemetry.
                       # memledger): admission passes skipped for want of
-                      # HBM headroom, and decode windows shrunk to one
-                      # step when KV growth found the pool exhausted —
-                      # both defer work instead of faulting. Present (at
+                      # HBM headroom — deferred, not faulted. Present (at
                       # 0) so the /metrics schema is stable.
-                      "hbm_deferred_admissions": 0,
-                      "hbm_growth_deferrals": 0}
+                      "hbm_deferred_admissions": 0}
         # Token-storm guard run length (consecutive all-slots-identical
         # decode steps).
         self._storm_run = 0
@@ -1756,6 +1759,51 @@ class InferenceEngine:
         real logit in one batched sample call; non-final chunks (chunked
         prefill) write KV only.
         """
+        tr = self._tracer
+        # The arguments cost a pass over the rows: only for a tracer that
+        # keeps them.
+        args = {"rows": len(chunks), "bucket": bucket,
+                "prompt_tokens": sum(len(c[1]) for c in chunks)} \
+            if tr.enabled else {}
+        with tr.span("engine/prefill_group", cat="engine", **args):
+            with tr.span("engine/prefill_launch", cat="engine"):
+                sampled = self._prefill_launch(bucket, chunks)
+            if sampled is None:
+                return  # mid-prompt chunks: KV writes only, nothing to sample
+            with tr.span("engine/prefill_wait", cat="engine"):
+                toks = np.asarray(jax.device_get(sampled[0]))
+                lps = np.asarray(jax.device_get(sampled[1]))
+            self._prefill_emit(chunks, toks, lps)
+
+    def _prefill_emit(self, chunks: List[tuple], toks: np.ndarray,
+                      lps: np.ndarray) -> None:
+        """Guard and emit the first tokens of a prefill batch's final
+        chunks (``toks``, ``lps``: one per row, on the host)."""
+        if self.cfg.guard_nonfinite:
+            bad = [slot.slot_id
+                   for r, (slot, *_rest, is_last) in enumerate(chunks)
+                   if is_last and not np.isfinite(lps[r])]
+            if bad:
+                # First-token guard: a numerically-dead model's prefill
+                # sample must not stream either (failover's resubmit
+                # preserves generated-so-far tokens).
+                self.stats["numeric_faults"] += 1
+                raise NumericFault(
+                    f"nonfinite prefill output on slot(s) {bad}: the "
+                    f"model is producing NaN/inf logits")
+        for r, (slot, tokens, start, is_last) in enumerate(chunks):
+            if is_last:
+                self._append_token(slot, int(toks[r]), float(lps[r]))
+                # Prefill completion: the first sampled token bumped the
+                # slot's gen count, and a chunked-mode slot's block-table
+                # row sheds its trash-block masking — either way the row
+                # must re-upload before the slot joins the decode batch.
+                self._mark_state_dirty(slot.slot_id)
+
+    def _prefill_launch(self, bucket: int, chunks: List[tuple]):
+        """Host arrays, the prefill program, key fold and sampling calls of
+        one batch, none of them waited for: the sampled ``(tokens,
+        logprobs)`` still on the device, or None when no chunk is final."""
         ec = self.cfg
         B = 1
         while B < len(chunks):
@@ -1810,36 +1858,14 @@ class InferenceEngine:
             jnp.asarray(bt), jnp.asarray(last_idx), *lora_args,
         )
         if not any(is_last for *_, is_last in chunks):
-            return  # mid-prompt chunks: KV writes only, nothing to sample
+            return None
         # Same per-slot key + count stream the decode path uses, folded in
         # one async dispatch (no host round trip per row).
         keys = self._fold_keys(jnp.asarray(slot_keys), jnp.asarray(counts))
-        toks, lps = self._sample_fn(
+        return self._sample_fn(
             last_logits, keys, jnp.asarray(temps),
             jnp.asarray(top_k), jnp.asarray(top_p),
         )
-        toks = np.asarray(jax.device_get(toks))
-        lps = np.asarray(jax.device_get(lps))
-        if self.cfg.guard_nonfinite:
-            bad = [slot.slot_id
-                   for r, (slot, *_rest, is_last) in enumerate(chunks)
-                   if is_last and not np.isfinite(lps[r])]
-            if bad:
-                # First-token guard: a numerically-dead model's prefill
-                # sample must not stream either (failover's resubmit
-                # preserves generated-so-far tokens).
-                self.stats["numeric_faults"] += 1
-                raise NumericFault(
-                    f"nonfinite prefill output on slot(s) {bad}: the "
-                    f"model is producing NaN/inf logits")
-        for r, (slot, tokens, start, is_last) in enumerate(chunks):
-            if is_last:
-                self._append_token(slot, int(toks[r]), float(lps[r]))
-                # Prefill completion: the first sampled token bumped the
-                # slot's gen count, and a chunked-mode slot's block-table
-                # row sheds its trash-block masking — either way the row
-                # must re-upload before the slot joins the decode batch.
-                self._mark_state_dirty(slot.slot_id)
 
     def _mark_state_dirty(self, slot_id: int) -> None:
         """A scheduling event changed ``slot_id``'s per-slot state mirrors
@@ -1878,6 +1904,21 @@ class InferenceEngine:
         arrays are still being computed, for :meth:`_decode_complete`.
         All host mirrors are snapshotted here (jnp.asarray copies at call
         time), so admission may mutate them while the call is in flight."""
+        tr = self._tracer
+        with tr.span("engine/decode_prep", cat="engine"):
+            plan = self._decode_prepare()
+        if plan is None:
+            return None
+        with tr.span("engine/decode_launch", cat="engine"):
+            launch = self._spec_launch if plan[0] == "spec" \
+                else self._decode_launch
+            return launch(*plan[1:])
+
+    def _decode_prepare(self):
+        """Everything of a decode round before its program call: spec
+        gate, block growth (and preemption), batch assembly and the upload
+        of the per-slot state. ``(kind, *arguments of the launch)``, or
+        None when preemption left nothing to decode."""
         ec = self.cfg
         # Multi-step windows are budget-clamped per round (_window_steps):
         # max_model_len safety lives in its min(...) term, so there is no
@@ -1954,7 +1995,6 @@ class InferenceEngine:
                 # read). One block per active slot is guaranteed by the
                 # admission-time max_blocks_per_seq check, so win=1 can
                 # only fail on genuine exhaustion.
-                self.stats["hbm_growth_deferrals"] += 1
                 use_spec = False
                 self._spec_last_k = 0
                 k_steps = 1
@@ -1969,14 +2009,17 @@ class InferenceEngine:
         if not active:
             return None
         if use_spec:
-            return self._spec_dispatch(active, spec_parts, spec_k)
+            return self._spec_prepare(active, spec_parts, spec_k)
 
         t_prep = time.perf_counter()
         ids = np.zeros((ec.max_seqs, 1), np.int32)
         pos = np.zeros((ec.max_seqs, 1), np.int32)  # inactive -> trash block
+        context = 0
         for s in active:
             ids[s.slot_id, 0] = s.last_token
             pos[s.slot_id, 0] = s.seq_len  # position of the new token
+            context += s.seq_len
+        self.stats["decode_context_tokens"] += context * k_steps
         if self._state_cache is not None:
             # Device-resident per-slot state: only rows dirtied since the
             # last dispatch are shipped; a clean step uploads nothing and
@@ -2003,6 +2046,11 @@ class InferenceEngine:
         # Host prep cost of this dispatch (batch assembly + state sync) —
         # the term dirty tracking is meant to hold flat as max_seqs grows.
         self.telemetry.host_prep.observe(time.perf_counter() - t_prep)
+        return ("plain", active, k_steps, args)
+
+    def _decode_launch(self, active: List[_Slot], k_steps: int, args):
+        """The compiled decode call (not waited for) and the resident
+        counts' advance."""
         if k_steps > 1:
             fn = self._multi_decode_fns.get(k_steps)
             if fn is None:
@@ -2023,11 +2071,23 @@ class InferenceEngine:
 
     def _decode_complete(self, pending) -> List[Request]:
         """Sync a dispatched decode round's results and walk emissions."""
-        if pending[0] == "spec":
-            return self._spec_complete(pending)
-        _, active, k_steps, tokens, logprobs = pending
-        tokens = np.asarray(jax.device_get(tokens))      # (S, k_steps)
-        logprobs = np.asarray(jax.device_get(logprobs))
+        tr = self._tracer
+        kind, *device = pending
+        # The wait ends when the round's results are on the host; until
+        # then the chip is at work. The emission walk after it is host
+        # time during which nothing is in flight.
+        with tr.span("engine/decode_wait", cat="engine"):
+            host = [np.asarray(jax.device_get(x)) if isinstance(x, jax.Array)
+                    else x for x in device]
+        with tr.span("engine/decode_emit", cat="engine"):
+            walk = self._spec_emit if kind == "spec" else self._decode_emit
+            return walk(*host)
+
+    def _decode_emit(self, active: List[_Slot], k_steps: int,
+                     tokens: np.ndarray, logprobs: np.ndarray,
+                     ) -> List[Request]:
+        """Numeric guards and the per-slot emission walk of a plain round
+        (``tokens``, ``logprobs``: (S, k_steps), on the host)."""
         self.stats["decode_steps"] += k_steps
 
         # Numeric guard — the WHOLE round is validated before any token
@@ -2133,9 +2193,9 @@ class InferenceEngine:
         self._spec_slot_pause[sid] = 0
         self._spec_slot_ewma[sid] = float(self.cfg.num_draft_tokens)
 
-    def _spec_dispatch(self, active: List[_Slot], parts: List[_Slot],
-                       k: int):
-        """Dispatch the fused propose→verify→accept program (no sync).
+    def _spec_prepare(self, active: List[_Slot], parts: List[_Slot],
+                      k: int):
+        """Arguments of the fused propose→verify→accept program.
 
         ``parts`` are the greedy slots allowed to propose this round
         (per-slot gate output); everyone else — sampling slots and greedy
@@ -2152,9 +2212,12 @@ class InferenceEngine:
         t_in = np.zeros((ec.max_seqs,), np.int32)
         seq_len = np.zeros((ec.max_seqs,), np.int32)
         spec_mask = np.zeros((ec.max_seqs,), np.bool_)
+        context = 0
         for s in active:
             t_in[s.slot_id] = s.last_token
             seq_len[s.slot_id] = s.seq_len
+            context += s.seq_len
+        self.stats["decode_context_tokens"] += context * R
         for s in parts:
             spec_mask[s.slot_id] = True
         # Multi-query attention takes the gather path (the Pallas paged
@@ -2171,29 +2234,32 @@ class InferenceEngine:
         if self.adapter_pool is not None:
             lora_args = (jnp.asarray(self._adapter_ids),
                          self.adapter_pool.tree)
-        self.cache, toks, lps, emit, prop, acc = self._spec_fn_for(k)(
-            self.params, self.cache, jnp.asarray(self._spec_hist), jnp.asarray(t_in),
+        args = (
+            jnp.asarray(self._spec_hist), jnp.asarray(t_in),
             jnp.asarray(seq_len), jnp.asarray(spec_mask),
             jnp.asarray(self._decode_block_tables()[:, :width]),
             jnp.asarray(self._slot_keys), jnp.asarray(self._gen_counts),
             jnp.asarray(self._temperature), jnp.asarray(self._top_k),
             jnp.asarray(self._top_p), *lora_args,
         )
+        return ("spec", active, spec_mask, k, args)
+
+    def _spec_launch(self, active: List[_Slot], spec_mask, k: int, args):
+        """Dispatch the spec program (no sync)."""
+        self.cache, toks, lps, emit, prop, acc = self._spec_fn_for(k)(
+            self.params, self.cache, *args)
         return ("spec", active, spec_mask, toks, lps, emit, prop, acc)
 
-    def _spec_complete(self, pending) -> List[Request]:
-        """Sync a dispatched spec round and walk its emissions. Per slot
+    def _spec_emit(self, active: List[_Slot], spec_mask: np.ndarray,
+                   toks: np.ndarray, lps: np.ndarray, emit: np.ndarray,
+                   prop: np.ndarray, acc: np.ndarray) -> List[Request]:
+        """Walk a spec round's emissions (``toks``, ``lps``: (S, R, k+1);
+        ``emit``, ``prop``, ``acc``: (S, R); all on the host). Per slot
         per round the device reports how many tokens were emitted (greedy:
         accepted prefix + bonus; sampling: exactly one); the host consumes
         them in order, stopping a slot at EOS/limit and discarding the
         rest of its window (same contract as multi-step decode)."""
-        _, active, spec_mask, toks, lps, emit, prop, acc = pending
         R = self._spec_rounds
-        toks = np.asarray(jax.device_get(toks))   # (S, R, k+1)
-        lps = np.asarray(jax.device_get(lps))
-        emit = np.asarray(jax.device_get(emit))   # (S, R)
-        prop = np.asarray(jax.device_get(prop))
-        acc = np.asarray(jax.device_get(acc))
         self.stats["decode_steps"] += R
 
         # Numeric guard over every EMITTED token (rejected draft

@@ -85,6 +85,13 @@ def build_registry(async_engine: "AsyncEngine") -> MetricsRegistry:
 
     registry.register(alerts_total)
     registry.register(dumps_total)
+    # Start-up phases and the compile / cache-fetch counters
+    # (telemetry.startup): written by the entry point and by JAX's
+    # compile listener, never by the step loop.
+    from dlti_tpu.telemetry.startup import STARTUP_METRICS
+
+    for metric in STARTUP_METRICS:
+        registry.register(metric)
     # Distributed-tracing federation counters (module-level, like the
     # watchdog/flight pair): spans adopted from fleet workers, spans
     # that arrived without any request/trace parentage, and the per-
@@ -295,6 +302,7 @@ class AsyncEngine:
     def __init__(self, engine: InferenceEngine):
         self.engine = engine
         self.logger = get_logger()
+        self._tracer = get_tracer()
         self._lock = threading.Lock()
         self._work = threading.Condition(self._lock)
         self._queues: Dict[str, queue.Queue] = {}
@@ -351,23 +359,34 @@ class AsyncEngine:
         self._thread.join(timeout=10)
 
     def _run(self) -> None:
+        # The spans below, with the engine's own, cover the stepper's whole
+        # loop: whatever the chip waits for between two programs is inside
+        # one of them (benchmark/lib/attribute_idle.py reads them). Taking
+        # ``_work`` is a span of its own: the handler threads' submits take
+        # it too.
+        tr = self._tracer
         while True:
-            with self._work:
+            with tr.span("server/lock_wait", cat="server"):
+                self._work.acquire()
+            try:
                 while not self._stop and not self.engine.has_work:
-                    if getattr(self.engine, "lifecycle_pending", False):
-                        # A quarantined replica awaits its probe or a
-                        # rolling reload is in flight: poll instead of
-                        # parking, so the fleet's lifecycle tick runs
-                        # even on an idle server (a no-work step() is
-                        # just the tick). Engines without a lifecycle
-                        # keep the legacy untimed park.
-                        self._work.wait(timeout=0.05)
-                        break
-                    self._work.wait()
+                    with tr.span("server/wait_work", cat="server"):
+                        if getattr(self.engine, "lifecycle_pending", False):
+                            # A quarantined replica awaits its probe or a
+                            # rolling reload is in flight: poll instead of
+                            # parking, so the fleet's lifecycle tick runs
+                            # even on an idle server (a no-work step() is
+                            # just the tick). Engines without a lifecycle
+                            # keep the legacy untimed park.
+                            self._work.wait(timeout=0.05)
+                            break
+                        self._work.wait()
                 if self._stop:
                     for q in self._queues.values():
                         q.put(("error", "server shutting down"))
                     return
+            finally:
+                self._work.release()
             # Step OUTSIDE the lock: one step is a compiled-program call
             # (>1 s at large steps_per_sync), and holding the lock across
             # it serializes every HTTP submit against the device, which
@@ -377,7 +396,8 @@ class AsyncEngine:
             # own stats key; admission consumes the deque at one point
             # inside step(), so a racing submit lands this step or next.
             try:
-                self.engine.step()
+                with tr.span("server/step", cat="server"):
+                    self.engine.step()
             except Exception as e:  # surface engine faults to the waiters
                 self.logger.exception("engine step failed")
                 rec = get_recorder()
@@ -416,8 +436,13 @@ class AsyncEngine:
                     if self._stop:
                         return
                 continue
-            with self._work:
-                self._drain_events()
+            with tr.span("server/lock_wait", cat="server"):
+                self._work.acquire()
+            try:
+                with tr.span("server/drain_events", cat="server"):
+                    self._drain_events()
+            finally:
+                self._work.release()
 
     def _drain_events(self) -> None:
         """Push tokens generated since the last step to per-request queues."""
@@ -908,15 +933,16 @@ class _Handler(BaseHTTPRequestHandler):
             # raise (or corrupt the first capture), so refuse loudly.
             return self._error(409, "a profile capture is already running")
         try:
-            import jax
-
             out_dir = os.path.join(trace_dir, "serve_profile")
             t0 = time.monotonic()
-            jax.profiler.start_trace(out_dir)
+            # Through the tracer, so that the capture carries the
+            # program's spans beside the device's operations.
+            tracer = get_tracer()
+            tracer.start_capture(out_dir)
             try:
                 time.sleep(seconds)
             finally:
-                jax.profiler.stop_trace()
+                tracer.stop_capture()
             self._json(200, {"status": "ok", "trace_dir": out_dir,
                              "seconds": round(time.monotonic() - t0, 3)})
         except Exception as e:  # profiler backends vary; fail this request
@@ -1481,9 +1507,20 @@ def serve(engine: InferenceEngine, tokenizer: Tokenizer,
     cfg = cfg or ServerConfig()
     httpd, async_engine = make_server(engine, tokenizer, cfg,
                                       deploy=deploy)
+    from dlti_tpu.telemetry import startup
+
+    startup.mark_startup("ready")  # the socket is bound; serve_forever next
     gateway = httpd.gateway
     get_logger().info("serving on http://%s:%d (model=%s)",
                       cfg.host, cfg.port, cfg.model_name)
+    get_logger().info(
+        "start-up, seconds since process start: %s; %d programs compiled "
+        "in %.1f s, %d fetched from the compile cache in %.1f s",
+        ", ".join(f"{p} {g.value:.1f}"
+                  for p, g in startup.startup_gauges.items()),
+        startup.compilations_total.value, startup.compile_seconds_total.value,
+        startup.compile_cache_hits_total.value,
+        startup.compile_cache_fetch_seconds_total.value)
     # SIGTERM (k8s eviction, orchestrator `kill`) gets the same clean
     # path as Ctrl-C: unblock serve_forever so the finally drains the
     # stepper and closes the socket instead of dying mid-decode. With a

@@ -15,6 +15,14 @@ Design constraints:
   clock read, no lock. This is what makes it safe to leave instrumentation
   in the engine's per-step path unconditionally (guarded by the overhead
   smoke in ``tests/test_telemetry.py``).
+* **On the profiler's clock when enabled.** An enabled ``span()`` also
+  enters a ``jax.profiler.TraceAnnotation`` of the same name and arguments
+  on the thread that runs it, so a profiler capture holds the program's
+  phases in its ``/host:CPU`` plane, on one clock with the device's
+  operations (outside a capture an annotation is a flag test). Each ring
+  span also keeps the CPU time its thread used (``cpu_us``): wall less CPU
+  is time the thread waited — for the device, a lock, the interpreter.
+  ``jax`` is imported by the first enabled span, never by this module.
 * **Bounded memory.** Events land in a ring buffer (``deque(maxlen=...)``);
   a long-lived server keeps the most recent ``capacity`` events and never
   grows. Export is a snapshot of the ring.
@@ -49,7 +57,8 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_cpu0",
+                 "_annotation")
 
     def __init__(self, tracer: "SpanTracer", name: str, cat: str, args: dict):
         self._tracer = tracer
@@ -58,13 +67,25 @@ class _Span:
         self._args = args
 
     def __enter__(self):
+        tracer = self._tracer
+        if tracer._annotate is None:
+            import jax.profiler
+
+            tracer._annotate = jax.profiler.TraceAnnotation
+        # Annotation first, so the ring's span lies inside it.
+        self._annotation = tracer._annotate(self._name, **self._args)
+        self._annotation.__enter__()
+        self._cpu0 = time.thread_time_ns()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        end = time.monotonic()
+        cpu_us = (time.thread_time_ns() - self._cpu0) / 1e3
         self._tracer._complete_event(
-            self._name, self._t0, time.monotonic(), self._cat,
-            threading.get_ident(), self._args)
+            self._name, self._t0, end, self._cat,
+            threading.get_ident(), {**self._args, "cpu_us": cpu_us})
+        self._annotation.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -93,6 +114,39 @@ class SpanTracer:
         # merged multi-process timeline renders one named row per source
         # instead of collapsing everything into anonymous pids.
         self.process_label: Optional[str] = None
+        # ``jax.profiler.TraceAnnotation``, once the first enabled span
+        # has imported it.
+        self._annotate = None
+        self._capture_enabled_ring = False
+
+    # -- profiler capture -----------------------------------------------
+    def start_capture(self, log_dir: str) -> None:
+        """Start a ``jax.profiler`` capture that holds this tracer's spans
+        beside the device's operations: the ring is enabled for the capture
+        if nothing else enabled it, and the profiler's own Python function
+        tracer is switched off — the spans name the host's phases, and a
+        function-level event for every generator step of every thread is
+        host time added inside the very gaps being measured. The profiler
+        is process-global: the caller sees to it that one capture runs at
+        a time. ``profiler/start`` and ``profiler/stop`` instants cut the
+        ring to the captured window."""
+        import jax.profiler
+
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(log_dir, profiler_options=options)
+        self._capture_enabled_ring = not self.enabled
+        self.enabled = True
+        self.instant("profiler/start", cat="profiler")
+
+    def stop_capture(self) -> None:
+        """End the capture ``start_capture`` began."""
+        import jax.profiler
+
+        self.instant("profiler/stop", cat="profiler")
+        if self._capture_enabled_ring:
+            self.enabled = False
+        jax.profiler.stop_trace()
 
     # -- recording ------------------------------------------------------
     def span(self, name: str, cat: str = "host", **args):

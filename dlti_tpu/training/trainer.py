@@ -655,6 +655,7 @@ class Trainer:
             _elastic.beat(start_step)  # liveness before the first step
 
         def _train_scalars():
+            from dlti_tpu.telemetry import startup as _startup
             from dlti_tpu.checkpoint.store import (
                 corrupt_skipped, last_verified_step, save_retries,
             )
@@ -664,6 +665,10 @@ class Trainer:
             d["ckpt_corrupt_skipped"] = corrupt_skipped.value
             d["ckpt_last_verified_step"] = last_verified_step.value
             d["trace_dropped_events"] = tracer.dropped_events
+            # Programs compiled / fetched from the cache so far (counted
+            # by the listener scripts/train.py installs): a count that
+            # grows mid-run is a recompile.
+            d.update(_startup.compile_scalars())
             # Sentinel/SDC counters (set once the sentinel initializes a
             # few lines below the sampler start): the watchdog's
             # loss_spike / nonfinite_step / sdc_mismatch rules watch
@@ -1465,13 +1470,15 @@ class Trainer:
                     if cfg.train.profile_dir and is_main_process():
                         if (profile_state == "pending"
                                 and global_step >= cfg.train.profile_start_step):
-                            jax.profiler.start_trace(cfg.train.profile_dir)
+                            # Through the tracer: the capture then
+                            # carries the train/* spans, trace dir or not.
+                            tracer.start_capture(cfg.train.profile_dir)
                             profile_state = "active"
                             profile_stop_at = (global_step
                                                + cfg.train.profile_num_steps)
                         elif (profile_state == "active"
                               and global_step >= profile_stop_at):
-                            jax.profiler.stop_trace()
+                            tracer.stop_capture()
                             profile_state = "done"
                             self.logger.info("profiler trace -> %s",
                                              cfg.train.profile_dir)
@@ -1634,7 +1641,7 @@ class Trainer:
                                prev_handler if prev_handler is not None
                                else _signal.SIG_DFL)
             if profile_state == "active":  # run ended inside the trace window
-                jax.profiler.stop_trace()
+                tracer.stop_capture()
             if cfg.checkpoint.save_strategy != "no":
                 # Settle in-flight async saves on EVERY exit path —
                 # exception and normal return alike — so a training crash

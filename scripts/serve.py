@@ -32,9 +32,14 @@ if os.path.isdir(os.path.join(_repo_root, "dlti_tpu")):
     sys.path.insert(0, _repo_root)
 del _repo_root
 
+from dlti_tpu.telemetry.startup import install_compile_listener, mark_startup
 from dlti_tpu.utils.platform import enable_compilation_cache
 
 enable_compilation_cache()
+# /metrics then says what compiling and fetching programs cost
+# (dlti_compile*), and how long after process start each start-up phase
+# was passed (dlti_startup_<phase>_seconds).
+install_compile_listener()
 
 
 def parse_args():
@@ -409,6 +414,7 @@ def main() -> None:
     )
 
     tok = get_tokenizer(args.tokenizer)
+    mark_startup("imports")
 
     tracer = None
     if args.trace_dir:
@@ -439,6 +445,7 @@ def main() -> None:
         print(f"random-initialized preset {args.random_init} "
               f"(layers={model_cfg.num_layers})")
 
+    mark_startup("weights")
     tiered = args.prefix_host_blocks > 0 or (
         args.prefix_disk_blocks > 0 and args.prefix_disk_dir)
     ec = EngineConfig(
@@ -573,6 +580,7 @@ def main() -> None:
             mesh = build_mesh(ParallelConfig(tensor=args.tensor))
         engine = InferenceEngine(model_cfg, params, ec, lora_cfg, mesh=mesh,
                                  donate_params=True)
+    mark_startup("kv_pool")
     # The engine owns (a possibly quantized copy of) the weights now; this
     # frame's reference would otherwise pin the original tree in HBM for
     # the server's lifetime — 13.5 GB of dead bf16 under --quantization.

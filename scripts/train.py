@@ -43,9 +43,13 @@ if os.path.isdir(os.path.join(_repo_root, "dlti_tpu")):
     sys.path.insert(0, _repo_root)
 del _repo_root
 
+from dlti_tpu.telemetry.startup import install_compile_listener
 from dlti_tpu.utils.platform import enable_compilation_cache
 
 enable_compilation_cache()
+# The trainer's sampler (/debug/vars, flight dumps) then carries what
+# compiling and fetching programs cost (compilations, compile_seconds, ...).
+install_compile_listener()
 
 
 def parse_args():

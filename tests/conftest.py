@@ -48,6 +48,30 @@ def devices():
 
 
 @pytest.fixture()
+def time_limit():
+    """``with time_limit(seconds):`` raises TimeoutError in the test's own
+    (main) thread when the block has not ended by then: every test that
+    starts a profiler, or waits for a process, bounds it with this."""
+    import contextlib
+    import signal
+
+    @contextlib.contextmanager
+    def limit(seconds: int):
+        def on_alarm(signum, frame):
+            raise TimeoutError(f"no end within {seconds} s")
+
+        was = signal.signal(signal.SIGALRM, on_alarm)
+        signal.alarm(seconds)
+        try:
+            yield
+        finally:
+            signal.alarm(0)
+            signal.signal(signal.SIGALRM, was)
+
+    return limit
+
+
+@pytest.fixture()
 def rng():
     return jax.random.PRNGKey(0)
 

@@ -43,6 +43,23 @@ SPAN_NAME_CATALOG = frozenset({
     "engine/kv_handoff",
     "engine/prefill_chunks",
     "engine/tier_restore",
+    # The children of the step phases: where the host's time between two
+    # device programs goes (benchmark/lib/span_rules.json reads them).
+    "engine/decode_prep",
+    "engine/decode_launch",
+    "engine/decode_wait",
+    "engine/decode_emit",
+    "engine/prefill_group",
+    "engine/prefill_launch",
+    "engine/prefill_wait",
+    # The stepper thread's loop around engine.step() (serving.server).
+    "server/wait_work",
+    "server/step",
+    "server/lock_wait",
+    "server/drain_events",
+    # Marks that cut the ring to a profiler capture (telemetry.tracer).
+    "profiler/start",
+    "profiler/stop",
     # Request lifecycle (telemetry.lifecycle).
     "request/submitted",
     "request/queued",
@@ -148,7 +165,7 @@ def test_span_names_follow_plane_slash_phase_convention():
         plane, _, phase = name.partition("/")
         assert plane and phase, name
         assert plane in ("train", "engine", "request", "gateway",
-                         "watchdog"), name
+                         "watchdog", "server", "profiler"), name
         assert phase == phase.lower().replace("-", "_"), name
 
 
