@@ -15,7 +15,9 @@ tracer's own ring export, gives CPU over wall time of the stepper's spans.
 Which planes, lines, span names and kernel names, is data:
 ``span_rules.json`` (written down after looking at one traced run of each
 cell by hand: PERF.md, "Reading a trace"); devices, operation and program
-lines and the programs' names come from ``trace_rules.json``. Two stages,
+lines and the programs' names come from ``trace_rules.json``. Files under
+``benchmark/rules/`` add kernels, span groups, clock checks and programs
+to the two (``spec.load_rules``; benchmark/rules/README.md). Two stages,
 like ``reduce_trace``: ``load`` reads an ``.xplane.pb`` into plain lists
 (host lines cut to the program's spans), ``attribute`` works on those, so
 the arithmetic is checked on small traces written by hand.
@@ -39,15 +41,20 @@ import subprocess
 import sys
 
 import reduce_trace
+import spec as spec_lib
 
-_RULES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                      "span_rules.json")
 RESULT_NAME = "idle_attribution.json"
 
 
-def rules() -> dict:
-    with open(_RULES) as f:
-        return json.load(f)
+def rules(bench_dir: str = spec_lib.BENCH_DIR) -> dict:
+    rule = spec_lib.load_rules("span_rules.json", spec_lib.SPAN_SECTIONS,
+                               bench_dir)
+    unplaced = sorted(set(rule["kernels"]) - set(rule["kernels_per"]))
+    if unplaced:
+        raise spec_lib.SpecError(
+            f"the rules give kernel {', '.join(unplaced)} no program to "
+            f"count its steps by (kernels_per)")
+    return rule
 
 
 def load(path: str, rule: dict, device_rule: dict) -> dict:

@@ -22,6 +22,11 @@ always about the code this run measured.
 The weights are the program's own initialisation from a fixed key (the
 server's ``--random-init`` uses ``PRNGKey(0)``), so both sides hold the
 same ones without a file changing hands.
+
+Which reference and which constructor of the program's model, the
+configuration file says (``spec.load_reference``, ``spec.program_model``);
+the reference takes its sizes from that file, the program from its own
+``ModelConfig``.
 """
 
 from __future__ import annotations
@@ -38,23 +43,25 @@ sys.path.insert(0, HERE)
 sys.path.insert(0, os.getcwd())
 
 
-def _sizes(model_cfg) -> dict:
-    return {"num_layers": model_cfg.num_layers,
-            "num_heads": model_cfg.num_heads,
-            "num_kv_heads": model_cfg.num_kv_heads,
-            "head_dim": model_cfg.resolved_head_dim,
-            "rms_norm_eps": model_cfg.rms_norm_eps,
-            "rope_theta": model_cfg.rope_theta,
-            "sliding_window": model_cfg.sliding_window,
-            "tie_embeddings": model_cfg.tie_embeddings}
+import spec as spec_lib  # noqa: E402
 
 
-def _model_config(path: str):
+def _configuration(path: str) -> dict:
+    with open(path) as f:
+        return json.load(f)
+
+
+def _program_model(config: dict, lora):
+    """The program's model as the configuration names its constructor,
+    built from the program's own translation of the file."""
+    import importlib
+
     from chip_child import model_fields
     from dlti_tpu.config import ModelConfig
 
-    with open(path) as f:
-        return ModelConfig(**model_fields(json.load(f)))
+    module, attr = spec_lib.program_model(config)
+    build = getattr(importlib.import_module(module), attr)
+    return build(ModelConfig(**model_fields(config)), lora)
 
 
 def _enable_cache() -> None:
@@ -97,20 +104,18 @@ def _tree_norm(tree) -> float:
                               for g in jax.tree_util.tree_leaves(tree))))
 
 
-def train_inputs(model_file: str, spec: dict):
-    """(model, its configuration, seeded parameters, seeded packed rows):
-    what both sides of the training check compute on."""
+def train_inputs(config: dict, spec: dict):
+    """(the program's model, seeded parameters, seeded packed rows): what
+    both sides of the training check compute on."""
     import jax
     import jax.numpy as jnp
 
     from dlti_tpu.config import LoRAConfig
-    from dlti_tpu.models import LlamaForCausalLM
 
     _enable_cache()
-    model_cfg = _model_config(model_file)
     r = int(spec["lora_r"])
-    model = LlamaForCausalLM(model_cfg,
-                             LoRAConfig(enabled=True, r=r, alpha=2 * r))
+    model = _program_model(config,
+                           LoRAConfig(enabled=True, r=r, alpha=2 * r))
     key = jax.random.PRNGKey(int(spec["seed"]))
     params = jax.jit(lambda k: model.init(
         k, jnp.zeros((1, 8), jnp.int32))["params"])(key)
@@ -125,12 +130,18 @@ def train_inputs(model_file: str, spec: dict):
 
     params = jax.tree_util.tree_map_with_path(perturb, params)
     batch = {k: jnp.asarray(v) for k, v in packed_rows(
-        int(spec["rows"]), int(spec["seq_len"]), model_cfg.vocab_size,
-        int(spec["seed"]), int(spec["doc_median"])).items()}
-    return model, model_cfg, params, batch
+        int(spec["rows"]), int(spec["seq_len"]),
+        int(config["model"]["vocab_size"]), int(spec["seed"]),
+        int(spec["doc_median"])).items()}
+    return model, params, batch
 
 
 LORA_SCALING = 2.0  # alpha / r of the adapters train_inputs builds
+
+
+def is_lora(path) -> bool:
+    """The leaves the fine-tune trains, in the tree train_inputs builds."""
+    return any(getattr(k, "key", None) in ("lora_a", "lora_b") for k in path)
 
 
 def _leaf_names(tree) -> list:
@@ -152,13 +163,13 @@ def train_reference(args) -> dict:
     import jax
     import numpy as np
 
-    import reference
-
     with open(args.spec) as f:
         spec = json.load(f)
-    _, model_cfg, params, batch = train_inputs(args.model_file, spec)
-    loss, grads, picked = reference.grad(params, _sizes(model_cfg), batch,
-                                         LORA_SCALING, reference.is_lora)
+    config = _configuration(args.model_file)
+    reference = spec_lib.load_reference(config, "train")
+    _, params, batch = train_inputs(config, spec)
+    loss, grads, picked = reference.grad(params, reference.sizes(config),
+                                         batch, LORA_SCALING, is_lora)
     arrays = {"token_logprobs": np.asarray(picked)}
     for name, g in zip(_leaf_names(grads),
                        jax.tree_util.tree_leaves(grads)):
@@ -177,14 +188,22 @@ def program_side(model, params, batch):
     import jax
     import jax.numpy as jnp
 
-    import reference
     from dlti_tpu.training.step import causal_lm_loss
 
-    trainable, frozen = reference.split(params, reference.is_lora)
+    # Two trees of one shape, with None where the other holds the leaf.
+    trainable = jax.tree_util.tree_map_with_path(
+        lambda p, v: v if is_lora(p) else None, params)
+    frozen = jax.tree_util.tree_map_with_path(
+        lambda p, v: None if is_lora(p) else v, params)
+
+    def merge(trainable, frozen):
+        return jax.tree_util.tree_map(
+            lambda a, b: b if a is None else a, trainable, frozen,
+            is_leaf=lambda v: v is None)
 
     def program_loss(trainable, frozen, batch):
         logits, _ = model.apply(
-            {"params": reference.merge(trainable, frozen)},
+            {"params": merge(trainable, frozen)},
             batch["input_ids"], positions=batch["positions"],
             segment_ids=batch["segment_ids"], deterministic=True)
         total, count = causal_lm_loss(logits, batch["input_ids"],
@@ -257,7 +276,8 @@ def train_program(args) -> dict:
         ref = json.load(f)
     arrays = np.load(os.path.join(os.path.dirname(args.reference),
                                   ref["arrays"]))
-    model, _, params, batch = train_inputs(args.model_file, spec)
+    model, params, batch = train_inputs(_configuration(args.model_file),
+                                        spec)
     p_loss, p_picked, p_grads = program_side(model, params, batch)
     return compare_train(p_loss, p_picked, p_grads, ref, arrays,
                          batch["loss_mask"], spec["tolerance"])
@@ -267,18 +287,16 @@ def check_serve(args) -> dict:
     import jax
     import jax.numpy as jnp
 
-    import reference
-    from dlti_tpu.models import LlamaForCausalLM
-
     with open(args.cases) as f:
         cases = json.load(f)
     _enable_cache()
-    model_cfg = _model_config(args.model_file)
-    model = LlamaForCausalLM(model_cfg, None)
+    config = _configuration(args.model_file)
+    reference = spec_lib.load_reference(config, "serve")
+    model = _program_model(config, None)
     # Exactly what scripts/serve.py --random-init does, so the same weights.
     params = model.init(jax.random.PRNGKey(0),
                         jnp.zeros((1, 8), jnp.int32))["params"]
-    sizes = _sizes(model_cfg)
+    sizes = reference.sizes(config)
 
     @jax.jit
     def logprobs(params, ids):

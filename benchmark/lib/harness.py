@@ -16,6 +16,8 @@ import subprocess
 import sys
 import time
 
+import spec as spec_lib
+
 PY = sys.executable
 LIB = os.path.dirname(os.path.abspath(__file__))
 
@@ -53,9 +55,15 @@ class Run:
         self.spec = spec
         config = dict(cell["config"])
         if rehearsal:
-            config["model"] = {**config["model"],
-                               **spec.get("model_overrides", {})}
+            # the tiny stand-in: the published keys, and what reaches the
+            # program's ModelConfig verbatim, each with its own overrides
+            for part in ("model", "program"):
+                over = spec.get(part + "_overrides")
+                if over:
+                    config[part] = {**config.get(part, {}), **over}
         self.config = config
+        self.reference_file = spec_lib.reference_file(config,
+                                                      cell["bench_dir"])
         self.model_file = self.path("model.json")
         with open(self.model_file, "w") as f:
             json.dump(config, f)
@@ -141,20 +149,23 @@ class Run:
         return out
 
     # -- reference values kept beside the compile cache ---------------------
-    def cached_reference(self, key_parts: list, compute) -> tuple:
+    def cached_reference(self, inputs, compute) -> tuple:
         """(file, whether it was already there). ``compute(out_path)`` runs
         the reference process; what it writes is kept under a key of
-        everything the *reference* depends on. Nothing the program under
-        test computed may go into such a file: that is computed in every
-        run (a kept verdict would vouch for code it never saw)."""
+        everything the *reference* depends on: the seeded ``inputs``, the
+        whole configuration as run (its sizes, and the reference and the
+        model constructor it names), the platform, and the bytes of that
+        reference module and of check.py. Nothing the program under test
+        computed may go into such a file: that is computed in every run (a
+        kept verdict would vouch for code it never saw)."""
         h = hashlib.sha256()
         # the platform too: the seeded bf16 weights are not the same bits
         # on the CPU and on the TPU (my runs, PR 23: reference loss 11.15829
         # and 11.15882 from one key)
-        for part in [*key_parts, "cpu" if self.rehearsal else "tpu"]:
+        for part in (inputs, self.config, "cpu" if self.rehearsal else "tpu"):
             h.update(json.dumps(part, sort_keys=True).encode())
-        for name in ("reference.py", "check.py"):
-            with open(os.path.join(LIB, name), "rb") as f:
+        for path in (self.reference_file, os.path.join(LIB, "check.py")):
+            with open(path, "rb") as f:
                 h.update(f.read())
         cache_dir = os.path.join(self.root, ".bench_cache", "checks")
         os.makedirs(cache_dir, exist_ok=True)
@@ -163,6 +174,13 @@ class Run:
         if not hit:
             compute(path)
         return path, hit
+
+    def judged_by(self) -> dict:
+        """Which reference and which model constructor the configuration
+        led the check to, for the notes line."""
+        return {"reference": os.path.relpath(self.reference_file, self.root),
+                "program_model": ":".join(
+                    spec_lib.program_model(self.config))}
 
     def run_check(self, what: str, extra: list, out: str,
                   timeout_s: float) -> None:

@@ -7,7 +7,8 @@ without the profiler: ``load`` reads an ``.xplane.pb`` with nothing but
 Which planes are devices, which lines hold operations and which hold whole
 programs, and how the programs are named, is data: ``trace_rules.json``
 (written down after looking at one trace of this program by hand — see
-PERF.md, "Reading a trace").
+PERF.md, "Reading a trace"); files under ``benchmark/rules/`` add programs
+to it (``spec.load_rules``).
 
     trace = {"planes": [{"name": str, "lines": [{"name": str,
              "events": [[name, start_ns, duration_ns], ...]}]}]}
@@ -21,13 +22,12 @@ import os
 import re
 import statistics
 
-_RULES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                      "trace_rules.json")
+import spec as spec_lib
 
 
-def rules() -> dict:
-    with open(_RULES) as f:
-        return json.load(f)
+def rules(bench_dir: str = spec_lib.BENCH_DIR) -> dict:
+    return spec_lib.load_rules("trace_rules.json", spec_lib.TRACE_SECTIONS,
+                               bench_dir)
 
 
 def find_xplane(profile_dir: str) -> str | None:

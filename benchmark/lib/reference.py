@@ -36,6 +36,25 @@ def _mm(a, b):
     return jnp.matmul(a, b, precision=HIGHEST)
 
 
+def sizes(config: dict) -> dict:
+    """What ``forward`` and ``grad`` need, from the configuration file as
+    run: ``config["model"]`` holds the keys of the model's public
+    config.json. Never from the program's ``ModelConfig``: a key that the
+    program drops or mistranslates has to show as a disagreement."""
+    m = config["model"]
+    heads = m["num_attention_heads"]
+    window = m.get("sliding_window") \
+        if m.get("use_sliding_window", True) else None
+    return {"num_layers": m["num_hidden_layers"],
+            "num_heads": heads,
+            "num_kv_heads": m["num_key_value_heads"],
+            "head_dim": m.get("head_dim") or m["hidden_size"] // heads,
+            "rms_norm_eps": m["rms_norm_eps"],
+            "rope_theta": m["rope_theta"],
+            "sliding_window": int(window) if window else None,
+            "tie_embeddings": bool(m.get("tie_word_embeddings", False))}
+
+
 def layer_weights(params: dict, i: int) -> dict:
     """Layer ``i`` of the program's parameter tree, by this module's names.
     A projection is ``(kernel, bias or None, lora_a or None, lora_b)``."""

@@ -355,14 +355,14 @@ def run(r: harness.Run) -> dict:
     with open(cases_file, "w") as f:
         json.dump(cases, f)
     kept, hit = r.cached_reference(
-        [r.config["model"], r.config.get("program", {}),
-         [[c["key"], c["prompt_ids"], c["tokens"]] for c in cases]],
+        [[c["key"], c["prompt_ids"], c["tokens"]] for c in cases],
         lambda out: r.run_check("serve", ["--cases", cases_file], out,
                                 spec["check"]["timeout_s"]))
     reference = harness.read_json(kept)
     verdict = judge(cases, reference, spec["check"]["tolerance"])
     r.notes["reference_check"] = {**verdict, "from_cache": hit,
-                                  "reference_device": reference["device"]}
+                                  "reference_device": reference["device"],
+                                  **r.judged_by()}
 
     row = summarise(ph)
     w0, w1 = ph["w0"], ph["w1"]

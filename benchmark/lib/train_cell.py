@@ -53,15 +53,15 @@ def check(r: harness.Run, check_spec: dict) -> dict:
     with open(spec_file, "w") as f:
         json.dump(check_spec, f)
     kept, hit = r.cached_reference(
-        [r.config["model"], r.config.get("program", {}),
-         {k: check_spec[k] for k in CHECK_INPUTS}],
+        {k: check_spec[k] for k in CHECK_INPUTS},
         lambda out: r.run_check("train-reference", ["--spec", spec_file],
                                 out, check_spec["timeout_s"]))
     out = r.path("check_program.json")
     r.run_check("train-program", ["--spec", spec_file, "--reference", kept],
                 out, check_spec["timeout_s"])
     verdict = harness.read_json(out)
-    r.notes["reference_check"] = {**verdict, "reference_from_cache": hit}
+    r.notes["reference_check"] = {**verdict, "reference_from_cache": hit,
+                                  **r.judged_by()}
     return verdict
 
 

@@ -192,3 +192,79 @@ def test_a_later_run_keeps_the_reference_and_computes_the_program_again(
     assert run.notes["reference_check"]["reference_from_cache"] is True
     kept = os.listdir(tmp_path / ".bench_cache" / "checks")
     assert len(kept) == 1, "one file per (configuration, rows)"
+
+
+# -- the reference takes its sizes from the configuration file -----------------
+
+def _sizes_from_the_programs_model_config(config):
+    """What check.py handed the reference before the reference read the
+    file itself: the program's own translation of it (kept here as what
+    ``sizes(config)`` has to equal for the configurations of that time)."""
+    from chip_child import model_fields
+    from dlti_tpu.config import ModelConfig
+
+    model_cfg = ModelConfig(**model_fields(config))
+    return {"num_layers": model_cfg.num_layers,
+            "num_heads": model_cfg.num_heads,
+            "num_kv_heads": model_cfg.num_kv_heads,
+            "head_dim": model_cfg.resolved_head_dim,
+            "rms_norm_eps": model_cfg.rms_norm_eps,
+            "rope_theta": model_cfg.rope_theta,
+            "sliding_window": model_cfg.sliding_window,
+            "tie_embeddings": model_cfg.tie_embeddings}
+
+
+@pytest.mark.parametrize("name", ["mistral_7b", "qwen2_7b"])
+def test_sizes_from_the_file_equal_those_the_program_derived(name):
+    import reference
+
+    config = json.load(open(os.path.join(bench_paths.BENCH, "configs",
+                                         name + ".json")))
+    got = reference.sizes(config)
+    want = _sizes_from_the_programs_model_config(config)
+    assert got == want
+    assert {k: type(v) for k, v in got.items()} == \
+        {k: type(v) for k, v in want.items()}
+    assert (got["sliding_window"] is None) == (name == "qwen2_7b")
+
+
+@pytest.mark.parametrize("model", [
+    MISTRAL_LIKE, QWEN2_LIKE,
+    {k: v for k, v in MISTRAL_LIKE.items() if k != "head_dim"},
+    {**QWEN2_LIKE, "tie_word_embeddings": True}],
+    ids=["mistral_like", "qwen2_like", "head_dim_left_out", "tied_head"])
+def test_forward_on_the_files_sizes_gives_the_same_bits(model):
+    """Seeded tiny weights, float32, CPU: the logits with the sizes read
+    from the file are those with the sizes the program derived, bit for
+    bit, for each key a configuration may leave to its default."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import reference
+
+    config = {"model": model}
+    sizes = reference.sizes(config)
+    assert sizes == _sizes_from_the_programs_model_config(config)
+    program = check._program_model(config, None)
+    params = program.init(jax.random.PRNGKey(3),
+                          jnp.zeros((1, 8), jnp.int32))["params"]
+    ids = jax.random.randint(jax.random.PRNGKey(4), (96,), 3, 512)
+    mine = reference.forward(params, sizes, ids)
+    theirs = reference.forward(
+        params, _sizes_from_the_programs_model_config(config), ids)
+    assert mine.dtype == jnp.float32 and mine.shape == (96, 512)
+    assert np.array_equal(np.asarray(mine), np.asarray(theirs))
+    # and they are the program's logits, to float32 summation order
+    logits, _ = program.apply({"params": params}, ids[None],
+                              deterministic=True)
+    assert np.abs(np.asarray(logits[0], np.float32)
+                  - np.asarray(mine)).max() < 1e-4
+
+
+def test_a_key_the_reference_needs_and_the_file_lacks_is_an_error():
+    import reference
+
+    model = {k: v for k, v in MISTRAL_LIKE.items() if k != "rope_theta"}
+    with pytest.raises(KeyError, match="rope_theta"):
+        reference.sizes({"model": model})
