@@ -108,6 +108,42 @@ class ModelConfig:
     # "kernel" forces the kernel (interpreted off-TPU, for tests);
     # "gather" forces the XLA path.
     paged_attention_impl: str = "auto"
+    # Hybrid families (nemotron_h): ONE mixer per layer behind one pre-norm
+    # residual, named by character i of the pattern: "M" Mamba-2, "E" routed
+    # experts, "*" attention. "" = the Llama block in every layer. A
+    # patterned model is built by dlti_tpu.models.build_model.
+    layer_pattern: str = ""
+    rope: bool = True  # False: attention applies no rotary embedding
+    # Mamba-2 mixer ("M"): d_inner = heads * head_dim; B and C are shared by
+    # runs of heads / n_groups; the recurrent state is (heads, head_dim,
+    # state_size) a sequence, kept by decode slot in ``mamba_state_dtype``.
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_n_groups: int = 1
+    mamba_state_size: int = 0
+    mamba_conv_kernel: int = 4
+    mamba_chunk_size: int = 128  # the prefill scan's block; changes no result
+    mamba_state_dtype: str = "float32"
+    # Dropless routed experts ("E", models.moe.HeldExpertsMLP): the router
+    # scores all ``moe_num_experts``; this process holds (and computes)
+    # experts [moe_held_start, moe_held_start + moe_held_count) — 0 = all —
+    # as expert parallelism gives a chip its share. top-k is
+    # ``num_experts_per_tok``; experts are ungated ``mlp_activation`` MLPs.
+    moe_num_experts: int = 0
+    moe_held_start: int = 0
+    moe_held_count: int = 0
+    moe_intermediate_size: int = 0
+    moe_shared_intermediate_size: int = 0  # 0 = no shared expert
+    moe_scoring: str = "sigmoid_bias"  # sigmoid + selection bias, renormed
+    moe_routed_scaling: float = 1.0
+
+    def __post_init__(self):
+        if self.layer_pattern and (
+                len(self.layer_pattern) != self.num_layers
+                or set(self.layer_pattern) - set("ME*")):
+            raise ValueError(
+                f"layer_pattern {self.layer_pattern!r} must name one mixer "
+                f"(M, E or *) for each of num_layers={self.num_layers}")
 
     @property
     def resolved_head_dim(self) -> int:
@@ -123,6 +159,25 @@ class ModelConfig:
         drives FLOPs/token and MFU)."""
         return self._count_params(include_lm_head, active_only=True)
 
+    @property
+    def mamba_inner_size(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels the causal convolution runs over: x, B and C."""
+        return (self.mamba_inner_size
+                + 2 * self.mamba_n_groups * self.mamba_state_size)
+
+    @property
+    def moe_held(self) -> int:
+        """Routed experts this process holds in each expert layer."""
+        return self.moe_held_count or self.moe_num_experts
+
+    @property
+    def has_recurrent_state(self) -> bool:
+        return "M" in self.layer_pattern
+
     def _count_params(self, include_lm_head: bool, active_only: bool) -> int:
         h, m, v = self.hidden_size, self.intermediate_size, self.vocab_size
         hd = self.resolved_head_dim
@@ -132,6 +187,28 @@ class ModelConfig:
         attn = q + kv + o
         if self.attention_bias:
             attn += (self.num_heads + 2 * self.num_kv_heads) * hd
+        if self.layer_pattern:
+            # One mixer and one norm a layer; of the routed experts, those
+            # held here (active: top-k of the router's width, of which the
+            # held share is what this process computes on average).
+            d_in, heads = self.mamba_inner_size, self.mamba_num_heads
+            mamba = (h * (2 * d_in + 2 * self.mamba_n_groups
+                          * self.mamba_state_size + heads)
+                     + self.mamba_conv_dim * (self.mamba_conv_kernel + 1)
+                     + 3 * heads + d_in + d_in * h)
+            f = self.moe_intermediate_size
+            n_routed = (self.num_experts_per_tok * self.moe_held
+                        / max(1, self.moe_num_experts)
+                        if active_only else self.moe_held)
+            experts = (int(n_routed * 2 * h * f)
+                       + 2 * h * self.moe_shared_intermediate_size
+                       + h * self.moe_num_experts + self.moe_num_experts)
+            per_kind = {"M": mamba, "*": attn, "E": experts}
+            total = v * h + h + sum(per_kind[c] + h
+                                    for c in self.layer_pattern)
+            if include_lm_head and not self.tie_embeddings:
+                total += h * v
+            return total
         if self.num_experts > 0:
             n_ffn = (self.num_experts_per_tok if active_only
                      else self.num_experts)
@@ -983,6 +1060,18 @@ MODEL_PRESETS: dict = {
         num_layers=32, num_heads=32, num_kv_heads=8, max_seq_len=8192,
         rope_theta=1000000.0, num_experts=8, num_experts_per_tok=2,
     ),
+    # Test-scale nemotron_h: every mixer kind twice, 8 sigmoid-routed relu2
+    # experts (top-3) with a shared expert, attention without rope.
+    "nemotron_h_tiny": ModelConfig(
+        vocab_size=512, hidden_size=64, intermediate_size=48, num_layers=6,
+        num_heads=4, num_kv_heads=2, head_dim=16, max_seq_len=256,
+        remat=False, dtype="float32", param_dtype="float32",
+        layer_pattern="MEM*EM", rope=False, mlp_activation="relu2",
+        mamba_num_heads=8, mamba_head_dim=8, mamba_n_groups=2,
+        mamba_state_size=16, mamba_chunk_size=8,
+        moe_num_experts=8, num_experts_per_tok=3, moe_intermediate_size=48,
+        moe_shared_intermediate_size=96, moe_routed_scaling=2.5,
+    ),
     # Test-scale MoE (structurally Mixtral: GQA + top-2 of 4 experts).
     "mixtral_tiny": ModelConfig(
         vocab_size=512, hidden_size=64, intermediate_size=128, num_layers=2,
@@ -1013,7 +1102,9 @@ def resolve_model(name: str) -> ModelConfig:
             raise ValueError(
                 f"DLTI_MODEL_LAYERS={cut!r}: {name} takes 1.."
                 f"{cfg.num_layers} whole layers")
-        cfg = dataclasses.replace(cfg, num_layers=int(cut))
+        cfg = dataclasses.replace(
+            cfg, num_layers=int(cut),
+            layer_pattern=cfg.layer_pattern[:int(cut)])
     return cfg
 
 

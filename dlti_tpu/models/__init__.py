@@ -1,6 +1,19 @@
-"""Model zoo: Llama-family transformer in Flax + LoRA grafting."""
+"""Model zoo: Llama-family transformer in Flax + LoRA grafting, and the
+patterned ``nemotron_h`` family (Mamba-2, routed experts, attention)."""
 
 from dlti_tpu.models.llama import LlamaForCausalLM, LlamaModel  # noqa: F401
+
+
+def build_model(cfg, lora=None, mesh=None):
+    """The causal LM a ``ModelConfig`` describes: the one place that picks
+    the model class (the trainer, the engine, ``serve.py --random-init``,
+    the fleet worker and the benchmark's check all come through here). A
+    configuration without a ``layer_pattern`` is the Llama family."""
+    if cfg.layer_pattern:
+        from dlti_tpu.models.nemotron_h import NemotronHForCausalLM
+
+        return NemotronHForCausalLM(cfg, lora, mesh)
+    return LlamaForCausalLM(cfg, lora, mesh)
 from dlti_tpu.models.lora import (  # noqa: F401
     LoRADense,
     lora_param_mask,

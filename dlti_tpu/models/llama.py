@@ -139,8 +139,9 @@ class LlamaAttention(nn.Module):
         k = k.reshape(b, s, cfg.num_kv_heads, hd)
         v = v.reshape(b, s, cfg.num_kv_heads, hd)
 
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
+        if cfg.rope:  # the nemotron_h family applies none (cos, sin None)
+            q = apply_rope(q, cos, sin, positions)
+            k = apply_rope(k, cos, sin, positions)
 
         new_cache = None
         if cache is not None and "block_tables" in cache:

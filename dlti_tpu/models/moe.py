@@ -1,11 +1,11 @@
-"""Mixture-of-Experts MLP with expert parallelism.
+"""Mixture-of-Experts MLPs: two layers, two ways to route.
 
-Mixtral-style top-k routed SwiGLU experts, expressed the TPU way: instead
-of per-token Python dispatch (host control flow XLA can't compile), tokens
-are packed into fixed-capacity per-expert buffers with one-hot dispatch /
-combine einsums (the GShard/Switch formulation). All shapes are static;
-the only data-dependent effect is token dropping when an expert
-overflows its capacity — controlled by ``moe_capacity_factor``.
+**``MoEMLP``** — Mixtral-style softmax top-k routed SwiGLU experts, expressed
+with static shapes: tokens are packed into fixed-capacity per-expert buffers
+with one-hot dispatch / combine einsums (the GShard/Switch formulation). The
+only data-dependent effect is token dropping when an expert overflows its
+capacity — controlled by ``moe_capacity_factor``. It serves the
+``num_experts`` presets (Mixtral) in training and serving.
 
 Expert parallelism rides a dedicated ``expert`` mesh axis: the stacked
 expert weights ``(E, ...)`` shard on dim 0, the dispatched activations
@@ -15,6 +15,16 @@ all-to-all between the token-sharded and expert-sharded layouts.
 The router's load-balance auxiliary loss (Switch §2.2 / Mixtral) is
 recorded via ``self.sow("intermediates", "router_aux_loss", ...)``; the
 train step collects it when ``ModelConfig.num_experts > 0``.
+
+**``HeldExpertsMLP``** — the dropless layer of the patterned families
+(``ModelConfig.layer_pattern``, "E" layers): no capacity, no token dropped.
+Every held expert runs over every token of a block under the routing weights
+as a mask (the same sum as sorting tokens by expert; see ``TOKEN_BLOCK``).
+The layer is told which experts it holds (``moe_held_start``,
+``moe_held_count``), routes over all ``moe_num_experts``, and computes its
+own experts' part of the result plus the shared expert — what expert
+parallelism asks of one chip. Nothing stands in for the experts held
+elsewhere.
 """
 
 from __future__ import annotations
@@ -27,6 +37,14 @@ import jax.numpy as jnp
 
 from dlti_tpu.config import ModelConfig
 from dlti_tpu.models.llama import _dtype
+from dlti_tpu.models.lora import LoRADense
+
+# What a HeldExpertsMLP call counts, in this order (summed over the expert
+# layers and steps of a program, the maximum apart): routed assignments of
+# real tokens; those on experts held here; held experts with at least one;
+# the largest count on one held expert in one layer-step.
+MOE_COUNTERS = ("moe_assignments", "moe_held_assignments",
+                "moe_experts_touched", "moe_expert_load_max")
 
 
 class MoEMLP(nn.Module):
@@ -147,6 +165,148 @@ class MoEMLP(nn.Module):
             return jax.lax.with_sharding_constraint(
                 v, NamedSharding(mesh, P("expert", None, None)))
         return v
+
+
+# HeldExpertsMLP runs every held expert over every token under the routing
+# weights as a mask, this many tokens at a time (the (tokens, experts, width)
+# product of a block is what bounds the memory). Why not sorted by expert
+# through `jax.lax.ragged_dot`: one layer of 64 held experts at the published
+# widths on the v5e, masked / sorted (my chip runs, PR 30): 32 tokens 1.89 /
+# 8.73 ms, 512 3.84 / 17.4, 1,024 7.42 / 18.9, 2,048 14.7 / 22.5, and at
+# 4,096 the sorted 29.2 ms is what two masked blocks take: `ragged_dot` pays
+# ~14 ms for its 64 groups however few rows it is given. And a 13-layer
+# prefill of 2 rows x 2,048 tokens through its kernel never returned on the
+# v5e (PERF.md, PR 30).
+TOKEN_BLOCK = 2048
+
+
+def _relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+def centred_out_init(scale: float, batch_axis=()):
+    """Seeded weights of a relu² MLP's down projection: LeCun-normal times
+    ``scale``, each output's weights centred over the inputs.
+
+    relu² is positive, so an uncentred projection puts one fixed vector, the
+    image of the mean activation, on every token: after five such layers the
+    residual streams of different tokens had a cosine of 0.9 and a decode
+    step's 32 tokens all chose the same experts, which no trained model's
+    traffic does. ``scale`` keeps a layer's part of the residual stream
+    below the embedding's, as a trained model's is: a routing flip between
+    two near-tied experts (bf16 against float32) then moves a log-prob by
+    hundredths, not tenths."""
+    base = nn.initializers.variance_scaling(
+        scale * scale, "fan_in", "truncated_normal", batch_axis=batch_axis)
+
+    def init(key, shape, dtype=jnp.float32):
+        w = base(key, shape, jnp.float32)
+        return (w - jnp.mean(w, axis=-2, keepdims=True)).astype(dtype)
+
+    return init
+
+
+# Seeded scales (see centred_out_init): the routed experts' and the shared
+# expert's down projections; the selection bias in units of a score (the
+# scores of one token spread by ~0.2, so this moves a choice now and then
+# and does not make it).
+ROUTED_OUT_SCALE, SHARED_OUT_SCALE, SCORE_BIAS_STD = 0.1, 0.25, 0.025
+
+
+class HeldExpertsMLP(nn.Module):
+    """Dropless top-k routed experts, of which this process holds a range,
+    and a shared expert for every token.
+
+        s = sigmoid(x W_r)                      float32, all E experts
+        chosen = top-k of s + e_score_correction_bias
+        w = s[chosen] / sum(s[chosen]) * moe_routed_scaling
+        y = sum_{e chosen and held here} w_e W_down,e act(W_up,e x)
+            + shared_down act(shared_up x)
+
+    ``act`` is ``mlp_activation`` ("relu2": ungated relu squared)."""
+
+    cfg: ModelConfig
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray,
+                 token_mask: Optional[jnp.ndarray] = None):
+        """``token_mask`` (b, s): true for real tokens; the rest are not
+        routed (they would count as load and touch experts). Returns
+        ``(y, counters (4,) int32 in the order of MOE_COUNTERS)``."""
+        cfg = self.cfg
+        if cfg.mlp_activation != "relu2" or cfg.moe_scoring != "sigmoid_bias":
+            raise NotImplementedError(
+                f"HeldExpertsMLP computes relu2 experts under sigmoid_bias "
+                f"scoring; got {cfg.mlp_activation!r}, {cfg.moe_scoring!r}")
+        dtype, pdtype = _dtype(cfg.dtype), _dtype(cfg.param_dtype)
+        b, s, h = x.shape
+        T, E, k = b * s, cfg.moe_num_experts, cfg.num_experts_per_tok
+        lo, held_n, f = cfg.moe_held_start, cfg.moe_held, \
+            cfg.moe_intermediate_size
+        xt = x.reshape(T, h)
+        valid = (jnp.ones((T,), bool) if token_mask is None
+                 else token_mask.reshape(T).astype(bool))
+
+        router = self.param("router", nn.initializers.lecun_normal(),
+                            (h, E), jnp.float32)
+        # Seeded non-zero so that a selection that ignored it would differ.
+        bias = self.param("e_score_correction_bias",
+                          nn.initializers.normal(SCORE_BIAS_STD), (E,),
+                          jnp.float32)
+        with jax.named_scope("dlti_moe_routed"):
+            scores = jax.nn.sigmoid(jnp.dot(
+                xt.astype(jnp.float32), router,
+                precision=jax.lax.Precision.HIGHEST))
+            _, chosen = jax.lax.top_k(scores + bias, k)               # (T,k)
+            self.sow("intermediates", "chosen", chosen)  # for checks only
+            w = jnp.take_along_axis(scores, chosen, axis=1)
+            w = w / jnp.sum(w, axis=1, keepdims=True) * cfg.moe_routed_scaling
+            held = (chosen >= lo) & (chosen < lo + held_n) & valid[:, None]
+
+            w_up = self.param("w_up", nn.initializers.lecun_normal(
+                batch_axis=(0,)), (held_n, h, f), pdtype).astype(dtype)
+            w_down = self.param("w_down", centred_out_init(
+                ROUTED_OUT_SCALE, batch_axis=(0,)), (held_n, f, h),
+                pdtype).astype(dtype)
+            # Tokens on each held expert, for the counters; an assignment
+            # held elsewhere counts as none.
+            local = jnp.where(held, chosen - lo, held_n)                # (T,k)
+            sizes = jnp.bincount(local.reshape(-1), length=held_n + 1)[
+                :held_n].astype(jnp.int32)
+            # Every held expert over every token, the routing weight as the
+            # mask: nothing sorted or gathered, each expert's weights read
+            # once a block, which for a decode step or a short prompt is
+            # all the time there is.
+            gate = jnp.zeros((T, held_n + 1), jnp.float32).at[
+                jnp.arange(T)[:, None], local].add(w)[:, :held_n]
+
+            def block(xg):
+                xb, gb = xg
+                act = _relu2(jnp.einsum("th,ehf->tef", xb, w_up))
+                return jnp.einsum("tef,efh->th", act * gb[:, :, None], w_down)
+
+            xs, gate = xt.astype(dtype), gate.astype(dtype)
+            if T <= TOKEN_BLOCK:
+                y = block((xs, gate))
+            else:  # whole blocks; the rows added carry a zero gate
+                n = -(-T // TOKEN_BLOCK)
+                xs, gate = (jnp.pad(v, ((0, n * TOKEN_BLOCK - T), (0, 0)))
+                            .reshape(n, TOKEN_BLOCK, -1) for v in (xs, gate))
+                y = jax.lax.map(block, (xs, gate)).reshape(-1, h)[:T]
+        with jax.named_scope("dlti_moe_shared"):
+            if cfg.moe_shared_intermediate_size:
+                def dense(name, features, **kw):
+                    return LoRADense(features=features, use_bias=False,
+                                     dtype=dtype, param_dtype=pdtype,
+                                     name=name, **kw)
+
+                y = y + dense("shared_down", h, kernel_init=centred_out_init(
+                    SHARED_OUT_SCALE))(_relu2(dense(
+                        "shared_up", cfg.moe_shared_intermediate_size)(xt)))
+        counters = jnp.stack([
+            jnp.sum(valid) * k, jnp.sum(held), jnp.sum(sizes > 0),
+            jnp.max(sizes)]).astype(jnp.int32)
+        return y.reshape(b, s, h), counters
 
 
 def collect_aux_loss(intermediates: dict) -> jnp.ndarray:

@@ -1,4 +1,15 @@
-"""Paged KV cache — device-side ops.
+"""The serving cache — device-side ops: paged keys and values, and the
+recurrent state of state-space layers by decode slot.
+
+One cache, one entry a layer, two kinds of state (:func:`init_cache`):
+``{"k", "v"}`` block pools addressed by block tables for attention layers
+(below), ``{"conv", "ssm"}`` of shape ``(slots, ...)`` for Mamba-2 layers
+(:func:`init_recurrent_state`; a sequence's state has a fixed size, so it
+is addressed by the decode slot that serves it and needs no allocator),
+and ``{}`` for layers that keep nothing (routed experts).
+
+## Paged keys and values
+
 
 The reference claims a vLLM serving leg ("PagedAttention, continuous
 batching", ``README.md:10``; ``requirements.txt:18``) but ships no code.
@@ -67,6 +78,61 @@ def init_paged_cache(
         {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
         for _ in range(num_layers)
     ]
+
+
+def init_recurrent_state(num_slots: int, conv_kernel: int, conv_dim: int,
+                         num_heads: int, head_dim: int, state_size: int,
+                         conv_dtype=jnp.bfloat16,
+                         state_dtype=jnp.float32) -> dict:
+    """One Mamba-2 layer's state for every decode slot: the last
+    ``conv_kernel - 1`` inputs of the convolution and the SSM state."""
+    return {"conv": jnp.zeros((num_slots, conv_kernel - 1, conv_dim),
+                              conv_dtype),
+            "ssm": jnp.zeros((num_slots, num_heads, head_dim, state_size),
+                             state_dtype)}
+
+
+def init_cache(model_cfg, num_blocks: int, block_size: int, num_slots: int,
+               dtype=jnp.bfloat16) -> List[dict]:
+    """The serving cache of ``model_cfg``, one entry a layer by its kind.
+    A model without a ``layer_pattern`` is attention in every layer."""
+    from dlti_tpu.utils.dtypes import resolve_dtype
+
+    def paged():
+        return init_paged_cache(1, num_blocks, block_size,
+                                model_cfg.num_kv_heads,
+                                model_cfg.resolved_head_dim, dtype)[0]
+
+    def recurrent():
+        return init_recurrent_state(
+            num_slots, model_cfg.mamba_conv_kernel, model_cfg.mamba_conv_dim,
+            model_cfg.mamba_num_heads, model_cfg.mamba_head_dim,
+            model_cfg.mamba_state_size, resolve_dtype(model_cfg.dtype),
+            resolve_dtype(model_cfg.mamba_state_dtype))
+
+    kinds = model_cfg.layer_pattern or "*" * model_cfg.num_layers
+    return [{"*": paged, "M": recurrent, "E": dict}[k]() for k in kinds]
+
+
+# What one program call adds to the layers' entries (:func:`bind_call`).
+_CALL_KEYS = ("block_tables", "state_slots", "own_rows")
+
+
+def bind_call(cache: List[dict], block_tables, state_slots=None,
+              own_rows: bool = False) -> List[dict]:
+    """The cache as one program call hands it to the model: every layer's
+    entry with the rows' block tables, and a recurrent layer's with each
+    row's decode slot (``state_slots`` (rows,): out of range for a row that
+    must write no state; ``own_rows``: a decode call, row i is slot i)."""
+    return [{**c, "block_tables": block_tables,
+             **({"state_slots": state_slots, "own_rows": own_rows}
+                if "ssm" in c else {})} for c in cache]
+
+
+def unbind_call(cache: List[dict]) -> List[dict]:
+    """The pools alone again, as the model returned them."""
+    return [{k: v for k, v in c.items() if k not in _CALL_KEYS}
+            for c in cache]
 
 
 def _quantize_rows(x: jnp.ndarray):

@@ -159,6 +159,9 @@ class DisaggController:
     ):
         import jax
 
+        from dlti_tpu.serving.engine import refuse_state_handoff
+
+        refuse_state_handoff(model_cfg, "disaggregated serving (--disagg)")
         if prefill_replicas < 1 or decode_replicas < 1:
             raise ValueError(
                 f"prefill_replicas ({prefill_replicas}) and decode_replicas "

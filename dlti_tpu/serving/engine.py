@@ -36,8 +36,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from dlti_tpu.config import LoRAConfig, ModelConfig
-from dlti_tpu.models import LlamaForCausalLM
-from dlti_tpu.ops.kv_cache import init_paged_cache
+from dlti_tpu.models import build_model
+from dlti_tpu.ops.kv_cache import bind_call, init_cache, unbind_call
 from dlti_tpu.serving.adapters import AdapterError
 from dlti_tpu.serving.block_manager import BlockManager
 from dlti_tpu.serving.sampling import SamplingParams, sample_tokens
@@ -351,6 +351,58 @@ class _Slot:
         return self.request is not None and self.next_pos < self.prefill_end
 
 
+def refuse_unsupported(model_cfg: ModelConfig, engine_cfg: "EngineConfig",
+                       mesh=None) -> None:
+    """Refuse, at start-up and with one clear error each, every feature
+    that takes a sequence's state to be its k/v blocks when the model has
+    recurrent layers, and what the patterned families do not implement."""
+    if not model_cfg.layer_pattern:
+        return
+    ec = engine_cfg
+    what = f"a model with layer_pattern {model_cfg.layer_pattern!r}"
+    if model_cfg.has_recurrent_state:
+        why = (f"{what} keeps a recurrent state per decode slot beside its "
+               f"k/v blocks, and ")
+        if (ec.enable_prefix_caching or ec.prefix_host_blocks > 0
+                or ec.prefix_disk_blocks > 0):
+            raise ValueError(
+                why + "prefix caching (and its host/disk tiers) reuses k/v "
+                "blocks alone: a cached prefix would resume from the wrong "
+                "state. Serve it without --enable-prefix-caching; state "
+                "snapshots for prefix reuse are not implemented")
+    if ec.speculative != "none":
+        raise ValueError(
+            f"{what} cannot be served with speculative decoding: rejected "
+            f"drafts are rolled back by position in the k/v cache, and a "
+            f"recurrent state cannot be rolled back (nor does the "
+            f"speculative program thread it). Serve it with --speculative "
+            f"none")
+    if mesh is not None:
+        raise ValueError(
+            f"{what} has no tensor-parallel sharding rules (Mamba-2 and "
+            f"held-expert layers); serve it on one chip per replica")
+    if ec.quantization != "none":
+        raise ValueError(
+            f"{what} is served in its own precision: weight-only "
+            f"{ec.quantization} is not implemented for Mamba-2 and expert "
+            f"layers")
+    if ec.adapter_slots > 0:
+        raise ValueError(
+            f"{what} has no multi-LoRA adapter branch; serve it with "
+            f"--adapter-slots 0")
+
+
+def refuse_state_handoff(model_cfg: ModelConfig, what: str) -> None:
+    """Disaggregated serving and k/v hand-off move a sequence as its k/v
+    blocks; a recurrent state is not in them."""
+    if model_cfg.has_recurrent_state:
+        raise ValueError(
+            f"{what} moves a sequence between engines as its k/v blocks; a "
+            f"model with layer_pattern {model_cfg.layer_pattern!r} also "
+            f"keeps a recurrent state per decode slot, which the hand-off "
+            f"does not carry. Serve it colocated (no --disagg)")
+
+
 class EngineExecutor:
     """The device half of the engine: weights, paged-KV pools, and every
     compiled program (bucketed prefill, the decode ladder, speculative
@@ -397,7 +449,17 @@ class EngineExecutor:
                     f"tensor={tp} must evenly divide num_heads="
                     f"{model_cfg.num_heads} and num_kv_heads="
                     f"{model_cfg.num_kv_heads}")
-        self.model = LlamaForCausalLM(model_cfg, lora_cfg, mesh)
+        refuse_unsupported(model_cfg, engine_cfg, mesh)
+        self.model = build_model(model_cfg, lora_cfg, mesh)
+        # A model may count what its forward pass did (``counter_names``:
+        # int32 scalars it returns, by name, with ``return_counters``);
+        # every program then returns them as rows after its tokens, and
+        # the engine books them in ``stats`` under their names. A model
+        # with recurrent layers keeps a per-slot state beside the paged
+        # cache, and every program takes each row's slot (``state_slots``)
+        # as the last of its per-slot arguments.
+        self.counter_names = tuple(getattr(self.model, "counter_names", ()))
+        self._recurrent = model_cfg.has_recurrent_state
         self._quantized = engine_cfg.quantization == "int8"
         if engine_cfg.quantization not in ("none", "int8"):
             raise ValueError(f"unknown quantization {engine_cfg.quantization!r}")
@@ -455,10 +517,10 @@ class EngineExecutor:
         # per-row fp32 scales — ops.kv_cache): half the KV HBM of bf16,
         # which buys roughly twice the decode slots on a fixed chip.
         dtype = "int8" if ec.cache_dtype == "int8" else resolve_dtype(ec.cache_dtype)
-        self.cache = init_paged_cache(
-            model_cfg.num_layers, ec.num_blocks, ec.block_size,
-            model_cfg.num_kv_heads, model_cfg.resolved_head_dim, dtype,
-        )
+        # One cache, one entry a layer: block pools of keys and values for
+        # attention layers, per-slot recurrent state for Mamba-2 layers.
+        self.cache = init_cache(model_cfg, ec.num_blocks, ec.block_size,
+                                ec.max_seqs, dtype)
         if mesh is not None:
             self._shard_for_tp(mesh)
         elif self._device is not None:
@@ -537,6 +599,16 @@ class EngineExecutor:
         if ec.speculative not in ("none", "ngram"):
             raise ValueError(f"unknown speculative mode {ec.speculative!r}")
         self._sample_fn = jax.jit(sample_tokens)
+        if self.counter_names:
+            # First tokens of a prefill with the prefill program's counters
+            # as rows after them: one fetch brings both.
+            def sample_counted(logits, keys, temperature, top_k, top_p,
+                               counters):
+                tokens, logprobs = sample_tokens(logits, keys, temperature,
+                                                 top_k, top_p)
+                return jnp.concatenate([tokens, counters]), logprobs
+
+            self._sample_counted_fn = jax.jit(sample_counted)
 
         # Batched per-slot key folding (the same fold the decode program
         # applies to raw uint32 key data): one async dispatch instead of a
@@ -572,8 +644,14 @@ class EngineExecutor:
     # Compiled programs
     # ------------------------------------------------------------------
     def _model_cache_call(self, params, cache_kv, block_tables, input_ids,
-                          positions, adapter_ids=None, adapters=None):
-        """Run the model over a paged cache; returns (logits, new k/v list).
+                          positions, adapter_ids=None, adapters=None,
+                          state_slots=None, own_rows: bool = False):
+        """Run the model over the cache; returns ``(logits, new cache list,
+        counters)``. ``state_slots`` (a model with recurrent layers): each
+        row's decode slot, out of range for a row that must write no
+        recurrent state; ``own_rows`` says that this is a decode call, in
+        which row i is slot i. ``counters`` is a vector in the order of
+        ``self.counter_names``, or None for a model that counts nothing.
 
         Quantized params pass through as-is — each module dequantizes its
         own weights at the consumer (``models.quantization.maybe_dequantize``),
@@ -585,20 +663,32 @@ class EngineExecutor:
         per batch row) gathers each row's factors inside LoRADense; both
         absent leaves the traced program identical to an adapter-free
         engine (the branch is Python-static)."""
-        cache = [
-            {**layer, "block_tables": block_tables} for layer in cache_kv
-        ]
+        cache = bind_call(cache_kv, block_tables, state_slots, own_rows)
         variables = {"params": params}
         kw = {}
         if adapters is not None:
             variables["adapters"] = adapters
             kw["adapter_ids"] = adapter_ids
-        logits, new_cache = self.model.apply(
+        if self.counter_names:
+            kw["return_counters"] = True
+        logits, new_cache, *counted = self.model.apply(
             variables, input_ids, positions=positions, cache=cache,
             deterministic=True, **kw,
         )
-        return logits, [{k: v for k, v in c.items() if k != "block_tables"}
-                        for c in new_cache]
+        counters = jnp.stack([counted[0][n] for n in self.counter_names]) \
+            if counted else None
+        return logits, unbind_call(new_cache), counters
+
+    def _named(self, extra: tuple) -> dict:
+        """What follows the six per-slot state arrays in a program's
+        arguments, by the name ``_model_cache_call`` knows it under:
+        ``(adapter_ids, adapters)`` with a multi-LoRA pool,
+        ``(state_slots,)`` for a model with recurrent layers (the two are
+        never on together: ``refuse_unsupported``), else nothing — and the
+        traced program is the one it always was."""
+        if self._recurrent:
+            return {"state_slots": extra[0]}
+        return dict(zip(("adapter_ids", "adapters"), extra))
 
     def prefill_fn(self, bucket: int):
         """The compiled prefill program for a suffix bucket (lazily built)."""
@@ -619,11 +709,13 @@ class EngineExecutor:
             # row's final real logit. With a multi-LoRA pool, *lora is
             # (adapter_ids, adapters) — per-row adapter gather; empty
             # otherwise (the traced program is then unchanged).
-            logits, new_kv = self._model_cache_call(
-                params, cache_kv, block_table, input_ids, positions, *lora
-            )
+            logits, new_kv, counters = self._model_cache_call(
+                params, cache_kv, block_table, input_ids, positions,
+                **self._named(lora))
             last = jnp.take_along_axis(
                 logits, last_idx[:, None, None], axis=1)[:, 0]
+            if counters is not None:  # a model that counts (Python-static)
+                return new_kv, last, counters
             return new_kv, last
 
         return prefill
@@ -636,13 +728,17 @@ class EngineExecutor:
             # *lora: (adapter_ids, adapters) when the multi-LoRA pool is
             # on (adapter_ids rides in decode-state argument order, the
             # pool tree LAST so state threading stays contiguous).
-            logits, new_kv = self._model_cache_call(
-                params, cache_kv, block_tables, input_ids, positions, *lora
-            )
+            logits, new_kv, counters = self._model_cache_call(
+                params, cache_kv, block_tables, input_ids, positions,
+                **self._named(lora), own_rows=True)
             rngs = jax.vmap(jax.random.fold_in)(slot_keys, gen_counts)
             tokens, logprobs = sample_tokens(
                 logits[:, 0, :], rngs, temperature, top_k, top_p
             )
+            if counters is not None:
+                # The model's counters ride as rows after the slots'
+                # tokens: the fetch that exists brings them.
+                tokens = jnp.concatenate([tokens, counters])
             return new_kv, tokens, logprobs
 
         return decode
@@ -698,18 +794,20 @@ class EngineExecutor:
                          *lora):
             def body(carry, _):
                 cache, tok, pos, cnt = carry
-                logits, new_kv = self._model_cache_call(
-                    params, cache, block_tables, tok, pos, *lora
-                )
+                logits, new_kv, counters = self._model_cache_call(
+                    params, cache, block_tables, tok, pos,
+                    **self._named(lora), own_rows=True)
                 rngs = jax.vmap(jax.random.fold_in)(slot_keys, cnt)
                 nxt, lp = sample_tokens(
                     logits[:, 0, :], rngs, temperature, top_k, top_p)
-                return (new_kv, nxt[:, None], pos + 1, cnt + 1), (nxt, lp)
+                out = nxt if counters is None \
+                    else jnp.concatenate([nxt, counters])
+                return (new_kv, nxt[:, None], pos + 1, cnt + 1), (out, lp)
 
             (new_kv, _, _, _), (toks, lps) = jax.lax.scan(
                 body, (cache_kv, input_ids, positions, gen_counts),
                 None, length=num_steps)
-            # (K, S) -> (S, K)
+            # (K, S) -> (S, K); with counters: (S + counters, K)
             return new_kv, toks.T, lps.T
 
         return decode_multi
@@ -786,8 +884,9 @@ class EngineExecutor:
                 ids = jnp.concatenate(
                     [t_in[:, None], jnp.maximum(drafts, 0)], axis=1)
                 pos = seq_len[:, None] + jnp.arange(k + 1)[None, :]
-                logits, new_kv = self._model_cache_call(
-                    params, cache, block_tables, ids, pos, *lora)
+                logits, new_kv, _ = self._model_cache_call(
+                    params, cache, block_tables, ids, pos,
+                    **self._named(lora))
                 logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
                 g = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (S, k+1)
                 g_lp = jnp.take_along_axis(
@@ -969,6 +1068,14 @@ class InferenceEngine:
         # row). Maintained unconditionally so _state_mirrors stays
         # uniform; without a pool it is never shipped to the device.
         self._adapter_ids = np.zeros((S,), np.int32)
+        # Recurrent state (models with such layers): the slot each decode row may
+        # write its state to — its own while the slot decodes, out of
+        # range (S) while it is free or still prefilling, so a decode call
+        # never touches a state that a prefill is building.
+        self._state_slots = np.full((S,), S, np.int32)
+        # Counters of prefill chunks that sampled nothing (chunked
+        # prefill), still on the device: added to the next fetch.
+        self._prefill_counters = None
 
         # Host mirror of every slot's token history at its context
         # positions, maintained incrementally at admission/append — the
@@ -1050,6 +1157,13 @@ class InferenceEngine:
                       # HBM headroom — deferred, not faulted. Present (at
                       # 0) so the /metrics schema is stable.
                       "hbm_deferred_admissions": 0}
+        # What the model counts (EngineExecutor.counter_names), summed over
+        # every program call under the counter's own name, and the decode
+        # steps' part of it under ``<name>_decode``. (A largest-of counter
+        # is the sum over calls of each call's largest.) No such key for a
+        # model that counts nothing.
+        for name in self.executor.counter_names:
+            self.stats[name] = self.stats[f"{name}_decode"] = 0
         # Token-storm guard run length (consecutive all-slots-identical
         # decode steps).
         self._storm_run = 0
@@ -1065,8 +1179,9 @@ class InferenceEngine:
             self._state_cache = DecodeStateCache(
                 ec.max_seqs, device=self._device, mesh=mesh,
                 stats=self.stats,
-                extra_fields=(("adapter_ids",)
-                              if ec.adapter_slots > 0 else ()))
+                extra_fields=(("adapter_ids",) if ec.adapter_slots > 0
+                              else ("state_slots",)
+                              if model_cfg.has_recurrent_state else ()))
 
         # Memory ledger (telemetry.memledger): the engine's owners. The
         # params and cache handles are callables because both rebind
@@ -1074,10 +1189,17 @@ class InferenceEngine:
         # cached blocks live INSIDE the pool arrays, so that owner is a
         # carve — bytes move from kv_block_pool to prefix_cache_hbm
         # without double counting.
+        self._recurrent_state_pool_bytes = tree_nbytes(
+            [c for c in self.cache if "ssm" in c])
         self.memledger = MemoryLedger(
             enabled=ec.memory_ledger, capacity_bytes=ec.hbm_budget_bytes)
         self.memledger.register("params", lambda: self.params)
-        self.memledger.register("kv_block_pool", lambda: self.cache)
+        self.memledger.register(
+            "kv_block_pool",
+            lambda: [c for c in self.cache if "ssm" not in c])
+        self.memledger.register(
+            "recurrent_state_pool",
+            lambda: [c for c in self.cache if "ssm" in c] or None)
         self.memledger.register(
             "decode_state_cache",
             lambda: (self._state_cache._dev
@@ -1271,7 +1393,7 @@ class InferenceEngine:
                 jax.ShapeDtypeStruct((S,), f32),
                 jax.ShapeDtypeStruct((S,), i32),
                 jax.ShapeDtypeStruct((S,), f32))
-            if self.adapter_pool is not None:
+            if self.adapter_pool is not None or self.executor._recurrent:
                 state_avals += (jax.ShapeDtypeStruct((S,), i32),)
         args = (avals(self.params), avals(self.cache),
                 jax.ShapeDtypeStruct((S, 1), i32),
@@ -1382,6 +1504,14 @@ class InferenceEngine:
     @property
     def num_free_blocks(self) -> int:
         return self.block_manager.num_free
+
+    @property
+    def recurrent_state_pool_bytes(self) -> int:
+        """Bytes of the per-slot recurrent state (0 without such layers):
+        fixed when the pool is made. (Not read off the arrays at scrape
+        time: a handler thread would meet buffers a program call has just
+        been given.)"""
+        return self._recurrent_state_pool_bytes
 
     @property
     def spec_acceptance_rate(self) -> float:
@@ -1623,11 +1753,21 @@ class InferenceEngine:
         for adm, suffix_len in zip(admissions, suffix_lens):
             by_bucket.setdefault(self._bucket_for(suffix_len), []).append(adm)
         for bucket, group in by_bucket.items():
-            # Chunk very wide admission waves: past ~8 rows the batched
-            # program's marginal win flattens while its padded work and
-            # jit-shape surface keep growing.
-            for i in range(0, len(group), 8):
-                self._prefill_group(bucket, group[i:i + 8])
+            rows = self._prefill_rows(bucket)
+            for i in range(0, len(group), rows):
+                self._prefill_group(bucket, group[i:i + rows])
+
+    def _prefill_rows(self, bucket: int) -> int:
+        """Rows of one bucketed prefill call: wide admission waves go out 8
+        rows at a time (past that the batched program's marginal win
+        flattens while its padded work and jit-shape surface keep growing),
+        and fewer, a power of two, where the model holds a call to
+        ``prefill_call_tokens`` padded tokens (a row at least)."""
+        limit = getattr(self.executor.model, "prefill_call_tokens", 0)
+        rows = 8
+        while limit and rows > 1 and rows * bucket > limit:
+            rows //= 2
+        return rows
 
     def _prefill_work(self) -> None:
         """Chunked prefill: spend up to ``max_prefill_tokens_per_step``
@@ -1659,8 +1799,9 @@ class InferenceEngine:
         for ch in chunks:
             by_bucket.setdefault(self._bucket_for(len(ch[1])), []).append(ch)
         for bucket, group in by_bucket.items():
-            for i in range(0, len(group), 8):
-                self._run_prefill_batch(bucket, group[i:i + 8])
+            rows = self._prefill_rows(bucket)
+            for i in range(0, len(group), rows):
+                self._run_prefill_batch(bucket, group[i:i + rows])
 
     def _ragged_groups(self, items: List, lengths: List[int]) -> List[tuple]:
         """FCFS ragged packing for multi-admission prefill: ``(width,
@@ -1728,6 +1869,8 @@ class InferenceEngine:
         # handoff adoption of a base request) decode under row 0, the
         # all-zero base adapter.
         self._adapter_ids[slot.slot_id] = max(req._adapter_slot, 0)
+        # Not a decode row until its prefill has handed the slot a state.
+        self._state_slots[slot.slot_id] = self.cfg.max_seqs
         self._mark_state_dirty(slot.slot_id)
         if self._spec_hist is not None:
             ctx = req.prompt_token_ids + req.output_token_ids
@@ -1773,6 +1916,8 @@ class InferenceEngine:
             with tr.span("engine/prefill_wait", cat="engine"):
                 toks = np.asarray(jax.device_get(sampled[0]))
                 lps = np.asarray(jax.device_get(sampled[1]))
+            if self.executor.counter_names:
+                self._count(toks[len(lps):][None, :], decode=False)
             self._prefill_emit(chunks, toks, lps)
 
     def _prefill_emit(self, chunks: List[tuple], toks: np.ndarray,
@@ -1794,6 +1939,8 @@ class InferenceEngine:
         for r, (slot, tokens, start, is_last) in enumerate(chunks):
             if is_last:
                 self._append_token(slot, int(toks[r]), float(lps[r]))
+                if not slot.free:  # (the first token may have ended it)
+                    self._state_slots[slot.slot_id] = slot.slot_id
                 # Prefill completion: the first sampled token bumped the
                 # slot's gen count, and a chunked-mode slot's block-table
                 # row sheds its trash-block masking — either way the row
@@ -1853,19 +2000,44 @@ class InferenceEngine:
             for r, (slot, *_rest) in enumerate(chunks):
                 ad[r] = self._adapter_ids[slot.slot_id]
             lora_args = (jnp.asarray(ad), self.adapter_pool.tree)
-        self.cache, last_logits = self._prefill_fns[bucket](
+        if self.executor._recurrent:
+            # State hand-off: each row names the slot its prefill fills
+            # (a padding row none), and the program writes the state after
+            # the row's last real token there. No host work beyond this
+            # array, hence no span of its own.
+            rows = np.full((B,), ec.max_seqs, np.int32)
+            rows[:len(chunks)] = [c[0].slot_id for c in chunks]
+            lora_args = (jnp.asarray(rows),)
+        self.cache, last_logits, *counters = self._prefill_fns[bucket](
             self.params, self.cache, jnp.asarray(ids), jnp.asarray(pos),
             jnp.asarray(bt), jnp.asarray(last_idx), *lora_args,
         )
+        if counters and self._prefill_counters is not None:
+            counters = [self._prefill_counters + counters[0]]
+            self._prefill_counters = None
         if not any(is_last for *_, is_last in chunks):
+            if counters:
+                self._prefill_counters = counters[0]
             return None
         # Same per-slot key + count stream the decode path uses, folded in
         # one async dispatch (no host round trip per row).
         keys = self._fold_keys(jnp.asarray(slot_keys), jnp.asarray(counts))
-        return self._sample_fn(
+        sample = self._sample_fn
+        if counters:
+            sample = self.executor._sample_counted_fn
+        return sample(
             last_logits, keys, jnp.asarray(temps),
-            jnp.asarray(top_k), jnp.asarray(top_p),
+            jnp.asarray(top_k), jnp.asarray(top_p), *counters,
         )
+
+    def _count(self, counters: np.ndarray, decode: bool) -> None:
+        """Book the model's counters: ``(program calls or decode steps,
+        len(counter_names))``, in the order of ``counter_names``."""
+        totals = counters.astype(np.int64).sum(axis=0)
+        for name, total in zip(self.executor.counter_names, totals):
+            self.stats[name] += int(total)
+            if decode:
+                self.stats[f"{name}_decode"] += int(total)
 
     def _mark_state_dirty(self, slot_id: int) -> None:
         """A scheduling event changed ``slot_id``'s per-slot state mirrors
@@ -1880,7 +2052,8 @@ class InferenceEngine:
                 "gen_counts": self._gen_counts,
                 "temperature": self._temperature,
                 "top_k": self._top_k, "top_p": self._top_p,
-                "adapter_ids": self._adapter_ids}
+                "adapter_ids": self._adapter_ids,
+                "state_slots": self._state_slots}
 
     def _masked_rows(self) -> list:
         return [s.slot_id for s in self.slots if s.prefilling]
@@ -2036,6 +2209,8 @@ class InferenceEngine:
             )
             if self.adapter_pool is not None:
                 state_args += (jnp.asarray(self._adapter_ids),)
+            elif self.executor._recurrent:
+                state_args += (jnp.asarray(self._state_slots),)
         args = (self.params, self.cache, jnp.asarray(ids), jnp.asarray(pos),
                 *state_args)
         if self.adapter_pool is not None:
@@ -2089,6 +2264,10 @@ class InferenceEngine:
         """Numeric guards and the per-slot emission walk of a plain round
         (``tokens``, ``logprobs``: (S, k_steps), on the host)."""
         self.stats["decode_steps"] += k_steps
+        if self.executor.counter_names:
+            # Rows after the slots': each step's counters.
+            self._count(tokens[self.cfg.max_seqs:].T, decode=True)
+            tokens = tokens[:self.cfg.max_seqs]
 
         # Numeric guard — the WHOLE round is validated before any token
         # is appended: a partially-appended round would survive failover
@@ -2400,6 +2579,9 @@ class InferenceEngine:
         self._slot_keys[slot.slot_id] = 0
         self._gen_counts[slot.slot_id] = 0
         self._adapter_ids[slot.slot_id] = 0
+        # The slot's recurrent state is dropped with it: nothing reads it
+        # again, and the next admission starts from zero (position 0).
+        self._state_slots[slot.slot_id] = self.cfg.max_seqs
         self._spec_reset_slot(slot.slot_id)
         self._mark_state_dirty(slot.slot_id)
 
@@ -2421,6 +2603,7 @@ class InferenceEngine:
         sampling left it. Returns None (slot untouched) if any block fetch
         fails — the caller falls back to a re-prefill elsewhere.
         """
+        refuse_state_handoff(self.model_cfg, "export_handoff")
         req = slot.request
         n_blocks = self.block_manager.blocks_needed(slot.seq_len)
         payloads = []
@@ -2459,6 +2642,7 @@ class InferenceEngine:
         the token the origin engine would have. Returns False (nothing
         consumed) when no slot or not enough blocks are free — the caller
         retries or degrades to a re-prefill."""
+        refuse_state_handoff(self.model_cfg, "adopt_handoff")
         slot = next((s for s in self.slots if s.free), None)
         if slot is None:
             return False

@@ -50,7 +50,8 @@ from dlti_tpu.utils.logging import get_logger
 # other numeric stat is a monotonic counter. Name-stability contract: the
 # exposition names are dlti_<key> — scraped by external dashboards, so keys
 # here and in the engine's stats dict must not be renamed.
-_GAUGE_KEYS = ("active_seqs", "waiting", "free_blocks")
+_GAUGE_KEYS = ("active_seqs", "waiting", "free_blocks",
+               "recurrent_state_pool_bytes")
 
 
 def build_registry(async_engine: "AsyncEngine") -> MetricsRegistry:
@@ -68,6 +69,8 @@ def build_registry(async_engine: "AsyncEngine") -> MetricsRegistry:
             "active_seqs": eng.num_active,
             "waiting": len(eng.waiting),
             "free_blocks": eng.num_free_blocks,
+            "recurrent_state_pool_bytes":
+                getattr(eng, "recurrent_state_pool_bytes", 0),
         }
 
     registry.add_scalar_source(_engine_scalars, gauge_keys=_GAUGE_KEYS,

@@ -75,10 +75,16 @@ class EngineWorker:
     # -- lifecycle -----------------------------------------------------
     def close(self) -> None:
         self._stop = True
-        try:
-            self._listener.close()
-        except OSError:
-            pass
+        # shutdown() first: closing a listening socket from another thread
+        # does not wake a serve thread parked in accept() on Linux, so
+        # whether close() ended the thread depended on which of the two
+        # got there first.
+        for end in (lambda: self._listener.shutdown(socket.SHUT_RDWR),
+                    self._listener.close):
+            try:
+                end()
+            except OSError:
+                pass
         # Unblock a serve thread parked in recv on the live connection —
         # without this, close() from another thread (or the in-process
         # test fake's kill path) leaves the worker hung mid-frame.

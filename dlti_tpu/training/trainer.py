@@ -25,7 +25,7 @@ import jax
 import numpy as np
 
 from dlti_tpu.config import Config
-from dlti_tpu.models import LlamaForCausalLM, count_params
+from dlti_tpu.models import LlamaForCausalLM, build_model, count_params
 from dlti_tpu.ops.attention import resolve_flash
 # Submodule imports (not the package) so that `dlti_tpu.parallel` ->
 # `training.state` -> `dlti_tpu.training` (which re-exports Trainer) does
@@ -206,7 +206,7 @@ class Trainer:
         # The model needs the mesh for sequence parallelism: with
         # parallel.sequence > 1 attention runs the ring schedule
         # (dlti_tpu.parallel.ring_attention) over the 'sequence' axis.
-        self.model = model or LlamaForCausalLM(
+        self.model = model or build_model(
             cfg.model, cfg.lora if cfg.lora.enabled else None, self.mesh
         )
         self._step_fn = None

@@ -75,11 +75,11 @@ def build_engine(spec: dict):
         lora_cfg = cfg.lora if cfg.lora.enabled else None
     else:
         from dlti_tpu.config import resolve_model
-        from dlti_tpu.models import LlamaForCausalLM
+        from dlti_tpu.models import build_model
 
         model_cfg = resolve_model(spec["model_preset"])
         lora_cfg = None
-        model = LlamaForCausalLM(model_cfg, None)
+        model = build_model(model_cfg, None)
         params = model.init(jax.random.PRNGKey(0),
                             jnp.zeros((1, 8), jnp.int32))["params"]
 

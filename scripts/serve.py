@@ -435,11 +435,11 @@ def main() -> None:
               f"(layers={model_cfg.num_layers}, hidden={model_cfg.hidden_size})")
     else:
         from dlti_tpu.config import resolve_model
-        from dlti_tpu.models import LlamaForCausalLM
+        from dlti_tpu.models import build_model
 
         model_cfg = resolve_model(args.random_init)
         lora_cfg = None
-        model = LlamaForCausalLM(model_cfg, None)
+        model = build_model(model_cfg, None)
         params = model.init(jax.random.PRNGKey(0),
                             jnp.zeros((1, 8), jnp.int32))["params"]
         print(f"random-initialized preset {args.random_init} "

@@ -105,6 +105,9 @@ def counted(monkeypatch):
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", annotation)
     monkeypatch.setattr(time, "thread_time_ns", cpu_clock)
     monkeypatch.setattr(get_tracer(), "_annotate", None)
+    # Spans an earlier test file of this worker left in the process-global
+    # ring are not this test's: what it asserts is what its own run records.
+    get_tracer().clear()
     return annotation, cpu_clock
 
 
