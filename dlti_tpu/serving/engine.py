@@ -17,8 +17,10 @@ ships (``README.md:10,16``; ``requirements.txt:18``). Architecture, XLA-first:
   (recompute-on-readmit, vLLM's recompute policy).
 * **Fused sampling.** Greedy / temperature / top-k / top-p are per-slot
   *data* (``dlti_tpu.serving.sampling``), sampled inside the compiled decode
-  step — mixed batches never branch. Per-request ``seed`` keys make a
-  request's draw stream independent of batch composition.
+  step — mixed batches never recompile; the one branch inside it (sort the
+  vocabulary or not) is taken on the device from that data. Per-request
+  ``seed`` keys make a request's draw stream independent of batch
+  composition.
 """
 
 from __future__ import annotations
@@ -1130,6 +1132,11 @@ class InferenceEngine:
                       # decode_steps is the mean context one step reads
                       # (what the paged-attention kernel's bytes follow).
                       "decode_context_tokens": 0,
+                      # Decode steps whose sampling sorted the vocabulary:
+                      # some slot's row set top-k or top-p, the predicate
+                      # sample_tokens evaluates on the device (read here
+                      # from the same mirrors). 0 under default traffic.
+                      "decode_steps_sorted_sampling": 0,
                       "prefix_cached_tokens": 0,
                       # Tokens whose KV came back from a LOWER tier (host
                       # or disk) via a restore scatter instead of either
@@ -2055,6 +2062,11 @@ class InferenceEngine:
                 "adapter_ids": self._adapter_ids,
                 "state_slots": self._state_slots}
 
+    def _sampling_sorts(self) -> bool:
+        """Whether a decode step dispatched now takes ``sample_tokens``'s
+        sorted branch: its predicate, over the rows the program is given."""
+        return bool((self._top_k > 0).any() or (self._top_p < 1.0).any())
+
     def _masked_rows(self) -> list:
         return [s.slot_id for s in self.slots if s.prefilling]
 
@@ -2193,6 +2205,8 @@ class InferenceEngine:
             pos[s.slot_id, 0] = s.seq_len  # position of the new token
             context += s.seq_len
         self.stats["decode_context_tokens"] += context * k_steps
+        self.stats["decode_steps_sorted_sampling"] += \
+            k_steps * self._sampling_sorts()
         if self._state_cache is not None:
             # Device-resident per-slot state: only rows dirtied since the
             # last dispatch are shipped; a clean step uploads nothing and
@@ -2397,6 +2411,8 @@ class InferenceEngine:
             seq_len[s.slot_id] = s.seq_len
             context += s.seq_len
         self.stats["decode_context_tokens"] += context * R
+        self.stats["decode_steps_sorted_sampling"] += \
+            R * self._sampling_sorts()
         for s in parts:
             spec_mask[s.slot_id] = True
         # Multi-query attention takes the gather path (the Pallas paged
