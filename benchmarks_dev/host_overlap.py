@@ -8,11 +8,10 @@ layer on both hot paths and emit one JSON artifact.
   cost. The metric is *host stall*: time the step thread blocked waiting
   for a batch (the ``train/batch_fetch`` tracer span). With prefetch on the
   gather overlaps the in-flight step, so the stall collapses toward zero.
-* **Serving**: the engine decodes twice — dirty tracking off (legacy full
-  re-upload every dispatch) vs on (device-resident decode-state cache) —
-  and reports host-prep time per dispatch plus the upload counters,
-  including a controlled steady-state window where the batch composition is
-  fixed and a correct cache must issue ZERO uploads.
+* **Serving**: the engine decodes on its device-resident per-slot state
+  (dirty tracking) and reports host-prep time per dispatch plus the upload
+  counters, including a controlled steady-state window where the batch
+  composition is fixed and a correct cache must issue ZERO uploads.
 
 Run:  JAX_PLATFORMS=cpu python benchmarks_dev/host_overlap.py
 Artifact: results/host_overlap_cpu.json (path override: first CLI arg).
@@ -101,7 +100,7 @@ def bench_training(prefetch_depth: int) -> dict:
     }
 
 
-def bench_serving(cache_on: bool) -> dict:
+def bench_serving() -> dict:
     import jax
     import jax.numpy as jnp
 
@@ -115,7 +114,7 @@ def bench_serving(cache_on: bool) -> dict:
                         jnp.zeros((1, 8), jnp.int32))["params"]
     ec = EngineConfig(max_seqs=4, block_size=64, num_blocks=16,
                       max_model_len=64, cache_dtype="float32",
-                      eos_token_id=-1, decode_state_cache=cache_on)
+                      eos_token_id=-1)
     eng = InferenceEngine(mc, params, ec)
     prompts = [[1, 2, 3, 4], [5, 6, 7], [8, 9, 10, 11], [12, 13]]
     sp = SamplingParams(temperature=0.0, max_tokens=DECODE_TOKENS)
@@ -137,7 +136,6 @@ def bench_serving(cache_on: bool) -> dict:
 
     prep = eng.telemetry.host_prep.summary()
     return {
-        "decode_state_cache": cache_on,
         "decode_steps": eng.stats["decode_steps"],
         "generated_tokens": eng.stats["generated_tokens"],
         "decode_state_uploads": eng.stats["decode_state_uploads"],
@@ -156,8 +154,7 @@ def main() -> int:
         _repo, "results", "host_overlap_cpu.json")
     train_off = bench_training(prefetch_depth=0)
     train_on = bench_training(prefetch_depth=2)
-    serve_off = bench_serving(cache_on=False)
-    serve_on = bench_serving(cache_on=True)
+    serve_on = bench_serving()
     stall_off, stall_on = train_off["host_stall_s"], train_on["host_stall_s"]
     report = {
         "benchmark": "host_overlap_cpu",
@@ -168,10 +165,7 @@ def main() -> int:
             "stall_reduction": round(1.0 - stall_on / stall_off, 4)
             if stall_off > 0 else 0.0,
         },
-        "serving": {
-            "reupload": serve_off,
-            "dirty_tracking": serve_on,
-        },
+        "serving": {"dirty_tracking": serve_on},
     }
     os.makedirs(os.path.dirname(out_path), exist_ok=True)
     with open(out_path, "w") as f:

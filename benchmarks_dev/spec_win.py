@@ -1,5 +1,4 @@
-"""Adaptive speculative decoding A/B: favorable AND adversarial traces,
-plus the ragged multi-admission prefill TTFT wave.
+"""Adaptive speculative decoding A/B: favorable AND adversarial traces.
 
 Methodology fixes over the r03 version (whose committed artifact
 recorded a 0.103 "speedup"): the measured window previously included
@@ -20,14 +19,12 @@ Traces:
 * **adversarial** — prompts whose continuation wanders: near-zero
   acceptance, so the per-slot gate pauses speculation and the ladder
   collapses toward k=1; the claim is bounded overhead, not a win.
-* **ragged wave** — a burst of mixed-length admissions, prefill TTFT
-  p99 with ragged packing on vs off at byte-identical outputs.
 
 Usage:
   python benchmarks_dev/spec_win.py --cpu            # llama_tiny check
   python benchmarks_dev/spec_win.py                  # real chip, export
   python benchmarks_dev/spec_win.py --cpu --runs 1 --max-tokens 48 \
-      --wave 8 --json-out /tmp/x.json                # CI smoke shape
+      --json-out /tmp/x.json                         # CI smoke shape
 """
 
 import argparse
@@ -50,8 +47,6 @@ def main():
     ap.add_argument("--max-tokens", type=int, default=160)
     ap.add_argument("--sync", type=int, default=8)
     ap.add_argument("--draft", type=int, default=6)
-    ap.add_argument("--wave", type=int, default=24,
-                    help="requests in the ragged-prefill admission wave")
     ap.add_argument("--json-out", default="")
     args = ap.parse_args()
 
@@ -159,77 +154,19 @@ def main():
             "outputs_equal": True,
         }
 
-    # ------------------------------------------------------------------
-    # Ragged multi-admission prefill: TTFT over an admission wave
-    # ------------------------------------------------------------------
-    # Lengths straddling four pow2 buckets: under a chunked-prefill token
-    # budget every step carries chunks from several admissions in several
-    # buckets — the bucketed path pays one program call per bucket per
-    # step, ragged packing merges them, so each step (and therefore every
-    # queued request's first token) lands sooner.
-    rng = np.random.RandomState(0)
-    wave_lens = [(5, 9, 17, 33)[i % 4] for i in range(args.wave)]
-    wave_prompts = [
-        [int(t) for t in rng.randint(2, cfg.vocab_size - 2, size=n)]
-        for n in wave_lens]
-    wave_sp = SamplingParams(temperature=0.0, max_tokens=8)
-
-    def ttft_wave(ragged: bool):
-        ec = EngineConfig(
-            max_seqs=max(8, args.wave), block_size=16, num_blocks=512,
-            max_model_len=128, eos_token_id=-1,
-            cache_dtype="float32" if args.cpu else "bfloat16",
-            max_prefill_tokens_per_step=64,
-            ragged_prefill=ragged)
-        eng = InferenceEngine(cfg, params, ec)
-        eng.generate(wave_prompts, wave_sp)  # compile warmup
-        p99s, p50s, toks = [], [], None
-        for _ in range(args.runs):
-            reqs = [eng.submit(p, wave_sp) for p in wave_prompts]
-            first = {}
-            t0 = time.perf_counter()
-            while eng.has_work:
-                eng.step()
-                now = time.perf_counter()
-                for r in reqs:
-                    if r.output_token_ids and r.request_id not in first:
-                        first[r.request_id] = now - t0
-            lat = sorted(first.values())
-            p99s.append(float(np.percentile(lat, 99)))
-            p50s.append(float(np.percentile(lat, 50)))
-            toks = [r.output_token_ids for r in reqs]
-        return (statistics.median(p99s), statistics.median(p50s), toks,
-                eng.stats["prefill_batches"])
-
-    p99_off, p50_off, toks_off, batches_off = ttft_wave(False)
-    p99_on, p50_on, toks_on, batches_on = ttft_wave(True)
-    assert toks_on == toks_off, "ragged packing changed outputs"
-
     out = {
         "what": "adaptive speculation (per-slot gate + draft-length "
                 "ladder) vs plain multi-step at the same steps_per_sync, "
-                "on favorable AND adversarial traces; plus ragged "
-                "multi-admission prefill TTFT",
+                "on favorable AND adversarial traces",
         "platform": "cpu/llama_tiny" if args.cpu else f"tpu/{args.export}",
         "steps_per_sync": args.sync, "num_draft_tokens": args.draft,
         "max_tokens": args.max_tokens, "runs": args.runs,
         "favorable": trace("favorable", favorable),
         "adversarial": trace("adversarial", adversarial),
-        "ragged_prefill": {
-            "wave_requests": args.wave,
-            "ttft_p99_s_off": round(p99_off, 4),
-            "ttft_p99_s_on": round(p99_on, 4),
-            "ttft_p50_s_off": round(p50_off, 4),
-            "ttft_p50_s_on": round(p50_on, 4),
-            "prefill_batches_off": batches_off,
-            "prefill_batches_on": batches_on,
-            "outputs_equal": True,
-        },
         "date": time.strftime("%Y-%m-%d"),
     }
     out["outputs_equal"] = (out["favorable"]["outputs_equal"]
-                            and out["adversarial"]["outputs_equal"]
-                            and out["ragged_prefill"]["outputs_equal"])
+                            and out["adversarial"]["outputs_equal"])
     name = args.json_out or ("results/spec_adaptive_cpu.json" if args.cpu
                              else "results/spec_adaptive.json")
     with open(name, "w") as f:

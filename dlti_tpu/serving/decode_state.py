@@ -1,21 +1,19 @@
-"""Device-resident decode-state cache — the serving half of the
-host-latency-hiding layer.
+"""Device-resident decode state — the serving half of the
+host-latency-hiding layer, owned by ``EngineExecutor``.
 
-The engine used to re-upload its *entire* per-slot decode state — block
-tables (rebuilt as a fresh numpy array in ``_decode_block_tables``), slot
-keys, gen counts, temperature/top-k/top-p — via ``jnp.asarray`` on every
-decode dispatch, even though a typical step dirties only a handful of slots
-(an admission, a retirement, a block-table row growing by one). This class
-keeps those six arrays as persistent device arrays and maintains them
-*incrementally*, vLLM-style (Kwon et al., SOSP 2023: incremental scheduler
-state is what keeps decode host overhead flat as batch size grows):
+The scheduler's per-slot mirrors — block tables, slot keys, gen counts,
+temperature/top-k/top-p, and one more where the programs take it (adapter
+ids or recurrent state slots) — live here as persistent device arrays,
+maintained *incrementally*, vLLM-style (Kwon et al., SOSP 2023:
+incremental scheduler state is what keeps decode host overhead flat as
+batch size grows); a typical step dirties only a handful of slots (an
+admission, a retirement, a block-table row growing by one):
 
-* The engine marks a slot dirty at admission, release (retire / preempt /
-  abort), block-table growth, and prefill completion. :meth:`sync` then
-  scatters just the dirty rows into the device arrays (one fused jitted
-  update, row count padded to a power of two so the compile surface stays
-  O(log max_seqs)) — ``_decode_block_tables``'s full rebuild becomes an
-  in-place row update.
+* The scheduler marks a slot dirty at admission, release (retire /
+  preempt / abort), block-table growth, and prefill completion.
+  :meth:`sync` then scatters just the dirty rows into the device arrays
+  (one fused jitted update, row count padded to a power of two so the
+  compile surface stays O(log max_seqs)).
 * A **clean step uploads nothing**: every decode dispatch between
   scheduling events reuses the resident arrays as-is (asserted in tier-1:
   ``tests/test_host_overlap.py``).
@@ -25,14 +23,14 @@ state is what keeps decode host overhead flat as batch size grows):
   was released, which marks it dirty). No host→device traffic for the one
   mirror that changes every single step.
 * Prefilling slots' block-table rows are masked to the trash block at
-  upload time (same invariant as the legacy rebuild): a decode program can
-  never scribble on KV a partially-prefilled slot has written.
+  upload time: a decode program can never scribble on KV a
+  partially-prefilled slot has written.
 
-The speculative path keeps the legacy re-upload (it ships the full token
+The speculative path ships the mirrors whole (it uploads the full token
 history anyway); a spec round calls :meth:`mark_all_dirty` so the next
-plain dispatch resynchronizes. Outputs are byte-identical to the re-upload
-path — for every *active* slot the resident rows equal the host mirrors at
-each dispatch (equivalence-tested, including across preemption and
+plain dispatch resynchronizes. For every *active* slot the resident rows
+equal the host mirrors at each dispatch (tier-1 holds the outputs to
+references that do not share this path, including across preemption and
 re-admission).
 
 Updates deliberately do **not** donate the old arrays: they are KB-scale,
@@ -54,16 +52,14 @@ _FIELDS = ("block_tables", "slot_keys", "gen_counts",
 
 
 class DecodeStateCache:
-    """Persistent device twins of the engine's per-slot host mirrors."""
+    """Persistent device twins of the scheduler's per-slot host mirrors."""
 
     def __init__(self, num_slots: int, device=None, mesh=None,
                  stats: Optional[dict] = None,
                  extra_fields: Sequence[str] = ()):
-        # Optional extra per-slot mirrors (e.g. the multi-LoRA path's
-        # "adapter_ids") ride APPENDED after the base six, so the
-        # positional invariants below — block_tables at index 0 (masked
-        # for prefilling rows), gen_counts at index 2 (bumped on device)
-        # — hold regardless.
+        # The extra per-slot mirror the executor names rides after the
+        # base six, so block_tables stays at index 0 (masked for
+        # prefilling rows) and gen_counts at index 2 (bumped on device).
         self._fields = _FIELDS + tuple(extra_fields)
         self._num_slots = num_slots
         self._device = device
@@ -100,8 +96,8 @@ class DecodeStateCache:
         self._dirty.add(slot_id)
 
     def mark_all_dirty(self) -> None:
-        """Resident state is stale wholesale (a spec round ran, or the
-        legacy path was used); re-upload everything at the next sync."""
+        """Resident state is stale wholesale (a spec round ran);
+        re-upload everything at the next sync."""
         self._all_dirty = True
 
     # ------------------------------------------------------------------

@@ -21,37 +21,33 @@ ships (``README.md:10,16``; ``requirements.txt:18``). Architecture, XLA-first:
   vocabulary or not) is taken on the device from that data. Per-request
   ``seed`` keys make a request's draw stream independent of batch
   composition.
+
+This module is the scheduler half and imports no jax: it plans each round in
+host (numpy) arrays and hands them, by name, to
+:class:`dlti_tpu.serving.executor.EngineExecutor`, which owns every device
+array and every program's calling convention.
 """
 
 from __future__ import annotations
 
 import collections
 import itertools
-import json
 import time
 from dataclasses import dataclass, field
-from functools import partial
 from typing import Dict, List, Optional, Sequence
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from dlti_tpu.config import LoRAConfig, ModelConfig
-from dlti_tpu.models import build_model
-from dlti_tpu.ops.kv_cache import bind_call, init_cache, unbind_call
 from dlti_tpu.serving.adapters import AdapterError
 from dlti_tpu.serving.block_manager import BlockManager
-from dlti_tpu.serving.sampling import SamplingParams, sample_tokens
+from dlti_tpu.serving.executor import EngineExecutor
+from dlti_tpu.serving.sampling import SamplingParams
 from dlti_tpu.telemetry import RequestTelemetry
 from dlti_tpu.telemetry.distributed_trace import mint_trace_id
 from dlti_tpu.telemetry.flightrecorder import get_recorder
-from dlti_tpu.telemetry.memledger import (
-    MemoryLedger, is_oom_error, tree_nbytes,
-)
+from dlti_tpu.telemetry.memledger import MemoryLedger, is_oom_error
 from dlti_tpu.utils.logging import get_logger
-from dlti_tpu.utils.native import native_runtime_name
-from dlti_tpu.utils.platform import device_facts
 
 # Speculative-decode /metrics names (registered by server.build_registry's
 # spec scalar source; the engine's stats dict stays the source of truth).
@@ -153,22 +149,6 @@ class EngineConfig:
     # this only trades verify-forward width for wasted lanes. False pins
     # dispatch at k=num_draft_tokens (the pre-ladder behavior).
     spec_adaptive: bool = True
-    # Ragged multi-admission prefill: instead of grouping prefill chunks
-    # by their own pow2 bucket (each group padded to its widest member's
-    # bucket), pack chunks from many admissions FCFS into shared groups
-    # bounded by padded total tokens — one prefill call advances several
-    # admissions. Rows keep their own block tables and last-token
-    # indices, so outputs are byte-identical ragged on/off; the win is
-    # fewer program dispatches (and fewer distinct jit specializations)
-    # under a multi-admission wave.
-    ragged_prefill: bool = False
-    # Device-resident decode state (dlti_tpu.serving.decode_state): block
-    # tables, slot keys, gen counts, and sampling params live as
-    # persistent device arrays maintained incrementally with per-slot
-    # dirty tracking — a clean decode step uploads nothing. False falls
-    # back to the legacy full re-upload (jnp.asarray of every mirror,
-    # every step); outputs are byte-identical either way.
-    decode_state_cache: bool = True
     # Chunked prefill (the vLLM latency lever the throughput headline
     # lacks): cap prompt tokens prefilled per engine step, so admission
     # never stalls running decodes for a whole prompt length — partially
@@ -231,6 +211,21 @@ class EngineConfig:
     @property
     def max_blocks_per_seq(self) -> int:
         return -(-self.max_model_len // self.block_size)
+
+    @property
+    def spec_rounds(self) -> int:
+        """Propose→verify→accept rounds in one speculative program call:
+        ``steps_per_sync`` of them, so speculation and multi-step decode
+        are one composed program, not alternatives."""
+        return max(1, self.steps_per_sync)
+
+    @property
+    def spec_hist_width(self) -> int:
+        """Columns of a token-history row (speculative decode): positions
+        0..max_model_len-1, the draft positions past them, one slack cell
+        for the in-flight input token, one scratch cell absorbing masked
+        scatter writes."""
+        return self.max_model_len + self.num_draft_tokens + 2
 
 
 class NumericFault(RuntimeError):
@@ -353,47 +348,6 @@ class _Slot:
         return self.request is not None and self.next_pos < self.prefill_end
 
 
-def refuse_unsupported(model_cfg: ModelConfig, engine_cfg: "EngineConfig",
-                       mesh=None) -> None:
-    """Refuse, at start-up and with one clear error each, every feature
-    that takes a sequence's state to be its k/v blocks when the model has
-    recurrent layers, and what the patterned families do not implement."""
-    if not model_cfg.layer_pattern:
-        return
-    ec = engine_cfg
-    what = f"a model with layer_pattern {model_cfg.layer_pattern!r}"
-    if model_cfg.has_recurrent_state:
-        why = (f"{what} keeps a recurrent state per decode slot beside its "
-               f"k/v blocks, and ")
-        if (ec.enable_prefix_caching or ec.prefix_host_blocks > 0
-                or ec.prefix_disk_blocks > 0):
-            raise ValueError(
-                why + "prefix caching (and its host/disk tiers) reuses k/v "
-                "blocks alone: a cached prefix would resume from the wrong "
-                "state. Serve it without --enable-prefix-caching; state "
-                "snapshots for prefix reuse are not implemented")
-    if ec.speculative != "none":
-        raise ValueError(
-            f"{what} cannot be served with speculative decoding: rejected "
-            f"drafts are rolled back by position in the k/v cache, and a "
-            f"recurrent state cannot be rolled back (nor does the "
-            f"speculative program thread it). Serve it with --speculative "
-            f"none")
-    if mesh is not None:
-        raise ValueError(
-            f"{what} has no tensor-parallel sharding rules (Mamba-2 and "
-            f"held-expert layers); serve it on one chip per replica")
-    if ec.quantization != "none":
-        raise ValueError(
-            f"{what} is served in its own precision: weight-only "
-            f"{ec.quantization} is not implemented for Mamba-2 and expert "
-            f"layers")
-    if ec.adapter_slots > 0:
-        raise ValueError(
-            f"{what} has no multi-LoRA adapter branch; serve it with "
-            f"--adapter-slots 0")
-
-
 def refuse_state_handoff(model_cfg: ModelConfig, what: str) -> None:
     """Disaggregated serving and k/v hand-off move a sequence as its k/v
     blocks; a recurrent state is not in them."""
@@ -404,581 +358,6 @@ def refuse_state_handoff(model_cfg: ModelConfig, what: str) -> None:
             f"keeps a recurrent state per decode slot, which the hand-off "
             f"does not carry. Serve it colocated (no --disagg)")
 
-
-class EngineExecutor:
-    """The device half of the engine: weights, paged-KV pools, and every
-    compiled program (bucketed prefill, the decode ladder, speculative
-    decode, fused sampling, the tier-restore scatter), plus the
-    device<->host block transport (:meth:`fetch_block_kv` /
-    :meth:`restore_block`).
-
-    Holds NO scheduling state — slots, queues, block accounting,
-    admission, and retirement live in :class:`InferenceEngine`, which
-    assembles host-side batches and calls in. The split is what
-    disaggregated serving (``serving.disagg``) builds on: a prefill-only
-    engine's executor never runs (or warms) the decode ladder, and
-    paged-KV handoff between pools talks to the executor's block
-    transport directly.
-    """
-
-    def __init__(
-        self,
-        model_cfg: ModelConfig,
-        params,
-        engine_cfg: EngineConfig = EngineConfig(),
-        lora_cfg: Optional[LoRAConfig] = None,
-        mesh=None,
-        donate_params: bool = False,
-    ):
-        self.cfg = engine_cfg
-        self.model_cfg = model_cfg
-        self.logger = get_logger()
-        self.mesh = mesh
-        if mesh is not None:
-            # Tensor-parallel serving: weights and KV pools shard over the
-            # 'tensor' axis (attention heads / MLP hidden / vocab); GSPMD
-            # inserts the collectives in the jitted prefill/decode programs.
-            # Other axes stay 1 — batch-level scaling is a replica concern.
-            bad = [ax for ax, n in mesh.shape.items()
-                   if n > 1 and ax != "tensor"]
-            if bad:
-                raise ValueError(
-                    f"serving mesh may only extend the 'tensor' axis; got "
-                    f"{dict(mesh.shape)} (axes {bad} > 1)")
-            tp = mesh.shape["tensor"]
-            if model_cfg.num_kv_heads % tp or model_cfg.num_heads % tp:
-                raise ValueError(
-                    f"tensor={tp} must evenly divide num_heads="
-                    f"{model_cfg.num_heads} and num_kv_heads="
-                    f"{model_cfg.num_kv_heads}")
-        refuse_unsupported(model_cfg, engine_cfg, mesh)
-        self.model = build_model(model_cfg, lora_cfg, mesh)
-        # A model may count what its forward pass did (``counter_names``:
-        # int32 scalars it returns, by name, with ``return_counters``);
-        # every program then returns them as rows after its tokens, and
-        # the engine books them in ``stats`` under their names. A model
-        # with recurrent layers keeps a per-slot state beside the paged
-        # cache, and every program takes each row's slot (``state_slots``)
-        # as the last of its per-slot arguments.
-        self.counter_names = tuple(getattr(self.model, "counter_names", ()))
-        self._recurrent = model_cfg.has_recurrent_state
-        self._quantized = engine_cfg.quantization == "int8"
-        if engine_cfg.quantization not in ("none", "int8"):
-            raise ValueError(f"unknown quantization {engine_cfg.quantization!r}")
-        if self._quantized:
-            # Composes with TP: the sharding rules match quantized
-            # {"q","scale"} leaves on the kernel's own path (int8 kernels
-            # shard like their fp ancestors; scales follow the output
-            # channels and replicate for row-parallel kernels).
-            # donate_params frees each source leaf as it quantizes — at 7B
-            # the bf16 and int8 trees cannot coexist in one chip's HBM.
-            from dlti_tpu.models.quantization import quantize_params_int8
-
-            params = quantize_params_int8(params, donate=donate_params)
-        self._device = None
-        if mesh is None:
-            # Pin host-resident weights to a serving device once.
-            # Checkpoint restores hand back numpy arrays; without this
-            # every compiled call re-uploads the whole tree. Leaves that
-            # are already committed jax.Arrays keep
-            # their placement — ReplicatedEngine pins each replica's copy
-            # to its own device before construction — and that device
-            # becomes THE engine device: the KV pool is committed to it
-            # too (below), so warmup's AOT lowering and every compiled
-            # call agree on placement instead of relying on jit's
-            # uncommitted-operand migration.
-            dev = next((d for leaf in jax.tree_util.tree_leaves(params)
-                        if isinstance(leaf, jax.Array)
-                        and getattr(leaf, "committed", False)
-                        for d in leaf.devices()), jax.devices()[0])
-            self._device = dev
-            params = jax.tree_util.tree_map(
-                lambda x: x if isinstance(x, jax.Array)
-                and getattr(x, "committed", False)
-                else jax.device_put(x, dev), params)
-        self.params = params
-
-        # Multi-LoRA adapter pool: stacked per-module A/B tensors the
-        # compiled programs gather per batch row (serving.adapters). Built
-        # AFTER quantization/placement so the target-shape walk sees the
-        # final param layout (int8 kernels keep their shape in "q") and
-        # the pool lands on the engine device alongside the weights.
-        self.adapter_pool = None
-        if engine_cfg.adapter_slots > 0:
-            from dlti_tpu.serving.adapters import AdapterPool
-
-            self.adapter_pool = AdapterPool(
-                self.params, engine_cfg.adapter_slots,
-                engine_cfg.adapter_rank, engine_cfg.adapter_targets,
-                device=self._device, mesh=mesh)
-
-        ec = engine_cfg
-        from dlti_tpu.utils.dtypes import resolve_dtype
-
-        # "int8" selects the quantized pool layout (int8 payload +
-        # per-row fp32 scales — ops.kv_cache): half the KV HBM of bf16,
-        # which buys roughly twice the decode slots on a fixed chip.
-        dtype = "int8" if ec.cache_dtype == "int8" else resolve_dtype(ec.cache_dtype)
-        # One cache, one entry a layer: block pools of keys and values for
-        # attention layers, per-slot recurrent state for Mamba-2 layers.
-        self.cache = init_cache(model_cfg, ec.num_blocks, ec.block_size,
-                                ec.max_seqs, dtype)
-        if mesh is not None:
-            self._shard_for_tp(mesh)
-        elif self._device is not None:
-            # Commit the pool to the engine device (see the params pin
-            # above): a replica off the default device otherwise starts
-            # with a device-0 pool that only migrates on first dispatch.
-            self.cache = jax.device_put(self.cache, self._device)
-
-        self._restore_fn = None  # lazily-jitted tier/handoff restore scatter
-        # Block fetches stage device→host through pinned_host when the
-        # backend exposes it — the ZeRO-3 offload path. Both prefix-tier
-        # demotion and disaggregated KV handoff use it.
-        self._demote_sharding = None
-        dev = self._device or jax.devices()[0]
-        memory_kinds = sorted(m.kind for m in dev.addressable_memories())
-        if "pinned_host" in memory_kinds:
-            from jax.sharding import SingleDeviceSharding
-
-            self._demote_sharding = SingleDeviceSharding(
-                dev, memory_kind="pinned_host")
-
-        # One line, once per engine: where it runs and what each attention
-        # site resolved to (chip_smoke.py and operators read it).
-        from dlti_tpu.ops.attention import resolve_paged_decode
-
-        decode_path, decode_why = resolve_paged_decode(
-            model_cfg.paged_attention_impl,
-            tp_sharded=mesh is not None and mesh.shape["tensor"] > 1)
-        own = list(mesh.devices.flat) if mesh is not None else [dev]
-        self.logger.info("engine build: %s", json.dumps({
-            **device_facts(),
-            "engine_devices": [str(d) for d in own],
-            # Weights and pool are placed: what each of THIS engine's
-            # chips holds now (null on the CPU backend — no stats).
-            "device_bytes_in_use": {
-                str(d): (d.memory_stats() or {}).get("bytes_in_use")
-                for d in own},
-            "model_layers": model_cfg.num_layers,
-            "param_dtype": ("int8" if self._quantized
-                            else model_cfg.param_dtype),
-            "kv_cache_dtype": ec.cache_dtype,
-            "prefill_attention": "xla",
-            "prefill_attention_reason":
-                "prefill attends over the gathered paged window",
-            "paged_decode": decode_path,
-            "paged_decode_reason": decode_why,
-            "host_staging": ("pinned_host" if self._demote_sharding
-                             is not None else "none"),
-            "memory_kinds": memory_kinds,
-            "block_allocator": native_runtime_name(),
-        }, sort_keys=True))
-
-        self._prefill_fns: Dict[int, callable] = {}
-        self._decode_fn = self._build_decode_fn()
-        # Multi-step decode programs, one per window length on the halving
-        # ladder (K, K//2, ..., 1; see _window_steps) — compiled lazily on
-        # first use. Bounded at ~log2(K)+1 variants.
-        self._multi_decode_fns: Dict[int, callable] = {}
-        # Speculative program: rounds = steps_per_sync (>=1), so spec and
-        # multi-step are one composed program, not alternatives.
-        self._spec_rounds = max(1, ec.steps_per_sync)
-        # Token-history rows: positions 0..max_model_len-1, one slack cell
-        # for the in-flight input token, one scratch cell absorbing masked
-        # scatter writes (see _build_spec_decode_fn).
-        self._spec_hist_width = ec.max_model_len + ec.num_draft_tokens + 2
-        self._spec_fn = (
-            self._build_spec_decode_fn(ec.num_draft_tokens, self._spec_rounds)
-            if ec.speculative == "ngram" else None)
-        # Draft-length ladder (spec_adaptive): one spec program per pow2 k
-        # on the halving ladder, compiled lazily on first dispatch at that
-        # k. The max-k program above is eagerly built (it doubles as the
-        # "speculation is on" sentinel) and seeds the ladder dict.
-        self._spec_fns: Dict[int, callable] = (
-            {ec.num_draft_tokens: self._spec_fn}
-            if self._spec_fn is not None else {})
-        if ec.speculative not in ("none", "ngram"):
-            raise ValueError(f"unknown speculative mode {ec.speculative!r}")
-        self._sample_fn = jax.jit(sample_tokens)
-        if self.counter_names:
-            # First tokens of a prefill with the prefill program's counters
-            # as rows after them: one fetch brings both.
-            def sample_counted(logits, keys, temperature, top_k, top_p,
-                               counters):
-                tokens, logprobs = sample_tokens(logits, keys, temperature,
-                                                 top_k, top_p)
-                return jnp.concatenate([tokens, counters]), logprobs
-
-            self._sample_counted_fn = jax.jit(sample_counted)
-
-        # Batched per-slot key folding (the same fold the decode program
-        # applies to raw uint32 key data): one async dispatch instead of a
-        # synchronous device round trip per admitted row.
-        self._fold_keys = jax.jit(jax.vmap(jax.random.fold_in))
-
-    # ------------------------------------------------------------------
-    def _shard_for_tp(self, mesh) -> None:
-        """Place weights and KV pools on the TP mesh.
-
-        Params follow the training TP rules (column/row-parallel
-        projections, sharded vocab); each layer's K/V pool shards its
-        kv_heads dim. Block tables and sampling state stay replicated.
-        """
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from dlti_tpu.config import Config, ParallelConfig
-        from dlti_tpu.parallel.sharding import param_shardings
-
-        cfg = Config(model=self.model_cfg,
-                     parallel=ParallelConfig(tensor=mesh.shape["tensor"]))
-        p_sh = param_shardings(self.params, cfg, mesh)
-        self.params = jax.tree_util.tree_map(jax.device_put, self.params, p_sh)
-        kv_sh = NamedSharding(mesh, P(None, None, "tensor", None))
-        scale_sh = NamedSharding(mesh, P(None, None, "tensor"))
-        self.cache = [
-            {k: jax.device_put(v, scale_sh if k.endswith("_scale") else kv_sh)
-             for k, v in l.items()}
-            for l in self.cache
-        ]
-
-    # ------------------------------------------------------------------
-    # Compiled programs
-    # ------------------------------------------------------------------
-    def _model_cache_call(self, params, cache_kv, block_tables, input_ids,
-                          positions, adapter_ids=None, adapters=None,
-                          state_slots=None, own_rows: bool = False):
-        """Run the model over the cache; returns ``(logits, new cache list,
-        counters)``. ``state_slots`` (a model with recurrent layers): each
-        row's decode slot, out of range for a row that must write no
-        recurrent state; ``own_rows`` says that this is a decode call, in
-        which row i is slot i. ``counters`` is a vector in the order of
-        ``self.counter_names``, or None for a model that counts nothing.
-
-        Quantized params pass through as-is — each module dequantizes its
-        own weights at the consumer (``models.quantization.maybe_dequantize``),
-        so only the executing layer holds a compute-dtype copy even inside
-        the multi-step decode scan.
-
-        With a multi-LoRA pool, ``adapters`` (the stacked A/B tree) rides
-        in as a Flax variable collection and ``adapter_ids`` (one pool row
-        per batch row) gathers each row's factors inside LoRADense; both
-        absent leaves the traced program identical to an adapter-free
-        engine (the branch is Python-static)."""
-        cache = bind_call(cache_kv, block_tables, state_slots, own_rows)
-        variables = {"params": params}
-        kw = {}
-        if adapters is not None:
-            variables["adapters"] = adapters
-            kw["adapter_ids"] = adapter_ids
-        if self.counter_names:
-            kw["return_counters"] = True
-        logits, new_cache, *counted = self.model.apply(
-            variables, input_ids, positions=positions, cache=cache,
-            deterministic=True, **kw,
-        )
-        counters = jnp.stack([counted[0][n] for n in self.counter_names]) \
-            if counted else None
-        return logits, unbind_call(new_cache), counters
-
-    def _named(self, extra: tuple) -> dict:
-        """What follows the six per-slot state arrays in a program's
-        arguments, by the name ``_model_cache_call`` knows it under:
-        ``(adapter_ids, adapters)`` with a multi-LoRA pool,
-        ``(state_slots,)`` for a model with recurrent layers (the two are
-        never on together: ``refuse_unsupported``), else nothing — and the
-        traced program is the one it always was."""
-        if self._recurrent:
-            return {"state_slots": extra[0]}
-        return dict(zip(("adapter_ids", "adapters"), extra))
-
-    def prefill_fn(self, bucket: int):
-        """The compiled prefill program for a suffix bucket (lazily built)."""
-        fn = self._prefill_fns.get(bucket)
-        if fn is None:
-            fn = self._prefill_fns[bucket] = self._build_prefill_fn(bucket)
-        return fn
-
-    def _build_prefill_fn(self, bucket: int):
-        @partial(jax.jit, donate_argnums=(1,))
-        def prefill(params, cache_kv, input_ids, positions, block_table,
-                    last_idx, *lora):
-            # input_ids/positions: (B, bucket); block_table: (B, nblk) —
-            # sliced so attention's gathered window is bucket-sized, not
-            # max_model_len-sized. B > 1 batches several admissions into
-            # one program call (padding rows carry position -1, whose
-            # writes slot_mapping drops); last_idx (B,) selects each
-            # row's final real logit. With a multi-LoRA pool, *lora is
-            # (adapter_ids, adapters) — per-row adapter gather; empty
-            # otherwise (the traced program is then unchanged).
-            logits, new_kv, counters = self._model_cache_call(
-                params, cache_kv, block_table, input_ids, positions,
-                **self._named(lora))
-            last = jnp.take_along_axis(
-                logits, last_idx[:, None, None], axis=1)[:, 0]
-            if counters is not None:  # a model that counts (Python-static)
-                return new_kv, last, counters
-            return new_kv, last
-
-        return prefill
-
-    def _build_decode_fn(self):
-        @partial(jax.jit, donate_argnums=(1,))
-        def decode(params, cache_kv, input_ids, positions, block_tables,
-                   slot_keys, gen_counts, temperature, top_k, top_p, *lora):
-            # input_ids/positions: (S, 1); block_tables: (S, max_blocks).
-            # *lora: (adapter_ids, adapters) when the multi-LoRA pool is
-            # on (adapter_ids rides in decode-state argument order, the
-            # pool tree LAST so state threading stays contiguous).
-            logits, new_kv, counters = self._model_cache_call(
-                params, cache_kv, block_tables, input_ids, positions,
-                **self._named(lora), own_rows=True)
-            rngs = jax.vmap(jax.random.fold_in)(slot_keys, gen_counts)
-            tokens, logprobs = sample_tokens(
-                logits[:, 0, :], rngs, temperature, top_k, top_p
-            )
-            if counters is not None:
-                # The model's counters ride as rows after the slots'
-                # tokens: the fetch that exists brings them.
-                tokens = jnp.concatenate([tokens, counters])
-            return new_kv, tokens, logprobs
-
-        return decode
-
-    @staticmethod
-    def _aot_or_jit(compiled, jit_fn):
-        """Dispatch through an AOT executable, permanently falling back to
-        the jit path the first time the executable REJECTS the inputs
-        (aval/sharding drift — should not happen with the engine's static
-        decode shapes, but a warmup must never be able to break serving).
-        Only input-validation errors raised BEFORE execution (so no
-        donated buffer is consumed) trigger the fallback: TypeError, and
-        the sharding-mismatch ValueError (e.g. a replica pinned off the
-        default device meeting an executable compiled for it). A runtime
-        failure mid-execution may already have consumed the donated KV
-        cache, so retrying via jit would only mask the real error with
-        'Array has been deleted' — let it propagate."""
-        state = {"aot": True}
-
-        def _is_input_rejection(e: Exception) -> bool:
-            return isinstance(e, TypeError) or (
-                isinstance(e, ValueError)
-                and "Compiled object called with input sharding" in str(e))
-
-        def call(*a):
-            if state["aot"]:
-                try:
-                    return compiled(*a)
-                except (TypeError, ValueError) as e:
-                    if not _is_input_rejection(e):
-                        raise
-                    state["aot"] = False
-                    get_logger().warning(
-                        "AOT decode executable rejected inputs (%s); "
-                        "falling back to jit dispatch permanently", e)
-            return jit_fn(*a)
-
-        call._aot_state = state  # test hook: did dispatch stay on the AOT path?
-        call._jit_fn = jit_fn    # warmup idempotency: the lowerable fn
-        return call
-
-    def _build_multi_decode_fn(self, num_steps: int):
-        """K decode iterations in one program: the sampled token feeds the
-        next forward inside a lax.scan; the host syncs once per K tokens.
-
-        The per-slot rng stream (fold_in(key, gen_count)) advances exactly
-        as in single-step decode, so results are identical for a given
-        request regardless of steps_per_sync.
-        """
-        @partial(jax.jit, donate_argnums=(1,))
-        def decode_multi(params, cache_kv, input_ids, positions, block_tables,
-                         slot_keys, gen_counts, temperature, top_k, top_p,
-                         *lora):
-            def body(carry, _):
-                cache, tok, pos, cnt = carry
-                logits, new_kv, counters = self._model_cache_call(
-                    params, cache, block_tables, tok, pos,
-                    **self._named(lora), own_rows=True)
-                rngs = jax.vmap(jax.random.fold_in)(slot_keys, cnt)
-                nxt, lp = sample_tokens(
-                    logits[:, 0, :], rngs, temperature, top_k, top_p)
-                out = nxt if counters is None \
-                    else jnp.concatenate([nxt, counters])
-                return (new_kv, nxt[:, None], pos + 1, cnt + 1), (out, lp)
-
-            (new_kv, _, _, _), (toks, lps) = jax.lax.scan(
-                body, (cache_kv, input_ids, positions, gen_counts),
-                None, length=num_steps)
-            # (K, S) -> (S, K); with counters: (S + counters, K)
-            return new_kv, toks.T, lps.T
-
-        return decode_multi
-
-    def _build_spec_decode_fn(self, k: int, rounds: int):
-        """``rounds`` propose→verify→accept iterations in ONE program.
-
-        Each round, entirely on device (no host round-trip between rounds):
-
-        1. **Propose** (prompt lookup): per slot, match the trailing
-           ``ngram_size``-gram of the token history against every earlier
-           position (one vectorized window comparison on the VPU) and copy
-           the k tokens that followed the most recent hit; no hit → an
-           all-(-1) draft, which degrades that slot to single-step.
-        2. **Verify**: one forward over (S, k+1) positions — the current
-           input token plus the k drafts.
-        3. **Accept**: greedy slots emit the longest draft prefix matching
-           the argmax plus one bonus token (exact greedy decoding);
-           sampling slots emit their position-0 ``sample_tokens`` draw
-           (identical fold_in rng stream to plain decode). Accepted tokens
-           are scattered back into the history so the *next* round's
-           proposal sees them — this is what makes speculation compose
-           with multi-step instead of excluding it.
-
-        The host syncs once per call: up to rounds*(k+1) tokens. KV writes
-        past a slot's accepted prefix are garbage but live at positions its
-        next round (or next plain decode) overwrites before any query can
-        attend to them (causal masking; same invariant as chunked prefill's
-        trash-block masking).
-        """
-        n = self.cfg.ngram_size
-        W = self._spec_hist_width
-
-        def propose(hist, seq_len):
-            # hist rows hold context tokens at their positions (the input
-            # token already placed at seq_len); valid length = seq_len+1.
-            S = hist.shape[0]
-            tails = jax.vmap(
-                lambda row, sl: jax.lax.dynamic_slice(row, (sl + 1 - n,), (n,))
-            )(hist, seq_len)                                     # (S, n)
-            win = jnp.stack(
-                [hist[:, j:W - n + 1 + j] for j in range(n)], axis=-1
-            )                                                    # (S, W-n+1, n)
-            eq = jnp.all(win == tails[:, None, :], axis=-1)
-            ii = jnp.arange(W - n + 1)[None, :]
-            # A hit must be an *earlier* occurrence fully inside known
-            # context: window ends at ii+n-1 <= seq_len-1.
-            valid = eq & (ii <= (seq_len - n)[:, None]) & (seq_len >= n)[:, None]
-            found = jnp.any(valid, axis=1)
-            best = jnp.argmax(jnp.where(valid, ii, -1), axis=1)  # most recent
-            drafts = jax.vmap(
-                lambda row, b: jax.lax.dynamic_slice(row, (b,), (k,))
-            )(hist, best + n)                                    # (S, k)
-            j = jnp.arange(k)[None, :]
-            ok = found[:, None] & ((best + n)[:, None] + j <= seq_len[:, None])
-            return jnp.where(ok, drafts, -1)
-
-        @partial(jax.jit, donate_argnums=(1,))
-        def spec_decode(params, cache_kv, hist, t_in, seq_len, spec_mask,
-                        block_tables, slot_keys, gen_counts, temperature,
-                        top_k, top_p, *lora):
-            S = t_in.shape[0]
-            rows = jnp.arange(S)
-            is_greedy = temperature == 0.0
-
-            def body(carry, _):
-                cache, hist, t_in, seq_len, cnt = carry
-                hist = hist.at[rows, seq_len].set(t_in)
-                drafts = propose(hist, seq_len)                  # (S, k)
-                # Per-slot gate: a paused slot's draft is forced to the
-                # all-(-1) no-hit form, degrading just that slot to
-                # single-step while its neighbors keep speculating.
-                drafts = jnp.where(spec_mask[:, None], drafts, -1)
-                ids = jnp.concatenate(
-                    [t_in[:, None], jnp.maximum(drafts, 0)], axis=1)
-                pos = seq_len[:, None] + jnp.arange(k + 1)[None, :]
-                logits, new_kv, _ = self._model_cache_call(
-                    params, cache, block_tables, ids, pos,
-                    **self._named(lora))
-                logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-                g = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (S, k+1)
-                g_lp = jnp.take_along_axis(
-                    logp, g[..., None], axis=-1)[..., 0]
-                # Position-0 emission via sample_tokens for EVERY slot:
-                # greedy rows reduce to the same argmax, sampling rows get
-                # exactly the plain-decode draw for fold_in(key, cnt).
-                rngs = jax.vmap(jax.random.fold_in)(slot_keys, cnt)
-                s_tok, s_lp = sample_tokens(
-                    logits[:, 0, :], rngs, temperature, top_k, top_p)
-                eq = (drafts == g[:, :k]) & (drafts >= 0)
-                m = jnp.sum(jnp.cumprod(eq.astype(jnp.int32), axis=1), axis=1)
-                emit = jnp.where(is_greedy, m + 1, 1).astype(jnp.int32)
-                toks = g.at[:, 0].set(s_tok)
-                lps = g_lp.at[:, 0].set(s_lp)
-                # Scatter emitted tokens into the history at context
-                # positions seq_len+1+j; masked lanes hit the scratch cell.
-                cols = seq_len[:, None] + 1 + jnp.arange(k + 1)[None, :]
-                cols = jnp.where(
-                    jnp.arange(k + 1)[None, :] < emit[:, None], cols, W - 1)
-                hist = hist.at[rows[:, None], cols].set(toks)
-                t_in2 = toks[rows, emit - 1]
-                prop_cnt = jnp.sum(drafts >= 0, axis=1).astype(jnp.int32)
-                carry = (new_kv, hist, t_in2, seq_len + emit, cnt + emit)
-                return carry, (toks, lps, emit, prop_cnt, m)
-
-            (new_kv, _, _, _, _), (toks, lps, emit, prop, acc) = jax.lax.scan(
-                body, (cache_kv, hist, t_in, seq_len, gen_counts),
-                None, length=rounds)
-            # (R, S, ...) -> slot-major for the host walk.
-            return (new_kv, toks.transpose(1, 0, 2), lps.transpose(1, 0, 2),
-                    emit.T, prop.T, acc.T)
-
-        return spec_decode
-
-    def spec_fn(self, k: int):
-        """The spec program for draft length ``k`` (pow2 halving-ladder
-        member), compiled lazily on first dispatch at that k — the same
-        bounded-variants pattern as ``_multi_decode_fns``."""
-        fn = self._spec_fns.get(k)
-        if fn is None:
-            fn = self._build_spec_decode_fn(k, self._spec_rounds)
-            self._spec_fns[k] = fn
-        return fn
-
-    # -- paged-KV block transport (tier demotion + disagg handoff) -----
-    def fetch_block_kv(self, block: int):
-        """One physical block's KV rows from every layer pool, fetched
-        device→host — the prefix-tier demotion path, reused verbatim as
-        the disaggregated-serving handoff transport. Runs on the stepper
-        thread; ``self.cache`` then holds the committed output of the
-        last dispatched program, so the read sees every write the block
-        ever received. Payload keys follow the disk format
-        ("l00000": {"k": ..., "v": ..., int8 scales if present})."""
-        try:
-            rows = [{name: arr[block] for name, arr in layer.items()}
-                    for layer in self.cache]
-            if self._demote_sharding is not None:
-                # Stage through pinned_host: the D2H DMA lands in pinned
-                # memory the host reads without a bounce (TPU path).
-                rows = jax.device_put(rows, self._demote_sharding)
-            host = jax.device_get(rows)
-        except Exception as e:  # noqa: BLE001 — the fetch is best-effort:
-            # a failure degrades to discard (demotion) or re-prefill
-            # (handoff), never faults the step loop that triggered it.
-            self.logger.warning("block KV fetch failed "
-                                "(%s: %s); block discarded",
-                                type(e).__name__, e)
-            return None
-        return {f"l{i:05d}": {k: np.asarray(v) for k, v in r.items()}
-                for i, r in enumerate(host)}
-
-    def restore_block(self, block: int, payload: dict) -> None:
-        """Scatter a fetched payload into physical ``block`` of every
-        layer pool. Dispatch is async (jit): the scatter overlaps host-side
-        admission work, and the following prefill/decode programs see the
-        restored rows through the ``self.cache`` data dependency."""
-        if self._restore_fn is None:
-            @partial(jax.jit, donate_argnums=(0,))
-            def restore(cache_kv, rows, bid):
-                return [
-                    {k: v.at[bid].set(r[k].astype(v.dtype)) for k, v in
-                     layer.items()}
-                    for layer, r in zip(cache_kv, rows)
-                ]
-
-            self._restore_fn = restore
-        rows = [payload[f"l{i:05d}"] for i in range(len(self.cache))]
-        self.cache = self._restore_fn(self.cache, rows,
-                                      jnp.asarray(block, jnp.int32))
 
 
 class InferenceEngine:
@@ -1020,99 +399,6 @@ class InferenceEngine:
         self.model_cfg = model_cfg
         self.logger = get_logger()
         self.mesh = mesh
-        # The device half (scheduler/executor split): weights, KV pools,
-        # and every compiled program live in the executor; this class
-        # keeps ONLY host-side scheduling state (slots, queues, block
-        # accounting, mirrors) and calls in with assembled batches.
-        self.executor = EngineExecutor(
-            model_cfg, params, engine_cfg, lora_cfg, mesh=mesh,
-            donate_params=donate_params)
-        del params  # the executor owns (a possibly quantized copy of) them
-        ec = engine_cfg
-        self.block_manager = BlockManager(ec.num_blocks, ec.block_size)
-        self.prefix_cache = None
-        if ec.enable_prefix_caching:
-            from dlti_tpu.serving.prefix_cache import PrefixCachingAllocator
-
-            tier_store = None
-            if ec.prefix_host_blocks > 0 or ec.prefix_disk_blocks > 0:
-                from dlti_tpu.serving.prefix_tiers import TieredBlockStore
-
-                tier_store = TieredBlockStore(
-                    host_blocks=ec.prefix_host_blocks,
-                    disk_dir=ec.prefix_disk_dir,
-                    disk_blocks=ec.prefix_disk_blocks)
-            self.prefix_cache = PrefixCachingAllocator(
-                self.block_manager, tier_store=tier_store,
-                kv_fetch=self._fetch_block_kv if tier_store is not None
-                else None)
-        self.slots = [_Slot(i) for i in range(ec.max_seqs)]
-        self.waiting: collections.deque[Request] = collections.deque()
-        # Recently-finished requests, for observability only (results are
-        # returned via step()/generate()); bounded so a long-lived server
-        # doesn't grow without limit.
-        self.finished: collections.deque[Request] = collections.deque(maxlen=256)
-        self._rng = jax.random.PRNGKey(0)
-        self._req_counter = itertools.count()
-
-        # Host mirrors of the per-slot device inputs.
-        S, MB = ec.max_seqs, ec.max_blocks_per_seq
-        self._block_tables = np.zeros((S, MB), np.int32)
-        self._temperature = np.ones((S,), np.float32)
-        self._top_k = np.zeros((S,), np.int32)
-        self._top_p = np.ones((S,), np.float32)
-        # Per-slot sampling key (uint32[2] threefry data) + tokens generated
-        # so far; decode folds key with the count, so a seeded request's
-        # draws don't depend on batch composition or admission order.
-        self._slot_keys = np.zeros((S, 2), np.uint32)
-        self._gen_counts = np.zeros((S,), np.int32)
-        # Multi-LoRA: each slot's adapter-pool row (0 = the all-zero base
-        # row). Maintained unconditionally so _state_mirrors stays
-        # uniform; without a pool it is never shipped to the device.
-        self._adapter_ids = np.zeros((S,), np.int32)
-        # Recurrent state (models with such layers): the slot each decode row may
-        # write its state to — its own while the slot decodes, out of
-        # range (S) while it is free or still prefilling, so a decode call
-        # never touches a state that a prefill is building.
-        self._state_slots = np.full((S,), S, np.int32)
-        # Counters of prefill chunks that sampled nothing (chunked
-        # prefill), still on the device: added to the next fetch.
-        self._prefill_counters = None
-
-        # Host mirror of every slot's token history at its context
-        # positions, maintained incrementally at admission/append — the
-        # spec program's proposal input, without rebuilding O(context)
-        # arrays from Python lists every sync. Rows beyond a slot's
-        # seq_len are never read (proposal masks on seq_len), so stale
-        # tails from previous occupants need no zeroing.
-        self._spec_hist = (
-            np.zeros((ec.max_seqs, self._spec_hist_width), np.int32)
-            if ec.speculative == "ngram" else None)
-        # Per-slot adaptive controller (replaces the old engine-wide
-        # _spec_pause): each slot carries its own rolling acceptance
-        # window and cooldown, so one zero-hit slot pauses alone while
-        # its batchmates keep speculating. prop/acc count slot-rounds and
-        # extra accepted tokens since that slot's last gate decision;
-        # pause is decode rounds left in that slot's cooldown; ewma is
-        # the smoothed accepted-drafts-per-round estimate feeding the
-        # draft-length ladder (optimistically seeded at full k so a fresh
-        # slot probes with the widest draft).
-        self._spec_slot_prop = np.zeros((S,), np.int64)
-        self._spec_slot_acc = np.zeros((S,), np.int64)
-        self._spec_slot_pause = np.zeros((S,), np.int32)
-        self._spec_slot_ewma = np.full((S,), float(ec.num_draft_tokens),
-                                       np.float64)
-        # Last dispatched draft length (0 = no spec round in flight /
-        # speculation off) — the dlti_spec_draft_len gauge.
-        self._spec_last_k = 0
-
-        # Disaggregated serving (serving/disagg.py): a prefill-only engine
-        # runs admission and chunked prefill but never dispatches decode —
-        # finished prefills are harvested via export_handoff() and their
-        # KV migrated to a decode replica, which continues the stream via
-        # adopt_handoff(). Plain engines leave this False.
-        self.prefill_only = False
-
         # Aggregate stats for the /stats endpoint and load reports.
         self.stats = {"requests": 0, "generated_tokens": 0, "prefill_tokens": 0,
                       "preemptions": 0, "decode_steps": 0,
@@ -1143,16 +429,14 @@ class InferenceEngine:
                       # an HBM hit or a re-prefill. Present (at 0) even
                       # without tiering so the /metrics schema is stable.
                       "prefix_restored_tokens": 0,
-                      # Prefill program dispatches (ragged packing exists
-                      # to shrink this under multi-admission waves).
-                      # Present (at 0) so the /metrics schema is stable.
+                      # Prefill program dispatches. Present (at 0) so
+                      # the /metrics schema is stable.
                       "prefill_batches": 0,
                       "spec_proposed": 0, "spec_accepted": 0,
                       "spec_paused_rounds": 0,
-                      # Decode-state cache accounting (decode_state.py):
-                      # upload syncs / rows shipped / clean (zero-upload)
-                      # syncs. Present (at 0) even with the cache disabled
-                      # so the /metrics exposition schema is stable.
+                      # Resident decode state (decode_state.py), booked
+                      # by the executor: upload syncs / rows shipped /
+                      # clean (zero-upload) syncs.
                       "decode_state_uploads": 0, "decode_state_rows": 0,
                       "decode_state_clean_syncs": 0,
                       # Numeric-guard trips (nonfinite decode outputs /
@@ -1164,6 +448,15 @@ class InferenceEngine:
                       # HBM headroom — deferred, not faulted. Present (at
                       # 0) so the /metrics schema is stable.
                       "hbm_deferred_admissions": 0}
+        # The device half: weights, the paged cache, the resident per-slot
+        # decode state and every compiled program with its calling
+        # convention live in the executor. This class keeps ONLY host-side
+        # scheduling state (slots, queues, block accounting, the numpy
+        # mirrors), plans each round in host arrays, and calls in.
+        self.executor = EngineExecutor(
+            model_cfg, params, engine_cfg, lora_cfg, mesh=mesh,
+            donate_params=donate_params, stats=self.stats)
+        del params  # the executor owns (a possibly quantized copy of) them
         # What the model counts (EngineExecutor.counter_names), summed over
         # every program call under the counter's own name, and the decode
         # steps' part of it under ``<name>_decode``. (A largest-of counter
@@ -1171,151 +464,106 @@ class InferenceEngine:
         # model that counts nothing.
         for name in self.executor.counter_names:
             self.stats[name] = self.stats[f"{name}_decode"] = 0
+        ec = engine_cfg
+        self.block_manager = BlockManager(ec.num_blocks, ec.block_size)
+        self.prefix_cache = None
+        if ec.enable_prefix_caching:
+            from dlti_tpu.serving.prefix_cache import PrefixCachingAllocator
+
+            tier_store = None
+            if ec.prefix_host_blocks > 0 or ec.prefix_disk_blocks > 0:
+                from dlti_tpu.serving.prefix_tiers import TieredBlockStore
+
+                tier_store = TieredBlockStore(
+                    host_blocks=ec.prefix_host_blocks,
+                    disk_dir=ec.prefix_disk_dir,
+                    disk_blocks=ec.prefix_disk_blocks)
+            self.prefix_cache = PrefixCachingAllocator(
+                self.block_manager, tier_store=tier_store,
+                kv_fetch=self.executor.fetch_block_kv
+                if tier_store is not None else None)
+        self.slots = [_Slot(i) for i in range(ec.max_seqs)]
+        self.waiting: collections.deque[Request] = collections.deque()
+        # Recently-finished requests, for observability only (results are
+        # returned via step()/generate()); bounded so a long-lived server
+        # doesn't grow without limit.
+        self.finished: collections.deque[Request] = collections.deque(maxlen=256)
+        self._req_counter = itertools.count()
+
+        # Host mirrors of the per-slot device inputs.
+        S, MB = ec.max_seqs, ec.max_blocks_per_seq
+        self._block_tables = np.zeros((S, MB), np.int32)
+        self._temperature = np.ones((S,), np.float32)
+        self._top_k = np.zeros((S,), np.int32)
+        self._top_p = np.ones((S,), np.float32)
+        # Per-slot sampling key (uint32[2] threefry data) + tokens generated
+        # so far; decode folds key with the count, so a seeded request's
+        # draws don't depend on batch composition or admission order.
+        self._slot_keys = np.zeros((S, 2), np.uint32)
+        self._gen_counts = np.zeros((S,), np.int32)
+        # Multi-LoRA: each slot's adapter-pool row (0 = the all-zero base
+        # row). Maintained unconditionally so _state_mirrors stays
+        # uniform; without a pool it is never shipped to the device.
+        self._adapter_ids = np.zeros((S,), np.int32)
+        # Recurrent state (models with such layers): the slot each decode row may
+        # write its state to — its own while the slot decodes, out of
+        # range (S) while it is free or still prefilling, so a decode call
+        # never touches a state that a prefill is building.
+        self._state_slots = np.full((S,), S, np.int32)
+
+        # Host mirror of every slot's token history at its context
+        # positions, maintained incrementally at admission/append — the
+        # spec program's proposal input, without rebuilding O(context)
+        # arrays from Python lists every sync. Rows beyond a slot's
+        # seq_len are never read (proposal masks on seq_len), so stale
+        # tails from previous occupants need no zeroing.
+        self._spec_hist = (
+            np.zeros((ec.max_seqs, ec.spec_hist_width), np.int32)
+            if ec.speculative == "ngram" else None)
+        # Per-slot adaptive controller (replaces the old engine-wide
+        # _spec_pause): each slot carries its own rolling acceptance
+        # window and cooldown, so one zero-hit slot pauses alone while
+        # its batchmates keep speculating. prop/acc count slot-rounds and
+        # extra accepted tokens since that slot's last gate decision;
+        # pause is decode rounds left in that slot's cooldown; ewma is
+        # the smoothed accepted-drafts-per-round estimate feeding the
+        # draft-length ladder (optimistically seeded at full k so a fresh
+        # slot probes with the widest draft).
+        self._spec_slot_prop = np.zeros((S,), np.int64)
+        self._spec_slot_acc = np.zeros((S,), np.int64)
+        self._spec_slot_pause = np.zeros((S,), np.int32)
+        self._spec_slot_ewma = np.full((S,), float(ec.num_draft_tokens),
+                                       np.float64)
+        # Last dispatched draft length (0 = no spec round in flight /
+        # speculation off) — the dlti_spec_draft_len gauge.
+        self._spec_last_k = 0
+
+        # Disaggregated serving (serving/disagg.py): a prefill-only engine
+        # runs admission and chunked prefill but never dispatches decode —
+        # finished prefills are harvested via export_handoff() and their
+        # KV migrated to a decode replica, which continues the stream via
+        # adopt_handoff(). Plain engines leave this False.
+        self.prefill_only = False
+
         # Token-storm guard run length (consecutive all-slots-identical
         # decode steps).
         self._storm_run = 0
 
-        # Device-resident twins of the per-slot mirrors, maintained
-        # incrementally (per-slot dirty tracking; clean steps upload
-        # nothing). All cache interaction happens on the stepper thread —
-        # same thread-safety contract as the mirrors themselves.
-        self._state_cache = None
-        if ec.decode_state_cache:
-            from dlti_tpu.serving.decode_state import DecodeStateCache
-
-            self._state_cache = DecodeStateCache(
-                ec.max_seqs, device=self._device, mesh=mesh,
-                stats=self.stats,
-                extra_fields=(("adapter_ids",) if ec.adapter_slots > 0
-                              else ("state_slots",)
-                              if model_cfg.has_recurrent_state else ()))
-
-        # Memory ledger (telemetry.memledger): the engine's owners. The
-        # params and cache handles are callables because both rebind
-        # (donated decode programs return a fresh cache list); prefix-
-        # cached blocks live INSIDE the pool arrays, so that owner is a
-        # carve — bytes move from kv_block_pool to prefix_cache_hbm
-        # without double counting.
-        self._recurrent_state_pool_bytes = tree_nbytes(
-            [c for c in self.cache if "ssm" in c])
+        # Memory ledger (telemetry.memledger). The executor names the
+        # device arrays it holds; prefix-cached blocks live INSIDE the pool
+        # arrays, so that owner is a carve — bytes move from kv_block_pool
+        # to prefix_cache_hbm without double counting.
         self.memledger = MemoryLedger(
             enabled=ec.memory_ledger, capacity_bytes=ec.hbm_budget_bytes)
-        self.memledger.register("params", lambda: self.params)
-        self.memledger.register(
-            "kv_block_pool",
-            lambda: [c for c in self.cache if "ssm" not in c])
-        self.memledger.register(
-            "recurrent_state_pool",
-            lambda: [c for c in self.cache if "ssm" in c] or None)
-        self.memledger.register(
-            "decode_state_cache",
-            lambda: (self._state_cache._dev
-                     if self._state_cache is not None else None))
-        self.memledger.register(
-            "lora_adapters",
-            lambda: (self.adapter_pool.tree
-                     if self.adapter_pool is not None else None))
+        self.executor.register_memory_owners(self.memledger)
+        # Bytes of the per-slot recurrent state (0 without such layers).
+        self.recurrent_state_pool_bytes = \
+            self.executor.recurrent_state_pool_bytes
         if self.prefix_cache is not None:
-            kv_pool_bytes = tree_nbytes(self.cache)
-            per_block = kv_pool_bytes // max(1, ec.num_blocks)
+            per_block = self.executor.pool_bytes // max(1, ec.num_blocks)
             self.memledger.register_carve(
                 "prefix_cache_hbm", "kv_block_pool",
                 lambda: self.prefix_cache.num_cached_blocks() * per_block)
-
-    # ------------------------------------------------------------------
-    # Executor delegation: scheduler code (and external callers — tests,
-    # replicas' NaN-poison fault injection, the memledger owner lambdas)
-    # keep addressing device state through the engine; the attributes
-    # live on the executor since the scheduler/executor split.
-    # ------------------------------------------------------------------
-    @property
-    def params(self):
-        return self.executor.params
-
-    @params.setter
-    def params(self, value):
-        self.executor.params = value
-
-    @property
-    def cache(self):
-        return self.executor.cache
-
-    @cache.setter
-    def cache(self, value):
-        self.executor.cache = value
-
-    @property
-    def model(self):
-        return self.executor.model
-
-    @property
-    def _device(self):
-        return self.executor._device
-
-    @property
-    def _demote_sharding(self):
-        return self.executor._demote_sharding
-
-    @property
-    def _quantized(self):
-        return self.executor._quantized
-
-    @property
-    def adapter_pool(self):
-        return self.executor.adapter_pool
-
-    @property
-    def _prefill_fns(self):
-        return self.executor._prefill_fns
-
-    @property
-    def _decode_fn(self):
-        return self.executor._decode_fn
-
-    @_decode_fn.setter
-    def _decode_fn(self, value):
-        self.executor._decode_fn = value
-
-    @property
-    def _multi_decode_fns(self):
-        return self.executor._multi_decode_fns
-
-    @property
-    def _spec_fn(self):
-        return self.executor._spec_fn
-
-    def _spec_fn_for(self, k: int):
-        return self.executor.spec_fn(k)
-
-    @property
-    def _spec_rounds(self):
-        return self.executor._spec_rounds
-
-    @property
-    def _spec_hist_width(self):
-        return self.executor._spec_hist_width
-
-    @property
-    def _sample_fn(self):
-        return self.executor._sample_fn
-
-    @property
-    def _fold_keys(self):
-        return self.executor._fold_keys
-
-    def _build_prefill_fn(self, bucket: int):
-        return self.executor._build_prefill_fn(bucket)
-
-    def _build_multi_decode_fn(self, num_steps: int):
-        return self.executor._build_multi_decode_fn(num_steps)
-
-    def _fetch_block_kv(self, block: int):
-        return self.executor.fetch_block_kv(block)
-
-    def _restore_block(self, block: int, payload: dict) -> None:
-        self.executor.restore_block(block, payload)
-
-    _aot_or_jit = staticmethod(EngineExecutor._aot_or_jit)
 
     def _window_steps(self, active: list) -> int:
         """Budget-clamped multi-step window (the occupancy lever).
@@ -1361,66 +609,9 @@ class InferenceEngine:
         return k
 
     def warmup_decode_ladder(self) -> None:
-        """Pre-compile the decode programs (single-step + every multi-step
-        halving-ladder length) BEFORE traffic: a window length's first use
-        otherwise stalls the live decode loop on an XLA compile at an
-        unpredictable moment. AOT-lowers on abstract shapes (donation only
-        consumes avals here — no scratch KV pool is materialized), then
-        KEEPS the compiled executables and swaps them into the dispatch
-        path: relying on the persistent compilation cache alone does
-        nothing for a compile that finishes under the cache's
-        min-compile-time floor."""
-        def avals(tree):
-            # Carry each leaf's ACTUAL sharding: a ReplicatedEngine pins
-            # every replica's params/KV to its own device, and an aval
-            # without it lowers for the default device — an executable
-            # replica 1 can only reject at dispatch time. Host-mirror
-            # args (ids/positions/tables/keys) stay plain avals: they
-            # arrive uncommitted and follow the committed operands.
-            return jax.tree_util.tree_map(
-                lambda v: jax.ShapeDtypeStruct(
-                    v.shape, v.dtype,
-                    sharding=getattr(v, "sharding", None)), tree)
-
-        S = self.cfg.max_seqs
-        i32, f32, u32 = jnp.int32, jnp.float32, jnp.uint32
-        if self._state_cache is not None:
-            # The decode-state cache feeds COMMITTED device arrays into
-            # the compiled programs; lower with their actual shardings so
-            # the AOT executables accept them (same reason params/cache
-            # carry theirs). Syncing here is correct at any time — it just
-            # brings the resident copies up to date with the mirrors.
-            state_avals = avals(self._state_cache.sync(
-                self._state_mirrors(), self._masked_rows()))
-        else:
-            state_avals = (
-                jax.ShapeDtypeStruct(self._block_tables.shape, i32),
-                jax.ShapeDtypeStruct((S, 2), u32),
-                jax.ShapeDtypeStruct((S,), i32),
-                jax.ShapeDtypeStruct((S,), f32),
-                jax.ShapeDtypeStruct((S,), i32),
-                jax.ShapeDtypeStruct((S,), f32))
-            if self.adapter_pool is not None or self.executor._recurrent:
-                state_avals += (jax.ShapeDtypeStruct((S,), i32),)
-        args = (avals(self.params), avals(self.cache),
-                jax.ShapeDtypeStruct((S, 1), i32),
-                jax.ShapeDtypeStruct((S, 1), i32),
-                *state_avals)
-        if self.adapter_pool is not None:
-            args = args + (avals(self.adapter_pool.tree),)
-        # Idempotent: a re-warm unwraps back to the raw jit fn (the
-        # _aot_or_jit wrapper has no .lower) and rebuilds the executable.
-        raw = getattr(self._decode_fn, "_jit_fn", self._decode_fn)
-        self._decode_fn = self._aot_or_jit(raw.lower(*args).compile(), raw)
-        k = self.cfg.steps_per_sync
-        while k > 1:
-            fn = self._multi_decode_fns.get(k)
-            if fn is None:
-                fn = self._build_multi_decode_fn(k)
-            raw = getattr(fn, "_jit_fn", fn)
-            self._multi_decode_fns[k] = self._aot_or_jit(
-                raw.lower(*args).compile(), raw)
-            k //= 2
+        """Pre-compile the decode programs ahead of traffic."""
+        self.executor.warmup_decode_ladder(self._state_mirrors(),
+                                           self._masked_rows())
 
     def _bucket_for(self, n: int) -> int:
         for b in self.cfg.buckets():
@@ -1511,14 +702,6 @@ class InferenceEngine:
     @property
     def num_free_blocks(self) -> int:
         return self.block_manager.num_free
-
-    @property
-    def recurrent_state_pool_bytes(self) -> int:
-        """Bytes of the per-slot recurrent state (0 without such layers):
-        fixed when the pool is made. (Not read off the arrays at scrape
-        time: a handler thread would meet buffers a program call has just
-        been given.)"""
-        return self._recurrent_state_pool_bytes
 
     @property
     def spec_acceptance_rate(self) -> float:
@@ -1648,7 +831,7 @@ class InferenceEngine:
             if req._adapter_slot < 0:
                 if not req.adapter:
                     req._adapter_slot = 0
-                elif self.adapter_pool is None:
+                elif self.executor.adapter_pool is None:
                     self.waiting.popleft()
                     self._fail_waiting(
                         req, f"request names adapter {req.adapter!r} but "
@@ -1658,7 +841,8 @@ class InferenceEngine:
                 else:
                     t_ad = time.monotonic()
                     try:
-                        row, loaded = self.adapter_pool.acquire(req.adapter)
+                        row, loaded = self.executor.adapter_pool.acquire(
+                            req.adapter)
                     except AdapterError as e:
                         self.waiting.popleft()
                         self._fail_waiting(req, str(e))
@@ -1713,7 +897,7 @@ class InferenceEngine:
                 payload, tier = self.prefix_cache.fetch_restore(key)
                 if payload is None:
                     break
-                self._restore_block(blocks[j], payload)
+                self.executor.restore_block(blocks[j], payload)
                 self.prefix_cache.register_restored(key, blocks[j])
                 restored_by_tier[tier] = restored_by_tier.get(tier, 0) + 1
                 n_restored += 1
@@ -1748,14 +932,6 @@ class InferenceEngine:
         suffix_lens = [len(req.prompt_token_ids) + len(req.output_token_ids)
                        - n_cached
                        for _slot, req, _blocks, n_cached in admissions]
-        if self.cfg.ragged_prefill:
-            # Ragged: one call advances admissions of MIXED suffix lengths
-            # (group width = widest member's bucket, padding bounded) —
-            # a heterogeneous admission wave stops costing one program
-            # call (and one jit specialization) per distinct bucket.
-            for width, group in self._ragged_groups(admissions, suffix_lens):
-                self._prefill_group(width, group)
-            return
         by_bucket: Dict[int, List[tuple]] = {}
         for adm, suffix_len in zip(admissions, suffix_lens):
             by_bucket.setdefault(self._bucket_for(suffix_len), []).append(adm)
@@ -1770,7 +946,7 @@ class InferenceEngine:
         flattens while its padded work and jit-shape surface keep growing),
         and fewer, a power of two, where the model holds a call to
         ``prefill_call_tokens`` padded tokens (a row at least)."""
-        limit = getattr(self.executor.model, "prefill_call_tokens", 0)
+        limit = self.executor.prefill_call_tokens
         rows = 8
         while limit and rows > 1 and rows * bucket > limit:
             rows //= 2
@@ -1797,11 +973,6 @@ class InferenceEngine:
             chunks.append((slot, piece, slot.next_pos, take == remaining))
             slot.next_pos += take
             budget -= take
-        if self.cfg.ragged_prefill:
-            for width, group in self._ragged_groups(
-                    chunks, [len(c[1]) for c in chunks]):
-                self._run_prefill_batch(width, group)
-            return
         by_bucket: Dict[int, List[tuple]] = {}
         for ch in chunks:
             by_bucket.setdefault(self._bucket_for(len(ch[1])), []).append(ch)
@@ -1809,39 +980,6 @@ class InferenceEngine:
             rows = self._prefill_rows(bucket)
             for i in range(0, len(group), rows):
                 self._run_prefill_batch(bucket, group[i:i + rows])
-
-    def _ragged_groups(self, items: List, lengths: List[int]) -> List[tuple]:
-        """FCFS ragged packing for multi-admission prefill: ``(width,
-        members)`` groups where width is the widest member's pow2 bucket.
-
-        A group closes at 8 rows (same flattening point as the bucketed
-        path) or when its padded footprint — pow2-padded row count times
-        group width — would exceed twice the members' own bucketed token
-        work. The 2x bound is the padding overhead the bucketed path
-        already tolerates per row, accounted group-wide: short chunks
-        pack behind a long one only while the wasted lanes stay cheaper
-        than a second program dispatch. Rows keep their own positions,
-        block tables, and last-token indices, so grouping choice never
-        changes any row's output (byte-identical ragged on/off)."""
-        groups: List[tuple] = []
-        cur: List = []
-        wid = real = 0
-        for it, ln in zip(items, lengths):
-            w = self._bucket_for(ln)
-            nwid = max(wid, w)
-            nreal = real + w
-            rows_pow2 = 1
-            while rows_pow2 < len(cur) + 1:
-                rows_pow2 *= 2
-            if cur and (len(cur) >= 8 or rows_pow2 * nwid > 2 * nreal):
-                groups.append((wid, cur))
-                cur = []
-                nwid, nreal = w, w
-            cur.append(it)
-            wid, real = nwid, nreal
-        if cur:
-            groups.append((wid, cur))
-        return groups
 
     def _register_slot(self, slot: _Slot, req: Request, blocks: List[int],
                        n: int) -> None:
@@ -1862,13 +1000,8 @@ class InferenceEngine:
         self._temperature[slot.slot_id] = req.params.temperature
         self._top_k[slot.slot_id] = req.params.top_k
         self._top_p[slot.slot_id] = req.params.top_p
-        if req.params.seed is not None:
-            key = jax.random.PRNGKey(req.params.seed)
-        else:
-            self._rng, key = jax.random.split(self._rng)
-        self._slot_keys[slot.slot_id] = np.asarray(jax.random.key_data(key)
-                                                   if jnp.issubdtype(key.dtype, jax.dtypes.prng_key)
-                                                   else key, np.uint32)
+        self._slot_keys[slot.slot_id] = self.executor.slot_key(
+            req.params.seed)
         # Count of tokens generated so far (nonzero on re-admission after
         # preemption, so the seeded draw stream continues where it left off).
         self._gen_counts[slot.slot_id] = len(req.output_token_ids)
@@ -1878,7 +1011,7 @@ class InferenceEngine:
         self._adapter_ids[slot.slot_id] = max(req._adapter_slot, 0)
         # Not a decode row until its prefill has handed the slot a state.
         self._state_slots[slot.slot_id] = self.cfg.max_seqs
-        self._mark_state_dirty(slot.slot_id)
+        self.executor.mark_dirty(slot.slot_id)
         if self._spec_hist is not None:
             ctx = req.prompt_token_ids + req.output_token_ids
             self._spec_hist[slot.slot_id, :len(ctx)] = ctx
@@ -1921,8 +1054,7 @@ class InferenceEngine:
             if sampled is None:
                 return  # mid-prompt chunks: KV writes only, nothing to sample
             with tr.span("engine/prefill_wait", cat="engine"):
-                toks = np.asarray(jax.device_get(sampled[0]))
-                lps = np.asarray(jax.device_get(sampled[1]))
+                toks, lps = self.executor.fetch(sampled)
             if self.executor.counter_names:
                 self._count(toks[len(lps):][None, :], decode=False)
             self._prefill_emit(chunks, toks, lps)
@@ -1952,12 +1084,13 @@ class InferenceEngine:
                 # slot's gen count, and a chunked-mode slot's block-table
                 # row sheds its trash-block masking — either way the row
                 # must re-upload before the slot joins the decode batch.
-                self._mark_state_dirty(slot.slot_id)
+                self.executor.mark_dirty(slot.slot_id)
 
     def _prefill_launch(self, bucket: int, chunks: List[tuple]):
-        """Host arrays, the prefill program, key fold and sampling calls of
-        one batch, none of them waited for: the sampled ``(tokens,
-        logprobs)`` still on the device, or None when no chunk is final."""
+        """The host arrays of one batch and its prefill call (program, key
+        fold and sampling, none of them waited for): the sampled
+        ``(tokens, logprobs)`` still on the device, or None when no chunk
+        is final."""
         ec = self.cfg
         B = 1
         while B < len(chunks):
@@ -1973,8 +1106,6 @@ class InferenceEngine:
             nblk_bucket *= 2
         nblk_bucket = min(nblk_bucket, ec.max_blocks_per_seq)
 
-        # Program dispatches — with ragged packing this is the number a
-        # multi-admission wave is supposed to shrink.
         self.stats["prefill_batches"] += 1
         ids = np.zeros((B, bucket), np.int32)
         pos = np.full((B, bucket), -1, np.int32)  # -1 -> write dropped
@@ -1985,6 +1116,11 @@ class InferenceEngine:
         temps = np.ones((B,), np.float32)
         top_k = np.zeros((B,), np.int32)
         top_p = np.ones((B,), np.float32)
+        adapter_ids = np.zeros((B,), np.int32)
+        # State hand-off (a model with recurrent layers): each row names
+        # the slot its prefill fills (a padding row none), and the program
+        # writes the state after the row's last real token there.
+        state_slots = np.full((B,), ec.max_seqs, np.int32)
         for r, (slot, tokens, start, is_last) in enumerate(chunks):
             req = slot.request
             ids[r, : len(tokens)] = tokens
@@ -1997,45 +1133,18 @@ class InferenceEngine:
             temps[r] = req.params.temperature
             top_k[r] = req.params.top_k
             top_p[r] = req.params.top_p
+            adapter_ids[r] = self._adapter_ids[slot.slot_id]
+            state_slots[r] = slot.slot_id
             self.stats["prefill_tokens"] += len(tokens)
 
-        if bucket not in self._prefill_fns:
-            self._prefill_fns[bucket] = self._build_prefill_fn(bucket)
-        lora_args = ()
-        if self.adapter_pool is not None:
-            ad = np.zeros((B,), np.int32)
-            for r, (slot, *_rest) in enumerate(chunks):
-                ad[r] = self._adapter_ids[slot.slot_id]
-            lora_args = (jnp.asarray(ad), self.adapter_pool.tree)
-        if self.executor._recurrent:
-            # State hand-off: each row names the slot its prefill fills
-            # (a padding row none), and the program writes the state after
-            # the row's last real token there. No host work beyond this
-            # array, hence no span of its own.
-            rows = np.full((B,), ec.max_seqs, np.int32)
-            rows[:len(chunks)] = [c[0].slot_id for c in chunks]
-            lora_args = (jnp.asarray(rows),)
-        self.cache, last_logits, *counters = self._prefill_fns[bucket](
-            self.params, self.cache, jnp.asarray(ids), jnp.asarray(pos),
-            jnp.asarray(bt), jnp.asarray(last_idx), *lora_args,
-        )
-        if counters and self._prefill_counters is not None:
-            counters = [self._prefill_counters + counters[0]]
-            self._prefill_counters = None
-        if not any(is_last for *_, is_last in chunks):
-            if counters:
-                self._prefill_counters = counters[0]
-            return None
-        # Same per-slot key + count stream the decode path uses, folded in
-        # one async dispatch (no host round trip per row).
-        keys = self._fold_keys(jnp.asarray(slot_keys), jnp.asarray(counts))
-        sample = self._sample_fn
-        if counters:
-            sample = self.executor._sample_counted_fn
-        return sample(
-            last_logits, keys, jnp.asarray(temps),
-            jnp.asarray(top_k), jnp.asarray(top_p), *counters,
-        )
+        sample = None
+        if any(is_last for *_, is_last in chunks):
+            sample = {"slot_keys": slot_keys, "gen_counts": counts,
+                      "temperature": temps, "top_k": top_k, "top_p": top_p}
+        return self.executor.prefill(
+            bucket, input_ids=ids, positions=pos, block_tables=bt,
+            last_idx=last_idx, adapter_ids=adapter_ids,
+            state_slots=state_slots, sample=sample)
 
     def _count(self, counters: np.ndarray, decode: bool) -> None:
         """Book the model's counters: ``(program calls or decode steps,
@@ -2045,13 +1154,6 @@ class InferenceEngine:
             self.stats[name] += int(total)
             if decode:
                 self.stats[f"{name}_decode"] += int(total)
-
-    def _mark_state_dirty(self, slot_id: int) -> None:
-        """A scheduling event changed ``slot_id``'s per-slot state mirrors
-        (admission, release, block growth, prefill completion): the next
-        decode dispatch must re-upload that row."""
-        if self._state_cache is not None:
-            self._state_cache.mark_dirty(slot_id)
 
     def _state_mirrors(self) -> dict:
         return {"block_tables": self._block_tables,
@@ -2070,25 +1172,13 @@ class InferenceEngine:
     def _masked_rows(self) -> list:
         return [s.slot_id for s in self.slots if s.prefilling]
 
-    def _decode_block_tables(self) -> np.ndarray:
-        """Block tables as the decode-side programs may see them: rows of
-        partially-prefilled slots are zeroed (the reserved trash block), so
-        a decode call can never scribble on KV those slots have written —
-        decode fills their rows with position 0, and block 0 absorbs it."""
-        if not any(s.prefilling for s in self.slots):
-            return self._block_tables
-        bt = self._block_tables.copy()
-        for s in self.slots:
-            if s.prefilling:
-                bt[s.slot_id] = 0
-        return bt
-
     def _decode_dispatch(self):
         """Schedule this round's decode work and dispatch its program call
         WITHOUT syncing: returns an opaque pending tuple whose device
         arrays are still being computed, for :meth:`_decode_complete`.
-        All host mirrors are snapshotted here (jnp.asarray copies at call
-        time), so admission may mutate them while the call is in flight."""
+        All host mirrors are snapshotted here (the executor uploads copies
+        at call time), so admission may mutate them while the call is in
+        flight."""
         tr = self._tracer
         with tr.span("engine/decode_prep", cat="engine"):
             plan = self._decode_prepare()
@@ -2126,11 +1216,11 @@ class InferenceEngine:
         # compiled variant per window size; not worth the compile surface.
         spec_parts: list = []
         spec_k = 0
-        if self._spec_fn is not None and active0:
+        if self._spec_hist is not None and active0:
             spec_parts = self._spec_round_gate(active0)
         if spec_parts:
             spec_k = self._spec_pick_k(spec_parts)
-        spec_window = self._spec_rounds * (spec_k + 1)
+        spec_window = self.cfg.spec_rounds * (spec_k + 1)
         use_spec = bool(spec_parts) and all(
             s.seq_len + spec_window <= ec.max_model_len for s in active0)
         self._spec_last_k = spec_k if use_spec else 0
@@ -2156,7 +1246,7 @@ class InferenceEngine:
                     # land on the trash block (unallocated table entries
                     # are 0), so don't allocate — and possibly preempt
                     # for — the full window.
-                    window = self._spec_rounds
+                    window = self.cfg.spec_rounds
                 need = self.block_manager.blocks_needed(
                     slot.seq_len + window)
                 while need > len(slot.blocks):
@@ -2168,7 +1258,7 @@ class InferenceEngine:
                     slot.blocks.extend(got)
                     self._block_tables[
                         slot.slot_id, len(slot.blocks) - 1] = got[0]
-                    self._mark_state_dirty(slot.slot_id)
+                    self.executor.mark_dirty(slot.slot_id)
             return True
 
         if not grow_tables(k_steps, use_spec):
@@ -2207,70 +1297,32 @@ class InferenceEngine:
         self.stats["decode_context_tokens"] += context * k_steps
         self.stats["decode_steps_sorted_sampling"] += \
             k_steps * self._sampling_sorts()
-        if self._state_cache is not None:
-            # Device-resident per-slot state: only rows dirtied since the
-            # last dispatch are shipped; a clean step uploads nothing and
-            # _decode_block_tables' full rebuild becomes a row update.
-            state_args = self._state_cache.sync(
-                self._state_mirrors(), self._masked_rows())
-        else:
-            state_args = (
-                jnp.asarray(self._decode_block_tables()),
-                jnp.asarray(self._slot_keys),
-                jnp.asarray(self._gen_counts),
-                jnp.asarray(self._temperature), jnp.asarray(self._top_k),
-                jnp.asarray(self._top_p),
-            )
-            if self.adapter_pool is not None:
-                state_args += (jnp.asarray(self._adapter_ids),)
-            elif self.executor._recurrent:
-                state_args += (jnp.asarray(self._state_slots),)
-        args = (self.params, self.cache, jnp.asarray(ids), jnp.asarray(pos),
-                *state_args)
-        if self.adapter_pool is not None:
-            # The pool tree rides LAST; NOT donated — an in-flight async
-            # window may still read the previous buffers, and a one-row
-            # scatter (acquire miss) rebinds pool.tree between windows.
-            args = args + (self.adapter_pool.tree,)
+        # Device-resident per-slot state: only rows dirtied since the
+        # last dispatch are shipped; a clean step uploads nothing.
+        staged = self.executor.stage_decode(
+            ids, pos, self._state_mirrors(), self._masked_rows())
         # Host prep cost of this dispatch (batch assembly + state sync) —
         # the term dirty tracking is meant to hold flat as max_seqs grows.
         self.telemetry.host_prep.observe(time.perf_counter() - t_prep)
-        return ("plain", active, k_steps, args)
+        return ("plain", active, k_steps, staged)
 
-    def _decode_launch(self, active: List[_Slot], k_steps: int, args):
-        """The compiled decode call (not waited for) and the resident
-        counts' advance."""
-        if k_steps > 1:
-            fn = self._multi_decode_fns.get(k_steps)
-            if fn is None:
-                fn = self._multi_decode_fns[k_steps] = \
-                    self._build_multi_decode_fn(k_steps)
-            self.cache, tokens, logprobs = fn(*args)
-        else:
-            self.cache, tokens, logprobs = self._decode_fn(*args)
-            tokens = tokens[:, None]
-            logprobs = logprobs[:, None]
-        if self._state_cache is not None:
-            # The window advances every surviving slot's gen count by
-            # exactly k_steps (a slot finishing mid-window is released,
-            # which marks it dirty) — advance the resident counts on
-            # device instead of re-uploading the one every-step mirror.
-            self._state_cache.bump_gen_counts(k_steps)
-        return ("plain", active, k_steps, tokens, logprobs)
+    def _decode_launch(self, active: List[_Slot], k_steps: int, staged):
+        """The compiled decode call (not waited for)."""
+        return ("plain", active, k_steps,
+                self.executor.launch_decode(staged, k_steps))
 
     def _decode_complete(self, pending) -> List[Request]:
         """Sync a dispatched decode round's results and walk emissions."""
         tr = self._tracer
-        kind, *device = pending
+        kind, *plan, device = pending
         # The wait ends when the round's results are on the host; until
         # then the chip is at work. The emission walk after it is host
         # time during which nothing is in flight.
         with tr.span("engine/decode_wait", cat="engine"):
-            host = [np.asarray(jax.device_get(x)) if isinstance(x, jax.Array)
-                    else x for x in device]
+            host = self.executor.fetch(device)
         with tr.span("engine/decode_emit", cat="engine"):
             walk = self._spec_emit if kind == "spec" else self._decode_emit
-            return walk(*host)
+            return walk(*plan, *host)
 
     def _decode_emit(self, active: List[_Slot], k_steps: int,
                      tokens: np.ndarray, logprobs: np.ndarray,
@@ -2309,6 +1361,7 @@ class InferenceEngine:
 
         finished = []
         for s in active:
+            req = s.request  # (retirement clears the slot's)
             for k in range(k_steps):
                 # Per-step occupancy: a slot that hits EOS mid-window
                 # stops counting here, so occupancy stays honest at large
@@ -2322,7 +1375,7 @@ class InferenceEngine:
                     # Tokens sampled after EOS/limit in this window are
                     # discarded (their stale KV writes sit past seq_len in
                     # the freed tail blocks — never registered or read).
-                    finished.append(s.request)
+                    finished.append(req)
                     break
         return finished
 
@@ -2395,13 +1448,7 @@ class InferenceEngine:
         slots in cooldown — is masked to single-step inside the program.
         ``k`` is the ladder draft length picked for this round."""
         ec = self.cfg
-        if self._state_cache is not None:
-            # The spec path ships the mirrors directly (it uploads the
-            # full token history anyway) and emits a variable number of
-            # tokens per slot — the resident copies are stale wholesale
-            # after this round.
-            self._state_cache.mark_all_dirty()
-        R = self._spec_rounds
+        R = ec.spec_rounds
         t_in = np.zeros((ec.max_seqs,), np.int32)
         seq_len = np.zeros((ec.max_seqs,), np.int32)
         spec_mask = np.zeros((ec.max_seqs,), np.bool_)
@@ -2425,25 +1472,15 @@ class InferenceEngine:
         while width < nblk:
             width *= 2
         width = min(width, ec.max_blocks_per_seq)
-        lora_args = ()
-        if self.adapter_pool is not None:
-            lora_args = (jnp.asarray(self._adapter_ids),
-                         self.adapter_pool.tree)
-        args = (
-            jnp.asarray(self._spec_hist), jnp.asarray(t_in),
-            jnp.asarray(seq_len), jnp.asarray(spec_mask),
-            jnp.asarray(self._decode_block_tables()[:, :width]),
-            jnp.asarray(self._slot_keys), jnp.asarray(self._gen_counts),
-            jnp.asarray(self._temperature), jnp.asarray(self._top_k),
-            jnp.asarray(self._top_p), *lora_args,
-        )
-        return ("spec", active, spec_mask, k, args)
+        staged = self.executor.stage_spec(
+            self._spec_hist, t_in, seq_len, spec_mask,
+            self._state_mirrors(), self._masked_rows(), table_width=width)
+        return ("spec", active, spec_mask, k, staged)
 
-    def _spec_launch(self, active: List[_Slot], spec_mask, k: int, args):
+    def _spec_launch(self, active: List[_Slot], spec_mask, k: int, staged):
         """Dispatch the spec program (no sync)."""
-        self.cache, toks, lps, emit, prop, acc = self._spec_fn_for(k)(
-            self.params, self.cache, *args)
-        return ("spec", active, spec_mask, toks, lps, emit, prop, acc)
+        return ("spec", active, spec_mask,
+                self.executor.launch_spec(staged, k))
 
     def _spec_emit(self, active: List[_Slot], spec_mask: np.ndarray,
                    toks: np.ndarray, lps: np.ndarray, emit: np.ndarray,
@@ -2454,7 +1491,7 @@ class InferenceEngine:
         accepted prefix + bonus; sampling: exactly one); the host consumes
         them in order, stopping a slot at EOS/limit and discarding the
         rest of its window (same contract as multi-step decode)."""
-        R = self._spec_rounds
+        R = self.cfg.spec_rounds
         self.stats["decode_steps"] += R
 
         # Numeric guard over every EMITTED token (rejected draft
@@ -2474,6 +1511,7 @@ class InferenceEngine:
         finished = []
         for s in active:
             sid = s.slot_id
+            req = s.request  # (retirement clears the slot's)
             # Only unmasked greedy slots actually proposed this round —
             # masked slots (sampling, or greedy in cooldown) ran single-
             # step and must not feed the acceptance windows.
@@ -2498,7 +1536,7 @@ class InferenceEngine:
                     done = self._append_token(s, int(toks[sid, r, j]),
                                               float(lps[sid, r, j]))
                     if done:
-                        finished.append(s.request)
+                        finished.append(req)
                         break
                 if done:
                     break
@@ -2547,8 +1585,8 @@ class InferenceEngine:
         """Drop the request's adapter-pool pin and reset it to unresolved
         (idempotent). Preemption and failover re-acquire at re-admission
         — the row may legitimately be LRU-evicted in between."""
-        if self.adapter_pool is not None and req._adapter_slot > 0:
-            self.adapter_pool.release(req._adapter_slot)
+        if self.executor.adapter_pool is not None and req._adapter_slot > 0:
+            self.executor.adapter_pool.release(req._adapter_slot)
         req._adapter_slot = -1
 
     def _fail_waiting(self, req: Request, msg: str) -> None:
@@ -2599,7 +1637,7 @@ class InferenceEngine:
         # again, and the next admission starts from zero (position 0).
         self._state_slots[slot.slot_id] = self.cfg.max_seqs
         self._spec_reset_slot(slot.slot_id)
-        self._mark_state_dirty(slot.slot_id)
+        self.executor.mark_dirty(slot.slot_id)
 
     # ------------------------------------------------------------------
     # Disaggregated prefill/decode handoff (serving/disagg.py)
@@ -2669,10 +1707,10 @@ class InferenceEngine:
         # caller retries or degrades to a re-prefill, where _admit's
         # resolution path owns failing the request properly.
         if req.adapter and req._adapter_slot < 0:
-            if self.adapter_pool is None:
+            if self.executor.adapter_pool is None:
                 return False
             try:
-                row, _ = self.adapter_pool.acquire(req.adapter)
+                row, _ = self.executor.adapter_pool.acquire(req.adapter)
             except AdapterError:
                 return False
             if row < 0:
@@ -2702,7 +1740,7 @@ class InferenceEngine:
         self._slot_keys[slot.slot_id] = snap["slot_key"]
         self._gen_counts[slot.slot_id] = snap["gen_count"]
         self._adapter_ids[slot.slot_id] = max(req._adapter_slot, 0)
-        self._mark_state_dirty(slot.slot_id)
+        self.executor.mark_dirty(slot.slot_id)
         if self._spec_hist is not None:
             ctx = req.prompt_token_ids + req.output_token_ids
             self._spec_hist[slot.slot_id, : len(ctx)] = ctx

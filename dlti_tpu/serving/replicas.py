@@ -370,7 +370,7 @@ class ReplicatedEngine:
         job."""
         import jax.numpy as jnp
 
-        leaves, treedef = jax.tree_util.tree_flatten(eng.params)
+        leaves, treedef = jax.tree_util.tree_flatten(eng.executor.params)
         for j, leaf in enumerate(leaves):
             if (hasattr(leaf, "dtype")
                     and jnp.issubdtype(leaf.dtype, jnp.inexact)):
@@ -379,7 +379,8 @@ class ReplicatedEngine:
                     leaf.sharding)
                 leaves[j] = poisoned
                 break
-        eng.params = jax.tree_util.tree_unflatten(treedef, leaves)
+        eng.executor.params = jax.tree_util.tree_unflatten(
+            treedef, leaves)
         self.logger.warning(
             "chaos: poisoned replica %d params with NaN (nan-logits "
             "fault injection)", idx)

@@ -249,12 +249,6 @@ def parse_args():
     p.add_argument("--quantization", default="none", choices=["none", "int8"],
                    help="weight-only quantization (int8 + per-channel scales; "
                         "~halves weight HBM)")
-    p.add_argument("--no-decode-state-cache", action="store_true",
-                   help="disable the device-resident decode-state cache "
-                        "(per-slot dirty tracking; clean decode steps "
-                        "upload no host state) and re-upload every mirror "
-                        "each step — debugging/A-B only, outputs are "
-                        "byte-identical either way")
     p.add_argument("--speculative", default="none", choices=["none", "ngram"],
                    help="n-gram prompt-lookup speculative decoding (exact "
                         "greedy outputs, multiple tokens per model call)")
@@ -281,12 +275,6 @@ def parse_args():
                         "instead of picking it per round from live "
                         "per-slot acceptance (the pow2 draft-length "
                         "ladder; outputs are byte-identical either way)")
-    p.add_argument("--ragged-prefill", action="store_true",
-                   help="pack prefill chunks from many admissions into "
-                        "shared ragged program calls (group width = "
-                        "widest member, padding bounded) instead of one "
-                        "call per length bucket — fewer dispatches under "
-                        "multi-admission waves, byte-identical outputs")
     p.add_argument("--trace-dir", default="",
                    help="enable the host-side span tracer (per-request "
                         "lifecycle + engine step phases) and export a "
@@ -467,8 +455,6 @@ def main() -> None:
         spec_cooldown=args.spec_cooldown,
         spec_adaptive=not args.no_spec_adaptive,
         max_prefill_tokens_per_step=args.max_prefill_tokens,
-        ragged_prefill=args.ragged_prefill,
-        decode_state_cache=not args.no_decode_state_cache,
         guard_nonfinite=not args.no_numeric_guard,
         guard_token_storm=args.guard_token_storm,
         memory_ledger=not args.no_memory_ledger,

@@ -307,7 +307,7 @@ def test_engine_memledger_owner_matches_plan(setup):
                           _ec(adapter_slots=3, adapter_rank=R))
     snap = eng.memledger.snapshot()
     measured = snap["owners"]["lora_adapters"]["bytes"]
-    assert measured == eng.adapter_pool.nbytes
+    assert measured == eng.executor.adapter_pool.nbytes
     assert measured == memory_plan.adapter_pool_bytes(CFG, 3, R, TARGETS)
     plan = memory_plan.plan_serving(CFG, adapter_slots=3, adapter_rank=R,
                                     adapter_targets=TARGETS)
@@ -417,7 +417,7 @@ def _check_equivalence(setup, shared_base, merged, quant, logprob_atol):
         shared.step()
         # The heterogeneous batch is real: both adapters resident, several
         # rows in flight in the SAME engine at once.
-        assert shared.adapter_pool.loaded_names() == ["ad-a", "ad-b"]
+        assert shared.executor.adapter_pool.loaded_names() == ["ad-a", "ad-b"]
         assert shared.num_active >= 2
         got = _drain(shared, reqs)
         for (prompt, name), g in zip(assign, got):
@@ -484,7 +484,7 @@ def test_hot_register_while_engine_is_mid_decode(setup):
     hot = eng.submit(PROMPTS[1], GREEDY, adapter="ad-hot")
     res = _drain(eng, [long_req, hot])
     assert [r.finish_reason for r in res] == ["length", "length"]
-    assert eng.adapter_pool.resident("ad-hot")
+    assert eng.executor.adapter_pool.resident("ad-hot")
     want = InferenceEngine(CFG, setup.merged["ad-a"], _ec()).generate(
         [PROMPTS[1]], GREEDY)[0]
     assert res[1].output_token_ids == want.output_token_ids

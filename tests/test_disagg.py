@@ -3,8 +3,8 @@
 Layers, mirroring the subsystem's own structure:
 
 * **Scheduler/executor split**: the engine's device half lives on
-  :class:`EngineExecutor`; the engine proper is host scheduling plus
-  delegation — the unit contract the disagg controller builds on.
+  :class:`EngineExecutor`; the engine proper is host scheduling alone —
+  the unit contract the disagg controller builds on.
 * **Paged-KV handoff**: block payloads fetched from a prefill engine and
   scattered into a decode engine are byte-equal on arrival, for bf16 AND
   int8 pools (scales travel with the payload).
@@ -34,7 +34,8 @@ from dlti_tpu.models import LlamaForCausalLM
 from dlti_tpu.serving import (
     DisaggController, EngineConfig, InferenceEngine, SamplingParams,
 )
-from dlti_tpu.serving.engine import EngineExecutor, Request
+from dlti_tpu.serving.engine import Request
+from dlti_tpu.serving.executor import EngineExecutor
 from dlti_tpu.telemetry.ledger import note_readmitted, note_requeue
 
 CFG = MODEL_PRESETS["llama_tiny"]
@@ -61,20 +62,24 @@ PROMPTS = [[1, 2, 3, 4, 5], [6, 7, 8], [9, 10, 11, 12], [13, 14]]
 # Scheduler/executor split
 # ----------------------------------------------------------------------
 
-def test_executor_owns_device_half_and_engine_delegates(tiny_params):
+def test_executor_owns_device_half_and_engine_holds_none_of_it(tiny_params):
     eng = InferenceEngine(CFG, tiny_params, _ec())
     assert isinstance(eng.executor, EngineExecutor)
-    # Delegation is identity, not a copy: the engine's params/cache ARE
-    # the executor's (replica NaN-poisoning and the memledger lambdas
-    # depend on writing through).
-    assert eng.params is eng.executor.params
-    assert eng.cache is eng.executor.cache
+    # One owner: what replica NaN-poisoning rebinds on the executor is
+    # what the programs and the memledger's owner read — the engine keeps
+    # no second handle on any of it, by any of its old names.
+    for name in ("params", "cache", "model", "adapter_pool", "_device",
+                 "_decode_fn", "_prefill_fns", "_multi_decode_fns",
+                 "_spec_fn", "_sample_fn", "_fold_keys", "_aot_or_jit",
+                 "_fetch_block_kv", "_restore_block", "_state_cache"):
+        assert not hasattr(eng, name), name
     marker = jax.tree_util.tree_map(lambda x: x, eng.executor.params)
-    eng.params = marker
-    assert eng.executor.params is marker
-    # The block transport the handoff rides lives on the executor class;
-    # the engine keeps only thin delegating wrappers.
-    for name in ("fetch_block_kv", "restore_block"):
+    eng.executor.params = marker
+    assert eng.memledger._owners["params"]() is marker
+    # The program calls and the block transport the handoff rides live on
+    # the executor class alone.
+    for name in ("prefill", "stage_decode", "launch_decode", "stage_spec",
+                 "launch_spec", "fetch", "fetch_block_kv", "restore_block"):
         assert name in EngineExecutor.__dict__
         assert name not in InferenceEngine.__dict__
 
@@ -128,7 +133,7 @@ def test_handoff_blocks_byte_equal_after_restore(tiny_params, kv_dtype):
         assert any("scale" in k for k in layer0)
     assert dst.adopt_handoff(snap)
     slot = next(s for s in dst.slots if s.request is req)
-    for got, sent in zip((dst._fetch_block_kv(b) for b in slot.blocks),
+    for got, sent in zip((dst.executor.fetch_block_kv(b) for b in slot.blocks),
                          snap["payloads"]):
         assert got is not None
         assert set(got) == set(sent)

@@ -659,13 +659,13 @@ def test_replica_warmup_aot_stays_engaged_off_default_device(devices):
                        SamplingParams(max_tokens=5, temperature=0.0))
     assert rep.num_live == 2 and rep.failover["replica_faults"] == 0
     for eng in rep.engines:
-        assert eng._decode_fn._aot_state["aot"], \
+        assert eng.executor._decode_fn._aot_state["aot"], \
             "replica fell off the AOT decode path"
     # Placement agrees end to end: each replica's KV pool is committed to
     # its own params' device (jit migration no longer papers over it).
     for eng in rep.engines:
-        p_dev = next(iter(jax.tree_util.tree_leaves(eng.params)[0].devices()))
-        c_dev = next(iter(jax.tree_util.tree_leaves(eng.cache)[0].devices()))
+        p_dev = next(iter(jax.tree_util.tree_leaves(eng.executor.params)[0].devices()))
+        c_dev = next(iter(jax.tree_util.tree_leaves(eng.executor.cache)[0].devices()))
         assert p_dev == c_dev
     single = InferenceEngine(CFG, params, ec).generate(
         [[1, 2, 3]], SamplingParams(max_tokens=5, temperature=0.0))

@@ -431,7 +431,7 @@ def test_rolling_reload_swaps_new_weights_with_zero_errors(tiny_params):
     # Every replica actually holds the new weights now.
     want = jax.tree_util.tree_leaves(new_host)[0]
     for e in rep.engines:
-        got = np.asarray(jax.tree_util.tree_leaves(e.params)[0])
+        got = np.asarray(jax.tree_util.tree_leaves(e.executor.params)[0])
         np.testing.assert_allclose(got, want, rtol=1e-6)
     # The canary digest was re-pinned against the new weights.
     assert rep._canary_digest is not None

@@ -302,8 +302,6 @@ SCENARIOS = {
         lengths=[45, 23], engine=dict(max_prefill_tokens_per_step=16)),
     # four decode steps a program call: the states ride the scan's carry
     "multi_step": dict(lengths=[12, 30], engine=dict(steps_per_sync=4)),
-    "no_state_cache": dict(lengths=[12, 30],
-                           engine=dict(decode_state_cache=False)),
 }
 
 
@@ -335,12 +333,16 @@ def test_engine_prefill_then_decode_agrees_with_full_forward(tiny, name):
     assert (eng._state_slots == eng.cfg.max_seqs).all()  # all released
 
 
-def test_preemption_drops_the_state_and_readmission_rebuilds_it(tiny):
+# "chunked": the victim may be dropped with its state half built, and the
+# re-admission feeds prompt + answer so far 16 tokens a step.
+@pytest.mark.parametrize("over", [{}, dict(max_prefill_tokens_per_step=16)],
+                         ids=["throughput", "chunked"])
+def test_preemption_drops_the_state_and_readmission_rebuilds_it(tiny, over):
     prompts = _prompts([30, 28, 26], seed=9)
     sp = SamplingParams(max_tokens=30, temperature=0.0)
     roomy = _engine(tiny, max_model_len=64).generate(prompts, sp)
     # 11 allocatable blocks of 8 for three sequences that grow to 8 each
-    tight = _engine(tiny, max_model_len=64, num_blocks=12)
+    tight = _engine(tiny, max_model_len=64, num_blocks=12, **over)
     squeezed = tight.generate(prompts, sp)
     assert tight.stats["preemptions"] > 0
     assert tight.stats["recurrent_state_resets"] > len(prompts)
@@ -423,11 +425,11 @@ def test_llama_programs_take_no_new_argument():
         cache_dtype="float32"))
     assert eng.executor.counter_names == () and not eng.executor._recurrent
     assert eng._prefill_rows(2048) == 8  # no model limit: as before
-    assert eng._state_cache._fields[-1] == "top_p"
+    assert eng.executor.decode_state._fields[-1] == "top_p"
     res = eng.generate(_prompts([9]), SamplingParams(max_tokens=4,
                                                      temperature=0.0))
     assert len(res[0].output_token_ids) == 4
-    assert all("ssm" not in layer for layer in eng.cache)
+    assert all("ssm" not in layer for layer in eng.executor.cache)
     assert "moe_assignments" not in eng.stats
     assert eng.recurrent_state_pool_bytes == 0
 

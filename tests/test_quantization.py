@@ -96,10 +96,10 @@ def test_int8_tp_engine_matches_unsharded_int8(model_and_params):
     tp_engine = InferenceEngine(CFG, params, ec, mesh=mesh)
     # Quantized kernels really are sharded: q_proj q-leaf over its out dim,
     # its scale alongside; down_proj (row-parallel) scale replicated.
-    qp = tp_engine.params["model"]["layers_0"]["attn"]["q_proj"]["kernel"]
+    qp = tp_engine.executor.params["model"]["layers_0"]["attn"]["q_proj"]["kernel"]
     assert qp["q"].sharding.spec[1] == "tensor"
     assert qp["scale"].sharding.spec[1] == "tensor"
-    dp = tp_engine.params["model"]["layers_0"]["mlp"]["down_proj"]["kernel"]
+    dp = tp_engine.executor.params["model"]["layers_0"]["mlp"]["down_proj"]["kernel"]
     assert dp["q"].sharding.spec[0] == "tensor"
     assert all(s is None for s in dp["scale"].sharding.spec)
     got = tp_engine.generate(prompts, sp)

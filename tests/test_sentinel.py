@@ -490,7 +490,7 @@ def test_engine_guard_trips_on_nan_params_before_streaming():
     eng.step()  # a decode step
     n_before = len(req.output_token_ids)
     assert n_before >= 1
-    eng.params = _nan_params(eng.params)
+    eng.executor.params = _nan_params(eng.executor.params)
     with pytest.raises(NumericFault):
         for _ in range(4):
             eng.step()

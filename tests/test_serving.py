@@ -332,7 +332,7 @@ def test_engine_tensor_parallel_matches_single_device(tiny_model_and_params):
     mesh = build_mesh(ParallelConfig(tensor=2), devices=jax.devices()[:2])
     tp_engine = InferenceEngine(CFG, params, ec, mesh=mesh)
     # Weights and pools really are sharded.
-    k0 = tp_engine.cache[0]["k"]
+    k0 = tp_engine.executor.cache[0]["k"]
     assert k0.sharding.spec[2] == "tensor"
     got = tp_engine.generate(prompts, sp)
     for g, w in zip(got, want):
@@ -394,13 +394,13 @@ def test_warmup_ladder_aot_dispatch_matches_cold(tiny_model_and_params):
     warm = mk(4)
     warm.warmup_decode_ladder()
     warm.warmup_decode_ladder()  # idempotent: re-warm must not crash
-    assert hasattr(warm._decode_fn, "_aot_state")
+    assert hasattr(warm.executor._decode_fn, "_aot_state")
     got = warm.generate(prompts, sp)
     for g, w in zip(got, want):
         assert g.output_token_ids == w.output_token_ids
     # Every ladder program dispatched through its compiled executable.
-    assert warm._decode_fn._aot_state["aot"]
-    for k, fn in warm._multi_decode_fns.items():
+    assert warm.executor._decode_fn._aot_state["aot"]
+    for k, fn in warm.executor._multi_decode_fns.items():
         assert getattr(fn, "_aot_state", {"aot": True})["aot"], k
 
 
@@ -579,7 +579,7 @@ def test_replicated_engine_single_chip_replicas(tiny_model_and_params):
                       cache_dtype="float32", eos_token_id=-1)
     rep = ReplicatedEngine(CFG, params, ec, replicas=2, tensor=1,
                            devices=jax.devices()[:2])
-    devs = [next(iter(jax.tree_util.tree_leaves(e.params)[0].devices()))
+    devs = [next(iter(jax.tree_util.tree_leaves(e.executor.params)[0].devices()))
             for e in rep.engines]
     assert devs[0] != devs[1]
     out = rep.generate([[1, 2, 3], [4, 5, 6]],
@@ -607,7 +607,7 @@ def test_engine_commits_host_params_to_device(tiny_model_and_params):
     ec = EngineConfig(max_seqs=2, block_size=8, num_blocks=32,
                       max_model_len=48, cache_dtype="float32", eos_token_id=-1)
     eng = InferenceEngine(CFG, host_params, ec)
-    leaves = jax.tree_util.tree_leaves(eng.params)
+    leaves = jax.tree_util.tree_leaves(eng.executor.params)
     assert all(isinstance(v, jax.Array) for v in leaves)
     dev = jax.devices()[0]
     assert all(next(iter(v.devices())) == dev for v in leaves)

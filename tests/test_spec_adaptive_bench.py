@@ -24,7 +24,7 @@ def test_spec_adaptive_bench_smoke(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [sys.executable, BENCH, "--cpu", "--runs", "1", "--max-tokens",
-         "48", "--wave", "8", "--json-out", str(out)],
+         "48", "--json-out", str(out)],
         env=env, capture_output=True, text=True, timeout=600, cwd=REPO)
     assert proc.returncode == 0, proc.stderr[-1500:]
     report = json.loads(out.read_text())
@@ -38,17 +38,16 @@ def test_spec_adaptive_bench_smoke(tmp_path):
         assert len(report[trace]["spec_tok_s_all"]) == 1
     # The favorable trace genuinely speculated on this cold run.
     assert report["favorable"]["draft_acceptance"] > 0.5
-    assert report["ragged_prefill"]["outputs_equal"] is True
     for key in ("what", "platform", "steps_per_sync", "num_draft_tokens",
-                "favorable", "adversarial", "ragged_prefill", "date"):
+                "favorable", "adversarial", "date"):
         assert key in report, key
 
 
 def test_committed_artifact_meets_the_bar():
     """The checked-in results/spec_adaptive_cpu.json is the PR's
     evidence; pin the acceptance bars (≥20% favorable win, ≤5%
-    adversarial regression with the gate on, outputs_equal every arm,
-    ragged TTFT p99 no worse than bucketed) so a regenerated artifact
+    adversarial regression with the gate on, outputs_equal every arm)
+    so a regenerated artifact
     that misses them fails CI instead of silently shipping — the r03
     artifact this replaces recorded a 0.103 "speedup" measured across
     in-window XLA compiles."""
@@ -63,7 +62,3 @@ def test_committed_artifact_meets_the_bar():
     assert adv["speedup"] >= 0.95
     # The adversarial trace exercised the gate, not an accidental win.
     assert adv["spec_paused_rounds"] > 0
-    rag = report["ragged_prefill"]
-    assert rag["outputs_equal"] is True
-    assert rag["ttft_p99_s_on"] <= rag["ttft_p99_s_off"]
-    assert rag["prefill_batches_on"] < rag["prefill_batches_off"]

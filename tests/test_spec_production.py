@@ -18,9 +18,6 @@ production seam — plus the per-slot controller semantics themselves:
   {disagg on/off} × {bf16, int8 KV}, a 2-worker fleet with a planned
   mid-decode drain migration, a multi-LoRA batch vs merged-weights
   oracles, and prefix-tier restores.
-* **Ragged prefill**: total-token-bucketed multi-admission packing is
-  byte-identical to per-bucket prefill while issuing fewer device calls,
-  in both throughput and chunked admission modes.
 
 The tiny random model is the test vocabulary: greedy generation after
 ``[6, 6, 7, 7, ...]`` locks into a period-1 loop (sustained ngram hits,
@@ -379,33 +376,3 @@ def test_spec_prefix_tier_restore_byte_identical(tiny_params, tmp_path):
             [want] = plain.generate([p], sp)
             assert got.output_token_ids == want.output_token_ids
     assert tiered.stats["prefix_restored_tokens"] > 0
-
-
-# ----------------------------------------------------------------------
-# Ragged multi-admission prefill
-# ----------------------------------------------------------------------
-
-RAGGED_PROMPTS = [list(range(2, 2 + n)) for n in (5, 3, 9, 2, 17, 4)]
-
-
-@pytest.mark.parametrize("mode", ["throughput", "chunked"])
-def test_ragged_prefill_byte_identical_with_fewer_batches(tiny_params,
-                                                          mode):
-    over = dict(max_seqs=8, speculative="none")
-    if mode == "chunked":
-        over["max_prefill_tokens_per_step"] = 16
-    sp = SamplingParams(temperature=0.0, max_tokens=6)
-
-    def run(ragged):
-        eng = InferenceEngine(CFG, tiny_params,
-                              _ec(ragged_prefill=ragged, **over))
-        reqs = [eng.submit(p, sp) for p in RAGGED_PROMPTS]
-        _drain(eng, reqs)
-        outs = [(r.output_token_ids, [float(x) for x in r.output_logprobs])
-                for r in reqs]
-        return outs, eng.stats["prefill_batches"]
-
-    off_outs, off_batches = run(False)
-    on_outs, on_batches = run(True)
-    assert on_outs == off_outs  # tokens AND logprobs, byte-for-byte
-    assert on_batches < off_batches  # packing genuinely merged calls
