@@ -418,6 +418,14 @@ class InferenceEngine:
                       # decode_steps is the mean context one step reads
                       # (what the paged-attention kernel's bytes follow).
                       "decode_context_tokens": 0,
+                      # Keys the paged decode kernel's live tiles hold for
+                      # the same rounds: a slot about to attend over n keys
+                      # costs ceil(n / tile) whole tiles (the tile: the
+                      # executor's decode_tile_tokens, the kernel's own
+                      # rule). decode_context_tokens over this is the share
+                      # of what the kernel fetches that is live; a sliding
+                      # window is left out of both.
+                      "decode_kernel_tile_tokens": 0,
                       # Decode steps whose sampling sorted the vocabulary:
                       # some slot's row set top-k or top-p, the predicate
                       # sample_tokens evaluates on the device (read here
@@ -1289,12 +1297,10 @@ class InferenceEngine:
         t_prep = time.perf_counter()
         ids = np.zeros((ec.max_seqs, 1), np.int32)
         pos = np.zeros((ec.max_seqs, 1), np.int32)  # inactive -> trash block
-        context = 0
         for s in active:
             ids[s.slot_id, 0] = s.last_token
             pos[s.slot_id, 0] = s.seq_len  # position of the new token
-            context += s.seq_len
-        self.stats["decode_context_tokens"] += context * k_steps
+        self._book_decode_context(active, k_steps)
         self.stats["decode_steps_sorted_sampling"] += \
             k_steps * self._sampling_sorts()
         # Device-resident per-slot state: only rows dirtied since the
@@ -1439,6 +1445,16 @@ class InferenceEngine:
         self._spec_slot_pause[sid] = 0
         self._spec_slot_ewma[sid] = float(self.cfg.num_draft_tokens)
 
+    def _book_decode_context(self, active: List[_Slot], steps: int) -> None:
+        """What a round of ``steps`` decode steps attends over, booked when it
+        is dispatched: the active slots' cached tokens, and the keys of the
+        kernel tiles that hold them and the new token."""
+        lens = np.fromiter((s.seq_len for s in active), np.int64, len(active))
+        tile = self.executor.decode_tile_tokens
+        self.stats["decode_context_tokens"] += int(lens.sum()) * steps
+        self.stats["decode_kernel_tile_tokens"] += \
+            int((lens // tile + 1).sum()) * tile * steps
+
     def _spec_prepare(self, active: List[_Slot], parts: List[_Slot],
                       k: int):
         """Arguments of the fused propose→verify→accept program.
@@ -1452,12 +1468,10 @@ class InferenceEngine:
         t_in = np.zeros((ec.max_seqs,), np.int32)
         seq_len = np.zeros((ec.max_seqs,), np.int32)
         spec_mask = np.zeros((ec.max_seqs,), np.bool_)
-        context = 0
         for s in active:
             t_in[s.slot_id] = s.last_token
             seq_len[s.slot_id] = s.seq_len
-            context += s.seq_len
-        self.stats["decode_context_tokens"] += context * R
+        self._book_decode_context(active, R)
         self.stats["decode_steps_sorted_sampling"] += \
             R * self._sampling_sorts()
         for s in parts:

@@ -21,6 +21,7 @@ import numpy as np
 from dlti_tpu.config import LoRAConfig, ModelConfig
 from dlti_tpu.models import build_model
 from dlti_tpu.ops.kv_cache import bind_call, init_cache, unbind_call
+from dlti_tpu.ops.pallas.paged_attention import tile_tokens
 from dlti_tpu.serving.decode_state import DecodeStateCache
 from dlti_tpu.serving.sampling import sample_tokens
 from dlti_tpu.telemetry.memledger import MemoryLedger, tree_nbytes
@@ -214,6 +215,13 @@ class EngineExecutor:
         self.pool_bytes = tree_nbytes(self.cache)
         self.recurrent_state_pool_bytes = tree_nbytes(
             [c for c in self.cache if "ssm" in c])
+        # Keys a step of the paged decode kernel covers at this engine's
+        # shapes (the scheduler's decode_kernel_tile_tokens counts in it).
+        token_bytes = next((c["k"].shape[2] * c["k"].shape[3]
+                            * c["k"].dtype.itemsize
+                            for c in self.cache if "k" in c), 0)
+        self.decode_tile_tokens = tile_tokens(
+            ec.block_size, ec.max_blocks_per_seq, token_bytes)
 
         self._restore_fn = None  # lazily-jitted tier/handoff restore scatter
         # Block fetches stage device→host through pinned_host when the

@@ -33,6 +33,7 @@ class ScriptedExecutor:
     adapter_pool = None
     pool_bytes = 0
     recurrent_state_pool_bytes = 0
+    decode_tile_tokens = 4  # keys a grid step of "the kernel" covers
 
     def __init__(self, model_cfg, params, engine_cfg, lora_cfg=None,
                  mesh=None, donate_params=False, stats=None):
@@ -343,7 +344,7 @@ def test_decode_counters_are_booked_as_perf_md_says(name, monkeypatch):
         eng.submit(p, SamplingParams(max_tokens=n, top_k=3 if p[0] == 40
                                      else 0))
     # the books, kept by hand from what the executor was asked to do
-    steps = slot_steps = context = sorted_steps = 0
+    steps = slot_steps = context = tile_keys = sorted_steps = 0
     seen = 0
     while eng.has_work:
         live = {s.slot_id: (s.seq_len, s.request.params.max_tokens
@@ -358,6 +359,9 @@ def test_decode_counters_are_booked_as_perf_md_says(name, monkeypatch):
             steps += k
             # context: what each live slot had cached when the round began
             context += k * sum(seq for seq, _ in live.values())
+            # whole tiles of 4 keys over those tokens and the new one
+            tile_keys += k * sum(4 * -(-(seq + 1) // 4)
+                                 for seq, _ in live.values())
             # a slot counts a step until its answer is complete
             slot_steps += sum(min(k, left) for _, left in live.values())
             sorted_steps += k * sorting
@@ -365,6 +369,7 @@ def test_decode_counters_are_booked_as_perf_md_says(name, monkeypatch):
     assert st["decode_steps"] == steps > 0
     assert st["decode_slot_steps"] == slot_steps
     assert st["decode_context_tokens"] == context
+    assert st["decode_kernel_tile_tokens"] == tile_keys > context
     assert st["decode_steps_sorted_sampling"] == sorted_steps > 0
     # the first token of each answer is the prefill's; the rest are decode's
     assert st["decode_slot_steps"] == sum(lengths) - len(lengths)
@@ -373,6 +378,33 @@ def test_decode_counters_are_booked_as_perf_md_says(name, monkeypatch):
         # counter i reads i + 1 on every prefill call and every decode step
         assert st[f"{key}_decode"] == (i + 1) * steps
         assert st[key] == (i + 1) * (steps + st["prefill_batches"])
+
+
+@pytest.mark.parametrize("tile", [4, 16])
+def test_live_share_of_the_kernels_tiles_on_a_hand_made_batch(tile,
+                                                              monkeypatch):
+    """Prompts of 3, 8 and 13 tokens, one decode step each at a time:
+    ``decode_context_tokens / decode_kernel_tile_tokens`` is the share of
+    the keys the kernel's live tiles hold that are live. First decode step,
+    tiles of 4: contexts 3 + 8 + 13 = 24 against tiles 4 + 12 + 16 = 32 (the
+    new token opens a tile for the 8 and fills one for the 3); tiles of 16:
+    24 against 48."""
+    monkeypatch.setattr(ScriptedExecutor, "decode_tile_tokens", tile)
+    eng = _engine(steps_per_sync=1, max_seqs=3)
+    for n in (3, 8, 13):
+        eng.submit(list(range(10, 10 + n)), SamplingParams(max_tokens=3))
+    while not eng.stats["decode_steps"]:
+        eng.step()
+    st = eng.stats
+    assert st["decode_steps"] == 1
+    assert st["decode_context_tokens"] == 24
+    assert st["decode_kernel_tile_tokens"] == {4: 32, 16: 48}[tile]
+    _drain(eng)
+    # second step: contexts 4, 9, 14 -> tiles of 4: 8 + 12 + 16; of 16: 16 x 3
+    assert st["decode_context_tokens"] == 24 + 27
+    assert st["decode_kernel_tile_tokens"] == {4: 32 + 36, 16: 48 + 48}[tile]
+    share = st["decode_context_tokens"] / st["decode_kernel_tile_tokens"]
+    assert share == {4: 51 / 68, 16: 51 / 96}[tile]
 
 
 def test_warmup_hands_the_mirrors_to_the_executor_by_name():

@@ -25,6 +25,7 @@ from dlti_tpu.config import (
     TrainConfig,
 )
 from dlti_tpu.models import LlamaForCausalLM
+from dlti_tpu.ops.pallas.paged_attention import tile_tokens
 from dlti_tpu.serving import EngineConfig, InferenceEngine, SamplingParams
 from dlti_tpu.serving.server import AsyncEngine
 from dlti_tpu.telemetry import configure_tracer, get_tracer, startup
@@ -336,6 +337,12 @@ def test_decode_context_tokens_against_a_hand_count(tiny_params, speculative):
         assert got == sum(3 * len(p) + 3 for p in prompts) == 36
         assert eng.stats["decode_steps"] == 3
         assert got / eng.stats["decode_steps"] == 12.0  # mean context a step
+        # the kernel's tiles over the same steps: every context (and its new
+        # token) fits one tile of the executor's size
+        tile = eng.executor.decode_tile_tokens
+        assert tile >= 16 and tile == tile_tokens(
+            eng.cfg.block_size, eng.cfg.max_blocks_per_seq)
+        assert eng.stats["decode_kernel_tile_tokens"] == 3 * 3 * tile
     else:
         # every dispatched round has every live slot's context at its start
         assert got >= sum(len(p) for p in prompts)

@@ -1,0 +1,71 @@
+"""The paged decode kernel compiles for the v5e at the geometries that
+``tests/benchmark/test_bench_kernels_v5e.py`` (the benchmark's file) does not
+hold: nemotron3_nano_30b's two kv heads with sixteen query heads each, and an
+int8 pool with its scale tiles. Nothing runs: the TPU compiler installed here
+compiles for a chip that is described, not attached. The topology is
+described inside a module-scoped fixture and never at import."""
+
+import pytest
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means: cannot describe
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip can be written to the persistent
+    # cache but not read back without the chip: keep these out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+# (query heads, kv heads, window, pool dtype); every engine of the serving
+# cells has 32 rows, 4,096 blocks of 16 and 256 blocks a row
+GEOMETRIES = {
+    "nemotron3_nano_30b": (32, 2, None, "bfloat16"),
+    "int8_pool_32q_8kv": (32, 8, None, "int8"),
+    "int8_pool_windowed": (32, 8, 4096, "int8"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(GEOMETRIES))
+def test_paged_decode_kernel_compiles_for_the_v5e(one_chip, name):
+    import jax
+    import jax.numpy as jnp
+
+    from dlti_tpu.ops.pallas.paged_attention import paged_decode_attention
+
+    heads, kv_heads, window, pool_dtype = GEOMETRIES[name]
+    batch, block, head_dim, blocks, max_blocks = 32, 16, 128, 4096, 256
+
+    def shape(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q = shape((batch, 1, heads, head_dim), jnp.bfloat16)
+    pool = shape((blocks, block, kv_heads, head_dim), jnp.dtype(pool_dtype))
+    args = [q, pool, pool, shape((batch, max_blocks), jnp.int32),
+            shape((batch,), jnp.int32)]
+    if pool_dtype == "int8":
+        scales = shape((blocks, block, kv_heads), jnp.float32)
+        args += [scales, scales]
+
+    def decode(q, k, v, tables, lens, k_scale=None, v_scale=None):
+        return paged_decode_attention(q, k, v, tables, lens, k_scale=k_scale,
+                                      v_scale=v_scale, window=window)
+
+    compiled = jax.jit(decode).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
