@@ -128,7 +128,9 @@ class ModelConfig:
     # scores all ``moe_num_experts``; this process holds (and computes)
     # experts [moe_held_start, moe_held_start + moe_held_count) — 0 = all —
     # as expert parallelism gives a chip its share. top-k is
-    # ``num_experts_per_tok``; experts are ungated ``mlp_activation`` MLPs.
+    # ``num_experts_per_tok``; ``mlp_activation`` gives an expert's form:
+    # "relu2" ungated, down(relu(up x)^2), as nemotron_h's are; "silu"
+    # gated, down(silu(gate x) * up x), as the deepseek_v3 family's.
     moe_num_experts: int = 0
     moe_held_start: int = 0
     moe_held_count: int = 0
@@ -136,6 +138,20 @@ class ModelConfig:
     moe_shared_intermediate_size: int = 0  # 0 = no shared expert
     moe_scoring: str = "sigmoid_bias"  # sigmoid + selection bias, renormed
     moe_routed_scaling: float = 1.0
+    # Latent attention (MLA, the deepseek_v3 family; models.latent).
+    # ``kv_lora_rank`` > 0 selects it: a token's cache entry is one row of
+    # ``kv_lora_rank + qk_rope_head_dim`` values a layer (the normed latent
+    # and one rotary key shared by all heads), from which keys
+    # (``qk_nope_head_dim`` a head) and values (``v_head_dim``) are
+    # up-projected, or into which the queries are absorbed. The first
+    # ``first_k_dense`` layers have a dense MLP of ``intermediate_size``,
+    # the rest HeldExpertsMLP.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False  # rotary pairs (2i, 2i+1), not halves
+    first_k_dense: int = 0
 
     def __post_init__(self):
         if self.layer_pattern and (
@@ -178,6 +194,12 @@ class ModelConfig:
     def has_recurrent_state(self) -> bool:
         return "M" in self.layer_pattern
 
+    @property
+    def latent_dim(self) -> int:
+        """Width of a token's latent cache row (0: no latent attention)."""
+        return self.kv_lora_rank + self.qk_rope_head_dim \
+            if self.kv_lora_rank else 0
+
     def _count_params(self, include_lm_head: bool, active_only: bool) -> int:
         h, m, v = self.hidden_size, self.intermediate_size, self.vocab_size
         hd = self.resolved_head_dim
@@ -187,6 +209,28 @@ class ModelConfig:
         attn = q + kv + o
         if self.attention_bias:
             attn += (self.num_heads + 2 * self.num_kv_heads) * hd
+        if self.kv_lora_rank:
+            # Latent attention, then a dense MLP (the leading layers) or the
+            # held experts with their router, bias and shared expert.
+            r, nope, rope_d, vd = (self.kv_lora_rank, self.qk_nope_head_dim,
+                                   self.qk_rope_head_dim, self.v_head_dim)
+            attn = (h * self.num_heads * (nope + rope_d) + h * (r + rope_d)
+                    + r + r * self.num_heads * (nope + vd)
+                    + self.num_heads * vd * h)
+            f = self.moe_intermediate_size
+            mats = 3 if self.mlp_activation == "silu" else 2  # gated or not
+            n_routed = (self.num_experts_per_tok * self.moe_held
+                        / max(1, self.moe_num_experts)
+                        if active_only else self.moe_held)
+            experts = (int(n_routed * mats * h * f)
+                       + mats * h * self.moe_shared_intermediate_size
+                       + h * self.moe_num_experts + self.moe_num_experts)
+            dense = min(self.first_k_dense, self.num_layers)
+            total = (v * h + h + self.num_layers * (attn + 2 * h)
+                     + dense * 3 * h * m + (self.num_layers - dense) * experts)
+            if include_lm_head and not self.tie_embeddings:
+                total += h * v
+            return total
         if self.layer_pattern:
             # One mixer and one norm a layer; of the routed experts, those
             # held here (active: top-k of the router's width, of which the
@@ -1071,6 +1115,18 @@ MODEL_PRESETS: dict = {
         mamba_state_size=16, mamba_chunk_size=8,
         moe_num_experts=8, num_experts_per_tok=3, moe_intermediate_size=48,
         moe_shared_intermediate_size=96, moe_routed_scaling=2.5,
+    ),
+    # Test-scale latent attention (structurally deepseek_v3: MLA over a
+    # latent cache, one dense layer, then gated held experts).
+    "latent_tiny": ModelConfig(
+        vocab_size=512, hidden_size=64, intermediate_size=96, num_layers=3,
+        num_heads=4, num_kv_heads=4, max_seq_len=256, rope_theta=1e6,
+        rms_norm_eps=1e-6, remat=False, dtype="float32",
+        param_dtype="float32", kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, rope_interleave=True,
+        first_k_dense=1, moe_num_experts=8,
+        num_experts_per_tok=3, moe_intermediate_size=24,
+        moe_shared_intermediate_size=48, moe_routed_scaling=2.448,
     ),
     # Test-scale MoE (structurally Mixtral: GQA + top-2 of 4 experts).
     "mixtral_tiny": ModelConfig(

@@ -1,5 +1,6 @@
-"""Model zoo: Llama-family transformer in Flax + LoRA grafting, and the
-patterned ``nemotron_h`` family (Mamba-2, routed experts, attention)."""
+"""Model zoo: Llama-family transformer in Flax + LoRA grafting, the
+patterned ``nemotron_h`` family (Mamba-2, routed experts, attention) and
+the latent-attention family (``deepseek_v3``: MLA, held gated experts)."""
 
 from dlti_tpu.models.llama import LlamaForCausalLM, LlamaModel  # noqa: F401
 
@@ -8,7 +9,12 @@ def build_model(cfg, lora=None, mesh=None):
     """The causal LM a ``ModelConfig`` describes: the one place that picks
     the model class (the trainer, the engine, ``serve.py --random-init``,
     the fleet worker and the benchmark's check all come through here). A
-    configuration without a ``layer_pattern`` is the Llama family."""
+    configuration with a ``kv_lora_rank`` is the latent-attention family;
+    one with neither that nor a ``layer_pattern`` is the Llama family."""
+    if cfg.kv_lora_rank:
+        from dlti_tpu.models.latent import LatentForCausalLM
+
+        return LatentForCausalLM(cfg, lora, mesh)
     if cfg.layer_pattern:
         from dlti_tpu.models.nemotron_h import NemotronHForCausalLM
 

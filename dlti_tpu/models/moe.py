@@ -17,7 +17,8 @@ recorded via ``self.sow("intermediates", "router_aux_loss", ...)``; the
 train step collects it when ``ModelConfig.num_experts > 0``.
 
 **``HeldExpertsMLP``** — the dropless layer of the patterned families
-(``ModelConfig.layer_pattern``, "E" layers): no capacity, no token dropped.
+(``ModelConfig.layer_pattern``, "E" layers) and of the latent-attention
+family's expert layers (``models.latent``): no capacity, no token dropped.
 Every held expert runs over every token of a block under the routing weights
 as a mask (the same sum as sorting tokens by expert; see ``TOKEN_BLOCK``).
 The layer is told which experts it holds (``moe_held_start``,
@@ -220,10 +221,14 @@ class HeldExpertsMLP(nn.Module):
         s = sigmoid(x W_r)                      float32, all E experts
         chosen = top-k of s + e_score_correction_bias
         w = s[chosen] / sum(s[chosen]) * moe_routed_scaling
-        y = sum_{e chosen and held here} w_e W_down,e act(W_up,e x)
-            + shared_down act(shared_up x)
+        y = sum_{e chosen and held here} w_e W_down,e f_e(x)  +  S_down f_S(x)
 
-    ``act`` is ``mlp_activation`` ("relu2": ungated relu squared)."""
+    An expert's inner part ``f`` is one of two, by ``mlp_activation``:
+    "relu2" ungated, ``relu(W_up x)^2`` (nemotron_h), or "silu" gated,
+    ``silu(W_gate x) * W_up x`` (deepseek_v3). The shared
+    part ``S`` has the same form at ``moe_shared_intermediate_size``: one
+    shared expert, or several as one MLP of their summed width (the sum of
+    n gated MLPs of width f is one of width n x f)."""
 
     cfg: ModelConfig
 
@@ -234,10 +239,13 @@ class HeldExpertsMLP(nn.Module):
         routed (they would count as load and touch experts). Returns
         ``(y, counters (4,) int32 in the order of MOE_COUNTERS)``."""
         cfg = self.cfg
-        if cfg.mlp_activation != "relu2" or cfg.moe_scoring != "sigmoid_bias":
+        if cfg.mlp_activation not in ("relu2", "silu") \
+                or cfg.moe_scoring != "sigmoid_bias":
             raise NotImplementedError(
-                f"HeldExpertsMLP computes relu2 experts under sigmoid_bias "
-                f"scoring; got {cfg.mlp_activation!r}, {cfg.moe_scoring!r}")
+                f"HeldExpertsMLP computes ungated relu2 or gated silu "
+                f"experts under sigmoid_bias scoring; got mlp_activation "
+                f"{cfg.mlp_activation!r}, moe_scoring {cfg.moe_scoring!r}")
+        gated = cfg.mlp_activation == "silu"
         dtype, pdtype = _dtype(cfg.dtype), _dtype(cfg.param_dtype)
         b, s, h = x.shape
         T, E, k = b * s, cfg.moe_num_experts, cfg.num_experts_per_tok
@@ -263,8 +271,12 @@ class HeldExpertsMLP(nn.Module):
             w = w / jnp.sum(w, axis=1, keepdims=True) * cfg.moe_routed_scaling
             held = (chosen >= lo) & (chosen < lo + held_n) & valid[:, None]
 
-            w_up = self.param("w_up", nn.initializers.lecun_normal(
-                batch_axis=(0,)), (held_n, h, f), pdtype).astype(dtype)
+            def inner(name):
+                return self.param(name, nn.initializers.lecun_normal(
+                    batch_axis=(0,)), (held_n, h, f), pdtype).astype(dtype)
+
+            w_gate = inner("w_gate") if gated else None
+            w_up = inner("w_up")
             w_down = self.param("w_down", centred_out_init(
                 ROUTED_OUT_SCALE, batch_axis=(0,)), (held_n, f, h),
                 pdtype).astype(dtype)
@@ -282,7 +294,9 @@ class HeldExpertsMLP(nn.Module):
 
             def block(xg):
                 xb, gb = xg
-                act = _relu2(jnp.einsum("th,ehf->tef", xb, w_up))
+                act = jnp.einsum("th,ehf->tef", xb, w_up)
+                act = jax.nn.silu(jnp.einsum("th,ehf->tef", xb, w_gate)) \
+                    * act if gated else _relu2(act)
                 return jnp.einsum("tef,efh->th", act * gb[:, :, None], w_down)
 
             xs, gate = xt.astype(dtype), gate.astype(dtype)
@@ -300,9 +314,12 @@ class HeldExpertsMLP(nn.Module):
                                      dtype=dtype, param_dtype=pdtype,
                                      name=name, **kw)
 
+                act = dense("shared_up", cfg.moe_shared_intermediate_size)(xt)
+                act = jax.nn.silu(dense(
+                    "shared_gate", cfg.moe_shared_intermediate_size)(xt)) \
+                    * act if gated else _relu2(act)
                 y = y + dense("shared_down", h, kernel_init=centred_out_init(
-                    SHARED_OUT_SCALE))(_relu2(dense(
-                        "shared_up", cfg.moe_shared_intermediate_size)(xt)))
+                    SHARED_OUT_SCALE))(act)
         counters = jnp.stack([
             jnp.sum(valid) * k, jnp.sum(held), jnp.sum(sizes > 0),
             jnp.max(sizes)]).astype(jnp.int32)

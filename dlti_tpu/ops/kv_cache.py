@@ -1,9 +1,14 @@
-"""The serving cache — device-side ops: paged keys and values, and the
-recurrent state of state-space layers by decode slot.
+"""The serving cache — device-side ops: paged keys and values, paged
+latents, and the recurrent state of state-space layers by decode slot.
 
-One cache, one entry a layer, two kinds of state (:func:`init_cache`):
+One cache, one entry a layer, three kinds of state (:func:`init_cache`):
 ``{"k", "v"}`` block pools addressed by block tables for attention layers
-(below), ``{"conv", "ssm"}`` of shape ``(slots, ...)`` for Mamba-2 layers
+(below), ``{"latent"}`` — ONE block pool ``(num_blocks, block_size,
+kv_lora_rank + qk_rope_head_dim`` rounded up to whole 128-value lanes``)``
+— for latent-attention layers (:func:`init_latent_cache`: a token's row is
+its normed latent and its rotated shared key, written by one scatter and
+addressed by the same block tables, so the allocator and the prefix cache
+see a block id like any other), ``{"conv", "ssm"}`` of shape ``(slots, ...)`` for Mamba-2 layers
 (:func:`init_recurrent_state`; a sequence's state has a fixed size, so it
 is addressed by the decode slot that serves it and needs no allocator),
 and ``{}`` for layers that keep nothing (routed experts).
@@ -92,11 +97,60 @@ def init_recurrent_state(num_slots: int, conv_kernel: int, conv_dim: int,
                              state_dtype)}
 
 
+# Values in one lane row of the TPU's tiled layouts.
+LANES = 128
+
+
+def init_latent_cache(num_blocks: int, block_size: int, latent_dim: int,
+                      dtype=jnp.bfloat16) -> dict:
+    """One latent-attention layer's pool: a row a token, ``[normed latent ;
+    rotated shared key ; zeros]``, under one key. The row is ``latent_dim``
+    values rounded up to whole lanes of 128: the TPU lays a 576-wide array
+    out in 640-wide rows whatever its shape says, and a block can be copied
+    by hand (the decode kernel) only at the width it lies in."""
+    if dtype == "int8" or dtype == jnp.int8:
+        raise ValueError(
+            "a latent cache has no int8 layout: a row is a normed latent "
+            "and a rotated key with one scale between them; serve it with "
+            "--kv-cache-dtype bfloat16")
+    return {"latent": jnp.zeros(
+        (num_blocks, block_size, -(-latent_dim // LANES) * LANES), dtype)}
+
+
+def latent_update(layer_cache: dict, rows: jnp.ndarray,
+                  slots: jnp.ndarray) -> dict:
+    """Scatter new latent rows ``(batch, s, latent_dim)`` into the pool at
+    the flat ``slots`` of :func:`slot_mapping`: one scatter a layer (the
+    lanes past ``latent_dim`` are written as zeros)."""
+    pool = layer_cache["latent"]
+    nb, bs, d = pool.shape
+    rows = jnp.pad(rows, ((0, 0), (0, 0), (0, d - rows.shape[-1])))
+    flat = pool.reshape(nb * bs, d).at[slots.reshape(-1)].set(
+        rows.reshape(-1, d).astype(pool.dtype), mode="drop")
+    return {**layer_cache, "latent": flat.reshape(nb, bs, d)}
+
+
+def latent_gather(layer_cache: dict, block_tables: jnp.ndarray) -> jnp.ndarray:
+    """Each sequence's logical window of latent rows, ``(batch, max_blocks *
+    block_size, pool width)``; what lies past a sequence's written length is
+    masked by the caller's positions."""
+    pool = layer_cache["latent"]
+    b, max_blk = block_tables.shape
+    return pool[block_tables].reshape(b, max_blk * pool.shape[1],
+                                      pool.shape[2])
+
+
 def init_cache(model_cfg, num_blocks: int, block_size: int, num_slots: int,
                dtype=jnp.bfloat16) -> List[dict]:
     """The serving cache of ``model_cfg``, one entry a layer by its kind.
-    A model without a ``layer_pattern`` is attention in every layer."""
+    A model without a ``layer_pattern`` is attention in every layer, over
+    latents where the configuration has a ``kv_lora_rank``."""
     from dlti_tpu.utils.dtypes import resolve_dtype
+
+    if model_cfg.latent_dim:
+        return [init_latent_cache(num_blocks, block_size,
+                                  model_cfg.latent_dim, dtype)
+                for _ in range(model_cfg.num_layers)]
 
     def paged():
         return init_paged_cache(1, num_blocks, block_size,

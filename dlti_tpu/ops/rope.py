@@ -4,7 +4,9 @@ The reference gets RoPE implicitly through HF ``LlamaModel``
 (``training/train_baseline.py:122-126`` loads ``meta-llama/Llama-2-7b-hf``);
 here it is implemented directly. Uses the split-half rotation convention
 (matching HF Llama), computed in float32 for numerical parity and cast back
-to the compute dtype.
+to the compute dtype. ``interleaved`` rotates the pairs ``(2i, 2i + 1)``
+instead (``rope_interleave`` of the deepseek_v3 family), each pair left
+where it lies.
 """
 
 from __future__ import annotations
@@ -42,11 +44,14 @@ def rope_frequencies(head_dim: int, max_seq_len: int, theta: float = 10000.0) ->
 
 
 def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
-               positions: jnp.ndarray) -> jnp.ndarray:
+               positions: jnp.ndarray,
+               interleaved: bool = False) -> jnp.ndarray:
     """Rotate ``x`` of shape (batch, seq, heads, head_dim) by position.
 
     ``positions`` is (batch, seq) int32 — explicit so the same op serves
     packed sequences and KV-cached decode (where position != index).
+    Frequency ``i`` turns the pair ``(i, i + head_dim / 2)``, or with
+    ``interleaved`` the pair ``(2i, 2i + 1)``.
     """
     orig_dtype = x.dtype
     half = x.shape[-1] // 2
@@ -63,6 +68,12 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
     sin_p = jnp.take(sin, positions, axis=0,
                      mode="clip")[:, :, None, :].astype(jnp.float32)
     x = x.astype(jnp.float32)
+    if interleaved:
+        x1, x2 = x[..., 0::2], x[..., 1::2]
+        rotated = jnp.stack(
+            [x1 * cos_p - x2 * sin_p, x2 * cos_p + x1 * sin_p], axis=-1
+        ).reshape(x.shape)
+        return rotated.astype(orig_dtype)
     x1, x2 = x[..., :half], x[..., half:]
     rotated = jnp.concatenate(
         [x1 * cos_p - x2 * sin_p, x2 * cos_p + x1 * sin_p], axis=-1

@@ -338,6 +338,9 @@ class _Slot:
         # with next_pos < prefill_end is admitted but not yet decodable.
         self.next_pos = 0
         self.prefill_end = 0
+        # Leading blocks this sequence shares with the prefix cache since
+        # its admission (hits and tier restores): already matchable.
+        self.shared_blocks = 0
 
     @property
     def free(self) -> bool:
@@ -350,7 +353,14 @@ class _Slot:
 
 def refuse_state_handoff(model_cfg: ModelConfig, what: str) -> None:
     """Disaggregated serving and k/v hand-off move a sequence as its k/v
-    blocks; a recurrent state is not in them."""
+    blocks; a recurrent state is not in them, and a latent block is not
+    what the wire format packs."""
+    if model_cfg.latent_dim:
+        raise ValueError(
+            f"{what} moves a sequence between engines as its k/v blocks "
+            f"(wire.pack_handoff packs \"k\" and \"v\"); a model with latent "
+            f"attention keeps latent blocks, which the hand-off does not "
+            f"carry. Serve it colocated (no --disagg)")
     if model_cfg.has_recurrent_state:
         raise ValueError(
             f"{what} moves a sequence between engines as its k/v blocks; a "
@@ -432,6 +442,11 @@ class InferenceEngine:
                       # from the same mirrors). 0 under default traffic.
                       "decode_steps_sorted_sampling": 0,
                       "prefix_cached_tokens": 0,
+                      # Cached tokens the prefill calls attended over beside
+                      # their own: each row of a call adds the position its
+                      # tokens start at (a prefix hit's tail, a later piece
+                      # of a long prompt).
+                      "prefill_context_tokens": 0,
                       # Tokens whose KV came back from a LOWER tier (host
                       # or disk) via a restore scatter instead of either
                       # an HBM hit or a re-prefill. Present (at 0) even
@@ -935,6 +950,7 @@ class InferenceEngine:
                 tokens = req.prompt_token_ids + req.output_token_ids
                 self._register_slot(slot, req, blocks, len(tokens))
                 slot.next_pos = n_cached  # _register_slot set it to the end
+                slot.shared_blocks = n_cached // self.cfg.block_size
             return
 
         suffix_lens = [len(req.prompt_token_ids) + len(req.output_token_ids)
@@ -973,7 +989,8 @@ class InferenceEngine:
                 break
             req = slot.request
             remaining = slot.prefill_end - slot.next_pos
-            take = min(remaining, budget)
+            take = min(remaining, budget,
+                       self.executor.prefill_call_tokens or remaining)
             # Position p holds (prompt + output)[p], so the chunk is an
             # index slice — no per-slot token copy is carried between steps.
             tokens = req.prompt_token_ids + req.output_token_ids
@@ -1034,9 +1051,21 @@ class InferenceEngine:
         prefilled.
         """
         chunks = []
+        limit = self.executor.prefill_call_tokens
         for slot, req, blocks, n_cached in group:
             tokens = req.prompt_token_ids + req.output_token_ids
             self._register_slot(slot, req, blocks, len(tokens))
+            slot.shared_blocks = n_cached // self.cfg.block_size
+            # A model that holds a call to ``limit`` padded tokens takes a
+            # longer suffix as several calls (the group is then this one
+            # row: _prefill_rows), each over what the earlier ones wrote.
+            while limit and len(tokens) - n_cached > limit:
+                self._run_prefill_batch(self._bucket_for(limit), [
+                    (slot, tokens[n_cached:n_cached + limit], n_cached,
+                     False)])
+                n_cached += limit
+            if limit and bucket > limit:
+                bucket = self._bucket_for(len(tokens) - n_cached)
             chunks.append((slot, tokens[n_cached:], n_cached, True))
         self._run_prefill_batch(bucket, chunks)
 
@@ -1088,11 +1117,33 @@ class InferenceEngine:
                 self._append_token(slot, int(toks[r]), float(lps[r]))
                 if not slot.free:  # (the first token may have ended it)
                     self._state_slots[slot.slot_id] = slot.slot_id
+                    self._publish_prompt_blocks(slot)
                 # Prefill completion: the first sampled token bumped the
                 # slot's gen count, and a chunked-mode slot's block-table
                 # row sheds its trash-block masking — either way the row
                 # must re-upload before the slot joins the decode batch.
                 self.executor.mark_dirty(slot.slot_id)
+
+    def _publish_prompt_blocks(self, slot: _Slot) -> None:
+        """Prefix caching: the whole blocks a prefill has just written become
+        matchable now, not when the sequence retires
+        (``PrefixCachingAllocator.register``). The same prompt asked again
+        while its first request still decodes is then a hit on the running
+        sequence's blocks, where it used to be a second cold prefill holding
+        a second copy (in a closed loop over a few long documents those
+        copies drove the documents already cached out of the pool: PERF.md
+        section 6, PR 38). Decode never writes a whole block of the prompt."""
+        n = slot.prefill_end // self.cfg.block_size
+        if self.prefix_cache is None or n <= slot.shared_blocks:
+            return  # (a hit on every whole block has nothing to add)
+        req = slot.request
+        tokens = (req.prompt_token_ids + req.output_token_ids)[
+            :n * self.cfg.block_size]
+        blocks = self.prefix_cache.register(tokens, slot.blocks[:n],
+                                            ns=req.adapter or None)
+        slot.blocks[:n] = blocks
+        slot.shared_blocks = n
+        self._block_tables[slot.slot_id, :n] = blocks
 
     def _prefill_launch(self, bucket: int, chunks: List[tuple]):
         """The host arrays of one batch and its prefill call (program, key
@@ -1113,6 +1164,8 @@ class InferenceEngine:
         while nblk_bucket < nblk_needed:
             nblk_bucket *= 2
         nblk_bucket = min(nblk_bucket, ec.max_blocks_per_seq)
+        if self.executor.prefill_whole_tables:
+            nblk_bucket = ec.max_blocks_per_seq
 
         self.stats["prefill_batches"] += 1
         ids = np.zeros((B, bucket), np.int32)
@@ -1144,6 +1197,7 @@ class InferenceEngine:
             adapter_ids[r] = self._adapter_ids[slot.slot_id]
             state_slots[r] = slot.slot_id
             self.stats["prefill_tokens"] += len(tokens)
+            self.stats["prefill_context_tokens"] += start
 
         sample = None
         if any(is_last for *_, is_last in chunks):
@@ -1640,6 +1694,7 @@ class InferenceEngine:
         slot.seq_len = 0
         slot.next_pos = 0
         slot.prefill_end = 0
+        slot.shared_blocks = 0
         self._block_tables[slot.slot_id] = 0
         self._temperature[slot.slot_id] = 1.0
         self._top_k[slot.slot_id] = 0

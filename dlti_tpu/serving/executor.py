@@ -11,6 +11,7 @@ comes back is on the device and not waited for until :meth:`fetch`.
 from __future__ import annotations
 
 import json
+import math
 from functools import partial
 from typing import TYPE_CHECKING, Dict, Optional, Sequence
 
@@ -37,11 +38,28 @@ def refuse_unsupported(model_cfg: ModelConfig, engine_cfg: "EngineConfig",
                        mesh=None) -> None:
     """Refuse, at start-up and with one clear error each, every feature
     that takes a sequence's state to be its k/v blocks when the model has
-    recurrent layers, and what the patterned families do not implement."""
-    if not model_cfg.layer_pattern:
+    recurrent layers or keeps latents, and what the patterned and the
+    latent-attention families do not implement."""
+    if not (model_cfg.layer_pattern or model_cfg.latent_dim):
         return
     ec = engine_cfg
-    what = f"a model with layer_pattern {model_cfg.layer_pattern!r}"
+    what = (f"a model with layer_pattern {model_cfg.layer_pattern!r}"
+            if model_cfg.layer_pattern else
+            f"a model with latent attention (kv_lora_rank "
+            f"{model_cfg.kv_lora_rank})")
+    if model_cfg.latent_dim:
+        if ec.cache_dtype == "int8":
+            raise ValueError(
+                f"{what} keeps one row of latent and rotated key a token, "
+                f"which has no int8 layout (one scale cannot serve both "
+                f"parts); serve it with --kv-cache-dtype bfloat16")
+        if ec.prefix_host_blocks > 0 or ec.prefix_disk_blocks > 0:
+            raise ValueError(
+                f"{what} keeps its cache as latent blocks; the host and "
+                f"disk prefix tiers store and verify k/v payloads "
+                f"(serving/prefix_tiers.py). Serve it with "
+                f"--enable-prefix-caching alone (the HBM tier treats a "
+                f"latent block like any other)")
     if model_cfg.has_recurrent_state:
         why = (f"{what} keeps a recurrent state per decode slot beside its "
                f"k/v blocks, and ")
@@ -57,17 +75,19 @@ def refuse_unsupported(model_cfg: ModelConfig, engine_cfg: "EngineConfig",
             f"{what} cannot be served with speculative decoding: rejected "
             f"drafts are rolled back by position in the k/v cache, and a "
             f"recurrent state cannot be rolled back (nor does the "
-            f"speculative program thread it). Serve it with --speculative "
-            f"none")
+            f"speculative program thread it, or the counters of a model "
+            f"that counts; the verify step over latents is held to no "
+            f"reference). Serve it with --speculative none")
     if mesh is not None:
         raise ValueError(
-            f"{what} has no tensor-parallel sharding rules (Mamba-2 and "
-            f"held-expert layers); serve it on one chip per replica")
+            f"{what} has no tensor-parallel sharding rules (Mamba-2, "
+            f"latent-attention and held-expert layers); serve it on one "
+            f"chip per replica")
     if ec.quantization != "none":
         raise ValueError(
             f"{what} is served in its own precision: weight-only "
-            f"{ec.quantization} is not implemented for Mamba-2 and expert "
-            f"layers")
+            f"{ec.quantization} is not implemented for Mamba-2, "
+            f"latent-attention and expert layers")
     if ec.adapter_slots > 0:
         raise ValueError(
             f"{what} has no multi-LoRA adapter branch; serve it with "
@@ -140,6 +160,11 @@ class EngineExecutor:
         self.counter_names = tuple(getattr(self.model, "counter_names", ()))
         # The most padded tokens one prefill call may hold (0: no limit).
         self.prefill_call_tokens = getattr(self.model, "prefill_call_tokens", 0)
+        # Whether a prefill call takes each row's whole block table (a model
+        # whose cached context is cheap to gather) or the narrowest power
+        # of two that holds the call's rows.
+        self.prefill_whole_tables = getattr(
+            self.model, "prefill_whole_tables", False)
         self._recurrent = model_cfg.has_recurrent_state
         self._quantized = engine_cfg.quantization == "int8"
         if engine_cfg.quantization not in ("none", "int8"):
@@ -215,11 +240,13 @@ class EngineExecutor:
         self.pool_bytes = tree_nbytes(self.cache)
         self.recurrent_state_pool_bytes = tree_nbytes(
             [c for c in self.cache if "ssm" in c])
-        # Keys a step of the paged decode kernel covers at this engine's
-        # shapes (the scheduler's decode_kernel_tile_tokens counts in it).
-        token_bytes = next((c["k"].shape[2] * c["k"].shape[3]
-                            * c["k"].dtype.itemsize
-                            for c in self.cache if "k" in c), 0)
+        # Keys a step of the paged decode kernel (of keys and values, or of
+        # latents) covers at this engine's shapes (the scheduler's
+        # decode_kernel_tile_tokens counts in it).
+        pool = next((c.get("k", c.get("latent")) for c in self.cache
+                     if "k" in c or "latent" in c), None)
+        token_bytes = 0 if pool is None \
+            else math.prod(pool.shape[2:]) * pool.dtype.itemsize
         self.decode_tile_tokens = tile_tokens(
             ec.block_size, ec.max_blocks_per_seq, token_bytes)
 

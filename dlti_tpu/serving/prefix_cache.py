@@ -9,7 +9,9 @@ preempted-then-readmitted sequences prefill only their novel suffix.
 Design:
 
 * Keys are exact: ``key_i = (key_{i-1}, tokens_of_block_i)`` — no hash
-  collisions, verification-free reuse.
+  collisions, verification-free reuse. A key keeps its hash
+  (:class:`_ChainKey`), and a walk goes on from the registered key object
+  it has just found, so matching n blocks costs O(n).
 * Ref-counted sharing: a cached block may back any number of active
   sequences; it is only evictable at refcount 0.
 * Eviction is lazy LRU: unreferenced cached blocks stay registered (and
@@ -73,6 +75,21 @@ blocks_gauge = Gauge(
     help="blocks currently cached per tier")
 
 
+class _ChainKey(tuple):
+    """``(parent key, a block's tokens)``: a nested tuple in content, repr
+    and hash, that computes its hash once. A plain tuple re-hashes its
+    whole chain on every lookup: O(n^2) over a prompt of n blocks, 9-10 ms
+    of the stepper thread for a 390-block document (PERF.md section 6,
+    PR 38)."""
+
+    def __hash__(self):
+        try:
+            return self._hash
+        except AttributeError:
+            self._hash = h = tuple.__hash__(self)
+            return h
+
+
 class _Entry:
     __slots__ = ("block", "key", "refcount")
 
@@ -122,9 +139,22 @@ class PrefixCachingAllocator:
         requests share one namespace."""
         keys, prev = [], (() if not ns else ("adapter", ns))
         for i in range(len(tokens) // block_size):
-            prev = (prev, tuple(tokens[i * block_size:(i + 1) * block_size]))
+            prev = _ChainKey(
+                (prev, tuple(tokens[i * block_size:(i + 1) * block_size])))
             keys.append(prev)
         return keys
+
+    def _walk(self, tokens: Sequence[int], ns: Optional[str] = None):
+        """``(key, entry or None)`` for each full block of ``tokens``, in
+        chain order. Where a key is registered the walk goes on from the
+        registered object, so the next lookup's equality test ends at an
+        identity (else it would compare the whole chain again)."""
+        bs, prev = self.block_size, (() if not ns else ("adapter", ns))
+        for i in range(len(tokens) // bs):
+            key = _ChainKey((prev, tuple(tokens[i * bs:(i + 1) * bs])))
+            entry = self._by_key.get(key)
+            prev = key if entry is None else entry.key
+            yield prev, entry
 
     # ------------------------------------------------------------------
     def match_prefix(self, tokens: Sequence[int],
@@ -139,9 +169,8 @@ class PrefixCachingAllocator:
         """
         usable = len(tokens) - 1
         blocks: List[int] = []
-        for key in self._chain_keys(tokens[:usable] if usable > 0 else [],
-                                    self.block_size, ns):
-            entry = self._by_key.get(key)
+        for _key, entry in self._walk(tokens[:usable] if usable > 0 else [],
+                                      ns):
             if entry is None:
                 break
             blocks.append(entry.block)
@@ -321,28 +350,53 @@ class PrefixCachingAllocator:
         sequence matched with, or cross-adapter aliasing serves one
         adapter's KV to another.
         """
-        keys = self._chain_keys(tokens, self.block_size, ns)
-        for i, block in enumerate(blocks):
+        walk = self._walk(tokens, ns)
+        for block in blocks:
+            key, registered = next(walk, (None, None))
             entry = self._by_block.get(block)
             if entry is not None:
                 # A block we were sharing: drop our reference.
                 self.release([block])
-                continue
-            if i < len(keys):
-                key = keys[i]
-                if key in self._by_key:
-                    # Same content already cached under another block
-                    # (two requests prefilling the same prompt
-                    # concurrently): keep the registered one, free ours.
-                    self.bm.free([block])
-                    continue
+            elif key is None or registered is not None:
+                # Past the full blocks, or the same content already cached
+                # under another block (two requests prefilling the same
+                # prompt concurrently): keep the registered one, free ours.
+                self.bm.free([block])
+            else:
                 e = _Entry(block, key)
                 self._by_key[key] = e
                 self._by_block[block] = e
                 self._lru[block] = e
-            else:
-                self.bm.free([block])
         self._set_block_gauges()
+
+    def register(self, tokens: Sequence[int], blocks: List[int],
+                 ns: Optional[str] = None) -> List[int]:
+        """Make a *running* sequence's full blocks matchable now, not when
+        it retires: ``blocks[i]`` holds ``tokens[i*bs:(i+1)*bs]`` and the
+        caller owns or shares each. Returns the blocks that hold that
+        content from now on, each pinned once for the caller: a block
+        already shared stays; an own block is registered under one
+        reference; an own block whose content another sequence registered
+        meanwhile is freed and the registered one taken in its place. The
+        same prompt asked again while its first request still decodes is
+        then a hit, not a second cold prefill holding a second copy.
+        Allocates nothing, so evicts nothing."""
+        out: List[int] = []
+        for block, (key, registered) in zip(blocks, self._walk(tokens, ns)):
+            entry = self._by_block.get(block)
+            if entry is None:
+                if registered is not None:
+                    self.bm.free([block])
+                    self.acquire([registered.block])
+                    block = registered.block
+                else:
+                    entry = _Entry(block, key)
+                    entry.refcount = 1
+                    self._by_key[key] = entry
+                    self._by_block[block] = entry
+            out.append(block)
+        self._set_block_gauges()
+        return out
 
     # ------------------------------------------------------------------
     @property

@@ -30,6 +30,7 @@ class ScriptedExecutor:
 
     counter_names = ()
     prefill_call_tokens = 0
+    prefill_whole_tables = False
     adapter_pool = None
     pool_bytes = 0
     recurrent_state_pool_bytes = 0
@@ -415,3 +416,65 @@ def test_warmup_hands_the_mirrors_to_the_executor_by_name():
                             "temperature", "top_k", "top_p", "adapter_ids",
                             "state_slots"])
     assert masked == []
+
+
+# -- a model that holds its prefill calls to a number of tokens --------------
+
+@pytest.mark.parametrize("whole_tables", [False, True])
+def test_a_prompt_past_the_models_call_limit_goes_as_several_calls(
+        monkeypatch, whole_tables):
+    """``prefill_call_tokens`` (a model's own limit): a longer prompt is cut
+    into calls of the limit, each starting where the last one ended, none but
+    the last sampling; ``prefill_whole_tables`` gives every call the whole
+    width of the block table instead of the narrowest power of two."""
+    monkeypatch.setattr(ScriptedExecutor, "prefill_call_tokens", 8)
+    monkeypatch.setattr(ScriptedExecutor, "prefill_whole_tables",
+                        whole_tables)
+    eng = _engine(max_model_len=48, num_blocks=64)
+    prompt = list(range(100, 121))                   # 21 = 8 + 8 + 5
+    req = eng.submit(prompt, SamplingParams(max_tokens=3))
+    short = eng.submit([7, 8, 9], SamplingParams(max_tokens=3))
+    _drain(eng)
+    calls = eng.executor.of("prefill")
+    assert [c["bucket"] for c in calls] == [8, 8, 8, 4]
+    assert [c["sampled"] for c in calls] == [False, False, True, True]
+    starts = [int(c["positions"][0, 0]) for c in calls]
+    assert starts == [0, 8, 16, 0]
+    assert [c["input_ids"][0, :5].tolist() for c in calls[:3]] == [
+        prompt[0:5], prompt[8:13], prompt[16:21]]
+    widths = [c["block_tables"].shape[1] for c in calls]
+    assert widths == ([12] * 4 if whole_tables else [2, 4, 8, 1])
+    assert req.output_token_ids == _stream(prompt, 3)
+    assert short.output_token_ids == _stream([7, 8, 9], 3)
+    st = eng.stats
+    assert (st["prefill_batches"], st["prefill_tokens"]) == (4, 24)
+    assert st["prefill_context_tokens"] == 8 + 16
+
+
+# -- prefix caching: a prompt's blocks are matchable once prefilled ----------
+
+def test_a_prompt_asked_again_while_it_decodes_hits_the_running_sequence():
+    """The whole blocks of a prefilled prompt are published at once: a second
+    ask while the first still decodes shares its blocks (and its table row
+    names them), and both retire without a block lost or freed twice."""
+    eng = _engine(enable_prefix_caching=True)
+    prompt = list(range(10, 21))                     # 11 tokens: 2 whole blocks
+    first = eng.submit(prompt, SamplingParams(max_tokens=6))
+    eng.step()                                       # admitted and prefilled
+    assert eng.prefix_cache.num_cached_blocks == 2
+    second = eng.submit(prompt, SamplingParams(max_tokens=6))
+    eng.step()
+    assert eng.stats["prefix_cached_tokens"] == 8
+    rows = [s for s in eng.slots if not s.free]
+    assert len(rows) == 2 and rows[0].blocks[:2] == rows[1].blocks[:2]
+    assert rows[0].blocks[2:] != rows[1].blocks[2:]
+    calls = eng.executor.of("prefill")
+    assert [int(c["positions"][0, 0]) for c in calls] == [0, 8]
+    assert eng._block_tables[rows[1].slot_id, :2].tolist() == \
+        rows[0].blocks[:2]
+    _drain(eng)
+    assert first.output_token_ids == second.output_token_ids == \
+        _stream(prompt, 6)
+    pc = eng.prefix_cache
+    assert pc.num_free + pc.num_reclaimable == 31   # every block accounted
+    assert all(e.refcount == 0 for e in pc._by_block.values())

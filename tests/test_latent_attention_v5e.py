@@ -1,0 +1,69 @@
+"""The latent-attention decode kernel compiles for the v5e at the geometry of
+``serve.kanana2_30b.doc_turns``: 32 rows, 32 heads, rows of 576 values laid in
+640 of which 512 are the values, 16,384 blocks of 16, 544 blocks a row. Nothing
+runs: the TPU compiler installed here compiles for a chip that is described,
+not attached. The topology is described inside a module-scoped fixture and
+never at import."""
+
+import pytest
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means: cannot describe
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip can be written to the persistent
+    # cache but not read back without the chip: keep these out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+# (rows, blocks in the pool, blocks a row): the cell's engine, and a short
+# table whose one tile is narrower than 256 keys
+GEOMETRIES = {"doc_turns": (32, 16384, 544), "short_rows": (8, 512, 8)}
+
+
+@pytest.mark.parametrize("name", sorted(GEOMETRIES))
+def test_latent_decode_kernel_compiles_for_the_v5e(one_chip, name):
+    import jax
+    import jax.numpy as jnp
+
+    from dlti_tpu.ops.kv_cache import init_latent_cache
+    from dlti_tpu.ops.pallas.latent_attention import latent_decode_attention
+
+    rows, blocks, max_blocks = GEOMETRIES[name]
+    heads, latent_dim, value_dim, block = 32, 576, 512, 16
+    width = jax.eval_shape(
+        lambda: init_latent_cache(4, block, latent_dim))["latent"].shape[-1]
+    assert width == 640
+
+    def shape(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def decode(q, pool, tables, lens):
+        return latent_decode_attention(q, pool, tables, lens,
+                                       value_dim=value_dim, scale=192 ** -0.5)
+
+    compiled = jax.jit(decode).lower(
+        shape((rows, heads, latent_dim), jnp.bfloat16),
+        shape((blocks, block, width), jnp.bfloat16),
+        shape((rows, max_blocks), jnp.int32),
+        shape((rows,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "dlti_latent_attention_decode" in text
