@@ -10,7 +10,8 @@ batch size grows); a typical step dirties only a handful of slots (an
 admission, a retirement, a block-table row growing by one):
 
 * The scheduler marks a slot dirty at admission, release (retire /
-  preempt / abort), block-table growth, and prefill completion.
+  preempt / abort), block-table growth, prefill completion, and when it
+  leaves a slot whose request ends with the token in flight out of a round.
   :meth:`sync` then scatters just the dirty rows into the device arrays
   (one fused jitted update, row count padded to a power of two so the
   compile surface stays O(log max_seqs)).
@@ -22,6 +23,14 @@ admission, a retirement, a block-table row growing by one):
   for every slot that survived the window; a slot that finished mid-window
   was released, which marks it dirty). No host→device traffic for the one
   mirror that changes every single step.
+* **A row is uploaded as of the round being launched, not as of the last
+  emission.** The engine's loop launches a one-step round while the round
+  before is still in flight (``InferenceEngine.step``): the device has
+  then counted a token the host has not seen, and the host's mirror of a
+  riding slot's count is one behind the resident one. The mirrors a
+  :meth:`sync` is given are the scheduler's view *at the launch* (kept
+  tokens plus the round in flight), so that a row dirtied by block growth
+  draws its next token with the next count and not the last one again.
 * Prefilling slots' block-table rows are masked to the trash block at
   upload time: a decode program can never scribble on KV a
   partially-prefilled slot has written.
@@ -29,9 +38,10 @@ admission, a retirement, a block-table row growing by one):
 The speculative path ships the mirrors whole (it uploads the full token
 history anyway); a spec round calls :meth:`mark_all_dirty` so the next
 plain dispatch resynchronizes. For every *active* slot the resident rows
-equal the host mirrors at each dispatch (tier-1 holds the outputs to
+equal the scheduler's view at each dispatch (tier-1 holds the outputs to
 references that do not share this path, including across preemption and
-re-admission).
+re-admission, and to the same engine fetching every round before it plans
+the next).
 
 Updates deliberately do **not** donate the old arrays: they are KB-scale,
 and the previous window's program may still hold them as in-flight
@@ -82,7 +92,7 @@ class DecodeStateCache:
     def _apply_rows(dev, idx, rows):
         return tuple(a.at[idx].set(r) for a, r in zip(dev, rows))
 
-    def _place(self, x: np.ndarray) -> jax.Array:
+    def place(self, x: np.ndarray) -> jax.Array:
         if self._mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -111,12 +121,13 @@ class DecodeStateCache:
         """
         masked = set(masked_rows)
         if self._dev is None or self._all_dirty:
-            host = [np.asarray(mirrors[f]) for f in self._fields]
+            # Copies: the CPU backend may alias a host array it is given,
+            # and the scheduler writes its mirrors while a round that was
+            # launched with these arrays is still in flight.
+            host = [np.array(mirrors[f]) for f in self._fields]
             if masked:
-                bt = host[0].copy()
-                bt[sorted(masked)] = 0
-                host[0] = bt
-            self._dev = tuple(self._place(h) for h in host)
+                host[0][sorted(masked)] = 0
+            self._dev = tuple(self.place(h) for h in host)
             self.stats["decode_state_uploads"] += 1
             self.stats["decode_state_rows"] += self._num_slots
             self._all_dirty = False

@@ -15,6 +15,11 @@ ships (``README.md:10,16``; ``requirements.txt:18``). Architecture, XLA-first:
   grows block tables as sequences cross block boundaries. Out-of-memory is
   handled by preempting the youngest sequence back to the waiting queue
   (recompute-on-readmit, vLLM's recompute policy).
+* **One round ahead of the host.** A plain one-step decode round is left
+  in flight when ``step()`` returns, and the next ``step()`` launches the
+  round after it from the tokens still on the device before it fetches
+  anything (:meth:`InferenceEngine.step`): the host's work between two
+  decode programs runs under a program.
 * **Fused sampling.** Greedy / temperature / top-k / top-p are per-slot
   *data* (``dlti_tpu.serving.sampling``), sampled inside the compiled decode
   step — mixed batches never recompile; the one branch inside it (sort the
@@ -41,7 +46,8 @@ import numpy as np
 from dlti_tpu.config import LoRAConfig, ModelConfig
 from dlti_tpu.serving.adapters import AdapterError
 from dlti_tpu.serving.block_manager import BlockManager
-from dlti_tpu.serving.executor import EngineExecutor
+from dlti_tpu.serving.executor import (
+    RIDES, EngineExecutor, PrefillCallRefused)
 from dlti_tpu.serving.sampling import SamplingParams
 from dlti_tpu.telemetry import RequestTelemetry
 from dlti_tpu.telemetry.distributed_trace import mint_trace_id
@@ -422,6 +428,14 @@ class InferenceEngine:
                       # cohort retirement drains slots faster than
                       # admission refills them).
                       "decode_slot_steps": 0,
+                      # Rounds launched while the round before was still in
+                      # flight (over decode_steps: the share of steps whose
+                      # host work hid under a program), and rows such a
+                      # round computed for a request that turned out to
+                      # have ended in the round before (its token is thrown
+                      # away; decode_slot_steps counts kept tokens only).
+                      "decode_rounds_launched_ahead": 0,
+                      "decode_rows_discarded": 0,
                       # Tokens of context the decode steps attended over:
                       # each round adds the sum of its active slots'
                       # seq_len times its steps, so decode_context_tokens /
@@ -455,6 +469,17 @@ class InferenceEngine:
                       # Prefill program dispatches. Present (at 0) so
                       # the /metrics schema is stable.
                       "prefill_batches": 0,
+                      # The widest of them, in padded tokens (rows x
+                      # bucket): a gauge. What one admission pass can ask
+                      # of the device's memory at once.
+                      "prefill_widest_call_tokens": 0,
+                      # Prefill calls the executor refused (the program
+                      # of that shape could not be built; nothing ran):
+                      # calls of several rows that went again as one-row
+                      # calls, and one-row calls, each of which failed its
+                      # own request (_prefill_refused). 0 in a healthy run.
+                      "prefill_calls_split": 0,
+                      "prefill_calls_failed": 0,
                       "spec_proposed": 0, "spec_accepted": 0,
                       "spec_paused_rounds": 0,
                       # Resident decode state (decode_state.py), booked
@@ -571,6 +596,10 @@ class InferenceEngine:
         # Token-storm guard run length (consecutive all-slots-identical
         # decode steps).
         self._storm_run = 0
+
+        # The plain one-step round left in flight when step() returned
+        # (what _decode_launch gave), or None: see step().
+        self._inflight = None
 
         # Memory ledger (telemetry.memledger). The executor names the
         # device arrays it holds; prefix-cached blocks live INSIDE the pool
@@ -755,34 +784,73 @@ class InferenceEngine:
         return [self._result(by_id[r.request_id]) for r in reqs]
 
     def step(self) -> List[Request]:
-        """One scheduler iteration: retire, admit (prefill), decode.
+        """One scheduler iteration: launch, fetch and retire, admit.
 
         Returns requests that finished during this step.
+
+        The loop runs one round ahead of the host. A plain decode round
+        (one step, no speculation) is left IN FLIGHT when this returns;
+        the next call then
+
+        1. plans the round after it from what the host knows without its
+           tokens (who is live, each position, block growth, who ends by
+           length) and launches it, the riding rows reading their input
+           token on the device (:meth:`_decode_prepare`);
+        2. fetches the round that was in flight and walks its emissions
+           (:meth:`_decode_complete`);
+        3. admits and prefills: a slot freed in 2 is refilled at once, and
+           the admission joins the round after next with its first token
+           as a host id.
+
+        So what the host does between two decode programs (the emission
+        walk, the handler threads woken by it, the next plan) runs while a
+        program does. A request that sampled its end-of-sequence in the
+        round fetched in 2 has a row in the round launched in 1: that row
+        is thrown away when its round is fetched (``decode_rows_discarded``).
+        Its one write lands past the sequence's last kept token, in a block
+        that is freed with the slot and never registered with the prefix
+        cache, and whatever reuses the block, the slot or its recurrent
+        state is dispatched after it on the one device queue.
+
+        The loop does not run ahead when it cannot plan without the last
+        tokens: nothing is in flight (the first round, or after a drain), a
+        speculative round or a multi-step window on either side, a plan
+        that would have to preempt. It then fetches first and plans from
+        the host's tokens, which is the only other order there is. A
+        speculative or multi-step round is fetched in the step that
+        launched it, after admission, whose prefill work hides under it.
         """
-        # Async scheduling: dispatch the decode program FIRST (JAX dispatch
-        # is asynchronous — the host gets control back while the device
-        # works), then do admission prefills, whose host-side cost hides
-        # under the in-flight decode; sync decode results last. Admitted slots were free when
-        # the decode was dispatched, so its block-table snapshot writes
-        # their rows to the trash block — no KV interleaving hazard — and
-        # they join the NEXT round's decode batch (their first token comes
-        # from prefill sampling either way, so TTFT only improves).
         tr = self._tracer
+        # Whatever is in flight is this call's to fetch: a fault below
+        # drops it, and the round launched behind it, with the step.
+        inflight, self._inflight = self._inflight, None
         try:
-            pending = None
-            if not self.prefill_only and any(
+            finished: List[Request] = []
+            launched = None
+            if inflight is not None:
+                with tr.span("engine/decode_dispatch", cat="engine"):
+                    launched = self._decode_dispatch(ahead_of=inflight)
+                with tr.span("engine/decode_sync", cat="engine"):
+                    finished = self._decode_complete(inflight)
+            if launched is None and not self.prefill_only and any(
                     not s.free and not s.prefilling for s in self.slots):
                 with tr.span("engine/decode_dispatch", cat="engine"):
-                    pending = self._decode_dispatch()
+                    launched = self._decode_dispatch()
             with tr.span("engine/admit", cat="engine"):
                 self._admit()
             if self.cfg.max_prefill_tokens_per_step > 0:
                 with tr.span("engine/prefill_chunks", cat="engine"):
                     self._prefill_work()
-            if pending is None:
-                return []
+            if launched is None:
+                return finished
+            kind, _rows, k_steps, *_ = launched
+            if kind == "plain" and k_steps == 1 and self.has_work:
+                self._inflight = launched
+                return finished
+            # A window or a speculative round; or every request the round
+            # carried has ended and none waits: nothing would come for it.
             with tr.span("engine/decode_sync", cat="engine"):
-                return self._decode_complete(pending)
+                return finished + self._decode_complete(launched)
         except Exception as e:
             if is_oom_error(e):
                 # OOM forensics: file the black box as an OOM (with
@@ -793,6 +861,24 @@ class InferenceEngine:
                     rec.dump(reason="oom", force=True, exc=e,
                              extra={"where": "engine_step"})
             raise
+
+    def _drop_inflight(self) -> None:
+        """Wait for the round in flight, if there is one, and throw its
+        tokens away: for an engine whose requests are about to go
+        (:meth:`abort_all`, which the server's stop calls too). Every
+        request keeps exactly the tokens that were emitted to it. Not for
+        an engine that decodes on: the round has written its keys, values
+        and recurrent state."""
+        inflight, self._inflight = self._inflight, None
+        if inflight is None:
+            return
+        try:
+            self.executor.fetch(inflight[-1])
+        except Exception:  # noqa: BLE001 — the round of a faulted engine
+            self.logger.exception("the dropped decode round did not finish")
+        for slot, _req in inflight[1]:
+            # The device's count of its rows is one ahead of the mirrors'.
+            self.executor.mark_dirty(slot.slot_id)
 
     # ------------------------------------------------------------------
     # Scheduling internals
@@ -969,7 +1055,15 @@ class InferenceEngine:
         rows at a time (past that the batched program's marginal win
         flattens while its padded work and jit-shape surface keep growing),
         and fewer, a power of two, where the model holds a call to
-        ``prefill_call_tokens`` padded tokens (a row at least)."""
+        ``prefill_call_tokens`` padded tokens (a row at least). One row
+        where a call of several rows of this bucket has been refused in
+        this process (:meth:`_prefill_refused`): the one-row program of
+        every bucket is warmed at start-up, a narrower one of several rows
+        may be one more that does not fit (8 x 2,048 and 4 x 2,048 alike
+        for qwen2_7b), found by one more failed compile."""
+        if any(b == bucket and r > 1
+               for r, b, _width in self.executor.refused_prefill_shapes):
+            return 1
         limit = self.executor.prefill_call_tokens
         rows = 8
         while limit and rows > 1 and rows * bucket > limit:
@@ -1059,15 +1153,19 @@ class InferenceEngine:
             # A model that holds a call to ``limit`` padded tokens takes a
             # longer suffix as several calls (the group is then this one
             # row: _prefill_rows), each over what the earlier ones wrote.
-            while limit and len(tokens) - n_cached > limit:
+            while limit and len(tokens) - n_cached > limit \
+                    and not slot.free:
                 self._run_prefill_batch(self._bucket_for(limit), [
                     (slot, tokens[n_cached:n_cached + limit], n_cached,
                      False)])
                 n_cached += limit
+            if slot.free:  # a call of its own was refused: it has failed
+                continue
             if limit and bucket > limit:
                 bucket = self._bucket_for(len(tokens) - n_cached)
             chunks.append((slot, tokens[n_cached:], n_cached, True))
-        self._run_prefill_batch(bucket, chunks)
+        if chunks:
+            self._run_prefill_batch(bucket, chunks)
 
     def _run_prefill_batch(self, bucket: int, chunks: List[tuple]) -> None:
         """One prefill program call over ``chunks``: rows of
@@ -1077,7 +1175,8 @@ class InferenceEngine:
         everywhere, which slot_mapping turns into dropped writes. Each
         *final* chunk's first generated token is sampled from its last
         real logit in one batched sample call; non-final chunks (chunked
-        prefill) write KV only.
+        prefill) write KV only. A call the executor refuses goes to
+        :meth:`_prefill_refused`.
         """
         tr = self._tracer
         # The arguments cost a pass over the rows: only for a tracer that
@@ -1085,16 +1184,41 @@ class InferenceEngine:
         args = {"rows": len(chunks), "bucket": bucket,
                 "prompt_tokens": sum(len(c[1]) for c in chunks)} \
             if tr.enabled else {}
-        with tr.span("engine/prefill_group", cat="engine", **args):
-            with tr.span("engine/prefill_launch", cat="engine"):
-                sampled = self._prefill_launch(bucket, chunks)
-            if sampled is None:
-                return  # mid-prompt chunks: KV writes only, nothing to sample
-            with tr.span("engine/prefill_wait", cat="engine"):
-                toks, lps = self.executor.fetch(sampled)
-            if self.executor.counter_names:
-                self._count(toks[len(lps):][None, :], decode=False)
-            self._prefill_emit(chunks, toks, lps)
+        try:
+            with tr.span("engine/prefill_group", cat="engine", **args):
+                with tr.span("engine/prefill_launch", cat="engine"):
+                    sampled = self._prefill_launch(bucket, chunks)
+                if sampled is None:
+                    return  # mid-prompt chunks: KV writes only
+                with tr.span("engine/prefill_wait", cat="engine"):
+                    toks, lps = self.executor.fetch(sampled)
+                if self.executor.counter_names:
+                    self._count(toks[len(lps):][None, :], decode=False)
+                self._prefill_emit(chunks, toks, lps)
+        except PrefillCallRefused as refused:
+            self._prefill_refused(bucket, chunks, refused)
+
+    def _prefill_refused(self, bucket: int, chunks: List[tuple],
+                         refused: PrefillCallRefused) -> None:
+        """A prefill call that could not be built (the executor has logged
+        its name, shape and error). Nothing ran and the cache is whole, so
+        this is not a fault of the engine's (``abort_all`` is for a round
+        that leaves the cache in doubt): a call of several rows goes again
+        as one-row calls, in order, in this step, which is what a narrower
+        admission would have run (each row's key and count are its own);
+        a one-row call costs its own request, and the step goes on."""
+        if len(chunks) > 1:
+            self.stats["prefill_calls_split"] += 1
+            for chunk in chunks:
+                self._run_prefill_batch(bucket, [chunk])
+            return
+        slot = chunks[0][0]
+        req = slot.request
+        self.stats["prefill_calls_failed"] += 1
+        # register=False: what its earlier chunks wrote is not offered to
+        # the prefix cache on a failed request's behalf.
+        self._release(slot, register=False)
+        self._fail_waiting(req, str(refused))
 
     def _prefill_emit(self, chunks: List[tuple], toks: np.ndarray,
                       lps: np.ndarray) -> None:
@@ -1167,7 +1291,6 @@ class InferenceEngine:
         if self.executor.prefill_whole_tables:
             nblk_bucket = ec.max_blocks_per_seq
 
-        self.stats["prefill_batches"] += 1
         ids = np.zeros((B, bucket), np.int32)
         pos = np.full((B, bucket), -1, np.int32)  # -1 -> write dropped
         bt = np.zeros((B, nblk_bucket), np.int32)
@@ -1196,17 +1319,22 @@ class InferenceEngine:
             top_p[r] = req.params.top_p
             adapter_ids[r] = self._adapter_ids[slot.slot_id]
             state_slots[r] = slot.slot_id
-            self.stats["prefill_tokens"] += len(tokens)
-            self.stats["prefill_context_tokens"] += start
 
         sample = None
         if any(is_last for *_, is_last in chunks):
             sample = {"slot_keys": slot_keys, "gen_counts": counts,
                       "temperature": temps, "top_k": top_k, "top_p": top_p}
-        return self.executor.prefill(
+        sampled = self.executor.prefill(
             bucket, input_ids=ids, positions=pos, block_tables=bt,
             last_idx=last_idx, adapter_ids=adapter_ids,
             state_slots=state_slots, sample=sample)
+        # Booked for a call that went out (a refused one raised above).
+        self.stats["prefill_batches"] += 1
+        self.stats["prefill_widest_call_tokens"] = max(
+            self.stats["prefill_widest_call_tokens"], B * bucket)
+        self.stats["prefill_tokens"] += sum(len(c[1]) for c in chunks)
+        self.stats["prefill_context_tokens"] += sum(c[2] for c in chunks)
+        return sampled
 
     def _count(self, counters: np.ndarray, decode: bool) -> None:
         """Book the model's counters: ``(program calls or decode steps,
@@ -1234,28 +1362,51 @@ class InferenceEngine:
     def _masked_rows(self) -> list:
         return [s.slot_id for s in self.slots if s.prefilling]
 
-    def _decode_dispatch(self):
-        """Schedule this round's decode work and dispatch its program call
-        WITHOUT syncing: returns an opaque pending tuple whose device
-        arrays are still being computed, for :meth:`_decode_complete`.
-        All host mirrors are snapshotted here (the executor uploads copies
-        at call time), so admission may mutate them while the call is in
-        flight."""
+    def _decode_dispatch(self, ahead_of=None):
+        """Schedule a decode round and dispatch its program call WITHOUT
+        syncing: returns an opaque pending tuple whose device arrays are
+        still being computed, for :meth:`_decode_complete`. All host
+        mirrors are snapshotted here (the executor uploads copies at call
+        time), so emission and admission may mutate them while the call is
+        in flight. ``ahead_of``: the plain round still in flight, behind
+        which this one is launched; None then means that the round cannot
+        be planned without that round's tokens (or that nothing would
+        decode), and the caller fetches first."""
         tr = self._tracer
         with tr.span("engine/decode_prep", cat="engine"):
-            plan = self._decode_prepare()
+            plan = self._decode_prepare(ahead_of)
         if plan is None:
             return None
         with tr.span("engine/decode_launch", cat="engine"):
-            launch = self._spec_launch if plan[0] == "spec" \
-                else self._decode_launch
-            return launch(*plan[1:])
+            if plan[0] == "spec":
+                return self._spec_launch(*plan[1:])
+            return self._decode_launch(*plan[1:], ahead_of)
 
-    def _decode_prepare(self):
+    def _ends_with_its_next_token(self, req: Request) -> bool:
+        """Whether the host can tell, before it sees the token a round in
+        flight draws for ``req``, that the token is the request's last:
+        the answer's or the model's length, or a cancellation. (An
+        end-of-sequence or stop token it cannot foresee.)"""
+        n = len(req.output_token_ids) + 1
+        return (req.cancel_requested or n >= req.params.max_tokens
+                or len(req.prompt_token_ids) + n >= self.cfg.max_model_len)
+
+    def _decode_prepare(self, ahead_of=None):
         """Everything of a decode round before its program call: spec
         gate, block growth (and preemption), batch assembly and the upload
         of the per-slot state. ``(kind, *arguments of the launch)``, or
-        None when preemption left nothing to decode."""
+        None when nothing is left to decode.
+
+        Behind a round still in flight (``ahead_of``) the plan is made from
+        what the host knows without that round's tokens. A slot that rides
+        in it stands one token further than the host has seen: its
+        position, its block growth and, where its row is uploaded again,
+        its gen count are those of this round's launch, and its input id is
+        ``RIDES``. A slot whose request ends with the token in flight is
+        left out and reads as a free slot does (:meth:`_clear_row`). None
+        also when such a plan cannot be made: a speculative round, a
+        multi-step window, or a pool that is out of blocks (preemption
+        needs every request's tokens on the host)."""
         ec = self.cfg
         # Multi-step windows are budget-clamped per round (_window_steps):
         # max_model_len safety lives in its min(...) term, so there is no
@@ -1264,6 +1415,62 @@ class InferenceEngine:
         # their block-table rows masked to the trash block.
         k_steps = 1
         active0 = [s for s in self.slots if not s.free and not s.prefilling]
+        riding: set = set()
+
+        # Grow block tables to cover the decode window; preempt the
+        # youngest if the pool is exhausted. (Prefilling slots already own
+        # blocks for prompt+1 from admission and are not decoding yet.)
+        def grow_tables(win_steps: int, spec: bool) -> bool:
+            for slot in sorted(active0,
+                               key=lambda s: s.request.arrival_time):
+                if slot.free:  # preempted by an earlier iteration
+                    continue
+                window = win_steps
+                if spec and slot.request.params.temperature != 0.0:
+                    # Sampling slots advance exactly one real token per
+                    # spec round; their draft-position writes past that
+                    # land on the trash block (unallocated table entries
+                    # are 0), so don't allocate — and possibly preempt
+                    # for — the full window.
+                    window = self.cfg.spec_rounds
+                need = self.block_manager.blocks_needed(
+                    slot.seq_len + (slot.slot_id in riding) + window)
+                while need > len(slot.blocks):
+                    got = self._alloc(1)
+                    if got is None:
+                        if ahead_of is not None or \
+                                not self._preempt_youngest(exclude=slot):
+                            return False
+                        continue
+                    slot.blocks.extend(got)
+                    self._block_tables[
+                        slot.slot_id, len(slot.blocks) - 1] = got[0]
+                    self.executor.mark_dirty(slot.slot_id)
+            return True
+
+        if ahead_of is not None:
+            if ec.steps_per_sync > 1 or (
+                    self._spec_hist is not None and any(
+                        s.request.params.temperature == 0.0
+                        and not self._spec_slot_pause[s.slot_id]
+                        for s in active0)):
+                return None
+            riding = {s.slot_id for s, req in ahead_of[1] if s.request is req}
+            ending = [s for s in active0 if s.slot_id in riding
+                      and self._ends_with_its_next_token(s.request)]
+            active0 = [s for s in active0 if s not in ending]
+            # A plain one-step round (no window, nobody speculates). Its
+            # blocks first: a plan given up for want of them has changed
+            # nothing (no cooldown ticked, no row cleared) but the blocks
+            # granted, which stay on slots that need them whatever order
+            # the rounds take.
+            if not grow_tables(1, False):
+                return None
+            for s in ending:
+                # No row of this round, and not free before the round in
+                # flight is fetched: for the device it is free already (the
+                # trash block, never position 0 of its own table).
+                self._clear_row(s.slot_id)
         # Speculative decode engages per ROUND when any active greedy slot
         # is unpaused (per-slot gating: _spec_round_gate ticks cooldowns
         # and returns this round's participants, and the program masks the
@@ -1291,39 +1498,7 @@ class InferenceEngine:
         elif ec.steps_per_sync > 1 and active0:
             k_steps = self._window_steps(active0)
 
-        # Grow block tables to cover the decode window; preempt the
-        # youngest if the pool is exhausted. (Prefilling slots already own
-        # blocks for prompt+1 from admission and are not decoding yet.)
-        def grow_tables(win_steps: int, spec: bool) -> bool:
-            for slot in sorted(
-                (s for s in self.slots if not s.free and not s.prefilling),
-                key=lambda s: s.request.arrival_time,
-            ):
-                if slot.free:  # preempted by an earlier iteration
-                    continue
-                window = win_steps
-                if spec and slot.request.params.temperature != 0.0:
-                    # Sampling slots advance exactly one real token per
-                    # spec round; their draft-position writes past that
-                    # land on the trash block (unallocated table entries
-                    # are 0), so don't allocate — and possibly preempt
-                    # for — the full window.
-                    window = self.cfg.spec_rounds
-                need = self.block_manager.blocks_needed(
-                    slot.seq_len + window)
-                while need > len(slot.blocks):
-                    got = self._alloc(1)
-                    if got is None:
-                        if not self._preempt_youngest(exclude=slot):
-                            return False
-                        continue
-                    slot.blocks.extend(got)
-                    self._block_tables[
-                        slot.slot_id, len(slot.blocks) - 1] = got[0]
-                    self.executor.mark_dirty(slot.slot_id)
-            return True
-
-        if not grow_tables(k_steps, use_spec):
+        if ahead_of is None and not grow_tables(k_steps, use_spec):
             if k_steps > 1:
                 # Defer, don't fault: a multi-step window that cannot
                 # reserve its worst-case blocks shrinks to a single-step
@@ -1341,8 +1516,7 @@ class InferenceEngine:
                     "increase num_blocks or lower max_seqs"
                 )
 
-        active = [s for s in self.slots
-                  if not s.free and not s.prefilling]
+        active = [s for s in active0 if not s.free and not s.prefilling]
         if not active:
             return None
         if use_spec:
@@ -1352,48 +1526,69 @@ class InferenceEngine:
         ids = np.zeros((ec.max_seqs, 1), np.int32)
         pos = np.zeros((ec.max_seqs, 1), np.int32)  # inactive -> trash block
         for s in active:
-            ids[s.slot_id, 0] = s.last_token
-            pos[s.slot_id, 0] = s.seq_len  # position of the new token
-        self._book_decode_context(active, k_steps)
+            rides = s.slot_id in riding
+            ids[s.slot_id, 0] = RIDES if rides else s.last_token
+            pos[s.slot_id, 0] = s.seq_len + rides  # position of the new token
+        self._book_decode_context(active, k_steps, riding)
         self.stats["decode_steps_sorted_sampling"] += \
             k_steps * self._sampling_sorts()
+        mirrors = self._state_mirrors()
+        if riding:
+            # A row uploaded again is uploaded as of this round's launch:
+            # the device has counted the token in flight, the mirror has
+            # not (a row drawn with the count before would repeat a draw).
+            mirrors["gen_counts"] = self._gen_counts.copy()
+            mirrors["gen_counts"][[s.slot_id for s in active
+                                   if s.slot_id in riding]] += 1
         # Device-resident per-slot state: only rows dirtied since the
         # last dispatch are shipped; a clean step uploads nothing.
         staged = self.executor.stage_decode(
-            ids, pos, self._state_mirrors(), self._masked_rows())
+            ids, pos, mirrors, self._masked_rows())
         # Host prep cost of this dispatch (batch assembly + state sync) —
         # the term dirty tracking is meant to hold flat as max_seqs grows.
         self.telemetry.host_prep.observe(time.perf_counter() - t_prep)
-        return ("plain", active, k_steps, staged)
+        return ("plain", [(s, s.request) for s in active], k_steps, staged)
 
-    def _decode_launch(self, active: List[_Slot], k_steps: int, staged):
-        """The compiled decode call (not waited for)."""
-        return ("plain", active, k_steps,
-                self.executor.launch_decode(staged, k_steps))
+    def _decode_launch(self, rows: List[tuple], k_steps: int, staged,
+                       ahead_of=None):
+        """The compiled decode call (not waited for). ``rows``: the
+        ``(slot, request)`` pairs it decodes for."""
+        if ahead_of is not None:
+            self.stats["decode_rounds_launched_ahead"] += 1
+        return ("plain", rows, k_steps, self.executor.launch_decode(
+            staged, k_steps, ahead_of[-1] if ahead_of is not None else None))
 
     def _decode_complete(self, pending) -> List[Request]:
         """Sync a dispatched decode round's results and walk emissions."""
         tr = self._tracer
         kind, *plan, device = pending
-        # The wait ends when the round's results are on the host; until
-        # then the chip is at work. The emission walk after it is host
-        # time during which nothing is in flight.
+        # The wait ends when the round's results are on the host. The
+        # emission walk after it is host time, under the next round's
+        # program where the loop ran ahead and under none where it did not.
         with tr.span("engine/decode_wait", cat="engine"):
             host = self.executor.fetch(device)
         with tr.span("engine/decode_emit", cat="engine"):
             walk = self._spec_emit if kind == "spec" else self._decode_emit
             return walk(*plan, *host)
 
-    def _decode_emit(self, active: List[_Slot], k_steps: int,
+    def _decode_emit(self, rows: List[tuple], k_steps: int,
                      tokens: np.ndarray, logprobs: np.ndarray,
                      ) -> List[Request]:
         """Numeric guards and the per-slot emission walk of a plain round
-        (``tokens``, ``logprobs``: (S, k_steps), on the host)."""
+        (``tokens``, ``logprobs``: (S, k_steps), or (S,) of one step, on
+        the host). A row counts only if its slot still holds the request
+        it held at the launch: a request that ended in the round before
+        has a row in a round launched ahead, and its slot may hold another
+        request by now, to whom that token does not belong."""
+        tokens = tokens.reshape(len(tokens), -1)
+        logprobs = logprobs.reshape(len(logprobs), -1)
         self.stats["decode_steps"] += k_steps
         if self.executor.counter_names:
             # Rows after the slots': each step's counters.
             self._count(tokens[self.cfg.max_seqs:].T, decode=True)
             tokens = tokens[:self.cfg.max_seqs]
+        active = [s for s, req in rows if s.request is req]
+        self.stats["decode_rows_discarded"] += len(rows) - len(active)
 
         # Numeric guard — the WHOLE round is validated before any token
         # is appended: a partially-appended round would survive failover
@@ -1499,11 +1694,14 @@ class InferenceEngine:
         self._spec_slot_pause[sid] = 0
         self._spec_slot_ewma[sid] = float(self.cfg.num_draft_tokens)
 
-    def _book_decode_context(self, active: List[_Slot], steps: int) -> None:
+    def _book_decode_context(self, active: List[_Slot], steps: int,
+                             riding=frozenset()) -> None:
         """What a round of ``steps`` decode steps attends over, booked when it
-        is dispatched: the active slots' cached tokens, and the keys of the
+        is dispatched: the active slots' cached tokens (one more for a slot
+        ``riding`` in the round still in flight), and the keys of the
         kernel tiles that hold them and the new token."""
-        lens = np.fromiter((s.seq_len for s in active), np.int64, len(active))
+        lens = np.fromiter((s.seq_len + (s.slot_id in riding) for s in active),
+                           np.int64, len(active))
         tile = self.executor.decode_tile_tokens
         self.stats["decode_context_tokens"] += int(lens.sum()) * steps
         self.stats["decode_kernel_tile_tokens"] += \
@@ -1658,9 +1856,10 @@ class InferenceEngine:
         req._adapter_slot = -1
 
     def _fail_waiting(self, req: Request, msg: str) -> None:
-        """Finish a not-yet-admitted request as an error (unknown or
-        corrupt adapter): strictly request-scoped — the engine, its
-        slots, and the rest of the queue are untouched."""
+        """Finish a request that holds no slot as an error (unknown or
+        corrupt adapter; a prefill call of its own that was refused, its
+        slot released): strictly request-scoped — the engine, its slots,
+        and the rest of the queue are untouched."""
         self.logger.warning("request %s failed at admission: %s",
                             req.request_id, msg)
         self._release_adapter(req)
@@ -1695,18 +1894,23 @@ class InferenceEngine:
         slot.next_pos = 0
         slot.prefill_end = 0
         slot.shared_blocks = 0
-        self._block_tables[slot.slot_id] = 0
-        self._temperature[slot.slot_id] = 1.0
-        self._top_k[slot.slot_id] = 0
-        self._top_p[slot.slot_id] = 1.0
-        self._slot_keys[slot.slot_id] = 0
-        self._gen_counts[slot.slot_id] = 0
-        self._adapter_ids[slot.slot_id] = 0
-        # The slot's recurrent state is dropped with it: nothing reads it
-        # again, and the next admission starts from zero (position 0).
-        self._state_slots[slot.slot_id] = self.cfg.max_seqs
+        self._clear_row(slot.slot_id)
         self._spec_reset_slot(slot.slot_id)
-        self.executor.mark_dirty(slot.slot_id)
+
+    def _clear_row(self, slot_id: int) -> None:
+        """A slot's row of every mirror as a free slot's reads, for the next
+        upload: the trash block, default sampling (which sorts nothing), no
+        recurrent state to write (it is dropped with the slot: nothing
+        reads it again, and the next admission starts from zero)."""
+        self._block_tables[slot_id] = 0
+        self._temperature[slot_id] = 1.0
+        self._top_k[slot_id] = 0
+        self._top_p[slot_id] = 1.0
+        self._slot_keys[slot_id] = 0
+        self._gen_counts[slot_id] = 0
+        self._adapter_ids[slot_id] = 0
+        self._state_slots[slot_id] = self.cfg.max_seqs
+        self.executor.mark_dirty(slot_id)
 
     # ------------------------------------------------------------------
     # Disaggregated prefill/decode handoff (serving/disagg.py)
@@ -1725,6 +1929,13 @@ class InferenceEngine:
         fold_in(key, gen_count) stream must continue exactly where prefill
         sampling left it. Returns None (slot untouched) if any block fetch
         fails — the caller falls back to a re-prefill elsewhere.
+
+        With a decode round in flight the snapshot is as of the tokens
+        emitted: the token that round draws for this slot is thrown away
+        when the round is fetched (the slot no longer holds the request),
+        and the adopting engine draws it again from the same key and count.
+        The block fetches wait for the round, whose one write for this
+        sequence is the key and value the adopter's first step writes too.
         """
         refuse_state_handoff(self.model_cfg, "export_handoff")
         req = slot.request
@@ -1835,8 +2046,10 @@ class InferenceEngine:
         requests in place would either hot-loop the same failing program
         (persistent faults) or burn whole decode windows generating
         tokens nobody reads (transient faults). Returns the aborted
-        requests (their ``finish_reason`` is set to ``reason``).
+        requests (their ``finish_reason`` is set to ``reason``). A round
+        in flight is waited for and its tokens thrown away first.
         """
+        self._drop_inflight()
         aborted: List[Request] = []
         for slot in self.slots:
             if slot.request is not None:

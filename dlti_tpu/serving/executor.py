@@ -33,6 +33,24 @@ from dlti_tpu.utils.platform import device_facts
 if TYPE_CHECKING:
     from dlti_tpu.serving.engine import EngineConfig
 
+# The id the scheduler gives a decode row whose input token is not on the
+# host yet: the row rides from the round before, still in flight, and the
+# decode program reads that round's sampled token on the device.
+RIDES = -1
+
+
+class PrefillCallRefused(RuntimeError):
+    """A prefill program could not be called at a shape: the call raised
+    before the program ran (it did not compile, or was refused its memory),
+    so nothing was written and the cache is whole. ``shape``: ``(rows,
+    bucket, table width)``, rows as padded."""
+
+    def __init__(self, shape: tuple, first_line: str):
+        super().__init__(
+            "prefill program refused at %d rows x %d tokens x %d blocks "
+            "a row: %s" % (*shape, first_line))
+        self.shape = shape
+
 
 def refuse_unsupported(model_cfg: ModelConfig, engine_cfg: "EngineConfig",
                        mesh=None) -> None:
@@ -160,6 +178,10 @@ class EngineExecutor:
         self.counter_names = tuple(getattr(self.model, "counter_names", ()))
         # The most padded tokens one prefill call may hold (0: no limit).
         self.prefill_call_tokens = getattr(self.model, "prefill_call_tokens", 0)
+        # The ``(rows, bucket, table width)`` at which a prefill call has
+        # been refused in this process (``PrefillCallRefused``): the
+        # scheduler does not form such a call again.
+        self.refused_prefill_shapes: set = set()
         # Whether a prefill call takes each row's whole block table (a model
         # whose cached context is cheap to gather) or the narrowest power
         # of two that holds the call's rows.
@@ -346,6 +368,10 @@ class EngineExecutor:
         self.decode_state = DecodeStateCache(
             ec.max_seqs, device=self._device, mesh=mesh, stats=stats,
             extra_fields=(self._row_extra,) if self._row_extra else ())
+        # What the one-step decode program takes for "the round before"
+        # when there is none: nothing rides, so nothing reads it.
+        self._no_prev = self.decode_state.place(np.zeros(
+            (ec.max_seqs + len(self.counter_names),), np.int32))
 
     # ------------------------------------------------------------------
     def _shard_for_tp(self, mesh) -> None:
@@ -477,13 +503,23 @@ class EngineExecutor:
         return prefill
 
     def _build_decode_fn(self):
+        S = self.cfg.max_seqs
+
         @partial(jax.jit, donate_argnums=(1,))
-        def decode(params, cache_kv, input_ids, positions, block_tables,
-                   slot_keys, gen_counts, temperature, top_k, top_p, *lora):
+        def decode(params, cache_kv, prev_tokens, input_ids, positions,
+                   block_tables, slot_keys, gen_counts, temperature, top_k,
+                   top_p, *lora):
             # input_ids/positions: (S, 1); block_tables: (S, max_blocks).
             # *lora: (adapter_ids, adapters) when the multi-LoRA pool is
             # on (adapter_ids rides in decode-state argument order, the
             # pool tree LAST so state threading stays contiguous).
+            # prev_tokens: the token output of the round before, as that
+            # call returned it (the model's counter rows after the slots',
+            # cut off here). A row whose host id is negative (RIDES) takes
+            # its input from there: the round before need not have reached
+            # the host when this one is launched.
+            input_ids = jnp.where(input_ids < 0, prev_tokens[:S, None],
+                                  input_ids)
             logits, new_kv, counters = self._model_cache_call(
                 params, cache_kv, block_tables, input_ids, positions,
                 **self._named(lora), own_rows=True)
@@ -495,6 +531,11 @@ class EngineExecutor:
                 # The model's counters ride as rows after the slots'
                 # tokens: the fetch that exists brings them.
                 tokens = jnp.concatenate([tokens, counters])
+            if self.mesh is not None:
+                # The next call takes these tokens back as they lie: pin
+                # the layout the warmed executable was lowered for.
+                tokens = jax.lax.with_sharding_constraint(
+                    tokens, self._no_prev.sharding)
             return new_kv, tokens, logprobs
 
         return decode
@@ -717,11 +758,31 @@ class EngineExecutor:
         last real logit, on the same per-slot key + count stream the decode
         programs use, and ``(tokens, logprobs)`` come back (the model's
         counters as rows after the tokens); else None — the call wrote the
-        cache only, and its counters wait for the next sampled call."""
-        self.cache, last_logits, *counters = self._prefill_fn(bucket)(
-            self.params, self.cache, jnp.asarray(input_ids),
-            jnp.asarray(positions), jnp.asarray(block_tables),
-            jnp.asarray(last_idx), *self._trailing(adapter_ids, state_slots))
+        cache only, and its counters wait for the next sampled call.
+
+        :class:`PrefillCallRefused` when the program call raises before the
+        program has run: a shape nobody compiled whose program does not
+        fit the device raises from the call itself, and the donated cache
+        is consumed only by a dispatch that succeeded (checked here, not
+        assumed: with the cache gone the error is passed on as it is)."""
+        try:
+            self.cache, last_logits, *counters = self._prefill_fn(bucket)(
+                self.params, self.cache, jnp.asarray(input_ids),
+                jnp.asarray(positions), jnp.asarray(block_tables),
+                jnp.asarray(last_idx),
+                *self._trailing(adapter_ids, state_slots))
+        except jax.errors.JaxRuntimeError as e:
+            if any(leaf.is_deleted()
+                   for leaf in jax.tree_util.tree_leaves(self.cache)):
+                raise
+            refused = PrefillCallRefused(
+                (*input_ids.shape, block_tables.shape[1]),
+                (str(e).splitlines() or [type(e).__name__])[0])
+            self.refused_prefill_shapes.add(refused.shape)
+            # One record: the line that names the shape, then the error as
+            # the compiler gave it (its largest allocations name the arrays).
+            self.logger.warning("jit_prefill: %s", refused, exc_info=e)
+            raise refused from e
         if counters and self._prefill_counters is not None:
             counters = [self._prefill_counters + counters[0]]
             self._prefill_counters = None
@@ -739,27 +800,32 @@ class EngineExecutor:
     def stage_decode(self, input_ids: np.ndarray, positions: np.ndarray,
                      mirrors: Dict[str, np.ndarray],
                      masked_rows: Sequence[int]) -> tuple:
-        """Upload a plain decode round: the ``(S, 1)`` tokens and positions,
-        and of the per-slot ``mirrors`` only the rows dirtied since the
-        last round (``masked_rows``: slots still prefilling, whose block
-        tables must read as the trash block). What :meth:`launch_decode`
-        takes."""
+        """Upload a plain decode round: the ``(S, 1)`` tokens (``RIDES``
+        where a row's token is the one the round before sampled, which the
+        program reads on the device) and positions, and of the per-slot
+        ``mirrors`` only the rows dirtied since the last round, as of THIS
+        round's launch (``gen_counts`` of a riding row counts the token
+        still in flight). ``masked_rows``: slots still prefilling, whose
+        block tables must read as the trash block. What
+        :meth:`launch_decode` takes."""
         return (jnp.asarray(input_ids), jnp.asarray(positions),
                 *self.decode_state.sync(mirrors, masked_rows),
                 *self._pool_tree())
 
-    def launch_decode(self, staged: tuple, k_steps: int):
+    def launch_decode(self, staged: tuple, k_steps: int, prev=None):
         """Call the ``k_steps``-step decode program: ``(tokens, logprobs)``,
-        each ``(S, k_steps)`` (the model's counters as rows after the
-        slots')."""
+        ``(S, k_steps)`` each, or ``(S,)`` from the one-step program (the
+        model's counters as rows after the slots'). ``prev``: what the
+        launch of the one-step round before returned, fetched or not; the
+        rows staged as ``RIDES`` read their token from it. Nothing is
+        dispatched between two one-step rounds but the count bump below."""
         if k_steps > 1:
             self.cache, tokens, logprobs = self._multi_decode_fn(k_steps)(
                 self.params, self.cache, *staged)
         else:
             self.cache, tokens, logprobs = self._decode_fn(
-                self.params, self.cache, *staged)
-            tokens = tokens[:, None]
-            logprobs = logprobs[:, None]
+                self.params, self.cache,
+                self._no_prev if prev is None else prev[0], *staged)
         # The window advances every surviving slot's gen count by exactly
         # k_steps (a slot finishing mid-window is released, which marks it
         # dirty) — advance the resident counts on device instead of
@@ -839,8 +905,12 @@ class EngineExecutor:
                 *avals(self._pool_tree()))
         # Idempotent: a re-warm unwraps back to the raw jit fn (the
         # _aot_or_jit wrapper has no .lower) and rebuilds the executable.
+        # The one-step program also takes the round before's tokens, a
+        # committed array whether it is the stand-in or a call's output.
         raw = getattr(self._decode_fn, "_jit_fn", self._decode_fn)
-        self._decode_fn = self._aot_or_jit(raw.lower(*args).compile(), raw)
+        self._decode_fn = self._aot_or_jit(
+            raw.lower(*args[:2], avals(self._no_prev), *args[2:]).compile(),
+            raw)
         k = self.cfg.steps_per_sync
         while k > 1:
             fn = self._multi_decode_fn(k)

@@ -51,7 +51,7 @@ from dlti_tpu.utils.logging import get_logger
 # exposition names are dlti_<key> — scraped by external dashboards, so keys
 # here and in the engine's stats dict must not be renamed.
 _GAUGE_KEYS = ("active_seqs", "waiting", "free_blocks",
-               "recurrent_state_pool_bytes")
+               "recurrent_state_pool_bytes", "prefill_widest_call_tokens")
 
 
 def build_registry(async_engine: "AsyncEngine") -> MetricsRegistry:
@@ -387,6 +387,11 @@ class AsyncEngine:
                 if self._stop:
                     for q in self._queues.values():
                         q.put(("error", "server shutting down"))
+                    # The engine leaves a decode round in flight between
+                    # two steps, and abort_all waits for it: no program
+                    # runs and no device array is owed when the process
+                    # exits.
+                    self.engine.abort_all(reason="shutdown")
                     return
             finally:
                 self._work.release()

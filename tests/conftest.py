@@ -76,6 +76,38 @@ def rng():
     return jax.random.PRNGKey(0)
 
 
+@pytest.fixture(scope="session")
+def fetch_first_engine():
+    """``InferenceEngine`` in the order it had before its loop ran a round
+    ahead of the host, as the tests' own hook (no option of the program):
+    every round is fetched in the step that launched it, so every plan is
+    made from the host's tokens and no row ever rides. What the loop that
+    runs ahead is held to, stream for stream."""
+    from dlti_tpu.serving import InferenceEngine
+
+    class FetchFirst(InferenceEngine):
+        def step(self):
+            out = super().step()
+            if self._inflight is not None:
+                pending, self._inflight = self._inflight, None
+                out = out + self._decode_complete(pending)
+            return out
+
+    return FetchFirst
+
+
+@pytest.fixture
+def engine_log(caplog):
+    """``caplog`` that also hears the package's logger (``dlti_tpu``, which
+    does not propagate to the root)."""
+    from dlti_tpu.utils.logging import get_logger
+
+    logger = get_logger()
+    logger.addHandler(caplog.handler)
+    yield caplog
+    logger.removeHandler(caplog.handler)
+
+
 def make_packed_segments(b, s, n_docs=3, seed=0):
     """Shared packed-batch layout for attention tests: contiguous docs
     1..n_docs with random cut points, trailing padding id 0."""

@@ -19,6 +19,7 @@ import pytest
 from dlti_tpu.config import MODEL_PRESETS
 from dlti_tpu.serving import EngineConfig, InferenceEngine, SamplingParams
 from dlti_tpu.serving import engine as engine_module
+from dlti_tpu.serving.executor import RIDES, PrefillCallRefused
 
 CFG = MODEL_PRESETS["llama_tiny"]
 EOS = 90
@@ -42,6 +43,10 @@ class ScriptedExecutor:
         self.cfg = engine_cfg
         self.calls = []
         self.dirty = []
+        self.unfetched = []  # token arrays of decode rounds not fetched yet
+        # (rows, bucket, table width) -> bool: a call the device refuses
+        self.refuses = lambda shape: False
+        self.refused_prefill_shapes = set()
 
     # -- what the scheduler asks besides program calls --------------------
     def register_memory_owners(self, ledger):
@@ -69,6 +74,12 @@ class ScriptedExecutor:
         _all_numpy(input_ids, positions, block_tables, last_idx,
                    adapter_ids, state_slots, *(sample or {}).values())
         assert input_ids.shape == positions.shape == (len(last_idx), bucket)
+        shape = (*input_ids.shape, block_tables.shape[1])
+        if self.refuses(shape):   # raised by the call: nothing ran
+            self.calls.append(("refused", shape))
+            self.refused_prefill_shapes.add(shape)
+            raise PrefillCallRefused(shape, "RESOURCE_EXHAUSTED: Used 18.28G "
+                                     "of 15.75G hbm")
         self.calls.append(("prefill", {
             "bucket": bucket, "input_ids": input_ids.copy(),
             "positions": positions.copy(),
@@ -87,20 +98,37 @@ class ScriptedExecutor:
             "input_ids": input_ids[:, 0].copy(),
             "positions": positions[:, 0].copy(),
             "block_tables": mirrors["block_tables"].copy(),
+            "gen_counts": mirrors["gen_counts"].copy(),
+            "state_slots": mirrors["state_slots"].copy(),
+            "top_k": mirrors["top_k"].copy(),
             "masked_rows": list(masked_rows)}))
         return input_ids[:, 0].copy()
 
-    def launch_decode(self, staged, k_steps):
-        self.calls[-1][1]["k_steps"] = k_steps
+    def launch_decode(self, staged, k_steps, prev=None):
+        call = self.calls[-1][1]
+        call["k_steps"] = k_steps
+        rides = staged == RIDES
+        call["launched_ahead"] = prev is not None
+        if rides.any():
+            # what the program does on the device: the round before's tokens
+            assert k_steps == 1 and prev is not None
+            staged = np.where(rides, prev[0][:len(staged)], staged)
+        call["inputs"] = staged.copy()
         tokens = staged[:, None] + 1 + np.arange(k_steps)[None, :]
         if self.counter_names:
             tokens = np.concatenate([tokens, self._counter_rows(k_steps)])
-        return (tokens.astype(np.int32),
-                np.zeros((len(staged), k_steps), np.float32))
+        logprobs = np.zeros((len(staged), k_steps), np.float32)
+        if k_steps == 1:  # the one-step program's outputs have no step axis
+            tokens, logprobs = tokens[:, 0], logprobs[:, 0]
+        tokens = tokens.astype(np.int32)
+        self.unfetched.append(tokens)
+        return tokens, logprobs
 
-    @staticmethod
-    def fetch(arrays):
+    def fetch(self, arrays):
         _all_numpy(*arrays)
+        decode = any(t is arrays[0] for t in self.unfetched)
+        self.unfetched = [t for t in self.unfetched if t is not arrays[0]]
+        self.calls.append(("fetch", {"decode": decode}))
         return list(arrays)
 
     def of(self, kind):
@@ -345,27 +373,27 @@ def test_decode_counters_are_booked_as_perf_md_says(name, monkeypatch):
         eng.submit(p, SamplingParams(max_tokens=n, top_k=3 if p[0] == 40
                                      else 0))
     # the books, kept by hand from what the executor was asked to do
-    steps = slot_steps = context = tile_keys = sorted_steps = 0
+    steps = context = tile_keys = sorted_steps = 0
     seen = 0
     while eng.has_work:
-        live = {s.slot_id: (s.seq_len, s.request.params.max_tokens
-                            - len(s.request.output_token_ids))
-                for s in eng.slots if not s.free and not s.prefilling}
-        sorting = bool((eng._top_k > 0).any())
         eng.step()
         calls = eng.executor.of("decode")[seen:]
         seen += len(calls)
         for call in calls:
             k = call["k_steps"]
             steps += k
-            # context: what each live slot had cached when the round began
-            context += k * sum(seq for seq, _ in live.values())
+            # context: what each row of the round had cached when it began,
+            # which is the position its new token is written at (a row that
+            # rides in the round before stands one further than the host
+            # has seen)
+            cached = call["positions"][np.nonzero(call["positions"])[0]]
+            context += k * int(cached.sum())
             # whole tiles of 4 keys over those tokens and the new one
-            tile_keys += k * sum(4 * -(-(seq + 1) // 4)
-                                 for seq, _ in live.values())
-            # a slot counts a step until its answer is complete
-            slot_steps += sum(min(k, left) for _, left in live.values())
-            sorted_steps += k * sorting
+            tile_keys += k * sum(4 * -(-(int(c) + 1) // 4) for c in cached)
+            # the program's own predicate, over the rows it was given
+            sorted_steps += k * bool((call["top_k"] > 0).any())
+    # a slot counts a step for every token it kept: all but the prefill's
+    slot_steps = sum(lengths) - len(lengths)
     st = eng.stats
     assert st["decode_steps"] == steps > 0
     assert st["decode_slot_steps"] == slot_steps
@@ -394,10 +422,11 @@ def test_live_share_of_the_kernels_tiles_on_a_hand_made_batch(tile,
     eng = _engine(steps_per_sync=1, max_seqs=3)
     for n in (3, 8, 13):
         eng.submit(list(range(10, 10 + n)), SamplingParams(max_tokens=3))
-    while not eng.stats["decode_steps"]:
+    while not eng.executor.of("decode"):
         eng.step()
     st = eng.stats
-    assert st["decode_steps"] == 1
+    # booked when a round is launched; it is fetched a step later
+    assert st["decode_steps"] == 0 and len(eng.executor.of("decode")) == 1
     assert st["decode_context_tokens"] == 24
     assert st["decode_kernel_tile_tokens"] == {4: 32, 16: 48}[tile]
     _drain(eng)
@@ -451,6 +480,21 @@ def test_a_prompt_past_the_models_call_limit_goes_as_several_calls(
     assert st["prefill_context_tokens"] == 8 + 16
 
 
+def test_an_admission_wave_past_the_call_limit_goes_as_calls_that_fit(
+        monkeypatch):
+    """Rows of one prompt bucket waiting together go out as many rows a call
+    as the model's limit holds of that bucket, never as one call over it."""
+    monkeypatch.setattr(ScriptedExecutor, "prefill_call_tokens", 32)
+    eng = _engine(max_seqs=8, max_model_len=48, num_blocks=64)
+    prompts = [list(range(100 + 20 * i, 109 + 20 * i)) for i in range(5)]
+    reqs = [eng.submit(p, SamplingParams(max_tokens=2)) for p in prompts]
+    _drain(eng)
+    calls = eng.executor.of("prefill")
+    assert [c["input_ids"].shape for c in calls] == [(2, 16), (2, 16), (1, 16)]
+    assert [r.output_token_ids for r in reqs] == [_stream(p, 2)
+                                                  for p in prompts]
+
+
 # -- prefix caching: a prompt's blocks are matchable once prefilled ----------
 
 def test_a_prompt_asked_again_while_it_decodes_hits_the_running_sequence():
@@ -478,3 +522,370 @@ def test_a_prompt_asked_again_while_it_decodes_hits_the_running_sequence():
     pc = eng.prefix_cache
     assert pc.num_free + pc.num_reclaimable == 31   # every block accounted
     assert all(e.refcount == 0 for e in pc._by_block.values())
+
+
+# -- one round ahead of the host ----------------------------------------------
+
+def _kinds(ex, start=0):
+    """The call log as words: a decode round by how it was launched, a fetch
+    by what it fetched."""
+    out = []
+    for kind, call in ex.calls[start:]:
+        if kind == "decode":
+            out.append("ahead" if call["launched_ahead"] else "launch")
+        elif kind == "fetch":
+            out.append("fetch" if call["decode"] else "fetch_prefill")
+        else:
+            out.append(kind)
+    return out
+
+
+def test_the_next_round_is_launched_before_the_round_in_flight_is_fetched():
+    eng = _engine()
+    req = eng.submit([10, 11, 12], SamplingParams(max_tokens=6))
+    eng.step()
+    assert _kinds(eng.executor) == ["prefill", "fetch_prefill"]
+    eng.step()                      # nothing in flight: from the host's token
+    assert _kinds(eng.executor, 2) == ["launch"]
+    assert eng.executor.of("decode")[0]["input_ids"][0] == 13
+    assert len(req.output_token_ids) == 1 and eng.stats["decode_steps"] == 0
+    for n in range(3):              # then: the next round first, then the fetch
+        eng.step()
+        assert _kinds(eng.executor, 3 + 2 * n) == ["ahead", "fetch"]
+        assert len(req.output_token_ids) == 2 + n
+    call = eng.executor.of("decode")[-1]
+    # the riding row's token is read on the device, its position is the
+    # host's plus the round in flight, and so is its count
+    assert call["input_ids"][0] == RIDES and call["inputs"][0] == 16
+    assert call["positions"][0] == 3 + 3 and call["gen_counts"][0] == 4
+    _drain(eng)
+    assert req.output_token_ids == _stream([10, 11, 12], 6)
+    assert not eng.executor.unfetched
+    st = eng.stats
+    assert (st["decode_steps"], st["decode_rounds_launched_ahead"]) == (5, 4)
+    assert st["decode_rows_discarded"] == 0  # its end was known beforehand
+
+
+def test_a_request_that_ends_unforeseen_has_its_next_row_thrown_away():
+    """EOS in round n: the request retires at n's emission; its row in round
+    n+1, launched before, is thrown away and counted; whoever takes its slot
+    and blocks is dispatched after n+1 and gets none of n+1's tokens."""
+    eng = _engine(max_seqs=1)
+    first = eng.submit([EOS - 3], SamplingParams(max_tokens=20))
+    second = eng.submit([20, 21], SamplingParams(max_tokens=4))
+    _drain(eng)
+    assert first.output_token_ids == [EOS - 2, EOS - 1, EOS]
+    assert first.finish_reason == "stop"
+    assert second.output_token_ids == _stream([20, 21], 4)
+    ex = eng.executor
+    log = _kinds(ex)
+    # first: prefill, round 1 (EOS - 1), round 2 (EOS) with round 3 behind it
+    assert log[:7] == ["prefill", "fetch_prefill", "launch", "ahead", "fetch",
+                       "ahead", "fetch"]
+    # ... and the step that fetched round 2 put the second request in the
+    # slot, after round 3 in the log; round 3's fetch gave it nothing
+    assert log[7:11] == ["prefill", "fetch_prefill", "ahead", "fetch"]
+    third = ex.of("decode")[2]
+    assert third["inputs"][0] == EOS and third["positions"][0] == 3
+    # the second request's first round goes out behind round 3 too, with its
+    # first token as a host id: nothing of it rides
+    fourth = ex.of("decode")[3]
+    assert fourth["input_ids"][0] == 22 and fourth["positions"][0] == 2
+    assert fourth["gen_counts"][0] == 1
+    st = eng.stats
+    assert st["decode_rows_discarded"] == 1
+    assert st["decode_slot_steps"] == 2 + 3   # kept tokens only
+    assert st["decode_steps"] == 3 + 3        # the thrown-away round ran
+    assert not ex.unfetched
+    assert eng.block_manager.num_free == eng.cfg.num_blocks - 1
+
+
+@pytest.mark.parametrize("limit", ["max_tokens", "max_model_len", "cancel"])
+def test_a_request_whose_end_is_foreseen_is_left_out_and_masked(limit):
+    """Ending by length (or cancelled): not a row of the round launched
+    behind its last one; while it is unretired its row reads as a free
+    slot's there (the trash block, default sampling, no recurrent state to
+    write), never position 0 of its own table."""
+    over = dict(max_model_len=8) if limit == "max_model_len" else {}
+    eng = _engine(**over)
+    # prompt 3 + 5 = 8 = max_model_len; or 5 = max_tokens
+    short = eng.submit([10, 11, 12], SamplingParams(
+        max_tokens=5 if limit == "max_tokens" else 50))
+    long = eng.submit([40], SamplingParams(max_tokens=7))
+    if limit == "cancel":
+        while len(short.output_token_ids) < 4:
+            eng.step()
+        short.cancel_requested = True
+    _drain(eng)
+    assert short.output_token_ids == _stream([10, 11, 12], 5)
+    assert long.output_token_ids == _stream([40], 7)
+    sid = 0
+    last = [c for c in eng.executor.of("decode") if c["positions"][sid]][-1]
+    rounds = eng.executor.of("decode")
+    behind = rounds[[c is last for c in rounds].index(True) + 1]
+    assert last["positions"][sid] == 3 + 3       # its fifth token's round
+    assert behind["launched_ahead"]
+    assert behind["positions"][sid] == 0
+    assert not behind["block_tables"][sid].any()
+    assert behind["state_slots"][sid] == eng.cfg.max_seqs
+    assert behind["block_tables"][1].any()       # (the other row decodes on)
+    assert last["block_tables"][sid].any()
+    assert eng.stats["decode_rows_discarded"] == 0
+    assert not eng.executor.unfetched
+
+
+def test_a_plan_that_must_preempt_fetches_first():
+    eng = _engine(max_seqs=3, num_blocks=6, max_model_len=16)
+    prompts = [[10, 11, 12, 13, 14, 15, 16], [30, 31], [50]]
+    lengths = [9, 8, 8]
+    reqs = [eng.submit(p, SamplingParams(max_tokens=n))
+            for p, n in zip(prompts, lengths)]
+    seen = 0
+    preempting = []
+    while eng.has_work:
+        before = eng.stats["preemptions"]
+        eng.step()
+        if eng.stats["preemptions"] > before:
+            preempting.append(_kinds(eng.executor, seen))
+        seen = len(eng.executor.calls)
+    assert preempting
+    for log in preempting:
+        # no round went out behind the one in flight: it was fetched, then
+        # the plan (with its preemption) was made from the host's tokens
+        assert log[:2] == ["fetch", "launch"], log
+    for r, p, n in zip(reqs, prompts, lengths):
+        assert r.output_token_ids == _stream(p, n)
+    assert not eng.executor.unfetched
+    assert eng.stats["decode_rows_discarded"] == 0
+
+
+def test_a_plan_given_up_for_want_of_blocks_ticks_no_cooldown():
+    """Under speculation with every greedy slot paused the rounds are plain
+    and run ahead, each ticking the paused slots' cooldowns once. A plan
+    behind a round in flight that the pool has no block for is given up
+    before it has ticked anything: the round planned after the fetch is the
+    one that ticks, so a cooldown lasts as many rounds as it says."""
+    eng = _engine(max_seqs=3, num_blocks=6, max_model_len=16,
+                  speculative="ngram", num_draft_tokens=2,
+                  spec_min_acceptance=0.5, spec_cooldown=100)
+    prompts = [[10, 11, 12, 13, 14, 15, 16], [30, 31], [50]]
+    lengths = [9, 8, 8]
+    reqs = [eng.submit(p, SamplingParams(max_tokens=n, temperature=0.0))
+            for p, n in zip(prompts, lengths)]
+    gave_up = 0
+    while eng.has_work:
+        for s in eng.slots:  # a zero-hit probe window closed on every slot
+            eng._spec_slot_pause[s.slot_id] = 100
+        rounds = len(eng.executor.of("decode"))
+        ahead = eng.stats["decode_rounds_launched_ahead"]
+        paused = eng.stats["spec_paused_rounds"]
+        live = sum(not s.free and not s.prefilling for s in eng.slots)
+        had_inflight = eng._inflight is not None
+        eng.step()
+        launched = len(eng.executor.of("decode")) - rounds
+        if had_inflight and launched and \
+                eng.stats["decode_rounds_launched_ahead"] == ahead:
+            gave_up += 1
+        # one tick a slot of every round that went out, and no other
+        assert eng.stats["spec_paused_rounds"] - paused <= live * launched
+    assert gave_up and eng.stats["preemptions"]
+    assert eng.stats["spec_proposed"] == 0
+    for r, p, n in zip(reqs, prompts, lengths):
+        assert r.output_token_ids == _stream(p, n)
+    assert not eng.executor.unfetched
+
+
+@pytest.mark.parametrize("how", ["abort_all", "shutdown", "cancel",
+                                 "empties", "fault"])
+def test_a_round_in_flight_is_always_accounted_for(how, monkeypatch):
+    """Whatever ends the engine's work with a round in flight: no request is
+    left without a token that was emitted to it, none gets one that was
+    not, and no round stays unfetched."""
+    eng = _engine()
+    a = eng.submit([10], SamplingParams(max_tokens=30))
+    b = eng.submit([EOS - 4, EOS - 3] if how == "empties" else [40],
+                   SamplingParams(max_tokens=30))
+    for _ in range(3):
+        eng.step()
+    assert eng._inflight is not None and len(eng.executor.unfetched) == 1
+    kept = [list(r.output_token_ids) for r in (a, b)]
+    if how == "abort_all":
+        aborted = eng.abort_all(reason="error")
+        assert aborted == [a, b] and a.finish_reason == "error"
+        assert [r.output_token_ids for r in (a, b)] == kept
+    elif how == "shutdown":
+        # what the server's stop does, and then what a faulted step's
+        # recovery would: the second finds nothing in flight, nobody to fail
+        assert eng.abort_all(reason="shutdown") == [a, b]
+        assert [r.output_token_ids for r in (a, b)] == kept
+        assert not eng.executor.unfetched and eng._inflight is None
+        assert eng.abort_all(reason="error") == []
+        assert a.finish_reason == b.finish_reason == "shutdown"
+    elif how == "cancel":
+        a.cancel_requested = b.cancel_requested = True
+        _drain(eng)
+        # one more token each: that of the round that was in flight
+        assert [len(r.output_token_ids) for r in (a, b)] == \
+            [len(k) + 1 for k in kept]
+        assert a.finish_reason == b.finish_reason == "stop"
+    elif how == "empties":
+        a.cancel_requested = True
+        _drain(eng)                      # b samples EOS with a round behind it
+        assert b.output_token_ids == [EOS - 2, EOS - 1, EOS]
+        assert eng.stats["decode_rows_discarded"] == 1
+    else:
+        boom = RuntimeError("the device is gone")
+
+        def fetch(arrays):
+            eng.executor.unfetched.clear()
+            raise boom
+        monkeypatch.setattr(eng.executor, "fetch", fetch)
+        with pytest.raises(RuntimeError, match="device is gone"):
+            eng.step()
+        # the step dropped the round in flight and the one behind it
+        assert eng._inflight is None
+        assert eng.abort_all(reason="error") == [a, b]
+        assert [r.output_token_ids for r in (a, b)] == kept
+    assert eng._inflight is None and not eng.executor.unfetched
+    assert not eng.has_work
+    assert eng.block_manager.num_free == eng.cfg.num_blocks - 1
+    # and the engine serves on
+    monkeypatch.undo()
+    monkeypatch.setattr(engine_module, "EngineExecutor", ScriptedExecutor)
+    again = eng.submit([60, 61], SamplingParams(max_tokens=5))
+    _drain(eng)
+    assert again.output_token_ids == _stream([60, 61], 5)
+    assert not eng.executor.unfetched
+
+
+def test_every_row_is_uploaded_as_of_the_round_being_launched():
+    """Block growth dirties a riding row: what is uploaded for it is the
+    count the device has after the round in flight (kept tokens + 1), as its
+    position is. Held for every row of every round: position - prompt + 1."""
+    eng = _engine(max_seqs=3, block_size=2, num_blocks=64)
+    prompts = [[10, 11, 12], [40], [70, 71]]
+    for p, n in zip(prompts, (9, 12, 7)):
+        eng.submit(p, SamplingParams(max_tokens=n))
+    eng.submit([90, 91, 92, 93], SamplingParams(max_tokens=6))  # waits
+    by_first = {p[0]: len(p) for p in prompts + [[90, 91, 92, 93]]}
+    prompt_len = {}
+    while eng.has_work:
+        eng.step()
+        for s in eng.slots:
+            if s.request is not None:
+                prompt_len[(s.slot_id, len(eng.executor.of("decode")))] = \
+                    by_first[s.request.prompt_token_ids[0]]
+    ahead = grown = 0
+    for i, call in enumerate(eng.executor.of("decode")):
+        for sid in np.nonzero(call["positions"])[0]:
+            n = prompt_len.get((sid, i)) or prompt_len[(sid, i + 1)]
+            assert call["gen_counts"][sid] == call["positions"][sid] - n + 1
+            rides = call["input_ids"][sid] == RIDES
+            ahead += rides
+            grown += rides and call["positions"][sid] % 2 == 0
+    assert ahead > 20 and grown > 5   # riding rows, and some that grew a block
+
+
+# -- a prefill call that cannot be built --------------------------------------
+
+def _no_abort(eng, monkeypatch):
+    def abort_all(reason="abort"):
+        raise AssertionError("a refused prefill call is no fault of the "
+                             "engine's: abort_all was called")
+    monkeypatch.setattr(eng, "abort_all", abort_all)
+
+
+def test_a_refused_call_of_several_rows_goes_again_as_one_row_calls(
+        monkeypatch):
+    """Four admissions of one bucket whose 4-row call the device refuses:
+    four one-row calls in the same step, in order, with the tokens a
+    narrower admission would have given; the books count calls that went
+    out; and the scheduler does not form that shape again."""
+    eng = _engine(max_seqs=4, num_blocks=64)
+    _no_abort(eng, monkeypatch)
+    ex = eng.executor
+    ex.refuses = lambda shape: shape[0] == 4
+    prompts = [[10 * i + j for j in range(5 + i % 2)] for i in range(1, 5)]
+    reqs = [eng.submit(p, SamplingParams(max_tokens=3)) for p in prompts]
+    eng.step()
+    assert [k for k, _ in ex.calls] == ["refused"] + ["prefill",
+                                                      "fetch"] * 4
+    assert ex.calls[0][1] == (4, 8, 2)
+    calls = ex.of("prefill")
+    assert [c["input_ids"].shape for c in calls] == [(1, 8)] * 4
+    assert [list(c["input_ids"][0, :len(p)]) for c, p in zip(calls, prompts)] \
+        == prompts
+    assert [len(r.output_token_ids) for r in reqs] == [1] * 4
+    st = eng.stats
+    assert (st["prefill_calls_split"], st["prefill_calls_failed"]) == (1, 0)
+    assert st["prefill_batches"] == 4
+    assert st["prefill_tokens"] == sum(map(len, prompts))
+    assert st["prefill_widest_call_tokens"] == 8
+    _drain(eng)
+    # a second wave of four: four calls of one row, and nothing refused
+    more = [eng.submit([p[0] + 100] + p[1:], SamplingParams(max_tokens=3))
+            for p in prompts]
+    _drain(eng)
+    assert len(ex.of("refused")) == 1
+    assert [c["input_ids"].shape for c in ex.of("prefill")[4:]] == \
+        [(1, 8)] * 4
+    assert st["prefill_calls_split"] == 1 and st["prefill_batches"] == 8
+    assert st["prefill_widest_call_tokens"] == 8
+    # (another bucket keeps its rows)
+    assert eng._prefill_rows(8) == 1 and eng._prefill_rows(16) == 8
+    for r in reqs + more:
+        assert r.output_token_ids == _stream(r.prompt_token_ids, 3)
+        assert r.finish_reason == "length"
+    assert eng.block_manager.num_free == eng.cfg.num_blocks - 1
+
+
+@pytest.mark.parametrize("mode", ["throughput", "chunked", "long_prompt",
+                                  "prefix_cache"])
+def test_a_refused_one_row_call_fails_its_own_request_alone(
+        mode, monkeypatch, engine_log):
+    """The running streams go on to their ends with the tokens they would
+    have had; the one request ends as an error with its slot and blocks
+    released; ``abort_all`` is not called; counters and one log line."""
+    over = {"chunked": dict(max_prefill_tokens_per_step=8),
+            "prefix_cache": dict(enable_prefix_caching=True)}.get(mode, {})
+    if mode == "long_prompt":
+        # a prompt that goes as calls of 8 tokens: its second call is refused
+        monkeypatch.setattr(ScriptedExecutor, "prefill_call_tokens", 8)
+    eng = _engine(max_seqs=4, num_blocks=64, **over)
+    _no_abort(eng, monkeypatch)
+    prompts = [[10, 11, 12], [30], [50, 51]]
+    running = [eng.submit(p, SamplingParams(max_tokens=12)) for p in prompts]
+    for _ in range(4):
+        eng.step()
+    assert all(1 < len(r.output_token_ids) < 12 for r in running)
+    # the wide call: 20 tokens, bucket 32 (or 8, then 8, then 4 in calls of
+    # 8 tokens, of which the one that starts at position 8 is refused)
+    wide = list(range(60, 80))
+    ex = eng.executor
+    ex.refuses = (lambda shape: shape[1] == 32) if mode != "long_prompt" \
+        else (lambda shape: shape[2] > 2)
+    if mode == "chunked":
+        ex.refuses = lambda shape: shape[2] > 2
+    bad = eng.submit(wide, SamplingParams(max_tokens=5))
+    fine = eng.submit([90, 91], SamplingParams(max_tokens=4))
+    _drain(eng)
+    assert bad.finish_reason == "error" and bad.output_token_ids == []
+    assert bad in eng.finished
+    for r, p in zip(running, prompts):
+        assert r.output_token_ids == _stream(p, 12)
+        assert r.finish_reason == "length"
+    assert fine.output_token_ids == _stream([90, 91], 4)
+    st = eng.stats
+    assert (st["prefill_calls_split"], st["prefill_calls_failed"]) == (0, 1)
+    assert len(ex.of("refused")) == 1
+    lines = [r.getMessage() for r in engine_log.records
+             if bad.request_id in r.getMessage()]
+    assert len(lines) == 1 and "Used 18.28G of 15.75G" in lines[0]
+    assert "x %d tokens" % ex.of("refused")[0][1] in lines[0]
+    assert not ex.unfetched
+    free = eng.block_manager.num_free
+    if mode == "prefix_cache":
+        free += eng.prefix_cache.num_reclaimable
+        assert all(e.refcount == 0
+                   for e in eng.prefix_cache._by_block.values())
+    assert free == eng.cfg.num_blocks - 1

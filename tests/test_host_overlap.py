@@ -366,6 +366,108 @@ def test_clean_decode_step_issues_zero_uploads(tiny_params):
     assert n >= 7
 
 
+# ----------------------------------------------------------------------
+# Serving: the loop one round ahead of the host serves the same streams
+# ----------------------------------------------------------------------
+
+def _streams(eng, prompts, sps):
+    reqs = [eng.submit(p, sp) for p, sp in zip(prompts, sps)]
+    while eng.has_work:
+        eng.step()
+    return [(r.output_token_ids, r.output_logprobs, r.finish_reason)
+            for r in reqs]
+
+
+MIXED = [[1, 2, 3, 4, 5], [6, 7, 8], [9, 10, 11, 12], [13, 14],
+         [15, 16, 17, 18, 19, 20, 21], [22], [23, 24, 25]]
+LENGTHS = [14, 5, 9, 17, 3, 11, 8]
+
+
+def _reference_logprobs(params, prompt, tokens):
+    """The float32 plain reference (``benchmark/lib/reference.py``): log-probs
+    of ``tokens`` after ``prompt``, a full forward, no cache, no batch."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.lib import reference
+
+    sizes = {"num_layers": CFG.num_layers, "num_heads": CFG.num_heads,
+             "num_kv_heads": CFG.num_kv_heads,
+             "head_dim": CFG.hidden_size // CFG.num_heads,
+             "rms_norm_eps": CFG.rms_norm_eps, "rope_theta": CFG.rope_theta,
+             "sliding_window": None, "tie_embeddings": False}
+    ids = jnp.asarray(prompt + tokens)
+    lp = jax.nn.log_softmax(reference.forward(params, sizes, ids), -1)
+    return np.asarray(lp[len(prompt) - 1:len(prompt) - 1 + len(tokens)])
+
+
+@pytest.mark.parametrize("kind", ["greedy", "seeded"])
+def test_running_ahead_serves_what_fetching_first_serves(
+        tiny_params, fetch_first_engine, kind):
+    """Requests of mixed lengths through three slots, so that rounds hold
+    retirements, admissions into slots just freed and block growth: the
+    token and log-prob streams of the loop that runs ahead equal, to the
+    bit, those of the same engine fetching every round before it plans the
+    next; and some of them end on a stop token nobody could foresee, whose
+    row in the round behind is thrown away."""
+    def params(stop=()):
+        return [SamplingParams(
+            temperature=0.0 if kind == "greedy" else 0.9, max_tokens=n,
+            seed=None if kind == "greedy" else 100 + i,
+            stop_token_ids=tuple(stop)) for i, n in enumerate(LENGTHS)]
+
+    cfg = EngineConfig(max_seqs=3, block_size=4, num_blocks=64,
+                       max_model_len=64, cache_dtype="float32",
+                       eos_token_id=-1)
+    plain = _streams(fetch_first_engine(CFG, tiny_params, cfg), MIXED,
+                     params())
+    # a stop token that some stream reaches mid-answer: an end nobody foresees
+    stop = [plain[3][0][6], plain[0][0][4]]
+    for fetch_first in (True, False):
+        eng = (fetch_first_engine if fetch_first else InferenceEngine)(
+            CFG, tiny_params, cfg)
+        got = _streams(eng, MIXED, params(stop))
+        if fetch_first:
+            want = got
+            assert eng.stats["decode_rounds_launched_ahead"] == 0
+            continue
+        assert got == want
+        st = eng.stats
+        assert st["decode_rows_discarded"] >= 2
+        assert st["decode_rounds_launched_ahead"] > 0.8 * st["decode_steps"]
+    assert {r[2] for r in want} == {"stop", "length"}
+    if kind == "greedy":
+        for prompt, (tokens, logprobs, _) in zip(MIXED, want):
+            rows = _reference_logprobs(tiny_params, prompt, tokens)
+            picked = rows[np.arange(len(tokens)), tokens]
+            np.testing.assert_allclose(logprobs, picked, atol=2e-4)
+            assert (rows.max(-1) - picked <= 2e-4).all()
+
+
+def test_a_prefix_hit_on_a_tail_block_a_discarded_row_wrote(tiny_params):
+    """Prefix cache on: a sequence ends on a stop token with its row riding
+    in the round behind, which writes one position past the last kept token
+    into the sequence's tail block. The blocks registered at its release are
+    the whole ones before that position; the next request with the same
+    prompt and answer so far hits them and serves what the reference says."""
+    kw = dict(block_size=4, max_model_len=64, enable_prefix_caching=True)
+    sp = SamplingParams(temperature=0.0, max_tokens=12)
+    prompt = [3, 1, 4, 1, 5, 9, 2, 6]
+    [(tokens, _, _)] = _streams(_engine(tiny_params, **kw), [prompt], [sp])
+    eng = _engine(tiny_params, **kw)
+    stop = tokens[7]   # prompt 8 + 8 kept = 16: the discarded write opens block 5
+    first = _streams(eng, [prompt], [SamplingParams(
+        temperature=0.0, max_tokens=12, stop_token_ids=(stop,))])
+    assert first[0][0] == tokens[:8] and first[0][2] == "stop"
+    assert eng.stats["decode_rows_discarded"] == 1
+    assert eng.prefix_cache.num_cached_blocks == 3  # positions 0..11 of 0..14
+    again = prompt + tokens[:8]
+    got = _streams(eng, [again], [sp])
+    assert eng.stats["prefix_cached_tokens"] >= 12
+    want = _uncached_greedy(tiny_params, [again], 12)
+    assert (got[0][0], got[0][2]) == want[0]
+
+
 def test_decode_state_upload_counters_exposed(tiny_params):
     """The counters ride the engine stats dict (the /metrics scalar
     source), present before the first decode round."""

@@ -205,13 +205,21 @@ def test_engine_spans_nest_as_documented(tiny_params, tracer, speculative):
     group = next(e for e in events if e["name"] == "engine/prefill_group")
     assert group["args"]["rows"] == 2 and group["args"]["bucket"] >= 8
     assert group["args"]["prompt_tokens"] == len(CYCLIC) + 5
-    # every round: prep, launch, then (after admission) wait, emit
+    # every round: prep, launch, wait, emit. A speculative round is fetched
+    # in the step that launched it (after admission); a plain round a step
+    # later, after the round behind it has been prepared and launched.
     order = [e["name"] for e in sorted(events, key=lambda e: e["ts"])
              if e["name"] in ("engine/decode_prep", "engine/decode_launch",
                               "engine/decode_wait", "engine/decode_emit")]
-    assert order[:4] == ["engine/decode_prep", "engine/decode_launch",
-                         "engine/decode_wait", "engine/decode_emit"]
-    assert len(order) % 4 == 0
+    round_ = ["engine/decode_prep", "engine/decode_launch",
+              "engine/decode_wait", "engine/decode_emit"]
+    assert order[:6] == (round_[:2] * 2 + round_[2:]
+                         if speculative == "none" else round_ + round_[:2])
+    # (a prep that finds nothing to plan behind the round in flight, every
+    # row of which ends by length, launches nothing)
+    n = order.count("engine/decode_launch")
+    assert [order.count(name) for name in round_[2:]] == [n, n]
+    assert order.count("engine/decode_prep") >= n
 
 
 def test_server_spans_cover_the_stepper_loop(tiny_params, tracer):

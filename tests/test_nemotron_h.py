@@ -360,6 +360,38 @@ def test_a_slot_is_reused_from_a_zero_state(tiny):
     _hold_to_reference(tiny, prompts, results)
 
 
+def test_a_round_launched_ahead_leaves_the_recurrent_state_as_it_should_be(
+        tiny, fetch_first_engine):
+    """The loop a round ahead of the host, over per-slot recurrent state: a
+    request that ends on a stop token nobody foresaw has a row in the round
+    behind, which advances the dead slot's state once more; the next request
+    in that slot starts from zero all the same, and every stream equals, to
+    the bit, that of the engine fetching every round first, and the
+    reference's."""
+    prompts = _prompts([20, 9, 31, 14, 26], seed=6)
+    cfg = EngineConfig(max_seqs=2, block_size=8, num_blocks=64,
+                       max_model_len=128, cache_dtype="float32")
+
+    def run(cls, stop=()):
+        eng = cls(tiny["cfg"], tiny["params"], cfg)
+        results = eng.generate(prompts, SamplingParams(
+            max_tokens=7, temperature=0.0, stop_token_ids=tuple(stop)))
+        return eng, results
+
+    _, plain = run(fetch_first_engine)
+    stop = [plain[0].output_token_ids[3], plain[2].output_token_ids[4]]
+    _, want = run(fetch_first_engine, stop)
+    eng, got = run(InferenceEngine, stop)
+    assert [(r.output_token_ids, r.output_logprobs, r.finish_reason)
+            for r in got] == \
+        [(r.output_token_ids, r.output_logprobs, r.finish_reason)
+         for r in want]
+    assert {r.finish_reason for r in got} == {"stop", "length"}
+    assert eng.stats["decode_rows_discarded"] >= 2
+    assert eng.stats["recurrent_state_resets"] == len(prompts)
+    _hold_to_reference(tiny, prompts, got)
+
+
 def test_memory_ledger_names_the_recurrent_pool(tiny):
     eng = _engine(tiny)
     owners = eng.memledger.snapshot()["owners"]

@@ -517,3 +517,160 @@ def test_stepper_fault_aborts_cleanly():
         assert sum(1 for e in events if e[0] == "token") == 3
     finally:
         aeng.shutdown()
+
+
+# -- the engine's round in flight, at a stop and at a fault --------------------
+
+def _tiny_engine(**over):
+    model = LlamaForCausalLM(CFG, None)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    kw = dict(max_seqs=4, block_size=8, num_blocks=64, max_model_len=96,
+              cache_dtype="float32", eos_token_id=-1)
+    kw.update(over)
+    return InferenceEngine(CFG, params, EngineConfig(**kw))
+
+
+def test_a_stop_with_a_round_in_flight_settles_it_and_ends_every_stream():
+    """``AsyncEngine.shutdown()`` while requests decode: between two steps a
+    round is in flight, so the stepper's way out (one ``abort_all``) waits
+    for it and throws it away; every open stream gets its terminal frame,
+    the thread ends, and no request holds a token that was not emitted to
+    it."""
+    from dlti_tpu.serving.server import AsyncEngine
+
+    eng = _tiny_engine()
+    in_flight_at_stop = []
+    real_abort = eng.abort_all
+
+    def abort_all(reason="abort"):
+        in_flight_at_stop.append((reason, eng._inflight is not None))
+        return real_abort(reason=reason)
+    eng.abort_all = abort_all
+    aeng = AsyncEngine(eng)
+    streams = [aeng.submit([3 + i, 1, 4, 1, 5], SamplingParams(
+        max_tokens=80, temperature=0.9, seed=i)) for i in range(3)]
+    try:
+        for _req, q in streams:
+            assert [q.get(timeout=120)[0] for _ in range(3)] == ["token"] * 3
+    finally:
+        aeng.shutdown()
+    assert not aeng._thread.is_alive()
+    assert in_flight_at_stop == [("shutdown", True)]
+    assert eng._inflight is None and all(s.free for s in eng.slots)
+    for req, q in streams:
+        events = []
+        while not q.empty():
+            events.append(q.get_nowait())
+        assert events[-1] == ("error", "server shutting down")
+        assert [e[0] for e in events[:-1]] == ["token"] * (len(events) - 1)
+        assert 3 + len(events) - 1 == len(req.output_token_ids)
+
+
+def test_a_step_that_faults_with_a_round_in_flight_ends_in_one_clean_abort():
+    """The numeric guard judges a round when it is fetched, one launch later:
+    the fault drops the round behind it too, the server's recovery is one
+    ``abort_all`` that finds nothing in flight, nothing of the dead round
+    was streamed, and the engine serves on."""
+    from dlti_tpu.serving.engine import NumericFault
+    from dlti_tpu.serving.server import AsyncEngine
+
+    eng = _tiny_engine()
+    real_fetch = eng.executor.fetch
+    rounds = {"fetched": 0, "poison_at": 4}
+
+    def fetch(arrays):
+        host = real_fetch(arrays)
+        if host[0].shape == (eng.cfg.max_seqs,):      # a decode round's
+            rounds["fetched"] += 1
+            if rounds["fetched"] == rounds["poison_at"]:
+                host[1] = host[1] * float("nan")
+        return host
+    eng.executor.fetch = fetch
+    aborts = []
+    real_abort = eng.abort_all
+
+    def abort_all(reason="abort"):
+        aborts.append((reason, eng._inflight is not None))
+        return real_abort(reason=reason)
+    eng.abort_all = abort_all
+    aeng = AsyncEngine(eng)
+    try:
+        req, q = aeng.submit([3, 1, 4, 1, 5], SamplingParams(
+            max_tokens=40, temperature=0.0))
+        events = []
+        while not events or events[-1][0] == "token":
+            events.append(q.get(timeout=120))
+        assert events[-1][0] == "error" and "NumericFault" in events[-1][1]
+        # the prefill's token and three rounds' were streamed, the fourth
+        # round's was not, and the fifth (launched behind it) went with it
+        assert len(events) - 1 == len(req.output_token_ids) == 4
+        assert aborts == [("error", False)]
+        assert eng._inflight is None and not eng.has_work
+        assert eng.stats["numeric_faults"] == 1
+        rounds["poison_at"] = -1
+        _, q2 = aeng.submit([2, 7, 1], SamplingParams(temperature=0.0,
+                                                      max_tokens=3))
+        again = [q2.get(timeout=60) for _ in range(4)]
+        assert [e[0] for e in again] == ["token"] * 3 + ["done"]
+    finally:
+        aeng.shutdown()
+    assert isinstance(NumericFault("x"), RuntimeError)
+
+
+def test_sigterm_mid_stream_exits_zero_and_ends_the_stream(tmp_path):
+    """``scripts/serve.py`` under SIGTERM while a stream is open and a round
+    is in flight: the process exits 0 (what the benchmark's harness waits
+    for, 120 s at most) and the stream ends instead of hanging."""
+    import os
+    import signal
+    import socket
+    import sys
+    import time
+
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        port = s.getsockname()[1]
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    log = open(tmp_path / "serve.log", "w")
+    proc = subprocess.Popen(
+        [sys.executable, "scripts/serve.py", "--random-init", "llama_tiny",
+         "--tokenizer", "byte", "--host", "127.0.0.1", "--port", str(port),
+         "--max-seqs", "4", "--num-blocks", "64", "--block-size", "8",
+         "--max-model-len", "128"],
+        cwd=root, stdout=log, stderr=subprocess.STDOUT)
+    try:
+        deadline = time.time() + 120
+        while True:
+            try:
+                if _get("127.0.0.1", port, "/health")[0] == 200:
+                    break
+            except OSError:
+                pass
+            assert proc.poll() is None and time.time() < deadline, \
+                open(tmp_path / "serve.log").read()[-2000:]
+            time.sleep(0.25)
+        conn = http.client.HTTPConnection("127.0.0.1", port, timeout=60)
+        conn.request("POST", "/v1/completions", json.dumps({
+            "prompt": "hello there", "max_tokens": 100, "stream": True,
+            "temperature": 1.0, "seed": 3}),
+            {"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        assert resp.status == 200
+        frames = []
+        while len(frames) < 3:
+            line = resp.readline()
+            if line.startswith(b"data: "):
+                frames.append(line)
+        proc.send_signal(signal.SIGTERM)
+        rest = resp.read()      # returns: the stream was ended, not left open
+        assert proc.wait(timeout=60) == 0, \
+            open(tmp_path / "serve.log").read()[-2000:]
+        tail = [l for l in rest.split(b"\n") if l.startswith(b"data: ")]
+        # whatever was written last is a whole frame: an answer's chunk, the
+        # shutdown's error frame, or the end marker
+        assert all(l == b"data: [DONE]" or json.loads(l[6:]) for l in tail)
+    finally:
+        if proc.poll() is None:
+            proc.kill()
+        log.close()
