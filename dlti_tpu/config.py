@@ -152,8 +152,36 @@ class ModelConfig:
     v_head_dim: int = 0
     rope_interleave: bool = False  # rotary pairs (2i, 2i+1), not halves
     first_k_dense: int = 0
+    # A query latent: q = RMSNorm(x W_qa) W_qb through ``q_lora_rank``
+    # values (0: one query projection).
+    q_lora_rank: int = 0
+    # YaRN (``ops.rope.yarn_inv_freq``): the public config's ``rope_scaling``
+    # object (factor, original_max_position_embeddings, beta_fast,
+    # beta_slow, mscale, mscale_all_dim), kept as sorted (key, value) pairs
+    # so that the configuration stays hashable. None: plain RoPE.
+    rope_scaling: Optional[tuple] = None
+    # Hyper-connected residual streams (mHC; models.hyper): ``hc_mult`` > 0
+    # widens the residual path to that many streams, mixed round every
+    # sublayer by maps computed from the token, one of them projected onto
+    # doubly stochastic matrices by ``hc_sinkhorn_iters`` Sinkhorn rounds.
+    # 0: the plain residual x + F(norm(x)).
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    mhc_h_res_clamp_min: float = -30.0
+    mhc_h_res_clamp_max: float = 30.0
+    # Multi-token-prediction modules the published model carries after its
+    # layers. No model here builds one and the engine refuses to be asked
+    # for one (serving.executor.refuse_unsupported).
+    num_nextn_predict_layers: int = 0
 
     def __post_init__(self):
+        if self.rope_scaling is not None:
+            pairs = (self.rope_scaling.items()
+                     if isinstance(self.rope_scaling, dict)
+                     else self.rope_scaling)
+            object.__setattr__(self, "rope_scaling", tuple(
+                sorted((str(k), v) for k, v in pairs)))
         if self.layer_pattern and (
                 len(self.layer_pattern) != self.num_layers
                 or set(self.layer_pattern) - set("ME*")):
@@ -200,6 +228,11 @@ class ModelConfig:
         return self.kv_lora_rank + self.qk_rope_head_dim \
             if self.kv_lora_rank else 0
 
+    @property
+    def yarn(self) -> Optional[dict]:
+        """``rope_scaling`` as the object the public config states."""
+        return dict(self.rope_scaling) if self.rope_scaling else None
+
     def _count_params(self, include_lm_head: bool, active_only: bool) -> int:
         h, m, v = self.hidden_size, self.intermediate_size, self.vocab_size
         hd = self.resolved_head_dim
@@ -214,9 +247,15 @@ class ModelConfig:
             # held experts with their router, bias and shared expert.
             r, nope, rope_d, vd = (self.kv_lora_rank, self.qk_nope_head_dim,
                                    self.qk_rope_head_dim, self.v_head_dim)
-            attn = (h * self.num_heads * (nope + rope_d) + h * (r + rope_d)
+            qk = self.num_heads * (nope + rope_d)
+            query = (h * self.q_lora_rank + self.q_lora_rank
+                     + self.q_lora_rank * qk) if self.q_lora_rank else h * qk
+            attn = (query + h * (r + rope_d)
                     + r + r * self.num_heads * (nope + vd)
                     + self.num_heads * vd * h)
+            # the stream maps of both sublayers (models.hyper.HyperMaps)
+            n = self.hc_mult
+            attn += 2 * ((n * h + 1) * (n * n + 2 * n) + 3) if n else 0
             f = self.moe_intermediate_size
             mats = 3 if self.mlp_activation == "silu" else 2  # gated or not
             n_routed = (self.num_experts_per_tok * self.moe_held

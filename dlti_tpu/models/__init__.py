@@ -1,6 +1,7 @@
 """Model zoo: Llama-family transformer in Flax + LoRA grafting, the
 patterned ``nemotron_h`` family (Mamba-2, routed experts, attention) and
-the latent-attention family (``deepseek_v3``: MLA, held gated experts)."""
+the latent-attention family (``deepseek_v3``: MLA, held gated experts;
+``xing4_0``: the same round hyper-connected residual streams)."""
 
 from dlti_tpu.models.llama import LlamaForCausalLM, LlamaModel  # noqa: F401
 
@@ -10,7 +11,13 @@ def build_model(cfg, lora=None, mesh=None):
     the model class (the trainer, the engine, ``serve.py --random-init``,
     the fleet worker and the benchmark's check all come through here). A
     configuration with a ``kv_lora_rank`` is the latent-attention family;
-    one with neither that nor a ``layer_pattern`` is the Llama family."""
+    one with neither that nor a ``layer_pattern`` is the Llama family.
+    Hyper-connected residual streams (``hc_mult``, ``models.hyper``) are
+    wired into the latent-attention family alone."""
+    if cfg.hc_mult and not cfg.kv_lora_rank:
+        raise NotImplementedError(
+            f"hc_mult {cfg.hc_mult}: only the latent-attention family's "
+            f"blocks go through stream maps (models.latent.LatentBlock)")
     if cfg.kv_lora_rank:
         from dlti_tpu.models.latent import LatentForCausalLM
 

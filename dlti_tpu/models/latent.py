@@ -1,18 +1,21 @@
-"""The latent-attention family (``model_type`` deepseek_v3): MLA over a
-latent cache, a leading dense MLP, then held routed experts.
+"""The latent-attention family (``model_type`` deepseek_v3, xing4_0): MLA
+over a latent cache, leading dense MLPs, then held routed experts.
 
-Every layer is ``x = x + Attn(RMSNorm(x)); x = x + MLP(RMSNorm(x))``. The
+Every layer is ``x = x + Attn(RMSNorm(x)); x = x + MLP(RMSNorm(x))``, or,
+with ``hc_mult`` n > 0, the same two sublayers round n residual streams
+mixed by maps computed from the token (``models.hyper``). The
 attention keeps, a token and layer, ONE row ``[c ; r]``: the normed latent
 ``c`` (``kv_lora_rank``) and one rotated key ``r`` (``qk_rope_head_dim``)
 that all heads share (x the normed input, h a head):
 
     q_h     = x W_q,h = [q_nope,h ; q_rope,h],   q_rope,h <- RoPE(q_rope,h)
+              (with ``q_lora_rank``: q_h = RMSNorm(x W_qa) W_qb,h)
     [c ; r] = x W_kva,   c <- RMSNorm(c),   r <- RoPE(r)
     expanded:  [k_nope,h ; v_h] = c W_kvb,h
-               s_h = (q_nope,h . k_nope,h + q_rope,h . r) / sqrt(d_qk)
+               s_h = (q_nope,h . k_nope,h + q_rope,h . r) * scale
                o_h = softmax(s_h) v_h
     absorbed:  q~_h = q_nope,h W_kvb,h[:, :nope]^T
-               s_h = (q~_h . c + q_rope,h . r) / sqrt(d_qk)
+               s_h = (q~_h . c + q_rope,h . r) * scale
                o_h = (softmax(s_h) c) W_kvb,h[:, nope:]
     y = [o_1 .. o_H] W_o
 
@@ -21,7 +24,9 @@ re-associated) and :class:`LatentAttention` holds both: expanded where many
 queries share the up-projected keys (training, a prefill call), absorbed
 where few do (a decode step through ``ops.pallas.latent_attention``, whose
 XLA gather form is the CPU fallback; the few tokens a prefix hit leaves).
-RoPE turns the pairs ``(2i, 2i + 1)`` (``rope_interleave``).
+RoPE turns the pairs ``(2i, 2i + 1)`` (``rope_interleave``); ``scale`` is
+``1 / sqrt(d_qk)``, under YaRN (``rope_scaling``) times
+``ops.rope.yarn_softmax_factor``, the same in all three paths.
 
 The MLP is ``LlamaMLP`` in the first ``first_k_dense`` layers and
 ``HeldExpertsMLP`` (gated silu experts, several shared experts as one MLP
@@ -45,12 +50,14 @@ from dlti_tpu.config import LoRAConfig, ModelConfig
 from dlti_tpu.models.llama import (
     LlamaMLP, RMSNorm, _dtype, head_matrix_from_leaves,
 )
+from dlti_tpu.models.hyper import MHC_COUNTERS, HyperMaps, mix_in, mix_out
 from dlti_tpu.models.lora import LoRADense
 from dlti_tpu.models.moe import MOE_COUNTERS, HeldExpertsMLP
 from dlti_tpu.ops.attention import reference_attention, resolve_paged_decode
 from dlti_tpu.ops.kv_cache import latent_gather, latent_update, slot_mapping
 from dlti_tpu.ops.rope import (
     apply_rope, assert_rope_table_covers, rope_frequencies,
+    yarn_softmax_factor,
 )
 
 # Most padded tokens (rows x bucket) the serving engine gives one prefill
@@ -74,6 +81,8 @@ ABSORB_MAX_QUERIES = 128
 # (PERF.md section 6, PR 38) pays for the keys it can see.
 KEY_BLOCK = 512
 NEG_INF = -1e30
+# Counters that combine across layers by the largest, not the sum.
+LARGEST_OF = ("moe_expert_load_max", "mhc_sinkhorn_residual_e6")
 
 
 class LatentAttention(nn.Module):
@@ -87,14 +96,20 @@ class LatentAttention(nn.Module):
         H, r = cfg.num_heads, cfg.kv_lora_rank
         nope, rope_d, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                             cfg.v_head_dim)
-        scale = (nope + rope_d) ** -0.5
+        yarn_factor = yarn_softmax_factor(cfg.yarn)
+        scale = (nope + rope_d) ** -0.5 * yarn_factor
 
         def proj(name, features):
             return LoRADense(features=features, use_bias=False, dtype=dtype,
                              param_dtype=pdtype, name=name, lora_r=0)
 
-        q = proj("q_proj", H * (nope + rope_d))(x).reshape(
-            b, s, H, nope + rope_d)
+        if cfg.q_lora_rank:
+            q = proj("q_b_proj", H * (nope + rope_d))(
+                RMSNorm(cfg.rms_norm_eps, name="q_a_norm")(
+                    proj("q_a_proj", cfg.q_lora_rank)(x)))
+        else:
+            q = proj("q_proj", H * (nope + rope_d))(x)
+        q = q.reshape(b, s, H, nope + rope_d)
         q_nope = q[..., :nope]
         q_rope = apply_rope(q[..., nope:], cos, sin, positions,
                             interleaved=cfg.rope_interleave)
@@ -210,41 +225,73 @@ class LatentAttention(nn.Module):
             k_nope, v = expand(c)
             k = jnp.concatenate([k_nope, jnp.broadcast_to(
                 k_rope[:, :, None], (b, s, H, rope_d))], axis=-1)
-            out = reference_attention(
-                jnp.concatenate([q_nope, q_rope], axis=-1), k, v,
-                causal=True, q_positions=positions)
+            q = jnp.concatenate([q_nope, q_rope], axis=-1)
+            if yarn_factor != 1.0:      # reference_attention scales by d^-0.5
+                q = q * yarn_factor
+            out = reference_attention(q, k, v, causal=True,
+                                      q_positions=positions)
         out = proj("o_proj", cfg.hidden_size)(
             out.astype(dtype).reshape(b, s, H * vd))
         return out, new_cache
 
 
 class LatentBlock(nn.Module):
+    """One layer. ``cfg.hc_mult`` 0: ``x`` (b, s, h) through the plain
+    residual. ``hc_mult`` n >= 1: ``x`` is the n streams (n, b, s, h) and
+    both sublayers go through their stream maps (``models.hyper``). Returns
+    ``(x, new cache, expert counters or None, map counters or None)``."""
+
     cfg: ModelConfig
     dense: bool
 
     @nn.compact
     def __call__(self, x, cos, sin, positions, cache=None, token_mask=None):
         cfg = self.cfg
-        attn_out, new_cache = LatentAttention(cfg, name="attn")(
-                RMSNorm(cfg.rms_norm_eps, name="input_norm")(x),
+        new_cache, moe = None, None
+
+        def attention(u):
+            nonlocal new_cache
+            out, new_cache = LatentAttention(cfg, name="attn")(
+                RMSNorm(cfg.rms_norm_eps, name="input_norm")(u),
                 cos, sin, positions, cache)
-        x = x + attn_out
-        h = RMSNorm(cfg.rms_norm_eps, name="post_attn_norm")(x)
-        if self.dense:
-            return x + LlamaMLP(cfg, None, name="mlp")(h), new_cache, None
-        out, counters = HeldExpertsMLP(cfg, name="mlp")(h, token_mask)
-        return x + out, new_cache, counters
+            return out
+
+        def mlp(u):
+            nonlocal moe
+            h = RMSNorm(cfg.rms_norm_eps, name="post_attn_norm")(u)
+            if self.dense:
+                return LlamaMLP(cfg, None, name="mlp")(h)
+            out, moe = HeldExpertsMLP(cfg, name="mlp")(h, token_mask)
+            return out
+
+        if not cfg.hc_mult:
+            x = x + attention(x)
+            return x + mlp(x), new_cache, moe, None
+        mask = jnp.ones(x.shape[1:3], bool) if token_mask is None \
+            else token_mask
+        counted = []
+        for name, sublayer in (("attn_hc", attention), ("mlp_hc", mlp)):
+            h_pre, h_post, h_res, n = HyperMaps(cfg, name=name)(x, mask)
+            x = mix_out(x, sublayer(mix_in(x, h_pre)), h_post, h_res)
+            counted.append(n)
+        return x, new_cache, moe, jnp.stack(
+            [jnp.maximum(counted[0][0], counted[1][0]),
+             counted[0][1] + counted[1][1]])
 
 
 class LatentForCausalLM(nn.Module):
     """Body + untied head. Returns float32 logits and the new cache; with
     ``return_counters`` also ``{name: int32 scalar}`` for ``counter_names``,
-    what this pass counted (the expert layers' counters)."""
+    what this pass counted (the expert layers' counters and, with
+    ``hc_mult``, the stream maps').
+
+    With ``hc_mult`` n the residual path is n streams (``models.hyper``):
+    the embedding row repeated n times goes in, every block mixes the
+    streams round its two sublayers, and their sum goes to ``final_norm``."""
 
     cfg: ModelConfig
     lora: Optional[LoRAConfig] = None
     mesh: Optional[Any] = None
-    counter_names = MOE_COUNTERS
     prefill_call_tokens = PREFILL_CALL_TOKENS
     # A prefill call takes each row's WHOLE block table, not the narrowest
     # power of two that holds the row: gathering a cached context costs 1,280
@@ -252,6 +299,10 @@ class LatentForCausalLM(nn.Module):
     # the programs a prefix hit's few tokens can meet by the ladder of
     # widths, which no warm-up reaches without replaying the hit.
     prefill_whole_tables = True
+
+    @property
+    def counter_names(self) -> tuple:
+        return MOE_COUNTERS + (MHC_COUNTERS if self.cfg.hc_mult else ())
 
     @nn.compact
     def __call__(self, input_ids, positions=None, segment_ids=None,
@@ -273,6 +324,8 @@ class LatentForCausalLM(nn.Module):
         embed = self.param("embed_tokens", nn.initializers.normal(1.0),
                            (cfg.vocab_size, cfg.hidden_size), pdtype)
         x = jnp.take(embed, input_ids, axis=0).astype(dtype)
+        if cfg.hc_mult:
+            x = jnp.broadcast_to(x, (cfg.hc_mult, *x.shape))
         if positions is None:
             positions = jnp.broadcast_to(
                 jnp.arange(s, dtype=jnp.int32)[None, :], (b, s))
@@ -284,7 +337,7 @@ class LatentForCausalLM(nn.Module):
             table_len = cache[0]["block_tables"].shape[1] \
                 * cache[0]["latent"].shape[1]
         cos, sin = rope_frequencies(cfg.qk_rope_head_dim, table_len,
-                                    cfg.rope_theta)
+                                    cfg.rope_theta, cfg.yarn)
         routed = positions >= 0
         if cache is not None:
             # A decode row of a slot that is free or still prefilling carries
@@ -294,18 +347,21 @@ class LatentForCausalLM(nn.Module):
         if token_mask is not None:
             routed = routed & token_mask.astype(bool)
 
-        counters = dict.fromkeys(MOE_COUNTERS, jnp.int32(0))
+        counters = dict.fromkeys(self.counter_names, jnp.int32(0))
         new_caches = [] if cache is not None else None
         for i in range(cfg.num_layers):
-            x, layer_cache, moe = LatentBlock(
+            x, layer_cache, moe, maps = LatentBlock(
                 cfg, i < cfg.first_k_dense, name=f"layers_{i}")(
                     x, cos, sin, positions,
                     cache[i] if cache is not None else None, routed)
             if cache is not None:
                 new_caches.append(layer_cache)
-            for name, n in zip(MOE_COUNTERS, () if moe is None else moe):
-                counters[name] = jnp.maximum(counters[name], n) \
-                    if name == "moe_expert_load_max" else counters[name] + n
+            for names, counted in ((MOE_COUNTERS, moe), (MHC_COUNTERS, maps)):
+                for name, n in zip(names, () if counted is None else counted):
+                    counters[name] = jnp.maximum(counters[name], n) \
+                        if name in LARGEST_OF else counters[name] + n
+        if cfg.hc_mult:
+            x = jnp.sum(x.astype(jnp.float32), axis=0).astype(dtype)
         x = RMSNorm(cfg.rms_norm_eps, name="final_norm")(x)
 
         def result(out):

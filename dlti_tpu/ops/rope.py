@@ -11,6 +11,9 @@ where it lies.
 
 from __future__ import annotations
 
+import math
+from typing import Optional
+
 import jax.numpy as jnp
 
 
@@ -35,12 +38,70 @@ def assert_rope_table_covers(table_len: int, needed_len: int,
             "rotary angles — size the table to >= max position + 1")
 
 
-def rope_frequencies(head_dim: int, max_seq_len: int, theta: float = 10000.0) -> tuple:
-    """Precompute cos/sin tables of shape ``(max_seq_len, head_dim // 2)``."""
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def yarn_correction_range(head_dim: int, theta: float, scaling: dict) -> tuple:
+    """``(low, high)``: the rotary pairs below ``low`` turn more than
+    ``beta_fast`` times over the original context and keep their frequency;
+    those above ``high`` turn less than ``beta_slow`` times and are
+    interpolated whole; between them the two are blended."""
+    span = scaling["original_max_position_embeddings"]
+
+    def pair_of(turns):
+        return head_dim * math.log(span / (2 * math.pi * turns)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(pair_of(scaling.get("beta_fast", 32))), 0)
+    high = min(math.ceil(pair_of(scaling.get("beta_slow", 1))), head_dim - 1)
+    return low, high
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor ``0.1 m ln(factor) + 1`` (1 at factor <= 1)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def _inv_freq(head_dim: int, theta: float) -> jnp.ndarray:
+    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+
+
+def yarn_inv_freq(head_dim: int, theta: float, scaling: dict) -> jnp.ndarray:
+    """YaRN's blended frequencies as the deepseek_v3 family writes them:
+    pair ``i`` keeps ``theta^(-2i/d)`` by the share ``m_i`` and takes it
+    divided by ``factor`` by the rest, ``m_i`` falling from 1 to 0 between
+    :func:`yarn_correction_range`'s two pairs."""
+    inv_freq = _inv_freq(head_dim, theta)
+    low, high = yarn_correction_range(head_dim, theta, scaling)
+    ramp = (jnp.arange(head_dim // 2, dtype=jnp.float32) - low) \
+        / max(high - low, 0.001)
+    keep = 1.0 - jnp.clip(ramp, 0.0, 1.0)
+    return inv_freq / scaling["factor"] * (1.0 - keep) + inv_freq * keep
+
+
+def yarn_softmax_factor(scaling: Optional[dict]) -> float:
+    """What the softmax scale is multiplied by under YaRN:
+    ``yarn_mscale(factor, mscale_all_dim)^2`` (1 with no scaling or no
+    ``mscale_all_dim``)."""
+    if not scaling or not scaling.get("mscale_all_dim"):
+        return 1.0
+    return yarn_mscale(scaling["factor"], scaling["mscale_all_dim"]) ** 2
+
+
+def rope_frequencies(head_dim: int, max_seq_len: int, theta: float = 10000.0,
+                     scaling: Optional[dict] = None) -> tuple:
+    """Precompute cos/sin tables of shape ``(max_seq_len, head_dim // 2)``.
+    ``scaling`` (a public config's ``rope_scaling`` of type yarn): blended
+    frequencies, and both tables times ``yarn_mscale(mscale) /
+    yarn_mscale(mscale_all_dim)``."""
+    inv_freq = yarn_inv_freq(head_dim, theta, scaling) if scaling \
+        else _inv_freq(head_dim, theta)
     t = jnp.arange(max_seq_len, dtype=jnp.float32)
     freqs = jnp.outer(t, inv_freq)  # (seq, head_dim//2)
-    return jnp.cos(freqs), jnp.sin(freqs)
+    cos, sin = jnp.cos(freqs), jnp.sin(freqs)
+    if scaling:
+        amplitude = yarn_mscale(scaling["factor"], scaling.get("mscale", 1)) \
+            / yarn_mscale(scaling["factor"], scaling.get("mscale_all_dim", 0))
+        if amplitude != 1.0:
+            cos, sin = cos * amplitude, sin * amplitude
+    return cos, sin
 
 
 def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,

@@ -461,6 +461,12 @@ class InferenceEngine:
                       # tokens start at (a prefix hit's tail, a later piece
                       # of a long prompt).
                       "prefill_context_tokens": 0,
+                      # (query, key) pairs the prefill calls' tokens
+                      # could see: a row of T tokens that start at
+                      # position p adds p T + T (T + 1) / 2. What
+                      # attention's products of a prefill are counted
+                      # from, whatever the model.
+                      "prefill_attention_pairs": 0,
                       # Tokens whose KV came back from a LOWER tier (host
                       # or disk) via a restore scatter instead of either
                       # an HBM hit or a re-prefill. Present (at 0) even
@@ -1334,6 +1340,9 @@ class InferenceEngine:
             self.stats["prefill_widest_call_tokens"], B * bucket)
         self.stats["prefill_tokens"] += sum(len(c[1]) for c in chunks)
         self.stats["prefill_context_tokens"] += sum(c[2] for c in chunks)
+        self.stats["prefill_attention_pairs"] += sum(
+            c[2] * len(c[1]) + len(c[1]) * (len(c[1]) + 1) // 2
+            for c in chunks)
         return sampled
 
     def _count(self, counters: np.ndarray, decode: bool) -> None:

@@ -57,7 +57,14 @@ def refuse_unsupported(model_cfg: ModelConfig, engine_cfg: "EngineConfig",
     """Refuse, at start-up and with one clear error each, every feature
     that takes a sequence's state to be its k/v blocks when the model has
     recurrent layers or keeps latents, and what the patterned and the
-    latent-attention families do not implement."""
+    latent-attention families do not implement. A model with stream maps
+    (``hc_mult``) is of the latent family and is named as such."""
+    if model_cfg.num_nextn_predict_layers > 0:
+        raise ValueError(
+            f"num_nextn_predict_layers {model_cfg.num_nextn_predict_layers}: "
+            f"no model here builds a multi-token-prediction module and the "
+            f"engine's speculative rounds draft by ngram alone; serve the "
+            f"model's own layers with num_nextn_predict_layers 0")
     if not (model_cfg.layer_pattern or model_cfg.latent_dim):
         return
     ec = engine_cfg
@@ -65,6 +72,9 @@ def refuse_unsupported(model_cfg: ModelConfig, engine_cfg: "EngineConfig",
             if model_cfg.layer_pattern else
             f"a model with latent attention (kv_lora_rank "
             f"{model_cfg.kv_lora_rank})")
+    if model_cfg.hc_mult:
+        what += (f" and {model_cfg.hc_mult} hyper-connected residual "
+                 f"streams (float32 stream maps round every sublayer)")
     if model_cfg.latent_dim:
         if ec.cache_dtype == "int8":
             raise ValueError(
