@@ -1,0 +1,198 @@
+"""A prefill program that may never return, tried without losing the call.
+
+nemotron3_nano_30b's 13-layer ``jit(prefill)`` alone on the chip (seeded
+weights of its own, the executor's program, two calls), one child process a
+variant, under a parent that never touches JAX: it reads the child's lines,
+kills the child's process group ``--wait`` seconds after ``compiled`` with
+no second ``DONE``, removes libtpu's lock file and goes on to the next
+variant (``PERF.md`` section 7 item 6: which of that family's programs never
+return, and what that was bisected to).
+
+    chiprun -- python3 benchmarks_dev/prefill_hang_drill.py \\
+        grouped+f1792:2x1024 masked+f1792:2x2048 masked:2x2048
+
+A variant is ``<words>:<rows>x<bucket>[:<layers>]``, the words joined by +:
+``masked`` (every expert layer on the mask) or ``grouped`` (every call of
+``GROUPED_MIN_TOKENS`` or more through the kernel, which takes a width of
+whole chunks alone); ``f<N>`` the routed experts N wide instead of 1,856;
+``nokernel`` the grouped layout and gathers with the kernel replaced by the
+identity on its rows; ``full`` no padding tokens (default: bucket - 8 real
+tokens a row); ``tiny`` the nemotron_h_tiny preset, for a try on the CPU.
+Prints ``RETURNED`` or ``DID NOT RETURN`` a variant; exit 0 either way.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import functools
+import json
+import os
+import queue
+import signal
+import subprocess
+import sys
+import threading
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+T0 = time.time()
+
+
+def say(*a):
+    print("[%6.1f]" % (time.time() - T0), *a, flush=True)
+
+
+def child(words: set, rows: int, bucket: int, layers: int) -> None:
+    sys.path[:0] = [ROOT, os.path.join(ROOT, "benchmark", "lib")]
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dlti_tpu.config import MODEL_PRESETS, ModelConfig
+    from dlti_tpu.models import build_model, moe
+    from dlti_tpu.ops.kv_cache import init_cache
+    from dlti_tpu.ops.pallas import grouped_experts as kernel
+    from dlti_tpu.serving.engine import EngineConfig
+    from dlti_tpu.serving.executor import EngineExecutor
+
+    say("imports", jax.devices()[0].device_kind)
+    if "tiny" in words:
+        cfg, blocks = MODEL_PRESETS["nemotron_h_tiny"], 256
+        moe.GROUPED_MIN_TOKENS, moe.GROUPED_TILE_ROWS = 16, 8
+        kernel.WIDTH_CHUNK = 8
+    else:
+        from chip_child import model_fields
+
+        with open(os.path.join(
+                ROOT, "benchmark/configs/nemotron3_nano_30b.json")) as fh:
+            fields = model_fields(json.load(fh))
+        for w in words:
+            if w[0] == "f" and w[1:].isdigit():
+                fields["moe_intermediate_size"] = int(w[1:])
+        fields["layer_pattern"] = fields["layer_pattern"][:layers]
+        fields["num_layers"] = len(fields["layer_pattern"])
+        cfg, blocks = dataclasses.replace(
+            ModelConfig(**fields), paged_attention_impl="kernel"), 4096
+    if "masked" in words:
+        moe.takes_grouped = lambda tokens, width: False
+    if "nokernel" in words:
+        kernel.grouped_experts = lambda x, *rest, **kw: x
+
+    model = build_model(cfg)
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+
+    @functools.partial(jax.jit, static_argnums=(1, 2, 3))
+    def leaf(key, shape, dtype, ones):
+        if ones:
+            return jnp.ones(shape, dtype)
+        scale = shape[-2] ** -0.5 if len(shape) > 1 else 0.02
+        return (jax.random.normal(key, shape, jnp.float32)
+                * scale).astype(dtype)
+
+    flat, tree = jax.tree_util.tree_flatten_with_path(shapes)
+    keys = jax.random.split(jax.random.PRNGKey(1), len(flat))
+    params = jax.tree_util.tree_unflatten(tree, [
+        leaf(k, v.shape, v.dtype, v.ndim == 1 and any(
+            w in jax.tree_util.keystr(p).lower() for w in ("norm", "scale")))
+        for k, (p, v) in zip(keys, flat)])
+    jax.block_until_ready(params)
+    say("params GB", round(sum(v.size * v.dtype.itemsize for v in
+                               jax.tree_util.tree_leaves(params)) / 1e9, 2))
+    cache = init_cache(cfg, blocks, 16, 32, jnp.bfloat16)
+    # The executor's prefill program without an engine round it.
+    ex = EngineExecutor.__new__(EngineExecutor)
+    ex.model, ex.counter_names = model, tuple(model.counter_names)
+    ex._recurrent, ex.adapter_pool, ex._row_extra = True, None, "state_slots"
+    ex.cfg = EngineConfig(max_seqs=32, block_size=16, num_blocks=blocks,
+                          max_model_len=8192)
+    real = bucket if "full" in words else bucket - 8
+    ids = np.random.default_rng(7).integers(
+        1, cfg.vocab_size - 1, (rows, bucket)).astype(np.int32)
+    at = np.arange(bucket, dtype=np.int32)[None, :]
+    positions = np.where(at < real, at, -1) * np.ones((rows, 1), np.int32)
+    width = bucket // 16
+    tables = 1 + np.arange(rows * width, dtype=np.int32).reshape(rows, width)
+    args = (jnp.asarray(ids), jnp.asarray(positions), jnp.asarray(tables),
+            jnp.full((rows,), real - 1, jnp.int32),
+            jnp.arange(rows, dtype=jnp.int32))
+    lowered = ex._build_prefill_fn(bucket).lower(params, cache, *args)
+    say("lowered")
+    program = lowered.compile()
+    say("compiled")
+    for i in range(2):
+        t = time.time()
+        out = program(params, cache, *args)
+        say("dispatched", i)
+        cache, last, counters = out
+        jax.block_until_ready(out)
+        say("DONE", i, "%.3f s" % (time.time() - t), "counters",
+            np.asarray(counters).tolist())
+
+
+def run_child(variant: str, wait: int, overall: int) -> bool:
+    say("=== child", variant)
+    p = subprocess.Popen(
+        [sys.executable, "-u", os.path.abspath(__file__), "--child", variant],
+        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
+        start_new_session=True)
+    lines: queue.Queue = queue.Queue()
+    threading.Thread(target=lambda: [lines.put(x) for x in p.stdout]
+                     + [lines.put(None)], daemon=True).start()
+    start, compiled, done, tail = time.time(), None, 0, []
+    while True:
+        try:
+            line = lines.get(timeout=1)
+        except queue.Empty:
+            line = ""
+        if line is None:
+            break
+        if line:
+            tail.append(line.rstrip())
+            if line.startswith("["):
+                print("   ", line.rstrip(), flush=True)
+            compiled = compiled or ("compiled" in line and time.time())
+            done += "DONE" in line
+        now = time.time()
+        if done < 2 and ((compiled and now - compiled > wait)
+                         or now - start > overall):
+            say("KILLING: no return %.0f s after the compile (%.0f s in all)"
+                % (now - (compiled or start), now - start))
+            os.killpg(p.pid, signal.SIGKILL)
+            break
+    p.wait()
+    time.sleep(3)
+    if done < 2:
+        print("    ... " + "\n    ... ".join(t[:300] for t in tail[-6:]),
+              flush=True)
+        try:  # a killed child's lock would refuse the next one the chip
+            os.remove("/tmp/libtpu_lockfile")
+        except OSError:
+            pass
+    say("=== result", variant,
+        "RETURNED" if done >= 2 else "DID NOT RETURN", "rc", p.returncode)
+    return done >= 2
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("variants", nargs="*", default=["grouped+f1792:2x1024"])
+    ap.add_argument("--child", default=None, help="run this one variant here")
+    ap.add_argument("--wait", type=int, default=60,
+                    help="seconds after the compile before a child is killed")
+    ap.add_argument("--overall", type=int, default=300)
+    args = ap.parse_args()
+    if args.child:
+        words, shape, *layers = args.child.split(":")
+        rows, bucket = map(int, shape.split("x"))
+        child(set(words.split("+")), rows, bucket,
+              int(layers[0]) if layers else 13)
+        return 0
+    say("SUMMARY", json.dumps({v: run_child(v, args.wait, args.overall)
+                               for v in args.variants}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

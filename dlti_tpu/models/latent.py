@@ -63,10 +63,11 @@ from dlti_tpu.ops.rope import (
 # Most padded tokens (rows x bucket) the serving engine gives one prefill
 # call of this family, a row at least: a longer prompt goes as several calls,
 # each attending over the latents the earlier ones wrote. What bounds a
-# call is the held-expert layer (every held expert over every token,
-# ``models.moe.TOKEN_BLOCK`` tokens at a time) and the float32 (heads,
-# queries, KEY_BLOCK) scores of the expanded form; the same limit nemotron_h
-# has, whose 2 x 2,048 program never returned on the v5e (PERF.md section 7).
+# call is the held-expert layer (a call's held assignments laid out by
+# expert, up to two rows of activations an assignment:
+# ``models.moe.routed_grouped``) and the float32 (heads, queries, KEY_BLOCK)
+# scores of the expanded form; the same limit nemotron_h has, whose
+# 2 x 2,048 program never returned on the v5e (PERF.md section 7).
 PREFILL_CALL_TOKENS = 2048
 # A call with at most this many query tokens a row over a cached context
 # takes the absorbed form. Expanding K keys costs 2 K r H (nope + v) FLOP

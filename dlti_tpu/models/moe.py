@@ -19,8 +19,15 @@ train step collects it when ``ModelConfig.num_experts > 0``.
 **``HeldExpertsMLP``** — the dropless layer of the patterned families
 (``ModelConfig.layer_pattern``, "E" layers) and of the latent-attention
 family's expert layers (``models.latent``): no capacity, no token dropped.
-Every held expert runs over every token of a block under the routing weights
-as a mask (the same sum as sorting tokens by expert; see ``TOKEN_BLOCK``).
+The routed sum is computed one of two ways, chosen from the call's static
+shape alone (``takes_grouped`` and the note above it): a call of few
+tokens (a decode round, a prompt's short tail) runs every held expert over
+every token under the routing weights as a mask, which costs the weights'
+read and nothing else; a call of many tokens (a prefill), where the kernel
+takes the experts' width, lays the held assignments out by expert and
+runs each tile of rows through its expert alone
+(``ops.pallas.grouped_experts``): top-k of ``held_n`` of the mask's
+products. The same sum either way, no assignment dropped.
 The layer is told which experts it holds (``moe_held_start``,
 ``moe_held_count``), routes over all ``moe_num_experts``, and computes its
 own experts' part of the result plus the shared expert — what expert
@@ -43,9 +50,13 @@ from dlti_tpu.models.lora import LoRADense
 # What a HeldExpertsMLP call counts, in this order (summed over the expert
 # layers and steps of a program, the maximum apart): routed assignments of
 # real tokens; those on experts held here; held experts with at least one;
-# the largest count on one held expert in one layer-step.
+# the largest count on one held expert in one layer-step; held assignments
+# that went through the grouped product (over ``moe_held_assignments``: the
+# share of the routed work it took) and the rows of the tiles it ran (the
+# former over this: how full its tiles were).
 MOE_COUNTERS = ("moe_assignments", "moe_held_assignments",
-                "moe_experts_touched", "moe_expert_load_max")
+                "moe_experts_touched", "moe_expert_load_max",
+                "moe_grouped_rows", "moe_grouped_tile_rows")
 
 
 class MoEMLP(nn.Module):
@@ -168,17 +179,115 @@ class MoEMLP(nn.Module):
         return v
 
 
-# HeldExpertsMLP runs every held expert over every token under the routing
-# weights as a mask, this many tokens at a time (the (tokens, experts, width)
-# product of a block is what bounds the memory). Why not sorted by expert
-# through `jax.lax.ragged_dot`: one layer of 64 held experts at the published
-# widths on the v5e, masked / sorted (my chip runs, PR 30): 32 tokens 1.89 /
-# 8.73 ms, 512 3.84 / 17.4, 1,024 7.42 / 18.9, 2,048 14.7 / 22.5, and at
-# 4,096 the sorted 29.2 ms is what two masked blocks take: `ragged_dot` pays
-# ~14 ms for its 64 groups however few rows it is given. And a 13-layer
-# prefill of 2 rows x 2,048 tokens through its kernel never returned on the
-# v5e (PERF.md, PR 30).
-TOKEN_BLOCK = 2048
+# Which way HeldExpertsMLP computes a call's routed sum is read from the
+# call's token count (rows x bucket, padding included): at least this many
+# and the held assignments go through ``ops.pallas.grouped_experts`` in tiles
+# of GROUPED_TILE_ROWS rows; fewer and every held expert runs over every
+# token under the routing weights as a mask. One layer of 64 held experts at
+# the published widths on the v5e, bf16, masked / grouped ms a call
+# (``benchmarks_dev/moe_grouped_sweep.py``, my chip runs, PR 42):
+#
+#   tokens   xing4 3,584 x 1,024   nemotron 2,688 x 1,856   kanana 2,048 x 768
+#            gated, top-4 of 64    relu2, ~3 of 64 held     gated, ~3 of 64
+#       32      2.03 /  1.80          1.82 / ( 1.93)           0.92 /  0.82
+#      128      2.08 /  2.29          1.87 / ( 2.34)           0.95 /  1.02
+#      256      2.16 /  2.38          1.94 / ( 2.46)           0.99 /  1.08
+#      512      4.00 /  2.61          3.61 / ( 2.58)           1.74 /  1.16
+#    1,024      7.68 /  2.76          7.09 / ( 3.07)           3.37 /  1.35
+#    2,048     15.26 /  3.73         14.07 / ( 4.01)           6.64 /  1.53
+#    4,096     30.40 /  5.66         28.76 / ( 6.16)          13.20 /  2.94
+#
+# (nemotron's grouped column, in brackets: an earlier revision of the kernel,
+# which took any width as one block; the kernel as it stands refuses 1,856.)
+#
+# Up to 256 tokens the mask costs the weights' read and nothing else, and the
+# layout (a rank, a scatter, two gathers of rows) is what grouped adds to
+# it; from 512 the mask's 64-fold products show and grouped is first, so the
+# boundary is the same at every geometry. (Call sizes are powers of two:
+# nothing lies between 256 and 512.) Tiles of 128 rows were best or within
+# 3 % everywhere (256 rows: +25-30 % under 1,024 tokens for the padding,
+# equal from 2,048: an earlier revision's sweep, which took the tile as a
+# variant).
+# What was tried before, and stays out: sorted by expert through
+# ``jax.lax.ragged_dot`` (my chip runs, PR 30: masked / sorted 32 tokens
+# 1.89 / 8.73 ms, 512 3.84 / 17.4, 1,024 7.42 / 18.9, 2,048 14.7 / 22.5:
+# it pays ~14 ms for its 64 groups however few rows it is given, and a
+# 13-layer prefill of 2 rows x 2,048 tokens through it never returned).
+#
+# The kernel takes an expert's width in whole chunks of 256 columns
+# (``grouped_experts.takes_width``), and a layer of any other width stays on
+# the mask. Of the geometries served that leaves out one,
+# nemotron3_nano_30b's 1,856 (7.25 chunks, 14.5 lane tiles), and what leaves
+# it out is a fault, not a loss: the form that took any width as one block
+# won at 1,856 alone (the bracketed column), but that family's 13-layer
+# prefill program at 2 rows x 1,024 tokens never returns on the v5e with it
+# in the five expert layers, and returns in 0.05 s with the experts 1,792 or
+# 2,048 wide through the kernel as it stands (my chip runs, PR 42; PERF.md
+# section 7 item 6 has the bisection: the width that is not whole lane
+# tiles is what it takes; not the routing data, the traced grid length, the
+# padding tokens or the layout and gathers, and the same rows return at
+# 1 x 2,048 and in a 7-layer stack). In the program compiled for a v5e such
+# a width is also stored with the hidden size minor, so XLA copies ``w_up``
+# whole (638 MB a layer a call) to hand it to a kernel. What would serve
+# that family:
+# its width padded to whole chunks, in the checkpoint or in the call;
+# neither is done here, its programs are the parent's and its gain (a third
+# of ``tool_turns``' window is prefill) is not taken.
+GROUPED_MIN_TOKENS = 512
+GROUPED_TILE_ROWS = 128
+
+
+def takes_grouped(tokens: int, width: int) -> bool:
+    """Whether a call of ``tokens`` (rows x bucket) over experts ``width``
+    wide goes through the grouped product: read from static shapes alone."""
+    if tokens < GROUPED_MIN_TOKENS:
+        return False
+    from dlti_tpu.ops.pallas.grouped_experts import takes_width
+
+    return takes_width(width)
+
+
+def routed_masked(xs, local, w, w_gate, w_up, w_down):
+    """Every held expert over every token, the routing weight as the mask:
+    nothing sorted or gathered, each expert's weights read once, which for
+    a decode step or a short prompt is all the time there is. ``xs``
+    (T, h); ``local`` (T, k) the held expert of each assignment or
+    ``held_n``; ``w`` (T, k) float32 routing weights; ``w_gate`` None for
+    ungated relu² experts."""
+    T, held_n = xs.shape[0], w_up.shape[0]
+    gate = jnp.zeros((T, held_n + 1), jnp.float32).at[
+        jnp.arange(T)[:, None], local].add(w)[:, :held_n].astype(xs.dtype)
+    act = jnp.einsum("th,ehf->tef", xs, w_up)
+    act = jax.nn.silu(jnp.einsum("th,ehf->tef", xs, w_gate)) * act \
+        if w_gate is not None else _relu2(act)
+    return jnp.einsum("tef,efh->th", act * gate[:, :, None], w_down)
+
+
+def routed_grouped(xs, local, sizes, w, w_gate, w_up, w_down):
+    """The same sum over the held assignments laid out by expert: rows of
+    ``xs`` gathered into that order, each tile of rows through its expert
+    (one kernel), and a token's result the float32 sum over its k
+    assignments of routing weight x its row, gathered back (no scatter-add:
+    the order of additions is fixed). Arguments as ``routed_masked``, with
+    ``sizes`` (held_n,) the assignments on each held expert. Returns
+    ``(y, rows of the tiles that ran)``."""
+    from dlti_tpu.ops.attention import _kernel_path
+    from dlti_tpu.ops.pallas.grouped_experts import (
+        group_rows, grouped_experts,
+    )
+
+    held_n, tile_rows = w_up.shape[0], GROUPED_TILE_ROWS
+    row, source, tile_expert, tiles = group_rows(local, sizes, tile_rows)
+    out = grouped_experts(
+        jnp.take(xs, source, axis=0, mode="clip"), tile_expert, tiles,
+        w_gate, w_up, w_down, tile_rows=tile_rows,
+        interpret=_kernel_path() == "pallas-interpret")
+    # A row past the last tile that ran was never written: chosen, not
+    # multiplied by zero.
+    mine = jnp.where((local < held_n)[:, :, None],
+                     jnp.take(out, row, axis=0, mode="clip"), 0)
+    y = jnp.sum(mine.astype(jnp.float32) * w[:, :, None], axis=1)
+    return y.astype(xs.dtype), tiles * tile_rows
 
 
 def _relu2(x):
@@ -237,7 +346,7 @@ class HeldExpertsMLP(nn.Module):
                  token_mask: Optional[jnp.ndarray] = None):
         """``token_mask`` (b, s): true for real tokens; the rest are not
         routed (they would count as load and touch experts). Returns
-        ``(y, counters (4,) int32 in the order of MOE_COUNTERS)``."""
+        ``(y, counters int32 in the order of MOE_COUNTERS)``."""
         cfg = self.cfg
         if cfg.mlp_activation not in ("relu2", "silu") \
                 or cfg.moe_scoring != "sigmoid_bias":
@@ -280,33 +389,19 @@ class HeldExpertsMLP(nn.Module):
             w_down = self.param("w_down", centred_out_init(
                 ROUTED_OUT_SCALE, batch_axis=(0,)), (held_n, f, h),
                 pdtype).astype(dtype)
-            # Tokens on each held expert, for the counters; an assignment
-            # held elsewhere counts as none.
+            # Tokens on each held expert (the counters, the grouped layout);
+            # an assignment held elsewhere counts as none.
             local = jnp.where(held, chosen - lo, held_n)                # (T,k)
             sizes = jnp.bincount(local.reshape(-1), length=held_n + 1)[
                 :held_n].astype(jnp.int32)
-            # Every held expert over every token, the routing weight as the
-            # mask: nothing sorted or gathered, each expert's weights read
-            # once a block, which for a decode step or a short prompt is
-            # all the time there is.
-            gate = jnp.zeros((T, held_n + 1), jnp.float32).at[
-                jnp.arange(T)[:, None], local].add(w)[:, :held_n]
-
-            def block(xg):
-                xb, gb = xg
-                act = jnp.einsum("th,ehf->tef", xb, w_up)
-                act = jax.nn.silu(jnp.einsum("th,ehf->tef", xb, w_gate)) \
-                    * act if gated else _relu2(act)
-                return jnp.einsum("tef,efh->th", act * gb[:, :, None], w_down)
-
-            xs, gate = xt.astype(dtype), gate.astype(dtype)
-            if T <= TOKEN_BLOCK:
-                y = block((xs, gate))
-            else:  # whole blocks; the rows added carry a zero gate
-                n = -(-T // TOKEN_BLOCK)
-                xs, gate = (jnp.pad(v, ((0, n * TOKEN_BLOCK - T), (0, 0)))
-                            .reshape(n, TOKEN_BLOCK, -1) for v in (xs, gate))
-                y = jax.lax.map(block, (xs, gate)).reshape(-1, h)[:T]
+            xs = xt.astype(dtype)
+            if takes_grouped(T, f):
+                y, tile_rows = routed_grouped(xs, local, sizes, w, w_gate,
+                                              w_up, w_down)
+                grouped = (jnp.sum(held), tile_rows)
+            else:
+                y = routed_masked(xs, local, w, w_gate, w_up, w_down)
+                grouped = (0, 0)
         with jax.named_scope("dlti_moe_shared"):
             if cfg.moe_shared_intermediate_size:
                 def dense(name, features, **kw):
@@ -322,7 +417,7 @@ class HeldExpertsMLP(nn.Module):
                     SHARED_OUT_SCALE))(act)
         counters = jnp.stack([
             jnp.sum(valid) * k, jnp.sum(held), jnp.sum(sizes > 0),
-            jnp.max(sizes)]).astype(jnp.int32)
+            jnp.max(sizes), *grouped]).astype(jnp.int32)
         return y.reshape(b, s, h), counters
 
 

@@ -160,6 +160,23 @@ class DecodeStateCache:
             self.stats["decode_state_clean_syncs"] += 1
         return self._dev
 
+    def warm_row_counts(self, mirrors: Dict[str, np.ndarray],
+                        masked_rows: Sequence[int] = ()) -> None:
+        """Run the row updater once at every padded count of dirty rows
+        (1, 2, 4, ... ``num_slots``), uploading those rows as they stand.
+        XLA specializes it per count, and a count first met under traffic
+        (nine streams ending and being replaced between two rounds) is a
+        compile inside the live decode loop. The upload counters stand
+        as they stood: these are no traffic's uploads."""
+        slots = self._num_slots
+        stats = {k: v for k, v in self.stats.items()
+                 if k.startswith("decode_state_")}
+        for n in sorted({min(1 << i, slots)
+                         for i in range(slots.bit_length() + 1)}):
+            self._dirty.update(range(n))
+            self.sync(mirrors, masked_rows)
+        self.stats.update(stats)
+
     def bump_gen_counts(self, k: int) -> None:
         """Advance the resident gen counts by ``k`` decode steps — on
         device, mirroring the host appends for every slot that survives

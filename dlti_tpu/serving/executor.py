@@ -913,6 +913,8 @@ class EngineExecutor:
         args = (avals(self.params), avals(self.cache), tok, tok,
                 *avals(self.decode_state.sync(mirrors, masked_rows)),
                 *avals(self._pool_tree()))
+        # The state's row updater is a program a count of dirty rows too.
+        self.decode_state.warm_row_counts(mirrors, masked_rows)
         # Idempotent: a re-warm unwraps back to the raw jit fn (the
         # _aot_or_jit wrapper has no .lower) and rebuilds the executable.
         # The one-step program also takes the round before's tokens, a

@@ -366,6 +366,35 @@ def test_clean_decode_step_issues_zero_uploads(tiny_params):
     assert n >= 7
 
 
+def test_warm_up_runs_the_row_updater_at_every_padded_count(tiny_params):
+    """The resident state's row updater is one jit that XLA specializes
+    per padded count of dirty rows; the warm-up meets every count up to
+    max_seqs, so a burst of ends and admissions under traffic (a count no
+    quiet step forms) compiles nothing, and it leaves the rows as they
+    stood."""
+    eng = _engine(tiny_params, max_seqs=8)
+    state = eng.executor.decode_state
+    state.sync(eng._state_mirrors(), eng._masked_rows())
+    counted = dict(state.stats)
+    state.warm_row_counts(eng._state_mirrors(), eng._masked_rows())
+    assert state.stats == counted    # the warm-up's uploads are not traffic's
+    eng.warmup_decode_ladder()
+    # (the jit's cache is shared by every engine of the process: what is
+    # pinned is that no count of dirty rows adds to it after the warm-up)
+    warmed = state._update._cache_size()
+    before = [np.asarray(a) for a in state._dev]
+    for dirty in range(1, 9):                        # pads to 1, 2, 4, 8
+        for slot in range(dirty):
+            state.mark_dirty(slot)
+        state.sync(eng._state_mirrors(), eng._masked_rows())
+        assert state._update._cache_size() == warmed
+    for a, b in zip(before, state._dev):
+        np.testing.assert_array_equal(a, np.asarray(b))
+    got = eng.generate([[3, 1, 4, 1, 5, 9]],
+                       SamplingParams(temperature=0.0, max_tokens=5))
+    assert len(got[0].output_token_ids) == 5
+
+
 # ----------------------------------------------------------------------
 # Serving: the loop one round ahead of the host serves the same streams
 # ----------------------------------------------------------------------

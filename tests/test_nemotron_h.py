@@ -23,6 +23,7 @@ sys.path.insert(0, os.path.join(ROOT, "benchmark", "lib"))
 import spec as spec_lib  # noqa: E402
 from chip_child import model_fields  # noqa: E402
 
+import dlti_tpu.ops.pallas.grouped_experts as grouped_experts  # noqa: E402
 from dlti_tpu.config import MODEL_PRESETS, ModelConfig  # noqa: E402
 from dlti_tpu.models import LlamaForCausalLM, build_model  # noqa: E402
 from dlti_tpu.models.mamba2 import Mamba2Mixer  # noqa: E402
@@ -190,15 +191,19 @@ def test_idle_decode_row_keeps_its_state(mamba):
 
 # -- the expert layer: shares and droplessness --------------------------------
 
-@pytest.fixture(params=["one_block", "blocks"])
+@pytest.fixture(params=["masked", "grouped"])
 def routing(request, monkeypatch):
-    """HeldExpertsMLP runs every held expert over every token under a mask,
-    ``TOKEN_BLOCK`` tokens at a time: all tokens in one block, or several
-    blocks of which the last is padded."""
+    """HeldExpertsMLP computes a call's routed sum one of two ways by the
+    call's token count: every held expert over every token under a mask, or
+    the held assignments laid out by expert in tiles of rows (here of 8, so
+    that an expert has whole, part-filled and no tiles) through the kernel,
+    interpreted."""
     import dlti_tpu.models.moe as moe
 
-    monkeypatch.setattr(moe, "TOKEN_BLOCK",
-                        1 << 30 if request.param == "one_block" else 8)
+    monkeypatch.setattr(moe, "GROUPED_MIN_TOKENS",
+                        1 << 30 if request.param == "masked" else 1)
+    monkeypatch.setattr(moe, "GROUPED_TILE_ROWS", 8)
+    monkeypatch.setattr(grouped_experts, "WIDTH_CHUNK", 8)
     return request.param
 
 
@@ -250,7 +255,12 @@ def test_no_token_is_dropped_under_a_skewed_router(tiny, routing):
     params = {**params, "e_score_correction_bias":
               jnp.zeros((8,)).at[3].set(10.0)}
     y, counters = layer.apply({"params": params}, x)
-    assignments, held, touched, load_max = (int(c) for c in counters)
+    assignments, held, touched, load_max, grouped_rows, tile_rows = (
+        int(c) for c in counters)
+    # 80 rows on expert 3 are ten whole tiles of 8; the other 160 spread
+    assert (grouped_rows, tile_rows % 8) == (
+        (held, 0) if routing == "grouped" else (0, 0))
+    assert tile_rows == 0 or held <= tile_rows <= held + 7 * 7
     assert assignments == held == 80 * cfg.num_experts_per_tok
     assert load_max == 80  # capacity 1.25 x 80 x 3 / 8 = 37 would drop 43
     config = tiny_config(n_routed_experts=8)
@@ -466,16 +476,28 @@ def test_llama_programs_take_no_new_argument():
     assert eng.recurrent_state_pool_bytes == 0
 
 
-def test_a_wide_prefill_call_runs_the_experts_in_blocks(tiny, monkeypatch):
-    """Four admissions of one bucket are one call of 4 x 32 padded tokens;
-    the expert layers take them 48 at a time, the last block padded."""
+def test_a_wide_prefill_call_runs_the_experts_grouped(tiny, monkeypatch):
+    """Four admissions of one bucket are one call of 4 x 32 padded tokens:
+    at the boundary and over it, so the expert layers lay its held
+    assignments out by expert (tiles of 16 rows) and the decode rounds, of 4
+    tokens, stay under the mask."""
     import dlti_tpu.models.moe as moe
 
-    monkeypatch.setattr(moe, "TOKEN_BLOCK", 48)
+    monkeypatch.setattr(moe, "GROUPED_MIN_TOKENS", 128)
+    monkeypatch.setattr(moe, "GROUPED_TILE_ROWS", 16)
+    monkeypatch.setattr(grouped_experts, "WIDTH_CHUNK", 8)
     prompts = _prompts([30, 29, 31, 28], seed=6)
     eng = _engine(tiny)
     got = eng.generate(prompts, SamplingParams(max_tokens=4, temperature=0.0))
     assert eng.stats["prefill_batches"] == 1
+    # every held assignment of the call's real tokens went grouped, and no
+    # decode round's did
+    assert eng.stats["moe_grouped_rows"] == (
+        eng.stats["moe_held_assignments"]
+        - eng.stats["moe_held_assignments_decode"]) > 0
+    assert eng.stats["moe_grouped_rows_decode"] == 0
+    assert eng.stats["moe_grouped_tile_rows"] % 16 == 0
+    assert eng.stats["moe_grouped_tile_rows"] >= eng.stats["moe_grouped_rows"]
     _hold_to_reference(tiny, prompts, got)
 
 

@@ -1,7 +1,9 @@
 """The paged decode kernel compiles for the v5e at the geometries that
 ``tests/benchmark/test_bench_kernels_v5e.py`` (the benchmark's file) does not
 hold: nemotron3_nano_30b's two kv heads with sixteen query heads each, and an
-int8 pool with its scale tiles. Nothing runs: the TPU compiler installed here
+int8 pool with its scale tiles; and the grouped expert kernel at the three
+configurations' published widths, as ``HeldExpertsMLP`` calls it for a
+2,048-token prefill. Nothing runs: the TPU compiler installed here
 compiles for a chip that is described, not attached. The topology is
 described inside a module-scoped fixture and never at import."""
 
@@ -68,4 +70,48 @@ def test_paged_decode_kernel_compiles_for_the_v5e(one_chip, name):
                                       v_scale=v_scale, window=window)
 
     compiled = jax.jit(decode).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# (hidden, expert width, gated, top-k): 64 experts held, a 2,048-token call
+EXPERT_GEOMETRIES = {
+    "xing4_29b": (3584, 1024, True, 4),
+    "nemotron3_nano_30b": (2688, 1856, False, 6),  # 7.25 chunks: refused
+    "kanana2_30b": (2048, 768, True, 6),
+}
+
+
+@pytest.mark.parametrize("name", sorted(EXPERT_GEOMETRIES))
+def test_grouped_expert_kernel_compiles_for_the_v5e(one_chip, name):
+    import jax
+    import jax.numpy as jnp
+
+    from dlti_tpu.models import moe
+    from dlti_tpu.ops.pallas.grouped_experts import (
+        grouped_experts, num_tiles,
+    )
+
+    h, f, gated, k = EXPERT_GEOMETRIES[name]
+    held, tokens, tile = 64, 2048, moe.GROUPED_TILE_ROWS
+    tiles = num_tiles(tokens * k, held, tile)
+
+    def shape(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    inner = shape((held, h, f), jnp.bfloat16)
+
+    def run(x, tile_expert, n, w_gate, w_up, w_down):
+        return grouped_experts(x, tile_expert, n, w_gate if gated else None,
+                               w_up, w_down, tile_rows=tile)
+
+    args = (shape((tiles * tile, h), jnp.bfloat16), shape((tiles,), jnp.int32),
+            shape((), jnp.int32), inner, inner,
+            shape((held, f, h), jnp.bfloat16))
+    if not moe.takes_grouped(tokens, f):
+        # A width the kernel does not take is left on the mask by the
+        # layer and refused by the kernel: never handed to the compiler.
+        with pytest.raises(ValueError, match="whole chunks"):
+            jax.jit(run).lower(*args)
+        return
+    compiled = jax.jit(run).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
