@@ -134,7 +134,11 @@ def bench_serving() -> dict:
         eng2.step()
     clean_window_uploads = eng2.stats["decode_state_uploads"] - up0
 
-    prep = eng.telemetry.host_prep.summary()
+    # The account's prep phase with what it nests (plan, assemble, stage).
+    acct = eng.telemetry.stepper
+    prep_s = sum(v for k, v in acct.seconds().items()
+                 if k in ("engine/decode_prep", "engine/decode_plan",
+                          "engine/decode_assemble", "engine/decode_stage"))
     return {
         "decode_steps": eng.stats["decode_steps"],
         "generated_tokens": eng.stats["generated_tokens"],
@@ -143,8 +147,8 @@ def bench_serving() -> dict:
         "decode_state_clean_syncs": eng.stats["decode_state_clean_syncs"],
         "clean_window_steps": 10,
         "clean_window_uploads": clean_window_uploads,
-        "host_prep_mean_s": prep["mean"],
-        "host_prep_p99_s": prep["p99"],
+        "host_prep_mean_s": round(
+            prep_s / max(1, acct.entries().get("engine/decode_prep", 0)), 6),
         "wall_s": round(wall, 4),
     }
 

@@ -52,6 +52,7 @@ from dlti_tpu.serving.sampling import SamplingParams
 from dlti_tpu.telemetry import RequestTelemetry
 from dlti_tpu.telemetry.distributed_trace import mint_trace_id
 from dlti_tpu.telemetry.flightrecorder import get_recorder
+from dlti_tpu.telemetry.ledger import DEVICE_WAIT
 from dlti_tpu.telemetry.memledger import MemoryLedger, is_oom_error
 from dlti_tpu.utils.logging import get_logger
 
@@ -295,6 +296,13 @@ class Request:
     stall_s: Dict[str, float] = field(default_factory=dict)
     stall_prefill_s: float = 0.0
     _requeue_mark: Optional[tuple] = None
+    # Of its decode, the wall its engines spent in other requests' prefill
+    # calls while it held a decoding slot ("decode_prefill_stall"): the
+    # engine's running total of prefill wall is noted when the request's
+    # own prefill has handed it a slot (_prefill_stall_mark) and the
+    # difference settled when it leaves the slot. O(1) a request.
+    prefill_stall_s: float = 0.0
+    _prefill_stall_mark: Optional[float] = None
     # Multi-LoRA serving: the registered adapter this request generates
     # under ("" = base model). _adapter_slot is the resolved pool row
     # (-1 = unresolved): acquisition happens at admission and the pin is
@@ -400,6 +408,12 @@ class InferenceEngine:
         self.telemetry = telemetry if telemetry is not None \
             else RequestTelemetry()
         self._tracer = self.telemetry.tracer
+        # The stepper thread's phase clock (telemetry.ledger): every span
+        # of the step's path is entered through it, so it books always and
+        # the tracer's span of the same name opens only while enabled.
+        self._account = self.telemetry.stepper
+        self._phase = self._account.phase
+        self._account.doing = self._doing
         if engine_cfg.max_blocks_per_seq > engine_cfg.num_blocks - 1:
             # Block 0 is the reserved trash block, so only num_blocks-1 are
             # allocatable. A config where one max-length sequence can never
@@ -436,6 +450,13 @@ class InferenceEngine:
                       # away; decode_slot_steps counts kept tokens only).
                       "decode_rounds_launched_ahead": 0,
                       "decode_rows_discarded": 0,
+                      # Slot-seconds the streams stood still for: the wall
+                      # of every prefill call (launch to fetch) times the
+                      # slots that held a decoding stream when it began.
+                      # Over decode_slot_steps (kept tokens): the seconds of
+                      # an average gap between two tokens that were a
+                      # prefill's.
+                      "decode_stream_stall_seconds_prefill": 0.0,
                       # Tokens of context the decode steps attended over:
                       # each round adds the sum of its active slots'
                       # seq_len times its steps, so decode_context_tokens /
@@ -537,6 +558,9 @@ class InferenceEngine:
                 kv_fetch=self.executor.fetch_block_kv
                 if tier_store is not None else None)
         self.slots = [_Slot(i) for i in range(ec.max_seqs)]
+        # Wall seconds of this engine's prefill calls so far (not times
+        # slots): what a request's decode_prefill_stall is the change of.
+        self._prefill_wall_s = 0.0
         self.waiting: collections.deque[Request] = collections.deque()
         # Recently-finished requests, for observability only (results are
         # returned via step()/generate()); bounded so a long-lived server
@@ -826,7 +850,7 @@ class InferenceEngine:
         speculative or multi-step round is fetched in the step that
         launched it, after admission, whose prefill work hides under it.
         """
-        tr = self._tracer
+        phase = self._phase
         # Whatever is in flight is this call's to fetch: a fault below
         # drops it, and the round launched behind it, with the step.
         inflight, self._inflight = self._inflight, None
@@ -834,18 +858,15 @@ class InferenceEngine:
             finished: List[Request] = []
             launched = None
             if inflight is not None:
-                with tr.span("engine/decode_dispatch", cat="engine"):
-                    launched = self._decode_dispatch(ahead_of=inflight)
-                with tr.span("engine/decode_sync", cat="engine"):
-                    finished = self._decode_complete(inflight)
+                launched = self._decode_dispatch(ahead_of=inflight)
+                finished = self._decode_complete(inflight)
             if launched is None and not self.prefill_only and any(
                     not s.free and not s.prefilling for s in self.slots):
-                with tr.span("engine/decode_dispatch", cat="engine"):
-                    launched = self._decode_dispatch()
-            with tr.span("engine/admit", cat="engine"):
+                launched = self._decode_dispatch()
+            with phase("engine/admit", "engine"):
                 self._admit()
             if self.cfg.max_prefill_tokens_per_step > 0:
-                with tr.span("engine/prefill_chunks", cat="engine"):
+                with phase("engine/prefill_chunks", "engine"):
                     self._prefill_work()
             if launched is None:
                 return finished
@@ -855,8 +876,7 @@ class InferenceEngine:
                 return finished
             # A window or a speculative round; or every request the round
             # carried has ended and none waits: nothing would come for it.
-            with tr.span("engine/decode_sync", cat="engine"):
-                return finished + self._decode_complete(launched)
+            return finished + self._decode_complete(launched)
         except Exception as e:
             if is_oom_error(e):
                 # OOM forensics: file the black box as an OOM (with
@@ -894,6 +914,27 @@ class InferenceEngine:
         if self.prefix_cache is not None:
             return self.prefix_cache.allocate(n)
         return self.block_manager.allocate(n)
+
+    def _doing(self) -> str:
+        """For the log line of a program built after start-up: the shape of
+        the newest prefill call, which is the call a program built inside
+        ``engine/prefill_launch`` was built for (a shape the warm-up did
+        not form)."""
+        shape = getattr(self.executor, "last_prefill_shape", None)
+        if shape is None:
+            return ""
+        return ("newest prefill call %d rows x %d tokens x %d blocks a row"
+                % shape)
+
+    def _streams_standing(self, chunks) -> int:
+        """Slots that hold a stream the prefill call of ``chunks`` makes
+        wait: a request that has had a token and is not prefilling, the
+        call's own rows apart (a preempted request's recompute is one)."""
+        mine = {c[0].slot_id for c in chunks}
+        return sum(1 for s in self.slots
+                   if s.request is not None and not s.prefilling
+                   and s.request.first_token_time is not None
+                   and s.slot_id not in mine)
 
     def _admit(self) -> None:
         """Admit waiting requests into free slots via bucketed prefill.
@@ -1061,7 +1102,8 @@ class InferenceEngine:
         rows at a time (past that the batched program's marginal win
         flattens while its padded work and jit-shape surface keep growing),
         and fewer, a power of two, where the model holds a call to
-        ``prefill_call_tokens`` padded tokens (a row at least). One row
+        ``prefill_call_tokens`` padded tokens or the device a call of
+        several rows to ``prefill_group_tokens`` (a row at least). One row
         where a call of several rows of this bucket has been refused in
         this process (:meth:`_prefill_refused`): the one-row program of
         every bucket is warmed at start-up, a narrower one of several rows
@@ -1070,7 +1112,9 @@ class InferenceEngine:
         if any(b == bucket and r > 1
                for r, b, _width in self.executor.refused_prefill_shapes):
             return 1
-        limit = self.executor.prefill_call_tokens
+        limit = min((n for n in (self.executor.prefill_call_tokens,
+                                 self.executor.prefill_group_tokens) if n),
+                    default=0)
         rows = 8
         while limit and rows > 1 and rows * bucket > limit:
             rows //= 2
@@ -1184,20 +1228,35 @@ class InferenceEngine:
         prefill) write KV only. A call the executor refuses goes to
         :meth:`_prefill_refused`.
         """
-        tr = self._tracer
+        tr, phase, acct = self._tracer, self._phase, self._account
         # The arguments cost a pass over the rows: only for a tracer that
         # keeps them.
         args = {"rows": len(chunks), "bucket": bucket,
                 "prompt_tokens": sum(len(c[1]) for c in chunks)} \
             if tr.enabled else {}
+        streams = self._streams_standing(chunks)
         try:
+            # (The group is the tracer's alone, for its arguments: what of
+            # it is neither launch nor wait books to the phase round it.)
             with tr.span("engine/prefill_group", cat="engine", **args):
-                with tr.span("engine/prefill_launch", cat="engine"):
+                with phase("engine/prefill_launch", "engine"):
+                    began = acct.last
                     sampled = self._prefill_launch(bucket, chunks)
+                if sampled is not None:
+                    with phase("engine/prefill_wait", "engine", DEVICE_WAIT):
+                        toks, lps = self.executor.fetch(sampled)
+                # The call's wall, launch to fetch (a mid-prompt chunk's
+                # is its launch: what it keeps the device for shows in the
+                # wait of whatever is fetched next), from the two clock
+                # reads the phases took. Booked before the rows' first
+                # tokens are emitted: a request's mark then holds its own
+                # prefill already.
+                wall = acct.last - began if acct.mine() else 0.0
+                self._prefill_wall_s += wall
+                self.stats["decode_stream_stall_seconds_prefill"] += \
+                    streams * wall
                 if sampled is None:
                     return  # mid-prompt chunks: KV writes only
-                with tr.span("engine/prefill_wait", cat="engine"):
-                    toks, lps = self.executor.fetch(sampled)
                 if self.executor.counter_names:
                     self._count(toks[len(lps):][None, :], decode=False)
                 self._prefill_emit(chunks, toks, lps)
@@ -1248,6 +1307,7 @@ class InferenceEngine:
                 if not slot.free:  # (the first token may have ended it)
                     self._state_slots[slot.slot_id] = slot.slot_id
                     self._publish_prompt_blocks(slot)
+                    slot.request._prefill_stall_mark = self._prefill_wall_s
                 # Prefill completion: the first sampled token bumped the
                 # slot's gen count, and a chunked-mode slot's block-table
                 # row sheds its trash-block masking — either way the row
@@ -1381,12 +1441,12 @@ class InferenceEngine:
         which this one is launched; None then means that the round cannot
         be planned without that round's tokens (or that nothing would
         decode), and the caller fetches first."""
-        tr = self._tracer
-        with tr.span("engine/decode_prep", cat="engine"):
+        phase = self._phase
+        with phase("engine/decode_prep", "engine"):
             plan = self._decode_prepare(ahead_of)
         if plan is None:
             return None
-        with tr.span("engine/decode_launch", cat="engine"):
+        with phase("engine/decode_launch", "engine"):
             if plan[0] == "spec":
                 return self._spec_launch(*plan[1:])
             return self._decode_launch(*plan[1:], ahead_of)
@@ -1415,7 +1475,52 @@ class InferenceEngine:
         left out and reads as a free slot does (:meth:`_clear_row`). None
         also when such a plan cannot be made: a speculative round, a
         multi-step window, or a pool that is out of blocks (preemption
-        needs every request's tokens on the host)."""
+        needs every request's tokens on the host).
+
+        Three phases inside the caller's ``engine/decode_prep``: the plan
+        (:meth:`_decode_plan`), the assembly of the round's host arrays,
+        and the executor's staging of them (the dirty rows' upload)."""
+        ec = self.cfg
+        phase = self._phase
+        with phase("engine/decode_plan", "engine"):
+            plan = self._decode_plan(ahead_of)
+        if plan is None:
+            return None
+        active, riding, k_steps, use_spec, spec_parts, spec_k = plan
+        if use_spec:
+            return self._spec_prepare(active, spec_parts, spec_k)
+
+        with phase("engine/decode_assemble", "engine"):
+            ids = np.zeros((ec.max_seqs, 1), np.int32)
+            pos = np.zeros((ec.max_seqs, 1), np.int32)  # inactive -> trash
+            for s in active:
+                rides = s.slot_id in riding
+                ids[s.slot_id, 0] = RIDES if rides else s.last_token
+                pos[s.slot_id, 0] = s.seq_len + rides  # the new token's
+            self._book_decode_context(active, k_steps, riding)
+            self.stats["decode_steps_sorted_sampling"] += \
+                k_steps * self._sampling_sorts()
+            mirrors = self._state_mirrors()
+            if riding:
+                # A row uploaded again is uploaded as of this round's
+                # launch: the device has counted the token in flight, the
+                # mirror has not (a row drawn with the count before would
+                # repeat a draw).
+                mirrors["gen_counts"] = self._gen_counts.copy()
+                mirrors["gen_counts"][[s.slot_id for s in active
+                                       if s.slot_id in riding]] += 1
+            masked = self._masked_rows()
+        # Device-resident per-slot state: only rows dirtied since the
+        # last dispatch are shipped; a clean step uploads nothing.
+        with phase("engine/decode_stage", "engine"):
+            staged = self.executor.stage_decode(ids, pos, mirrors, masked)
+        return ("plain", [(s, s.request) for s in active], k_steps, staged)
+
+    def _decode_plan(self, ahead_of=None):
+        """Who decodes in the round and over how many steps: the
+        speculation gate, block growth (and preemption), who rides behind
+        the round in flight. ``(active, riding, k_steps, use_spec,
+        spec_parts, spec_k)``, or None (:meth:`_decode_prepare`)."""
         ec = self.cfg
         # Multi-step windows are budget-clamped per round (_window_steps):
         # max_model_len safety lives in its min(...) term, so there is no
@@ -1528,35 +1633,7 @@ class InferenceEngine:
         active = [s for s in active0 if not s.free and not s.prefilling]
         if not active:
             return None
-        if use_spec:
-            return self._spec_prepare(active, spec_parts, spec_k)
-
-        t_prep = time.perf_counter()
-        ids = np.zeros((ec.max_seqs, 1), np.int32)
-        pos = np.zeros((ec.max_seqs, 1), np.int32)  # inactive -> trash block
-        for s in active:
-            rides = s.slot_id in riding
-            ids[s.slot_id, 0] = RIDES if rides else s.last_token
-            pos[s.slot_id, 0] = s.seq_len + rides  # position of the new token
-        self._book_decode_context(active, k_steps, riding)
-        self.stats["decode_steps_sorted_sampling"] += \
-            k_steps * self._sampling_sorts()
-        mirrors = self._state_mirrors()
-        if riding:
-            # A row uploaded again is uploaded as of this round's launch:
-            # the device has counted the token in flight, the mirror has
-            # not (a row drawn with the count before would repeat a draw).
-            mirrors["gen_counts"] = self._gen_counts.copy()
-            mirrors["gen_counts"][[s.slot_id for s in active
-                                   if s.slot_id in riding]] += 1
-        # Device-resident per-slot state: only rows dirtied since the
-        # last dispatch are shipped; a clean step uploads nothing.
-        staged = self.executor.stage_decode(
-            ids, pos, mirrors, self._masked_rows())
-        # Host prep cost of this dispatch (batch assembly + state sync) —
-        # the term dirty tracking is meant to hold flat as max_seqs grows.
-        self.telemetry.host_prep.observe(time.perf_counter() - t_prep)
-        return ("plain", [(s, s.request) for s in active], k_steps, staged)
+        return active, riding, k_steps, use_spec, spec_parts, spec_k
 
     def _decode_launch(self, rows: List[tuple], k_steps: int, staged,
                        ahead_of=None):
@@ -1569,14 +1646,14 @@ class InferenceEngine:
 
     def _decode_complete(self, pending) -> List[Request]:
         """Sync a dispatched decode round's results and walk emissions."""
-        tr = self._tracer
+        phase = self._phase
         kind, *plan, device = pending
         # The wait ends when the round's results are on the host. The
         # emission walk after it is host time, under the next round's
         # program where the loop ran ahead and under none where it did not.
-        with tr.span("engine/decode_wait", cat="engine"):
+        with phase("engine/decode_wait", "engine", DEVICE_WAIT):
             host = self.executor.fetch(device)
-        with tr.span("engine/decode_emit", cat="engine"):
+        with phase("engine/decode_emit", "engine"):
             walk = self._spec_emit if kind == "spec" else self._decode_emit
             return walk(*plan, *host)
 
@@ -1726,30 +1803,34 @@ class InferenceEngine:
         ``k`` is the ladder draft length picked for this round."""
         ec = self.cfg
         R = ec.spec_rounds
-        t_in = np.zeros((ec.max_seqs,), np.int32)
-        seq_len = np.zeros((ec.max_seqs,), np.int32)
-        spec_mask = np.zeros((ec.max_seqs,), np.bool_)
-        for s in active:
-            t_in[s.slot_id] = s.last_token
-            seq_len[s.slot_id] = s.seq_len
-        self._book_decode_context(active, R)
-        self.stats["decode_steps_sorted_sampling"] += \
-            R * self._sampling_sorts()
-        for s in parts:
-            spec_mask[s.slot_id] = True
-        # Multi-query attention takes the gather path (the Pallas paged
-        # kernel is single-token); bound its window to the blocks the
-        # whole spec window can touch, quantized pow2 so jit
-        # specializations stay O(log).
-        nblk = max(self.block_manager.blocks_needed(s.seq_len + R * (k + 1))
-                   for s in active)
-        width = 1
-        while width < nblk:
-            width *= 2
-        width = min(width, ec.max_blocks_per_seq)
-        staged = self.executor.stage_spec(
-            self._spec_hist, t_in, seq_len, spec_mask,
-            self._state_mirrors(), self._masked_rows(), table_width=width)
+        phase = self._phase
+        with phase("engine/decode_assemble", "engine"):
+            t_in = np.zeros((ec.max_seqs,), np.int32)
+            seq_len = np.zeros((ec.max_seqs,), np.int32)
+            spec_mask = np.zeros((ec.max_seqs,), np.bool_)
+            for s in active:
+                t_in[s.slot_id] = s.last_token
+                seq_len[s.slot_id] = s.seq_len
+            self._book_decode_context(active, R)
+            self.stats["decode_steps_sorted_sampling"] += \
+                R * self._sampling_sorts()
+            for s in parts:
+                spec_mask[s.slot_id] = True
+            # Multi-query attention takes the gather path (the Pallas paged
+            # kernel is single-token); bound its window to the blocks the
+            # whole spec window can touch, quantized pow2 so jit
+            # specializations stay O(log).
+            nblk = max(self.block_manager.blocks_needed(
+                s.seq_len + R * (k + 1)) for s in active)
+            width = 1
+            while width < nblk:
+                width *= 2
+            width = min(width, ec.max_blocks_per_seq)
+        with phase("engine/decode_stage", "engine"):
+            staged = self.executor.stage_spec(
+                self._spec_hist, t_in, seq_len, spec_mask,
+                self._state_mirrors(), self._masked_rows(),
+                table_width=width)
         return ("spec", active, spec_mask, k, staged)
 
     def _spec_launch(self, active: List[_Slot], spec_mask, k: int, staged):
@@ -1851,10 +1932,20 @@ class InferenceEngine:
             req.finish_reason = reason
             req.finish_time = now
             self.finished.append(req)
+            self._settle_prefill_stall(req)
             self.telemetry.on_finished(req)
             self._release(slot)
             return True
         return False
+
+    def _settle_prefill_stall(self, req: Request) -> None:
+        """Close the request's mark on this engine's prefill wall: what the
+        total has grown by since its prefill handed it a slot was other
+        requests' prefill calls, during its decode."""
+        if req._prefill_stall_mark is not None:
+            req.prefill_stall_s += \
+                self._prefill_wall_s - req._prefill_stall_mark
+            req._prefill_stall_mark = None
 
     def _release_adapter(self, req: Request) -> None:
         """Drop the request's adapter-pool pin and reset it to unresolved
@@ -1897,6 +1988,7 @@ class InferenceEngine:
             self.block_manager.free(slot.blocks)
         if slot.request is not None:
             self._release_adapter(slot.request)
+            self._settle_prefill_stall(slot.request)
         slot.request = None
         slot.blocks = []
         slot.seq_len = 0
@@ -2020,6 +2112,7 @@ class InferenceEngine:
         slot.next_pos = seq_len
         slot.prefill_end = seq_len
         slot.last_token = snap["last_token"]
+        req._prefill_stall_mark = self._prefill_wall_s
         row = np.zeros((self.cfg.max_blocks_per_seq,), np.int32)
         row[: len(blocks)] = blocks
         self._block_tables[slot.slot_id] = row

@@ -52,6 +52,25 @@ class PrefillCallRefused(RuntimeError):
         self.shape = shape
 
 
+# A prefill program computes float32 logits over every position of every
+# row. A call of several rows whose logits alone would take more than this
+# share of one device's memory is not formed: the compiler tries for ten
+# seconds before it refuses such a program (qwen2_7b's 152k-row head at
+# 8 x 1,024 or 4 x 2,048: 4.6 GiB of logits and their 3.7 GiB gather beside
+# the weights and the cache, "Used 18.28G of 15.75G hbm"), all streams stand
+# still meanwhile, and the failed compile counts as a compilation. What the
+# benchmark's cells warm lies under it (4,096 padded tokens there: 2.3 GiB;
+# mistral_7b's 32k-row head at 8 x 2,048: 2.0 GiB).
+PREFILL_LOGITS_SHARE = 0.2
+
+
+def prefill_group_tokens(vocab_size: int, device_bytes: int) -> int:
+    """The most padded tokens a prefill call of several rows may hold
+    (``PREFILL_LOGITS_SHARE``); 0, no limit, where the device's memory is
+    not known (the CPU backend reports none)."""
+    return int(PREFILL_LOGITS_SHARE * device_bytes) // (4 * vocab_size)
+
+
 def refuse_unsupported(model_cfg: ModelConfig, engine_cfg: "EngineConfig",
                        mesh=None) -> None:
     """Refuse, at start-up and with one clear error each, every feature
@@ -188,6 +207,12 @@ class EngineExecutor:
         self.counter_names = tuple(getattr(self.model, "counter_names", ()))
         # The most padded tokens one prefill call may hold (0: no limit).
         self.prefill_call_tokens = getattr(self.model, "prefill_call_tokens", 0)
+        # The most a call of several rows may hold (0: no limit): what its
+        # logits may take of the smallest device (prefill_group_tokens).
+        self.prefill_group_tokens = prefill_group_tokens(
+            model_cfg.vocab_size, min(
+                ((d.memory_stats() or {}).get("bytes_limit", 0)
+                 for d in jax.local_devices()), default=0))
         # The ``(rows, bucket, table width)`` at which a prefill call has
         # been refused in this process (``PrefillCallRefused``): the
         # scheduler does not form such a call again.
@@ -312,6 +337,7 @@ class EngineExecutor:
                 str(d): (d.memory_stats() or {}).get("bytes_in_use")
                 for d in own},
             "model_layers": model_cfg.num_layers,
+            "prefill_group_tokens": self.prefill_group_tokens,
             "param_dtype": ("int8" if self._quantized
                             else model_cfg.param_dtype),
             "kv_cache_dtype": ec.cache_dtype,
@@ -342,6 +368,8 @@ class EngineExecutor:
             self._spec_fns[ec.num_draft_tokens] = self._build_spec_decode_fn(
                 ec.num_draft_tokens, ec.spec_rounds)
         self._sample_fn = jax.jit(sample_tokens)
+        # ``(rows, bucket, table width)`` of the newest prefill call.
+        self.last_prefill_shape: Optional[tuple] = None
         if self.counter_names:
             # First tokens of a prefill with the prefill program's counters
             # as rows after them: one fetch brings both.
@@ -775,6 +803,8 @@ class EngineExecutor:
         fit the device raises from the call itself, and the donated cache
         is consumed only by a dispatch that succeeded (checked here, not
         assumed: with the cache gone the error is passed on as it is)."""
+        # (for the line that names a program built after start-up)
+        self.last_prefill_shape = (*input_ids.shape, block_tables.shape[1])
         try:
             self.cache, last_logits, *counters = self._prefill_fn(bucket)(
                 self.params, self.cache, jnp.asarray(input_ids),

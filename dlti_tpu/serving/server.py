@@ -43,8 +43,68 @@ from dlti_tpu.telemetry import (
     get_recorder, get_tracer, install_recorder, render_dashboard_html,
     request_breakdown,
 )
-from dlti_tpu.telemetry.ledger import REQUEST_PHASES as _REQUEST_PHASES
+from dlti_tpu.telemetry.ledger import (
+    REQUEST_PHASES as _REQUEST_PHASES, WAIT, gc_collections_total,
+    gc_pause_seconds_total,
+)
+from dlti_tpu.telemetry.registry import Counter
 from dlti_tpu.utils.logging import get_logger
+
+# What the streaming handlers pay a token (detokenise the answer so far,
+# scan for stop strings, write the frame), on threads that share the
+# interpreter lock with the stepper: CPU seconds over events is the cost of
+# one event where it is paid.
+sse_handler_cpu_seconds_total = Counter(
+    "dlti_sse_handler_cpu_seconds_total",
+    help="thread CPU seconds of the streaming handlers, booked at every "
+         "64th event of a thread")
+sse_events_total = Counter(
+    "dlti_sse_events_total",
+    help="events of the streaming handlers that CPU is booked for (whole "
+         "blocks of 64 a thread)")
+for _c in (sse_handler_cpu_seconds_total, sse_events_total):
+    _c.inc(0)  # the series exists from the start
+_handler = threading.local()
+
+
+class _HandlerMeter:
+    """A handler thread's CPU over the events it streams. The thread's CPU
+    clock is a slow system call on a virtual host
+    (``telemetry.ledger.STEPPER_CPU_MARK_EVERY``), read under the
+    interpreter lock the stepper needs, and there it ticks in steps of
+    10 ms (my chip run, PR 44): so it is read at one event in ``EVERY``
+    and never scaled. What is booked then is the thread's running total
+    since its last read (since the thread began, the first time: a handler
+    thread does nothing but handle), for the ``EVERY`` events since: the
+    clock's error stays one tick a thread however many blocks it books, and
+    a response's last events, short of a block, are left out of both
+    series alike. One meter a thread, kept across its responses; an event
+    otherwise costs one add."""
+
+    EVERY = 64
+    __slots__ = ("events", "_cpu")
+
+    def __init__(self):
+        self.events = 0
+        self._cpu = 0.0
+
+    @classmethod
+    def of_this_thread(cls) -> "_HandlerMeter":
+        try:
+            return _handler.meter
+        except AttributeError:
+            meter = _handler.meter = cls()
+            return meter
+
+    def event(self) -> None:
+        """An event has arrived."""
+        self.events += 1
+        if not self.events % self.EVERY:
+            now = time.thread_time()
+            sse_handler_cpu_seconds_total.inc(now - self._cpu)
+            sse_events_total.inc(self.EVERY)
+            self._cpu = now
+
 
 # /stats keys exposed as Prometheus gauges (point-in-time values); every
 # other numeric stat is a monotonic counter. Name-stability contract: the
@@ -77,6 +137,12 @@ def build_registry(async_engine: "AsyncEngine") -> MetricsRegistry:
                                prefix="dlti_")
     for hist in async_engine.engine.telemetry.histograms():
         registry.register(hist)
+    # The stepper's phase clock, the collector's pauses and the streaming
+    # handlers' CPU: always on, read from their writers' books at a scrape.
+    for metric in (*async_engine.engine.telemetry.stepper.metrics(),
+                   gc_pause_seconds_total, gc_collections_total,
+                   sse_handler_cpu_seconds_total, sse_events_total):
+        registry.register(metric)
     # Self-monitoring series: the span ring's eviction counter (truncated
     # forensics must be self-announcing) plus the module-level watchdog /
     # flight-recorder counters (shared with any trainer in-process).
@@ -305,7 +371,14 @@ class AsyncEngine:
     def __init__(self, engine: InferenceEngine):
         self.engine = engine
         self.logger = get_logger()
-        self._tracer = get_tracer()
+        # The stepper's phase clock: the engine's own (its step phases book
+        # into it), so that the loop round the step and the step's inside
+        # are one account (telemetry.ledger.StepperAccount).
+        self.account = engine.telemetry.stepper
+        self.account.describe = lambda: {
+            "live_slots": self.engine.num_active,
+            "waiting": len(self.engine.waiting)}
+        self.account.steps_done = lambda: self.engine.stats["decode_steps"]
         self._lock = threading.Lock()
         self._work = threading.Condition(self._lock)
         self._queues: Dict[str, queue.Queue] = {}
@@ -362,18 +435,20 @@ class AsyncEngine:
         self._thread.join(timeout=10)
 
     def _run(self) -> None:
-        # The spans below, with the engine's own, cover the stepper's whole
-        # loop: whatever the chip waits for between two programs is inside
-        # one of them (benchmark/lib/attribute_idle.py reads them). Taking
-        # ``_work`` is a span of its own: the handler threads' submits take
-        # it too.
-        tr = self._tracer
+        # The phases below, with the engine's own, cover the stepper's
+        # whole loop: they book into the account always, and while the
+        # tracer is enabled each is its span of the same name, so whatever
+        # the chip waits for between two programs is inside one of them
+        # (benchmark/lib/attribute_idle.py reads them). Taking ``_work``
+        # is a phase of its own: the handler threads' submits take it too.
+        self.account.bind()
+        phase = self.account.phase
         while True:
-            with tr.span("server/lock_wait", cat="server"):
+            with phase("server/lock_wait"):
                 self._work.acquire()
             try:
                 while not self._stop and not self.engine.has_work:
-                    with tr.span("server/wait_work", cat="server"):
+                    with phase("server/wait_work", kind=WAIT):
                         if getattr(self.engine, "lifecycle_pending", False):
                             # A quarantined replica awaits its probe or a
                             # rolling reload is in flight: poll instead of
@@ -404,7 +479,7 @@ class AsyncEngine:
             # own stats key; admission consumes the deque at one point
             # inside step(), so a racing submit lands this step or next.
             try:
-                with tr.span("server/step", cat="server"):
+                with phase("server/step", step=True):
                     self.engine.step()
             except Exception as e:  # surface engine faults to the waiters
                 self.logger.exception("engine step failed")
@@ -444,10 +519,10 @@ class AsyncEngine:
                     if self._stop:
                         return
                 continue
-            with tr.span("server/lock_wait", cat="server"):
+            with phase("server/lock_wait"):
                 self._work.acquire()
             try:
-                with tr.span("server/drain_events", cat="server"):
+                with phase("server/drain_events"):
                     self._drain_events()
             finally:
                 self._work.release()
@@ -1276,6 +1351,7 @@ class _Handler(BaseHTTPRequestHandler):
         token_ids: List[int] = []
         emitted = ""
         finish = None
+        meter = _HandlerMeter.of_this_thread()
         try:
             if chat:
                 chunk(json.dumps({
@@ -1286,6 +1362,7 @@ class _Handler(BaseHTTPRequestHandler):
             cancelled = False
             matcher = self._StopMatcher(stops)
             for ev in self._collect(q, req):
+                meter.event()
                 if ev[0] == "token":
                     if cancelled:
                         # Stop already matched: drain (the engine finishes

@@ -28,7 +28,10 @@ reference-parity CSV in ``utils/metrics.py``, ``StepTimer`` in
 * :mod:`~dlti_tpu.telemetry.ledger` — goodput ledger (every training
   second booked to one bucket, conservation-tested) + per-request
   critical-path attribution (phase breakdowns summing to client-observed
-  latency, ``GET /debug/slow``), stitched across elastic restarts.
+  latency, ``GET /debug/slow``), stitched across elastic restarts; the
+  serving stepper's always-on phase clock (``StepperAccount``: where a
+  decode round's host time goes, a record of every stall) and the
+  collector's pauses.
 * :mod:`~dlti_tpu.telemetry.memledger` — HBM memory ledger (every
   device byte attributed to a named owner, conservation-tested against
   ``jax.live_arrays()``/``memory_stats()``), feeding ``GET
@@ -78,11 +81,17 @@ from dlti_tpu.telemetry.flightrecorder import (  # noqa: F401
 )
 from dlti_tpu.telemetry.ledger import (  # noqa: F401
     CriticalPathTracker,
+    GC_METRIC_NAMES,
     GOODPUT_BUCKETS,
     GoodputLedger,
     LEDGER_METRIC_NAMES,
+    NullStepperAccount,
     REQUEST_PHASE_METRIC_NAMES,
     REQUEST_PHASES,
+    STEPPER_METRIC_NAMES,
+    StepperAccount,
+    install_gc_hook,
+    remove_gc_hook,
     request_breakdown,
     stitch_ledgers,
 )

@@ -37,6 +37,17 @@ worst requests with their full timelines for ``GET /debug/slow`` — the
 answer to "why was this p99 request slow: queue, prefill, tier restore,
 or failover?".
 
+**Serving — :class:`StepperAccount`, the stepper thread's phase clock.**
+The same idea on the thread that drives ``engine.step()``: the thread is
+in exactly one phase at any instant, an inner phase suspends the one round
+it, and a transition books the time since the last one to the phase that
+was open. It is always on (one clock read and one add a transition: what an
+operator has is a scrape, not a profiler), its phases are the tracer's
+spans under the same names through one helper
+(:meth:`StepperAccount.phase`), and a host phase that stood still for
+:data:`STEPPER_STALL_S` leaves a record. Beside it the collector's pauses
+(:func:`install_gc_hook`).
+
 Cost contract (same as the tracer): a *disabled* ledger's ``enter()`` is
 one attribute read + an early return — no clock read, no lock, no dict —
 so the per-step instrumentation can stay in the trainer unconditionally.
@@ -48,13 +59,18 @@ parsing contracts for the same reason.
 
 from __future__ import annotations
 
+import collections
+import gc
 import json
 import os
 import threading
 import time
 from typing import Callable, Dict, List, Optional
 
-from dlti_tpu.telemetry.registry import Counter, Gauge
+from dlti_tpu.telemetry import startup
+from dlti_tpu.telemetry.registry import Counter, Gauge, ReadCounter
+from dlti_tpu.telemetry.tracer import _NULL_SPAN
+from dlti_tpu.utils.logging import get_logger
 
 # ----------------------------------------------------------------------
 # Bucket / phase catalogs (label contracts — postmortem, dashboards and
@@ -101,6 +117,7 @@ REQUEST_PHASES = (
     "preempt",         # preempted under memory pressure, waiting again
     "kv_handoff",      # disagg: prefill→decode paged-KV block migration
     "decode",          # first token → finish, minus requeue stalls
+    "decode_prefill_stall",  # of decode: its engine was in others' prefills
     "other",           # residual (clamp slivers; sum stays exact)
 )
 
@@ -113,6 +130,20 @@ LEDGER_METRIC_NAMES = (
 REQUEST_PHASE_METRIC_NAMES = (
     "dlti_request_phase_seconds_total",
     "dlti_request_phase_requests_total",
+)
+STEPPER_METRIC_NAMES = (
+    "dlti_stepper_phase_seconds_total",
+    "dlti_stepper_phase_entries_total",
+    "dlti_stepper_cpu_seconds_total",
+    "dlti_stepper_device_wait_cpu_seconds_total",
+    "dlti_stepper_marked_host_seconds_total",
+    "dlti_stepper_marked_decode_steps_total",
+    "dlti_stepper_stalls_total",
+    "dlti_stepper_stall_seconds_total",
+)
+GC_METRIC_NAMES = (
+    "dlti_gc_pause_seconds_total",
+    "dlti_gc_collections_total",
 )
 
 # Module-level metrics (the checkpoint-store/watchdog pattern: trainer
@@ -348,6 +379,484 @@ def stitch_ledgers(worker_ledgers: List[dict], timeline: List[dict],
 
 
 # ----------------------------------------------------------------------
+# Serving: the stepper thread's phase clock
+# ----------------------------------------------------------------------
+
+# What a phase is to the thread, declared where it is entered
+# (``account.phase(name, cat, kind)``) and carried as the ``kind`` label of
+# its series, so that neither this module nor a reader keeps a list of
+# names. HOST: work on the path of a decode round, not meant to stand still.
+# WAIT: where the thread waits by design (for work). DEVICE_WAIT: where it
+# waits for the device; the thread's CPU in there is booked apart.
+HOST, WAIT, DEVICE_WAIT = "host", "wait", "device_wait"
+# The phase the clock starts in: what of the owner's time is inside no phase.
+# Under a server that is its loop's own tests and jumps (microseconds); for
+# an engine driven without one it is the caller's time between two steps.
+# Nothing of a round either way, so a wait.
+STEPPER_BASE_PHASE = "server/loop"
+# The thread's CPU clock is a system call, and a slow one where the host is
+# a virtual machine (5.5 us a read on the benchmark's v5e host against
+# 0.09 us for the wall clock: my chip run, PR 43): read every round, four
+# reads would cost more than the 24 transitions. It is also a coarse one
+# there (it ticks in steps of 10 ms: my chip run, PR 44), so an interval of
+# one step read from it and scaled is mostly the tick's noise. So one step
+# in this many is a marked step: at its entry the thread's running total is
+# read (exact to a tick however long the run), and round its device waits,
+# which no running total holds, the clock is read and what it shows is
+# booked times this many.
+STEPPER_CPU_MARK_EVERY = 16
+# A host phase that went this long without a transition is a stall (host
+# phases last under 15 ms at 32 live slots).
+STEPPER_STALL_S = 0.25
+
+
+_thread_id = threading.get_ident
+
+
+def _programs_built() -> float:
+    """Programs this process has compiled or fetched from the persistent
+    cache so far (``telemetry.startup``'s listener counts them)."""
+    return (startup.compilations_total.value
+            + startup.compile_cache_hits_total.value)
+
+
+class _Phase:
+    """One phase of a :class:`StepperAccount` and its books; as a context
+    manager, the transition into it and back. Holds no state of an entry
+    (that is on the account's stacks), so one object serves every entry."""
+
+    __slots__ = ("_acct", "name", "cat", "kind", "host", "_waits_for_device",
+                 "_begins_step", "seconds", "entries", "stalls",
+                 "stall_seconds")
+
+    def __init__(self, acct: "StepperAccount", name: str, cat: str,
+                 kind: str, step: bool):
+        self._acct = acct
+        self.name = name
+        self.cat = cat
+        self.kind = kind
+        self.host = kind == HOST
+        self._waits_for_device = kind == DEVICE_WAIT
+        self._begins_step = step
+        self.seconds = 0.0
+        self.entries = 0
+        self.stalls = 0
+        self.stall_seconds = 0.0
+
+    def __enter__(self):
+        a = self._acct
+        stack = a._stack
+        if a._tracer.enabled:
+            # The tracer's span of this entry, kept with the depth it was
+            # opened at: a phase entered while the tracer was off has none.
+            # The step's span alone keeps its thread's CPU (``cpu_us``).
+            span = a._tracer.span(self.name, self.cat, self._begins_step)
+            span.__enter__()
+            a._spans.append((len(stack), span))
+        now = a._clock()
+        dt = now - a.last
+        cur = stack[-1]
+        cur.seconds += dt
+        if dt > STEPPER_STALL_S and cur.host:
+            a._stood_still(cur, dt, now)
+        a.last = now
+        stack.append(self)
+        self.entries += 1
+        if self._begins_step:
+            a._step_began()
+        elif self._waits_for_device and a._marked_step:
+            a._wait_cpu0 = a._cpu_clock()
+
+    def __exit__(self, exc_type, exc, tb):
+        a = self._acct
+        now = a._clock()
+        dt = now - a.last
+        self.seconds += dt
+        if dt > STEPPER_STALL_S and self.host:
+            a._stood_still(self, dt, now)
+        a.last = now
+        a._stack.pop()
+        if a._marked_step:
+            if self._waits_for_device:
+                a.device_wait_cpu_seconds += STEPPER_CPU_MARK_EVERY * (
+                    a._cpu_clock() - a._wait_cpu0)
+            elif self._begins_step:
+                a._marked_step = False
+        if a._spans and a._spans[-1][0] == len(a._stack):
+            a._spans.pop()[1].__exit__(exc_type, exc, tb)
+
+
+class StepperAccount:
+    """The phase clock of the thread that steps a serving engine.
+
+    One thread owns it (the first to enter a phase, or the one that called
+    :meth:`bind`). ``with account.phase(name, cat, kind):`` is the one way
+    into a phase: it books the transition always, and opens the tracer's
+    ring span and profiler annotation of the same name only while the tracer
+    is enabled, so the phases an operator scrapes and the spans a capture
+    holds are the same intervals under the same names. Another thread that
+    comes through the same code (a disaggregated fleet's prefill thread
+    shares its engines' telemetry) gets the tracer's span alone.
+
+    Conservation by construction: the clock starts in
+    :data:`STEPPER_BASE_PHASE`, every transition books the time since the
+    last one to the innermost open phase, so the phases' seconds sum to
+    ``last - start``, the owner's wall time up to its last transition.
+    The time of the phase still open is booked when it ends; a scrape reads
+    plain floats and never sees a half-made transition count twice.
+
+    The thread's CPU clock is read in a *marked* step alone: one entry in
+    :data:`STEPPER_CPU_MARK_EVERY` of the phase that begins a step
+    (``phase(..., step=True)``). At its entry three running totals are
+    taken as of one instant, so that a ratio of their changes is of like
+    with like: ``cpu_seconds`` (the thread's CPU since it was bound),
+    ``marked_host_seconds`` (the wall of its host phases) and
+    ``marked_decode_steps`` (by :attr:`steps_done`). Round that step's
+    :data:`DEVICE_WAIT` phases the clock is read too, and what they took is
+    booked times ``STEPPER_CPU_MARK_EVERY`` (``device_wait_cpu_seconds``:
+    an estimate from one step in sixteen; no running total holds it). Host
+    wall less the CPU outside the device waits is time the thread was
+    runnable and did not run: the interpreter lock, or the OS.
+    """
+
+    def __init__(self, tracer, clock: Callable[[], float] = time.monotonic,
+                 cpu_clock: Callable[[], float] = time.thread_time):
+        self._tracer = tracer
+        self._clock = clock
+        self._cpu_clock = cpu_clock
+        self._logger = get_logger()
+        self._phases: Dict[str, _Phase] = {}
+        self._owner: Optional[int] = None
+        self._stack: List[_Phase] = [self._new_phase(STEPPER_BASE_PHASE,
+                                                     "server", WAIT, False)]
+        self._spans: list = []
+        self.start = self.last = clock()
+        self.cpu_seconds = 0.0
+        self.device_wait_cpu_seconds = 0.0
+        self.marked_host_seconds = 0.0
+        self.marked_decode_steps = 0.0
+        self._cpu_base = 0.0
+        self._wait_cpu0 = 0.0
+        self._steps_to_mark = 1
+        self._marked_step = False
+        # As of the last marked step's entry (or the bind): what a stall's
+        # record is counted from.
+        self._mark_wall = self.start
+        self._mark_cpu = 0.0
+        self._mark_process = 0.0
+        self._mark_gc = gc_totals()
+        # As of the last mark or the last phase found over the limit: a
+        # phase that built a program since then was not standing still.
+        self._programs_seen = 0.0
+        # Of the engine stepped (the server's stepper sets them):
+        # ``() -> {"live_slots": n, "waiting": n}`` for a stall's record,
+        # and ``() -> decode steps so far`` for the marked steps' count.
+        self.describe: Optional[Callable[[], dict]] = None
+        self.steps_done: Optional[Callable[[], int]] = None
+        # ``() -> str``: what the engine stepped was last asked to run (the
+        # shape of its newest prefill call), for the line that names a
+        # program built after start-up (the engine sets it).
+        self.doing: Optional[Callable[[], str]] = None
+
+    def _new_phase(self, name: str, cat: str, kind: str,
+                   step: bool) -> _Phase:
+        p = self._phases[name] = _Phase(self, name, cat, kind, step)
+        return p
+
+    def bind(self) -> None:
+        """Make the calling thread the owner, in the base phase, from now:
+        what another thread left open is dropped with its thread, and the
+        tracer's ``profiler/start`` instant and a late program's log line
+        learn the open phase here."""
+        self._owner = _thread_id()
+        del self._stack[1:]
+        del self._spans[:]
+        self.last = self._clock()
+        self.start = self.last - sum(
+            p.seconds for p in self._phases.values())
+        self._marked_step = False
+        self._tracer.capture_context = self.open_phase
+        startup.stepper_phase = self._where
+        # A first call of a shape builds its program inside a host phase:
+        # count the programs built, to tell that from a stall.
+        startup.install_compile_listener()
+        cpu = self._cpu_clock()
+        self._cpu_base = cpu - self.cpu_seconds
+        self._mark(cpu)
+
+    def phase(self, name: str, cat: str = "server", kind: str = HOST,
+              step: bool = False):
+        """The context manager of one phase, for the owner; for any other
+        thread the tracer's span of that name and nothing booked. ``kind``
+        and ``step`` (entering it begins a step: the CPU mark) are a phase's
+        for good, as declared where it is first entered."""
+        if self._owner != _thread_id():
+            if self._owner is not None:
+                return self._tracer.span(name, cat)
+            self.bind()
+        try:
+            return self._phases[name]
+        except KeyError:
+            return self._new_phase(name, cat, kind, step)
+
+    def _doing_note(self) -> str:
+        doing = self.doing() if self.doing is not None else ""
+        return f" ({doing})" if doing else ""
+
+    def _where(self) -> str:
+        """The open phase by name and, where the engine says it, what it
+        was last asked to run."""
+        return self._stack[-1].name + self._doing_note()
+
+    def mine(self) -> bool:
+        """Whether the calling thread owns the account: ``last`` is then
+        its own last transition (for what is timed off the phases' reads)."""
+        return self._owner == _thread_id()
+
+    def open_phase(self) -> dict:
+        """The innermost open phase and when its current stretch began
+        (microseconds on the tracer's clock). Read from any thread: a read
+        that falls into a transition may pair one's name with the other's
+        start."""
+        return {"stepper_phase": self._stack[-1].name,
+                "stepper_phase_since_us": self.last * 1e6}
+
+    # -- the step's marks, and a stall's record -------------------------
+    def _step_began(self) -> None:
+        self._steps_to_mark -= 1
+        if self._steps_to_mark:
+            return
+        self._steps_to_mark = STEPPER_CPU_MARK_EVERY
+        self._marked_step = True
+        if _GC_BOOK.parked:
+            _GC_BOOK.flush()
+        # The three totals as of this instant (the clock last: nearest to
+        # the step's work).
+        self.marked_host_seconds = sum(
+            p.seconds for p in self._phases.values() if p.host)
+        if self.steps_done is not None:
+            self.marked_decode_steps = self.steps_done()
+        cpu = self._cpu_clock()
+        self.cpu_seconds = cpu - self._cpu_base
+        self._mark(cpu)
+
+    def _mark(self, cpu: float) -> None:
+        self._mark_wall = self.last
+        self._mark_cpu = cpu
+        self._mark_process = time.process_time()
+        self._mark_gc = gc_totals()
+        self._programs_seen = _programs_built()
+
+    def _stood_still(self, phase: _Phase, dt: float, now: float) -> None:
+        """A host phase went ``dt`` (over the limit) without a transition:
+        a stall and its record, unless it built a program meanwhile (a
+        first call of a shape compiles inside ``engine/prefill_launch`` or
+        ``engine/decode_launch``: one INFO line with the programs' names,
+        nothing booked)."""
+        built = _programs_built()
+        if built != self._programs_seen:
+            n = int(built - self._programs_seen)
+            self._logger.info(
+                "stepper: %.3f s in %s%s, %d program(s) compiled or fetched "
+                "meanwhile: %s", dt, phase.name, self._doing_note(), n,
+                ", ".join(f"{name} ({how}, {s:.3f} s)" for name, s, how
+                          in startup.recent_programs(n)))
+            self._programs_seen = built
+            return
+        phase.stalls += 1
+        phase.stall_seconds += dt
+        pause0, count0 = self._mark_gc
+        pause, count = gc_totals()
+        about = self.describe() if self.describe is not None else {}
+        self._tracer.instant("server/stall", cat="server", phase=phase.name,
+                             seconds=round(dt, 6))
+        # (The CPU clocks are read at one step's entry in
+        # STEPPER_CPU_MARK_EVERY, so the deltas are over the stretch the
+        # line names: the stall and at most that many steps before it.)
+        self._logger.warning(
+            "stepper stalled: %.3f s in %s without a transition; in the "
+            "%.3f s since the last CPU mark: stepper cpu %.3f s, process "
+            "cpu %.3f s, gc pause %.3f s in %s collections (generations 0, "
+            "1, 2); live slots %s, waiting %s",
+            dt, phase.name, now - self._mark_wall,
+            self._cpu_clock() - self._mark_cpu,
+            time.process_time() - self._mark_process,
+            sum(pause) - sum(pause0),
+            [b - a for a, b in zip(count0, count)],
+            about.get("live_slots", "?"), about.get("waiting", "?"))
+
+    # -- reads ----------------------------------------------------------
+    def wall(self) -> float:
+        """The owner's wall time from the start to its last transition:
+        what the phases' seconds sum to."""
+        return self.last - self.start
+
+    def seconds(self) -> Dict[str, float]:
+        return {p.name: p.seconds for p in list(self._phases.values())}
+
+    def entries(self) -> Dict[str, int]:
+        return {p.name: p.entries for p in list(self._phases.values())
+                if p.entries}
+
+    def stalls(self) -> Dict[str, int]:
+        """By host phase entered so far (0 in a quiet run: the family is
+        on ``/metrics`` before the first stall)."""
+        return {p.name: p.stalls for p in list(self._phases.values())
+                if p.host and p.entries}
+
+    def stall_seconds(self) -> float:
+        return sum(p.stall_seconds for p in list(self._phases.values()))
+
+    def metrics(self) -> tuple:
+        """The series of :data:`STEPPER_METRIC_NAMES`, read from the books
+        when scraped (``telemetry.registry.ReadCounter``)."""
+        n = STEPPER_METRIC_NAMES
+        mark = f"as of the last marked step's entry (one step in " \
+               f"{STEPPER_CPU_MARK_EVERY})"
+
+        def phases():
+            return list(self._phases.values())
+
+        return (
+            ReadCounter(n[0], lambda: {(p.name, p.kind): p.seconds
+                                       for p in phases()},
+                        label=("phase", "kind"),
+                        help="wall seconds of the stepper thread by its "
+                             "innermost open phase; the phases sum to the "
+                             "thread's wall time (kind: host work, a wait "
+                             "for work, a wait for the device)"),
+            ReadCounter(n[1], lambda: {(p.name, p.kind): p.entries
+                                       for p in phases() if p.entries},
+                        label=("phase", "kind"),
+                        help="entries into each phase of the stepper"),
+            ReadCounter(n[2], lambda: {"": self.cpu_seconds},
+                        help=f"CPU seconds of the stepper thread, {mark}"),
+            ReadCounter(n[3], lambda: {"": self.device_wait_cpu_seconds},
+                        help="of them, inside the waits for the device: "
+                             "those of the marked steps, times "
+                             f"{STEPPER_CPU_MARK_EVERY}"),
+            ReadCounter(n[4], lambda: {"": self.marked_host_seconds},
+                        help="wall seconds of the stepper's host phases, "
+                             f"{mark}"),
+            ReadCounter(n[5], lambda: {"": self.marked_decode_steps},
+                        help=f"decode steps of the engine stepped, {mark}"),
+            ReadCounter(n[6], self.stalls, label="phase",
+                        help=f"host phases that went {STEPPER_STALL_S} s "
+                             f"without a transition (and built no program "
+                             f"meanwhile)"),
+            ReadCounter(n[7], lambda: {"": self.stall_seconds()},
+                        help="wall seconds of those stalls"),
+        )
+
+
+class NullStepperAccount:
+    """An account that books nothing and opens no span: the engine's step
+    without the clock, for the test that the clock changes no output and
+    for the measurement of what it costs."""
+
+    last = 0.0
+    describe = steps_done = doing = None
+
+    def phase(self, name: str, cat: str = "server", kind: str = HOST,
+              step: bool = False):
+        return _NULL_SPAN
+
+    def bind(self) -> None:
+        pass
+
+    def mine(self) -> bool:
+        return False
+
+    def metrics(self) -> tuple:
+        return ()
+
+
+# ----------------------------------------------------------------------
+# The collector's pauses
+# ----------------------------------------------------------------------
+
+class _GcBook:
+    """Pause seconds and collections by generation, written by the
+    ``gc.callbacks`` hook on whichever thread ran the collection (one
+    collection runs at a time) and read as plain lists.
+
+    The hook takes no lock and calls nothing that does: a collection starts
+    at any bytecode boundary of any thread, also inside the tracer's
+    ``_append`` with the ring's lock held, and a hook that appended its span
+    there would wait for its own thread (it did, in one ring-on run in nine:
+    my chip runs, PR 43). So a collection's span is parked here and goes to
+    the ring from :meth:`flush`, which the stepper's account calls at a
+    marked step and :func:`remove_gc_hook` at the end."""
+
+    def __init__(self):
+        self.pause = [0.0, 0.0, 0.0]
+        self.count = [0, 0, 0]
+        self.t0 = 0.0
+        self.tracer = None
+        self.installed = False   # ever: the series then stand at 0 or more
+        self.parked: collections.deque = collections.deque(maxlen=1024)
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self.t0 = time.monotonic()
+            return
+        now = time.monotonic()
+        gen = info["generation"]
+        self.pause[gen] += now - self.t0
+        self.count[gen] += 1
+        if self.tracer is not None and self.tracer.enabled:
+            self.parked.append((self.t0, now, _thread_id(), gen,
+                                info.get("collected", 0)))
+
+    def flush(self) -> None:
+        """The parked collections into the tracer's ring, as ``gc/collect``
+        spans on the threads that ran them. Called where no ring lock is
+        held."""
+        tracer = self.tracer
+        while self.parked and tracer is not None:
+            t0, t1, tid, gen, collected = self.parked.popleft()
+            tracer.complete("gc/collect", t0, t1, cat="gc", tid=tid,
+                            generation=gen, collected=collected)
+
+
+_GC_BOOK = _GcBook()
+
+gc_pause_seconds_total = ReadCounter(
+    GC_METRIC_NAMES[0],
+    lambda: dict(enumerate(_GC_BOOK.pause)) if _GC_BOOK.installed else {},
+    label="generation",
+    help="wall seconds inside the cyclic collector, by generation "
+         "(booked while install_gc_hook's hook is in)")
+gc_collections_total = ReadCounter(
+    GC_METRIC_NAMES[1],
+    lambda: dict(enumerate(_GC_BOOK.count)) if _GC_BOOK.installed else {},
+    label="generation", help="collections of the cyclic collector")
+
+
+def gc_totals() -> tuple:
+    """``(pause seconds, collections)`` by generation so far, as tuples."""
+    return tuple(_GC_BOOK.pause), tuple(_GC_BOOK.count)
+
+
+def install_gc_hook(tracer=None) -> None:
+    """Book every collection from now on (idempotent); with ``tracer``, a
+    ``gc/collect`` ring span for each while that tracer is enabled (put
+    into the ring a little later: :class:`_GcBook`). An entry point installs
+    it at start-up and removes it at shutdown."""
+    _GC_BOOK.tracer = tracer
+    _GC_BOOK.installed = True
+    if _GC_BOOK not in gc.callbacks:
+        gc.callbacks.append(_GC_BOOK)
+
+
+def remove_gc_hook() -> None:
+    if _GC_BOOK in gc.callbacks:
+        gc.callbacks.remove(_GC_BOOK)
+    _GC_BOOK.flush()
+    _GC_BOOK.tracer = None
+
+
+# ----------------------------------------------------------------------
 # Serving: per-request critical-path attribution
 # ----------------------------------------------------------------------
 
@@ -424,6 +933,15 @@ def request_breakdown(req, end: Optional[float] = None) -> dict:
         timeline.append(("first_token", max(0.0, first - t0)))
         phases["decode"] = max(0.0, (end - first)
                                - (stall_total - stall_pre))
+        # Of its decode, the wall its engines spent in prefill calls of
+        # other requests while it held a decoding slot (the engine settles
+        # the sum when the request leaves a slot): moved out of "decode",
+        # so the two sum to what "decode" was.
+        held = min(float(getattr(req, "prefill_stall_s", 0.0)),
+                   phases["decode"])
+        if held > 0:
+            phases["decode"] -= held
+            phases["decode_prefill_stall"] = held
     for kind, s in stall.items():
         if s > 0:
             phases[kind] = s

@@ -31,10 +31,10 @@ import zlib
 from typing import Optional
 
 from dlti_tpu.telemetry.ledger import (
-    CriticalPathTracker, note_readmitted, note_requeue,
+    CriticalPathTracker, StepperAccount, note_readmitted, note_requeue,
 )
 from dlti_tpu.telemetry.registry import (
-    Histogram, HOST_PREP_BUCKETS, LATENCY_BUCKETS, TPOT_BUCKETS,
+    Histogram, LATENCY_BUCKETS, TPOT_BUCKETS,
 )
 from dlti_tpu.telemetry.tracer import SpanTracer, get_tracer
 
@@ -68,17 +68,13 @@ class RequestTelemetry:
             "dlti_request_queue_time_seconds", LATENCY_BUCKETS,
             help="time from request arrival to slot admission",
             stats_key="request_queue_time_seconds")
-        # Host-side prep per decode dispatch (batch assembly + state
-        # sync): the term the device-resident decode-state cache holds
-        # flat as max_seqs grows (serving.decode_state).
-        self.host_prep = Histogram(
-            "dlti_decode_host_prep_seconds", HOST_PREP_BUCKETS,
-            help="host-side prep time per decode dispatch "
-                 "(batch assembly + decode-state sync)",
-            stats_key="decode_host_prep_seconds")
+        # The phase clock of the thread that steps the engine(s) this
+        # telemetry serves (telemetry.ledger): the engine's step phases
+        # and the server's loop round them book into one account, always.
+        self.stepper = StepperAccount(self.tracer)
 
     def histograms(self):
-        return (self.ttft, self.tpot, self.queue_time, self.host_prep)
+        return (self.ttft, self.tpot, self.queue_time)
 
     # -- lifecycle hooks (called by the engine) -------------------------
     # Requests flagged ``shadow`` (the deployment controller's mirrored

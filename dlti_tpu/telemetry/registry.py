@@ -37,10 +37,6 @@ LATENCY_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
 # for a request stalled behind compiles or long prefills.
 TPOT_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
                 0.5, 1.0, 2.5, 5.0)
-# Host-side prep work per dispatch (batch assembly, decode-state sync):
-# tens of microseconds when clean, low milliseconds when rebuilding.
-HOST_PREP_BUCKETS = (1e-5, 2.5e-5, 5e-5, 1e-4, 2.5e-4, 5e-4, 0.001,
-                     0.0025, 0.005, 0.01, 0.025, 0.05, 0.1)
 
 
 def _fmt_labels(labels: Tuple[Tuple[str, str], ...]) -> str:
@@ -141,6 +137,43 @@ class Gauge(_Metric):
     @property
     def value(self) -> float:
         return self._default().value
+
+
+class _Read:
+    """A value read from its writer at exposition time."""
+
+    __slots__ = ("value",)
+
+    def __init__(self, value: float):
+        self.value = value
+
+
+class ReadCounter(_Metric):
+    """A counter family whose numbers live with their one writer and are
+    read when asked for: ``fn()`` gives ``{label value: number}`` (or
+    ``{"": number}`` for a series without a label; with a tuple of label
+    names, ``{tuple of values: number}``). For what a hot loop books many
+    times a round (the stepper's phase clock, the collector's pauses): the
+    writer adds to a plain float, no lock and no child lookup, and a scrape
+    copies it."""
+
+    kind = "counter"
+
+    def __init__(self, name: str, fn: Callable[[], dict],
+                 label=None, help: str = ""):
+        super().__init__(name, help)
+        self._fn = fn
+        self._labels = (label,) if isinstance(label, str) else label
+
+    def samples(self) -> List[Tuple[str, str, object]]:
+        names = self._labels or ()
+        out = []
+        for key, value in sorted(self._fn().items()):
+            values = key if len(names) > 1 else (key,)
+            out.append((self.name,
+                        _fmt_labels(tuple(zip(names, map(str, values)))),
+                        _Read(value)))
+        return out
 
 
 class Histogram:
@@ -324,7 +357,7 @@ class MetricsRegistry:
             if isinstance(m, Histogram):
                 if m.stats_key not in out:
                     out[m.stats_key] = m.summary()
-            elif isinstance(m, (Counter, Gauge)):
+            elif isinstance(m, _Metric):
                 for name, labels, child in m.samples():
                     key = name + labels
                     if key not in out:

@@ -16,7 +16,11 @@ itself spent where, so an odd reading can be put down to a phase:
   persistent compilation cache instead. One ``jax.monitoring`` listener
   (:func:`install_compile_listener`) counts them; JAX calls it per
   compilation or fetch, never per step. A count that grows while a server
-  is under load is a shape that start-up did not warm.
+  is under load is a shape that start-up did not warm: once the ``ready``
+  phase has been marked, every program compiled or fetched leaves one INFO
+  line with its name (``compiled after ready: <fun_name>, <seconds> s,
+  compiled | fetched; stepper in <phase>``), and the newest few are kept
+  (:func:`recent_programs`) for the stepper's stall record.
 
 Module-level like the watchdog and flight-recorder counters: the server's
 registry and the trainer's sampler both read these objects.
@@ -24,11 +28,14 @@ registry and the trainer's sampler both read these objects.
 
 from __future__ import annotations
 
+import collections
 import os
 import threading
 import time
+from typing import Callable, Optional
 
 from dlti_tpu.telemetry.registry import Counter, Gauge
+from dlti_tpu.utils.logging import get_logger
 
 STARTUP_PHASES = ("imports", "weights", "kv_pool", "ready")
 
@@ -69,28 +76,57 @@ def process_age_s() -> float:
         return time.monotonic() - _IMPORTED_AT
 
 
+_ready = False
+
+
 def mark_startup(phase: str) -> None:
     """The entry point has passed ``phase``: set its gauge to the process's
-    age now."""
+    age now. From ``ready`` on a program compiled or fetched is a late one
+    and is logged by name."""
+    global _ready
     startup_gauges[phase].set(process_age_s())
+    if phase == "ready":
+        _ready = True
 
 
 _fetched = threading.local()
+# The newest programs compiled or fetched: (name, seconds, "compiled" |
+# "fetched"). Written by the listener on whichever thread compiled.
+_recent: collections.deque = collections.deque(maxlen=8)
+# ``() -> name`` of the serving stepper's open phase, for the late program's
+# line (the stepper's account sets it when its thread is bound).
+stepper_phase: Optional[Callable[[], str]] = None
 
 
-def _on_duration(event: str, seconds: float, **_kw) -> None:
+def recent_programs(n: int) -> list:
+    """The newest ``n`` (at most 8) programs compiled or fetched, oldest
+    first, as ``(name, seconds, "compiled" | "fetched")``."""
+    return list(_recent)[-n:] if n > 0 else []
+
+
+def _on_duration(event: str, seconds: float, fun_name: str = "?",
+                 **_kw) -> None:
     # JAX reports the whole of compile-or-fetch as a compile duration, and a
-    # fetch inside it first: the flag keeps a fetch from counting twice.
+    # fetch inside it first: the flag keeps a fetch from counting twice. The
+    # program's name comes with the compile duration alone.
     if event == _FETCH_EVENT:
         compile_cache_hits_total.inc()
         compile_cache_fetch_seconds_total.inc(seconds)
         _fetched.pending = True
     elif event == _COMPILE_EVENT:
+        how = "compiled"
         if getattr(_fetched, "pending", False):
             _fetched.pending = False
+            how = "fetched"
         else:
             compilations_total.inc()
             compile_seconds_total.inc(seconds)
+        _recent.append((fun_name, seconds, how))
+        if _ready:
+            get_logger().info(
+                "compiled after ready: %s, %.3f s, %s; stepper in %s",
+                fun_name, seconds, how,
+                stepper_phase() if stepper_phase is not None else "-")
 
 
 _installed = False

@@ -19,9 +19,13 @@ Design constraints:
   enters a ``jax.profiler.TraceAnnotation`` of the same name and arguments
   on the thread that runs it, so a profiler capture holds the program's
   phases in its ``/host:CPU`` plane, on one clock with the device's
-  operations (outside a capture an annotation is a flag test). Each ring
-  span also keeps the CPU time its thread used (``cpu_us``): wall less CPU
-  is time the thread waited — for the device, a lock, the interpreter.
+  operations (outside a capture an annotation is a flag test). A span
+  asked for with ``cpu=True`` also keeps the CPU time its thread used
+  (``cpu_us``): wall less CPU is time the thread waited — for the device, a
+  lock, the interpreter. Only where it is asked for: that clock is a system
+  call, 5.5 us a read on the benchmark's host against 0.09 us for the wall
+  clock (my chip run, PR 43), and the stepper's ``server/step`` is the one
+  span whose ``cpu_us`` anything reads.
   ``jax`` is imported by the first enabled span, never by this module.
 * **Bounded memory.** Events land in a ring buffer (``deque(maxlen=...)``);
   a long-lived server keeps the most recent ``capacity`` events and never
@@ -60,11 +64,14 @@ class _Span:
     __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_cpu0",
                  "_annotation")
 
-    def __init__(self, tracer: "SpanTracer", name: str, cat: str, args: dict):
+    def __init__(self, tracer: "SpanTracer", name: str, cat: str, args: dict,
+                 cpu: bool = False):
         self._tracer = tracer
         self._name = name
         self._cat = cat
         self._args = args
+        # None: the site did not ask for the thread's CPU clock.
+        self._cpu0 = 0 if cpu else None
 
     def __enter__(self):
         tracer = self._tracer
@@ -75,16 +82,20 @@ class _Span:
         # Annotation first, so the ring's span lies inside it.
         self._annotation = tracer._annotate(self._name, **self._args)
         self._annotation.__enter__()
-        self._cpu0 = time.thread_time_ns()
+        if self._cpu0 is not None:
+            self._cpu0 = time.thread_time_ns()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         end = time.monotonic()
-        cpu_us = (time.thread_time_ns() - self._cpu0) / 1e3
+        args = self._args
+        if self._cpu0 is not None:
+            args = {**args,
+                    "cpu_us": (time.thread_time_ns() - self._cpu0) / 1e3}
         self._tracer._complete_event(
             self._name, self._t0, end, self._cat,
-            threading.get_ident(), {**self._args, "cpu_us": cpu_us})
+            threading.get_ident(), args)
         self._annotation.__exit__(exc_type, exc, tb)
         return False
 
@@ -118,6 +129,10 @@ class SpanTracer:
         # has imported it.
         self._annotate = None
         self._capture_enabled_ring = False
+        # ``() -> dict`` of arguments for the ``profiler/start`` instant:
+        # the stepper's account gives its open phase and since when (the
+        # span open at that moment began as a no-op and is in no trace).
+        self.capture_context = None
 
     # -- profiler capture -----------------------------------------------
     def start_capture(self, log_dir: str) -> None:
@@ -137,7 +152,9 @@ class SpanTracer:
         jax.profiler.start_trace(log_dir, profiler_options=options)
         self._capture_enabled_ring = not self.enabled
         self.enabled = True
-        self.instant("profiler/start", cat="profiler")
+        self.instant("profiler/start", cat="profiler",
+                     **(self.capture_context() if self.capture_context
+                        else {}))
 
     def stop_capture(self) -> None:
         """End the capture ``start_capture`` began."""
@@ -149,11 +166,12 @@ class SpanTracer:
         jax.profiler.stop_trace()
 
     # -- recording ------------------------------------------------------
-    def span(self, name: str, cat: str = "host", **args):
-        """Context manager timing a host phase. Disabled: a shared no-op."""
+    def span(self, name: str, cat: str = "host", cpu: bool = False, **args):
+        """Context manager timing a host phase. Disabled: a shared no-op.
+        ``cpu``: keep the CPU time the thread used in it as ``cpu_us``."""
         if not self.enabled:
             return _NULL_SPAN
-        return _Span(self, name, cat, args)
+        return _Span(self, name, cat, args, cpu)
 
     def complete(self, name: str, start_s: float, end_s: float,
                  cat: str = "host", tid: Optional[int] = None,

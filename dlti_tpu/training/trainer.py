@@ -1545,7 +1545,11 @@ class Trainer:
                             state, executed = exec_window(state)
                         else:  # max_steps-capped short window
                             state, executed = drain_window(state)
-                    bookkeep(state, executed)
+                    # The step's books and its log line: host time between
+                    # one step's sync and the next batch's fetch (eval and
+                    # saves inside it keep their own spans).
+                    with tracer.span("train/bookkeep", cat="train"):
+                        bookkeep(state, executed)
                     if self._fault is not None:
                         # param-flip chaos: corrupt a replicated leaf in
                         # the LIVE state at the step boundary (rank-gated)

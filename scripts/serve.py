@@ -703,9 +703,16 @@ def main() -> None:
           f"(pool: {args.num_blocks} blocks x {args.block_size} tokens)")
     print(f"live dashboard: http://{args.host}:{args.port}/dashboard  "
           f"(JSON: /debug/vars; profiler: POST /debug/profile)")
+    # The collector's pauses, booked from here to shutdown
+    # (dlti_gc_pause_seconds_total{generation=}; a gc/collect span while the
+    # tracer is on): a stall of the stepper is held against them.
+    from dlti_tpu.telemetry import get_tracer, install_gc_hook, remove_gc_hook
+
+    install_gc_hook(get_tracer())
     try:
         serve(engine, tok, sc, deploy=deploy)
     finally:
+        remove_gc_hook()
         if args.fleet_workers > 0:
             engine.close()  # FT_SHUTDOWN + terminate/kill ladder
         if args.disagg:

@@ -140,8 +140,32 @@ def test_host_overlap_metric_names_are_schema_stable():
         "dlti_request_ttft_seconds",
         "dlti_request_tpot_seconds",
         "dlti_request_queue_time_seconds",
-        "dlti_decode_host_prep_seconds",
     ]
+    # The decode round's host path (its prep among nine phases) is the
+    # stepper's phase clock: seconds and entries by phase, the thread's
+    # CPU, every stall, the collector's pauses, the handlers' CPU.
+    from dlti_tpu.serving import server
+    from dlti_tpu.telemetry import ledger
+
+    assert ledger.STEPPER_METRIC_NAMES == (
+        "dlti_stepper_phase_seconds_total",
+        "dlti_stepper_phase_entries_total",
+        "dlti_stepper_cpu_seconds_total",
+        "dlti_stepper_device_wait_cpu_seconds_total",
+        "dlti_stepper_marked_host_seconds_total",
+        "dlti_stepper_marked_decode_steps_total",
+        "dlti_stepper_stalls_total",
+        "dlti_stepper_stall_seconds_total",
+    )
+    assert [m.name for m in tel.stepper.metrics()] == list(
+        ledger.STEPPER_METRIC_NAMES)
+    assert ledger.GC_METRIC_NAMES == (
+        "dlti_gc_pause_seconds_total", "dlti_gc_collections_total")
+    assert (ledger.gc_pause_seconds_total.name,
+            ledger.gc_collections_total.name) == ledger.GC_METRIC_NAMES
+    assert (server.sse_handler_cpu_seconds_total.name,
+            server.sse_events_total.name) == (
+        "dlti_sse_handler_cpu_seconds_total", "dlti_sse_events_total")
 
     # Engine stats keys ride the /metrics scalar source (dlti_ prefix):
     # dlti_decode_state_uploads / _rows / _clean_syncs.
@@ -455,7 +479,8 @@ def test_ledger_metric_names_are_schema_stable():
     assert ledger.PRODUCTIVE_BUCKETS == ("step_compute", "device_sync")
     assert ledger.REQUEST_PHASES == (
         "gateway_queue", "queue", "tier_restore", "prefill",
-        "failover", "preempt", "kv_handoff", "decode", "other",
+        "failover", "preempt", "kv_handoff", "decode",
+        "decode_prefill_stall", "other",
     )
 
 

@@ -171,7 +171,7 @@ def test_federator_counts_unparented_and_dropped():
     fed = TraceFederator(capacity=4)
     base_fed = federated_spans_total.value
     base_unp = unparented_spans_total.value
-    spans = [_span("engine/decode_dispatch", i * 100, 10) for i in range(6)]
+    spans = [_span("engine/decode_prep", i * 100, 10) for i in range(6)]
     spans.append(_span("request/prefill", 999, 10, trace="t1"))
     fed.ingest(3, spans, remote_dropped=5)
     assert len(fed) == 4                       # ring bound holds

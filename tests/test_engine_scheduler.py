@@ -31,6 +31,7 @@ class ScriptedExecutor:
 
     counter_names = ()
     prefill_call_tokens = 0
+    prefill_group_tokens = 0
     prefill_whole_tables = False
     adapter_pool = None
     pool_bytes = 0
@@ -480,19 +481,52 @@ def test_a_prompt_past_the_models_call_limit_goes_as_several_calls(
     assert st["prefill_context_tokens"] == 8 + 16
 
 
+@pytest.mark.parametrize("limits", [
+    {"prefill_call_tokens": 32}, {"prefill_group_tokens": 32},
+    {"prefill_call_tokens": 32, "prefill_group_tokens": 64},
+    {"prefill_call_tokens": 64, "prefill_group_tokens": 32}],
+    ids=["model", "device", "model_under_device", "device_under_model"])
 def test_an_admission_wave_past_the_call_limit_goes_as_calls_that_fit(
-        monkeypatch):
+        limits, monkeypatch):
     """Rows of one prompt bucket waiting together go out as many rows a call
-    as the model's limit holds of that bucket, never as one call over it."""
-    monkeypatch.setattr(ScriptedExecutor, "prefill_call_tokens", 32)
+    as the model's limit (``prefill_call_tokens``) or the device's (what a
+    call's logits may take of it: ``prefill_group_tokens``) holds of that
+    bucket, the smaller of the two, never as one call over it: a call that
+    cannot fit costs ten seconds of compiler before it is refused."""
+    for name, n in limits.items():
+        monkeypatch.setattr(ScriptedExecutor, name, n)
     eng = _engine(max_seqs=8, max_model_len=48, num_blocks=64)
+    eng.executor.refuses = lambda shape: shape[0] * shape[1] > 32
     prompts = [list(range(100 + 20 * i, 109 + 20 * i)) for i in range(5)]
     reqs = [eng.submit(p, SamplingParams(max_tokens=2)) for p in prompts]
     _drain(eng)
     calls = eng.executor.of("prefill")
     assert [c["input_ids"].shape for c in calls] == [(2, 16), (2, 16), (1, 16)]
+    assert not eng.executor.of("refused")
+    assert eng.stats["prefill_calls_split"] == 0
     assert [r.output_token_ids for r in reqs] == [_stream(p, 2)
                                                   for p in prompts]
+    # (a row alone always goes, whatever its bucket)
+    assert [eng._prefill_rows(b) for b in (4, 8, 16, 32, 64)] == \
+        [8, 4, 2, 1, 1]
+
+
+@pytest.mark.parametrize("vocab,rows", [
+    (152064, {512: 8, 1024: 4, 2048: 2, 4096: 1}),   # qwen2_7b
+    (32000, {512: 8, 1024: 8, 2048: 8, 4096: 4})],   # mistral_7b
+    ids=["qwen2_7b", "mistral_7b"])
+def test_the_devices_limit_is_what_the_benchmarks_cells_warm(vocab, rows,
+                                                             monkeypatch):
+    """On a v5e (15.75 GiB) the logits' share holds a call of several rows
+    of qwen2_7b to the shapes ``serve.qwen2_7b.batch`` warms (8 x 1,024 and
+    4 x 2,048 are the ones the compiler refuses there) and leaves
+    mistral_7b's 8 x 2,048 alone; an unknown device (the CPU) sets none."""
+    from dlti_tpu.serving.executor import prefill_group_tokens
+    assert prefill_group_tokens(vocab, 0) == 0
+    monkeypatch.setattr(ScriptedExecutor, "prefill_group_tokens",
+                        prefill_group_tokens(vocab, 15.75 * 2**30))
+    eng = _engine()
+    assert {b: eng._prefill_rows(b) for b in rows} == rows
 
 
 # -- prefix caching: a prompt's blocks are matchable once prefilled ----------

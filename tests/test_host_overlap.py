@@ -361,9 +361,10 @@ def test_clean_decode_step_issues_zero_uploads(tiny_params):
     assert eng.stats["decode_steps"] == steps_before + 6
     assert eng.stats["decode_state_uploads"] == settled  # ZERO new uploads
     assert eng.stats["decode_state_clean_syncs"] >= clean_before + 6
-    # Host-prep histogram observed every dispatch.
-    _, _, n = eng.telemetry.host_prep.snapshot()
-    assert n >= 7
+    # The account's decode_prep phase entered every dispatch, and booked.
+    acct = eng.telemetry.stepper
+    assert acct.entries()["engine/decode_prep"] >= 7
+    assert acct.seconds()["engine/decode_prep"] > 0
 
 
 def test_warm_up_runs_the_row_updater_at_every_padded_count(tiny_params):

@@ -1,8 +1,10 @@
 """Host spans on the profiler's clock, and the counters beside them.
 
 * Off path: with the tracer disabled an engine step, a server loop and a
-  trainer step make no ``TraceAnnotation`` and read no thread CPU clock, and
-  importing the tracer imports no jax.
+  trainer step make no ``TraceAnnotation`` and no span reads the thread's
+  CPU clock (``thread_time_ns``; the stepper's always-on account reads
+  ``thread_time`` a fixed few times a step: tests/test_stepper_account.py),
+  and importing the tracer imports no jax.
 * On path: the engine's, server's and trainer's spans nest as
   ``COMPONENTS.md`` ("Host spans") says, on the speculative path too, and a
   profiler capture holds them in its host plane around the device's work.
@@ -37,10 +39,9 @@ CYCLIC = [6, 6, 7, 7, 6, 6, 7, 7]  # generation loops: n-gram drafts hit
 
 # child -> the span it lies directly inside, on the same thread
 PARENTS = {
-    "engine/decode_prep": "engine/decode_dispatch",
-    "engine/decode_launch": "engine/decode_dispatch",
-    "engine/decode_wait": "engine/decode_sync",
-    "engine/decode_emit": "engine/decode_sync",
+    "engine/decode_plan": "engine/decode_prep",
+    "engine/decode_assemble": "engine/decode_prep",
+    "engine/decode_stage": "engine/decode_prep",
     "engine/prefill_group": "engine/admit",
     "engine/prefill_launch": "engine/prefill_group",
     "engine/prefill_wait": "engine/prefill_group",
@@ -135,13 +136,19 @@ def test_disabled_tracer_costs_an_engine_step_no_annotation_and_no_cpu_clock(
     assert (annotation.calls, cpu_clock.calls) == (0, 0)
     assert len(get_tracer()) == 0
     # the stand-ins do see an enabled tracer: the zeros above mean something
+    # (and of an enabled tracer's spans only one whose site asks reads the
+    # CPU clock, at its two ends: no span of the engine's does)
     configure_tracer(enabled=True)
     try:
         eng.generate([[5, 6, 7]], GREEDY)
+        asked = annotation.calls
+        with get_tracer().span("server/step", cat="server", cpu=True):
+            pass
     finally:
         configure_tracer(enabled=False)
         get_tracer().clear()
-    assert annotation.calls > 0 and cpu_clock.calls == 2 * annotation.calls
+    assert asked > 0 and annotation.calls == asked + 1
+    assert cpu_clock.calls == 2
 
 
 def test_disabled_tracer_costs_the_server_loop_no_annotation_and_no_cpu_clock(
@@ -190,7 +197,8 @@ def _assert_nested(events, parents):
                        and _inside(kid, e) and _inside(e, mine[0])
                        and (e["ts"], e["dur"]) != (kid["ts"], kid["dur"])]
             assert not between, (child, [e["name"] for e in between])
-            assert 0 <= kid["args"]["cpu_us"] <= kid["dur"] + 1e3
+            # an engine span's site does not ask for the thread's CPU clock
+            assert "cpu_us" not in kid.get("args", {})
 
 
 @pytest.mark.parametrize("speculative", ["none", "ngram"])
@@ -237,11 +245,17 @@ def test_server_spans_cover_the_stepper_loop(tiny_params, tracer):
     steps = [e for e in events if e["name"] == "server/step"]
     stepper = {e["tid"] for e in steps}
     assert len(stepper) == 1
+    # the step's span alone keeps the CPU its thread used (stepper_cpu_share)
+    for e in events:
+        if e["name"] == "server/step":
+            assert 0 <= e["args"]["cpu_us"] <= e["dur"] + 1e3
+        else:
+            assert "cpu_us" not in e.get("args", {}), e["name"]
     for e in events:
         if e["name"].startswith("engine/"):
             assert e["tid"] in stepper
-            if e["name"] in ("engine/admit", "engine/decode_dispatch",
-                             "engine/decode_sync"):
+            if e["name"] in ("engine/admit", "engine/decode_prep",
+                             "engine/decode_wait"):
                 assert sum(_inside(e, s) for s in steps) == 1, e["name"]
     # the loop's own spans follow one another, never overlap
     loop = sorted((e for e in events if e["name"].startswith("server/")),

@@ -1,5 +1,7 @@
 """Static guard: every ``tracer.span/complete/instant`` call-site name in
-the package is pinned here.
+the package is pinned here, and every ``account.phase`` name with them (a
+phase of the stepper's clock is its span of the same name while the tracer
+is enabled: ``telemetry.ledger.StepperAccount``).
 
 The goodput ledger and the critical-path attribution parse span names
 ("train/*" phases, "engine/*" step phases, "request/*" lifecycle,
@@ -35,10 +37,9 @@ SPAN_NAME_CATALOG = frozenset({
     "train/sdc_probe",
     "train/sentinel_rollback",
     "train/prefetch",
+    "train/bookkeep",
     # Engine step phases + the prefix-tier restore charge.
     "engine/admit",
-    "engine/decode_dispatch",
-    "engine/decode_sync",
     "engine/adapter_load",
     "engine/kv_handoff",
     "engine/prefill_chunks",
@@ -46,6 +47,9 @@ SPAN_NAME_CATALOG = frozenset({
     # The children of the step phases: where the host's time between two
     # device programs goes (benchmark/lib/span_rules.json reads them).
     "engine/decode_prep",
+    "engine/decode_plan",
+    "engine/decode_assemble",
+    "engine/decode_stage",
     "engine/decode_launch",
     "engine/decode_wait",
     "engine/decode_emit",
@@ -57,6 +61,10 @@ SPAN_NAME_CATALOG = frozenset({
     "server/step",
     "server/lock_wait",
     "server/drain_events",
+    # A host phase of the stepper that stood still (telemetry.ledger).
+    "server/stall",
+    # A collection of the cyclic collector (telemetry.ledger's gc hook).
+    "gc/collect",
     # Marks that cut the ring to a profiler capture (telemetry.tracer).
     "profiler/start",
     "profiler/stop",
@@ -76,7 +84,7 @@ SPAN_NAME_CATALOG = frozenset({
     "watchdog/alert",
 })
 
-_TRACER_METHODS = ("span", "complete", "instant")
+_TRACER_METHODS = ("span", "complete", "instant", "phase")
 
 # Call sites whose first argument is not a string literal, allowed ONLY
 # because their name is a literal *default* elsewhere (asserted below):
@@ -85,12 +93,16 @@ _DYNAMIC_ALLOWED = {
     # HostPrefetcher worker span: self._tracer.span(self._span_name, ...)
     # with span_name="train/prefetch" in the constructor signature.
     os.path.join("data", "prefetch.py"),
+    # The stepper account's one helper: a phase opens the tracer's span
+    # under the phase's own name, and that name is a literal at every
+    # ``phase(...)`` call site, which this walk pins like a span's.
+    os.path.join("telemetry", "ledger.py"),
 }
 
 
 def _walk_calls():
     """Yield (relpath, lineno, first_arg_node) for every
-    ``<obj>.span|complete|instant(...)`` call in the package."""
+    ``<obj>.span|complete|instant|phase(...)`` call in the package."""
     for root, _dirs, files in os.walk(PKG):
         if "__pycache__" in root:
             continue
@@ -105,8 +117,12 @@ def _walk_calls():
                 if not isinstance(node, ast.Call):
                     continue
                 func = node.func
+                # (``phase`` is also called through a local alias of the
+                # account's bound method on the stepper's path)
                 if not (isinstance(func, ast.Attribute)
-                        and func.attr in _TRACER_METHODS):
+                        and func.attr in _TRACER_METHODS
+                        or isinstance(func, ast.Name)
+                        and func.id == "phase"):
                     continue
                 if not node.args:
                     continue
@@ -165,7 +181,7 @@ def test_span_names_follow_plane_slash_phase_convention():
         plane, _, phase = name.partition("/")
         assert plane and phase, name
         assert plane in ("train", "engine", "request", "gateway",
-                         "watchdog", "server", "profiler"), name
+                         "watchdog", "server", "profiler", "gc"), name
         assert phase == phase.lower().replace("-", "_"), name
 
 

@@ -252,9 +252,9 @@ def test_request_lifecycle_span_ordering(traced_engine):
 def test_engine_step_phase_spans_present(traced_engine):
     _, _, events = traced_engine
     names = {e["name"] for e in events}
-    assert "engine/decode_dispatch" in names
+    assert "engine/decode_prep" in names
     assert "engine/admit" in names
-    assert "engine/decode_sync" in names
+    assert "engine/decode_wait" in names
 
 
 def test_lifecycle_histograms_observed(traced_engine):
