@@ -178,7 +178,7 @@ class EngineConfig:
     # agree; enable with a window sized for your traffic).
     guard_token_storm: int = 0
     # Memory ledger (telemetry.memledger): per-owner HBM attribution
-    # (params / kv_block_pool / prefix_cache_hbm / decode_state_cache),
+    # (params / kv_block_pool / prefix_cache_hbm / lora_adapters),
     # feeding /debug/memory, the hbm_* metric gauges, and memory.json in
     # engine flight dumps.
     memory_ledger: bool = True
@@ -509,11 +509,11 @@ class InferenceEngine:
                       "prefill_calls_failed": 0,
                       "spec_proposed": 0, "spec_accepted": 0,
                       "spec_paused_rounds": 0,
-                      # Resident decode state (decode_state.py), booked
-                      # by the executor: upload syncs / rows shipped /
-                      # clean (zero-upload) syncs.
-                      "decode_state_uploads": 0, "decode_state_rows": 0,
-                      "decode_state_clean_syncs": 0,
+                      # What decode rounds cost the host, booked by the
+                      # executor: arrays staged host-to-device for them and
+                      # program calls made for them. Each over the rounds
+                      # launched reads 1.0 for plain rounds.
+                      "decode_host_uploads": 0, "decode_program_calls": 0,
                       # Numeric-guard trips (nonfinite decode outputs /
                       # token storms). Present (at 0) so the /metrics
                       # schema is stable.
@@ -692,8 +692,7 @@ class InferenceEngine:
 
     def warmup_decode_ladder(self) -> None:
         """Pre-compile the decode programs ahead of traffic."""
-        self.executor.warmup_decode_ladder(self._state_mirrors(),
-                                           self._masked_rows())
+        self.executor.warmup_decode_ladder()
 
     def _bucket_for(self, n: int) -> int:
         for b in self.cfg.buckets():
@@ -902,9 +901,6 @@ class InferenceEngine:
             self.executor.fetch(inflight[-1])
         except Exception:  # noqa: BLE001 — the round of a faulted engine
             self.logger.exception("the dropped decode round did not finish")
-        for slot, _req in inflight[1]:
-            # The device's count of its rows is one ahead of the mirrors'.
-            self.executor.mark_dirty(slot.slot_id)
 
     # ------------------------------------------------------------------
     # Scheduling internals
@@ -1180,7 +1176,6 @@ class InferenceEngine:
         self._adapter_ids[slot.slot_id] = max(req._adapter_slot, 0)
         # Not a decode row until its prefill has handed the slot a state.
         self._state_slots[slot.slot_id] = self.cfg.max_seqs
-        self.executor.mark_dirty(slot.slot_id)
         if self._spec_hist is not None:
             ctx = req.prompt_token_ids + req.output_token_ids
             self._spec_hist[slot.slot_id, :len(ctx)] = ctx
@@ -1308,11 +1303,6 @@ class InferenceEngine:
                     self._state_slots[slot.slot_id] = slot.slot_id
                     self._publish_prompt_blocks(slot)
                     slot.request._prefill_stall_mark = self._prefill_wall_s
-                # Prefill completion: the first sampled token bumped the
-                # slot's gen count, and a chunked-mode slot's block-table
-                # row sheds its trash-block masking — either way the row
-                # must re-upload before the slot joins the decode batch.
-                self.executor.mark_dirty(slot.slot_id)
 
     def _publish_prompt_blocks(self, slot: _Slot) -> None:
         """Prefix caching: the whole blocks a prefill has just written become
@@ -1469,8 +1459,7 @@ class InferenceEngine:
         Behind a round still in flight (``ahead_of``) the plan is made from
         what the host knows without that round's tokens. A slot that rides
         in it stands one token further than the host has seen: its
-        position, its block growth and, where its row is uploaded again,
-        its gen count are those of this round's launch, and its input id is
+        position, its block growth and its gen count are those of this round's launch, and its input id is
         ``RIDES``. A slot whose request ends with the token in flight is
         left out and reads as a free slot does (:meth:`_clear_row`). None
         also when such a plan cannot be made: a speculative round, a
@@ -1479,7 +1468,7 @@ class InferenceEngine:
 
         Three phases inside the caller's ``engine/decode_prep``: the plan
         (:meth:`_decode_plan`), the assembly of the round's host arrays,
-        and the executor's staging of them (the dirty rows' upload)."""
+        and the executor's staging of them (one packed upload)."""
         ec = self.cfg
         phase = self._phase
         with phase("engine/decode_plan", "engine"):
@@ -1502,16 +1491,16 @@ class InferenceEngine:
                 k_steps * self._sampling_sorts()
             mirrors = self._state_mirrors()
             if riding:
-                # A row uploaded again is uploaded as of this round's
-                # launch: the device has counted the token in flight, the
-                # mirror has not (a row drawn with the count before would
+                # Every row goes up as of this round's launch: a riding
+                # row's token in flight is drawn and not yet counted by
+                # the mirror (a row drawn with the count before would
                 # repeat a draw).
                 mirrors["gen_counts"] = self._gen_counts.copy()
                 mirrors["gen_counts"][[s.slot_id for s in active
                                        if s.slot_id in riding]] += 1
             masked = self._masked_rows()
-        # Device-resident per-slot state: only rows dirtied since the
-        # last dispatch are shipped; a clean step uploads nothing.
+        # The round's tokens, positions and every per-slot row go up as
+        # one packed array, which the decode program unpacks itself.
         with phase("engine/decode_stage", "engine"):
             staged = self.executor.stage_decode(ids, pos, mirrors, masked)
         return ("plain", [(s, s.request) for s in active], k_steps, staged)
@@ -1559,7 +1548,6 @@ class InferenceEngine:
                     slot.blocks.extend(got)
                     self._block_tables[
                         slot.slot_id, len(slot.blocks) - 1] = got[0]
-                    self.executor.mark_dirty(slot.slot_id)
             return True
 
         if ahead_of is not None:
@@ -2011,7 +1999,6 @@ class InferenceEngine:
         self._gen_counts[slot_id] = 0
         self._adapter_ids[slot_id] = 0
         self._state_slots[slot_id] = self.cfg.max_seqs
-        self.executor.mark_dirty(slot_id)
 
     # ------------------------------------------------------------------
     # Disaggregated prefill/decode handoff (serving/disagg.py)
@@ -2122,7 +2109,6 @@ class InferenceEngine:
         self._slot_keys[slot.slot_id] = snap["slot_key"]
         self._gen_counts[slot.slot_id] = snap["gen_count"]
         self._adapter_ids[slot.slot_id] = max(req._adapter_slot, 0)
-        self.executor.mark_dirty(slot.slot_id)
         if self._spec_hist is not None:
             ctx = req.prompt_token_ids + req.output_token_ids
             self._spec_hist[slot.slot_id, : len(ctx)] = ctx

@@ -23,7 +23,7 @@ from dlti_tpu.config import LoRAConfig, ModelConfig
 from dlti_tpu.models import build_model
 from dlti_tpu.ops.kv_cache import bind_call, init_cache, unbind_call
 from dlti_tpu.ops.pallas.paged_attention import tile_tokens
-from dlti_tpu.serving.decode_state import DecodeStateCache
+from dlti_tpu.serving.decode_state import RoundPacking
 from dlti_tpu.serving.sampling import sample_tokens
 from dlti_tpu.telemetry.memledger import MemoryLedger, tree_nbytes
 from dlti_tpu.utils.logging import get_logger
@@ -143,7 +143,8 @@ def refuse_unsupported(model_cfg: ModelConfig, engine_cfg: "EngineConfig",
 
 class EngineExecutor:
     """The device half of the engine: weights, paged-KV pools, the
-    per-slot decode state (:class:`DecodeStateCache`), the adapter pool,
+    packing of a decode round's host inputs (:class:`RoundPacking`), the
+    adapter pool,
     and every compiled program (bucketed prefill, the decode ladder,
     speculative decode, fused sampling, the tier-restore scatter) with
     its calling convention, plus the device<->host block transport
@@ -398,18 +399,32 @@ class EngineExecutor:
         # multi-LoRA pool, the pool's tree.
         self._row_extra = ("adapter_ids" if self.adapter_pool is not None
                            else "state_slots" if self._recurrent else None)
-        # Device-resident twins of the scheduler's per-slot mirrors,
-        # maintained incrementally (per-slot dirty tracking; clean steps
-        # upload nothing). Touched on the stepper thread alone, like the
-        # mirrors themselves. Its counters are booked in ``stats`` (the
-        # scheduler's dict, where it gives one).
-        self.decode_state = DecodeStateCache(
-            ec.max_seqs, device=self._device, mesh=mesh, stats=stats,
-            extra_fields=(self._row_extra,) if self._row_extra else ())
+        # A plain decode round's host inputs (tokens, positions, every
+        # per-slot mirror) go up as one packed int32 array that the decode
+        # programs unpack themselves: one transfer and one program call a
+        # round, nothing per-slot resident between rounds.
+        self.round_packing = RoundPacking(
+            ec.max_seqs, ec.max_blocks_per_seq, self._row_extra)
+        # Where a round goes, committed: this engine's device, or every
+        # chip of the tensor mesh (replicated).
+        self._round_sharding = self._device
+        if mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
+            self._round_sharding = NamedSharding(mesh, P())
+        # What a decode round costs the host, booked in ``stats`` (the
+        # scheduler's dict, where it gives one; on /metrics): arrays
+        # staged host-to-device for decode rounds, and program calls made
+        # for them. Each over the rounds launched reads 1.0 for plain
+        # rounds (a speculative round ships its mirrors one by one).
+        self.stats = stats if stats is not None else {}
+        for k in ("decode_host_uploads", "decode_program_calls"):
+            self.stats.setdefault(k, 0)
         # What the one-step decode program takes for "the round before"
         # when there is none: nothing rides, so nothing reads it.
-        self._no_prev = self.decode_state.place(np.zeros(
-            (ec.max_seqs + len(self.counter_names),), np.int32))
+        self._no_prev = jax.device_put(
+            np.zeros((ec.max_seqs + len(self.counter_names),), np.int32),
+            self._round_sharding)
 
     # ------------------------------------------------------------------
     def _shard_for_tp(self, mesh) -> None:
@@ -544,23 +559,23 @@ class EngineExecutor:
         S = self.cfg.max_seqs
 
         @partial(jax.jit, donate_argnums=(1,))
-        def decode(params, cache_kv, prev_tokens, input_ids, positions,
-                   block_tables, slot_keys, gen_counts, temperature, top_k,
-                   top_p, *lora):
-            # input_ids/positions: (S, 1); block_tables: (S, max_blocks).
-            # *lora: (adapter_ids, adapters) when the multi-LoRA pool is
-            # on (adapter_ids rides in decode-state argument order, the
-            # pool tree LAST so state threading stays contiguous).
+        def decode(params, cache_kv, prev_tokens, packed, *pool):
+            # packed: the round as the host packed it (RoundPacking):
+            # input_ids/positions (S, 1), block_tables (S, max_blocks), the
+            # sampling state, and the one extra per-slot row where the
+            # programs take one. *pool: the multi-LoRA pool's tree, LAST.
             # prev_tokens: the token output of the round before, as that
             # call returned it (the model's counter rows after the slots',
             # cut off here). A row whose host id is negative (RIDES) takes
             # its input from there: the round before need not have reached
             # the host when this one is launched.
+            (input_ids, positions, block_tables, slot_keys, gen_counts,
+             temperature, top_k, top_p, *lora) = self.round_packing.unpack(packed)
             input_ids = jnp.where(input_ids < 0, prev_tokens[:S, None],
                                   input_ids)
             logits, new_kv, counters = self._model_cache_call(
                 params, cache_kv, block_tables, input_ids, positions,
-                **self._named(lora), own_rows=True)
+                **self._named((*lora, *pool)), own_rows=True)
             rngs = jax.vmap(jax.random.fold_in)(slot_keys, gen_counts)
             tokens, logprobs = sample_tokens(
                 logits[:, 0, :], rngs, temperature, top_k, top_p
@@ -624,9 +639,11 @@ class EngineExecutor:
         request regardless of steps_per_sync.
         """
         @partial(jax.jit, donate_argnums=(1,))
-        def decode_multi(params, cache_kv, input_ids, positions, block_tables,
-                         slot_keys, gen_counts, temperature, top_k, top_p,
-                         *lora):
+        def decode_multi(params, cache_kv, packed, *pool):
+            (input_ids, positions, block_tables, slot_keys, gen_counts,
+             temperature, top_k, top_p, *lora) = self.round_packing.unpack(packed)
+            lora = (*lora, *pool)
+
             def body(carry, _):
                 cache, tok, pos, cnt = carry
                 logits, new_kv, counters = self._model_cache_call(
@@ -840,16 +857,18 @@ class EngineExecutor:
     def stage_decode(self, input_ids: np.ndarray, positions: np.ndarray,
                      mirrors: Dict[str, np.ndarray],
                      masked_rows: Sequence[int]) -> tuple:
-        """Upload a plain decode round: the ``(S, 1)`` tokens (``RIDES``
-        where a row's token is the one the round before sampled, which the
-        program reads on the device) and positions, and of the per-slot
-        ``mirrors`` only the rows dirtied since the last round, as of THIS
-        round's launch (``gen_counts`` of a riding row counts the token
-        still in flight). ``masked_rows``: slots still prefilling, whose
-        block tables must read as the trash block. What
-        :meth:`launch_decode` takes."""
-        return (jnp.asarray(input_ids), jnp.asarray(positions),
-                *self.decode_state.sync(mirrors, masked_rows),
+        """Upload a plain decode round, whole, as ONE array: the ``(S, 1)``
+        tokens (``RIDES`` where a row's token is the one the round before
+        sampled, which the program reads on the device) and positions, and
+        every row of the per-slot ``mirrors`` as of THIS round's launch
+        (``gen_counts`` of a riding row counts the token still in flight),
+        packed (:class:`RoundPacking`) and placed with one transfer.
+        ``masked_rows``: slots still prefilling, whose block tables must
+        read as the trash block. What :meth:`launch_decode` takes."""
+        packed = self.round_packing.pack(
+            input_ids, positions, mirrors, masked_rows)
+        self.stats["decode_host_uploads"] += 1
+        return (jax.device_put(packed, self._round_sharding),
                 *self._pool_tree())
 
     def launch_decode(self, staged: tuple, k_steps: int, prev=None):
@@ -857,8 +876,9 @@ class EngineExecutor:
         ``(S, k_steps)`` each, or ``(S,)`` from the one-step program (the
         model's counters as rows after the slots'). ``prev``: what the
         launch of the one-step round before returned, fetched or not; the
-        rows staged as ``RIDES`` read their token from it. Nothing is
-        dispatched between two one-step rounds but the count bump below."""
+        rows staged as ``RIDES`` read their token from it. The one program
+        call of the round."""
+        self.stats["decode_program_calls"] += 1
         if k_steps > 1:
             self.cache, tokens, logprobs = self._multi_decode_fn(k_steps)(
                 self.params, self.cache, *staged)
@@ -866,36 +886,33 @@ class EngineExecutor:
             self.cache, tokens, logprobs = self._decode_fn(
                 self.params, self.cache,
                 self._no_prev if prev is None else prev[0], *staged)
-        # The window advances every surviving slot's gen count by exactly
-        # k_steps (a slot finishing mid-window is released, which marks it
-        # dirty) — advance the resident counts on device instead of
-        # re-uploading the one every-step mirror.
-        self.decode_state.bump_gen_counts(k_steps)
         return tokens, logprobs
 
     def stage_spec(self, hist: np.ndarray, t_in: np.ndarray,
                    seq_len: np.ndarray, spec_mask: np.ndarray,
                    mirrors: Dict[str, np.ndarray],
                    masked_rows: Sequence[int], table_width: int) -> tuple:
-        """Upload a speculative round. It ships the mirrors whole (it
-        uploads the full token history anyway) and emits a variable number
-        of tokens a slot, so the resident decode state is stale wholesale
-        after it. What :meth:`launch_spec` takes."""
-        self.decode_state.mark_all_dirty()
+        """Upload a speculative round: the token history and the mirrors,
+        an array each (the history dwarfs the rest). What
+        :meth:`launch_spec` takes."""
         tables = mirrors["block_tables"]
         if len(masked_rows):
             tables = tables.copy()
             tables[list(masked_rows)] = 0
-        return (
+        staged = (
             jnp.asarray(hist), jnp.asarray(t_in), jnp.asarray(seq_len),
             jnp.asarray(spec_mask), jnp.asarray(tables[:, :table_width]),
             *(jnp.asarray(mirrors[f]) for f in (
                 "slot_keys", "gen_counts", "temperature", "top_k", "top_p")),
             *self._trailing(mirrors["adapter_ids"], mirrors["state_slots"]))
+        self.stats["decode_host_uploads"] += \
+            len(staged) - len(self._pool_tree())
+        return staged
 
     def launch_spec(self, staged: tuple, k: int):
         """Call the draft-length-``k`` spec program: ``(tokens, logprobs,
         emitted, proposed, accepted)``, slot-major."""
+        self.stats["decode_program_calls"] += 1
         self.cache, *out = self._spec_fn(k)(self.params, self.cache, *staged)
         return tuple(out)
 
@@ -904,14 +921,7 @@ class EngineExecutor:
         """Wait for program results and bring them to the host."""
         return [np.asarray(jax.device_get(x)) for x in arrays]
 
-    def mark_dirty(self, slot_id: int) -> None:
-        """A scheduling event changed ``slot_id``'s per-slot mirrors
-        (admission, release, block growth, prefill completion): the next
-        decode round re-uploads that row."""
-        self.decode_state.mark_dirty(slot_id)
-
-    def warmup_decode_ladder(self, mirrors: Dict[str, np.ndarray],
-                             masked_rows: Sequence[int]) -> None:
+    def warmup_decode_ladder(self) -> None:
         """Pre-compile the decode programs (single-step + every multi-step
         halving-ladder length) BEFORE traffic: a window length's first use
         otherwise stalls the live decode loop on an XLA compile at an
@@ -925,26 +935,21 @@ class EngineExecutor:
             # Carry each leaf's ACTUAL sharding: a ReplicatedEngine pins
             # every replica's params/KV to its own device, and an aval
             # without it lowers for the default device — an executable
-            # replica 1 can only reject at dispatch time. The tokens and
-            # positions stay plain avals: they arrive uncommitted and
-            # follow the committed operands.
+            # replica 1 can only reject at dispatch time.
             return jax.tree_util.tree_map(
                 lambda v: jax.ShapeDtypeStruct(
                     v.shape, v.dtype,
                     sharding=getattr(v, "sharding", None)), tree)
 
-        S = self.cfg.max_seqs
-        tok = jax.ShapeDtypeStruct((S, 1), jnp.int32)
-        # The decode state feeds COMMITTED device arrays into the compiled
-        # programs; lower with their actual shardings so the AOT
-        # executables accept them (same reason params/cache carry theirs).
-        # Syncing here is correct at any time — it just brings the resident
-        # copies up to date with the mirrors.
-        args = (avals(self.params), avals(self.cache), tok, tok,
-                *avals(self.decode_state.sync(mirrors, masked_rows)),
+        # The packed round arrives COMMITTED (``stage_decode``), like the
+        # round-before's tokens; lower with the sharding it will carry so
+        # the AOT executables accept it (same reason params/cache carry
+        # theirs).
+        pk = self.round_packing
+        args = (avals(self.params), avals(self.cache),
+                jax.ShapeDtypeStruct((pk.num_slots, pk.width), jnp.int32,
+                                     sharding=self._no_prev.sharding),
                 *avals(self._pool_tree()))
-        # The state's row updater is a program a count of dirty rows too.
-        self.decode_state.warm_row_counts(mirrors, masked_rows)
         # Idempotent: a re-warm unwraps back to the raw jit fn (the
         # _aot_or_jit wrapper has no .lower) and rebuilds the executable.
         # The one-step program also takes the round before's tokens, a
@@ -972,7 +977,6 @@ class EngineExecutor:
         ledger.register(
             "recurrent_state_pool",
             lambda: [c for c in self.cache if "ssm" in c] or None)
-        ledger.register("decode_state_cache", lambda: self.decode_state._dev)
         ledger.register(
             "lora_adapters",
             lambda: (self.adapter_pool.tree

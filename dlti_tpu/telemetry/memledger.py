@@ -10,7 +10,7 @@ parameters, optimizer state, KV blocks, or a leak.
 
 **Model.** Subsystems register named *owners* (``params``,
 ``optimizer_state``, ``kv_block_pool``, ``prefix_cache_hbm``,
-``decode_state_cache``, ``prefetch_buffers``, ...) with their
+``prefetch_buffers``, ...) with their
 pytree/array handles (or a zero-arg callable returning one, for handles
 that are swapped out across steps). A :meth:`MemoryLedger.snapshot` sums
 per-device ``nbytes`` over each owner's live arrays, reconciles against
@@ -64,7 +64,6 @@ MEMORY_OWNERS = (
     "grad_buffers",
     "kv_block_pool",
     "prefix_cache_hbm",
-    "decode_state_cache",
     "prefetch_buffers",
     "kv_handoff_staging",  # disagg: host-staged prefill→decode KV payloads
     "lora_adapters",      # multi-LoRA serving: the stacked A/B adapter pool

@@ -124,8 +124,9 @@ def test_prefix_cache_metric_names_are_schema_stable():
 def test_host_overlap_metric_names_are_schema_stable():
     """Host-latency-hiding telemetry names are a scrape contract like the
     gateway set: the training prefetcher's gauge/histogram, the engine's
-    decode host-prep histogram, and the decode-state upload counters
-    (exposed via the engine stats scalar source as dlti_<key>)."""
+    decode host-prep histogram, and the counters of what a decode round
+    costs the host (exposed via the engine stats scalar source as
+    dlti_<key>)."""
     from dlti_tpu.data.prefetch import PREFETCH_METRIC_NAMES
 
     assert PREFETCH_METRIC_NAMES == (
@@ -168,13 +169,22 @@ def test_host_overlap_metric_names_are_schema_stable():
         "dlti_sse_handler_cpu_seconds_total", "dlti_sse_events_total")
 
     # Engine stats keys ride the /metrics scalar source (dlti_ prefix):
-    # dlti_decode_state_uploads / _rows / _clean_syncs.
-    from dlti_tpu.serving.decode_state import DecodeStateCache
+    # dlti_decode_host_uploads / dlti_decode_program_calls, which the
+    # executor books into the dict the engine hands it. The layout of the
+    # packed round is a contract of the decode programs' first lines.
+    from dlti_tpu.serving.decode_state import RoundPacking
+    from dlti_tpu.serving.engine import InferenceEngine
 
-    stats: dict = {}
-    DecodeStateCache(2, stats=stats)
-    assert set(stats) == {"decode_state_uploads", "decode_state_rows",
-                          "decode_state_clean_syncs"}
+    import inspect
+
+    src = inspect.getsource(InferenceEngine.__init__)
+    assert '"decode_host_uploads": 0, "decode_program_calls": 0' in src
+    assert "decode_state_" not in src
+    pk = RoundPacking(2, 5, "adapter_ids")
+    assert list(pk.columns) == [
+        "input_ids", "positions", "block_tables", "slot_keys", "gen_counts",
+        "temperature", "top_k", "top_p", "adapter_ids"]
+    assert pk.width == 5 + 9 and RoundPacking(2, 5).width == 5 + 8
 
 
 def test_ckpt_metric_names_are_schema_stable():
@@ -508,7 +518,7 @@ def test_memledger_metric_names_are_schema_stable():
         memledger.MEMLEDGER_METRIC_NAMES[3]
     assert memledger.MEMORY_OWNERS == (
         "params", "optimizer_state", "grad_buffers", "kv_block_pool",
-        "prefix_cache_hbm", "decode_state_cache", "prefetch_buffers",
+        "prefix_cache_hbm", "prefetch_buffers",
         "kv_handoff_staging", "lora_adapters", "chaos_balloon",
     )
 

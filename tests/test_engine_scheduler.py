@@ -43,7 +43,6 @@ class ScriptedExecutor:
         assert params is None, "the scheduler hands its params straight on"
         self.cfg = engine_cfg
         self.calls = []
-        self.dirty = []
         self.unfetched = []  # token arrays of decode rounds not fetched yet
         # (rows, bucket, table width) -> bool: a call the device refuses
         self.refuses = lambda shape: False
@@ -56,11 +55,8 @@ class ScriptedExecutor:
     def slot_key(self, seed):
         return np.full((2,), 0 if seed is None else seed, np.uint32)
 
-    def mark_dirty(self, slot_id):
-        self.dirty.append(slot_id)
-
-    def warmup_decode_ladder(self, mirrors, masked_rows):
-        self.calls.append(("warmup", sorted(mirrors), list(masked_rows)))
+    def warmup_decode_ladder(self):
+        self.calls.append(("warmup",))
 
     # -- program calls ----------------------------------------------------
     def _counter_rows(self, calls):
@@ -96,6 +92,7 @@ class ScriptedExecutor:
     def stage_decode(self, input_ids, positions, mirrors, masked_rows):
         _all_numpy(input_ids, positions, *mirrors.values())
         self.calls.append(("decode", {
+            "mirrors": sorted(mirrors),
             "input_ids": input_ids[:, 0].copy(),
             "positions": positions[:, 0].copy(),
             "block_tables": mirrors["block_tables"].copy(),
@@ -280,8 +277,10 @@ def test_tables_grow_and_the_youngest_is_preempted_when_the_pool_runs_out(
     for r, p, n in zip((old, mid, young), prompts, lengths):
         assert r.output_token_ids == _stream(p, n)
     assert eng.block_manager.num_free == 5
-    # admissions, growths and releases were named to the executor
-    assert {0, 1, 2} <= set(ex.dirty)
+    # no event is named to the executor: every round is handed every
+    # slot's row as the scheduler holds it at the launch
+    assert all(c["block_tables"].shape == (3, 4) and len(c["gen_counts"]) == 3
+               for c in ex.of("decode"))
 
 
 def test_a_pool_with_nothing_left_to_preempt_is_an_error_not_a_hang():
@@ -438,14 +437,19 @@ def test_live_share_of_the_kernels_tiles_on_a_hand_made_batch(tile,
     assert share == {4: 51 / 68, 16: 51 / 96}[tile]
 
 
-def test_warmup_hands_the_mirrors_to_the_executor_by_name():
+def test_warmup_needs_nothing_of_the_scheduler_and_a_round_all_mirrors():
+    """No per-slot state is resident on the device, so the warm-up is the
+    executor's alone; a round is handed every mirror by name, whole."""
     eng = _engine()
     eng.warmup_decode_ladder()
-    [(_, names, masked)] = eng.executor.calls
-    assert names == sorted(["block_tables", "slot_keys", "gen_counts",
-                            "temperature", "top_k", "top_p", "adapter_ids",
-                            "state_slots"])
-    assert masked == []
+    assert eng.executor.calls == [("warmup",)]
+    eng.submit([10, 11, 12], SamplingParams(max_tokens=3))
+    while eng.has_work:
+        eng.step()
+    assert {tuple(c["mirrors"]) for c in eng.executor.of("decode")} == {
+        tuple(sorted(["block_tables", "slot_keys", "gen_counts",
+                      "temperature", "top_k", "top_p", "adapter_ids",
+                      "state_slots"]))}
 
 
 # -- a model that holds its prefill calls to a number of tokens --------------

@@ -6,12 +6,13 @@ Two hot paths, one invariant each:
   *invisible* in the numbers — bit-identical loss trajectory vs. the
   synchronous path for every (preset, packing) combination — and safe to
   shut down mid-epoch (preemption).
-* Serving (``dlti_tpu.serving.decode_state``): the device-resident
-  decode state must serve what references that do not share its path say
-  (the uncached full forward; each seeded request alone — including
-  across preemption and re-admission), and a clean decode step
-  — no admission/retire/preempt/growth since the last one — must issue
-  ZERO host→device decode-state uploads (the acceptance criterion).
+* Serving (``dlti_tpu.serving.decode_state``): a plain decode round goes
+  up as one packed array and is one program call (the acceptance
+  criterion), the packing loses no bit, and the engine serves what
+  references that do not share its path say (the uncached full forward;
+  each seeded request alone — including across preemption and
+  re-admission, block growth, rows masked while they prefill, and a
+  speculative round followed by a plain one).
 """
 
 import json
@@ -247,7 +248,8 @@ def test_drop_remainder_padded_step_trains(tmp_path):
 
 
 # ----------------------------------------------------------------------
-# Serving: resident decode state against references + zero-upload clean steps
+# Serving: the packed decode round against references; one upload and one
+# program call a round
 # ----------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
@@ -305,10 +307,11 @@ def _each_alone(params, prompts, sp, **over):
             for p in prompts]
 
 
-def test_resident_decode_state_matches_references(tiny_params):
-    """The resident per-slot state serves a batch exactly as the
+def test_packed_round_matches_references(tiny_params):
+    """Rounds staged as one packed array serve a batch exactly as the
     references say: greedy against the uncached full forward, seeded
-    sampling against each request alone."""
+    sampling against each request alone (block size 8, answers of 10:
+    every row grows a block on the way)."""
     prompts = [[1, 2, 3, 4, 5], [6, 7, 8], [9, 10, 11, 12]]
     greedy = _engine(tiny_params).generate(
         prompts, SamplingParams(temperature=0.0, max_tokens=10))
@@ -318,7 +321,7 @@ def test_resident_decode_state_matches_references(tiny_params):
         _each_alone(tiny_params, prompts, sp)
 
 
-def test_resident_decode_state_matches_across_preemption(tiny_params):
+def test_packed_round_matches_across_preemption(tiny_params):
     """A pool small enough to force preempt → re-admission (recompute)
     still agrees with the references, seeded sampling included (gen
     counts resume mid-stream on re-admission)."""
@@ -336,64 +339,266 @@ def test_resident_decode_state_matches_across_preemption(tiny_params):
             want or _each_alone(tiny_params, prompts, sp, max_model_len=48))
 
 
-def test_resident_decode_state_matches_multi_step(tiny_params):
+def test_packed_round_matches_multi_step(tiny_params):
     prompts = [[1, 2, 3, 4], [5, 6, 7]]
     eng = _engine(tiny_params, max_seqs=2, steps_per_sync=4)
     got = eng.generate(prompts, SamplingParams(temperature=0.0, max_tokens=9))
     assert _tokens(got) == _uncached_greedy(tiny_params, prompts, 9)
 
 
-def test_clean_decode_step_issues_zero_uploads(tiny_params):
-    """THE acceptance criterion: once the batch composition settles, every
-    further decode step reuses the resident device state — zero
-    host→device decode-state uploads, while decode_steps keeps advancing."""
-    # One 64-token block per sequence: no block-table growth inside the
-    # observation window (growth is a legitimately dirty event).
-    eng = _engine(tiny_params, block_size=64, num_blocks=8)
-    eng.submit([1, 2, 3, 4], SamplingParams(temperature=0.0, max_tokens=30))
-    eng.step()   # admission + prefill
-    eng.step()   # first decode: uploads the admitted row
-    settled = eng.stats["decode_state_uploads"]
-    clean_before = eng.stats["decode_state_clean_syncs"]
-    steps_before = eng.stats["decode_steps"]
-    for _ in range(6):
-        eng.step()
-    assert eng.stats["decode_steps"] == steps_before + 6
-    assert eng.stats["decode_state_uploads"] == settled  # ZERO new uploads
-    assert eng.stats["decode_state_clean_syncs"] >= clean_before + 6
-    # The account's decode_prep phase entered every dispatch, and booked.
-    acct = eng.telemetry.stepper
-    assert acct.entries()["engine/decode_prep"] >= 7
-    assert acct.seconds()["engine/decode_prep"] > 0
+def test_packed_round_matches_while_a_row_prefills(tiny_params):
+    """Chunked prefill: a slot is admitted and prefills over several steps
+    while the others decode; its block-table row is packed as the trash
+    block until its prompt is in, and every stream is what the references
+    say."""
+    prompts = [[1, 2, 3], list(range(20, 46)), [4, 5, 6, 7]]
+    kw = dict(max_prefill_tokens_per_step=8, max_model_len=64)
+    eng = _engine(tiny_params, **kw)
+    staged = []
+    stage = eng.executor.stage_decode
+
+    def spy(ids, pos, mirrors, masked):
+        staged.append(list(masked))
+        return stage(ids, pos, mirrors, masked)
+
+    eng.executor.stage_decode = spy
+    got = eng.generate(prompts, SamplingParams(temperature=0.0, max_tokens=9))
+    assert any(staged), "no round was launched beside a prefilling slot"
+    assert _tokens(got) == _uncached_greedy(tiny_params, prompts, 9)
+    sp = SamplingParams(temperature=0.8, seed=3, max_tokens=9)
+    assert _tokens(_engine(tiny_params, **kw).generate(prompts, sp)) == \
+        _each_alone(tiny_params, prompts, sp)
 
 
-def test_warm_up_runs_the_row_updater_at_every_padded_count(tiny_params):
-    """The resident state's row updater is one jit that XLA specializes
-    per padded count of dirty rows; the warm-up meets every count up to
-    max_seqs, so a burst of ends and admissions under traffic (a count no
-    quiet step forms) compiles nothing, and it leaves the rows as they
-    stood."""
-    eng = _engine(tiny_params, max_seqs=8)
-    state = eng.executor.decode_state
-    state.sync(eng._state_mirrors(), eng._masked_rows())
-    counted = dict(state.stats)
-    state.warm_row_counts(eng._state_mirrors(), eng._masked_rows())
-    assert state.stats == counted    # the warm-up's uploads are not traffic's
+def test_a_plain_round_after_a_speculative_one_matches(tiny_params):
+    """A greedy request speculates and ends; the seeded one beside it goes
+    on in plain rounds. Nothing is resident on the device that the
+    speculative rounds could have left stale: both streams are what a
+    plain engine gives each request alone."""
+    prompts = [[7, 8, 9, 7, 8, 9, 7, 8], [4, 5, 4, 5, 4, 5, 4]]
+    sps = [SamplingParams(temperature=0.0, max_tokens=6),
+           SamplingParams(temperature=0.9, seed=21, max_tokens=24)]
+    kw = dict(max_seqs=2, max_model_len=96)
+    eng = _engine(tiny_params, speculative="ngram", num_draft_tokens=4,
+                  ngram_size=2, **kw)
+    calls = []
+    for name in ("launch_spec", "launch_decode"):
+        def spy(*a, _real=getattr(eng.executor, name), _name=name):
+            calls.append(_name)
+            return _real(*a)
+        setattr(eng.executor, name, spy)
+    got = _streams(eng, prompts, sps)
+    assert "launch_spec" in calls
+    assert calls[-1] == "launch_decode" and \
+        calls.index("launch_spec") < len(calls) - 1
+    want = [_streams(_engine(tiny_params, **kw), [p], [sp])[0]
+            for p, sp in zip(prompts, sps)]
+    for (tok, lps, why), (wtok, wlps, wwhy) in zip(got, want):
+        assert (tok, why) == (wtok, wwhy)
+        np.testing.assert_allclose(lps, wlps, atol=1e-4)
+    st = eng.stats
+    # A spec round ships its arrays one by one; a plain one is one and one.
+    assert st["decode_program_calls"] == len(calls)
+    assert st["decode_host_uploads"] == \
+        calls.count("launch_decode") + 10 * calls.count("launch_spec")
+
+
+def _lora_engine(params, **kw):
+    return _engine(params, adapter_slots=2, adapter_rank=4, **kw)
+
+
+def _recurrent_engine(_params, **kw):
+    """The hybrid family (Mamba-2 layers beside attention: ``state_slots``
+    is the extra per-slot row), the tiny preset."""
+    import jax
+    import jax.numpy as jnp
+
+    from dlti_tpu.models import build_model
+
+    cfg = MODEL_PRESETS["nemotron_h_tiny"]
+    params = build_model(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    over = dict(max_seqs=3, block_size=8, num_blocks=64, max_model_len=64,
+                cache_dtype="float32", eos_token_id=-1)
+    over.update(kw)
+    return InferenceEngine(cfg, params, EngineConfig(**over))
+
+
+ROUND_KINDS = {
+    # name: (engine maker, engine options, rounds launched ahead?)
+    "one_step": (None, {}, False),
+    "riding": (_engine, {}, True),
+    "steps_per_sync_4": (_engine, {"steps_per_sync": 4}, False),
+    "multi_lora": (_lora_engine, {}, True),
+    "recurrent": (_recurrent_engine, {}, True),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(ROUND_KINDS))
+def test_a_plain_round_is_one_upload_and_one_program_call(
+        tiny_params, fetch_first_engine, monkeypatch, kind):
+    """THE acceptance criterion: whatever changed between two rounds
+    (admissions, ends, block growth: block size 8 here, so rows grow all
+    the time), a plain decode round's staging makes exactly one
+    host-to-device transfer and its launch exactly one program call, by
+    the patched ``jax.device_put`` / ``jnp.asarray`` and by the engine's
+    own counters; one-step rounds fetched before the next is planned,
+    rounds riding behind the round in flight, four-step windows, a
+    multi-LoRA pool (``adapter_ids`` packed, the pool's tree after it) and
+    a recurrent model (``state_slots`` packed)."""
+    import jax
+    import jax.numpy as jnp
+
+    make, over, ahead = ROUND_KINDS[kind]
+    if make is None:
+        eng = fetch_first_engine(CFG, tiny_params, EngineConfig(
+            max_seqs=3, block_size=8, num_blocks=64, max_model_len=64,
+            cache_dtype="float32", eos_token_id=-1))
+    else:
+        eng = make(tiny_params, **over)
+    ex = eng.executor
+    extra = {"multi_lora": "adapter_ids", "recurrent": "state_slots"}.get(kind)
+    assert ex.round_packing.extra_field == extra
+    assert ex.round_packing.width == eng.cfg.max_blocks_per_seq + 8 + \
+        (extra is not None)
+
+    transfers = {"stage": 0, "launch": 0}
+    where = [None]
+
+    def counting(real):
+        def put(x, *a, **k):
+            if where[0] is not None and not isinstance(x, jax.Array):
+                transfers[where[0]] += 1
+            return real(x, *a, **k)
+        return put
+
+    monkeypatch.setattr(jax, "device_put", counting(jax.device_put))
+    monkeypatch.setattr(jnp, "asarray", counting(jnp.asarray))
+    programs = []
+    for name, at in (("stage_decode", "stage"), ("launch_decode", "launch")):
+        def inside(*a, _real=getattr(ex, name), _at=at):
+            where[0] = _at
+            try:
+                return _real(*a)
+            finally:
+                where[0] = None
+        setattr(ex, name, inside)
+
+    def counted_program(fn):
+        def call(*a):
+            assert where[0] == "launch"
+            # host arguments would be uploads the patch above cannot see
+            assert all(isinstance(x, jax.Array)
+                       for x in jax.tree_util.tree_leaves(a))
+            programs.append(fn)
+            return fn(*a)
+        return call
+
+    ex._decode_fn = counted_program(ex._decode_fn)
+    real_multi = ex._multi_decode_fn
+    ex._multi_decode_fn = lambda k: counted_program(real_multi(k))
+
+    prompts = [[1, 2, 3, 4, 5], [6, 7, 8], [9, 10, 11, 12], [13, 14],
+               [15, 16, 17, 18, 19, 20, 21]]
+    sps = [SamplingParams(temperature=0.0 if i % 2 else 0.9, seed=40 + i,
+                          max_tokens=n)
+           for i, n in enumerate([14, 5, 9, 17, 11])]
+    _streams(eng, prompts, sps)
+    st = eng.stats
+    rounds = st["decode_program_calls"]
+    assert rounds >= 8 and st["preemptions"] == 0
+    assert transfers == {"stage": rounds, "launch": 0}
+    assert len(programs) == rounds == st["decode_host_uploads"]
+    assert (st["decode_rounds_launched_ahead"] > 0) == ahead
+    if over.get("steps_per_sync"):
+        assert st["decode_steps"] > rounds     # windows of several steps
+    else:
+        assert st["decode_steps"] == rounds
+
+
+def test_the_packing_round_trips_bit_for_bit():
+    """float32 values and uint32 keys travel as int32 by their bits:
+    1.0, a denormal, -0.0 and the largest finite float; keys with the top
+    bit set; a block-table row at full width; a negative id (``RIDES``);
+    masked rows read as the trash block and nothing else of them moves."""
+    import jax
+
+    from dlti_tpu.serving.decode_state import RoundPacking
+
+    S, MB = 4, 6
+    pk = RoundPacking(S, MB, "state_slots")
+    assert pk.width == MB + 9
+    rng = np.random.default_rng(0)
+    denormal = np.float32(1e-42)
+    assert denormal != 0 and denormal < np.finfo(np.float32).tiny
+    mirrors = {
+        "block_tables": rng.integers(1, 2**31 - 1, (S, MB)).astype(np.int32),
+        "slot_keys": np.array([[0xFFFFFFFF, 0x80000000], [0, 1],
+                               [0x80000001, 0x7FFFFFFF],
+                               [0xDEADBEEF, 0xCAFEF00D]], np.uint32),
+        "gen_counts": np.array([0, 1, 2**31 - 1, 77], np.int32),
+        "temperature": np.array([1.0, 0.0, denormal, 3.4028235e38],
+                                np.float32),
+        "top_k": np.array([0, 7, 50, 2**31 - 1], np.int32),
+        "top_p": np.array([1.0, denormal, -0.0, 0.95], np.float32),
+        "state_slots": np.array([0, 1, S, 3], np.int32),
+        "adapter_ids": np.zeros((S,), np.int32),   # not this layout's extra
+    }
+    ids = np.array([[5], [-1], [0], [2**31 - 1]], np.int32)
+    pos = np.array([[0], [63], [7], [1]], np.int32)
+    packed = pk.pack(ids, pos, mirrors, masked_rows=[2])
+    assert packed.dtype == np.int32 and packed.shape == (S, pk.width)
+    names = ("input_ids", "positions", "block_tables", "slot_keys",
+             "gen_counts", "temperature", "top_k", "top_p", "state_slots")
+    for unpack in (pk.unpack, jax.jit(pk.unpack)):
+        out = dict(zip(names, unpack(jax.numpy.asarray(packed))))
+        want = dict(mirrors, input_ids=ids, positions=pos)
+        want["block_tables"] = mirrors["block_tables"].copy()
+        want["block_tables"][2] = 0
+        for name in names:
+            got = np.asarray(out[name])
+            assert got.dtype == want[name].dtype, name
+            assert got.shape == want[name].shape, name
+            assert got.tobytes() == want[name].tobytes(), name
+    # the pack is a copy: the scheduler writes its mirrors under a round
+    mirrors["gen_counts"][:] = -1
+    assert (packed[:, pk.columns["gen_counts"][0]] != -1).all()
+    with pytest.raises(TypeError, match="temperature"):
+        pk.pack(ids, pos, dict(mirrors, temperature=np.ones(S)), ())
+
+
+def test_no_decode_program_is_built_after_the_warm_up(tiny_params):
+    """One program serves every round: after ``warmup_decode_ladder`` 1, 9
+    and 32 slots change between two rounds (ends and admissions at once)
+    and the decode calls stay on the warmed executables, with nothing
+    traced or compiled for them. (The row updater this replaces was a
+    program a padded count of changed rows.)"""
+    eng = _engine(tiny_params, max_seqs=32, num_blocks=160, block_size=8,
+                  max_model_len=32, steps_per_sync=1)
     eng.warmup_decode_ladder()
-    # (the jit's cache is shared by every engine of the process: what is
-    # pinned is that no count of dirty rows adds to it after the warm-up)
-    warmed = state._update._cache_size()
-    before = [np.asarray(a) for a in state._dev]
-    for dirty in range(1, 9):                        # pads to 1, 2, 4, 8
-        for slot in range(dirty):
-            state.mark_dirty(slot)
-        state.sync(eng._state_mirrors(), eng._masked_rows())
-        assert state._update._cache_size() == warmed
-    for a, b in zip(before, state._dev):
-        np.testing.assert_array_equal(a, np.asarray(b))
-    got = eng.generate([[3, 1, 4, 1, 5, 9]],
-                       SamplingParams(temperature=0.0, max_tokens=5))
-    assert len(got[0].output_token_ids) == 5
+    call = eng.executor._decode_fn
+    assert call._aot_state["aot"]
+
+    def admit(n, max_tokens):
+        for i in range(n):
+            eng.submit([1 + i, 2, 3], SamplingParams(
+                temperature=0.7, seed=i, max_tokens=max_tokens))
+
+    admit(32, 4)
+    for changed in (1, 9, 32):
+        while eng.has_work:
+            eng.step()
+        admit(32 - changed, 6)      # these stay
+        eng.step()
+        eng.step()
+        admit(changed, 3)           # these join between two rounds
+        for _ in range(3):
+            eng.step()
+    while eng.has_work:
+        eng.step()
+    assert eng.stats["decode_program_calls"] == eng.stats["decode_steps"] > 10
+    assert eng.executor._decode_fn is call and call._aot_state["aot"]
+    assert call._jit_fn._cache_size() == 0   # never traced by a live call
 
 
 # ----------------------------------------------------------------------
@@ -498,13 +703,15 @@ def test_a_prefix_hit_on_a_tail_block_a_discarded_row_wrote(tiny_params):
     assert (got[0][0], got[0][2]) == want[0]
 
 
-def test_decode_state_upload_counters_exposed(tiny_params):
+def test_decode_round_cost_counters_exposed(tiny_params):
     """The counters ride the engine stats dict (the /metrics scalar
-    source), present before the first decode round."""
+    source), present before the first decode round, and the ones they
+    replace are gone."""
     eng = _engine(tiny_params)
-    for k in ("decode_state_uploads", "decode_state_rows",
-              "decode_state_clean_syncs"):
+    for k in ("decode_host_uploads", "decode_program_calls"):
         assert eng.stats[k] == 0
+    assert eng.executor.stats is eng.stats
+    assert not [k for k in eng.stats if k.startswith("decode_state_")]
 
 
 # ----------------------------------------------------------------------

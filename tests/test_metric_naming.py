@@ -146,8 +146,7 @@ def full_registry():
                  "preemptions": 0, "decode_steps": 0, "decode_slot_steps": 0,
                  "prefix_cached_tokens": 0, "spec_proposed": 0,
                  "spec_accepted": 0, "spec_paused_rounds": 0,
-                 "decode_state_uploads": 0, "decode_state_rows": 0,
-                 "decode_state_clean_syncs": 0}
+                 "decode_host_uploads": 0, "decode_program_calls": 0}
         telemetry = RequestTelemetry(tracer=SpanTracer(enabled=False))
         waiting: list = []
         num_active = 0

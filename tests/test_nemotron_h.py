@@ -467,7 +467,8 @@ def test_llama_programs_take_no_new_argument():
         cache_dtype="float32"))
     assert eng.executor.counter_names == () and not eng.executor._recurrent
     assert eng._prefill_rows(2048) == 8  # no model limit: as before
-    assert eng.executor.decode_state._fields[-1] == "top_p"
+    assert eng.executor.round_packing.extra_field is None
+    assert list(eng.executor.round_packing.columns)[-1] == "top_p"
     res = eng.generate(_prompts([9]), SamplingParams(max_tokens=4,
                                                      temperature=0.0))
     assert len(res[0].output_token_ids) == 4

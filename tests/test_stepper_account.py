@@ -345,8 +345,7 @@ def _drive(params, telemetry):
             return real(*args, **kwargs)
         return call
 
-    for name in ("prefill", "stage_decode", "launch_decode", "fetch",
-                 "mark_dirty"):
+    for name in ("prefill", "stage_decode", "launch_decode", "fetch"):
         setattr(eng.executor, name, recorded(name, getattr(eng.executor,
                                                             name)))
     acct = eng.telemetry.stepper
