@@ -66,7 +66,21 @@ class ModelConfig:
     # Family knobs beyond Llama-2 (the reference is HF AutoModel-generic,
     # ``training/train_baseline.py:122``, so sibling families must load):
     attention_bias: bool = False        # Qwen2: bias on q/k/v (never o)
-    sliding_window: Optional[int] = None  # Mistral: local attention window
+    # A local attention window: a query at position i sees keys j with
+    # i - window < j <= i. ``sliding_window`` is one for every layer
+    # (Mistral); ``layer_windows``, where given, states one a layer (0 = every
+    # key) and wins over it (exaone_moe: 128, 128, 128, 0, ...).
+    sliding_window: Optional[int] = None
+    layer_windows: tuple = ()
+    # A norm over each head's values of queries and keys (one weight of
+    # head_dim each, shared by the heads) before the rotary embedding.
+    qk_norm: bool = False
+    # Whether the layers that see every key rotate queries and keys too
+    # (false: a hybrid model's global layers carry no position).
+    rope_on_full_layers: bool = True
+    # Where a block's two norms stand: false ``x + F(norm(x))`` (pre-norm),
+    # true ``x + norm(F(x))`` (the exaone4 family's placement).
+    post_sublayer_norm: bool = False
     mlp_activation: str = "silu"        # "silu" | "gelu_tanh" | "gelu_exact"
     rmsnorm_offset: bool = False        # Gemma: normalize with (1 + weight)
     embedding_scale: bool = False       # Gemma: embed * sqrt(hidden_size)
@@ -145,7 +159,9 @@ class ModelConfig:
     # (``qk_nope_head_dim`` a head) and values (``v_head_dim``) are
     # up-projected, or into which the queries are absorbed. The first
     # ``first_k_dense`` layers have a dense MLP of ``intermediate_size``,
-    # the rest HeldExpertsMLP.
+    # the rest HeldExpertsMLP. The Llama block reads ``first_k_dense`` the
+    # same way where ``moe_num_experts`` > 0 (``num_experts`` keeps meaning
+    # the capacity layer MoEMLP in every block).
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
@@ -182,6 +198,19 @@ class ModelConfig:
                      else self.rope_scaling)
             object.__setattr__(self, "rope_scaling", tuple(
                 sorted((str(k), v) for k, v in pairs)))
+        windows = tuple(int(w or 0) for w in self.layer_windows)
+        object.__setattr__(self, "layer_windows", windows)
+        if windows and len(windows) != self.num_layers:
+            raise ValueError(
+                f"layer_windows states {len(windows)} windows for "
+                f"num_layers={self.num_layers}")
+        if len(set(windows)) > 1 and (
+                0 not in windows or len(set(windows)) > 2):
+            raise ValueError(
+                f"layer_windows {sorted(set(windows))}: the serving cache "
+                f"keeps one pool for the layers that see every key and one "
+                f"for the layers of ONE window; several window lengths in "
+                f"one model are not implemented")
         if self.layer_pattern and (
                 len(self.layer_pattern) != self.num_layers
                 or set(self.layer_pattern) - set("ME*")):
@@ -192,6 +221,28 @@ class ModelConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim if self.head_dim is not None else self.hidden_size // self.num_heads
+
+    def window_of_layer(self, layer: int) -> Optional[int]:
+        """Layer ``layer``'s attention window (None: every key)."""
+        if self.layer_windows:
+            return self.layer_windows[layer] or None
+        return self.sliding_window
+
+    @property
+    def kv_group_windows(self) -> tuple:
+        """The serving cache's groups of attention layers, as the window of
+        each (0: every key): layers of equal window share block tables and
+        an allocator. One group for a model whose layers agree (every
+        model without ``layer_windows``), whatever the window; else the
+        layers that see every key first, then the window's."""
+        distinct = sorted(set(self.layer_windows))
+        return tuple(distinct) if len(distinct) > 1 \
+            else (self.window_of_layer(0) or 0,)
+
+    def kv_group_of_layer(self, layer: int) -> int:
+        groups = self.kv_group_windows
+        return groups.index(self.layer_windows[layer]) \
+            if len(groups) > 1 else 0
 
     def num_params(self, include_lm_head: bool = True) -> int:
         """Analytic parameter count (for MFU and reporting)."""
@@ -233,6 +284,20 @@ class ModelConfig:
         """``rope_scaling`` as the object the public config states."""
         return dict(self.rope_scaling) if self.rope_scaling else None
 
+    def _held_expert_layer_params(self, active_only: bool) -> int:
+        """One HeldExpertsMLP layer: of the routed experts those held here
+        (active: top-k of the router's width, of which the held share is
+        what this process computes in the mean), the shared expert, the
+        router and its bias. Gated experts have three matrices, relu2 two."""
+        h, f = self.hidden_size, self.moe_intermediate_size
+        mats = 3 if self.mlp_activation == "silu" else 2
+        n_routed = (self.num_experts_per_tok * self.moe_held
+                    / max(1, self.moe_num_experts)
+                    if active_only else self.moe_held)
+        return (int(n_routed * mats * h * f)
+                + mats * h * self.moe_shared_intermediate_size
+                + h * self.moe_num_experts + self.moe_num_experts)
+
     def _count_params(self, include_lm_head: bool, active_only: bool) -> int:
         h, m, v = self.hidden_size, self.intermediate_size, self.vocab_size
         hd = self.resolved_head_dim
@@ -256,14 +321,7 @@ class ModelConfig:
             # the stream maps of both sublayers (models.hyper.HyperMaps)
             n = self.hc_mult
             attn += 2 * ((n * h + 1) * (n * n + 2 * n) + 3) if n else 0
-            f = self.moe_intermediate_size
-            mats = 3 if self.mlp_activation == "silu" else 2  # gated or not
-            n_routed = (self.num_experts_per_tok * self.moe_held
-                        / max(1, self.moe_num_experts)
-                        if active_only else self.moe_held)
-            experts = (int(n_routed * mats * h * f)
-                       + mats * h * self.moe_shared_intermediate_size
-                       + h * self.moe_num_experts + self.moe_num_experts)
+            experts = self._held_expert_layer_params(active_only)
             dense = min(self.first_k_dense, self.num_layers)
             total = (v * h + h + self.num_layers * (attn + 2 * h)
                      + dense * 3 * h * m + (self.num_layers - dense) * experts)
@@ -279,16 +337,22 @@ class ModelConfig:
                           * self.mamba_state_size + heads)
                      + self.mamba_conv_dim * (self.mamba_conv_kernel + 1)
                      + 3 * heads + d_in + d_in * h)
-            f = self.moe_intermediate_size
-            n_routed = (self.num_experts_per_tok * self.moe_held
-                        / max(1, self.moe_num_experts)
-                        if active_only else self.moe_held)
-            experts = (int(n_routed * 2 * h * f)
-                       + 2 * h * self.moe_shared_intermediate_size
-                       + h * self.moe_num_experts + self.moe_num_experts)
+            experts = self._held_expert_layer_params(active_only)
             per_kind = {"M": mamba, "*": attn, "E": experts}
             total = v * h + h + sum(per_kind[c] + h
                                     for c in self.layer_pattern)
+            if include_lm_head and not self.tie_embeddings:
+                total += h * v
+            return total
+        if self.qk_norm:
+            attn += 2 * hd
+        if self.moe_num_experts > 0:
+            # Held experts under the Llama block: the leading layers dense,
+            # the rest the held experts with router, bias and shared expert.
+            experts = self._held_expert_layer_params(active_only)
+            dense = min(self.first_k_dense, self.num_layers)
+            total = (v * h + h + self.num_layers * (attn + 2 * h)
+                     + dense * 3 * h * m + (self.num_layers - dense) * experts)
             if include_lm_head and not self.tie_embeddings:
                 total += h * v
             return total
@@ -1199,7 +1263,8 @@ def resolve_model(name: str) -> ModelConfig:
                 f"{cfg.num_layers} whole layers")
         cfg = dataclasses.replace(
             cfg, num_layers=int(cut),
-            layer_pattern=cfg.layer_pattern[:int(cut)])
+            layer_pattern=cfg.layer_pattern[:int(cut)],
+            layer_windows=cfg.layer_windows[:int(cut)])
     return cfg
 
 

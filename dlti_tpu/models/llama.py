@@ -22,6 +22,7 @@ updates so the whole engine stays inside one compiled program.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, Optional
 
@@ -55,11 +56,16 @@ class RMSNorm(nn.Module):
     eps: float = 1e-5
     param_dtype: Any = jnp.float32
     offset: bool = False
+    # Seeded weights 1 + N(0, init_std) instead of ones (0: ones).
+    init_std: float = 0.0
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         orig_dtype = x.dtype
         init = nn.initializers.zeros if self.offset else nn.initializers.ones
+        if self.init_std:
+            def init(key, shape, dtype, std=self.init_std):
+                return (1.0 + std * jax.random.normal(key, shape)).astype(dtype)
         scale = self.param("scale", init, (x.shape[-1],), self.param_dtype)
         x32 = x.astype(jnp.float32)
         var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
@@ -77,6 +83,19 @@ def _lora_kwargs(cfg: ModelConfig, lora: Optional[LoRAConfig], name: str) -> dic
     return dict(lora_r=0)
 
 
+# Seeded spread of the query and key norms' weights (``qk_norm``): away from
+# 1, as ``models.moe.SCORE_BIAS_STD`` keeps the selection bias away from 0,
+# so that a program without the norms' weights differs from one with them.
+QK_NORM_INIT_STD = 0.25
+# Most padded tokens (rows x bucket) the serving engine gives one prefill
+# call of a model whose layers disagree about their window: a longer prompt
+# goes as several calls, each over what the earlier ones wrote. It bounds
+# what the window group of the cache has to hold for a call
+# (``ops.kv_cache.window_group_blocks``) and the held-expert layer's layout,
+# as the latent family's limit does (``models.latent.PREFILL_CALL_TOKENS``).
+PREFILL_CALL_TOKENS = 2048
+
+
 class LlamaAttention(nn.Module):
     cfg: ModelConfig
     lora: Optional[LoRAConfig] = None
@@ -85,6 +104,71 @@ class LlamaAttention(nn.Module):
     # (dlti_tpu.parallel.ring_attention) — the reference has no SP at all
     # (SURVEY.md §5.7); here it is first-class.
     mesh: Optional[Any] = None
+    # Which layer this is: its window and whether it rotates are the
+    # configuration's (``window_of_layer``, ``rope_on_full_layers``).
+    layer: int = 0
+
+    @property
+    def window(self) -> Optional[int]:
+        return self.cfg.window_of_layer(self.layer)
+
+    def _over_paged_cache(self, q, k, v, cache, positions):
+        """The serving engine's paged cache: this call's keys and values
+        into the layer's pool, then its queries over each row's table.
+        ``(out (b, s, heads, head_dim), the layer's new cache)``."""
+        cfg, window, s = self.cfg, self.window, q.shape[1]
+        # Stale/unallocated slots are at logical positions > the query
+        # position, so the explicit-position causal mask hides them.
+        from dlti_tpu.ops.attention import attend_over_cache, walks_cache
+        from dlti_tpu.ops.kv_cache import paged_gather, paged_update, slot_mapping
+
+        nb, blk_size = cache["k"].shape[0], cache["k"].shape[1]
+        if "table_base" in cache:
+            # A window group's table (ops.kv_cache): column 0 is the
+            # block that holds token ``table_base`` of the row, what
+            # lies before it has been released. Keys are stored
+            # rotated and the mask reads differences of positions, so
+            # the cache is addressed, and attended over, in positions
+            # counted from there.
+            positions = jnp.where(
+                positions >= 0,
+                positions - cache["table_base"][:, None], -1)
+        slots = slot_mapping(cache["block_tables"], positions, blk_size, nb)
+        new_cache = paged_update(cache, k, v, slots)
+        # The kernel for decode steps (s == 1); a prefill call walks the
+        # cache in blocks or gathers the row's window (``walks_cache``).
+        path, _ = resolve_paged_decode(
+            cfg.paged_attention_impl,
+            tp_sharded=(self.mesh is not None
+                        and self.mesh.shape.get("tensor", 1) > 1))
+        use_kernel = s == 1 and path != "xla"
+        if use_kernel:
+            # Pallas kernel: reads K/V blocks in place via the block
+            # table (no O(batch*max_len) gather); decode steps only.
+            from dlti_tpu.ops.pallas.paged_attention import (
+                paged_decode_attention,
+            )
+
+            out = paged_decode_attention(
+                q, new_cache["k"], new_cache["v"],
+                cache["block_tables"], positions[:, 0] + 1,
+                k_scale=new_cache.get("k_scale"),
+                v_scale=new_cache.get("v_scale"),
+                window=window,
+                interpret=path == "pallas-interpret",
+            ).astype(q.dtype)
+        elif walks_cache(s, cache["block_tables"].shape[1] * blk_size,
+                         "table_base" in cache):
+            out = attend_over_cache(q, new_cache, cache["block_tables"],
+                                    positions, window)
+        else:
+            ck, cv = paged_gather(new_cache, cache["block_tables"])
+            out = reference_attention(
+                q, ck.astype(q.dtype), cv.astype(q.dtype),
+                causal=True, q_positions=positions,
+                window=window,
+            )
+        return out, new_cache
 
     def _effective_window(self, segment_ids) -> Optional[int]:
         """Sliding window combined with the packed doc-length bound.
@@ -96,7 +180,7 @@ class LlamaAttention(nn.Module):
         skip) applies without changing any logit.
         """
         cfg = self.cfg
-        window = cfg.sliding_window
+        window = self.window
         if segment_ids is not None and cfg.packed_attention_window:
             window = (min(window, cfg.packed_attention_window)
                       if window else cfg.packed_attention_window)
@@ -139,50 +223,28 @@ class LlamaAttention(nn.Module):
         k = k.reshape(b, s, cfg.num_kv_heads, hd)
         v = v.reshape(b, s, cfg.num_kv_heads, hd)
 
-        if cfg.rope:  # the nemotron_h family applies none (cos, sin None)
+        window = self.window
+        if cfg.qk_norm:
+            # a head at a time over its head_dim values, before the rotation
+            q = RMSNorm(cfg.rms_norm_eps, init_std=QK_NORM_INIT_STD,
+                        name="q_norm")(q)
+            k = RMSNorm(cfg.rms_norm_eps, init_std=QK_NORM_INIT_STD,
+                        name="k_norm")(k)
+        # the nemotron_h family applies no rotation (cos, sin None); a hybrid
+        # model's layers that see every key may carry no position either
+        if cfg.rope and (window or cfg.rope_on_full_layers):
             q = apply_rope(q, cos, sin, positions)
             k = apply_rope(k, cos, sin, positions)
 
         new_cache = None
         if cache is not None and "block_tables" in cache:
-            # Paged cache (serving engine): scatter K/V into the shared block
-            # pool, then attend over this sequence's gathered logical window.
-            # Stale/unallocated slots are at logical positions > the query
-            # position, so the explicit-position causal mask hides them.
-            from dlti_tpu.ops.kv_cache import paged_gather, paged_update, slot_mapping
-
-            nb, blk_size = cache["k"].shape[0], cache["k"].shape[1]
-            slots = slot_mapping(cache["block_tables"], positions, blk_size, nb)
-            new_cache = paged_update(cache, k, v, slots)
-            # Decode steps only (s == 1): prefill attends over the
-            # gathered window on the XLA path.
-            path, _ = resolve_paged_decode(
-                cfg.paged_attention_impl,
-                tp_sharded=(self.mesh is not None
-                            and self.mesh.shape.get("tensor", 1) > 1))
-            use_kernel = s == 1 and path != "xla"
-            if use_kernel:
-                # Pallas kernel: reads K/V blocks in place via the block
-                # table (no O(batch*max_len) gather); decode steps only.
-                from dlti_tpu.ops.pallas.paged_attention import (
-                    paged_decode_attention,
-                )
-
-                out = paged_decode_attention(
-                    q, new_cache["k"], new_cache["v"],
-                    cache["block_tables"], positions[:, 0] + 1,
-                    k_scale=new_cache.get("k_scale"),
-                    v_scale=new_cache.get("v_scale"),
-                    window=cfg.sliding_window,
-                    interpret=path == "pallas-interpret",
-                ).astype(q.dtype)
-            else:
-                ck, cv = paged_gather(new_cache, cache["block_tables"])
-                out = reference_attention(
-                    q, ck.astype(q.dtype), cv.astype(q.dtype),
-                    causal=True, q_positions=positions,
-                    window=cfg.sliding_window,
-                )
+            # (scopes for a model whose layers differ alone: the others'
+            # programs are named as they were)
+            with (jax.named_scope("dlti_attn_window" if window
+                                  else "dlti_attn_full")
+                  if cfg.layer_windows else contextlib.nullcontext()):
+                out, new_cache = self._over_paged_cache(
+                    q, k, v, cache, positions)
         elif cache is not None:
             # Fixed-capacity cache: (b, max_len, kv_heads, hd). `index` is the
             # write offset (same for the whole batch in the engine's design —
@@ -197,7 +259,7 @@ class LlamaAttention(nn.Module):
             out = reference_attention(
                 q, ck.astype(q.dtype), cv.astype(q.dtype),
                 causal=True, q_positions=positions,
-                window=cfg.sliding_window,
+                window=window,
             )
         elif (self.mesh is not None and "sequence" in self.mesh.shape
               and self.mesh.shape["sequence"] > 1):
@@ -214,7 +276,7 @@ class LlamaAttention(nn.Module):
 
             out = ring_attention(q, k, v, self.mesh, positions=positions,
                                  segment_ids=segment_ids, causal=True,
-                                 window=cfg.sliding_window)
+                                 window=window)
         else:
             window = self._effective_window(segment_ids)
             attend = functools.partial(
@@ -283,22 +345,46 @@ class LlamaMLP(nn.Module):
 
 
 class LlamaBlock(nn.Module):
+    """One layer: ``x + Attn(norm(x))`` then ``x + MLP(norm(x))``, or with
+    ``post_sublayer_norm`` ``x + norm(Attn(x))`` then ``x + norm(MLP(x))``
+    (the same two norms, after their sublayers). The MLP is ``LlamaMLP``;
+    ``MoEMLP`` where ``num_experts`` > 0; with ``moe_num_experts`` > 0
+    ``HeldExpertsMLP`` from layer ``first_k_dense`` on, and the block then
+    returns that layer's counters as a third value."""
+
     cfg: ModelConfig
     lora: Optional[LoRAConfig] = None
     mesh: Optional[Any] = None
+    layer: int = 0
 
     @nn.compact
     def __call__(self, x, cos, sin, positions, segment_ids=None, cache=None,
                  deterministic: bool = True, token_mask=None,
                  adapter_ids=None):
         cfg = self.cfg
-        attn_out, new_cache = LlamaAttention(cfg, self.lora, self.mesh, name="attn")(
-            RMSNorm(cfg.rms_norm_eps, offset=cfg.rmsnorm_offset, name="input_norm")(x),
+        input_norm = RMSNorm(cfg.rms_norm_eps, offset=cfg.rmsnorm_offset,
+                             name="input_norm")
+        post_attn_norm = RMSNorm(cfg.rms_norm_eps, offset=cfg.rmsnorm_offset,
+                                 name="post_attn_norm")
+        after = cfg.post_sublayer_norm
+        attn_out, new_cache = LlamaAttention(
+            cfg, self.lora, self.mesh, self.layer, name="attn")(
+            x if after else input_norm(x),
             cos, sin, positions, segment_ids, cache, deterministic,
             adapter_ids,
         )
-        x = x + attn_out
-        normed = RMSNorm(cfg.rms_norm_eps, offset=cfg.rmsnorm_offset, name="post_attn_norm")(x)
+        x = x + (input_norm(attn_out) if after else attn_out)
+        normed = x if after else post_attn_norm(x)
+        if cfg.moe_num_experts > 0:
+            if self.layer < cfg.first_k_dense:
+                mlp_out, counted = LlamaMLP(cfg, None, name="mlp")(normed), None
+            else:
+                from dlti_tpu.models.moe import HeldExpertsMLP
+
+                mlp_out, counted = HeldExpertsMLP(cfg, name="mlp")(
+                    normed, token_mask)
+            return (x + (post_attn_norm(mlp_out) if after else mlp_out),
+                    new_cache, counted)
         if cfg.num_experts > 0:
             from dlti_tpu.models.moe import MoEMLP
 
@@ -314,7 +400,7 @@ class LlamaBlock(nn.Module):
         else:
             mlp_out = LlamaMLP(cfg, self.lora, name="mlp")(
                 normed, deterministic, adapter_ids)
-        return x + mlp_out, new_cache
+        return x + (post_attn_norm(mlp_out) if after else mlp_out), new_cache
 
 
 def _remat_policy(name: str):
@@ -352,7 +438,11 @@ class LlamaModel(nn.Module):
 
         embed = self.param(
             "embed_tokens",
-            nn.initializers.normal(stddev=0.02),
+            # With held experts, seeded at unit scale as the other held-
+            # expert families are: the residual stream carries the token and
+            # the layers add to it (models.moe.centred_out_init).
+            nn.initializers.normal(
+                stddev=1.0 if cfg.moe_num_experts > 0 else 0.02),
             (cfg.vocab_size, cfg.hidden_size),
             pdtype,
         )
@@ -390,7 +480,10 @@ class LlamaModel(nn.Module):
             # Paged: capacity = logical window = blocks/seq * block_size.
             # Positions are bounded by the engine's seq_len < capacity =
             # table_len by construction (not statically knowable here).
-            table_len = cache[0]["block_tables"].shape[1] * cache[0]["k"].shape[1]
+            # (The widest of the layers' tables: a window group's holds a
+            # window's blocks alone.)
+            table_len = max(c["block_tables"].shape[1] for c in cache) \
+                * cache[0]["k"].shape[1]
         else:
             table_len = cache[0]["k"].shape[1]
             # Decode over a dense cache: the query chunk's positions lie
@@ -406,6 +499,19 @@ class LlamaModel(nn.Module):
                 static_argnums=(7,),  # deterministic (arg 0 is the module)
             )
 
+        counters = None
+        if cfg.moe_num_experts > 0:
+            from dlti_tpu.models.moe import MOE_COUNTERS
+
+            counters = dict.fromkeys(MOE_COUNTERS, jnp.int32(0))
+            # Held experts route and count real tokens alone: not padding,
+            # nor a decode row of a slot that is free or still prefilling
+            # (position 0, a table of the reserved trash block).
+            routed = positions >= 0
+            if cache is not None and "block_tables" in cache[0]:
+                routed = routed & (cache[0]["block_tables"][:, :1] > 0)
+            token_mask = routed if token_mask is None \
+                else routed & token_mask.astype(bool)
         new_caches = [] if cache is not None else None
         for i in range(cfg.num_layers):
             # Selective remat: every remat_stride-th block keeps its
@@ -417,14 +523,22 @@ class LlamaModel(nn.Module):
                     and i % cfg.remat_stride == 0):
                 cls_i = LlamaBlock
             layer_cache = cache[i] if cache is not None else None
-            x, layer_new_cache = cls_i(cfg, self.lora, self.mesh, name=f"layers_{i}")(
+            x, layer_new_cache, *counted = cls_i(
+                cfg, self.lora, self.mesh, i, name=f"layers_{i}")(
                 x, cos, sin, positions, segment_ids, layer_cache, deterministic,
                 token_mask, adapter_ids,
             )
             if cache is not None:
                 new_caches.append(layer_new_cache)
+            if counted and counted[0] is not None:
+                for name, n in zip(counters, counted[0]):
+                    counters[name] = jnp.maximum(counters[name], n) \
+                        if name == "moe_expert_load_max" \
+                        else counters[name] + n
 
         x = RMSNorm(cfg.rms_norm_eps, offset=cfg.rmsnorm_offset, name="final_norm")(x)
+        if counters is not None:
+            return x, new_caches, counters
         return x, new_caches
 
 
@@ -455,16 +569,50 @@ class LlamaForCausalLM(nn.Module):
     lora: Optional[LoRAConfig] = None
     mesh: Optional[Any] = None
 
+    @property
+    def counter_names(self) -> tuple:
+        """What the forward pass counts (``return_counters``): the held
+        experts' counters where the model has them."""
+        if self.cfg.moe_num_experts > 0:
+            from dlti_tpu.models.moe import MOE_COUNTERS
+
+            return MOE_COUNTERS
+        return ()
+
+    @property
+    def prefill_call_tokens(self) -> int:
+        """The most padded tokens of one prefill call (0: no limit): held
+        to ``PREFILL_CALL_TOKENS`` where the layers' windows differ."""
+        return PREFILL_CALL_TOKENS \
+            if len(self.cfg.kv_group_windows) > 1 else 0
+
+    @property
+    def prefill_whole_tables(self) -> bool:
+        """Such a model's prefill calls take each row's whole block table
+        (one program a (rows, bucket) whatever the cached context: the
+        walk over the cache ends at the call's highest position)."""
+        return len(self.cfg.kv_group_windows) > 1
+
     @nn.compact
     def __call__(self, input_ids, positions=None, segment_ids=None, cache=None,
                  deterministic: bool = True, token_mask=None,
-                 return_hidden: bool = False, adapter_ids=None):
+                 return_hidden: bool = False, adapter_ids=None,
+                 return_counters: bool = False):
         cfg = self.cfg
         pdtype = _dtype(cfg.param_dtype)
-        x, new_cache = LlamaModel(cfg, self.lora, self.mesh, name="model")(
+        if cfg.moe_num_experts > 0 and self.lora is not None \
+                and self.lora.enabled:
+            raise NotImplementedError(
+                "LoRA through held experts under the Llama block is not "
+                "implemented (HeldExpertsMLP has no adapter branch)")
+        x, new_cache, *counted = LlamaModel(
+            cfg, self.lora, self.mesh, name="model")(
             input_ids, positions, segment_ids, cache, deterministic, token_mask,
             adapter_ids,
         )
+        if return_counters:
+            # (a model that counts nothing is never asked: counter_names)
+            new_cache = (new_cache, counted[0])
         if return_hidden:
             # Skip the LM head: the caller computes a seq-chunked loss so
             # (B, S, V) fp32 logits are never materialized whole
@@ -472,7 +620,8 @@ class LlamaForCausalLM(nn.Module):
             # still be grafted when this module owns them, so init traces
             # the normal path.
             if not self.is_initializing():
-                return x, new_cache
+                return (x, *new_cache) if return_counters \
+                    else (x, new_cache)
         if cfg.tie_embeddings:
             from dlti_tpu.models.quantization import maybe_dequantize
 
@@ -492,6 +641,8 @@ class LlamaForCausalLM(nn.Module):
                 lm_head = maybe_dequantize(lm_head, x.dtype, anchor=x)
             logits = jnp.dot(x, lm_head.astype(x.dtype),
                              preferred_element_type=jnp.float32)
+        if return_counters:
+            return (logits.astype(jnp.float32), *new_cache)
         return logits.astype(jnp.float32), new_cache
 
     # ------------------------------------------------------------------

@@ -112,6 +112,102 @@ def reference_attention(
     return out.astype(q.dtype)
 
 
+# A prefill call over the paged cache either gathers each row's whole table
+# and scores it at once (``kv_cache.paged_gather`` + ``reference_attention``)
+# or walks the table a block of keys at a time (``attend_over_cache``). The
+# gather reads and scores a table's every key whatever the row's context, so
+# it stays where a table is short: up to this many keys a row (the dense
+# family's serving cells hand a call the narrowest power of two of blocks
+# that holds its rows, 4,096 keys at most, and keep the programs they had).
+GATHER_MAX_KEYS = 8192
+# Keys a step of the walk covers (whole blocks) and queries a walk takes at
+# a time: the float32 scores of a step are (rows, heads, WALK_QUERIES,
+# WALK_KEYS), 64 MiB a row at 64 heads.
+WALK_KEYS = 512
+WALK_QUERIES = 512
+NEG_INF = -1e30
+
+
+def walks_cache(queries: int, table_keys: int, released: bool) -> bool:
+    """Whether a call of ``queries`` tokens a row over a table of
+    ``table_keys`` keys a row walks the cache: from shapes alone. A decode
+    step off the kernel (one query) gathers; a table longer than
+    ``GATHER_MAX_KEYS`` is walked, and so is one whose head has been
+    ``released`` (a window group's: the walk skips what a window hides)."""
+    return queries > 1 and (released or table_keys > GATHER_MAX_KEYS)
+
+
+def attend_over_cache(q, layer_cache, block_tables, positions,
+                      window: int | None = None):
+    """This call's queries against each row's cached keys and values (its
+    own just written among them), ``WALK_KEYS`` keys at a time with an
+    online softmax: never a (heads, queries, context) array.
+
+    ``q`` (b, s, heads, d); ``positions`` (b, s), a key's index in its
+    row's table being its position (-1: a padding token, which sees
+    nothing and reads 0). Queries go ``WALK_QUERIES`` at a time, and a
+    walk runs from the block that holds the lowest key its queries can see
+    (their lowest position less the window; block 0 without one) to their
+    highest position: the trip count is data, so one program serves a
+    (rows, tokens) shape whatever the cached context."""
+    from dlti_tpu.ops.kv_cache import paged_gather
+
+    b, s, num_heads, d = q.shape
+    block_size, kv_heads = layer_cache["k"].shape[1:3]
+    blocks = max(1, WALK_KEYS // block_size)
+    keys = blocks * block_size
+    tables = jnp.pad(block_tables,
+                     ((0, 0), (0, -block_tables.shape[1] % blocks)))
+    group = num_heads // kv_heads
+    scale = d ** -0.5
+    far = tables.shape[1] * block_size  # past every position
+
+    def walk(qb, pos):
+        """qb (b, n, kv_heads, group, d), pos (b, n) -> (b, n, heads, d)."""
+        n = pos.shape[1]
+        last = jnp.max(pos) // keys + 1
+        lowest = jnp.min(jnp.where(pos >= 0, pos, far))
+        first = jnp.maximum(lowest - window + 1, 0) // keys if window else 0
+
+        def step(j, carry):
+            m, l, acc = carry
+            k, v = paged_gather(layer_cache, jax.lax.dynamic_slice_in_dim(
+                tables, j * blocks, blocks, axis=1))
+            k, v = k.astype(q.dtype), v.astype(q.dtype)
+            scores = jnp.einsum("bngqd,bkgd->bgqnk", qb, k,
+                                preferred_element_type=jnp.float32) * scale
+            k_pos = (j * keys + jnp.arange(keys))[None, None, :]
+            visible = k_pos <= pos[:, :, None]
+            if window:
+                visible &= k_pos > pos[:, :, None] - window
+            visible = visible[:, None, None]              # (b, 1, 1, n, k)
+            scores = jnp.where(visible, scores, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(scores, -1, keepdims=True))
+            p = jnp.exp(scores - m_new) * visible
+            alpha = jnp.exp(m - m_new)
+            l = alpha * l + jnp.sum(p, -1, keepdims=True)
+            acc = acc * alpha + jnp.einsum(
+                "bgqnk,bkgd->bgqnd", p.astype(q.dtype), v,
+                preferred_element_type=jnp.float32)
+            return m_new, l, acc
+
+        shape = (b, kv_heads, group, n)
+        m, l, acc = jax.lax.fori_loop(
+            first, last, step,
+            (jnp.full((*shape, 1), NEG_INF, jnp.float32),
+             jnp.zeros((*shape, 1), jnp.float32),
+             jnp.zeros((*shape, d), jnp.float32)))
+        out = acc / jnp.maximum(l, 1e-30)   # a padding token saw no key
+        return jnp.moveaxis(out, 3, 1).reshape(b, n, num_heads, d)
+
+    with jax.named_scope("dlti_attn_over_cache"):
+        qg = q.reshape(b, s, kv_heads, group, d)
+        out = [walk(qg[:, at:at + WALK_QUERIES],
+                    positions[:, at:at + WALK_QUERIES])
+               for at in range(0, s, WALK_QUERIES)]
+        return jnp.concatenate(out, axis=1).astype(q.dtype)
+
+
 def _kernel_path() -> str:
     """``"pallas"`` on the TPU, ``"pallas-interpret"`` on the CPU backend;
     no other backend has a Pallas path in this repo."""

@@ -3,7 +3,9 @@ latents, and the recurrent state of state-space layers by decode slot.
 
 One cache, one entry a layer, three kinds of state (:func:`init_cache`):
 ``{"k", "v"}`` block pools addressed by block tables for attention layers
-(below), ``{"latent"}`` — ONE block pool ``(num_blocks, block_size,
+(below; where a model's layers differ in their attention window they form
+GROUPS, a pool size, a table and an allocator a group:
+:func:`window_group_blocks`, :func:`bind_call`), ``{"latent"}`` — ONE block pool ``(num_blocks, block_size,
 kv_lora_rank + qk_rope_head_dim`` rounded up to whole 128-value lanes``)``
 — for latent-attention layers (:func:`init_latent_cache`: a token's row is
 its normed latent and its rotated shared key, written by one scatter and
@@ -140,17 +142,54 @@ def latent_gather(layer_cache: dict, block_tables: jnp.ndarray) -> jnp.ndarray:
                                       pool.shape[2])
 
 
+def window_blocks(window: int, block_size: int, tokens: int) -> int:
+    """Blocks a sequence holds of a window group while it writes ``tokens``
+    tokens more: those of the ``window`` keys before them, theirs, and one
+    for each end that falls inside a block."""
+    return -(-(window + tokens) // block_size) + 2
+
+
+def window_group_blocks(window: int, block_size: int, num_slots: int,
+                        call_tokens: int, decode_steps: int = 1) -> int:
+    """Blocks of a window group's pool, from shapes alone: what every slot
+    holds between two calls and through a decode round of ``decode_steps``
+    steps, the tokens of the widest prefill call (``call_tokens``, over at
+    most 8 rows, an edge block each), and the trash block. The allocator
+    (``serving.engine``) releases a block as soon as it lies wholly before
+    its sequence's window, so this pool never runs out while the engine
+    holds to ``call_tokens``: the full group's pool (``--num-blocks``) is
+    what admission waits for."""
+    return (num_slots * window_blocks(window, block_size, decode_steps)
+            + -(-call_tokens // block_size) + 8 + 1)
+
+
 def init_cache(model_cfg, num_blocks: int, block_size: int, num_slots: int,
-               dtype=jnp.bfloat16) -> List[dict]:
+               dtype=jnp.bfloat16, call_tokens: int = 0,
+               decode_steps: int = 1) -> List[dict]:
     """The serving cache of ``model_cfg``, one entry a layer by its kind.
     A model without a ``layer_pattern`` is attention in every layer, over
-    latents where the configuration has a ``kv_lora_rank``."""
+    latents where the configuration has a ``kv_lora_rank``. Attention
+    layers of one window form a group (``ModelConfig.kv_group_windows``):
+    the group that sees every key, and every model's only group, has pools
+    of ``num_blocks``; a window group's are sized by
+    :func:`window_group_blocks` (``call_tokens``: the most tokens of a
+    prefill call; ``decode_steps``: of a decode round)."""
     from dlti_tpu.utils.dtypes import resolve_dtype
 
     if model_cfg.latent_dim:
         return [init_latent_cache(num_blocks, block_size,
                                   model_cfg.latent_dim, dtype)
                 for _ in range(model_cfg.num_layers)]
+
+    groups = model_cfg.kv_group_windows
+    if len(groups) > 1:
+        sizes = [num_blocks if not w else window_group_blocks(
+            w, block_size, num_slots, call_tokens, decode_steps)
+            for w in groups]
+        return [init_paged_cache(
+            1, sizes[model_cfg.kv_group_of_layer(i)], block_size,
+            model_cfg.num_kv_heads, model_cfg.resolved_head_dim, dtype)[0]
+            for i in range(model_cfg.num_layers)]
 
     def paged():
         return init_paged_cache(1, num_blocks, block_size,
@@ -169,15 +208,24 @@ def init_cache(model_cfg, num_blocks: int, block_size: int, num_slots: int,
 
 
 # What one program call adds to the layers' entries (:func:`bind_call`).
-_CALL_KEYS = ("block_tables", "state_slots", "own_rows")
+_CALL_KEYS = ("block_tables", "table_base", "state_slots", "own_rows")
 
 
 def bind_call(cache: List[dict], block_tables, state_slots=None,
-              own_rows: bool = False) -> List[dict]:
+              own_rows: bool = False, groups=None) -> List[dict]:
     """The cache as one program call hands it to the model: every layer's
     entry with the rows' block tables, and a recurrent layer's with each
     row's decode slot (``state_slots`` (rows,): out of range for a row that
-    must write no state; ``own_rows``: a decode call, row i is slot i)."""
+    must write no state; ``own_rows``: a decode call, row i is slot i).
+
+    A model with several groups of attention layers (``groups``: each
+    layer's group) gives ``block_tables`` as a tuple, a dict a group of
+    what its layers' entries get: ``{"block_tables"}`` for the group that
+    sees every key, ``{"block_tables", "table_base"}`` for a window group,
+    whose table starts at the block that holds token ``table_base`` (rows,)
+    of each row (what lies before has been released)."""
+    if groups is not None:
+        return [{**c, **block_tables[g]} for c, g in zip(cache, groups)]
     return [{**c, "block_tables": block_tables,
              **({"state_slots": state_slots, "own_rows": own_rows}
                 if "ssm" in c else {})} for c in cache]

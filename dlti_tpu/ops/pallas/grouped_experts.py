@@ -35,6 +35,14 @@ chunks is refused: ``models.moe.takes_grouped`` asks ``takes_width`` and
 leaves such a layer on the mask (what that leaves out, and why the form
 that took any width whole was not kept: the note above
 ``models.moe.GROUPED_MIN_TOKENS``).
+
+An expert whose matrices, two buffers each, do not fit ``VMEM_WEIGHTS``
+(6,144 x 2,048 gated: 144 MB of the v5e's 128 MiB, which the chip's
+compiler refuses) has its width in grid blocks after all
+(``width_block``, from shapes alone): a second grid axis over blocks of
+whole chunks, the tile's float32 result kept across them. Every tile then
+re-reads its expert; an expert that fits keeps the one-axis grid and the
+program it had.
 """
 
 from __future__ import annotations
@@ -53,9 +61,27 @@ from dlti_tpu.ops.pallas.flash_attention import out_struct
 WIDTH_CHUNK = 256
 
 
+# What the blocks of one expert's matrices, two buffers each, may take of
+# VMEM (the kernel is given 100 MiB at most, for them, the tiles of rows and
+# the float32 activations).
+VMEM_WEIGHTS = 80 << 20
+
+
 def takes_width(width: int) -> bool:
     """Whether the kernel takes experts ``width`` wide: whole chunks."""
     return width % WIDTH_CHUNK == 0
+
+
+def width_block(h: int, f: int, itemsize: int, gated: bool) -> int:
+    """Columns of an expert's width one grid block holds: the whole width
+    where its matrices fit ``VMEM_WEIGHTS`` twice over, else the most whole
+    chunks that divide the width and fit."""
+    chunks = f // WIDTH_CHUNK
+    for n in range(1, chunks + 1):
+        if chunks % n == 0 and \
+                2 * (2 + gated) * h * (f // n) * itemsize <= VMEM_WEIGHTS:
+            return f // n
+    return WIDTH_CHUNK
 
 
 def num_tiles(assignments: int, experts: int, tile_rows: int) -> int:
@@ -106,7 +132,8 @@ def group_rows(local: jnp.ndarray, sizes: jnp.ndarray, tile_rows: int):
             (ends[-1] // tile_rows).astype(jnp.int32))
 
 
-def _kernel(tile_expert_ref, x_ref, *refs, gated: bool, chunk: int):
+def _kernel(tile_expert_ref, x_ref, *refs, gated: bool, chunk: int,
+            blocks: int = 1):
     del tile_expert_ref  # read by the index maps
     if gated:
         w_gate_ref, w_up_ref, w_down_ref, o_ref, acc_ref = refs
@@ -126,16 +153,29 @@ def _kernel(tile_expert_ref, x_ref, *refs, gated: bool, chunk: int):
             else jnp.square(jnp.maximum(act, 0.0))
         return dot(act.astype(x.dtype), w_down_ref[cols, :])
 
-    # The width a chunk at a time, in a loop and not unrolled: the body's
-    # code is a chunk's, and so is the float32 activation.
-    acc_ref[...] = jnp.zeros_like(acc_ref)
-
     def step(c, carry):
         acc_ref[...] += part(pl.ds(pl.multiple_of(c * chunk, chunk), chunk))
         return carry
 
+    # The width a chunk at a time, in a loop and not unrolled: the body's
+    # code is a chunk's, and so is the float32 activation.
+    if blocks == 1:
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        jax.lax.fori_loop(0, w_down_ref.shape[0] // chunk, step, 0)
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+        return
+
+    # The width in grid blocks (axis 1): the tile's result is kept across
+    # them and written with the last.
+    @pl.when(pl.program_id(1) == 0)
+    def _first():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
     jax.lax.fori_loop(0, w_down_ref.shape[0] // chunk, step, 0)
-    o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+    @pl.when(pl.program_id(1) == blocks - 1)
+    def _last():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
 def grouped_experts(
@@ -188,26 +228,36 @@ def _forward_only(x, tile_expert, tiles, w_gate, w_up, w_down, tile_rows,
         raise ValueError(f"grouped_experts: experts {f} wide are not whole "
                          f"chunks of {chunk} columns")
 
-    # An expert's whole matrix is one block.
-    inner = pl.BlockSpec((None, h, f), lambda i, e: (e[i], 0, 0))
-    by_tile = pl.BlockSpec((tile_rows, h), lambda i, e: (i, 0))
     item = x.dtype.itemsize
+    fb = width_block(h, f, item, gated)
+    blocks = f // fb
+    if blocks == 1:
+        # An expert's whole matrix is one block.
+        grid = (jnp.maximum(tiles, 1),)
+        inner = pl.BlockSpec((None, h, f), lambda i, e: (e[i], 0, 0))
+        outer = pl.BlockSpec((None, f, h), lambda i, e: (e[i], 0, 0))
+        by_tile = pl.BlockSpec((tile_rows, h), lambda i, e: (i, 0))
+    else:
+        grid = (jnp.maximum(tiles, 1), blocks)
+        inner = pl.BlockSpec((None, h, fb), lambda i, j, e: (e[i], 0, j))
+        outer = pl.BlockSpec((None, fb, h), lambda i, j, e: (e[i], j, 0))
+        by_tile = pl.BlockSpec((tile_rows, h), lambda i, j, e: (i, 0))
     # Two buffers a block, and the float32 activations and result.
-    vmem = (2 * ((2 + gated) * h * f + 2 * tile_rows * h) * item
-            + 4 * tile_rows * (h + 3 * f))
+    vmem = (2 * ((2 + gated) * h * fb + 2 * tile_rows * h) * item
+            + 4 * tile_rows * (h + 3 * fb))
     call = pl.pallas_call(
-        functools.partial(_kernel, gated=gated, chunk=chunk),
+        functools.partial(_kernel, gated=gated, chunk=chunk,
+                          **({} if blocks == 1 else {"blocks": blocks})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(jnp.maximum(tiles, 1),),
-            in_specs=[by_tile] + [inner] * (1 + gated) + [
-                pl.BlockSpec((None, f, h), lambda i, e: (e[i], 0, 0))],
+            grid=grid,
+            in_specs=[by_tile] + [inner] * (1 + gated) + [outer],
             out_specs=by_tile,
             scratch_shapes=[pltpu.VMEM((tile_rows, h), jnp.float32)],
         ),
         out_shape=out_struct((rows, h), x.dtype, x),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
+            dimension_semantics=("arbitrary",) * len(grid),
             vmem_limit_bytes=int(min(max(vmem * 5 // 4, 32 << 20),
                                      100 << 20))),
         interpret=interpret,

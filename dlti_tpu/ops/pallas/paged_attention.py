@@ -14,8 +14,10 @@ Design:
 * **One software pipeline over the live tiles of the whole batch.** A tile
   is T consecutive logical blocks of one sequence (``tile_blocks``: T x
   ``block_size`` = ``TILE_KEYS`` = 256 keys at the serving cells' shapes).
-  ``live_tiles`` lists, in XLA and once a decode step (every layer's call
-  shares it), each sequence's tiles inside ``[seq_len - window, seq_len)``;
+  ``live_tiles`` lists, in XLA and once a decode step for each (table,
+  window) the layers' calls are given (the layers of one window share it: one
+  schedule for most models, two where window and full layers are mixed),
+  each sequence's tiles inside ``[seq_len - window, seq_len)``;
   the kernel has a grid of one step and a ``fori_loop`` over that list, so
   a tile past a context, or before a window, is never a step at all.
 * **The pools stay in HBM** (``memory_space=ANY``). A step starts the next
@@ -114,6 +116,8 @@ def tile_tokens(block_size: int, max_blocks: int, token_bytes: int = 0) -> int:
 
 def live_tiles(seq_lens, keys: int, window: int, steps: int):
     """The kernel's schedule: every live tile of the batch, row by row.
+    (A window group's call gives lengths counted from its table's first
+    block, ``ops.kv_cache.bind_call``: the band is the same keys.)
 
     Returns ``(row, tile, total)``: entries ``[0, total)`` of ``row`` and
     ``tile`` (each ``(steps,)`` int32) name a sequence and one of its tiles

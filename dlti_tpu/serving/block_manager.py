@@ -2,7 +2,11 @@
 
 The bookkeeping half of the paged cache (device half:
 ``dlti_tpu.ops.kv_cache``) — the role vLLM's C++/Python BlockManager plays in
-the stack the reference claims but doesn't ship (``README.md:10``).
+the stack the reference claims but doesn't ship (``README.md:10``). One
+manager a pool: a model whose layers differ in their attention window has
+two groups of layers, a pool size and a manager each
+(``serving.engine``: the group that sees every key, and the window group,
+whose blocks are released behind the window while their sequence lives).
 
 Allocation is delegated to the C++ core via ctypes (``native/``, built from
 source on first use — ``dlti_tpu.utils.native``); where that cannot be

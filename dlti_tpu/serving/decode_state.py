@@ -54,11 +54,20 @@ class RoundPacking:
     top_k | top_p | extra]``, one row a slot, int32 by bits."""
 
     def __init__(self, num_slots: int, max_blocks: int,
-                 extra_field: Optional[str] = None):
+                 extra_field: Optional[str] = None, window_blocks: int = 0):
+        """``window_blocks`` > 0 (a model with a window group of layers):
+        that group's table, as wide as a slot's live blocks need, and the
+        token its first column starts at ride after ``top_p``:
+        ``window_tables (window_blocks) | window_base``."""
         self.num_slots = num_slots
         self.extra_field = extra_field
-        cols = _COLUMNS if extra_field is None \
-            else _COLUMNS + ((extra_field, 1, np.int32, True),)
+        self.window_blocks = window_blocks
+        cols = _COLUMNS
+        if window_blocks:
+            cols += (("window_tables", window_blocks, np.int32, False),
+                     ("window_base", 1, np.int32, True))
+        if extra_field is not None:
+            cols += ((extra_field, 1, np.int32, True),)
         # name -> (first column, columns, dtype, one value a slot)
         self.columns: Dict[str, Tuple[int, int, type, bool]] = {}
         at = 0
@@ -83,19 +92,27 @@ class RoundPacking:
                 raise TypeError(f"{name} is {src.dtype}, packed as {dt}")
             out[:, at:at + n] = src.view(np.int32).reshape(self.num_slots, n)
         if len(masked_rows):
-            at, n, _, _ = self.columns["block_tables"]
-            out[list(masked_rows), at:at + n] = 0
+            for name in ("block_tables", "window_tables", "window_base"):
+                if name in self.columns:
+                    at, n, _, _ = self.columns[name]
+                    out[list(masked_rows), at:at + n] = 0
         return out
 
     def unpack(self, packed: jax.Array) -> tuple:
         """``packed`` back in the decode programs' argument order:
         ``(input_ids (S, 1), positions (S, 1), block_tables, slot_keys,
         gen_counts, temperature, top_k, top_p[, extra])``. Traced: the
-        programs' first lines."""
-        out = []
-        for at, n, dt, scalar in self.columns.values():
+        programs' first lines. With a window group ``block_tables`` is the
+        tuple ``ops.kv_cache.bind_call`` takes: a dict a group."""
+        out = {}
+        for name, (at, n, dt, scalar) in self.columns.items():
             x = packed[:, at] if scalar else packed[:, at:at + n]
             if dt is not np.int32:
                 x = jax.lax.bitcast_convert_type(x, jnp.dtype(dt))
-            out.append(x)
-        return tuple(out)
+            out[name] = x
+        if self.window_blocks:
+            out["block_tables"] = (
+                {"block_tables": out["block_tables"]},
+                {"block_tables": out.pop("window_tables"),
+                 "table_base": out.pop("window_base")})
+        return tuple(out.values())

@@ -45,6 +45,7 @@ import numpy as np
 
 from dlti_tpu.config import LoRAConfig, ModelConfig
 from dlti_tpu.serving.adapters import AdapterError
+from dlti_tpu.ops.kv_cache import window_blocks, window_group_blocks
 from dlti_tpu.serving.block_manager import BlockManager
 from dlti_tpu.serving.executor import (
     RIDES, EngineExecutor, PrefillCallRefused)
@@ -355,6 +356,11 @@ class _Slot:
         # Leading blocks this sequence shares with the prefix cache since
         # its admission (hits and tier restores): already matchable.
         self.shared_blocks = 0
+        # A window group's blocks (a model whose layers differ in their
+        # window): those of logical blocks [window_first, window_first +
+        # len(window_blocks)); what lay before has been released.
+        self.window_blocks: List[int] = []
+        self.window_first = 0
 
     @property
     def free(self) -> bool:
@@ -365,10 +371,27 @@ class _Slot:
         return self.request is not None and self.next_pos < self.prefill_end
 
 
+def _pairs_under_window(n: int, window: int) -> int:
+    """(query, key) pairs the first ``n`` positions of a sequence can see
+    under ``window``: the sum over positions i < n of min(i + 1, window)."""
+    if n <= window:
+        return n * (n + 1) // 2
+    return window * (window + 1) // 2 + (n - window) * window
+
+
 def refuse_state_handoff(model_cfg: ModelConfig, what: str) -> None:
     """Disaggregated serving and k/v hand-off move a sequence as its k/v
-    blocks; a recurrent state is not in them, and a latent block is not
-    what the wire format packs."""
+    blocks; a recurrent state is not in them, a latent block is not what
+    the wire format packs, and a model whose layers differ in their window
+    keeps a list of blocks a group of layers."""
+    if len(model_cfg.kv_group_windows) > 1:
+        raise ValueError(
+            f"{what} moves a sequence between engines as ONE list of k/v "
+            f"blocks that are all there; a model whose layers differ in "
+            f"their attention window keeps a list a group of layers, the "
+            f"window group's released behind the window "
+            f"(wire.pack_handoff carries neither). Serve it colocated (no "
+            f"--disagg)")
     if model_cfg.latent_dim:
         raise ValueError(
             f"{what} moves a sequence between engines as its k/v blocks "
@@ -463,6 +486,10 @@ class InferenceEngine:
                       # decode_steps is the mean context one step reads
                       # (what the paged-attention kernel's bytes follow).
                       "decode_context_tokens": 0,
+                      # The same under a window group's window: each slot
+                      # adds min(seq_len, window). What the window layers'
+                      # kernel calls read; 0 for a model with one group.
+                      "decode_window_context_tokens": 0,
                       # Keys the paged decode kernel's live tiles hold for
                       # the same rounds: a slot about to attend over n keys
                       # costs ceil(n / tile) whole tiles (the tile: the
@@ -488,6 +515,9 @@ class InferenceEngine:
                       # attention's products of a prefill are counted
                       # from, whatever the model.
                       "prefill_attention_pairs": 0,
+                      # The same under a window group's window (a query
+                      # sees at most ``window`` keys); 0 with one group.
+                      "prefill_window_attention_pairs": 0,
                       # Tokens whose KV came back from a LOWER tier (host
                       # or disk) via a restore scatter instead of either
                       # an HBM hit or a re-prefill. Present (at 0) even
@@ -541,6 +571,26 @@ class InferenceEngine:
             self.stats[name] = self.stats[f"{name}_decode"] = 0
         ec = engine_cfg
         self.block_manager = BlockManager(ec.num_blocks, ec.block_size)
+        # A model whose layers differ in their attention window has a
+        # second group of layers with pools, an allocator and tables of its
+        # own (ops.kv_cache.window_group_blocks): a sequence holds there
+        # the blocks of its last ``window`` keys alone, and they are
+        # released as the sequence moves on (:meth:`_window_cover`).
+        self.window = 0
+        self.window_manager = None
+        groups = model_cfg.kv_group_windows
+        if len(groups) > 1:
+            self.window = groups[1]
+            self.window_manager = BlockManager(
+                window_group_blocks(
+                    self.window, ec.block_size, ec.max_seqs,
+                    self.executor.prefill_call_tokens, ec.steps_per_sync),
+                ec.block_size)
+        # The cache's books (kv_metrics): blocks released by group and why,
+        # and the seconds the window group's release took.
+        self.kv_freed = {("full", "end"): 0, ("window", "window"): 0,
+                         ("window", "end"): 0}
+        self.kv_window_free_s = 0.0
         self.prefix_cache = None
         if ec.enable_prefix_caching:
             from dlti_tpu.serving.prefix_cache import PrefixCachingAllocator
@@ -571,6 +621,12 @@ class InferenceEngine:
         # Host mirrors of the per-slot device inputs.
         S, MB = ec.max_seqs, ec.max_blocks_per_seq
         self._block_tables = np.zeros((S, MB), np.int32)
+        # The window group's table of a decode round, as wide as a slot's
+        # live blocks need, and the token its column 0 starts at.
+        self._window_tables = np.zeros(
+            (S, window_blocks(self.window, ec.block_size, ec.steps_per_sync)
+             if self.window else 0), np.int32)
+        self._window_base = np.zeros((S,), np.int32)
         self._temperature = np.ones((S,), np.float32)
         self._top_k = np.zeros((S,), np.int32)
         self._top_p = np.ones((S,), np.float32)
@@ -910,6 +966,86 @@ class InferenceEngine:
         if self.prefix_cache is not None:
             return self.prefix_cache.allocate(n)
         return self.block_manager.allocate(n)
+
+    def _window_cover(self, slot: _Slot, first: int, upto: int) -> None:
+        """The window group's blocks of ``slot`` for a call that writes
+        positions ``[first, upto)``: release what lies wholly before the
+        lowest key the call can see (``first - window + 1``), then take
+        blocks up to ``upto``. A released block may still be read by a
+        program in flight: whatever writes it next is dispatched behind
+        that program on the one device queue (as a block freed at a
+        sequence's end always was). The pool is sized so that this never
+        waits (``ops.kv_cache.window_group_blocks``)."""
+        bs = self.cfg.block_size
+        keep_from = max(0, first - self.window + 1) // bs
+        drop = max(0, min(keep_from - slot.window_first,
+                          len(slot.window_blocks)))
+        if drop:
+            t0 = time.monotonic()
+            self.window_manager.free(slot.window_blocks[:drop])
+            del slot.window_blocks[:drop]
+            self.kv_freed["window", "window"] += drop
+            t1 = time.monotonic()
+            self.kv_window_free_s += t1 - t0
+            if self._tracer.enabled:
+                self._tracer.complete("engine/window_free", t0, t1,
+                                      cat="engine", blocks=drop)
+        slot.window_first = slot.window_first + drop \
+            if slot.window_blocks else keep_from
+        need = -(-upto // bs) - slot.window_first - len(slot.window_blocks)
+        if need > 0:
+            got = self.window_manager.allocate(need)
+            if got is None:
+                raise RuntimeError(
+                    f"the window group's pool is out of blocks "
+                    f"({self.window_manager.num_free} free, {need} asked): "
+                    f"it is sized for calls of at most "
+                    f"{self.executor.prefill_call_tokens} tokens")
+            slot.window_blocks.extend(got)
+        if drop or need > 0:
+            row = self._window_tables[slot.slot_id]
+            n = min(len(slot.window_blocks), len(row))
+            row[:n] = slot.window_blocks[:n]
+            row[n:] = 0
+            self._window_base[slot.slot_id] = slot.window_first * bs
+
+    def kv_metrics(self) -> tuple:
+        """The cache's gauges and counters, read from the engine's books
+        at a scrape: blocks in use and in the pool by group (a model with
+        one group reports ``full`` alone), the tokens the live slots hold,
+        blocks released by group and why, the window group's release
+        seconds."""
+        from dlti_tpu.telemetry.registry import ReadCounter, ReadGauge
+
+        managers = {"full": self.block_manager}
+        if self.window_manager is not None:
+            managers["window"] = self.window_manager
+
+        def in_use():
+            return {g: m.num_blocks - 1 - m.num_free
+                    for g, m in managers.items()}
+
+        return (
+            ReadGauge("dlti_kv_blocks_in_use", in_use, "group",
+                      help="blocks of the cache handed out, by group of "
+                           "layers (the trash block apart)"),
+            ReadGauge("dlti_kv_pool_blocks",
+                      lambda: {g: m.num_blocks for g, m in managers.items()},
+                      "group", help="blocks of a layer's pool, by group"),
+            ReadGauge("dlti_kv_context_tokens",
+                      lambda: {"": sum(s.seq_len for s in self.slots)},
+                      help="tokens the live slots have in the cache"),
+            ReadCounter("dlti_kv_blocks_freed_total",
+                        lambda: {k: v for k, v in self.kv_freed.items()
+                                 if k[0] in managers},
+                        ("group", "why"),
+                        help="blocks released: behind a window, or at a "
+                             "sequence's end"),
+            ReadCounter("dlti_kv_window_free_seconds_total",
+                        lambda: {"": self.kv_window_free_s},
+                        help="seconds the stepper spent releasing a window "
+                             "group's blocks"),
+        )
 
     def _doing(self) -> str:
         """For the log line of a program built after start-up: the shape of
@@ -1350,6 +1486,18 @@ class InferenceEngine:
         ids = np.zeros((B, bucket), np.int32)
         pos = np.full((B, bucket), -1, np.int32)  # -1 -> write dropped
         bt = np.zeros((B, nblk_bucket), np.int32)
+        if self.window_manager is not None:
+            # The window group's table of the call: each row's blocks from
+            # the first its tokens can see, as wide as the widest call
+            # needs (one program a (rows, bucket)).
+            wt = np.zeros((B, window_blocks(
+                self.window, ec.block_size,
+                self.executor.prefill_call_tokens)), np.int32)
+            wbase = np.zeros((B,), np.int32)
+            for r, (slot, tokens, start, _) in enumerate(chunks):
+                self._window_cover(slot, start, start + len(tokens))
+                wt[r, :len(slot.window_blocks)] = slot.window_blocks
+                wbase[r] = slot.window_first * ec.block_size
         last_idx = np.zeros((B,), np.int32)
         slot_keys = np.zeros((B, 2), np.uint32)
         counts = np.zeros((B,), np.int32)
@@ -1380,10 +1528,20 @@ class InferenceEngine:
         if any(is_last for *_, is_last in chunks):
             sample = {"slot_keys": slot_keys, "gen_counts": counts,
                       "temperature": temps, "top_k": top_k, "top_p": top_p}
+        if self.window_manager is not None:
+            bt = ({"block_tables": bt},
+                  {"block_tables": wt, "table_base": wbase})
         sampled = self.executor.prefill(
             bucket, input_ids=ids, positions=pos, block_tables=bt,
             last_idx=last_idx, adapter_ids=adapter_ids,
             state_slots=state_slots, sample=sample)
+        if self.window_manager is not None:
+            # What the call has written lies behind the next one's window
+            # but for its last ``window`` keys: released now, so that a
+            # pass of several calls holds one call's blocks at a time.
+            for slot, tokens, start, _ in chunks:
+                self._window_cover(slot, start + len(tokens),
+                                   start + len(tokens))
         # Booked for a call that went out (a refused one raised above).
         self.stats["prefill_batches"] += 1
         self.stats["prefill_widest_call_tokens"] = max(
@@ -1393,6 +1551,10 @@ class InferenceEngine:
         self.stats["prefill_attention_pairs"] += sum(
             c[2] * len(c[1]) + len(c[1]) * (len(c[1]) + 1) // 2
             for c in chunks)
+        if self.window:
+            self.stats["prefill_window_attention_pairs"] += sum(
+                _pairs_under_window(c[2] + len(c[1]), self.window)
+                - _pairs_under_window(c[2], self.window) for c in chunks)
         return sampled
 
     def _count(self, counters: np.ndarray, decode: bool) -> None:
@@ -1406,6 +1568,8 @@ class InferenceEngine:
 
     def _state_mirrors(self) -> dict:
         return {"block_tables": self._block_tables,
+                **({"window_tables": self._window_tables,
+                    "window_base": self._window_base} if self.window else {}),
                 "slot_keys": self._slot_keys,
                 "gen_counts": self._gen_counts,
                 "temperature": self._temperature,
@@ -1536,8 +1700,8 @@ class InferenceEngine:
                     # are 0), so don't allocate — and possibly preempt
                     # for — the full window.
                     window = self.cfg.spec_rounds
-                need = self.block_manager.blocks_needed(
-                    slot.seq_len + (slot.slot_id in riding) + window)
+                at = slot.seq_len + (slot.slot_id in riding)
+                need = self.block_manager.blocks_needed(at + window)
                 while need > len(slot.blocks):
                     got = self._alloc(1)
                     if got is None:
@@ -1548,6 +1712,10 @@ class InferenceEngine:
                     slot.blocks.extend(got)
                     self._block_tables[
                         slot.slot_id, len(slot.blocks) - 1] = got[0]
+                if self.window_manager is not None:
+                    # (between rounds: behind the first position the round
+                    # writes, which is the shortest it can end at)
+                    self._window_cover(slot, at, at + window)
             return True
 
         if ahead_of is not None:
@@ -1778,6 +1946,9 @@ class InferenceEngine:
                            np.int64, len(active))
         tile = self.executor.decode_tile_tokens
         self.stats["decode_context_tokens"] += int(lens.sum()) * steps
+        if self.window:
+            self.stats["decode_window_context_tokens"] += \
+                int(np.minimum(lens, self.window).sum()) * steps
         self.stats["decode_kernel_tile_tokens"] += \
             int((lens // tile + 1).sum()) * tile * steps
 
@@ -1974,6 +2145,12 @@ class InferenceEngine:
                                                ns=req.adapter or None)
         else:
             self.block_manager.free(slot.blocks)
+        self.kv_freed["full", "end"] += len(slot.blocks)
+        if slot.window_blocks:
+            self.window_manager.free(slot.window_blocks)
+            self.kv_freed["window", "end"] += len(slot.window_blocks)
+        slot.window_blocks = []
+        slot.window_first = 0
         if slot.request is not None:
             self._release_adapter(slot.request)
             self._settle_prefill_stall(slot.request)
@@ -1992,6 +2169,8 @@ class InferenceEngine:
         recurrent state to write (it is dropped with the slot: nothing
         reads it again, and the next admission starts from zero)."""
         self._block_tables[slot_id] = 0
+        self._window_tables[slot_id] = 0
+        self._window_base[slot_id] = 0
         self._temperature[slot_id] = 1.0
         self._top_k[slot_id] = 0
         self._top_p[slot_id] = 1.0

@@ -21,7 +21,9 @@ import numpy as np
 
 from dlti_tpu.config import LoRAConfig, ModelConfig
 from dlti_tpu.models import build_model
-from dlti_tpu.ops.kv_cache import bind_call, init_cache, unbind_call
+from dlti_tpu.ops.kv_cache import (
+    bind_call, init_cache, unbind_call, window_blocks,
+)
 from dlti_tpu.ops.pallas.paged_attention import tile_tokens
 from dlti_tpu.serving.decode_state import RoundPacking
 from dlti_tpu.serving.sampling import sample_tokens
@@ -85,6 +87,7 @@ def refuse_unsupported(model_cfg: ModelConfig, engine_cfg: "EngineConfig",
             f"engine's speculative rounds draft by ngram alone; serve the "
             f"model's own layers with num_nextn_predict_layers 0")
     if not (model_cfg.layer_pattern or model_cfg.latent_dim):
+        refuse_unsupported_dense(model_cfg, engine_cfg, mesh)
         return
     ec = engine_cfg
     what = (f"a model with layer_pattern {model_cfg.layer_pattern!r}"
@@ -139,6 +142,57 @@ def refuse_unsupported(model_cfg: ModelConfig, engine_cfg: "EngineConfig",
         raise ValueError(
             f"{what} has no multi-LoRA adapter branch; serve it with "
             f"--adapter-slots 0")
+
+
+def refuse_unsupported_dense(model_cfg: ModelConfig,
+                             engine_cfg: "EngineConfig", mesh=None) -> None:
+    """The same for the Llama family: what takes a sequence's cache to be
+    ONE list of blocks that are all there, when the layers' windows differ
+    (a window group's blocks are released behind the window); and what
+    held experts under the Llama block do not implement."""
+    ec = engine_cfg
+    groups = model_cfg.kv_group_windows
+    if len(groups) > 1:
+        what = (f"a model whose layers differ in their attention window "
+                f"(layer_windows: {groups[1]} and every key) keeps a block "
+                f"list a group of layers and releases a window group's "
+                f"blocks behind the window, and ")
+        if (ec.enable_prefix_caching or ec.prefix_host_blocks > 0
+                or ec.prefix_disk_blocks > 0):
+            raise ValueError(
+                what + "prefix caching (and its host/disk tiers) matches "
+                "and shares one list of whole blocks: a hit would need the "
+                "prefix's last window in the window group too. Serve it "
+                "without --enable-prefix-caching")
+        if ec.speculative != "none":
+            raise ValueError(
+                what + "speculative decoding rolls rejected drafts back by "
+                "position over blocks that may have been released. Serve "
+                "it with --speculative none")
+        if mesh is not None:
+            raise ValueError(
+                what + "its tables have no tensor-parallel placement; "
+                "serve it on one chip per replica")
+    if model_cfg.moe_num_experts > 0:
+        what = (f"a model with held routed experts "
+                f"({model_cfg.moe_held} of {model_cfg.moe_num_experts})")
+        if mesh is not None:
+            raise ValueError(
+                f"{what} has no tensor-parallel sharding rules for its "
+                f"expert layers; serve it on one chip per replica")
+        if ec.quantization != "none":
+            raise ValueError(
+                f"{what} is served in its own precision: weight-only "
+                f"{ec.quantization} is not implemented for expert layers")
+        if ec.adapter_slots > 0:
+            raise ValueError(
+                f"{what} has no multi-LoRA adapter branch; serve it with "
+                f"--adapter-slots 0")
+        if ec.speculative != "none":
+            raise ValueError(
+                f"{what} counts what its forward pass did, which the "
+                f"speculative program does not thread. Serve it with "
+                f"--speculative none")
 
 
 class EngineExecutor:
@@ -283,8 +337,16 @@ class EngineExecutor:
         dtype = "int8" if ec.cache_dtype == "int8" else resolve_dtype(ec.cache_dtype)
         # One cache, one entry a layer: block pools of keys and values for
         # attention layers, per-slot recurrent state for Mamba-2 layers.
-        self.cache = init_cache(model_cfg, ec.num_blocks, ec.block_size,
-                                ec.max_seqs, dtype)
+        # Attention layers of one window form a group with its own pools,
+        # table and allocator (one group for a model whose layers agree).
+        self.kv_groups = model_cfg.kv_group_windows
+        self._layer_groups = None if len(self.kv_groups) == 1 else [
+            model_cfg.kv_group_of_layer(i)
+            for i in range(model_cfg.num_layers)]
+        self.cache = init_cache(
+            model_cfg, ec.num_blocks, ec.block_size, ec.max_seqs, dtype,
+            call_tokens=self.prefill_call_tokens,
+            decode_steps=ec.steps_per_sync)
         if mesh is not None:
             self._shard_for_tp(mesh)
         elif self._device is not None:
@@ -343,8 +405,11 @@ class EngineExecutor:
                             else model_cfg.param_dtype),
             "kv_cache_dtype": ec.cache_dtype,
             "prefill_attention": "xla",
-            "prefill_attention_reason":
-                "prefill attends over the gathered paged window",
+            "prefill_attention_reason": (
+                "prefill walks the paged cache in blocks of keys"
+                if self._layer_groups is not None else
+                "prefill attends over the gathered paged window"),
+            "kv_groups": list(self.kv_groups),
             "paged_decode": decode_path,
             "paged_decode_reason": decode_why,
             "host_staging": ("pinned_host" if self._demote_sharding
@@ -404,7 +469,9 @@ class EngineExecutor:
         # programs unpack themselves: one transfer and one program call a
         # round, nothing per-slot resident between rounds.
         self.round_packing = RoundPacking(
-            ec.max_seqs, ec.max_blocks_per_seq, self._row_extra)
+            ec.max_seqs, ec.max_blocks_per_seq, self._row_extra,
+            window_blocks=0 if self._layer_groups is None else window_blocks(
+                self.kv_groups[1], ec.block_size, ec.steps_per_sync))
         # Where a round goes, committed: this engine's device, or every
         # chip of the tensor mesh (replicated).
         self._round_sharding = self._device
@@ -474,7 +541,8 @@ class EngineExecutor:
         per batch row) gathers each row's factors inside LoRADense; both
         absent leaves the traced program identical to an adapter-free
         engine (the branch is Python-static)."""
-        cache = bind_call(cache_kv, block_tables, state_slots, own_rows)
+        cache = bind_call(cache_kv, block_tables, state_slots, own_rows,
+                          groups=self._layer_groups)
         variables = {"params": params}
         kw = {}
         if adapters is not None:
@@ -820,12 +888,15 @@ class EngineExecutor:
         fit the device raises from the call itself, and the donated cache
         is consumed only by a dispatch that succeeded (checked here, not
         assumed: with the cache gone the error is passed on as it is)."""
-        # (for the line that names a program built after start-up)
-        self.last_prefill_shape = (*input_ids.shape, block_tables.shape[1])
+        # (for the line that names a program built after start-up; of a
+        # table a group of layers, the first: the group that sees every key)
+        width = jax.tree_util.tree_leaves(block_tables)[0].shape[1]
+        self.last_prefill_shape = (*input_ids.shape, width)
         try:
             self.cache, last_logits, *counters = self._prefill_fn(bucket)(
                 self.params, self.cache, jnp.asarray(input_ids),
-                jnp.asarray(positions), jnp.asarray(block_tables),
+                jnp.asarray(positions),
+                jax.tree_util.tree_map(jnp.asarray, block_tables),
                 jnp.asarray(last_idx),
                 *self._trailing(adapter_ids, state_slots))
         except jax.errors.JaxRuntimeError as e:
@@ -833,7 +904,7 @@ class EngineExecutor:
                    for leaf in jax.tree_util.tree_leaves(self.cache)):
                 raise
             refused = PrefillCallRefused(
-                (*input_ids.shape, block_tables.shape[1]),
+                (*input_ids.shape, width),
                 (str(e).splitlines() or [type(e).__name__])[0])
             self.refused_prefill_shapes.add(refused.shape)
             # One record: the line that names the shape, then the error as

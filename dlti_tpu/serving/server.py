@@ -137,6 +137,11 @@ def build_registry(async_engine: "AsyncEngine") -> MetricsRegistry:
                                prefix="dlti_")
     for hist in async_engine.engine.telemetry.histograms():
         registry.register(hist)
+    # The cache's books by group of layers (blocks in use, released, the
+    # live context): read from the engine at a scrape. (A facade over
+    # several engines has none.)
+    for metric in getattr(async_engine.engine, "kv_metrics", tuple)():
+        registry.register(metric)
     # The stepper's phase clock, the collector's pauses and the streaming
     # handlers' CPU: always on, read from their writers' books at a scrape.
     for metric in (*async_engine.engine.telemetry.stepper.metrics(),

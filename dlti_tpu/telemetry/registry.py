@@ -176,6 +176,12 @@ class ReadCounter(_Metric):
         return out
 
 
+class ReadGauge(ReadCounter):
+    """The same for point-in-time values (a pool's blocks in use)."""
+
+    kind = "gauge"
+
+
 class Histogram:
     """Fixed-bucket histogram (Prometheus semantics: ``le`` upper bounds,
     cumulative on exposition). Unlabeled — one instance per series is all

@@ -44,6 +44,9 @@ SPAN_NAME_CATALOG = frozenset({
     "engine/kv_handoff",
     "engine/prefill_chunks",
     "engine/tier_restore",
+    # The release of a window group's blocks behind the window (inside
+    # engine/decode_plan and engine/prefill_launch; PR 46).
+    "engine/window_free",
     # The children of the step phases: where the host's time between two
     # device programs goes (benchmark/lib/span_rules.json reads them).
     "engine/decode_prep",
