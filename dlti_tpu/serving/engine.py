@@ -1239,8 +1239,12 @@ class InferenceEngine:
         where a call of several rows of this bucket has been refused in
         this process (:meth:`_prefill_refused`): the one-row program of
         every bucket is warmed at start-up, a narrower one of several rows
-        may be one more that does not fit (8 x 2,048 and 4 x 2,048 alike
-        for qwen2_7b), found by one more failed compile."""
+        may be one more that does not fit, found by one more failed
+        compile. ``prefill_group_tokens`` reckons with float32 logits over
+        every position, which no program computes any more (it heads one
+        position a row); the limit stays for the set of shapes it makes
+        the engine form, which are the ones the benchmark's cells warm
+        (``executor.PREFILL_LOGITS_SHARE`` says when it goes)."""
         if any(b == bucket and r > 1
                for r, b, _width in self.executor.refused_prefill_shapes):
             return 1

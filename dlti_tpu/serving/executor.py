@@ -45,7 +45,9 @@ class PrefillCallRefused(RuntimeError):
     """A prefill program could not be called at a shape: the call raised
     before the program ran (it did not compile, or was refused its memory),
     so nothing was written and the cache is whole. ``shape``: ``(rows,
-    bucket, table width)``, rows as padded."""
+    bucket, table width)``, rows as padded. (No program holds logits
+    over every position, the array the compiler used to refuse; one can
+    still fail to fit beside the weights and the cache.)"""
 
     def __init__(self, shape: tuple, first_line: str):
         super().__init__(
@@ -54,15 +56,15 @@ class PrefillCallRefused(RuntimeError):
         self.shape = shape
 
 
-# A prefill program computes float32 logits over every position of every
-# row. A call of several rows whose logits alone would take more than this
-# share of one device's memory is not formed: the compiler tries for ten
-# seconds before it refuses such a program (qwen2_7b's 152k-row head at
-# 8 x 1,024 or 4 x 2,048: 4.6 GiB of logits and their 3.7 GiB gather beside
-# the weights and the cache, "Used 18.28G of 15.75G hbm"), all streams stand
-# still meanwhile, and the failed compile counts as a compilation. What the
-# benchmark's cells warm lies under it (4,096 padded tokens there: 2.3 GiB;
-# mistral_7b's 32k-row head at 8 x 2,048: 2.0 GiB).
+# A call of several rows is held to the padded tokens whose float32 logits
+# over every position would take this share of one device's memory. No
+# program computes those any more (a prefill program heads one position a
+# row: ``_model_cache_call``, ``last_idx``), so the limit guards against
+# nothing in the program. It stays because it decides which calls the
+# engine forms, and those are the shapes the benchmark's cells warm (up to
+# 4,096 padded tokens of qwen2_7b's 152k-row head, 8 x 2,048 of mistral_7b's
+# 32k rows): a call of another shape inside a measured window is a
+# compilation. It goes once the cells' warm lists are wider (ROADMAP C16).
 PREFILL_LOGITS_SHARE = 0.2
 
 
@@ -262,8 +264,8 @@ class EngineExecutor:
         self.counter_names = tuple(getattr(self.model, "counter_names", ()))
         # The most padded tokens one prefill call may hold (0: no limit).
         self.prefill_call_tokens = getattr(self.model, "prefill_call_tokens", 0)
-        # The most a call of several rows may hold (0: no limit): what its
-        # logits may take of the smallest device (prefill_group_tokens).
+        # The most a call of several rows may hold (0: no limit), by the
+        # smallest device's memory (PREFILL_LOGITS_SHARE says why it stays).
         self.prefill_group_tokens = prefill_group_tokens(
             model_cfg.vocab_size, min(
                 ((d.memory_stats() or {}).get("bytes_limit", 0)
@@ -523,13 +525,22 @@ class EngineExecutor:
     # ------------------------------------------------------------------
     def _model_cache_call(self, params, cache_kv, block_tables, input_ids,
                           positions, adapter_ids=None, adapters=None,
-                          state_slots=None, own_rows: bool = False):
+                          state_slots=None, own_rows: bool = False,
+                          last_idx=None):
         """Run the model over the cache; returns ``(logits, new cache list,
         counters)``. ``state_slots`` (a model with recurrent layers): each
         row's decode slot, out of range for a row that must write no
         recurrent state; ``own_rows`` says that this is a decode call, in
         which row i is slot i. ``counters`` is a vector in the order of
         ``self.counter_names``, or None for a model that counts nothing.
+
+        ``last_idx`` (a prefill call; ``(rows,)``): the one position of each
+        row whose logits anyone reads. The model then hands back its final
+        hidden states, each row's state there is taken out of them, and the
+        head (``model.head_matrix``: the matrix and the dtypes of
+        ``__call__``'s own product) multiplies those ``(rows, hidden)``
+        alone: logits ``(rows, vocab)``, and no array of every position
+        by the vocabulary in the program.
 
         Quantized params pass through as-is — each module dequantizes its
         own weights at the consumer (``models.quantization.maybe_dequantize``),
@@ -550,13 +561,20 @@ class EngineExecutor:
             kw["adapter_ids"] = adapter_ids
         if self.counter_names:
             kw["return_counters"] = True
-        logits, new_cache, *counted = self.model.apply(
+        if last_idx is not None:
+            kw["return_hidden"] = True
+        out, new_cache, *counted = self.model.apply(
             variables, input_ids, positions=positions, cache=cache,
             deterministic=True, **kw,
         )
+        if last_idx is not None:
+            last = jnp.take_along_axis(
+                out, last_idx[:, None, None], axis=1)[:, 0]
+            out = jnp.dot(last, self.model.head_matrix(params, last),
+                          preferred_element_type=jnp.float32)
         counters = jnp.stack([counted[0][n] for n in self.counter_names]) \
             if counted else None
-        return logits, unbind_call(new_cache), counters
+        return out, unbind_call(new_cache), counters
 
     def _named(self, extra: tuple) -> dict:
         """What follows the six per-slot state arrays in a program's
@@ -609,14 +627,13 @@ class EngineExecutor:
             # max_model_len-sized. B > 1 batches several admissions into
             # one program call (padding rows carry position -1, whose
             # writes slot_mapping drops); last_idx (B,) selects each
-            # row's final real logit. With a multi-LoRA pool, *lora is
-            # (adapter_ids, adapters) — per-row adapter gather; empty
-            # otherwise (the traced program is then unchanged).
-            logits, new_kv, counters = self._model_cache_call(
+            # row's final real position, the one the head is applied to.
+            # With a multi-LoRA pool, *lora is (adapter_ids, adapters) —
+            # per-row adapter gather; empty otherwise (the traced program
+            # is then unchanged).
+            last, new_kv, counters = self._model_cache_call(
                 params, cache_kv, block_table, input_ids, positions,
-                **self._named(lora))
-            last = jnp.take_along_axis(
-                logits, last_idx[:, None, None], axis=1)[:, 0]
+                **self._named(lora), last_idx=last_idx)
             if counters is not None:  # a model that counts (Python-static)
                 return new_kv, last, counters
             return new_kv, last

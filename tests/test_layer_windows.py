@@ -290,13 +290,13 @@ PARENT = {
     "mistral_7b": {
         "decode": "d0e054da3edad2d30a120025da7855a16df9fd42e2d5cdf4e895f1f03802c261",
         "decode_multi": "dbc42ee45cfa69e3bcdf2b3db41032cd1f4bbff09b3910ee968dcb67fabd3261",
-        "prefill": "8ec37c855d3f4eb6e7d0f5ecb78d6a23b57183b5fc5634480d34fe50feb69c66",
+        "prefill": "e889ce67c7b9a98a15f6eb6f644f4e7bf0d78cc27f6523ab96edc567a6888a83",
         "train": "5f78927809fbbcc96d06477e3005b3bf4de6a7178f8a1dc8e459b8fd1ab5e04d",
         "tree": "32578896e834d2de20a200433bb5a739413e8ffe7ea44a53800ff6c7a97006d8"},
     "qwen2_7b": {
         "decode": "a9fc0cc93eacc5901a5b912890850f62713ad4d446d91f65d2b3c02861955204",
         "decode_multi": "0db3bb2266960676ce8664b1c860315a6356be07a99941e4698ae1515c3834b9",
-        "prefill": "b8ca5d20fbd9da1c519ebdc841e3f532d169a9c3e136c72cd2e37388ff317c4d",
+        "prefill": "71e824a8e45bd7ac4c9b9cf5aab0c7208b29b69673df3a5a3bc0476627faa370",
         "train": "521295162228289d68ffc08c3aeaad2d86a531dc72fe77416d345530eecc5562",
         "tree": "a2cfbee9d3dc1b2803a9c0cdc6ea85523b8383fd965943d0e7528e8d2d948eb3"},
 }
@@ -308,7 +308,9 @@ def test_the_dense_presets_lower_to_the_programs_they_were(name):
     gather stays for a table of 4,096 keys) and the LoRA training step of
     ``mistral_7b`` and ``qwen2_7b`` at test widths: hashes taken at the
     parent commit with this function under this suite's conftest (a change
-    that means to change their programs re-pins them)."""
+    that means to change their programs re-pins them: PR 47 re-took
+    ``"prefill"`` alone, whose program now heads one position a row; the
+    other four are ``c1c8496``'s and say nothing else moved)."""
     from dlti_tpu.training.step import causal_lm_loss
 
     cfg = narrow(name)
@@ -361,14 +363,14 @@ GROUPED_PARENT = {
         (64, 2048, 768),
         dict(moe_held_start=0, moe_held_count=4),
         "976d6ef1e0a9d84542ccf5d07ac6b181304f1fdd72209d3c4a6a3952acc7864e",
-        "f1b00cb65868fe4b0e8990146811e1e31a81a0baa06ac0f86dd9ac0b8c9ce31a"),
+        "06e3f28e491f52ceea01dc5ab1d74602ecb53463e171ba1cf1cb821fe9a16307"),
     "xing4_29b": (
         (64, 3584, 1024),
         dict(num_layers=4, first_k_dense=2, q_lora_rank=24, rope_scaling=YARN,
              hc_mult=4, moe_shared_intermediate_size=24,
              num_experts_per_tok=4, moe_routed_scaling=2.0),
         "2808634fcc605124d271e202939cf225247e4c0d873b656b0158a051f6237c4f",
-        "5e1bd6d3674dfdf6a2879f9cd2b46f88666331e07a8b4638399e708f13b7e7a8"),
+        "8dfce5748aec289c87883293b990077a3039a0101a8a7a2f70e931d48a3ec218"),
 }
 
 
@@ -382,7 +384,8 @@ def test_the_other_grouped_prefills_are_the_programs_they_were(name):
     program through the grouped path at test widths: hashes taken at the
     parent commit with this function under this suite's conftest (the
     Mosaic payload itself carries source lines, so its bytes differ with any
-    edit of the file)."""
+    edit of the file). The programs' hashes were re-taken in PR 47 (the
+    head over one position a row); the kernel's jaxprs are ``c1c8496``'s."""
     from dlti_tpu.models.moe import takes_grouped
     from dlti_tpu.ops.pallas import grouped_experts as ge
 
