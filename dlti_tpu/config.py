@@ -81,6 +81,26 @@ class ModelConfig:
     # Where a block's two norms stand: false ``x + F(norm(x))`` (pre-norm),
     # true ``x + norm(F(x))`` (the exaone4 family's placement).
     post_sublayer_norm: bool = False
+    # FOUR norms a block, one before and one after each sublayer:
+    # ``a = x + N2(Attn(N1(x)))``, ``y = a + N4(MLP(N3(a)))`` (the ouro
+    # family's sandwich normalisation). Not a third placement of the same
+    # two norms, so a field of its own beside ``post_sublayer_norm``.
+    sandwich_norm: bool = False
+    # Passes over the stack: the ``num_layers`` blocks run ``ut_steps``
+    # times over ONE set of weights, the final norm after every pass (its
+    # output is the next pass's input), and pass u of layer l keeps keys and
+    # values of its own: a cache entry a (pass, layer),
+    # :attr:`cache_entries` of them, pass u's in the u-th run of blocks of
+    # the layer's pool (``models.llama.entry_of_pass``). More than one pass
+    # brings the exit gate, one ``Linear(hidden, 1)`` with bias on each
+    # pass's normed state. 1: every layer once, no gate, the model as it
+    # always was.
+    ut_steps: int = 1
+    # A token leaves at the first pass whose cumulated exit probability
+    # reaches this. The programs run every pass for every token, which is
+    # what 1.0 (the published value) says; adaptive exit is not implemented
+    # and a lower threshold is refused.
+    early_exit_threshold: float = 1.0
     mlp_activation: str = "silu"        # "silu" | "gelu_tanh" | "gelu_exact"
     rmsnorm_offset: bool = False        # Gemma: normalize with (1 + weight)
     embedding_scale: bool = False       # Gemma: embed * sqrt(hidden_size)
@@ -211,6 +231,27 @@ class ModelConfig:
                 f"keeps one pool for the layers that see every key and one "
                 f"for the layers of ONE window; several window lengths in "
                 f"one model are not implemented")
+        if self.sandwich_norm and self.post_sublayer_norm:
+            raise ValueError(
+                "sandwich_norm puts a norm before AND after each sublayer; "
+                "post_sublayer_norm moves a block's two norms after them: "
+                "state one of the two")
+        if self.ut_steps < 1:
+            raise ValueError(f"ut_steps {self.ut_steps}: at least one pass")
+        if self.ut_steps > 1 and (
+                self.layer_pattern or self.kv_lora_rank
+                or self.num_experts or self.moe_num_experts
+                or len(set(windows)) > 1):
+            raise ValueError(
+                f"ut_steps {self.ut_steps}: several passes over one set of "
+                f"weights are implemented for the dense Llama family with "
+                f"one attention window alone, not for a layer_pattern, "
+                f"latent attention, experts or layer_windows that differ")
+        if self.ut_steps > 1 and self.early_exit_threshold < 1.0:
+            raise ValueError(
+                f"early_exit_threshold {self.early_exit_threshold}: every "
+                f"token runs every pass here (rows that leave at different "
+                f"passes are not implemented); state 1.0")
         if self.layer_pattern and (
                 len(self.layer_pattern) != self.num_layers
                 or set(self.layer_pattern) - set("ME*")):
@@ -244,8 +285,16 @@ class ModelConfig:
         return groups.index(self.layer_windows[layer]) \
             if len(groups) > 1 else 0
 
+    @property
+    def cache_entries(self) -> int:
+        """Entries of a sequence's cache: one a (pass, layer). Whatever
+        sizes the cache or counts its bytes reads this, never
+        ``num_layers``: a layer's pool holds ``ut_steps`` entries."""
+        return self.ut_steps * self.num_layers
+
     def num_params(self, include_lm_head: bool = True) -> int:
-        """Analytic parameter count (for MFU and reporting)."""
+        """Analytic parameter count (for MFU and reporting): each layer's
+        weights once, however many passes run over them."""
         return self._count_params(include_lm_head, active_only=False)
 
     def num_active_params(self, include_lm_head: bool = True) -> int:
@@ -346,12 +395,16 @@ class ModelConfig:
             return total
         if self.qk_norm:
             attn += 2 * hd
+        if self.sandwich_norm:
+            attn += 2 * h  # the second norm of each sublayer
+        # the exit gate of a looped stack: Linear(hidden, 1) with bias
+        gate = h + 1 if self.ut_steps > 1 else 0
         if self.moe_num_experts > 0:
             # Held experts under the Llama block: the leading layers dense,
             # the rest the held experts with router, bias and shared expert.
             experts = self._held_expert_layer_params(active_only)
             dense = min(self.first_k_dense, self.num_layers)
-            total = (v * h + h + self.num_layers * (attn + 2 * h)
+            total = (v * h + h + gate + self.num_layers * (attn + 2 * h)
                      + dense * 3 * h * m + (self.num_layers - dense) * experts)
             if include_lm_head and not self.tie_embeddings:
                 total += h * v
@@ -364,7 +417,8 @@ class ModelConfig:
             mlp = 3 * h * m
         norms = 2 * h
         per_layer = attn + mlp + norms
-        total = v * h + self.num_layers * per_layer + h  # embed + layers + final norm
+        # embed + layers + final norm + exit gate
+        total = v * h + self.num_layers * per_layer + h + gate
         if include_lm_head and not self.tie_embeddings:
             total += h * v
         return total

@@ -56,16 +56,20 @@ class RMSNorm(nn.Module):
     eps: float = 1e-5
     param_dtype: Any = jnp.float32
     offset: bool = False
-    # Seeded weights 1 + N(0, init_std) instead of ones (0: ones).
+    # Seeded weights init_mean x (1 + N(0, init_std)) instead of ones
+    # (init_std 0: ones).
     init_std: float = 0.0
+    init_mean: float = 1.0
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         orig_dtype = x.dtype
         init = nn.initializers.zeros if self.offset else nn.initializers.ones
         if self.init_std:
-            def init(key, shape, dtype, std=self.init_std):
-                return (1.0 + std * jax.random.normal(key, shape)).astype(dtype)
+            def init(key, shape, dtype, std=self.init_std,
+                     mean=self.init_mean):
+                return (mean * (1.0 + std * jax.random.normal(key, shape))
+                        ).astype(dtype)
         scale = self.param("scale", init, (x.shape[-1],), self.param_dtype)
         x32 = x.astype(jnp.float32)
         var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
@@ -87,6 +91,39 @@ def _lora_kwargs(cfg: ModelConfig, lora: Optional[LoRAConfig], name: str) -> dic
 # 1, as ``models.moe.SCORE_BIAS_STD`` keeps the selection bias away from 0,
 # so that a program without the norms' weights differs from one with them.
 QK_NORM_INIT_STD = 0.25
+# The same for the four norms of a sandwich-normed block (``sandwich_norm``):
+# a program that leaves out a sublayer's second norm, or shares one weight
+# between its two, then differs from the stated one.
+SANDWICH_NORM_INIT_STD = 0.25
+# ... and the mean of the two norms AFTER the sublayers (the two before them
+# spread round 1), and the gain of seeded query and key projections. A norm
+# after a sublayer gives its output a fixed size whatever it computed, and
+# seeded attention is near uniform, an average over the context in which
+# what the positions share survives and what tells them apart cancels: so
+# what the states of a batch have in common grows from layer to layer, and
+# the next pass starts from it. At the published widths and 4 x 48 layers,
+# with every norm near 1 (and at 0.1 as well), every prompt and every
+# position ended in ONE state: the same greedy token at log-prob -7.50 in
+# every answer, fp8-rounded weights no further from the reference than
+# bf16, three passes for four within 0.04 (my chip runs, PR 49; the
+# reference alone on the CPU reads a cosine of 0.82 between two prompts'
+# last states after one pass, 0.99 after two, 1.00 after four). A check on
+# such outputs holds nothing. So the sublayers are seeded as small
+# increments (0.02) on an embedding of unit scale that carries the token,
+# as the held-expert families seed theirs, and queries and keys three times
+# LeCun's scale, score spread ~9, so that a query attends to a few keys as
+# a trained one does.
+SANDWICH_OUT_NORM_MEAN = 0.02
+SEEDED_QK_GAIN = 3.0
+# What a looped stack (``ut_steps`` > 1) counts, as ``HeldExpertsMLP``'s
+# counters travel: passes a program call ran (``ut_steps`` while every token
+# runs every pass: what an adaptive exit would lower), and over the call's
+# real tokens the sum of 1000 x the expected exit pass ``sum_u u p_u`` of
+# the exit gate's distribution.
+LOOP_COUNTERS = ("loop_passes", "loop_exit_pass_e3")
+# Seeded spread of the exit gate's bias: away from 0, so that lambda_u is
+# not 0.5 in the mean and a program that dropped the bias differs.
+EXIT_GATE_BIAS_STD = 1.0
 # Most padded tokens (rows x bucket) the serving engine gives one prefill
 # call of a model whose layers disagree about their window: a longer prompt
 # goes as several calls, each over what the earlier ones wrote. It bounds
@@ -204,18 +241,22 @@ class LlamaAttention(nn.Module):
         b, s, _ = x.shape
         hd = cfg.resolved_head_dim
 
-        def proj(name: str, features: int, use_bias: bool = False):
+        def proj(name: str, features: int, use_bias: bool = False, **init):
             return LoRADense(
                 features=features, use_bias=use_bias, dtype=dtype, param_dtype=pdtype,
-                name=name, **_lora_kwargs(cfg, self.lora, name),
+                name=name, **_lora_kwargs(cfg, self.lora, name), **init,
             )
 
         # Qwen2-style bias on q/k/v only, never o (config.attention_bias).
         qkv_bias = cfg.attention_bias
-        q = proj("q_proj", cfg.num_heads * hd, qkv_bias)(x, deterministic,
-                                                         adapter_ids)
-        k = proj("k_proj", cfg.num_kv_heads * hd, qkv_bias)(x, deterministic,
-                                                            adapter_ids)
+        # (seeded queries and keys of a sandwich-normed stack: SEEDED_QK_GAIN)
+        sharp = {"kernel_init": nn.initializers.variance_scaling(
+            SEEDED_QK_GAIN ** 2, "fan_in", "truncated_normal")} \
+            if cfg.sandwich_norm else {}
+        q = proj("q_proj", cfg.num_heads * hd, qkv_bias, **sharp)(
+            x, deterministic, adapter_ids)
+        k = proj("k_proj", cfg.num_kv_heads * hd, qkv_bias, **sharp)(
+            x, deterministic, adapter_ids)
         v = proj("v_proj", cfg.num_kv_heads * hd, qkv_bias)(x, deterministic,
                                                             adapter_ids)
 
@@ -347,7 +388,11 @@ class LlamaMLP(nn.Module):
 class LlamaBlock(nn.Module):
     """One layer: ``x + Attn(norm(x))`` then ``x + MLP(norm(x))``, or with
     ``post_sublayer_norm`` ``x + norm(Attn(x))`` then ``x + norm(MLP(x))``
-    (the same two norms, after their sublayers). The MLP is ``LlamaMLP``;
+    (the same two norms, after their sublayers), or with ``sandwich_norm``
+    FOUR norms, ``a = x + N2(Attn(N1(x)))``, ``y = a + N4(MLP(N3(a)))``
+    (``input_norm``, ``attn_out_norm``, ``post_attn_norm``,
+    ``mlp_out_norm``). Called several times (``ut_steps``) the block's
+    weights are the same every time. The MLP is ``LlamaMLP``;
     ``MoEMLP`` where ``num_experts`` > 0; with ``moe_num_experts`` > 0
     ``HeldExpertsMLP`` from layer ``first_k_dense`` on, and the block then
     returns that layer's counters as a third value."""
@@ -362,10 +407,14 @@ class LlamaBlock(nn.Module):
                  deterministic: bool = True, token_mask=None,
                  adapter_ids=None):
         cfg = self.cfg
-        input_norm = RMSNorm(cfg.rms_norm_eps, offset=cfg.rmsnorm_offset,
-                             name="input_norm")
-        post_attn_norm = RMSNorm(cfg.rms_norm_eps, offset=cfg.rmsnorm_offset,
-                                 name="post_attn_norm")
+
+        def norm(name, mean=1.0):
+            return RMSNorm(
+                cfg.rms_norm_eps, offset=cfg.rmsnorm_offset, name=name,
+                init_std=SANDWICH_NORM_INIT_STD if cfg.sandwich_norm else 0.0,
+                init_mean=mean)
+
+        input_norm, post_attn_norm = norm("input_norm"), norm("post_attn_norm")
         after = cfg.post_sublayer_norm
         attn_out, new_cache = LlamaAttention(
             cfg, self.lora, self.mesh, self.layer, name="attn")(
@@ -373,6 +422,12 @@ class LlamaBlock(nn.Module):
             cos, sin, positions, segment_ids, cache, deterministic,
             adapter_ids,
         )
+        if cfg.sandwich_norm:
+            x = x + norm("attn_out_norm", SANDWICH_OUT_NORM_MEAN)(attn_out)
+            mlp_out = LlamaMLP(cfg, self.lora, name="mlp")(
+                post_attn_norm(x), deterministic, adapter_ids)
+            return x + norm("mlp_out_norm", SANDWICH_OUT_NORM_MEAN)(
+                mlp_out), new_cache
         x = x + (input_norm(attn_out) if after else attn_out)
         normed = x if after else post_attn_norm(x)
         if cfg.moe_num_experts > 0:
@@ -418,8 +473,88 @@ def _remat_policy(name: str):
     return policies[name]
 
 
+def entry_of_pass(layer_cache: dict, u, ut_steps: int) -> dict:
+    """Pass ``u``'s entry of a layer's paged cache. A looped stack's pool
+    holds ``ut_steps`` times the blocks of ``--num-blocks``, a run of them
+    a pass: block b of pass u lies at ``u x (blocks a pass) + b``, so one
+    block table serves every pass and the scatter, the gather and the
+    decode kernel address the pool as they always did."""
+    per_pass = layer_cache["k"].shape[0] // ut_steps
+    return {**layer_cache,
+            "block_tables": layer_cache["block_tables"] + u * per_pass}
+
+
+class LoopPass(nn.Module):
+    """One pass of a looped stack (``ut_steps`` > 1), the body that
+    ``LlamaModel`` scans: the blocks in order, each over its entry of this
+    pass, then the final norm (inside the loop: its output is the next
+    pass's input) and the exit gate on the normed state. Carry ``(x, the
+    layers' caches)``; returns the pass's exit rate ``(b, s)``."""
+
+    cfg: ModelConfig
+    lora: Optional[LoRAConfig] = None
+    mesh: Optional[Any] = None
+    deterministic: bool = True
+
+    @nn.compact
+    def __call__(self, carry, u, cos, sin, positions, segment_ids,
+                 token_mask, adapter_ids):
+        cfg = self.cfg
+        x, cache = carry
+        new_cache = None if cache is None else []
+        # (the loop index is traced: one scope for the body, run u = 0 ..
+        # ut_steps - 1)
+        with jax.named_scope("dlti_loop_pass_u"):
+            rest = (cos, sin, positions, segment_ids)
+            if self.is_initializing():
+                # the tree: a submodule a layer
+                def block(i, x, layer_cache):
+                    return LlamaBlock(
+                        cfg, self.lora, self.mesh, name=f"layers_{i}")(
+                        x, *rest, layer_cache, self.deterministic,
+                        token_mask, adapter_ids)
+            else:
+                # Every layer has the same shapes, so the block is ONE
+                # jitted function of a layer's weights: traced once a
+                # program, not once a layer (a looped stack's layers differ
+                # in nothing but their weights; tracing 48 of them took
+                # most of a prefill program's first call on the chip's
+                # host: PERF.md section 6, PR 49).
+                shared = LlamaBlock(cfg, self.lora, self.mesh, parent=None)
+                traced_once = jax.jit(
+                    lambda w, x, layer_cache, rest, mask, ids: shared.apply(
+                        {"params": w}, x, *rest, layer_cache,
+                        self.deterministic, mask, ids))
+                weights = self.variables["params"]
+
+                def block(i, x, layer_cache):
+                    return traced_once(weights[f"layers_{i}"], x, layer_cache,
+                                       rest, token_mask, adapter_ids)
+
+            for i in range(cfg.num_layers):
+                layer_cache = None if cache is None else entry_of_pass(
+                    cache[i], u, cfg.ut_steps)
+                x, written = block(i, x, layer_cache)
+                if cache is not None:
+                    # the pools as written, the call's own table again
+                    new_cache.append({**written, "block_tables":
+                                      cache[i]["block_tables"]})
+            x = RMSNorm(cfg.rms_norm_eps, offset=cfg.rmsnorm_offset,
+                        name="final_norm")(x)
+            rate = exit_rate(
+                x,
+                self.param("exit_gate_kernel", nn.initializers.lecun_normal(),
+                           (cfg.hidden_size, 1), jnp.float32),
+                self.param("exit_gate_bias",
+                           nn.initializers.normal(EXIT_GATE_BIAS_STD), (1,),
+                           jnp.float32))
+        return (x, new_cache), rate
+
+
 class LlamaModel(nn.Module):
-    """Transformer body (embeddings + blocks + final norm)."""
+    """Transformer body (embeddings + blocks + final norm; with
+    ``ut_steps`` > 1 the blocks, the norm and an exit gate as one scanned
+    pass, ``loop`` in the tree)."""
 
     cfg: ModelConfig
     lora: Optional[LoRAConfig] = None
@@ -440,9 +575,11 @@ class LlamaModel(nn.Module):
             "embed_tokens",
             # With held experts, seeded at unit scale as the other held-
             # expert families are: the residual stream carries the token and
-            # the layers add to it (models.moe.centred_out_init).
+            # the layers add to it (models.moe.centred_out_init). The same
+            # under sandwich norms (SANDWICH_OUT_NORM_MEAN).
             nn.initializers.normal(
-                stddev=1.0 if cfg.moe_num_experts > 0 else 0.02),
+                stddev=1.0 if cfg.moe_num_experts > 0 or cfg.sandwich_norm
+                else 0.02),
             (cfg.vocab_size, cfg.hidden_size),
             pdtype,
         )
@@ -512,6 +649,20 @@ class LlamaModel(nn.Module):
                 routed = routed & (cache[0]["block_tables"][:, :1] > 0)
             token_mask = routed if token_mask is None \
                 else routed & token_mask.astype(bool)
+        if cfg.ut_steps > 1:
+            x, new_caches, rates = self._passes(
+                x, cos, sin, positions, segment_ids, cache, deterministic,
+                token_mask, adapter_ids)
+            real = positions >= 0
+            if cache is not None:
+                real = real & (cache[0]["block_tables"][:, :1] > 0)
+            self.sow("intermediates", "exit_rates", rates)
+            expected = expected_exit_pass(exit_distribution(rates))
+            return x, new_caches, {
+                "loop_passes": jnp.int32(cfg.ut_steps),
+                "loop_exit_pass_e3": jnp.sum(jnp.where(
+                    real, jnp.round(1000.0 * expected), 0.0)
+                ).astype(jnp.int32)}
         new_caches = [] if cache is not None else None
         for i in range(cfg.num_layers):
             # Selective remat: every remat_stride-th block keeps its
@@ -540,6 +691,66 @@ class LlamaModel(nn.Module):
         if counters is not None:
             return x, new_caches, counters
         return x, new_caches
+
+    def _passes(self, x, cos, sin, positions, segment_ids, cache,
+                deterministic, token_mask, adapter_ids):
+        """``ut_steps`` passes over the stack as ONE scanned body
+        (:class:`LoopPass`): a program holds ``num_layers`` block bodies
+        whatever the passes (unrolled, 192 bodies of the 48-layer model
+        compile in 66 s a program; PERF.md section 6, PR 49), the weights
+        are the loop's constants and the pools its carry, written in
+        place. (Neither a multi-LoRA pool's tree nor a dropout stream is
+        handed to the body: both are refused for a looped stack.) Returns
+        ``(x, the layers' new caches, exit rates (b, s, passes))``."""
+        cfg = self.cfg
+        if cache is not None and "block_tables" not in cache[0]:
+            raise NotImplementedError(
+                "ut_steps > 1 over the dense (batch, max_len) cache: a "
+                "looped stack is served over the paged cache alone")
+        args = (cos, sin, positions, segment_ids, token_mask, adapter_ids)
+        if self.is_initializing():
+            # The tree is made by ONE pass, called as the submodule
+            # ``loop``: every pass reads the same weights.
+            (x, new_caches), rate = LoopPass(
+                cfg, self.lora, self.mesh, deterministic, name="loop")(
+                (x, cache), jnp.int32(0), *args)
+            return x, new_caches, jnp.stack([rate] * cfg.ut_steps, -1)
+        # ``lax.scan`` over the pass applied as a function of the tree's
+        # ``loop`` (not ``nn.scan``, which traces its body twice: a prefill
+        # program's first call cost 41 s of tracing on the chip's host, 16
+        # programs a start-up; PERF.md section 6, PR 49).
+        weights = {"params": self.variables["params"]["loop"]}
+        one_pass = LoopPass(cfg, self.lora, self.mesh, deterministic,
+                            parent=None)
+        (x, new_caches), rates = jax.lax.scan(
+            lambda carry, u: one_pass.apply(weights, carry, u, *args),
+            (x, cache), jnp.arange(cfg.ut_steps, dtype=jnp.int32))
+        return x, new_caches, jnp.moveaxis(rates, 0, -1)
+
+
+def exit_rate(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """The exit gate on one pass's normed state, ``lambda = sigmoid(w . x +
+    b)`` (b, s) in float32: one ``Linear(hidden, 1)`` with bias, the same
+    for every pass."""
+    return jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), w[:, 0],
+        precision=jax.lax.Precision.HIGHEST) + b[0])
+
+
+def exit_distribution(exit_rates: jnp.ndarray) -> jnp.ndarray:
+    """``p_u = lambda_u prod_{v<u} (1 - lambda_v)`` for every pass but the
+    last, which takes the rest: ``(..., passes)`` from the rates ``(...,
+    passes)``, summing to 1."""
+    stay = jnp.cumprod(1.0 - exit_rates[..., :-1], axis=-1)
+    before = jnp.concatenate([jnp.ones_like(stay[..., :1]), stay[..., :-1]],
+                             axis=-1)
+    return jnp.concatenate([exit_rates[..., :-1] * before, stay[..., -1:]],
+                           axis=-1)
+
+
+def expected_exit_pass(p: jnp.ndarray) -> jnp.ndarray:
+    """``sum_u u p_u`` with passes counted from 1."""
+    return jnp.sum(p * jnp.arange(1, p.shape[-1] + 1, dtype=p.dtype), -1)
 
 
 def head_matrix_from_leaves(embed_leaf, head_leaf, tie_embeddings: bool,
@@ -572,7 +783,10 @@ class LlamaForCausalLM(nn.Module):
     @property
     def counter_names(self) -> tuple:
         """What the forward pass counts (``return_counters``): the held
-        experts' counters where the model has them."""
+        experts' counters where the model has them, a looped stack's
+        passes and exit gate (the two never meet: ``ModelConfig``)."""
+        if self.cfg.ut_steps > 1:
+            return LOOP_COUNTERS
         if self.cfg.moe_num_experts > 0:
             from dlti_tpu.models.moe import MOE_COUNTERS
 

@@ -1,7 +1,13 @@
 """The serving cache — device-side ops: paged keys and values, paged
 latents, and the recurrent state of state-space layers by decode slot.
 
-One cache, one entry a layer, three kinds of state (:func:`init_cache`):
+One cache, one entry a layer (a looped stack, ``ut_steps`` > 1, keeps an
+entry a (pass, layer), ``ModelConfig.cache_entries`` of them: pass u of
+layer l attends over what pass u of layer l wrote. A layer's pool then
+holds ``ut_steps`` runs of ``num_blocks`` blocks, pass u's block b at ``u x
+num_blocks + b`` (``models.llama.entry_of_pass``), so that one block table
+and one allocator serve every pass), three kinds of state
+(:func:`init_cache`):
 ``{"k", "v"}`` block pools addressed by block tables for attention layers
 (below; where a model's layers differ in their attention window they form
 GROUPS, a pool size, a table and an allocator a group:
@@ -166,9 +172,11 @@ def window_group_blocks(window: int, block_size: int, num_slots: int,
 def init_cache(model_cfg, num_blocks: int, block_size: int, num_slots: int,
                dtype=jnp.bfloat16, call_tokens: int = 0,
                decode_steps: int = 1) -> List[dict]:
-    """The serving cache of ``model_cfg``, one entry a layer by its kind.
-    A model without a ``layer_pattern`` is attention in every layer, over
-    latents where the configuration has a ``kv_lora_rank``. Attention
+    """The serving cache of ``model_cfg``, one entry a layer by its kind
+    (a looped stack: ``ut_steps`` runs of ``num_blocks`` blocks in each
+    layer's pool, an entry a pass). A model without a ``layer_pattern`` is
+    attention in every layer, over latents where the configuration has a
+    ``kv_lora_rank``. Attention
     layers of one window form a group (``ModelConfig.kv_group_windows``):
     the group that sees every key, and every model's only group, has pools
     of ``num_blocks``; a window group's are sized by
@@ -192,7 +200,7 @@ def init_cache(model_cfg, num_blocks: int, block_size: int, num_slots: int,
             for i in range(model_cfg.num_layers)]
 
     def paged():
-        return init_paged_cache(1, num_blocks, block_size,
+        return init_paged_cache(1, model_cfg.ut_steps * num_blocks, block_size,
                                 model_cfg.num_kv_heads,
                                 model_cfg.resolved_head_dim, dtype)[0]
 

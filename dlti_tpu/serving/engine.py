@@ -569,6 +569,9 @@ class InferenceEngine:
         # model that counts nothing.
         for name in self.executor.counter_names:
             self.stats[name] = self.stats[f"{name}_decode"] = 0
+        # (a looped stack's passes also by the prefill calls' part)
+        if model_cfg.ut_steps > 1:
+            self.stats["loop_passes_prefill"] = 0
         ec = engine_cfg
         self.block_manager = BlockManager(ec.num_blocks, ec.block_size)
         # A model whose layers differ in their attention window has a
@@ -1035,6 +1038,14 @@ class InferenceEngine:
             ReadGauge("dlti_kv_context_tokens",
                       lambda: {"": sum(s.seq_len for s in self.slots)},
                       help="tokens the live slots have in the cache"),
+            ReadGauge("dlti_kv_cache_entries",
+                      lambda: {"": self.model_cfg.cache_entries},
+                      help="entries of a sequence's cache: one a layer, a "
+                           "looped stack one a (pass, layer)"),
+            ReadGauge("dlti_kv_bytes_per_context_token",
+                      lambda: {"": self.executor.kv_bytes_per_context_token},
+                      help="bytes a token of context holds over every "
+                           "entry of the pool of --num-blocks"),
             ReadCounter("dlti_kv_blocks_freed_total",
                         lambda: {k: v for k, v in self.kv_freed.items()
                                  if k[0] in managers},
@@ -1565,10 +1576,11 @@ class InferenceEngine:
         """Book the model's counters: ``(program calls or decode steps,
         len(counter_names))``, in the order of ``counter_names``."""
         totals = counters.astype(np.int64).sum(axis=0)
+        part = "_decode" if decode else "_prefill"
         for name, total in zip(self.executor.counter_names, totals):
             self.stats[name] += int(total)
-            if decode:
-                self.stats[f"{name}_decode"] += int(total)
+            if name + part in self.stats:
+                self.stats[name + part] += int(total)
 
     def _state_mirrors(self) -> dict:
         return {"block_tables": self._block_tables,

@@ -153,6 +153,34 @@ def refuse_unsupported_dense(model_cfg: ModelConfig,
     (a window group's blocks are released behind the window); and what
     held experts under the Llama block do not implement."""
     ec = engine_cfg
+    if model_cfg.ut_steps > 1:
+        # (What walks a sequence's cache entry by entry works as it is:
+        # prefix caching and its tiers, hand-off, preemption, int8 keys
+        # and values: tests/test_looped_layers.py.)
+        what = (f"a model whose layers run {model_cfg.ut_steps} times over "
+                f"one set of weights (ut_steps)")
+        if ec.speculative != "none":
+            raise ValueError(
+                f"{what} counts what its forward pass did (passes, the "
+                f"exit gate), which the speculative program does not "
+                f"thread. Serve it with --speculative none")
+        if mesh is not None:
+            raise ValueError(
+                f"{what} has no tensor-parallel placement for its exit "
+                f"gate, and its pools of {model_cfg.ut_steps} entries a "
+                f"layer have been sharded by no test; serve it on one chip "
+                f"per replica")
+        if ec.quantization != "none":
+            raise ValueError(
+                f"{what} is served in its own precision: weight-only "
+                f"{ec.quantization} has no rule for the exit gate, and a "
+                f"rounding made once is applied {model_cfg.ut_steps} times "
+                f"a token, which no reference has been held against")
+        if ec.adapter_slots > 0:
+            raise ValueError(
+                f"{what} has no multi-LoRA adapter branch held to a "
+                f"reference (an adapter would be applied in every pass); "
+                f"serve it with --adapter-slots 0")
     groups = model_cfg.kv_group_windows
     if len(groups) > 1:
         what = (f"a model whose layers differ in their attention window "
@@ -362,11 +390,19 @@ class EngineExecutor:
         self.pool_bytes = tree_nbytes(self.cache)
         self.recurrent_state_pool_bytes = tree_nbytes(
             [c for c in self.cache if "ssm" in c])
+        # Bytes a token of context holds over every entry of the pools of
+        # ``num_blocks`` (a looped stack's hold ``ut_steps`` entries each;
+        # a window group's smaller pools apart).
+        pools = [c.get("k", c.get("latent")) for c in self.cache]
+        self.kv_bytes_per_context_token = sum(
+            tree_nbytes(c) for c, pool in zip(self.cache, pools)
+            if pool is not None
+            and pool.shape[0] == model_cfg.ut_steps * ec.num_blocks
+        ) // (ec.num_blocks * ec.block_size)
         # Keys a step of the paged decode kernel (of keys and values, or of
         # latents) covers at this engine's shapes (the scheduler's
         # decode_kernel_tile_tokens counts in it).
-        pool = next((c.get("k", c.get("latent")) for c in self.cache
-                     if "k" in c or "latent" in c), None)
+        pool = next((p for p in pools if p is not None), None)
         token_bytes = 0 if pool is None \
             else math.prod(pool.shape[2:]) * pool.dtype.itemsize
         self.decode_tile_tokens = tile_tokens(
@@ -402,6 +438,9 @@ class EngineExecutor:
                 str(d): (d.memory_stats() or {}).get("bytes_in_use")
                 for d in own},
             "model_layers": model_cfg.num_layers,
+            # a looped stack keeps an entry a (pass, layer)
+            "cache_entries": model_cfg.cache_entries,
+            "kv_bytes_per_context_token": self.kv_bytes_per_context_token,
             "prefill_group_tokens": self.prefill_group_tokens,
             "param_dtype": ("int8" if self._quantized
                             else model_cfg.param_dtype),
@@ -1080,7 +1119,11 @@ class EngineExecutor:
         ever received. Payload keys follow the disk format
         ("l00000": {"k": ..., "v": ..., int8 scales if present})."""
         try:
-            rows = [{name: arr[block] for name, arr in layer.items()}
+            # (a looped stack: the block of every pass's entry, a leading
+            # axis of ``ut_steps``; models.llama.entry_of_pass)
+            at = block if self.model_cfg.ut_steps == 1 \
+                else slice(block, None, self.cfg.num_blocks)
+            rows = [{name: arr[at] for name, arr in layer.items()}
                     for layer in self.cache]
             if self._demote_sharding is not None:
                 # Stage through pinned_host: the D2H DMA lands in pinned
@@ -1103,8 +1146,12 @@ class EngineExecutor:
         admission work, and the following prefill/decode programs see the
         restored rows through the ``self.cache`` data dependency."""
         if self._restore_fn is None:
+            passes = self.model_cfg.ut_steps
+
             @partial(jax.jit, donate_argnums=(0,))
             def restore(cache_kv, rows, bid):
+                if passes > 1:  # the block of every pass's entry
+                    bid = bid + jnp.arange(passes) * self.cfg.num_blocks
                 return [
                     {k: v.at[bid].set(r[k].astype(v.dtype)) for k, v in
                      layer.items()}

@@ -197,6 +197,13 @@ class Trainer:
         # Pretrained base weights (e.g. from models.load_hf_checkpoint) to
         # overlay onto the initialized tree — the from_pretrained analog.
         self.base_params = base_params
+        if cfg.model.ut_steps > 1:
+            raise NotImplementedError(
+                f"ut_steps {cfg.model.ut_steps}: training through weights "
+                f"that a step uses several times (gradients summed over the "
+                f"passes, the activations of every pass, an exit-gate loss) "
+                f"has been held to no reference; the looped stack is served, "
+                f"not trained")
         self.tx = build_optimizer(cfg.optimizer)
         if cfg.parallel.pipe > 1:
             _validate_pipeline_config(cfg)

@@ -35,6 +35,7 @@ class ScriptedExecutor:
     prefill_whole_tables = False
     adapter_pool = None
     pool_bytes = 0
+    kv_bytes_per_context_token = 0
     recurrent_state_pool_bytes = 0
     decode_tile_tokens = 4  # keys a grid step of "the kernel" covers
 

@@ -213,3 +213,52 @@ if __name__ == "__main__":
     import sys
 
     sys.exit(pytest.main([__file__, "-q"]))
+
+
+# -- device scopes --------------------------------------------------------------
+# ``jax.named_scope`` names: what the profile of a device trace groups by
+# (benchmark/lib/scope_time.py, the kernels' rules, a builder reading a
+# trace by hand). The same contract as the host spans above: a new scope is
+# added here on purpose, a rename fails here first.
+DEVICE_SCOPE_CATALOG = frozenset({
+    "dlti_flash_attention_fwd", "dlti_flash_attention_bwd_dq",
+    "dlti_flash_attention_bwd_dkv",
+    "dlti_paged_attention_decode", "dlti_latent_attention_decode",
+    "dlti_grouped_experts",
+    "dlti_attn_window", "dlti_attn_full", "dlti_attn_over_cache",
+    "dlti_mla_absorb", "dlti_mla_expand",
+    "dlti_moe_routed", "dlti_moe_shared", "dlti_mamba2",
+    "dlti_mhc_map", "dlti_mhc_mix",
+    # One pass of a looped stack (``ut_steps`` > 1), the body the model
+    # scans: the loop index is traced, so the passes share the one scope
+    # and a profile shows it ``ut_steps`` times a step (PR 49).
+    "dlti_loop_pass_u",
+})
+
+
+def _scope_literals():
+    found = set()
+    for root, _dirs, files in os.walk(PKG):
+        for name in files:
+            if not name.endswith(".py"):
+                continue
+            with open(os.path.join(root, name)) as f:
+                tree = ast.parse(f.read())
+            for node in ast.walk(tree):
+                if (isinstance(node, ast.Call)
+                        and isinstance(node.func, ast.Attribute)
+                        and node.func.attr == "named_scope"):
+                    found.update(
+                        c.value for arg in node.args for c in ast.walk(arg)
+                        if isinstance(c, ast.Constant)
+                        and isinstance(c.value, str)
+                        and c.value.startswith("dlti_"))
+    return found
+
+
+def test_every_device_scope_name_is_pinned():
+    found = _scope_literals()
+    assert "dlti_loop_pass_u" in found and "dlti_attn_full" in found
+    assert found == DEVICE_SCOPE_CATALOG, (
+        sorted(found - DEVICE_SCOPE_CATALOG),
+        sorted(DEVICE_SCOPE_CATALOG - found))
