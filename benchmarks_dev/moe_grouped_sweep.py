@@ -8,10 +8,14 @@ calls, at the published widths of the three configurations that run it, 64
 experts held, bf16, over 32 to 4,096 tokens, with a seeded skewed routing
 (popularity lognormal, sigma 0.7: the fullest expert gets about four times
 the mean at 2,048 tokens, as ``moe_expert_load_max_over_mean`` reads in the
-cells). A geometry whose width the kernel does not take
-(``moe.takes_grouped``: nemotron's 1,856) is timed masked alone. A case is chained ``--chain`` times inside one jit so that dispatch
-does not show; a geometry runs in a process of its own under a time limit
-(a shape that never returns costs that, not the call).
+cells). The weights are held as the layer holds them: drawn at the
+published width and zero-padded to ``grouped_experts.held_width`` of it
+(nemotron's 1,856 at 1,920), both paths at that width. ``--held-width N``
+holds them another width (2048: whole chunks; 1856: as published, masked
+alone, what a decode step read before PR 50). A case is chained ``--chain``
+times inside one jit so that dispatch does not show; a geometry runs in a
+process of its own under a time limit (a shape that never returns costs
+that, not the call).
 
     chiprun -- python3 benchmarks_dev/moe_grouped_sweep.py \\
         --out chiprun_out/moe_grouped_sweep.jsonl
@@ -61,17 +65,22 @@ def child(args):
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     from dlti_tpu.models import moe
+    from dlti_tpu.ops.pallas import grouped_experts as kernel
 
     h, f, gated, experts, k = GEOMETRIES[args.geometry]
+    held_f = args.held_width or kernel.held_width(f)
     keys = jax.random.split(jax.random.PRNGKey(args.seed), 4)
 
-    def weight(key, shape):
-        return (jax.random.normal(key, shape, jnp.float32)
-                * shape[1] ** -0.5).astype(jnp.bfloat16)
+    def weight(key, shape, axis):
+        w = (jax.random.normal(key, shape, jnp.float32)
+             * shape[1] ** -0.5).astype(jnp.bfloat16)
+        pad = [(0, 0)] * 3
+        pad[axis] = (0, held_f - f)
+        return jnp.pad(w, pad)
 
-    w_gate = weight(keys[0], (HELD, h, f)) if gated else None
-    w_up = weight(keys[1], (HELD, h, f))
-    w_down = weight(keys[2], (HELD, f, h))
+    w_gate = weight(keys[0], (HELD, h, f), 2) if gated else None
+    w_up = weight(keys[1], (HELD, h, f), 2)
+    w_down = weight(keys[2], (HELD, f, h), 1)
     device = jax.devices()[0]
 
     def timed(fn, xs):
@@ -96,6 +105,7 @@ def child(args):
         sizes = np.bincount(local.reshape(-1), minlength=HELD + 1)[:HELD]
         xs = jax.random.normal(keys[3], (tokens, h)).astype(jnp.bfloat16)
         base = {"geometry": args.geometry, "tokens": tokens,
+                "held_width": held_f,
                 "device": device.device_kind,
                 "held_assignments": int(sizes.sum()),
                 "load_max_over_mean": round(float(
@@ -104,7 +114,7 @@ def child(args):
                     jnp.asarray(w), w_gate, w_up, w_down)
         tile = moe.GROUPED_TILE_ROWS
         for path in ("masked", "grouped")[
-                :1 + moe.takes_grouped(moe.GROUPED_MIN_TOKENS, f)]:
+                :1 + moe.takes_grouped(moe.GROUPED_MIN_TOKENS, held_f)]:
             if path == "masked":
                 def fn(xs, local, sizes, w, *weights):
                     return moe.routed_masked(xs, local, w, *weights)
@@ -134,14 +144,19 @@ def main():
     ap.add_argument("--case-seconds", type=int, default=120,
                     help="a case whose first call takes longer ends the child")
     ap.add_argument("--geometry-seconds", type=int, default=900)
+    ap.add_argument("--geometries", nargs="+", default=sorted(GEOMETRIES),
+                    choices=sorted(GEOMETRIES))
+    ap.add_argument("--held-width", type=int, default=0,
+                    help="hold the experts this wide, whatever the rule says")
     ap.add_argument("--out", default=None, help="the lines, as a file too")
     args = ap.parse_args()
     if args.geometry:
         return child(args)
 
     lines, failed = [], []
-    for name in GEOMETRIES:
+    for name in args.geometries:
         cmd = [sys.executable, os.path.abspath(__file__), "--geometry", name,
+               "--held-width", str(args.held_width),
                "--chain", str(args.chain), "--reps", str(args.reps),
                "--seed", str(args.seed), "--case-seconds",
                str(args.case_seconds), "--tokens", *map(str, args.tokens)]
@@ -166,7 +181,7 @@ def main():
     for x in lines:
         print(json.dumps(x))
     print("geometry tokens masked grouped")
-    for name in GEOMETRIES:
+    for name in args.geometries:
         for tokens in args.tokens:
             row = {x["path"]: x["ms"] for x in lines
                    if (x["geometry"], x["tokens"]) == (name, tokens)}

@@ -18,9 +18,12 @@ the name of a file in ``benchmark/configs`` (default nemotron3_nano_30b;
 its widths as published, the engine's shapes from the configuration's
 serving cell, as many of its layers as the file runs unless ``<layers>``
 says fewer); ``masked`` (every expert layer on the mask) or ``grouped``
-(every call of ``GROUPED_MIN_TOKENS`` or more through the kernel, which
-takes a width of whole chunks alone); ``f<N>`` the routed experts N wide
-instead of as published; ``nokernel`` the grouped layout and gathers with
+(the default: every call of ``GROUPED_MIN_TOKENS`` or more through the
+kernel, where it takes the width the experts are held at); ``f<N>`` the
+routed experts published N wide (``f2048``: nemotron3_nano_30b's held at
+whole chunks); ``unpadded`` the experts held as published (1,856: the
+program of before PR 50, on the mask); ``nokernel`` the grouped layout
+and gathers with
 the kernel replaced by the identity on its rows; ``full`` no padding tokens
 (default: bucket - 8 real tokens a row); ``tiny`` a test-width preset
 (nemotron_h_tiny, or the one of ``MODEL_PRESETS`` a word names), for a try
@@ -103,6 +106,8 @@ def child(words: set, rows: int, bucket: int, layers: int,
             ModelConfig(**fields), paged_attention_impl="kernel")
         say("configuration", name, "layers", cfg.num_layers, "blocks", blocks,
             "max_model_len", model_len)
+    if "unpadded" in words:
+        kernel.held_width = lambda width: width
     if "masked" in words:
         moe.takes_grouped = lambda tokens, width: False
     if "nokernel" in words:

@@ -347,6 +347,23 @@ class ModelConfig:
                 + mats * h * self.moe_shared_intermediate_size
                 + h * self.moe_num_experts + self.moe_num_experts)
 
+    @property
+    def held_pad_params(self) -> int:
+        """Zeros the expert layers hold beside ``num_params()``: the routed
+        experts' matrices lie at ``grouped_experts.held_width`` of the
+        published width (models.moe.HeldExpertsMLP); 0 where that is the
+        published width, as for every width the kernel takes."""
+        if not self.moe_num_experts:
+            return 0
+        from dlti_tpu.ops.pallas.grouped_experts import held_width
+
+        layers = self.layer_pattern.count("E") if self.layer_pattern \
+            else self.num_layers - min(self.first_k_dense, self.num_layers)
+        pad = held_width(self.moe_intermediate_size) \
+            - self.moe_intermediate_size
+        return (layers * self.moe_held * self.hidden_size * pad
+                * (3 if self.mlp_activation == "silu" else 2))
+
     def _count_params(self, include_lm_head: bool, active_only: bool) -> int:
         h, m, v = self.hidden_size, self.intermediate_size, self.vocab_size
         hd = self.resolved_head_dim

@@ -24,7 +24,9 @@ shape alone (``takes_grouped`` and the note above it): a call of few
 tokens (a decode round, a prompt's short tail) runs every held expert over
 every token under the routing weights as a mask, which costs the weights'
 read and nothing else; a call of many tokens (a prefill), where the kernel
-takes the experts' width, lays the held assignments out by expert and
+takes the width the experts are held at (``grouped_experts.held_width``:
+the published width, or the next one of whole lane tiles, zero-padded,
+where that is near), lays the held assignments out by expert and
 runs each tile of rows through its expert alone
 (``ops.pallas.grouped_experts``): top-k of ``held_n`` of the mask's
 products. The same sum either way, no assignment dropped.
@@ -185,20 +187,27 @@ class MoEMLP(nn.Module):
 # of GROUPED_TILE_ROWS rows; fewer and every held expert runs over every
 # token under the routing weights as a mask. One layer of 64 held experts at
 # the published widths on the v5e, bf16, masked / grouped ms a call
-# (``benchmarks_dev/moe_grouped_sweep.py``, my chip runs, PR 42):
+# (``benchmarks_dev/moe_grouped_sweep.py``, my chip runs, PR 42; nemotron's
+# last column PR 50):
 #
-#   tokens   xing4 3,584 x 1,024   nemotron 2,688 x 1,856   kanana 2,048 x 768
-#            gated, top-4 of 64    relu2, ~3 of 64 held     gated, ~3 of 64
-#       32      2.03 /  1.80          1.82 / ( 1.93)           0.92 /  0.82
-#      128      2.08 /  2.29          1.87 / ( 2.34)           0.95 /  1.02
-#      256      2.16 /  2.38          1.94 / ( 2.46)           0.99 /  1.08
-#      512      4.00 /  2.61          3.61 / ( 2.58)           1.74 /  1.16
-#    1,024      7.68 /  2.76          7.09 / ( 3.07)           3.37 /  1.35
-#    2,048     15.26 /  3.73         14.07 / ( 4.01)           6.64 /  1.53
-#    4,096     30.40 /  5.66         28.76 / ( 6.16)          13.20 /  2.94
+#   tokens  xing4 3,584x1,024  nemotron 2,688 x 1,856          kanana 2,048x768
+#           gated, top-4 of 64 relu2, ~3 of 64 held            gated, ~3 of 64
+#                              as published   held at 1,920
+#       32     2.03 /  1.80     1.82 / ( 1.93)   2.02 /  1.65    0.92 /  0.82
+#      128     2.08 /  2.29     1.87 / ( 2.34)                   0.95 /  1.02
+#      256     2.16 /  2.38     1.94 / ( 2.46)   2.10 /  2.12    0.99 /  1.08
+#      512     4.00 /  2.61     3.61 / ( 2.58)   3.65 /  2.24    1.74 /  1.16
+#    1,024     7.68 /  2.76     7.09 / ( 3.07)   7.20 /  2.72    3.37 /  1.35
+#    2,048    15.26 /  3.73    14.07 / ( 4.01)  14.24 /  3.52    6.64 /  1.53
+#    4,096    30.40 /  5.66    28.76 / ( 6.16)                  13.20 /  2.94
 #
-# (nemotron's grouped column, in brackets: an earlier revision of the kernel,
-# which took any width as one block; the kernel as it stands refuses 1,856.)
+# (nemotron as published, grouped, in brackets: an earlier revision of the
+# kernel, which took any width as one block, and with which the family's
+# whole prefill program did not return: below. The masked column there is
+# what its calls cost until PR 50; the same sweep in PR 50 read it 1.82,
+# 1.96, 3.62, 7.10, 14.06 at 32, 256, 512, 1,024, 2,048 tokens. Held at
+# 2,048, whole chunks: 2.00 / 1.75, 2.14 / 2.25, 4.02 / 2.38, 7.77 / 2.86,
+# 15.40 / 3.62 at the same counts, 2.04 / 2.18 at 128, 30.8 / 5.70 at 4,096.)
 #
 # Up to 256 tokens the mask costs the weights' read and nothing else, and the
 # layout (a rank, a scatter, two gathers of rows) is what grouped adds to
@@ -214,32 +223,35 @@ class MoEMLP(nn.Module):
 # it pays ~14 ms for its 64 groups however few rows it is given, and a
 # 13-layer prefill of 2 rows x 2,048 tokens through it never returned).
 #
-# The kernel takes an expert's width in whole chunks of 256 columns
-# (``grouped_experts.takes_width``), and a layer of any other width stays on
-# the mask. Of the geometries served that leaves out one,
-# nemotron3_nano_30b's 1,856 (7.25 chunks, 14.5 lane tiles), and what leaves
-# it out is a fault, not a loss: the form that took any width as one block
-# won at 1,856 alone (the bracketed column), but that family's 13-layer
-# prefill program at 2 rows x 1,024 tokens never returns on the v5e with it
-# in the five expert layers, and returns in 0.05 s with the experts 1,792 or
-# 2,048 wide through the kernel as it stands (my chip runs, PR 42; PERF.md
-# section 7 item 6 has the bisection: the width that is not whole lane
-# tiles is what it takes; not the routing data, the traced grid length, the
-# padding tokens or the layout and gathers, and the same rows return at
-# 1 x 2,048 and in a 7-layer stack). In the program compiled for a v5e such
-# a width is also stored with the hidden size minor, so XLA copies ``w_up``
-# whole (638 MB a layer a call) to hand it to a kernel. What would serve
-# that family:
-# its width padded to whole chunks, in the checkpoint or in the call;
-# neither is done here, its programs are the parent's and its gain (a third
-# of ``tool_turns``' window is prefill) is not taken.
+# The kernel takes an expert's width in whole chunks of 256 columns, or in
+# lane tiles of 128 where the width is whole lane tiles and not whole chunks
+# (``grouped_experts.takes_width``, ``width_chunk``). Of the geometries
+# served one is neither: nemotron3_nano_30b's 1,856 (7.25 chunks, 14.5 lane
+# tiles). The form of the kernel that took any width as one block won at
+# 1,856 alone (the bracketed column), but that family's 13-layer prefill
+# program at 2 rows x 1,024 tokens never returned on the v5e with it in the
+# five expert layers, and returned in 0.05 s with the experts 1,792 or 2,048
+# wide through the kernel as it stood (my chip runs, PR 42; PERF.md section
+# 7 item 6 has the bisection: the width that is not whole lane tiles is what
+# it takes; in the program compiled for a v5e such a ``w_up`` is stored with
+# the hidden size minor and copied whole, 638 MB a layer a call, to be
+# handed to a kernel). So the layer holds its experts at a width the kernel
+# takes (``grouped_experts.held_width``: 1,856 at 1,920, the pad zeros;
+# every other served width as published) and that family's calls of 512
+# tokens or more go through the kernel as every other's do: every prefill
+# shape its cell warms returned on the chip (my chip runs, PR 50; PERF.md
+# section 6). The price is the decode step's: its mask reads what is held,
+# 2.02 ms a layer for 1.82 at 32 tokens. Which width, of 1,920 and 2,048:
+# the mask costs the same at both (2.02, 2.00), the kernel is 3-6 % faster
+# at 1,920 and 0.44 GB fewer zeros are held.
 GROUPED_MIN_TOKENS = 512
 GROUPED_TILE_ROWS = 128
 
 
 def takes_grouped(tokens: int, width: int) -> bool:
-    """Whether a call of ``tokens`` (rows x bucket) over experts ``width``
-    wide goes through the grouped product: read from static shapes alone."""
+    """Whether a call of ``tokens`` (rows x bucket) over experts held
+    ``width`` wide goes through the grouped product: read from static shapes
+    alone."""
     if tokens < GROUPED_MIN_TOKENS:
         return False
     from dlti_tpu.ops.pallas.grouped_experts import takes_width
@@ -294,6 +306,21 @@ def _relu2(x):
     return jnp.square(jax.nn.relu(x))
 
 
+def zero_padded(base, axis: int, width: int):
+    """``base`` drawn at ``width`` along ``axis`` (the published shape, so
+    the draw is the one an unpadded layer makes) and zeros from there to the
+    shape asked for."""
+
+    def init(key, shape, dtype=jnp.float32):
+        published = list(shape)
+        published[axis] = width
+        pad = [(0, 0)] * len(shape)
+        pad[axis] = (0, shape[axis] - width)
+        return jnp.pad(base(key, tuple(published), dtype), pad)
+
+    return init
+
+
 def centred_out_init(scale: float, batch_axis=()):
     """Seeded weights of a relu² MLP's down projection: LeCun-normal times
     ``scale``, each output's weights centred over the inputs.
@@ -337,7 +364,18 @@ class HeldExpertsMLP(nn.Module):
     ``silu(W_gate x) * W_up x`` (deepseek_v3). The shared
     part ``S`` has the same form at ``moe_shared_intermediate_size``: one
     shared expert, or several as one MLP of their summed width (the sum of
-    n gated MLPs of width f is one of width n x f)."""
+    n gated MLPs of width f is one of width n x f).
+
+    The routed experts' matrices are held at ``grouped_experts.held_width``
+    of the published ``moe_intermediate_size`` ``f``: where that is wider,
+    columns ``f:`` of ``w_up`` and ``w_gate`` and rows ``f:`` of ``w_down``
+    are zero. ``relu(x 0)^2 = 0`` and ``silu(x 0) (x 0) = 0``, and a zero
+    row of ``w_down`` adds nothing, so the sum over the held width is the
+    sum over the published one exactly. The seeded draw is made at the
+    published shape and padded, so the unpadded part is what an unpadded
+    layer draws. A pad's gradient is zero on both sides (the activation
+    there is 0, and so is its derivative at 0 times 0), so a training path,
+    which goes through the mask, leaves the pads zero."""
 
     cfg: ModelConfig
 
@@ -358,8 +396,11 @@ class HeldExpertsMLP(nn.Module):
         dtype, pdtype = _dtype(cfg.dtype), _dtype(cfg.param_dtype)
         b, s, h = x.shape
         T, E, k = b * s, cfg.moe_num_experts, cfg.num_experts_per_tok
+        from dlti_tpu.ops.pallas.grouped_experts import held_width
+
         lo, held_n, f = cfg.moe_held_start, cfg.moe_held, \
             cfg.moe_intermediate_size
+        held_f = held_width(f)
         xt = x.reshape(T, h)
         valid = (jnp.ones((T,), bool) if token_mask is None
                  else token_mask.reshape(T).astype(bool))
@@ -380,14 +421,18 @@ class HeldExpertsMLP(nn.Module):
             w = w / jnp.sum(w, axis=1, keepdims=True) * cfg.moe_routed_scaling
             held = (chosen >= lo) & (chosen < lo + held_n) & valid[:, None]
 
+            def drawn(base, axis):
+                return base if held_f == f else zero_padded(base, axis, f)
+
             def inner(name):
-                return self.param(name, nn.initializers.lecun_normal(
-                    batch_axis=(0,)), (held_n, h, f), pdtype).astype(dtype)
+                return self.param(name, drawn(nn.initializers.lecun_normal(
+                    batch_axis=(0,)), 2), (held_n, h, held_f),
+                    pdtype).astype(dtype)
 
             w_gate = inner("w_gate") if gated else None
             w_up = inner("w_up")
-            w_down = self.param("w_down", centred_out_init(
-                ROUTED_OUT_SCALE, batch_axis=(0,)), (held_n, f, h),
+            w_down = self.param("w_down", drawn(centred_out_init(
+                ROUTED_OUT_SCALE, batch_axis=(0,)), 1), (held_n, held_f, h),
                 pdtype).astype(dtype)
             # Tokens on each held expert (the counters, the grouped layout);
             # an assignment held elsewhere counts as none.
@@ -395,7 +440,7 @@ class HeldExpertsMLP(nn.Module):
             sizes = jnp.bincount(local.reshape(-1), length=held_n + 1)[
                 :held_n].astype(jnp.int32)
             xs = xt.astype(dtype)
-            if takes_grouped(T, f):
+            if takes_grouped(T, held_f):
                 y, tile_rows = routed_grouped(xs, local, sizes, w, w_gate,
                                               w_up, w_down)
                 grouped = (jnp.sum(held), tile_rows)

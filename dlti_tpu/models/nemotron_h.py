@@ -45,7 +45,8 @@ COUNTERS = MOE_COUNTERS + ("recurrent_state_resets",
 # at 2 x 2,048 all run; with or without a `ragged_dot` kernel; cause not
 # found), so the engine is kept off every call above 2,048 tokens by
 # construction until that program is repaired. Every shape within the limit
-# ran on the chip.
+# ran on the chip, and runs with the experts held 1,920 wide and every call
+# of 512 tokens or more through the grouped kernel (PR 50; PERF.md section 6).
 PREFILL_CALL_TOKENS = 2048
 
 
@@ -118,11 +119,10 @@ class NemotronHForCausalLM(nn.Module):
 
         counters = dict.fromkeys(COUNTERS, jnp.int32(0))
         new_caches = [] if cache is not None else None
+        block = self._block_of_a_kind(positions, routed)
         for i, kind in enumerate(cfg.layer_pattern):
-            x, layer_cache, moe = NemotronHBlock(
-                cfg, kind, self.lora, self.mesh, name=f"layers_{i}")(
-                    x, positions, cache[i] if cache is not None else None,
-                    routed)
+            x, layer_cache, moe = block(
+                i, kind, x, cache[i] if cache is not None else None)
             if cache is not None:
                 new_caches.append(layer_cache)
             for name, n in zip(MOE_COUNTERS, () if moe is None else moe):
@@ -147,6 +147,44 @@ class NemotronHForCausalLM(nn.Module):
         logits = jnp.dot(x, lm_head.astype(x.dtype),
                          preferred_element_type=jnp.float32)
         return result(logits.astype(jnp.float32))
+
+    def _block_of_a_kind(self, positions, routed):
+        """``(i, kind, x, layer cache) -> NemotronHBlock's result`` for layer
+        ``i``. The layers of one kind differ in nothing but their weights and
+        their cache entry, so outside ``init`` a kind's block is ONE jitted
+        function of those: a program traces and lowers a Mamba-2 layer and
+        an expert layer once, not six and five times (the same as
+        ``models.llama.LoopPass``'s block; XLA inlines the calls; what a
+        layer sows stays inside its call, and nothing reads it). On the
+        chip's host tracing a 13-layer prefill program took over a second,
+        fourteen of them a start: PERF.md section 6, PR 50."""
+        cfg = self.cfg
+        if self.is_initializing():  # the tree: a submodule a layer
+            return lambda i, kind, x, layer_cache: NemotronHBlock(
+                cfg, kind, self.lora, self.mesh, name=f"layers_{i}")(
+                    x, positions, layer_cache, routed)
+        weights = self.variables["params"]
+        traced_once = {}
+
+        def block(i, kind, x, layer_cache):
+            # what of the entry is not an array stays outside the trace
+            static = {k: v for k, v in (layer_cache or {}).items()
+                      if isinstance(v, bool)}
+            key = (kind, tuple(sorted(static.items())))
+            if key not in traced_once:
+                shared = NemotronHBlock(cfg, kind, self.lora, self.mesh,
+                                        parent=None)
+                traced_once[key] = jax.jit(
+                    lambda w, x, positions, entry, routed: shared.apply(
+                        {"params": w}, x, positions,
+                        None if entry is None else {**entry, **static},
+                        routed))
+            arrays = None if layer_cache is None else {
+                k: v for k, v in layer_cache.items() if k not in static}
+            return traced_once[key](weights[f"layers_{i}"], x, positions,
+                                    arrays, routed)
+
+        return block
 
     def head_matrix(self, params, anchor):
         return head_matrix_from_leaves(params["embed_tokens"],

@@ -30,10 +30,16 @@ Inside a grid step the width is taken ``WIDTH_CHUNK`` columns at a time in
 a loop that is not unrolled: the same time to within 2 % as the width
 whole (3.65 for 3.60 ms a layer at 2,048 tokens) and 0.58 MB of kernel code
 for 1.41, which a prefill program holds once an expert layer and a warm
-start loads from the cache (my chip runs, PR 42). A width that is not whole
-chunks is refused: ``models.moe.takes_grouped`` asks ``takes_width`` and
-leaves such a layer on the mask (what that leaves out, and why the form
-that took any width whole was not kept: the note above
+start loads from the cache (my chip runs, PR 42). A width that is whole lane
+tiles of 128 columns and not whole chunks is taken half a chunk at a time
+(``width_chunk``: 1,920 = 15 x 128; a width of whole chunks keeps the chunk
+and the kernel text it had). Any other width is refused (``takes_width``),
+and the layer does not hand it one: ``models.moe.HeldExpertsMLP`` holds its
+experts at ``held_width`` of the published width, zeros from there on, which
+is whole lane tiles wherever the pad is small against the width
+(nemotron3_nano_30b's 1,856 at 1,920) and the published width elsewhere,
+where ``models.moe.takes_grouped`` leaves the layer on the mask (why the
+form that took any width whole was not kept: the note above
 ``models.moe.GROUPED_MIN_TOKENS``).
 
 An expert whose matrices, two buffers each, do not fit ``VMEM_WEIGHTS``
@@ -57,7 +63,9 @@ from jax.experimental.pallas import tpu as pltpu
 from dlti_tpu.ops.pallas.flash_attention import out_struct
 
 
-# Columns of an expert's width a step of the kernel's inner loop takes.
+# Columns of an expert's width a step of the kernel's inner loop takes; half
+# of it (a lane tile) for a width that is whole lane tiles and not whole
+# chunks (``width_chunk``).
 WIDTH_CHUNK = 256
 
 
@@ -67,21 +75,43 @@ WIDTH_CHUNK = 256
 VMEM_WEIGHTS = 80 << 20
 
 
+def width_chunk(width: int) -> int:
+    """The inner loop's step for experts ``width`` wide: ``WIDTH_CHUNK``
+    where it divides the width (every kernel of before keeps its text), else
+    half of it."""
+    return WIDTH_CHUNK if width % WIDTH_CHUNK == 0 else WIDTH_CHUNK // 2
+
+
 def takes_width(width: int) -> bool:
-    """Whether the kernel takes experts ``width`` wide: whole chunks."""
-    return width % WIDTH_CHUNK == 0
+    """Whether the kernel takes experts ``width`` wide: whole half chunks
+    (lane tiles of 128 columns)."""
+    return width % (WIDTH_CHUNK // 2) == 0
+
+
+def held_width(width: int) -> int:
+    """The width at which ``models.moe.HeldExpertsMLP`` holds experts
+    published ``width`` wide: the next width the kernel takes where the pad
+    is at most an eighth of the width (nemotron3_nano_30b's 1,856 is held
+    at 1,920: 3.4 %), else the width as published. The pad is zeros, which
+    add nothing to the sum, but a decode step's mask reads what is held:
+    a narrow layer (a test preset's 24 to 48 columns) is not made a lane
+    tile wide for the kernel's sake and stays on the mask."""
+    lanes = WIDTH_CHUNK // 2
+    padded = -(-width // lanes) * lanes
+    return padded if 8 * (padded - width) <= width else width
 
 
 def width_block(h: int, f: int, itemsize: int, gated: bool) -> int:
     """Columns of an expert's width one grid block holds: the whole width
     where its matrices fit ``VMEM_WEIGHTS`` twice over, else the most whole
     chunks that divide the width and fit."""
-    chunks = f // WIDTH_CHUNK
+    chunk = width_chunk(f)
+    chunks = f // chunk
     for n in range(1, chunks + 1):
         if chunks % n == 0 and \
                 2 * (2 + gated) * h * (f // n) * itemsize <= VMEM_WEIGHTS:
             return f // n
-    return WIDTH_CHUNK
+    return chunk
 
 
 def num_tiles(assignments: int, experts: int, tile_rows: int) -> int:
@@ -204,7 +234,7 @@ def grouped_experts(
     down product. Rows of a tile past ``tiles`` are not written.
     """
     return _jitted(x, tile_expert, tiles, w_gate, w_up, w_down,
-                   tile_rows, WIDTH_CHUNK, interpret)
+                   tile_rows, width_chunk(w_down.shape[1]), interpret)
 
 
 def _no_backward(*_):

@@ -129,7 +129,8 @@ def plan_training(cfg: ModelConfig, param_dtype: Optional[str] = None,
     n = cfg.num_params()
     trainable = n if trainable_params is None else trainable_params
     owners = {
-        "params": n * pbytes,
+        # what is held: the published count and the held experts' pads
+        "params": (n + cfg.held_pad_params) * pbytes,
         # AdamW first/second moments, fp32 regardless of param dtype.
         "optimizer_state": 2 * trainable * 4,
         # Transient but peak-relevant: one fp32 grad per trainable param.
@@ -165,7 +166,8 @@ def plan_serving(cfg: ModelConfig, param_dtype: Optional[str] = None,
     n = cfg.num_params()
     per_tok = kv_bytes_per_token(cfg, kv_dtype)
     owners = {
-        "params": n * pbytes,
+        # what is held: the published count and the held experts' pads
+        "params": (n + cfg.held_pad_params) * pbytes,
         "kv_block_pool": per_tok * block_size * num_blocks,
     }
     if adapter_slots > 0:

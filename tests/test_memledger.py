@@ -512,6 +512,27 @@ def test_memory_plan_serving_matches_measured_kv_pool(engine):
     assert plan["max_resident_tokens"] == (ec.num_blocks - 1) * ec.block_size
 
 
+@pytest.mark.parametrize("width,held", [(48, 48), (1024, 1024),
+                                        (1856, 1920)])
+def test_memory_plan_counts_what_the_held_experts_hold(width, held):
+    """Parameters reported are the published count; the bytes held are of
+    the experts at their held width (nemotron3_nano_30b's 1,856: 1,920)."""
+    import dataclasses
+
+    from dlti_tpu.config import MODEL_PRESETS
+
+    cfg = dataclasses.replace(MODEL_PRESETS["nemotron_h_tiny"],
+                              moe_intermediate_size=width)
+    pads = (cfg.layer_pattern.count("E") * cfg.moe_held * 2
+            * cfg.hidden_size * (held - width))
+    assert cfg.held_pad_params == pads
+    for plan in (memory_plan.plan_training(cfg, param_dtype="bfloat16"),
+                 memory_plan.plan_serving(cfg, param_dtype="bfloat16")):
+        assert plan["num_params"] == cfg.num_params()
+        assert plan["owners"]["params"] == 2 * (cfg.num_params() + pads)
+    assert MODEL_PRESETS["llama_tiny"].held_pad_params == 0
+
+
 def test_memory_plan_lora_trainable_count():
     n = memory_plan.lora_trainable_params(CFG, r=2)
     h, hd = CFG.hidden_size, CFG.resolved_head_dim

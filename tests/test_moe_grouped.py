@@ -133,11 +133,15 @@ def test_grouped_is_masked_is_the_loop(activation, case, monkeypatch):
 def test_the_path_is_read_from_the_call_s_static_shape_alone():
     """At the constants as they stand: a call of GROUPED_MIN_TOKENS tokens
     over experts whole chunks of the kernel wide is grouped, one token
-    fewer is masked whatever the rows x bucket, and a width the kernel does
-    not take (nemotron's 1,856) stays masked at any count."""
+    fewer is masked whatever the rows x bucket; nemotron's 1,856 is held
+    at a width the kernel takes and goes grouped with the rest, and a width
+    that is held as it is and is not whole chunks stays masked at any
+    count."""
     assert moe.takes_grouped(512, 1024) and moe.takes_grouped(2048, 768)
     assert not moe.takes_grouped(256, 1024)
-    assert not moe.takes_grouped(2048, 1856)
+    assert moe.takes_grouped(2048, kernel_module.held_width(1856))
+    assert not moe.takes_grouped(256, kernel_module.held_width(1856))
+    assert not moe.takes_grouped(2048, kernel_module.held_width(96))
     cfg = _cfg("silu", moe_intermediate_size=256)
     T = moe.GROUPED_MIN_TOKENS
     x = jax.random.normal(jax.random.PRNGKey(2), (2, T // 2, cfg.hidden_size))
@@ -263,9 +267,13 @@ def test_a_layout_with_nothing_held_runs_one_tile_and_adds_nothing(
     assert not np.asarray(y).any() and int(tile_rows) == 0
 
 
+# two chunks of 256; three lane tiles of 128, which is not whole chunks (as
+# nemotron3_nano_30b's held 1,920 is fifteen)
+@pytest.mark.parametrize("f", [512, 384])
 @pytest.mark.parametrize("gated", [False, True], ids=["relu2", "silu"])
-def test_the_kernel_takes_each_tile_through_its_expert(gated):
-    experts, h, f, tile = 3, 32, 512, 8           # two chunks of the width
+def test_the_kernel_takes_each_tile_through_its_expert(gated, f):
+    experts, h, tile = 3, 32, 8
+    assert kernel_module.width_chunk(f) == (256 if f == 512 else 128)
     keys = jax.random.split(jax.random.PRNGKey(7), 5)
     tile_expert = jnp.asarray([2, 0, 0, 1, 1, 1], jnp.int32)
     x = jax.random.normal(keys[0], (6 * tile, h))
@@ -297,8 +305,105 @@ def test_the_kernel_refuses_rows_that_are_not_whole_tiles():
 
 def test_the_kernel_refuses_a_width_that_is_not_whole_chunks():
     assert kernel_module.takes_width(1024) and kernel_module.takes_width(768)
+    # as published it is refused still; the layer holds it at a width that
+    # is not: whole lane tiles, taken half a chunk at a time
     assert not kernel_module.takes_width(1856)
+    assert kernel_module.takes_width(kernel_module.held_width(1856))
+    assert kernel_module.width_chunk(1920) == 128
+    assert [kernel_module.width_chunk(f) for f in (768, 1024, 2048)] \
+        == [256] * 3
     with pytest.raises(ValueError, match="whole chunks"):
         grouped_experts(jnp.zeros((16, 32)), jnp.zeros((2,), jnp.int32),
-                        jnp.int32(2), None, jnp.zeros((2, 32, 384)),
-                        jnp.zeros((2, 384, 32)), tile_rows=8, interpret=True)
+                        jnp.int32(2), None, jnp.zeros((2, 32, 320)),
+                        jnp.zeros((2, 320, 32)), tile_rows=8, interpret=True)
+
+
+# -- the held width -----------------------------------------------------------
+
+# published width -> the width the layer holds: nemotron3_nano_30b's alone of
+# the served ones is padded (to whole lane tiles of 128); widths the kernel
+# takes and the narrow test presets (a pad of more than an eighth of the
+# width) are held as they are
+HELD_WIDTHS = {1856: 1920, 464: 512, 1024: 1024, 768: 768, 2048: 2048,
+               512: 512, 24: 24, 32: 32, 48: 48, 96: 96, 1792: 1792,
+               225: 225, 228: 256, 350: 384, 340: 340}
+
+
+@pytest.mark.parametrize("width", sorted(HELD_WIDTHS))
+def test_the_held_width_is_read_from_the_published_width_alone(width):
+    held = kernel_module.held_width(width)
+    assert held == HELD_WIDTHS[width]
+    assert held == width or (kernel_module.takes_width(held)
+                             and 8 * (held - width) <= width)
+
+
+def _padded_layer(activation):
+    """A layer published 464 wide (1,856 / 4), which the rule holds at 512,
+    with its seeded parameters, and the same parameters cut to 464."""
+    cfg = _cfg(activation, moe_intermediate_size=464)
+    x = jax.random.normal(jax.random.PRNGKey(1), (3, 11, cfg.hidden_size))
+    params = dict(HeldExpertsMLP(cfg).init(jax.random.PRNGKey(5), x)["params"])
+    inner = ["w_up"] + ["w_gate"] * (activation == "silu")
+    cut = {**params, "w_down": params["w_down"][:, :464],
+           **{name: params[name][:, :, :464] for name in inner}}
+    return cfg, x, params, cut, inner
+
+
+@pytest.mark.parametrize("activation", ["relu2", "silu"])
+def test_a_padded_layer_is_the_sum_over_the_published_width(activation,
+                                                            monkeypatch):
+    """Masked and grouped (the kernel at its own chunk of 256 columns, two
+    of them) over the held 512 columns equal the loop over the 464 published
+    ones of the same weights, and each other."""
+    cfg, x, params, cut, inner = _padded_layer(activation)
+    assert [params[n].shape[-1] for n in inner] == [512] * len(inner)
+    assert params["w_down"].shape[1] == 512
+    assert kernel_module.width_chunk(512) == 256
+    want, sizes, _ = _loop(cfg, cut, x, None)
+
+    def apply(min_tokens):
+        monkeypatch.setattr(moe, "GROUPED_MIN_TOKENS", min_tokens)
+        monkeypatch.setattr(moe, "GROUPED_TILE_ROWS", TILE)
+        y, counters = HeldExpertsMLP(cfg).apply({"params": params}, x)
+        return np.asarray(y), [int(c) for c in counters]
+
+    masked, counted_m = apply(1 << 30)
+    grouped, counted_g = apply(1)
+    np.testing.assert_allclose(masked, want, atol=3e-5)
+    np.testing.assert_allclose(grouped, want, atol=3e-5)
+    np.testing.assert_allclose(grouped, masked, atol=3e-5)
+    assert counted_m[4:] == [0, 0]
+    assert counted_g[4] == int(sizes.sum()) > 0
+
+
+@pytest.mark.parametrize("activation", ["relu2", "silu"])
+def test_the_seeded_pads_are_zero_round_the_published_draw(activation,
+                                                           monkeypatch):
+    """The unpadded part of every seeded weight is, to the bit, what the
+    layer drew before it held a pad (the base initialisers at the published
+    shape: the down projection centred over the published rows alone), the
+    rest is zero, and a gradient through the mask leaves it zero."""
+    cfg, x, params, cut, inner = _padded_layer(activation)
+    for name in inner:
+        assert not np.asarray(params[name][:, :, 464:]).any()
+    assert not np.asarray(params["w_down"][:, 464:]).any()
+    with monkeypatch.context() as m:
+        m.setattr(kernel_module, "held_width", lambda width: width)
+        unpadded = HeldExpertsMLP(cfg).init(jax.random.PRNGKey(5), x)["params"]
+    assert unpadded["w_up"].shape == (EXPERTS, cfg.hidden_size, 464)
+    for name in inner + ["w_down", "router", "e_score_correction_bias"]:
+        assert np.array_equal(np.asarray(cut[name]),
+                              np.asarray(unpadded[name])), name
+    np.testing.assert_allclose(
+        np.asarray(params["w_down"]).sum(axis=1), 0, atol=1e-5)
+
+    def loss(p):
+        y = HeldExpertsMLP(cfg).apply({"params": p}, x)[0]
+        return jnp.sum(y * jnp.cos(jnp.arange(y.size).reshape(y.shape)))
+
+    grads = jax.grad(loss)(params)
+    for name in inner:
+        assert np.asarray(grads[name][:, :, :464]).any()
+        assert not np.asarray(grads[name][:, :, 464:]).any()
+    assert np.asarray(grads["w_down"][:, :464]).any()
+    assert not np.asarray(grads["w_down"][:, 464:]).any()

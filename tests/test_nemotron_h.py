@@ -556,3 +556,91 @@ def test_a_prefill_call_is_held_to_the_models_padded_tokens(tiny, monkeypatch):
     assert [r.output_token_ids for r in got] == \
         [r.output_token_ids for r in want]
     _hold_to_reference(tiny, prompts, got)
+
+
+# -- experts held wider than published ---------------------------------------
+
+PUBLISHED, HELD = 232, 256  # a pad of 24 columns: a tenth of the width
+
+
+@pytest.fixture(scope="module")
+def padded(tiny):
+    """The tiny model with experts published 232 wide, which the rule holds
+    at 256 at the constants as they stand; the reference is given the same
+    weights cut to the published width (it takes shapes from the arrays)."""
+    assert grouped_experts.held_width(PUBLISHED) == HELD
+    cfg = dataclasses.replace(tiny["cfg"], moe_intermediate_size=PUBLISHED)
+    params = build_model(cfg).init(jax.random.PRNGKey(0),
+                                   jnp.zeros((1, 8), jnp.int32))["params"]
+    cut = dict(params)
+    for i, kind in enumerate(cfg.layer_pattern):
+        if kind == "E":
+            mixer = params[f"layers_{i}"]["mixer"]
+            cut[f"layers_{i}"] = {**params[f"layers_{i}"], "mixer": {
+                **mixer, "w_up": mixer["w_up"][:, :, :PUBLISHED],
+                "w_down": mixer["w_down"][:, :PUBLISHED]}}
+    ref_logprobs = jax.jit(lambda ids: jax.nn.log_softmax(
+        tiny["reference"].forward(cut, tiny["sizes"], ids), -1))
+    return {**tiny, "cfg": cfg, "params": params,
+            "ref_logprobs": ref_logprobs}
+
+
+def test_a_padded_models_prefill_of_512_tokens_holds_the_kernel(padded):
+    """The path is read from the held width: a prefill call of 4 x 128
+    tokens lowers with the grouped kernel in every expert layer, one of
+    2 x 128 without it, at the constants as they stand."""
+    mixer = padded["params"]["layers_1"]["mixer"]
+    assert mixer["w_up"].shape[-1] == mixer["w_down"].shape[1] == HELD
+    assert padded["cfg"].num_params() == sum(
+        x.size for x in jax.tree_util.tree_leaves(padded["params"])) \
+        - padded["cfg"].held_pad_params
+    ex = _engine(padded, max_seqs=8, max_model_len=256).executor
+
+    def text(rows):
+        ids = jnp.zeros((rows, 128), jnp.int32)
+        row = jnp.zeros((rows,), jnp.int32)
+        return ex._prefill_fn(128).lower(
+            ex.params, ex.cache, ids, ids, jnp.zeros((rows, 16), jnp.int32),
+            row, row).as_text(debug_info=True)  # the scopes' names
+
+    assert text(4).count("dlti_grouped_experts") >= \
+        padded["cfg"].layer_pattern.count("E")
+    assert "dlti_grouped_experts" not in text(2)
+
+
+def test_a_padded_engine_agrees_with_the_published_widths_forward(
+        padded, monkeypatch):
+    """Prefill (one call of 4 x 32 tokens, grouped at the boundary set here,
+    the kernel at its own chunk) then decode (masked) over the held 256
+    columns, against the float32 reference over the 232 published ones."""
+    import dlti_tpu.models.moe as moe
+
+    monkeypatch.setattr(moe, "GROUPED_MIN_TOKENS", 128)
+    monkeypatch.setattr(moe, "GROUPED_TILE_ROWS", 16)
+    prompts = _prompts([30, 29, 31, 28], seed=6)
+    eng = _engine(padded)
+    got = eng.generate(prompts, SamplingParams(max_tokens=6, temperature=0.0))
+    assert eng.stats["moe_grouped_rows"] == (
+        eng.stats["moe_held_assignments"]
+        - eng.stats["moe_held_assignments_decode"]) > 0
+    assert eng.stats["moe_grouped_rows_decode"] == 0
+    _hold_to_reference(padded, prompts, got)
+
+
+def test_a_kinds_block_is_traced_once_a_program(tiny):
+    """Outside ``init`` the layers of one kind are calls of ONE jitted
+    function of a layer's weights and cache entry (the pattern here is
+    MEM*E: two Mamba-2 layers, two expert layers, one attention layer):
+    five calls, three traces, and the same logits as ever."""
+    ids = jnp.asarray(_prompts([16])[0])[None]
+    jaxpr = jax.make_jaxpr(lambda p: tiny["model"].apply(
+        {"params": p}, ids)[0])(tiny["params"])
+    blocks = [e for e in jaxpr.jaxpr.eqns if e.primitive.name in (
+        "pjit", "jit") and e.params["name"] == "<lambda>"]
+    assert len(blocks) == len(tiny["cfg"].layer_pattern) == 5
+    assert len({id(e.params["jaxpr"]) for e in blocks}) == 3
+    want = tiny["reference"].forward(tiny["params"], tiny["sizes"], ids[0])
+    got, _ = jax.jit(lambda p: tiny["model"].apply({"params": p}, ids))(
+        tiny["params"])
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want),
+                               atol=2e-5, rtol=1e-4)

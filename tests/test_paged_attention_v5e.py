@@ -2,10 +2,12 @@
 ``tests/benchmark/test_bench_kernels_v5e.py`` (the benchmark's file) does not
 hold: nemotron3_nano_30b's two kv heads with sixteen query heads each, and an
 int8 pool with its scale tiles; and the grouped expert kernel at the three
-configurations' published widths, as ``HeldExpertsMLP`` calls it for a
+configurations' held widths, as ``HeldExpertsMLP`` calls it for a
 2,048-token prefill. Nothing runs: the TPU compiler installed here
 compiles for a chip that is described, not attached. The topology is
 described inside a module-scoped fixture and never at import."""
+
+import re
 
 import pytest
 
@@ -76,7 +78,7 @@ def test_paged_decode_kernel_compiles_for_the_v5e(one_chip, name):
 # (hidden, expert width, gated, top-k): 64 experts held, a 2,048-token call
 EXPERT_GEOMETRIES = {
     "xing4_29b": (3584, 1024, True, 4),
-    "nemotron3_nano_30b": (2688, 1856, False, 6),  # 7.25 chunks: refused
+    "nemotron3_nano_30b": (2688, 1856, False, 6),  # held at 15 lane tiles
     "kanana2_30b": (2048, 768, True, 6),
 }
 
@@ -88,10 +90,12 @@ def test_grouped_expert_kernel_compiles_for_the_v5e(one_chip, name):
 
     from dlti_tpu.models import moe
     from dlti_tpu.ops.pallas.grouped_experts import (
-        grouped_experts, num_tiles,
+        grouped_experts, held_width, num_tiles,
     )
 
-    h, f, gated, k = EXPERT_GEOMETRIES[name]
+    h, published, gated, k = EXPERT_GEOMETRIES[name]
+    f = held_width(published)
+    assert f == (1920 if name == "nemotron3_nano_30b" else published)
     held, tokens, tile = 64, 2048, moe.GROUPED_TILE_ROWS
     tiles = num_tiles(tokens * k, held, tile)
 
@@ -107,11 +111,12 @@ def test_grouped_expert_kernel_compiles_for_the_v5e(one_chip, name):
     args = (shape((tiles * tile, h), jnp.bfloat16), shape((tiles,), jnp.int32),
             shape((), jnp.int32), inner, inner,
             shape((held, f, h), jnp.bfloat16))
-    if not moe.takes_grouped(tokens, f):
-        # A width the kernel does not take is left on the mask by the
-        # layer and refused by the kernel: never handed to the compiler.
-        with pytest.raises(ValueError, match="whole chunks"):
-            jax.jit(run).lower(*args)
-        return
-    compiled = jax.jit(run).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert moe.takes_grouped(tokens, f)
+    text = jax.jit(run).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    # The kernel is handed the weights as they are held: no instruction
+    # but the parameters has a weight's shape (a width that is not whole
+    # lane tiles had XLA copy ``w_up`` whole in front of the kernel).
+    made = re.findall(r"= bf16\[%d,(?:%d,%d|%d,%d)\]\S* ([\w-]+)\("
+                      % (held, h, f, f, h), text)
+    assert made and set(made) == {"parameter"}, made
