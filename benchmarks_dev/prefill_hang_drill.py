@@ -25,7 +25,15 @@ whole chunks); ``unpadded`` the experts held as published (1,856: the
 program of before PR 50, on the mask); ``nokernel`` the grouped layout
 and gathers with
 the kernel replaced by the identity on its rows; ``full`` no padding tokens
-(default: bucket - 8 real tokens a row); ``tiny`` a test-width preset
+(default: bucket - 8 real tokens a row); ``ctx<N>`` every row starts at
+position N over a context of N cached rows (default 0: fresh rows; the pool
+is zeros, which times as any other content does); ``kb<N>`` the latent
+family's prefill kernel with tiles of N queries and keys
+(``models.latent.KERNEL_BLOCK``; a checkout without it ignores the word);
+``trace``
+three more calls under the profiler, then the seconds a call spends under
+each ``dlti_`` scope and in each family of operations
+(``benchmark/lib/scope_time.py``, ``reduce_trace.py``); ``tiny`` a test-width preset
 (nemotron_h_tiny, or the one of ``MODEL_PRESETS`` a word names), for a try
 on the CPU. Prints ``RETURNED`` or ``DID NOT RETURN`` a variant; exit 0
 either way. ``--dump DIR`` saves each variant's returned logits there, to
@@ -66,7 +74,7 @@ def child(words: set, rows: int, bucket: int, layers: int,
     import numpy as np
 
     from dlti_tpu.config import MODEL_PRESETS, ModelConfig
-    from dlti_tpu.models import build_model, moe
+    from dlti_tpu.models import build_model, latent, moe
     from dlti_tpu.ops.kv_cache import init_cache, window_blocks
     from dlti_tpu.ops.pallas import grouped_experts as kernel
     from dlti_tpu.serving.engine import EngineConfig
@@ -108,6 +116,12 @@ def child(words: set, rows: int, bucket: int, layers: int,
             "max_model_len", model_len)
     if "unpadded" in words:
         kernel.held_width = lambda width: width
+    for w in words:
+        if w[:2] == "kb" and w[2:].isdigit() \
+                and hasattr(latent, "KERNEL_BLOCK"):
+            latent.KERNEL_BLOCK = int(w[2:])
+    start = next((int(w[3:]) for w in words
+                  if w[:3] == "ctx" and w[3:].isdigit()), 0)
     if "masked" in words:
         moe.takes_grouped = lambda tokens, width: False
     if "nokernel" in words:
@@ -152,10 +166,11 @@ def child(words: set, rows: int, bucket: int, layers: int,
     ids = np.random.default_rng(7).integers(
         1, cfg.vocab_size - 1, (rows, bucket)).astype(np.int32)
     at = np.arange(bucket, dtype=np.int32)[None, :]
-    positions = np.where(at < real, at, -1) * np.ones((rows, 1), np.int32)
+    positions = np.where(at < real, start + at, -1) \
+        * np.ones((rows, 1), np.int32)
     # The table as the engine forms it: a row's whole table where the model
     # asks for that, else the call's own blocks; a window group its own.
-    used = bucket // 16
+    used = (start + bucket) // 16
     width = model_len // 16 \
         if getattr(model, "prefill_whole_tables", False) else used
     tables = np.zeros((rows, width), np.int32)
@@ -197,6 +212,28 @@ def child(words: set, rows: int, bucket: int, layers: int,
         "ms": times[2:], "median_ms": sorted(times[2:])[2],
         "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
         "logits": list(last.shape)}))
+    if "trace" in words:
+        import tempfile
+
+        traced = tempfile.mkdtemp(prefix="drill_trace_")
+        with jax.profiler.trace(traced):
+            for _ in range(3):
+                cache, *rest = program(params, cache, *args)
+                jax.block_until_ready(rest)
+        lib = os.path.join(ROOT, "benchmark", "lib")
+        for script, label in (("scope_time.py", "SCOPES"),
+                              ("reduce_trace.py", "OPS")):
+            out = os.path.join(traced, label + ".json")
+            subprocess.run(
+                [sys.executable, os.path.join(lib, script), traced, "--out",
+                 out], env={**os.environ, "JAX_PLATFORMS": "cpu"},
+                capture_output=True, timeout=600)
+            with open(out) as fh:
+                got = json.load(fh) or {}
+            say(label, json.dumps(
+                got.get("programs", {}).get("prefill") if label == "SCOPES"
+                else {"prefill": got.get("programs", {}).get("prefill"),
+                      "device_ops": got.get("device_ops")}))
     if dump:  # the last rows' logits, to lay beside another checkout's
         os.makedirs(dump, exist_ok=True)
         np.save(os.path.join(dump, "%s_%dx%d.npy" % (

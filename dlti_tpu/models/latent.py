@@ -21,9 +21,12 @@ that all heads share (x the normed input, h a head):
 
 Both forms are the same function of one set of weights (the products
 re-associated) and :class:`LatentAttention` holds both: expanded where many
-queries share the up-projected keys (training, a prefill call), absorbed
-where few do (a decode step through ``ops.pallas.latent_attention``, whose
-XLA gather form is the CPU fallback; the few tokens a prefix hit leaves).
+queries share the up-projected keys (training, a prefill call: its own
+tokens through ``ops.pallas.flash_attention``'s forward kernel on the TPU,
+what earlier calls wrote through a loop over the pool, which on the CPU
+takes both), absorbed where few do (a decode step through
+``ops.pallas.latent_attention``, whose XLA gather form is the CPU fallback;
+the few tokens a prefix hit leaves).
 RoPE turns the pairs ``(2i, 2i + 1)`` (``rope_interleave``); ``scale`` is
 ``1 / sqrt(d_qk)``, under YaRN (``rope_scaling``) times
 ``ops.rope.yarn_softmax_factor``, the same in all three paths.
@@ -65,9 +68,12 @@ from dlti_tpu.ops.rope import (
 # each attending over the latents the earlier ones wrote. What bounds a
 # call is the held-expert layer (a call's held assignments laid out by
 # expert, up to two rows of activations an assignment:
-# ``models.moe.routed_grouped``) and the float32 (heads, queries, KEY_BLOCK)
-# scores of the expanded form; the same limit nemotron_h has, whose
-# 2 x 2,048 program never returned on the v5e (PERF.md section 7).
+# ``models.moe.routed_grouped``); the same limit nemotron_h has, whose
+# 2 x 2,048 program never returned on the v5e (PERF.md section 7). The
+# float32 (heads, queries, KEY_BLOCK) scores of the expanded form bound it
+# only where the loop over the pool runs (the CPU form; a later call's walk
+# over what earlier calls wrote): a call's own tokens are scored in the
+# kernel's VMEM (``prefill_takes_kernel``).
 PREFILL_CALL_TOKENS = 2048
 # A call with at most this many query tokens a row over a cached context
 # takes the absorbed form. Expanding K keys costs 2 K r H (nope + v) FLOP
@@ -79,11 +85,36 @@ ABSORB_MAX_QUERIES = 128
 # loop runs as far as the call's highest position, not as far as the block
 # table is wide: a 2,048-token call whose float32 scores against a whole
 # 8,704-key table took 200 ms of softmax passes through HBM on the v5e
-# (PERF.md section 6, PR 38) pays for the keys it can see.
+# (PERF.md section 6, PR 38) pays for the keys it can see. Beside the
+# kernel it runs as far as the highest of the rows' FIRST positions: no
+# step at all for a call of fresh prompts' first pieces.
 KEY_BLOCK = 512
+# A prefill call's own tokens go through ``ops.pallas.flash_attention``'s
+# forward kernel where the call has more than ``ABSORB_MAX_QUERIES`` tokens a
+# row in whole tiles of this many (every warmed bucket from 256 up) and the
+# kernel path resolves as it does for decode (``prefill_takes_kernel``).
+KERNEL_TOKENS_MULTIPLE = 128
+# Queries and keys a tile of that kernel: at 1 x 2,048 tokens, 32 heads,
+# 192 / 128 wide, 0.87 ms a call on the v5e against 1.16 at the kernel's own
+# 512 (a float32 (2048, 512) tile does not fit its VMEM; PERF.md section 6,
+# PR 51).
+KERNEL_BLOCK = 1024
 NEG_INF = -1e30
 # Counters that combine across layers by the largest, not the sum.
 LARGEST_OF = ("moe_expert_load_max", "mhc_sinkhorn_residual_e6")
+
+
+def prefill_takes_kernel(tokens: int, path: str) -> bool:
+    """Whether a call of ``tokens`` (padded) tokens a row over a cache scores
+    its own tokens through the flash forward kernel; ``path`` as
+    ``resolve_paged_decode`` gives it."""
+    return (tokens > ABSORB_MAX_QUERIES
+            and tokens % KERNEL_TOKENS_MULTIPLE == 0 and path != "xla")
+
+
+def walk_keys(block_size: int) -> int:
+    """Keys a step of the loop over cached latents covers: whole blocks."""
+    return max(1, KEY_BLOCK // block_size) * block_size
 
 
 class LatentAttention(nn.Module):
@@ -138,17 +169,20 @@ class LatentAttention(nn.Module):
                 return jnp.einsum("...hr,rhv->...hv", o_lat.astype(dtype),
                                   w_kvb[..., nope:])
 
-        def over_cache(layer_cache, tables, absorb):
+        def walk(layer_cache, tables, absorb, before=None):
             """This call's queries against each row's cached rows (its own
             just written among them), a block of ``KEY_BLOCK`` keys at a time
             with an online softmax, up to the call's highest position. A
             key's index in a row's logical window is its position, so the
             explicit-position mask hides what is stale or unallocated.
             ``absorb``: score the rows themselves (few queries); else expand
-            each block to keys and values (many)."""
+            each block to keys and values (many). ``before`` (b,): only the
+            keys below each row's, what earlier calls wrote, in steps up to
+            the highest of them (none where every row starts at 0). Returns
+            the online softmax's ``(m, l, acc)``, not yet divided."""
             block_size = layer_cache["latent"].shape[1]
-            blocks = max(1, KEY_BLOCK // block_size)
-            keys = blocks * block_size
+            keys = walk_keys(block_size)
+            blocks = keys // block_size
             tables = jnp.pad(tables, ((0, 0), (0, -tables.shape[1] % blocks)))
             q_lat = absorb_query(q_nope) if absorb else None
 
@@ -170,7 +204,8 @@ class LatentAttention(nn.Module):
                     "bshd,bkd->bhsk", q_rope, key,
                     preferred_element_type=jnp.float32)) * scale
                 visible = (j * keys + jnp.arange(keys))[None, None, None, :] \
-                    <= positions[:, None, :, None]
+                    <= (positions[:, None, :, None] if before is None
+                        else before[:, None, None, None] - 1)
                 scores = jnp.where(visible, scores, NEG_INF)
                 m_new = jnp.maximum(m, jnp.max(scores, -1, keepdims=True))
                 p = jnp.exp(scores - m_new) * visible
@@ -182,14 +217,62 @@ class LatentAttention(nn.Module):
                 return m_new, l, acc
 
             width = r if absorb else vd
-            m, l, acc = jax.lax.fori_loop(
-                0, jnp.max(positions) // keys + 1, step,
+            return jax.lax.fori_loop(
+                0, jnp.max(positions) // keys + 1 if before is None
+                else (jnp.max(before) + keys - 1) // keys, step,
                 (jnp.full((b, H, s, 1), NEG_INF, jnp.float32),
                  jnp.zeros((b, H, s, 1), jnp.float32),
                  jnp.zeros((b, s, H, width), jnp.float32)))
+
+        def over_cache(layer_cache, tables, absorb):
+            """Every key a query can see through the loop (the CPU form, and
+            the absorbed form of a call of few tokens)."""
+            _, l, acc = walk(layer_cache, tables, absorb)
             # a padding row (every position -1) saw no key: l = 0
             out = acc / jnp.maximum(jnp.swapaxes(l, 1, 2), 1e-30)
             return absorb_output(out) if absorb else out.astype(dtype)
+
+        def own_then_cached(layer_cache, tables, interpret):
+            """A prefill call's queries over two sets of keys, each in the
+            form that suits it, merged by their log-sum-exps in float32 (the
+            one softmax over all of them, re-associated as the loop's online
+            softmax already is). The call's own tokens: expanded once and
+            scored by the flash forward kernel, causal by index (a row is
+            ``start + arange(n)`` then -1s, so index order is position
+            order), a padding token segment 0; nothing of them is read back
+            from the pool. What earlier calls wrote: the loop, over keys
+            below each row's first position."""
+            from dlti_tpu.ops.pallas.flash_attention import (
+                flash_attention_fwd,
+            )
+
+            k_nope, v = expand(c)
+            k = jnp.concatenate([k_nope, jnp.broadcast_to(
+                k_rope[:, :, None], (b, s, H, rope_d))], axis=-1)
+            own, lse = flash_attention_fwd(
+                jnp.concatenate([q_nope, q_rope], axis=-1), k, v,
+                scale=scale, causal=True,
+                segment_ids=(positions >= 0).astype(jnp.int32),
+                block_q=KERNEL_BLOCK, block_kv=KERNEL_BLOCK,
+                interpret=interpret)
+            first = jnp.maximum(positions[:, 0], 0)
+
+            def with_cached():
+                m, l, acc = walk(layer_cache, tables, False, before=first)
+                # The kernel's lse of a query that saw no key (a padding
+                # token) is +1e30, for its backward: here it weighs nothing.
+                own_lse = jnp.where(lse > -NEG_INF / 2, NEG_INF,
+                                    lse)[..., None]
+                top = jnp.maximum(m, own_lse)               # (b, h, s, 1)
+                w_own, w_cached = jnp.exp(own_lse - top), jnp.exp(m - top)
+                # a padding token: both weights 1 over zeros, the divisor 1
+                out = (acc * jnp.swapaxes(w_cached, 1, 2)
+                       + own.astype(jnp.float32) * jnp.swapaxes(w_own, 1, 2)) \
+                    / jnp.swapaxes(w_cached * l + w_own, 1, 2)
+                return out.astype(dtype)
+
+            # fresh prompts' first pieces alone: the kernel's output as it is
+            return jax.lax.cond(jnp.max(first) > 0, with_cached, lambda: own)
 
         new_cache = None
         if cache is not None:
@@ -217,6 +300,10 @@ class LatentAttention(nn.Module):
                     value_dim=r, scale=scale,
                     interpret=path == "pallas-interpret")
                 out = absorb_output(o_lat)[:, None]
+            elif prefill_takes_kernel(s, path):
+                with jax.named_scope("dlti_mla_prefill_attn"):
+                    out = own_then_cached(new_cache, tables,
+                                          path == "pallas-interpret")
             else:
                 out = over_cache(new_cache, tables,
                                  absorb=s <= ABSORB_MAX_QUERIES)
@@ -380,3 +467,17 @@ class LatentForCausalLM(nn.Module):
     def head_matrix(self, params, anchor):
         return head_matrix_from_leaves(params["embed_tokens"],
                                        params.get("lm_head"), False, anchor)
+
+    def prefill_kernel_counts(self, rows: int, bucket: int, chunks: list,
+                              block_size: int) -> tuple:
+        """What a prefill call of ``rows`` x ``bucket`` padded tokens does,
+        read on the host from its shape and its real rows' ``(tokens, first
+        position)``: ``(query tokens whose own keys go through the flash
+        kernel, steps of the loop over cached latents x rows)``; zeros for
+        a call that takes another form."""
+        path, _ = resolve_paged_decode(self.cfg.paged_attention_impl,
+                                       tp_sharded=False)
+        if not prefill_takes_kernel(bucket, path):
+            return 0, 0
+        steps = -(-max(start for _, start in chunks) // walk_keys(block_size))
+        return sum(tokens for tokens, _ in chunks), steps * rows

@@ -170,7 +170,7 @@ def _fwd_kernel(*refs, scale: float, block_q: int, block_kv: int,
         # 2D operands, and the flattened form is one big MXU matmul.
         q = q_ref[0].astype(jnp.float32).reshape(gbq, -1)
         k = k_ref[0].astype(jnp.float32)  # (block_kv, d)
-        v = v_ref[0].astype(jnp.float32)
+        v = v_ref[0].astype(jnp.float32)  # (block_kv, dv)
         if seq_kv % block_kv != 0:
             # Zero OOB tile padding: Pallas leaves it garbage (NaN in
             # interpret mode) and the p @ v contraction sums over it —
@@ -269,10 +269,11 @@ def _seg_specs(h_kv, block_q, block_kv, transposed=False, kv_index=None,
 
 def _flash_fwd(q, k, v, q_seg, kv_seg, *, h_kv, scale, block_q, block_kv,
                causal, window, interpret):
-    """q: (b*h_kv, group, sq, d); k/v: (b*h_kv, skv, d);
-    q_seg: (b, sq, 1) / kv_seg: (b, 1, skv) or None -> (o, lse)."""
+    """q: (b*h_kv, group, sq, d); k: (b*h_kv, skv, d); v: (b*h_kv, skv, dv),
+    a width of its own; q_seg: (b, sq, 1) / kv_seg: (b, 1, skv) or None
+    -> (o (b*h_kv, group, sq, dv), lse)."""
     bh, group, sq, d = q.shape
-    skv = k.shape[1]
+    skv, dv = k.shape[1], v.shape[2]
     block_q = min(block_q, sq)
     block_kv = min(block_kv, skv)
     nk = pl.cdiv(skv, block_kv)
@@ -284,17 +285,22 @@ def _flash_fwd(q, k, v, q_seg, kv_seg, *, h_kv, scale, block_q, block_kv,
         group=group, causal=causal, window=window, seq_q=sq, seq_kv=skv,
         has_segs=q_seg is not None, window_blocks=win_blocks,
     )
-    q_spec = pl.BlockSpec((1, group, block_q, d), lambda b, i, j: (b, 0, i, 0))
-    if win_blocks:
-        kv_index = functools.partial(_kv_block_index, block_q=block_q,
-                                     block_kv=block_kv,
-                                     window_blocks=win_blocks, nk=nk)
-        kv_spec = pl.BlockSpec((1, block_kv, d),
-                               lambda b, i, j: (b, kv_index(i, j), 0))
-    else:
-        kv_index = None
-        kv_spec = pl.BlockSpec((1, block_kv, d), lambda b, i, j: (b, j, 0))
-    in_specs = [q_spec, kv_spec, kv_spec]
+
+    def q_spec(width):
+        return pl.BlockSpec((1, group, block_q, width),
+                            lambda b, i, j: (b, 0, i, 0))
+
+    kv_index = functools.partial(
+        _kv_block_index, block_q=block_q, block_kv=block_kv,
+        window_blocks=win_blocks, nk=nk) if win_blocks else None
+
+    def kv_spec(width):
+        return pl.BlockSpec(
+            (1, block_kv, width),
+            (lambda b, i, j: (b, kv_index(i, j), 0)) if win_blocks
+            else (lambda b, i, j: (b, j, 0)))
+
+    in_specs = [q_spec(d), kv_spec(d), kv_spec(dv)]
     inputs = [q, k, v]
     if q_seg is not None:
         qs_spec, ks_spec = _seg_specs(h_kv, block_q, block_kv,
@@ -304,19 +310,19 @@ def _flash_fwd(q, k, v, q_seg, kv_seg, *, h_kv, scale, block_q, block_kv,
     call = pl.pallas_call(
         kernel,
         out_shape=(
-            out_struct((bh, group, sq, d), q.dtype, q),
+            out_struct((bh, group, sq, dv), q.dtype, q),
             out_struct((bh, group, sq, 1), jnp.float32, q),  # logsumexp
         ),
         grid=grid,
         in_specs=in_specs,
         out_specs=(
-            q_spec,
+            q_spec(dv),
             pl.BlockSpec((1, group, block_q, 1), lambda b, i, j: (b, 0, i, 0)),
         ),
         scratch_shapes=[
             pltpu.VMEM((group * block_q, 1), jnp.float32),
             pltpu.VMEM((group * block_q, 1), jnp.float32),
-            pltpu.VMEM((group * block_q, d), jnp.float32),
+            pltpu.VMEM((group * block_q, dv), jnp.float32),
         ],
         interpret=interpret,
         # The names under which a device trace shows the three kernels
@@ -326,7 +332,7 @@ def _flash_fwd(q, k, v, q_seg, kv_seg, *, h_kv, scale, block_q, block_kv,
         cost_estimate=pl.CostEstimate(
             # Banded fraction: a windowed grid visits win_blocks kv blocks
             # per q tile instead of the causal triangle.
-            flops=int(2 * 2 * bh * group * sq * d
+            flops=int(2 * (d + dv) * bh * group * sq
                       * (min(win_blocks * block_kv, skv) if win_blocks
                          else skv * (0.5 if causal else 1.0))),
             bytes_accessed=(2 * q.size + k.size + v.size) * q.dtype.itemsize,
@@ -609,14 +615,15 @@ def _split_heads(q, k, v):
     qt = (q.transpose(0, 2, 1, 3)
           .reshape(b * h_kv, group, sq, d))
     kt = k.transpose(0, 2, 1, 3).reshape(b * h_kv, k.shape[1], d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h_kv, v.shape[1], d)
+    vt = v.transpose(0, 2, 1, 3).reshape(b * h_kv, v.shape[1], v.shape[3])
     return qt, kt, vt, h_kv, group
 
 
-def _core_fwd(q, k, v, segment_ids, causal, block_q, block_kv, window,
-              interpret):
-    b, sq, h, d = q.shape
-    scale = d ** -0.5
+def _forward(q, k, v, segment_ids, scale, causal, block_q, block_kv, window,
+             interpret):
+    """``(out (b, sq, h, dv), what the backward keeps)``; the kept ``lse``
+    is (b*h_kv, group, sq, 1) float32."""
+    b, sq, h, _ = q.shape
     qt, kt, vt, h_kv, group = _split_heads(q, k, v)
     if segment_ids is not None:
         if k.shape[1] != sq:
@@ -632,8 +639,14 @@ def _core_fwd(q, k, v, segment_ids, causal, block_q, block_kv, window,
     o, lse = _flash_fwd(qt, kt, vt, q_seg, kv_seg, h_kv=h_kv, scale=scale,
                         block_q=block_q, block_kv=block_kv, causal=causal,
                         window=window, interpret=interpret)
-    out = (o.reshape(b, h, sq, d).transpose(0, 2, 1, 3))
+    out = (o.reshape(b, h, sq, -1).transpose(0, 2, 1, 3))
     return out, (qt, kt, vt, o, lse, q_seg, kv_seg)
+
+
+def _core_fwd(q, k, v, segment_ids, causal, block_q, block_kv, window,
+              interpret):
+    return _forward(q, k, v, segment_ids, q.shape[3] ** -0.5, causal,
+                    block_q, block_kv, window, interpret)
 
 
 def _core_bwd(causal, block_q, block_kv, window, interpret, res, g):
@@ -692,12 +705,56 @@ def flash_attention(
     masking with whole-block skipping of segment-disjoint tiles; id 0 is
     padding (such tokens attend to nothing and produce zero output).
     """
-    group = q.shape[2] // k.shape[2]
-    # Per-head positions per tile: the largest power of two that keeps the
-    # tile within block_q rows (floor 8, the sublane tile), so it divides
-    # every 128-aligned sequence whatever the group (7 -> 64, not 72 with
-    # a padded last tile).
+    return _flash_attention_core(
+        q, k, v, segment_ids, causal,
+        _positions_a_tile(block_q, q.shape[2] // k.shape[2]), block_kv,
+        window or 0, interpret)
+
+
+def _positions_a_tile(block_q: int, group: int) -> int:
+    """Per-head positions per tile: the largest power of two that keeps the
+    tile within ``block_q`` rows (floor 8, the sublane tile), so it divides
+    every 128-aligned sequence whatever the group (7 -> 64, not 72 with a
+    padded last tile)."""
     if group > 1:
         block_q = max(8, 1 << (max(1, block_q // group).bit_length() - 1))
-    return _flash_attention_core(q, k, v, segment_ids, causal, block_q,
-                                 block_kv, window or 0, interpret)
+    return block_q
+
+
+@functools.partial(
+    jax.jit, static_argnames=("scale", "causal", "block_q", "block_kv",
+                              "window", "interpret"))
+def flash_attention_fwd(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    *,
+    scale: float,
+    causal: bool = True,
+    segment_ids=None,
+    block_q: int = 512,
+    block_kv: int = 512,
+    window: int | None = None,
+    interpret: bool = False,
+) -> tuple:
+    """The forward kernel alone, for a caller that differentiates nothing
+    (a serving program) and merges the result with attention over other
+    keys: ``(o, lse)``, o (b, sq, h, dv) in q's dtype and lse (b, h, sq)
+    float32, the log of each query's summed ``exp(score)`` over the keys it
+    saw, or **+1e30 for a query that saw none** (a padding token; its ``o``
+    row is zero), the value the backward kernels want there.
+
+    As :func:`flash_attention` but for: ``scale`` is the caller's (nothing
+    is taken from a width, so zero columns appended to q and k change
+    nothing); v may be narrower or wider than q and k (q, k: (.., d), v:
+    (.., dv)); it is one jitted function, so a program of several layers
+    traces and lowers the kernel once (a chip host took ~2 s a program to
+    lower seven: PERF.md section 6, PR 51). Both products are float32 products of operands cast in VMEM,
+    as training's: on bf16 operands they measured 1 % faster on the v5e
+    (1.155 against 1.167 ms at 1 x 2,048 tokens, 32 heads, 192 / 128 wide:
+    PERF.md section 6, PR 51), which buys no second form."""
+    out, kept = _forward(
+        q, k, v, segment_ids, scale, causal,
+        _positions_a_tile(block_q, q.shape[2] // k.shape[2]), block_kv,
+        window or 0, interpret)
+    return out, kept[4].reshape(q.shape[0], q.shape[2], q.shape[1])

@@ -569,6 +569,12 @@ class InferenceEngine:
         # model that counts nothing.
         for name in self.executor.counter_names:
             self.stats[name] = self.stats[f"{name}_decode"] = 0
+        # The latent family's prefill calls (models.latent): real query
+        # tokens whose own keys went through the flash forward kernel, and
+        # steps of the loop over cached latents times the program's rows.
+        if self.executor.prefill_kernel_counts is not None:
+            self.stats["mla_kernel_query_tokens_total"] = 0
+            self.stats["mla_walked_key_blocks_total"] = 0
         # (a looped stack's passes also by the prefill calls' part)
         if model_cfg.ut_steps > 1:
             self.stats["loop_passes_prefill"] = 0
@@ -1566,6 +1572,12 @@ class InferenceEngine:
         self.stats["prefill_attention_pairs"] += sum(
             c[2] * len(c[1]) + len(c[1]) * (len(c[1]) + 1) // 2
             for c in chunks)
+        if self.executor.prefill_kernel_counts is not None:
+            tokens, walked = self.executor.prefill_kernel_counts(
+                B, bucket, [(len(c[1]), c[2]) for c in chunks],
+                ec.block_size)
+            self.stats["mla_kernel_query_tokens_total"] += tokens
+            self.stats["mla_walked_key_blocks_total"] += walked
         if self.window:
             self.stats["prefill_window_attention_pairs"] += sum(
                 _pairs_under_window(c[2] + len(c[1]), self.window)

@@ -307,6 +307,11 @@ class EngineExecutor:
         # of two that holds the call's rows.
         self.prefill_whole_tables = getattr(
             self.model, "prefill_whole_tables", False)
+        # What a prefill call's attention does by its shape, for a model
+        # that says (the latent family: tokens through the flash kernel,
+        # steps of the loop over cached latents); None for the others.
+        self.prefill_kernel_counts = getattr(
+            self.model, "prefill_kernel_counts", None)
         self._recurrent = model_cfg.has_recurrent_state
         self._quantized = engine_cfg.quantization == "int8"
         if engine_cfg.quantization not in ("none", "int8"):

@@ -33,6 +33,7 @@ class ScriptedExecutor:
     prefill_call_tokens = 0
     prefill_group_tokens = 0
     prefill_whole_tables = False
+    prefill_kernel_counts = None
     adapter_pool = None
     pool_bytes = 0
     kv_bytes_per_context_token = 0

@@ -263,3 +263,122 @@ def test_gqa_tile_is_a_power_of_two_share_of_block_q(monkeypatch):
                            jnp.zeros((1, 128, kv_heads, 128)),
                            jnp.zeros((1, 128, kv_heads, 128)), block_q=512)
     assert seen == [512, 128, 64, 8]
+
+
+# -- the forward alone: its own scale and value width, the lse beside it -----
+
+def _masked_scores(q, k, seg, scale):
+    """float32 scores with what a causal, segmented call hides at -inf."""
+    s = q.shape[1]
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    seen = (jnp.arange(s)[:, None] >= jnp.arange(s)[None])[None, None] \
+        & (seg[:, None, :, None] == seg[:, None, None, :]) \
+        & (seg[:, None, None, :] != 0)
+    return jnp.where(seen, scores, -jnp.inf)
+
+
+@pytest.mark.parametrize("case", ["width_192", "width_192_padded_to_256",
+                                  "bf16", "gqa"])
+def test_forward_alone_gives_the_output_and_the_lse(rng, case):
+    """Queries and keys 192 wide (or their zero-padded 256: zeros add
+    nothing to a score and the scale is the caller's) with values 128 wide,
+    against ``reference_attention``; the returned lse against ``logsumexp``
+    of the masked scores, +1e30 for a padding query that saw no key."""
+    from dlti_tpu.ops.pallas.flash_attention import flash_attention_fwd
+
+    hkv = 2 if case == "gqa" else 4
+    q, k, _ = _qkv(rng, d=192, hkv=hkv)
+    v = jax.random.normal(jax.random.fold_in(rng, 3), (2, 256, hkv, 128))
+    seg = jnp.ones((2, 256), jnp.int32).at[1, 100:].set(0)
+    scale = 192 ** -0.5
+    want = reference_attention(q, k, v, causal=True, segment_ids=seg)
+    lse_want = jax.nn.logsumexp(_masked_scores(
+        q, jnp.repeat(k, 4 // hkv, axis=2), seg, scale), -1)
+    given, tol = (q, k, v), 2e-5
+    if case == "width_192_padded_to_256":
+        pad = ((0, 0), (0, 0), (0, 0), (0, 64))
+        given = (jnp.pad(q, pad), jnp.pad(k, pad), v)
+    if case == "bf16":
+        given, tol = [t.astype(jnp.bfloat16) for t in given], 4e-2
+    out, lse = flash_attention_fwd(
+        *given, scale=scale, segment_ids=seg, block_q=128, block_kv=128,
+        interpret=True)
+    assert out.shape == (2, 256, 4, 128) and out.dtype == given[0].dtype
+    assert lse.shape == (2, 4, 256) and lse.dtype == jnp.float32
+    real = np.asarray(seg != 0)
+    np.testing.assert_allclose(
+        np.asarray(out.astype(jnp.float32))[real], np.asarray(want)[real],
+        atol=tol, rtol=1e-3)
+    np.testing.assert_allclose(
+        np.asarray(lse)[:, :, :100], np.asarray(lse_want)[:, :, :100],
+        atol=tol)
+    # a padding query: no key seen, a zero row and the backward's +BIG
+    assert float(jnp.abs(out[1, 100:].astype(jnp.float32)).max()) == 0.0
+    assert float(lse[1, :, 100:].min()) >= 1e29
+
+
+# The three kernels as Mosaic is handed them at training's shape
+# (train.mistral_7b.lora_sft: 4 x 2,048 tokens, 32/8 heads of 128, bf16,
+# segment ids, window 4,096), hashed at the commit before the forward learnt
+# a value width of its own and a caller's scale (PR 51): with v as wide as
+# q, the text is that commit's.
+TRAINING_KERNEL_TEXTS = {
+    "dlti_flash_attention_fwd":
+        "80cbd0cbc2445330",
+    "dlti_flash_attention_bwd_dq":
+        "333749f11ae79606",
+    "dlti_flash_attention_bwd_dkv":
+        "47ca84a5518d2346",
+}
+
+
+def _mosaic_texts(hlo: str) -> dict:
+    """``{kernel name: its Mosaic module without locations + its cost
+    estimate}`` of a program lowered for the TPU (a location names this
+    file's lines and every caller's, which any edit moves)."""
+    import base64
+    import json
+    import re
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    found = {}
+    for config in re.findall(r'backend_config = "((?:[^"\\]|\\.)*)"', hlo):
+        config = json.loads(re.sub(
+            r"\\([0-9A-F]{2})", lambda m: chr(int(m.group(1), 16)),
+            config)).get("custom_call_config", {})
+        if "body" not in config:
+            continue
+        ctx = jax_mlir.make_ir_context()
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(config["body"]))
+            text = module.operation.get_asm(enable_debug_info=False)
+        name = re.search(r"module @(\w+)", text).group(1)
+        found[name] = text + json.dumps(config.get("cost_estimate"),
+                                        sort_keys=True)
+    return found
+
+
+def test_training_kernels_lower_to_the_text_they_had():
+    import hashlib
+
+    q = jax.ShapeDtypeStruct((4, 2048, 32, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((4, 2048, 8, 128), jnp.bfloat16)
+    seg = jax.ShapeDtypeStruct((4, 2048), jnp.int32)
+
+    def grads(q, k, v, seg):
+        return jax.grad(lambda *a: flash_attention(
+            *a, segment_ids=seg, window=4096).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    # (the suite's process-wide "highest" is not what a trainer runs under)
+    with jax.default_matmul_precision("default"):
+        hlo = jax.jit(grads).trace(q, kv, kv, seg).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert {name: hashlib.sha256(text.encode()).hexdigest()[:16]
+            for name, text in _mosaic_texts(hlo).items()} \
+        == TRAINING_KERNEL_TEXTS

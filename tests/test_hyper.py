@@ -362,16 +362,33 @@ def test_engine_prefill_then_decode_agrees_with_full_forward(tiny, name):
         <= st["mhc_sinkhorn_residual_e6"]
 
 
-def test_a_long_prompt_goes_as_calls_over_cached_latents(tiny, monkeypatch):
+@pytest.mark.parametrize("form", ["loop", "flash_kernel"])
+def test_a_long_prompt_goes_as_calls_over_cached_latents(tiny, monkeypatch,
+                                                         form):
     """Three calls of the model's limit, the later ones in expanded form
-    over the latents the earlier ones wrote, past the original positions."""
+    over the latents the earlier ones wrote, past the original positions:
+    through the loop over all keys, and with each call's own tokens through
+    the flash forward kernel (interpreted; whole tiles of 8 tokens, loop
+    steps of 16 keys) merged with the loop over what earlier calls wrote."""
     monkeypatch.setattr(latent.LatentForCausalLM, "prefill_call_tokens", 64)
-    eng = _engine(tiny, max_model_len=192)
+    over = {}
+    if form == "flash_kernel":
+        monkeypatch.setattr(latent, "ABSORB_MAX_QUERIES", 16)
+        monkeypatch.setattr(latent, "KERNEL_TOKENS_MULTIPLE", 8)
+        monkeypatch.setattr(latent, "KERNEL_BLOCK", 16)
+        monkeypatch.setattr(latent, "KEY_BLOCK", 16)
+        over = dict(model=dict(paged_attention_impl="kernel"))
+    eng = _engine(tiny, max_model_len=192, **over)
     prompts = _prompts([150], seed=9)
     results = eng.generate(prompts,
                            SamplingParams(max_tokens=6, temperature=0.0))
     assert eng.stats["prefill_batches"] == 3
     _hold_to_reference(tiny, prompts, results)
+    # calls of 64, 64 and 22 tokens that start at 0, 64 and 128: no step,
+    # four and eight steps of the loop over cached latents
+    assert (eng.stats["mla_kernel_query_tokens_total"],
+            eng.stats["mla_walked_key_blocks_total"]) \
+        == ((150, 12) if form == "flash_kernel" else (0, 0))
 
 
 def test_the_counters_reach_metrics_with_their_decode_parts(tiny):

@@ -217,6 +217,77 @@ def test_the_loop_over_key_blocks_ends_at_the_calls_highest_position(
     assert np.isfinite(np.asarray(got)).all()
 
 
+@pytest.fixture
+def kernel_at_tiny_sizes(monkeypatch):
+    """The prefill kernel path at sizes a test can run: calls of more than 4
+    tokens a row in whole tiles of 8, kernel tiles of 8 queries and keys,
+    loop steps of 8 keys (two blocks of 4)."""
+    monkeypatch.setattr(latent, "ABSORB_MAX_QUERIES", 4)
+    monkeypatch.setattr(latent, "KERNEL_TOKENS_MULTIPLE", 8)
+    monkeypatch.setattr(latent, "KERNEL_BLOCK", 8)
+    monkeypatch.setattr(latent, "KEY_BLOCK", 8)
+
+
+@pytest.mark.parametrize("case", [
+    "first_call_nothing_cached", "second_call_over_cached_latents",
+    "unequal_rows_and_a_padding_row", "rows_from_zero_walk_nothing"])
+def test_prefill_through_the_flash_kernel_equals_the_loop_and_the_full_forward(
+        layer, kernel_at_tiny_sizes, case):
+    """A call's own tokens through the flash forward kernel (interpreted),
+    what earlier calls wrote through the loop, merged by their
+    log-sum-exps: against the loop over all keys (the CPU form) and against
+    the no-cache form over the whole sequence."""
+    cfg, attn, params, x, pos, cos, sin = layer
+    want, _ = attn.apply({"params": params}, x, cos, sin, pos)
+    kernel = LatentAttention(dataclasses.replace(
+        cfg, paged_attention_impl="kernel"))
+    cache = _paged(cfg, rows=3, blocks_a_row=7)
+    cache["block_tables"] = cache["block_tables"].at[2].set(0)
+    x3 = jnp.concatenate([x, x[:1]])      # row 2: padding alone
+    pad = jnp.full((1, 24), -1)
+    if case == "first_call_nothing_cached":
+        at, n, pos3 = (0, 0), (24, 24), jnp.concatenate([pos, pad])
+    elif case == "rows_from_zero_walk_nothing":
+        # a pool of NaNs: a step of the loop over any of it (0 x NaN under
+        # the mask) would show, and the loop form does show it past row 1's
+        # 13 tokens
+        cache["latent"] = jnp.full_like(cache["latent"], jnp.nan)
+        at, n = (0, 0), (24, 13)
+        pos3 = jnp.concatenate([pos.at[1, 13:].set(-1), pad])
+        assert LatentForCausalLM(kernel.cfg).prefill_kernel_counts(
+            4, 24, [(24, 0), (13, 0)], 4) == (37, 0)
+    else:
+        # rows 0 and 1 have 8 tokens cached (the loop form wrote them)
+        _, new = attn.apply({"params": params}, x3[:, :8], cos, sin,
+                            jnp.concatenate([pos[:, :8], pad[:, :8]]), cache)
+        cache = {**cache, **new}
+        if case == "second_call_over_cached_latents":
+            at, n = (8, 8), (16, 16)
+        else:   # row 0 goes on from 8; row 1 is a fresh prompt of 11 tokens
+            at, n = (8, 0), (16, 11)
+        x3 = jnp.concatenate([x[:1, 8:], x[1:, at[1]:at[1] + 16], x[:1, :16]])
+        pos3 = jnp.stack([
+            jnp.where(jnp.arange(16) < n[r], at[r] + jnp.arange(16), -1)
+            for r in range(2)] + [pad[0, :16]])
+    got, pool = kernel.apply({"params": params}, x3, cos, sin, pos3, cache)
+    loop, loop_pool = attn.apply({"params": params}, x3, cos, sin, pos3, cache)
+    assert np.isfinite(np.asarray(got)).all()      # the padding tokens too
+    for r in range(2):
+        np.testing.assert_allclose(
+            np.asarray(got[r, :n[r]]),
+            np.asarray(want[r, at[r]:at[r] + n[r]]), atol=2e-5)
+    if case == "rows_from_zero_walk_nothing":
+        assert not np.isfinite(np.asarray(loop[1, :13])).all()
+        return
+    real = np.asarray(pos3 >= 0)
+    np.testing.assert_allclose(np.asarray(got)[real], np.asarray(loop)[real],
+                               atol=2e-5)
+    # the pool is the loop form's; the padding row wrote nothing
+    np.testing.assert_array_equal(np.asarray(pool["latent"]),
+                                  np.asarray(loop_pool["latent"]))
+    assert float(jnp.abs(pool["latent"][0]).max()) == 0.0
+
+
 # contexts of 1, tile - 1, tile, tile + 1 and several tiles; a tile is 256 keys
 # (32 blocks of 8) at these shapes
 KERNEL_CASES = {
@@ -428,6 +499,38 @@ def test_a_long_prompt_goes_as_calls_of_the_models_limit(tiny, monkeypatch):
         np.testing.assert_allclose(a.output_logprobs, b.output_logprobs,
                                    atol=2e-5)
     _hold_to_reference(tiny, prompts, in_calls)
+
+
+def test_the_prefill_kernel_counters_reach_metrics(tiny):
+    """A fresh 300-token prompt is one call of 512 padded tokens through the
+    flash kernel by the rule as it stands (no constant patched): 300 query
+    tokens, no step of the loop over cached latents. Asked again with
+    another end, its 8-token tail takes the absorbed form and adds nothing
+    to either count."""
+    import types
+
+    from dlti_tpu.serving.server import build_registry
+
+    eng = _engine(tiny, max_model_len=640, num_blocks=192,
+                  enable_prefix_caching=True,
+                  model=dict(paged_attention_impl="kernel"))
+    sp = SamplingParams(max_tokens=3, temperature=0.0)
+    prompt = _prompts([300], seed=21)[0]
+    results = eng.generate([prompt], sp)
+    _hold_to_reference(tiny, [prompt], results)
+
+    def counted():
+        text = build_registry(
+            types.SimpleNamespace(engine=eng)).render_prometheus()
+        return [int(float(line.split()[-1])) for line in text.splitlines()
+                if line.startswith(("dlti_mla_kernel_query_tokens_total ",
+                                    "dlti_mla_walked_key_blocks_total "))]
+
+    assert counted() == [300, 0]
+    eng.generate([prompt[:296] + _prompts([8], seed=22)[0]], sp)
+    assert eng.stats["prefix_cached_tokens"] == 296
+    assert eng.stats["prefill_batches"] == 2
+    assert counted() == [300, 0]
 
 
 def test_a_prefill_call_takes_the_whole_block_table(tiny):

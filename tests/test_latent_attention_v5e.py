@@ -67,3 +67,39 @@ def test_latent_decode_kernel_compiles_for_the_v5e(one_chip, name):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "dlti_latent_attention_decode" in text
+
+
+# (rows, tokens) of the prefill programs the latent cells warm that take the
+# flash forward kernel for their own tokens: queries and keys 192 wide (the
+# array's whole last dimension), values 128, 32 heads, bf16 operands
+PREFILL_SHAPES = {"one_long_row": (1, 2048), "four_rows": (4, 512),
+                  "smallest": (1, 256)}
+
+
+@pytest.mark.parametrize("name", sorted(PREFILL_SHAPES))
+def test_flash_forward_compiles_for_the_v5e_at_the_latent_widths(one_chip,
+                                                                 name):
+    import jax
+    import jax.numpy as jnp
+
+    from dlti_tpu.models import latent
+    from dlti_tpu.ops.pallas.flash_attention import flash_attention_fwd
+
+    rows, tokens = PREFILL_SHAPES[name]
+
+    def shape(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def own_tokens(q, k, v, real):
+        return flash_attention_fwd(
+            q, k, v, scale=192 ** -0.5, segment_ids=real,
+            block_q=latent.KERNEL_BLOCK, block_kv=latent.KERNEL_BLOCK)
+
+    compiled = jax.jit(own_tokens).lower(
+        shape((rows, tokens, 32, 192), jnp.bfloat16),
+        shape((rows, tokens, 32, 192), jnp.bfloat16),
+        shape((rows, tokens, 32, 128), jnp.bfloat16),
+        shape((rows, tokens), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "dlti_flash_attention_fwd" in text

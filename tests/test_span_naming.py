@@ -227,6 +227,10 @@ DEVICE_SCOPE_CATALOG = frozenset({
     "dlti_grouped_experts",
     "dlti_attn_window", "dlti_attn_full", "dlti_attn_over_cache",
     "dlti_mla_absorb", "dlti_mla_expand",
+    # A latent-family prefill call's attention where its own tokens go
+    # through the flash forward kernel: the expand, the kernel call, the
+    # loop over what earlier calls wrote and the merge (PR 51).
+    "dlti_mla_prefill_attn",
     "dlti_moe_routed", "dlti_moe_shared", "dlti_mamba2",
     "dlti_mhc_map", "dlti_mhc_mix",
     # One pass of a looped stack (``ut_steps`` > 1), the body the model
