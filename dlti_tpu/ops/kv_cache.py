@@ -156,22 +156,21 @@ def window_blocks(window: int, block_size: int, tokens: int) -> int:
 
 
 def window_group_blocks(window: int, block_size: int, num_slots: int,
-                        call_tokens: int, decode_steps: int = 1) -> int:
+                        call_tokens: int) -> int:
     """Blocks of a window group's pool, from shapes alone: what every slot
-    holds between two calls and through a decode round of ``decode_steps``
-    steps, the tokens of the widest prefill call (``call_tokens``, over at
-    most 8 rows, an edge block each), and the trash block. The allocator
+    holds between two calls and through a decode round (one token), the
+    tokens of the widest prefill call (``call_tokens``, over at most 8
+    rows, an edge block each), and the trash block. The allocator
     (``serving.engine``) releases a block as soon as it lies wholly before
     its sequence's window, so this pool never runs out while the engine
     holds to ``call_tokens``: the full group's pool (``--num-blocks``) is
     what admission waits for."""
-    return (num_slots * window_blocks(window, block_size, decode_steps)
+    return (num_slots * window_blocks(window, block_size, 1)
             + -(-call_tokens // block_size) + 8 + 1)
 
 
 def init_cache(model_cfg, num_blocks: int, block_size: int, num_slots: int,
-               dtype=jnp.bfloat16, call_tokens: int = 0,
-               decode_steps: int = 1) -> List[dict]:
+               dtype=jnp.bfloat16, call_tokens: int = 0) -> List[dict]:
     """The serving cache of ``model_cfg``, one entry a layer by its kind
     (a looped stack: ``ut_steps`` runs of ``num_blocks`` blocks in each
     layer's pool, an entry a pass). A model without a ``layer_pattern`` is
@@ -181,7 +180,7 @@ def init_cache(model_cfg, num_blocks: int, block_size: int, num_slots: int,
     the group that sees every key, and every model's only group, has pools
     of ``num_blocks``; a window group's are sized by
     :func:`window_group_blocks` (``call_tokens``: the most tokens of a
-    prefill call; ``decode_steps``: of a decode round)."""
+    prefill call)."""
     from dlti_tpu.utils.dtypes import resolve_dtype
 
     if model_cfg.latent_dim:
@@ -192,7 +191,7 @@ def init_cache(model_cfg, num_blocks: int, block_size: int, num_slots: int,
     groups = model_cfg.kv_group_windows
     if len(groups) > 1:
         sizes = [num_blocks if not w else window_group_blocks(
-            w, block_size, num_slots, call_tokens, decode_steps)
+            w, block_size, num_slots, call_tokens)
             for w in groups]
         return [init_paged_cache(
             1, sizes[model_cfg.kv_group_of_layer(i)], block_size,

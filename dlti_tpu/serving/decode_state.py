@@ -10,9 +10,8 @@ upload and each program call gives up the interpreter lock, and the stepper
 then queues for it behind every streaming handler. So a round is packed
 here into one ``(max_seqs, width)`` int32 array (:meth:`RoundPacking.pack`,
 numpy, built fresh each round from the scheduler's mirrors as of the
-round's launch), goes up as one transfer, and ``decode`` / ``decode_multi``
-slice and bitcast it back as their first lines (:meth:`RoundPacking.unpack`,
-traced). uint32 keys and float32 values travel by their bits: nothing is
+round's launch), goes up as one transfer, and ``decode`` slices and
+bitcasts it back as its first lines (:meth:`RoundPacking.unpack`, traced). uint32 keys and float32 values travel by their bits: nothing is
 rounded, and a seeded request draws what it always drew.
 
 Nothing per-slot stays resident on the device between rounds, so there is

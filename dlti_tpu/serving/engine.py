@@ -98,13 +98,6 @@ class EngineConfig:
     prefix_host_blocks: int = 0
     prefix_disk_dir: str = ""
     prefix_disk_blocks: int = 0
-    # Multi-step decode: run this many decode iterations inside ONE
-    # compiled program (lax.scan: forward -> sample -> feed back), syncing
-    # with the host only at the boundary. Amortizes per-step dispatch and
-    # host round-trips (vLLM's multi-step scheduling); the trade-off is up
-    # to steps_per_sync-1 discarded tokens after an EOS and coarser
-    # admission cadence.
-    steps_per_sync: int = 1
     # Weight-only quantization: "int8" stores matmul weights as int8 +
     # per-channel scales (~half the weight HBM -> bigger KV pool),
     # dequantized inside the compiled programs. "none" keeps param_dtype.
@@ -116,11 +109,9 @@ class EngineConfig:
     # differently than a 1-position one, the same ~1e-2 bf16 logit delta any
     # batch-shape change causes; ties only flip on near-ties, which trained
     # models rarely produce at the argmax). Proposal, verification, and
-    # acceptance all run ON DEVICE, and ``steps_per_sync`` such rounds chain
-    # inside one compiled program (lax.scan over a token-history buffer), so
-    # speculation COMPOSES with multi-step decode: up to
-    # steps_per_sync*(num_draft_tokens+1) tokens per host sync on
-    # repetitive text. Gating is PER SLOT: greedy slots accept draft
+    # acceptance all run ON DEVICE, one round a program call: up to
+    # num_draft_tokens+1 tokens per host sync on repetitive text.
+    # Gating is PER SLOT: greedy slots accept draft
     # prefixes while sampling slots in the same batch take their
     # single-step sampled token (same fold_in rng stream), so one sampling
     # request no longer disables speculation batch-wide. Caveat of that
@@ -141,7 +132,7 @@ class EngineConfig:
     # greedy slot-rounds drops below spec_min_acceptance (extra tokens per
     # round — rounds where prompt lookup finds no match count as 0), pause
     # proposing for spec_cooldown engine rounds (which run the plain
-    # multi-step path), then re-probe. 0.0 disables the gate (always
+    # path), then re-probe. 0.0 disables the gate (always
     # speculate). On by default: on text where prompt lookup never hits,
     # the (k+1)-position forwards are pure overhead, and the gate is what
     # makes --speculative ngram safe to leave enabled.
@@ -221,18 +212,11 @@ class EngineConfig:
         return -(-self.max_model_len // self.block_size)
 
     @property
-    def spec_rounds(self) -> int:
-        """Propose→verify→accept rounds in one speculative program call:
-        ``steps_per_sync`` of them, so speculation and multi-step decode
-        are one composed program, not alternatives."""
-        return max(1, self.steps_per_sync)
-
-    @property
     def spec_hist_width(self) -> int:
         """Columns of a token-history row (speculative decode): positions
-        0..max_model_len-1, the draft positions past them, one slack cell
-        for the in-flight input token, one scratch cell absorbing masked
-        scatter writes."""
+        0..max_model_len-1, the draft positions past them, and two cells
+        of slack, so that no slice the program takes near a row's end is
+        moved to fit."""
         return self.max_model_len + self.num_draft_tokens + 2
 
 
@@ -284,7 +268,7 @@ class Request:
     # SET from any thread (a GIL-atomic bool write, the same contract as
     # AsyncEngine.submit), CONSUMED by the stepper thread at the next
     # token-emission walk — the slot is released there, so a cancelled
-    # request costs at most one decode window.
+    # request costs at most one decode round.
     cancel_requested: bool = False
     # Critical-path attribution inputs (telemetry.ledger): when the
     # request came through the admission gateway, its enqueue time (the
@@ -455,9 +439,9 @@ class InferenceEngine:
         # Aggregate stats for the /stats endpoint and load reports.
         self.stats = {"requests": 0, "generated_tokens": 0, "prefill_tokens": 0,
                       "preemptions": 0, "decode_steps": 0,
-                      # slot x step units CONSUMED (a slot that hits
-                      # EOS/limit mid-window stops counting, even though
-                      # the device still runs its dead steps — that waste
+                      # slot x step units CONSUMED (a row thrown away
+                      # because its request ended in the round before does
+                      # not count, though the device ran it — that waste
                       # deliberately shows up as occupancy < 100%);
                       # decode_slot_steps / (max_seqs * decode_steps) is
                       # the mean slot occupancy — the first thing to look
@@ -593,7 +577,7 @@ class InferenceEngine:
             self.window_manager = BlockManager(
                 window_group_blocks(
                     self.window, ec.block_size, ec.max_seqs,
-                    self.executor.prefill_call_tokens, ec.steps_per_sync),
+                    self.executor.prefill_call_tokens),
                 ec.block_size)
         # The cache's books (kv_metrics): blocks released by group and why,
         # and the seconds the window group's release took.
@@ -633,7 +617,7 @@ class InferenceEngine:
         # The window group's table of a decode round, as wide as a slot's
         # live blocks need, and the token its column 0 starts at.
         self._window_tables = np.zeros(
-            (S, window_blocks(self.window, ec.block_size, ec.steps_per_sync)
+            (S, window_blocks(self.window, ec.block_size, 1)
              if self.window else 0), np.int32)
         self._window_base = np.zeros((S,), np.int32)
         self._temperature = np.ones((S,), np.float32)
@@ -712,51 +696,8 @@ class InferenceEngine:
                 "prefix_cache_hbm", "kv_block_pool",
                 lambda: self.prefix_cache.num_cached_blocks() * per_block)
 
-    def _window_steps(self, active: list) -> int:
-        """Budget-clamped multi-step window (the occupancy lever).
-
-        A slot that exhausts its token budget at step j of a K-step window
-        idles for K-j device steps, and uniform workloads retire whole
-        cohorts inside one window, which shows as decode occupancy well
-        under 100%. So never run a
-        window longer than the smallest PREDICTABLE retirement among
-        active slots (max_tokens budget or model-length room; natural EOS
-        is unpredictable and still wastes its tail). Window lengths come
-        from the halving ladder K, K//2, ..., 1 so the compile surface
-        stays ~log2(K)+1 programs instead of one per distinct remainder.
-        Side effect: near max_model_len the old batch-wide fallback to
-        k=1 becomes a right-sized window instead.
-        """
-        ec = self.cfg
-        # Length retirement fires at prompt+output >= max_model_len
-        # (_append_token), which is one step EARLIER than KV room
-        # (output leads seq_len by one at dispatch): remaining decode
-        # steps until a length stop = max_model_len - (prompt + output).
-        min_rem = min(
-            min(s.request.params.max_tokens - len(s.request.output_token_ids),
-                ec.max_model_len - len(s.request.prompt_token_ids)
-                - len(s.request.output_token_ids))
-            for s in active)
-        # Round UP to the ladder: the smallest ladder length >= min_rem.
-        # Rounding down would fragment a 63-step tail into 32+16+8+4+2+1 —
-        # five extra host syncs to save a handful of dead device steps
-        # (what either costs on the chip is not measured). Round-up keeps one
-        # window with < k/2 dead steps, and still lands exact fits
-        # (min_rem a ladder value) at 100% occupancy.
-        k = ec.steps_per_sync
-        while k > 1 and k // 2 >= min_rem:
-            k //= 2
-        # ...but NEVER past hard KV room: dead steps past a budget stop are
-        # merely discarded samples, while steps past max_model_len would
-        # grow a slot's block table beyond max_blocks_per_seq (an
-        # out-of-bounds block-table write). Round DOWN under the room cap.
-        min_room = min(ec.max_model_len - s.seq_len for s in active)
-        while k > 1 and k > min_room:
-            k //= 2
-        return k
-
     def warmup_decode_ladder(self) -> None:
-        """Pre-compile the decode programs ahead of traffic."""
+        """Pre-compile the decode program ahead of traffic."""
         self.executor.warmup_decode_ladder()
 
     def _bucket_for(self, n: int) -> int:
@@ -883,8 +824,8 @@ class InferenceEngine:
         Returns requests that finished during this step.
 
         The loop runs one round ahead of the host. A plain decode round
-        (one step, no speculation) is left IN FLIGHT when this returns;
-        the next call then
+        (no speculation: one step, always) is left IN FLIGHT when this
+        returns; the next call then
 
         1. plans the round after it from what the host knows without its
            tokens (who is live, each position, block growth, who ends by
@@ -908,11 +849,11 @@ class InferenceEngine:
 
         The loop does not run ahead when it cannot plan without the last
         tokens: nothing is in flight (the first round, or after a drain), a
-        speculative round or a multi-step window on either side, a plan
-        that would have to preempt. It then fetches first and plans from
-        the host's tokens, which is the only other order there is. A
-        speculative or multi-step round is fetched in the step that
-        launched it, after admission, whose prefill work hides under it.
+        speculative round on either side, a plan that would have to
+        preempt. It then fetches first and plans from the host's tokens,
+        which is the only other order there is. A speculative round is
+        fetched in the step that launched it, after admission, whose
+        prefill work hides under it.
         """
         phase = self._phase
         # Whatever is in flight is this call's to fetch: a fault below
@@ -934,12 +875,11 @@ class InferenceEngine:
                     self._prefill_work()
             if launched is None:
                 return finished
-            kind, _rows, k_steps, *_ = launched
-            if kind == "plain" and k_steps == 1 and self.has_work:
+            if launched[0] == "plain" and self.has_work:
                 self._inflight = launched
                 return finished
-            # A window or a speculative round; or every request the round
-            # carried has ended and none waits: nothing would come for it.
+            # A speculative round; or every request the round carried has
+            # ended and none waits: nothing would come for it.
             return finished + self._decode_complete(launched)
         except Exception as e:
             if is_oom_error(e):
@@ -1091,7 +1031,7 @@ class InferenceEngine:
         Admissions collected in one pass are prefilled in *batched*
         program calls (grouped by suffix bucket): on a deep queue the
         admission stall is a handful of model calls instead of one per
-        request — the dominant TTFT term once decode windows are long.
+        request — the dominant TTFT term there.
         """
         # Headroom-aware admission (telemetry.memledger): under HBM
         # pressure (a fragmented allocator, a co-tenant balloon, a tier
@@ -1654,9 +1594,9 @@ class InferenceEngine:
         position, its block growth and its gen count are those of this round's launch, and its input id is
         ``RIDES``. A slot whose request ends with the token in flight is
         left out and reads as a free slot does (:meth:`_clear_row`). None
-        also when such a plan cannot be made: a speculative round, a
-        multi-step window, or a pool that is out of blocks (preemption
-        needs every request's tokens on the host).
+        also when such a plan cannot be made: a speculative round, or a
+        pool that is out of blocks (preemption needs every request's
+        tokens on the host).
 
         Three phases inside the caller's ``engine/decode_prep``: the plan
         (:meth:`_decode_plan`), the assembly of the round's host arrays,
@@ -1667,8 +1607,8 @@ class InferenceEngine:
             plan = self._decode_plan(ahead_of)
         if plan is None:
             return None
-        active, riding, k_steps, use_spec, spec_parts, spec_k = plan
-        if use_spec:
+        active, riding, spec_parts, spec_k = plan
+        if spec_parts:
             return self._spec_prepare(active, spec_parts, spec_k)
 
         with phase("engine/decode_assemble", "engine"):
@@ -1678,9 +1618,9 @@ class InferenceEngine:
                 rides = s.slot_id in riding
                 ids[s.slot_id, 0] = RIDES if rides else s.last_token
                 pos[s.slot_id, 0] = s.seq_len + rides  # the new token's
-            self._book_decode_context(active, k_steps, riding)
+            self._book_decode_context(active, riding)
             self.stats["decode_steps_sorted_sampling"] += \
-                k_steps * self._sampling_sorts()
+                self._sampling_sorts()
             mirrors = self._state_mirrors()
             if riding:
                 # Every row goes up as of this round's launch: a riding
@@ -1695,39 +1635,35 @@ class InferenceEngine:
         # one packed array, which the decode program unpacks itself.
         with phase("engine/decode_stage", "engine"):
             staged = self.executor.stage_decode(ids, pos, mirrors, masked)
-        return ("plain", [(s, s.request) for s in active], k_steps, staged)
+        return ("plain", [(s, s.request) for s in active], staged)
 
     def _decode_plan(self, ahead_of=None):
-        """Who decodes in the round and over how many steps: the
-        speculation gate, block growth (and preemption), who rides behind
-        the round in flight. ``(active, riding, k_steps, use_spec,
-        spec_parts, spec_k)``, or None (:meth:`_decode_prepare`)."""
+        """Who decodes in the round: the speculation gate, block growth
+        (and preemption), who rides behind the round in flight. ``(active,
+        riding, spec_parts, spec_k)`` (``spec_parts`` empty: a plain
+        round), or None (:meth:`_decode_prepare`)."""
         ec = self.cfg
-        # Multi-step windows are budget-clamped per round (_window_steps):
-        # max_model_len safety lives in its min(...) term, so there is no
-        # batch-wide all-or-nothing room gate anymore. Prefilling slots are
-        # admitted but not yet decodable: excluded everywhere below, with
-        # their block-table rows masked to the trash block.
-        k_steps = 1
+        # Prefilling slots are admitted but not yet decodable: excluded
+        # everywhere below, with their block-table rows masked to the
+        # trash block.
         active0 = [s for s in self.slots if not s.free and not s.prefilling]
         riding: set = set()
 
-        # Grow block tables to cover the decode window; preempt the
+        # Grow block tables to cover what the round writes: one token a
+        # slot, or a speculative round's ``spec_window``; preempt the
         # youngest if the pool is exhausted. (Prefilling slots already own
         # blocks for prompt+1 from admission and are not decoding yet.)
-        def grow_tables(win_steps: int, spec: bool) -> bool:
+        def grow_tables(spec_window: int) -> bool:
             for slot in sorted(active0,
                                key=lambda s: s.request.arrival_time):
                 if slot.free:  # preempted by an earlier iteration
                     continue
-                window = win_steps
-                if spec and slot.request.params.temperature != 0.0:
-                    # Sampling slots advance exactly one real token per
-                    # spec round; their draft-position writes past that
-                    # land on the trash block (unallocated table entries
-                    # are 0), so don't allocate — and possibly preempt
-                    # for — the full window.
-                    window = self.cfg.spec_rounds
+                # Sampling slots advance exactly one real token per spec
+                # round; their draft-position writes past that land on the
+                # trash block (unallocated table entries are 0), so don't
+                # allocate — and possibly preempt for — the full window.
+                window = spec_window \
+                    if slot.request.params.temperature == 0.0 else 1
                 at = slot.seq_len + (slot.slot_id in riding)
                 need = self.block_manager.blocks_needed(at + window)
                 while need > len(slot.blocks):
@@ -1747,22 +1683,20 @@ class InferenceEngine:
             return True
 
         if ahead_of is not None:
-            if ec.steps_per_sync > 1 or (
-                    self._spec_hist is not None and any(
-                        s.request.params.temperature == 0.0
-                        and not self._spec_slot_pause[s.slot_id]
-                        for s in active0)):
+            if self._spec_hist is not None and any(
+                    s.request.params.temperature == 0.0
+                    and not self._spec_slot_pause[s.slot_id]
+                    for s in active0):
                 return None
             riding = {s.slot_id for s, req in ahead_of[1] if s.request is req}
             ending = [s for s in active0 if s.slot_id in riding
                       and self._ends_with_its_next_token(s.request)]
             active0 = [s for s in active0 if s not in ending]
-            # A plain one-step round (no window, nobody speculates). Its
-            # blocks first: a plan given up for want of them has changed
-            # nothing (no cooldown ticked, no row cleared) but the blocks
-            # granted, which stay on slots that need them whatever order
-            # the rounds take.
-            if not grow_tables(1, False):
+            # A plain round (nobody speculates). Its blocks first: a plan
+            # given up for want of them has changed nothing (no cooldown
+            # ticked, no row cleared) but the blocks granted, which stay on
+            # slots that need them whatever order the rounds take.
+            if not grow_tables(1):
                 return None
             for s in ending:
                 # No row of this round, and not free before the round in
@@ -1774,59 +1708,49 @@ class InferenceEngine:
         # and returns this round's participants, and the program masks the
         # rest to single-step) and every active slot has room for the
         # worst-case window at the SELECTED draft length. When every
-        # greedy slot is paused the round falls back to plain multi-step —
-        # the (k+1)-wide verify forwards would be pure overhead.
-        # Trade-off: the room check is batch-wide (R is compile-static),
-        # so one slot within R*(k+1) tokens of max_model_len falls the
-        # whole batch back to plain multi-step until it retires — at most
-        # its last R*(k+1) decode rounds. A per-slot R would need one
-        # compiled variant per window size; not worth the compile surface.
+        # greedy slot is paused the round is a plain one: the (k+1)-wide
+        # verify forward would be pure overhead. Trade-off: the room check
+        # is batch-wide, so one slot within k+1 tokens of max_model_len
+        # falls the whole batch back to plain rounds until it retires: at
+        # most its last k+1 decode rounds.
         spec_parts: list = []
         spec_k = 0
         if self._spec_hist is not None and active0:
             spec_parts = self._spec_round_gate(active0)
         if spec_parts:
             spec_k = self._spec_pick_k(spec_parts)
-        spec_window = self.cfg.spec_rounds * (spec_k + 1)
-        use_spec = bool(spec_parts) and all(
-            s.seq_len + spec_window <= ec.max_model_len for s in active0)
-        self._spec_last_k = spec_k if use_spec else 0
-        if use_spec:
-            k_steps = spec_window  # block-growth window
-        elif ec.steps_per_sync > 1 and active0:
-            k_steps = self._window_steps(active0)
+            if any(s.seq_len + spec_k + 1 > ec.max_model_len
+                   for s in active0):
+                spec_parts = []
+        self._spec_last_k = spec_k if spec_parts else 0
 
-        if ahead_of is None and not grow_tables(k_steps, use_spec):
-            if k_steps > 1:
-                # Defer, don't fault: a multi-step window that cannot
-                # reserve its worst-case blocks shrinks to a single-step
-                # round (blocks already granted stay on their slots and
-                # carry over; table rows past the shrunk window are never
-                # read). One block per active slot is guaranteed by the
-                # admission-time max_blocks_per_seq check, so win=1 can
-                # only fail on genuine exhaustion.
-                use_spec = False
-                self._spec_last_k = 0
-                k_steps = 1
-            if not grow_tables(k_steps, use_spec):
+        if ahead_of is None and not grow_tables(
+                spec_k + 1 if spec_parts else 1):
+            # Defer, don't fault: a speculative round that cannot reserve
+            # its worst-case blocks shrinks to a plain one (blocks already
+            # granted stay on their slots and carry over; table rows past
+            # the one step are never read). One block per active slot is
+            # guaranteed by the admission-time max_blocks_per_seq check,
+            # so a plain round can only fail on genuine exhaustion.
+            if not spec_parts or not grow_tables(1):
                 raise RuntimeError(
                     "KV pool exhausted and nothing to preempt; "
                     "increase num_blocks or lower max_seqs"
                 )
+            spec_parts, self._spec_last_k = [], 0
 
         active = [s for s in active0 if not s.free and not s.prefilling]
         if not active:
             return None
-        return active, riding, k_steps, use_spec, spec_parts, spec_k
+        return active, riding, spec_parts, spec_k
 
-    def _decode_launch(self, rows: List[tuple], k_steps: int, staged,
-                       ahead_of=None):
+    def _decode_launch(self, rows: List[tuple], staged, ahead_of=None):
         """The compiled decode call (not waited for). ``rows``: the
         ``(slot, request)`` pairs it decodes for."""
         if ahead_of is not None:
             self.stats["decode_rounds_launched_ahead"] += 1
-        return ("plain", rows, k_steps, self.executor.launch_decode(
-            staged, k_steps, ahead_of[-1] if ahead_of is not None else None))
+        return ("plain", rows, self.executor.launch_decode(
+            staged, ahead_of[-1] if ahead_of is not None else None))
 
     def _decode_complete(self, pending) -> List[Request]:
         """Sync a dispatched decode round's results and walk emissions."""
@@ -1841,21 +1765,18 @@ class InferenceEngine:
             walk = self._spec_emit if kind == "spec" else self._decode_emit
             return walk(*plan, *host)
 
-    def _decode_emit(self, rows: List[tuple], k_steps: int,
-                     tokens: np.ndarray, logprobs: np.ndarray,
-                     ) -> List[Request]:
+    def _decode_emit(self, rows: List[tuple], tokens: np.ndarray,
+                     logprobs: np.ndarray) -> List[Request]:
         """Numeric guards and the per-slot emission walk of a plain round
-        (``tokens``, ``logprobs``: (S, k_steps), or (S,) of one step, on
-        the host). A row counts only if its slot still holds the request
-        it held at the launch: a request that ended in the round before
-        has a row in a round launched ahead, and its slot may hold another
-        request by now, to whom that token does not belong."""
-        tokens = tokens.reshape(len(tokens), -1)
-        logprobs = logprobs.reshape(len(logprobs), -1)
-        self.stats["decode_steps"] += k_steps
+        (``tokens``, ``logprobs``: (S,), a token a row, on the host). A
+        row counts only if its slot still holds the request it held at the
+        launch: a request that ended in the round before has a row in a
+        round launched ahead, and its slot may hold another request by
+        now, to whom that token does not belong."""
+        self.stats["decode_steps"] += 1
         if self.executor.counter_names:
-            # Rows after the slots': each step's counters.
-            self._count(tokens[self.cfg.max_seqs:].T, decode=True)
+            # Rows after the slots': the step's counters.
+            self._count(tokens[None, self.cfg.max_seqs:], decode=True)
             tokens = tokens[:self.cfg.max_seqs]
         active = [s for s, req in rows if s.request is req]
         self.stats["decode_rows_discarded"] += len(rows) - len(active)
@@ -1865,43 +1786,30 @@ class InferenceEngine:
         # (resubmit keeps generated-so-far tokens) and stream garbage.
         if self.cfg.guard_nonfinite:
             bad = [s.slot_id for s in active
-                   if not np.isfinite(logprobs[s.slot_id, :k_steps]).all()]
+                   if not np.isfinite(logprobs[s.slot_id])]
             if bad:
                 self.stats["numeric_faults"] += 1
                 raise NumericFault(
-                    f"nonfinite decode output on slot(s) {bad} "
-                    f"(window of {k_steps} step(s)): the model is "
-                    f"producing NaN/inf logits")
+                    f"nonfinite decode output on slot(s) {bad}: the model "
+                    f"is producing NaN/inf logits")
         if self.cfg.guard_token_storm > 0 and len(active) >= 2:
-            for k in range(k_steps):
-                col = {int(tokens[s.slot_id, k]) for s in active}
-                self._storm_run = self._storm_run + 1 if len(col) == 1 \
-                    else 0
-                if self._storm_run >= self.cfg.guard_token_storm:
-                    self.stats["numeric_faults"] += 1
-                    raise NumericFault(
-                        f"token storm: every active slot sampled the "
-                        f"same token for {self._storm_run} consecutive "
-                        f"steps (token {col.pop()})")
+            col = {int(tokens[s.slot_id]) for s in active}
+            self._storm_run = self._storm_run + 1 if len(col) == 1 else 0
+            if self._storm_run >= self.cfg.guard_token_storm:
+                self.stats["numeric_faults"] += 1
+                raise NumericFault(
+                    f"token storm: every active slot sampled the "
+                    f"same token for {self._storm_run} consecutive "
+                    f"steps (token {col.pop()})")
 
         finished = []
         for s in active:
             req = s.request  # (retirement clears the slot's)
-            for k in range(k_steps):
-                # Per-step occupancy: a slot that hits EOS mid-window
-                # stops counting here, so occupancy stays honest at large
-                # steps_per_sync (the device still runs the dead steps —
-                # that waste shows up as occupancy < 100%, as it should).
-                self.stats["decode_slot_steps"] += 1
-                s.seq_len += 1  # the input token is now in the cache
-                done = self._append_token(s, int(tokens[s.slot_id, k]),
-                                          float(logprobs[s.slot_id, k]))
-                if done:
-                    # Tokens sampled after EOS/limit in this window are
-                    # discarded (their stale KV writes sit past seq_len in
-                    # the freed tail blocks — never registered or read).
-                    finished.append(req)
-                    break
+            self.stats["decode_slot_steps"] += 1
+            s.seq_len += 1  # the input token is now in the cache
+            if self._append_token(s, int(tokens[s.slot_id]),
+                                  float(logprobs[s.slot_id])):
+                finished.append(req)
         return finished
 
     def _spec_round_gate(self, active: List["_Slot"]) -> List["_Slot"]:
@@ -1964,21 +1872,21 @@ class InferenceEngine:
         self._spec_slot_pause[sid] = 0
         self._spec_slot_ewma[sid] = float(self.cfg.num_draft_tokens)
 
-    def _book_decode_context(self, active: List[_Slot], steps: int,
+    def _book_decode_context(self, active: List[_Slot],
                              riding=frozenset()) -> None:
-        """What a round of ``steps`` decode steps attends over, booked when it
-        is dispatched: the active slots' cached tokens (one more for a slot
-        ``riding`` in the round still in flight), and the keys of the
-        kernel tiles that hold them and the new token."""
+        """What a decode round attends over, booked when it is dispatched:
+        the active slots' cached tokens (one more for a slot ``riding`` in
+        the round still in flight), and the keys of the kernel tiles that
+        hold them and the new token."""
         lens = np.fromiter((s.seq_len + (s.slot_id in riding) for s in active),
                            np.int64, len(active))
         tile = self.executor.decode_tile_tokens
-        self.stats["decode_context_tokens"] += int(lens.sum()) * steps
+        self.stats["decode_context_tokens"] += int(lens.sum())
         if self.window:
             self.stats["decode_window_context_tokens"] += \
-                int(np.minimum(lens, self.window).sum()) * steps
+                int(np.minimum(lens, self.window).sum())
         self.stats["decode_kernel_tile_tokens"] += \
-            int((lens // tile + 1).sum()) * tile * steps
+            int((lens // tile + 1).sum()) * tile
 
     def _spec_prepare(self, active: List[_Slot], parts: List[_Slot],
                       k: int):
@@ -1989,7 +1897,6 @@ class InferenceEngine:
         slots in cooldown — is masked to single-step inside the program.
         ``k`` is the ladder draft length picked for this round."""
         ec = self.cfg
-        R = ec.spec_rounds
         phase = self._phase
         with phase("engine/decode_assemble", "engine"):
             t_in = np.zeros((ec.max_seqs,), np.int32)
@@ -1998,17 +1905,17 @@ class InferenceEngine:
             for s in active:
                 t_in[s.slot_id] = s.last_token
                 seq_len[s.slot_id] = s.seq_len
-            self._book_decode_context(active, R)
+            self._book_decode_context(active)
             self.stats["decode_steps_sorted_sampling"] += \
-                R * self._sampling_sorts()
+                self._sampling_sorts()
             for s in parts:
                 spec_mask[s.slot_id] = True
             # Multi-query attention takes the gather path (the Pallas paged
             # kernel is single-token); bound its window to the blocks the
-            # whole spec window can touch, quantized pow2 so jit
+            # round's k + 1 positions can touch, quantized pow2 so jit
             # specializations stay O(log).
             nblk = max(self.block_manager.blocks_needed(
-                s.seq_len + R * (k + 1)) for s in active)
+                s.seq_len + k + 1) for s in active)
             width = 1
             while width < nblk:
                 width *= 2
@@ -2028,23 +1935,20 @@ class InferenceEngine:
     def _spec_emit(self, active: List[_Slot], spec_mask: np.ndarray,
                    toks: np.ndarray, lps: np.ndarray, emit: np.ndarray,
                    prop: np.ndarray, acc: np.ndarray) -> List[Request]:
-        """Walk a spec round's emissions (``toks``, ``lps``: (S, R, k+1);
-        ``emit``, ``prop``, ``acc``: (S, R); all on the host). Per slot
-        per round the device reports how many tokens were emitted (greedy:
-        accepted prefix + bonus; sampling: exactly one); the host consumes
-        them in order, stopping a slot at EOS/limit and discarding the
-        rest of its window (same contract as multi-step decode)."""
-        R = self.cfg.spec_rounds
-        self.stats["decode_steps"] += R
+        """Walk a spec round's emissions (``toks``, ``lps``: (S, k+1);
+        ``emit``, ``prop``, ``acc``: (S,); all on the host). Per slot the
+        device reports how many tokens were emitted (greedy: accepted
+        prefix + bonus; sampling: exactly one); the host consumes them in
+        order, stopping a slot at EOS/limit and discarding the rest."""
+        self.stats["decode_steps"] += 1
 
         # Numeric guard over every EMITTED token (rejected draft
         # positions legitimately carry junk), before anything appends —
         # same no-garbage-survives-failover contract as plain decode.
         if self.cfg.guard_nonfinite:
             bad = [s.slot_id for s in active
-                   if any(not np.isfinite(
-                       lps[s.slot_id, r, :int(emit[s.slot_id, r])]).all()
-                       for r in range(R))]
+                   if not np.isfinite(
+                       lps[s.slot_id, :int(emit[s.slot_id])]).all()]
             if bad:
                 self.stats["numeric_faults"] += 1
                 raise NumericFault(
@@ -2055,33 +1959,28 @@ class InferenceEngine:
         for s in active:
             sid = s.slot_id
             req = s.request  # (retirement clears the slot's)
+            self.stats["decode_slot_steps"] += 1
             # Only unmasked greedy slots actually proposed this round —
             # masked slots (sampling, or greedy in cooldown) ran single-
             # step and must not feed the acceptance windows.
             proposing = bool(spec_mask[sid])
+            if proposing:
+                self._spec_slot_prop[sid] += 1
+                self._spec_slot_acc[sid] += int(emit[sid]) - 1
+                # Smoothed accepted-drafts-per-round estimate for the
+                # draft-length ladder (rounds with no lookup hit pull
+                # it toward 0, as they should).
+                self._spec_slot_ewma[sid] += 0.2 * (
+                    int(acc[sid]) - self._spec_slot_ewma[sid])
+                self.stats["spec_proposed"] += int(prop[sid])
+                self.stats["spec_accepted"] += int(acc[sid])
             done = False
-            for r in range(R):
-                # Per-round occupancy (see _decode_complete): rounds after
-                # a slot finishes mid-window don't count as occupied.
-                self.stats["decode_slot_steps"] += 1
-                if proposing:
-                    self._spec_slot_prop[sid] += 1
-                    self._spec_slot_acc[sid] += int(emit[sid, r]) - 1
-                    # Smoothed accepted-drafts-per-round estimate for the
-                    # draft-length ladder (rounds with no lookup hit pull
-                    # it toward 0, as they should).
-                    self._spec_slot_ewma[sid] += 0.2 * (
-                        int(acc[sid, r]) - self._spec_slot_ewma[sid])
-                    self.stats["spec_proposed"] += int(prop[sid, r])
-                    self.stats["spec_accepted"] += int(acc[sid, r])
-                for j in range(int(emit[sid, r])):
-                    s.seq_len += 1
-                    done = self._append_token(s, int(toks[sid, r, j]),
-                                              float(lps[sid, r, j]))
-                    if done:
-                        finished.append(req)
-                        break
+            for j in range(int(emit[sid])):
+                s.seq_len += 1
+                done = self._append_token(s, int(toks[sid, j]),
+                                          float(lps[sid, j]))
                 if done:
+                    finished.append(req)
                     break
             if proposing and not done:
                 self._spec_note_slot(sid)
@@ -2339,7 +2238,7 @@ class InferenceEngine:
         The server's step-failure recovery: after a faulted
         ``engine.step()`` the queues' consumers are gone, so leaving the
         requests in place would either hot-loop the same failing program
-        (persistent faults) or burn whole decode windows generating
+        (persistent faults) or burn decode rounds generating
         tokens nobody reads (transient faults). Returns the aborted
         requests (their ``finish_reason`` is set to ``reason``). A round
         in flight is waited for and its tokens thrown away first.

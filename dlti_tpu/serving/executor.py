@@ -380,8 +380,7 @@ class EngineExecutor:
             for i in range(model_cfg.num_layers)]
         self.cache = init_cache(
             model_cfg, ec.num_blocks, ec.block_size, ec.max_seqs, dtype,
-            call_tokens=self.prefill_call_tokens,
-            decode_steps=ec.steps_per_sync)
+            call_tokens=self.prefill_call_tokens)
         if mesh is not None:
             self._shard_for_tp(mesh)
         elif self._device is not None:
@@ -466,10 +465,6 @@ class EngineExecutor:
 
         self._prefill_fns: Dict[int, callable] = {}
         self._decode_fn = self._build_decode_fn()
-        # Multi-step decode programs, one per window length on the halving
-        # ladder (K, K//2, ..., 1; see _window_steps) — compiled lazily on
-        # first use. Bounded at ~log2(K)+1 variants.
-        self._multi_decode_fns: Dict[int, callable] = {}
         if ec.speculative not in ("none", "ngram"):
             raise ValueError(f"unknown speculative mode {ec.speculative!r}")
         # Draft-length ladder (spec_adaptive): one spec program per pow2 k
@@ -478,7 +473,7 @@ class EngineExecutor:
         self._spec_fns: Dict[int, callable] = {}
         if ec.speculative == "ngram":
             self._spec_fns[ec.num_draft_tokens] = self._build_spec_decode_fn(
-                ec.num_draft_tokens, ec.spec_rounds)
+                ec.num_draft_tokens)
         self._sample_fn = jax.jit(sample_tokens)
         # ``(rows, bucket, table width)`` of the newest prefill call.
         self.last_prefill_shape: Optional[tuple] = None
@@ -512,12 +507,12 @@ class EngineExecutor:
                            else "state_slots" if self._recurrent else None)
         # A plain decode round's host inputs (tokens, positions, every
         # per-slot mirror) go up as one packed int32 array that the decode
-        # programs unpack themselves: one transfer and one program call a
+        # program unpacks itself: one transfer and one program call a
         # round, nothing per-slot resident between rounds.
         self.round_packing = RoundPacking(
             ec.max_seqs, ec.max_blocks_per_seq, self._row_extra,
             window_blocks=0 if self._layer_groups is None else window_blocks(
-                self.kv_groups[1], ec.block_size, ec.steps_per_sync))
+                self.kv_groups[1], ec.block_size, 1))
         # Where a round goes, committed: this engine's device, or every
         # chip of the tensor mesh (replicated).
         self._round_sharding = self._device
@@ -588,8 +583,7 @@ class EngineExecutor:
 
         Quantized params pass through as-is — each module dequantizes its
         own weights at the consumer (``models.quantization.maybe_dequantize``),
-        so only the executing layer holds a compute-dtype copy even inside
-        the multi-step decode scan.
+        so only the executing layer holds a compute-dtype copy.
 
         With a multi-LoRA pool, ``adapters`` (the stacked A/B tree) rides
         in as a Flax variable collection and ``adapter_ids`` (one pool row
@@ -633,9 +627,9 @@ class EngineExecutor:
 
     def _pool_tree(self) -> tuple:
         """The adapter pool's tree as a program's LAST argument. NOT
-        donated — an in-flight async window may still read the previous
+        donated — a round in flight may still read the previous
         buffers, and a one-row scatter (acquire miss) rebinds ``pool.tree``
-        between windows."""
+        between rounds."""
         return () if self.adapter_pool is None else (self.adapter_pool.tree,)
 
     def _trailing(self, adapter_ids: np.ndarray,
@@ -651,9 +645,9 @@ class EngineExecutor:
 
     @staticmethod
     def _built(table: Dict[int, callable], key: int, build):
-        """``table[key]``, built on first use: the prefill buckets, the
-        multi-step ladder and the spec ladder each hold a bounded set of
-        programs, compiled when traffic first needs one."""
+        """``table[key]``, built on first use: the prefill buckets and the
+        spec ladder each hold a bounded set of programs, compiled when
+        traffic first needs one."""
         fn = table.get(key)
         if fn is None:
             fn = table[key] = build(key)
@@ -759,44 +753,8 @@ class EngineExecutor:
         call._jit_fn = jit_fn    # warmup idempotency: the lowerable fn
         return call
 
-    def _build_multi_decode_fn(self, num_steps: int):
-        """K decode iterations in one program: the sampled token feeds the
-        next forward inside a lax.scan; the host syncs once per K tokens.
-
-        The per-slot rng stream (fold_in(key, gen_count)) advances exactly
-        as in single-step decode, so results are identical for a given
-        request regardless of steps_per_sync.
-        """
-        @partial(jax.jit, donate_argnums=(1,))
-        def decode_multi(params, cache_kv, packed, *pool):
-            (input_ids, positions, block_tables, slot_keys, gen_counts,
-             temperature, top_k, top_p, *lora) = self.round_packing.unpack(packed)
-            lora = (*lora, *pool)
-
-            def body(carry, _):
-                cache, tok, pos, cnt = carry
-                logits, new_kv, counters = self._model_cache_call(
-                    params, cache, block_tables, tok, pos,
-                    **self._named(lora), own_rows=True)
-                rngs = jax.vmap(jax.random.fold_in)(slot_keys, cnt)
-                nxt, lp = sample_tokens(
-                    logits[:, 0, :], rngs, temperature, top_k, top_p)
-                out = nxt if counters is None \
-                    else jnp.concatenate([nxt, counters])
-                return (new_kv, nxt[:, None], pos + 1, cnt + 1), (out, lp)
-
-            (new_kv, _, _, _), (toks, lps) = jax.lax.scan(
-                body, (cache_kv, input_ids, positions, gen_counts),
-                None, length=num_steps)
-            # (K, S) -> (S, K); with counters: (S + counters, K)
-            return new_kv, toks.T, lps.T
-
-        return decode_multi
-
-    def _build_spec_decode_fn(self, k: int, rounds: int):
-        """``rounds`` propose→verify→accept iterations in ONE program.
-
-        Each round, entirely on device (no host round-trip between rounds):
+    def _build_spec_decode_fn(self, k: int):
+        """One propose→verify→accept round, entirely on device:
 
         1. **Propose** (prompt lookup): per slot, match the trailing
            ``ngram_size``-gram of the token history against every earlier
@@ -808,13 +766,12 @@ class EngineExecutor:
         3. **Accept**: greedy slots emit the longest draft prefix matching
            the argmax plus one bonus token (exact greedy decoding);
            sampling slots emit their position-0 ``sample_tokens`` draw
-           (identical fold_in rng stream to plain decode). Accepted tokens
-           are scattered back into the history so the *next* round's
-           proposal sees them — this is what makes speculation compose
-           with multi-step instead of excluding it.
+           (identical fold_in rng stream to plain decode). The host keeps
+           the token history (the scheduler's mirror, which the next
+           round's proposal is given).
 
-        The host syncs once per call: up to rounds*(k+1) tokens. KV writes
-        past a slot's accepted prefix are garbage but live at positions its
+        The host syncs once per call: up to k+1 tokens. KV writes past a
+        slot's accepted prefix are garbage but live at positions its
         next round (or next plain decode) overwrites before any query can
         attend to them (causal masking; same invariant as chunked prefill's
         trash-block masking).
@@ -851,68 +808,40 @@ class EngineExecutor:
                         block_tables, slot_keys, gen_counts, temperature,
                         top_k, top_p, *lora):
             S = t_in.shape[0]
-            rows = jnp.arange(S)
-            is_greedy = temperature == 0.0
-
-            def body(carry, _):
-                cache, hist, t_in, seq_len, cnt = carry
-                hist = hist.at[rows, seq_len].set(t_in)
-                drafts = propose(hist, seq_len)                  # (S, k)
-                # Per-slot gate: a paused slot's draft is forced to the
-                # all-(-1) no-hit form, degrading just that slot to
-                # single-step while its neighbors keep speculating.
-                drafts = jnp.where(spec_mask[:, None], drafts, -1)
-                ids = jnp.concatenate(
-                    [t_in[:, None], jnp.maximum(drafts, 0)], axis=1)
-                pos = seq_len[:, None] + jnp.arange(k + 1)[None, :]
-                logits, new_kv, _ = self._model_cache_call(
-                    params, cache, block_tables, ids, pos,
-                    **self._named(lora))
-                logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-                g = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (S, k+1)
-                g_lp = jnp.take_along_axis(
-                    logp, g[..., None], axis=-1)[..., 0]
-                # Position-0 emission via sample_tokens for EVERY slot:
-                # greedy rows reduce to the same argmax, sampling rows get
-                # exactly the plain-decode draw for fold_in(key, cnt).
-                rngs = jax.vmap(jax.random.fold_in)(slot_keys, cnt)
-                s_tok, s_lp = sample_tokens(
-                    logits[:, 0, :], rngs, temperature, top_k, top_p)
-                eq = (drafts == g[:, :k]) & (drafts >= 0)
-                m = jnp.sum(jnp.cumprod(eq.astype(jnp.int32), axis=1), axis=1)
-                emit = jnp.where(is_greedy, m + 1, 1).astype(jnp.int32)
-                toks = g.at[:, 0].set(s_tok)
-                lps = g_lp.at[:, 0].set(s_lp)
-                # Scatter emitted tokens into the history at context
-                # positions seq_len+1+j; masked lanes hit the scratch cell.
-                cols = seq_len[:, None] + 1 + jnp.arange(k + 1)[None, :]
-                cols = jnp.where(
-                    jnp.arange(k + 1)[None, :] < emit[:, None], cols, W - 1)
-                hist = hist.at[rows[:, None], cols].set(toks)
-                t_in2 = toks[rows, emit - 1]
-                prop_cnt = jnp.sum(drafts >= 0, axis=1).astype(jnp.int32)
-                carry = (new_kv, hist, t_in2, seq_len + emit, cnt + emit)
-                return carry, (toks, lps, emit, prop_cnt, m)
-
-            (new_kv, _, _, _, _), (toks, lps, emit, prop, acc) = jax.lax.scan(
-                body, (cache_kv, hist, t_in, seq_len, gen_counts),
-                None, length=rounds)
-            # (R, S, ...) -> slot-major for the host walk.
-            return (new_kv, toks.transpose(1, 0, 2), lps.transpose(1, 0, 2),
-                    emit.T, prop.T, acc.T)
+            hist = hist.at[jnp.arange(S), seq_len].set(t_in)
+            drafts = propose(hist, seq_len)                      # (S, k)
+            # Per-slot gate: a paused slot's draft is forced to the
+            # all-(-1) no-hit form, degrading just that slot to
+            # single-step while its neighbors keep speculating.
+            drafts = jnp.where(spec_mask[:, None], drafts, -1)
+            ids = jnp.concatenate(
+                [t_in[:, None], jnp.maximum(drafts, 0)], axis=1)
+            pos = seq_len[:, None] + jnp.arange(k + 1)[None, :]
+            logits, new_kv, _ = self._model_cache_call(
+                params, cache_kv, block_tables, ids, pos,
+                **self._named(lora))
+            logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+            g = jnp.argmax(logits, axis=-1).astype(jnp.int32)    # (S, k+1)
+            g_lp = jnp.take_along_axis(logp, g[..., None], axis=-1)[..., 0]
+            # Position-0 emission via sample_tokens for EVERY slot:
+            # greedy rows reduce to the same argmax, sampling rows get
+            # exactly the plain-decode draw for fold_in(key, cnt).
+            rngs = jax.vmap(jax.random.fold_in)(slot_keys, gen_counts)
+            s_tok, s_lp = sample_tokens(
+                logits[:, 0, :], rngs, temperature, top_k, top_p)
+            eq = (drafts == g[:, :k]) & (drafts >= 0)
+            m = jnp.sum(jnp.cumprod(eq.astype(jnp.int32), axis=1), axis=1)
+            emit = jnp.where(temperature == 0.0, m + 1, 1).astype(jnp.int32)
+            prop_cnt = jnp.sum(drafts >= 0, axis=1).astype(jnp.int32)
+            return (new_kv, g.at[:, 0].set(s_tok), g_lp.at[:, 0].set(s_lp),
+                    emit, prop_cnt, m)
 
         return spec_decode
 
     def _spec_fn(self, k: int):
         """The spec program for draft length ``k`` (pow2 halving-ladder
         member)."""
-        return self._built(
-            self._spec_fns, k,
-            lambda k: self._build_spec_decode_fn(k, self.cfg.spec_rounds))
-
-    def _multi_decode_fn(self, num_steps: int):
-        return self._built(self._multi_decode_fns, num_steps,
-                           self._build_multi_decode_fn)
+        return self._built(self._spec_fns, k, self._build_spec_decode_fn)
 
     # ------------------------------------------------------------------
     # Program calls: host arrays in by name, device results out, none of
@@ -1003,21 +932,16 @@ class EngineExecutor:
         return (jax.device_put(packed, self._round_sharding),
                 *self._pool_tree())
 
-    def launch_decode(self, staged: tuple, k_steps: int, prev=None):
-        """Call the ``k_steps``-step decode program: ``(tokens, logprobs)``,
-        ``(S, k_steps)`` each, or ``(S,)`` from the one-step program (the
-        model's counters as rows after the slots'). ``prev``: what the
-        launch of the one-step round before returned, fetched or not; the
-        rows staged as ``RIDES`` read their token from it. The one program
-        call of the round."""
+    def launch_decode(self, staged: tuple, prev=None):
+        """Call the decode program: ``(tokens, logprobs)``, ``(S,)`` each
+        (the model's counters as rows after the slots'). ``prev``: what
+        the launch of the round before returned, fetched or not; the rows
+        staged as ``RIDES`` read their token from it. The one program call
+        of the round."""
         self.stats["decode_program_calls"] += 1
-        if k_steps > 1:
-            self.cache, tokens, logprobs = self._multi_decode_fn(k_steps)(
-                self.params, self.cache, *staged)
-        else:
-            self.cache, tokens, logprobs = self._decode_fn(
-                self.params, self.cache,
-                self._no_prev if prev is None else prev[0], *staged)
+        self.cache, tokens, logprobs = self._decode_fn(
+            self.params, self.cache,
+            self._no_prev if prev is None else prev[0], *staged)
         return tokens, logprobs
 
     def stage_spec(self, hist: np.ndarray, t_in: np.ndarray,
@@ -1042,8 +966,9 @@ class EngineExecutor:
         return staged
 
     def launch_spec(self, staged: tuple, k: int):
-        """Call the draft-length-``k`` spec program: ``(tokens, logprobs,
-        emitted, proposed, accepted)``, slot-major."""
+        """Call the draft-length-``k`` spec program: ``(tokens, logprobs)``,
+        ``(S, k + 1)`` each, and ``(emitted, proposed, accepted)``,
+        ``(S,)`` each."""
         self.stats["decode_program_calls"] += 1
         self.cache, *out = self._spec_fn(k)(self.params, self.cache, *staged)
         return tuple(out)
@@ -1054,15 +979,14 @@ class EngineExecutor:
         return [np.asarray(jax.device_get(x)) for x in arrays]
 
     def warmup_decode_ladder(self) -> None:
-        """Pre-compile the decode programs (single-step + every multi-step
-        halving-ladder length) BEFORE traffic: a window length's first use
-        otherwise stalls the live decode loop on an XLA compile at an
-        unpredictable moment. AOT-lowers on abstract shapes (donation only
-        consumes avals here — no scratch KV pool is materialized), then
-        KEEPS the compiled executables and swaps them into the dispatch
-        path: relying on the persistent compilation cache alone does
-        nothing for a compile that finishes under the cache's
-        min-compile-time floor."""
+        """Pre-compile the decode program (the one a plain round calls;
+        nothing else is warmed here) BEFORE traffic: its first use
+        otherwise stalls the live decode loop on an XLA compile.
+        AOT-lowers on abstract shapes (donation only consumes avals here —
+        no scratch KV pool is materialized), then KEEPS the compiled
+        executable and swaps it into the dispatch path: relying on the
+        persistent compilation cache alone does nothing for a compile that
+        finishes under the cache's min-compile-time floor."""
         def avals(tree):
             # Carry each leaf's ACTUAL sharding: a ReplicatedEngine pins
             # every replica's params/KV to its own device, and an aval
@@ -1074,29 +998,20 @@ class EngineExecutor:
                     sharding=getattr(v, "sharding", None)), tree)
 
         # The packed round arrives COMMITTED (``stage_decode``), like the
-        # round-before's tokens; lower with the sharding it will carry so
-        # the AOT executables accept it (same reason params/cache carry
-        # theirs).
+        # round-before's tokens (the stand-in or a call's output); lower
+        # with the sharding each will carry so the AOT executable accepts
+        # them (same reason params/cache carry theirs).
         pk = self.round_packing
-        args = (avals(self.params), avals(self.cache),
-                jax.ShapeDtypeStruct((pk.num_slots, pk.width), jnp.int32,
-                                     sharding=self._no_prev.sharding),
-                *avals(self._pool_tree()))
         # Idempotent: a re-warm unwraps back to the raw jit fn (the
         # _aot_or_jit wrapper has no .lower) and rebuilds the executable.
-        # The one-step program also takes the round before's tokens, a
-        # committed array whether it is the stand-in or a call's output.
         raw = getattr(self._decode_fn, "_jit_fn", self._decode_fn)
         self._decode_fn = self._aot_or_jit(
-            raw.lower(*args[:2], avals(self._no_prev), *args[2:]).compile(),
+            raw.lower(
+                avals(self.params), avals(self.cache), avals(self._no_prev),
+                jax.ShapeDtypeStruct((pk.num_slots, pk.width), jnp.int32,
+                                     sharding=self._no_prev.sharding),
+                *avals(self._pool_tree())).compile(),
             raw)
-        k = self.cfg.steps_per_sync
-        while k > 1:
-            fn = self._multi_decode_fn(k)
-            raw = getattr(fn, "_jit_fn", fn)
-            self._multi_decode_fns[k] = self._aot_or_jit(
-                raw.lower(*args).compile(), raw)
-            k //= 2
 
     def register_memory_owners(self, ledger: MemoryLedger) -> None:
         """The device arrays this class holds, by owner (telemetry.

@@ -476,7 +476,7 @@ class AsyncEngine:
             finally:
                 self._work.release()
             # Step OUTSIDE the lock: one step is a compiled-program call
-            # (>1 s at large steps_per_sync), and holding the lock across
+            # (a long prefill runs for seconds), and holding the lock across
             # it serializes every HTTP submit against the device, which
             # shows as low slot occupancy under load. Concurrent
             # engine.submit() only

@@ -238,9 +238,6 @@ def parse_args():
                         "'teamA:ad1,teamB:ad2': requests without an "
                         "explicit X-Adapter header get their tenant's "
                         "adapter (needs --gateway; X-Adapter always works)")
-    p.add_argument("--steps-per-sync", type=int, default=1,
-                   help="decode iterations per compiled program (multi-step "
-                        "scheduling; amortizes host round-trips)")
     p.add_argument("--kv-cache-dtype", default="bfloat16",
                    choices=["bfloat16", "float16", "float32", "int8"],
                    help="KV pool dtype; int8 stores per-row-scaled "
@@ -444,7 +441,6 @@ def main() -> None:
         prefix_host_blocks=args.prefix_host_blocks,
         prefix_disk_dir=args.prefix_disk_dir,
         prefix_disk_blocks=args.prefix_disk_blocks,
-        steps_per_sync=args.steps_per_sync,
         cache_dtype=args.kv_cache_dtype,
         quantization=args.quantization,
         speculative=args.speculative,

@@ -69,8 +69,7 @@ def test_executor_owns_device_half_and_engine_holds_none_of_it(tiny_params):
     # what the programs and the memledger's owner read — the engine keeps
     # no second handle on any of it, by any of its old names.
     for name in ("params", "cache", "model", "adapter_pool", "_device",
-                 "_decode_fn", "_prefill_fns", "_multi_decode_fns",
-                 "_spec_fn", "_sample_fn", "_fold_keys", "_aot_or_jit",
+                 "_decode_fn", "_prefill_fns", "_spec_fn", "_sample_fn", "_fold_keys", "_aot_or_jit",
                  "_fetch_block_kv", "_restore_block", "_state_cache"):
         assert not hasattr(eng, name), name
     marker = jax.tree_util.tree_map(lambda x: x, eng.executor.params)

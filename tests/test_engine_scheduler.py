@@ -13,6 +13,11 @@ chunking or recompute gives the same stream: the token after ``t`` is
 ``t + 1``.
 """
 
+import dataclasses
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -61,12 +66,10 @@ class ScriptedExecutor:
         self.calls.append(("warmup",))
 
     # -- program calls ----------------------------------------------------
-    def _counter_rows(self, calls):
-        """One row a counter, one column a program call or decode step:
-        counter i reads i + 1 every time."""
-        n = len(self.counter_names)
-        return np.tile(np.arange(1, n + 1, dtype=np.int32)[:, None],
-                       (1, calls))
+    def _counter_rows(self):
+        """One row a counter of a program call or decode step: counter i
+        reads i + 1 every time."""
+        return np.arange(1, len(self.counter_names) + 1, dtype=np.int32)
 
     def prefill(self, bucket, *, input_ids, positions, block_tables,
                 last_idx, adapter_ids, state_slots, sample=None):
@@ -88,7 +91,7 @@ class ScriptedExecutor:
             return None
         tokens = input_ids[np.arange(len(last_idx)), last_idx] + 1
         if self.counter_names:
-            tokens = np.concatenate([tokens, self._counter_rows(1)[:, 0]])
+            tokens = np.concatenate([tokens, self._counter_rows()])
         return tokens.astype(np.int32), np.zeros(len(last_idx), np.float32)
 
     def stage_decode(self, input_ids, positions, mirrors, masked_rows):
@@ -104,22 +107,19 @@ class ScriptedExecutor:
             "masked_rows": list(masked_rows)}))
         return input_ids[:, 0].copy()
 
-    def launch_decode(self, staged, k_steps, prev=None):
+    def launch_decode(self, staged, prev=None):
         call = self.calls[-1][1]
-        call["k_steps"] = k_steps
         rides = staged == RIDES
         call["launched_ahead"] = prev is not None
         if rides.any():
             # what the program does on the device: the round before's tokens
-            assert k_steps == 1 and prev is not None
+            assert prev is not None
             staged = np.where(rides, prev[0][:len(staged)], staged)
         call["inputs"] = staged.copy()
-        tokens = staged[:, None] + 1 + np.arange(k_steps)[None, :]
+        tokens = staged + 1  # (no step axis: a round is one step)
         if self.counter_names:
-            tokens = np.concatenate([tokens, self._counter_rows(k_steps)])
-        logprobs = np.zeros((len(staged), k_steps), np.float32)
-        if k_steps == 1:  # the one-step program's outputs have no step axis
-            tokens, logprobs = tokens[:, 0], logprobs[:, 0]
+            tokens = np.concatenate([tokens, self._counter_rows()])
+        logprobs = np.zeros((len(staged),), np.float32)
         tokens = tokens.astype(np.int32)
         self.unfetched.append(tokens)
         return tokens, logprobs
@@ -184,16 +184,37 @@ def test_the_scheduler_module_knows_no_device():
     cannot touch. (The autouse fixture guards the calls of the rest.)"""
     names = vars(engine_module)
     assert "jax" not in names and "jnp" not in names
-    for name in ("params", "cache", "_prefill_fns", "_multi_decode_fns",
-                 "_decode_fn", "_state_cache"):
+    for name in ("params", "cache", "_prefill_fns", "_decode_fn",
+                 "_state_cache"):
         assert not hasattr(InferenceEngine, name), name
+
+
+def test_the_engine_has_no_field_for_a_round_of_several_steps():
+    """A plain round is one step, always: the field that asked for a window
+    of several is gone, and code that still passes it is told so."""
+    assert "steps_per_sync" not in {
+        f.name for f in dataclasses.fields(EngineConfig)}
+    with pytest.raises(TypeError, match="steps_per_sync"):
+        EngineConfig(steps_per_sync=1)
+
+
+def test_the_server_refuses_the_flag_for_a_round_of_several_steps():
+    """An operator who still passes ``--steps-per-sync`` is told so at start
+    (argparse's own error), not served something else."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, "scripts/serve.py", "--random-init", "llama_tiny",
+         "--tokenizer", "byte", "--steps-per-sync", "4"],
+        cwd=root, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 2
+    assert "unrecognized arguments: --steps-per-sync 4" in proc.stderr
 
 
 # -- admission order and slot reuse ------------------------------------------
 
 @pytest.mark.parametrize("over", [
-    {}, dict(max_prefill_tokens_per_step=3), dict(steps_per_sync=4)],
-    ids=["throughput", "chunked", "multi_step"])
+    {}, dict(max_prefill_tokens_per_step=3)], ids=["throughput", "chunked"])
 def test_admission_is_first_come_first_served_and_slots_are_reused(over):
     eng = _engine(**over)
     prompts = [[10, 11, 12], [20, 21], [30, 31, 32, 33, 34], [40], [50, 51]]
@@ -232,21 +253,14 @@ def test_admission_is_first_come_first_served_and_slots_are_reused(over):
         for call in ex.of("prefill"):
             assert (call["positions"] >= 0).sum() <= 3
         assert any(call["masked_rows"] for call in ex.of("decode"))
-    if "steps_per_sync" in over:
-        # windows come off the halving ladder, clamped to the budget
-        assert {c["k_steps"] for c in ex.of("decode")} <= {1, 2, 4}
-        assert 4 in {c["k_steps"] for c in ex.of("decode")}
 
 
 # -- block growth, and preemption of the youngest ----------------------------
 
-@pytest.mark.parametrize("steps_per_sync", [1, 2], ids=["single", "window"])
-def test_tables_grow_and_the_youngest_is_preempted_when_the_pool_runs_out(
-        steps_per_sync):
+def test_tables_grow_and_the_youngest_is_preempted_when_the_pool_runs_out():
     # 5 allocatable blocks of 4 tokens for three sequences that, together,
     # want 9: the pool runs dry while the middle one grows.
-    eng = _engine(max_seqs=3, num_blocks=6, max_model_len=16,
-                  steps_per_sync=steps_per_sync)
+    eng = _engine(max_seqs=3, num_blocks=6, max_model_len=16)
     prompts = [[10, 11, 12, 13, 14, 15, 16], [30, 31], [50]]
     lengths = [9, 8, 8]
     old, mid, young = (eng.submit(p, SamplingParams(max_tokens=n))
@@ -262,7 +276,7 @@ def test_tables_grow_and_the_youngest_is_preempted_when_the_pool_runs_out(
     # growth: every decode call's table covers the positions it writes
     for call in ex.of("decode"):
         for sid in np.nonzero(call["positions"])[0]:
-            last = call["positions"][sid] + call["k_steps"] - 1
+            last = call["positions"][sid]
             row = call["block_tables"][sid]
             assert (row[:last // 4 + 1] > 0).all(), (sid, last, row)
     # the pool ran out: the youngest paid first, the oldest never
@@ -296,26 +310,31 @@ def test_a_pool_with_nothing_left_to_preempt_is_an_error_not_a_hang():
 # -- retirement ---------------------------------------------------------------
 
 RETIRES = {
-    # the stream reaches EOS at its third token
+    # the stream reaches EOS at its third token, with its row of the round
+    # behind in flight: that row is thrown away (``discarded``)
     "eos": dict(prompt=[EOS - 3], sp=dict(max_tokens=20),
-                want=(3, "stop")),
+                want=(3, "stop"), discarded=1),
     "eos_from_prefill": dict(prompt=[5, EOS - 1], sp=dict(max_tokens=20),
-                             want=(1, "stop")),
+                             want=(1, "stop"), discarded=0),
     "stop_token": dict(prompt=[10], sp=dict(max_tokens=20,
                                             stop_token_ids=[14]),
-                       want=(4, "stop")),
+                       want=(4, "stop"), discarded=1),
+    # an end by length is foreseen: no row goes out behind the last one
     "max_tokens": dict(prompt=[10, 11], sp=dict(max_tokens=7),
-                       want=(7, "length")),
+                       want=(7, "length"), discarded=0),
     # prompt 5 + answer 7 = max_model_len 12
     "max_model_len": dict(prompt=[10, 11, 12, 13, 14],
                           sp=dict(max_tokens=100), want=(7, "length"),
-                          engine=dict(max_model_len=12)),
-    # the same two limits met inside a 4-step window: the tail is dropped
-    "max_tokens_mid_window": dict(prompt=[10, 11], sp=dict(max_tokens=6),
-                                  want=(6, "length"),
-                                  engine=dict(steps_per_sync=4)),
-    "eos_mid_window": dict(prompt=[EOS - 7], sp=dict(max_tokens=20),
-                           want=(7, "stop"), engine=dict(steps_per_sync=4)),
+                          engine=dict(max_model_len=12), discarded=0),
+    # the same two ends seven tokens in, the loop long a round ahead, with
+    # a request waiting: it takes the slot in the step that fetched the
+    # end, and its first round goes out behind the thrown-away row
+    "eos_row_in_flight": dict(prompt=[EOS - 7], sp=dict(max_tokens=20),
+                              want=(7, "stop"), discarded=1,
+                              waiting=[70, 71]),
+    "stop_token_row_in_flight": dict(
+        prompt=[10], sp=dict(max_tokens=20, stop_token_ids=[17]),
+        want=(7, "stop"), discarded=1, waiting=[70, 71]),
 }
 
 
@@ -325,16 +344,35 @@ def test_a_request_retires_when_and_why_it_should(name):
     eng = _engine(**case.get("engine", {}))
     req = eng.submit(case["prompt"], SamplingParams(**case["sp"]))
     bystander = eng.submit([60], SamplingParams(max_tokens=8))
+    everyone = [req, bystander]
+    waiting = case.get("waiting")
+    if waiting:
+        everyone.append(eng.submit(waiting, SamplingParams(max_tokens=5)))
     returned = _drain(eng)
     n, reason = case["want"]
     assert req.output_token_ids == _stream(case["prompt"], n)
     assert req.finish_reason == reason
     assert bystander.output_token_ids == _stream([60], 8)
-    assert list(eng.finished) == [req, bystander]
+    if waiting:
+        assert everyone[2].output_token_ids == _stream(waiting, 5)
+        rounds = eng.executor.of("decode")
+        at = [c["input_ids"][0] for c in rounds].index(waiting[-1] + 1)
+        # its first token is a host id; the round before it carried the
+        # ended request's row, read on the device, given to nobody
+        assert rounds[at]["launched_ahead"] and rounds[at]["positions"][0] == 2
+        assert rounds[at - 1]["input_ids"][0] == RIDES
+    assert list(eng.finished) == everyone
     # step() hands back what a decode round retired (a request whose first
     # token ended it never decoded)
-    assert returned == [r for r in (req, bystander)
-                        if len(r.output_token_ids) > 1]
+    assert returned == [r for r in everyone if len(r.output_token_ids) > 1]
+    st = eng.stats
+    assert st["decode_rows_discarded"] == case["discarded"]
+    # a slot counts a step for every token it kept after its prefill's, a
+    # round for every launch: the thrown-away row is in the second alone
+    kept = sum(len(r.output_token_ids) - 1 for r in everyone)
+    assert st["decode_slot_steps"] == kept
+    assert st["decode_steps"] == len(eng.executor.of("decode"))
+    assert not eng.executor.unfetched
     assert eng.block_manager.num_free == eng.cfg.num_blocks - 1
 
 
@@ -354,26 +392,32 @@ def test_a_cancelled_request_leaves_at_its_next_token_or_before_admission():
 
 # -- the counters PERF.md section 3 reads --------------------------------------
 
+# name: (the model counts, the first request ends on a stop token nobody
+# foresees: its row of the round behind runs and is thrown away)
 BOOKED = {
-    "single_step": dict(steps_per_sync=1),
-    "windows": dict(steps_per_sync=4),
-    "model_counters": dict(steps_per_sync=2),
+    "single_step": (False, False),
+    "rows_discarded": (False, True),
+    "model_counters": (True, False),
+    "model_counters_rows_discarded": (True, True),
 }
 
 
 @pytest.mark.parametrize("name", sorted(BOOKED))
 def test_decode_counters_are_booked_as_perf_md_says(name, monkeypatch):
-    counted = ("first_counter", "second_counter") \
-        if name == "model_counters" else ()
+    counts, stops = BOOKED[name]
+    counted = ("first_counter", "second_counter") if counts else ()
     monkeypatch.setattr(ScriptedExecutor, "counter_names", counted)
-    eng = _engine(**BOOKED[name])
+    eng = _engine()
     for key in counted:
         assert eng.stats[key] == eng.stats[f"{key}_decode"] == 0
     prompts = [[10, 11, 12], [40, 41, 42, 43, 44]]
     lengths = [9, 5]
     for p, n in zip(prompts, lengths):
-        eng.submit(p, SamplingParams(max_tokens=n, top_k=3 if p[0] == 40
-                                     else 0))
+        eng.submit(p, SamplingParams(
+            max_tokens=n, top_k=3 if p[0] == 40 else 0,
+            stop_token_ids=[16] if stops and p[0] == 10 else []))
+    if stops:
+        lengths = [4, 5]  # 13, 14, 15, 16
     # the books, kept by hand from what the executor was asked to do
     steps = context = tile_keys = sorted_steps = 0
     seen = 0
@@ -382,23 +426,25 @@ def test_decode_counters_are_booked_as_perf_md_says(name, monkeypatch):
         calls = eng.executor.of("decode")[seen:]
         seen += len(calls)
         for call in calls:
-            k = call["k_steps"]
-            steps += k
+            steps += 1
             # context: what each row of the round had cached when it began,
             # which is the position its new token is written at (a row that
             # rides in the round before stands one further than the host
             # has seen)
             cached = call["positions"][np.nonzero(call["positions"])[0]]
-            context += k * int(cached.sum())
+            context += int(cached.sum())
             # whole tiles of 4 keys over those tokens and the new one
-            tile_keys += k * sum(4 * -(-(int(c) + 1) // 4) for c in cached)
+            tile_keys += sum(4 * -(-(int(c) + 1) // 4) for c in cached)
             # the program's own predicate, over the rows it was given
-            sorted_steps += k * bool((call["top_k"] > 0).any())
+            sorted_steps += bool((call["top_k"] > 0).any())
     # a slot counts a step for every token it kept: all but the prefill's
+    # (a row thrown away ran, and is in the rounds' books alone)
     slot_steps = sum(lengths) - len(lengths)
     st = eng.stats
     assert st["decode_steps"] == steps > 0
     assert st["decode_slot_steps"] == slot_steps
+    assert st["decode_rows_discarded"] == stops
+    assert st["generated_tokens"] == sum(lengths)
     assert st["decode_context_tokens"] == context
     assert st["decode_kernel_tile_tokens"] == tile_keys > context
     assert st["decode_steps_sorted_sampling"] == sorted_steps > 0
@@ -421,7 +467,7 @@ def test_live_share_of_the_kernels_tiles_on_a_hand_made_batch(tile,
     new token opens a tile for the 8 and fills one for the 3); tiles of 16:
     24 against 48."""
     monkeypatch.setattr(ScriptedExecutor, "decode_tile_tokens", tile)
-    eng = _engine(steps_per_sync=1, max_seqs=3)
+    eng = _engine(max_seqs=3)
     for n in (3, 8, 13):
         eng.submit(list(range(10, 10 + n)), SamplingParams(max_tokens=3))
     while not eng.executor.of("decode"):
@@ -674,29 +720,54 @@ def test_a_request_whose_end_is_foreseen_is_left_out_and_masked(limit):
     assert not eng.executor.unfetched
 
 
-def test_a_plan_that_must_preempt_fetches_first():
-    eng = _engine(max_seqs=3, num_blocks=6, max_model_len=16)
-    prompts = [[10, 11, 12, 13, 14, 15, 16], [30, 31], [50]]
-    lengths = [9, 8, 8]
+PLANS_GIVEN_UP = {
+    # three sequences that want 9 blocks of 5: the plan behind the round in
+    # flight would have to preempt, which needs every token on the host
+    "must_preempt": dict(
+        engine=dict(max_seqs=3, num_blocks=6, max_model_len=16),
+        prompts=[[10, 11, 12, 13, 14, 15, 16], [30, 31], [50]],
+        want=[_stream([10, 11, 12, 13, 14, 15, 16], 9), _stream([30, 31], 8),
+              _stream([50], 8)], max_tokens=[9, 8, 8], preempts=True),
+    # two sequences over 3 blocks, each wanting its second behind the very
+    # round in which the first samples its EOS. The plan is given up, the
+    # fetch retires the first and frees its block, and the plan made from
+    # the host's tokens has what it needs: nobody is preempted
+    "blocks_come_with_the_fetch": dict(
+        engine=dict(num_blocks=4, max_model_len=12),
+        prompts=[[EOS - 4], [10]],
+        want=[[EOS - 3, EOS - 2, EOS - 1, EOS], _stream([10], 8)],
+        max_tokens=[20, 8], preempts=False),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PLANS_GIVEN_UP))
+def test_a_plan_that_cannot_have_its_blocks_fetches_first(name):
+    case = PLANS_GIVEN_UP[name]
+    eng = _engine(**case["engine"])
     reqs = [eng.submit(p, SamplingParams(max_tokens=n))
-            for p, n in zip(prompts, lengths)]
+            for p, n in zip(case["prompts"], case["max_tokens"])]
     seen = 0
-    preempting = []
+    given_up = []
     while eng.has_work:
-        before = eng.stats["preemptions"]
+        preempted = eng.stats["preemptions"]
+        had_inflight = eng._inflight is not None
         eng.step()
-        if eng.stats["preemptions"] > before:
-            preempting.append(_kinds(eng.executor, seen))
+        log = _kinds(eng.executor, seen)
         seen = len(eng.executor.calls)
-    assert preempting
-    for log in preempting:
+        if eng.stats["preemptions"] > preempted or (
+                had_inflight and "launch" in log):
+            given_up.append(log)
+    assert given_up
+    for log in given_up:
         # no round went out behind the one in flight: it was fetched, then
-        # the plan (with its preemption) was made from the host's tokens
+        # the plan (with its preemption, where it needs one) was made from
+        # the host's tokens
         assert log[:2] == ["fetch", "launch"], log
-    for r, p, n in zip(reqs, prompts, lengths):
-        assert r.output_token_ids == _stream(p, n)
+    assert bool(eng.stats["preemptions"]) == case["preempts"]
+    assert [r.output_token_ids for r in reqs] == case["want"]
     assert not eng.executor.unfetched
     assert eng.stats["decode_rows_discarded"] == 0
+    assert eng.block_manager.num_free == eng.cfg.num_blocks - 1
 
 
 def test_a_plan_given_up_for_want_of_blocks_ticks_no_cooldown():

@@ -15,6 +15,7 @@ Two hot paths, one invariant each:
   speculative round followed by a plain one).
 """
 
+import dataclasses
 import json
 
 import numpy as np
@@ -27,6 +28,8 @@ from dlti_tpu.config import (
 from dlti_tpu.data import TokenBatchDataset
 from dlti_tpu.data.prefetch import HostPrefetcher
 from dlti_tpu.serving import EngineConfig, InferenceEngine, SamplingParams
+from test_layer_windows import TINY as WINDOW_GROUPS
+from test_looped_layers import TINY as LOOPED
 
 CFG = MODEL_PRESETS["llama_tiny"]
 
@@ -339,13 +342,6 @@ def test_packed_round_matches_across_preemption(tiny_params):
             want or _each_alone(tiny_params, prompts, sp, max_model_len=48))
 
 
-def test_packed_round_matches_multi_step(tiny_params):
-    prompts = [[1, 2, 3, 4], [5, 6, 7]]
-    eng = _engine(tiny_params, max_seqs=2, steps_per_sync=4)
-    got = eng.generate(prompts, SamplingParams(temperature=0.0, max_tokens=9))
-    assert _tokens(got) == _uncached_greedy(tiny_params, prompts, 9)
-
-
 def test_packed_round_matches_while_a_row_prefills(tiny_params):
     """Chunked prefill: a slot is admitted and prefills over several steps
     while the others decode; its block-table row is packed as the trash
@@ -424,13 +420,51 @@ def _recurrent_engine(_params, **kw):
     return InferenceEngine(cfg, params, EngineConfig(**over))
 
 
+LATENT = dataclasses.replace(MODEL_PRESETS["latent_tiny"],
+                             moe_scoring="sigmoid_bias")
+# Every kind of cache the executor serves, at test widths:
+# name: (model configuration, EngineConfig fields)
+FAMILIES = {
+    "dense": (CFG, {}),
+    "int8_kv": (CFG, {"cache_dtype": "int8"}),
+    "latent": (LATENT, {}),
+    "stream_maps": (dataclasses.replace(LATENT, hc_mult=4), {}),
+    "window_groups": (WINDOW_GROUPS, {}),
+    "looped": (LOOPED, {}),
+}
+_FAMILY_PARAMS = {}
+
+
+def _family_engine(family, cls=InferenceEngine, **kw):
+    """An engine over one of ``FAMILIES`` (its parameters built once a
+    module), at the sizes of ``_engine``."""
+    cfg, over = FAMILIES[family]
+    if family not in _FAMILY_PARAMS:
+        import jax
+        import jax.numpy as jnp
+
+        from dlti_tpu.models import build_model
+
+        _FAMILY_PARAMS[family] = build_model(cfg).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    return cls(cfg, _FAMILY_PARAMS[family], EngineConfig(**{
+        **dict(max_seqs=3, block_size=4, num_blocks=128, max_model_len=64,
+               cache_dtype="float32", eos_token_id=-1), **over, **kw}))
+
+
+def _window_groups_engine(_params, **kw):
+    """Layers of a window beside layers that see every key: a second table
+    and its base ride in the packed round."""
+    return _family_engine("window_groups", block_size=8, **kw)
+
+
 ROUND_KINDS = {
     # name: (engine maker, engine options, rounds launched ahead?)
     "one_step": (None, {}, False),
     "riding": (_engine, {}, True),
-    "steps_per_sync_4": (_engine, {"steps_per_sync": 4}, False),
     "multi_lora": (_lora_engine, {}, True),
     "recurrent": (_recurrent_engine, {}, True),
+    "window_groups": (_window_groups_engine, {}, True),
 }
 
 
@@ -443,9 +477,10 @@ def test_a_plain_round_is_one_upload_and_one_program_call(
     host-to-device transfer and its launch exactly one program call, by
     the patched ``jax.device_put`` / ``jnp.asarray`` and by the engine's
     own counters; one-step rounds fetched before the next is planned,
-    rounds riding behind the round in flight, four-step windows, a
-    multi-LoRA pool (``adapter_ids`` packed, the pool's tree after it) and
-    a recurrent model (``state_slots`` packed)."""
+    rounds riding behind the round in flight, a multi-LoRA pool
+    (``adapter_ids`` packed, the pool's tree after it), a recurrent model
+    (``state_slots`` packed) and a model with a window group of layers
+    (its table and base packed)."""
     import jax
     import jax.numpy as jnp
 
@@ -459,8 +494,10 @@ def test_a_plain_round_is_one_upload_and_one_program_call(
     ex = eng.executor
     extra = {"multi_lora": "adapter_ids", "recurrent": "state_slots"}.get(kind)
     assert ex.round_packing.extra_field == extra
+    window = ex.round_packing.window_blocks
+    assert (window > 0) == (kind == "window_groups")
     assert ex.round_packing.width == eng.cfg.max_blocks_per_seq + 8 + \
-        (extra is not None)
+        (extra is not None) + (window + 1 if window else 0)
 
     transfers = {"stage": 0, "launch": 0}
     where = [None]
@@ -495,8 +532,6 @@ def test_a_plain_round_is_one_upload_and_one_program_call(
         return call
 
     ex._decode_fn = counted_program(ex._decode_fn)
-    real_multi = ex._multi_decode_fn
-    ex._multi_decode_fn = lambda k: counted_program(real_multi(k))
 
     prompts = [[1, 2, 3, 4, 5], [6, 7, 8], [9, 10, 11, 12], [13, 14],
                [15, 16, 17, 18, 19, 20, 21]]
@@ -510,10 +545,7 @@ def test_a_plain_round_is_one_upload_and_one_program_call(
     assert transfers == {"stage": rounds, "launch": 0}
     assert len(programs) == rounds == st["decode_host_uploads"]
     assert (st["decode_rounds_launched_ahead"] > 0) == ahead
-    if over.get("steps_per_sync"):
-        assert st["decode_steps"] > rounds     # windows of several steps
-    else:
-        assert st["decode_steps"] == rounds
+    assert st["decode_steps"] == rounds
 
 
 def test_the_packing_round_trips_bit_for_bit():
@@ -574,7 +606,7 @@ def test_no_decode_program_is_built_after_the_warm_up(tiny_params):
     traced or compiled for them. (The row updater this replaces was a
     program a padded count of changed rows.)"""
     eng = _engine(tiny_params, max_seqs=32, num_blocks=160, block_size=8,
-                  max_model_len=32, steps_per_sync=1)
+                  max_model_len=32)
     eng.warmup_decode_ladder()
     call = eng.executor._decode_fn
     assert call._aot_state["aot"]
@@ -636,31 +668,32 @@ def _reference_logprobs(params, prompt, tokens):
     return np.asarray(lp[len(prompt) - 1:len(prompt) - 1 + len(tokens)])
 
 
+@pytest.mark.parametrize("family", sorted(FAMILIES))
 @pytest.mark.parametrize("kind", ["greedy", "seeded"])
 def test_running_ahead_serves_what_fetching_first_serves(
-        tiny_params, fetch_first_engine, kind):
+        tiny_params, fetch_first_engine, kind, family):
     """Requests of mixed lengths through three slots, so that rounds hold
     retirements, admissions into slots just freed and block growth: the
     token and log-prob streams of the loop that runs ahead equal, to the
     bit, those of the same engine fetching every round before it plans the
     next; and some of them end on a stop token nobody could foresee, whose
-    row in the round behind is thrown away."""
+    row in the round behind is thrown away. Over every kind of cache: that
+    row writes a key and a value (int8 rows and their scales), a latent, a
+    position of every pass's entry, and a block of the window group that
+    is released behind the window."""
     def params(stop=()):
         return [SamplingParams(
             temperature=0.0 if kind == "greedy" else 0.9, max_tokens=n,
             seed=None if kind == "greedy" else 100 + i,
             stop_token_ids=tuple(stop)) for i, n in enumerate(LENGTHS)]
 
-    cfg = EngineConfig(max_seqs=3, block_size=4, num_blocks=64,
-                       max_model_len=64, cache_dtype="float32",
-                       eos_token_id=-1)
-    plain = _streams(fetch_first_engine(CFG, tiny_params, cfg), MIXED,
+    plain = _streams(_family_engine(family, fetch_first_engine), MIXED,
                      params())
     # a stop token that some stream reaches mid-answer: an end nobody foresees
     stop = [plain[3][0][6], plain[0][0][4]]
     for fetch_first in (True, False):
-        eng = (fetch_first_engine if fetch_first else InferenceEngine)(
-            CFG, tiny_params, cfg)
+        eng = _family_engine(
+            family, fetch_first_engine if fetch_first else InferenceEngine)
         got = _streams(eng, MIXED, params(stop))
         if fetch_first:
             want = got
@@ -670,8 +703,15 @@ def test_running_ahead_serves_what_fetching_first_serves(
         st = eng.stats
         assert st["decode_rows_discarded"] >= 2
         assert st["decode_rounds_launched_ahead"] > 0.8 * st["decode_steps"]
+        assert eng.block_manager.num_free == eng.cfg.num_blocks - 1
+        if family == "window_groups":
+            # sequences outgrew the window of 8 and ended: both releases
+            assert eng.kv_freed["window", "window"] > 0 < \
+                eng.kv_freed["window", "end"]
+            assert eng.window_manager.num_free == \
+                eng.window_manager.num_blocks - 1
     assert {r[2] for r in want} == {"stop", "length"}
-    if kind == "greedy":
+    if kind == "greedy" and family == "dense":
         for prompt, (tokens, logprobs, _) in zip(MIXED, want):
             rows = _reference_logprobs(tiny_params, prompt, tokens)
             picked = rows[np.arange(len(tokens)), tokens]

@@ -51,15 +51,14 @@ def prompts(lengths, seed=0):
 # -- pools, tables, packing ----------------------------------------------------
 
 def test_a_window_group_has_a_pool_of_its_own_size_from_shapes_alone():
-    cache = init_cache(TINY, 320, 4, 4, jnp.float32, call_tokens=64,
-                       decode_steps=1)
-    want = window_group_blocks(8, 4, 4, 64, 1)
+    cache = init_cache(TINY, 320, 4, 4, jnp.float32, call_tokens=64)
+    want = window_group_blocks(8, 4, 4, 64)
     assert want == 4 * (3 + 2) + 16 + 8 + 1
     assert [c["k"].shape[0] for c in cache] == [want, want, want, 320, want]
     assert all(c["k"].shape[1:] == (4, 2, 16) for c in cache)
     assert window_blocks(128, 16, 1) == 11 and window_blocks(128, 16, 2048) == 138
     # the cell's: 32 slots, a window of 128 over blocks of 16, calls of 2,048
-    assert window_group_blocks(128, 16, 32, 2048, 1) == 489
+    assert window_group_blocks(128, 16, 32, 2048) == 489
 
 
 @pytest.mark.parametrize("name", ["llama_tiny", "mistral_7b"])
@@ -67,8 +66,7 @@ def test_a_model_of_one_group_keeps_its_cache_to_the_byte(name):
     cfg = dataclasses.replace(MODEL_PRESETS[name], num_layers=2,
                               hidden_size=64, num_heads=4, num_kv_heads=2,
                               head_dim=None)
-    cache = init_cache(cfg, 32, 4, 4, jnp.bfloat16, call_tokens=2048,
-                       decode_steps=4)
+    cache = init_cache(cfg, 32, 4, 4, jnp.bfloat16, call_tokens=2048)
     plain = init_paged_cache(2, 32, 4, 2, 16, jnp.bfloat16)
     assert jax.tree_util.tree_structure(cache) == \
         jax.tree_util.tree_structure(plain)
@@ -152,25 +150,29 @@ def check_invariants(eng):
             assert list(row[:n]) == s.window_blocks and not row[n:].any()
             assert eng._window_base[s.slot_id] == s.window_first * bs
             # between calls a sequence holds a window's blocks and the
-            # round's alone
-            assert n <= window_blocks(w, bs, eng.cfg.steps_per_sync)
+            # round's one token's alone
+            assert n <= window_blocks(w, bs, 1)
 
 
 @pytest.mark.parametrize("native", [True, False])
-@pytest.mark.parametrize("mode", ["plain", "chunked", "multi_step"])
+@pytest.mark.parametrize("mode", ["plain", "chunked", "stop_tokens"])
 def test_random_traffic_keeps_the_allocators_invariants(params, monkeypatch,
                                                         native, mode):
     """Random admissions, prefill calls (whole or in chunks), decode rounds
     and ends over the two groups: no block held twice, the window group
     within its bound, every table entry a live tile can touch a held block,
-    everything free at the end; the native core and the fallback alike."""
+    everything free at the end; the native core and the fallback alike.
+    ``stop_tokens``: ends the plan cannot foresee (an end by length it
+    can), so rows of sequences that have ended, their blocks of both groups
+    released, are in flight and thrown away."""
     if not native:
         monkeypatch.setattr(bmod, "load_native_runtime", lambda: None)
     elif bmod.load_native_runtime() is None:
         pytest.skip("no native runtime here")
-    ec = dataclasses.replace(EC, **{
-        "plain": {}, "chunked": {"max_prefill_tokens_per_step": 40},
-        "multi_step": {"steps_per_sync": 4}}[mode])
+    ec = dataclasses.replace(EC, **(
+        {"max_prefill_tokens_per_step": 40} if mode == "chunked" else {}))
+    # (an eighth of the vocabulary: an answer seldom reaches its length)
+    stops = tuple(range(3, 512, 8)) if mode == "stop_tokens" else ()
     eng = InferenceEngine(TINY, params, ec)
     assert (eng.window_manager._native is not None) == native
     assert (eng.block_manager._native is not None) == native
@@ -180,6 +182,7 @@ def test_random_traffic_keeps_the_allocators_invariants(params, monkeypatch,
     for i, prompt in enumerate(prompts(lengths, seed=1)):
         reqs.append((prompt, SamplingParams(
             temperature=rng.choice([0.0, 1.0]), seed=100 + i,
+            stop_token_ids=stops,
             max_tokens=min(rng.randint(1, 24), 255 - len(prompt)))))
     pending, steps = list(reqs), 0
     while pending or eng.has_work:
@@ -193,6 +196,7 @@ def test_random_traffic_keeps_the_allocators_invariants(params, monkeypatch,
     assert eng.window_manager.num_free == eng.window_manager.num_blocks - 1
     assert eng.block_manager.num_free == eng.block_manager.num_blocks - 1
     assert eng.kv_freed["window", "window"] > 0 < eng.kv_freed["window", "end"]
+    assert (eng.stats["decode_rows_discarded"] >= 4) == (mode == "stop_tokens")
     assert eng.stats["decode_window_context_tokens"] \
         < eng.stats["decode_context_tokens"]
     assert 0 < eng.stats["prefill_window_attention_pairs"] \
@@ -254,7 +258,7 @@ def test_the_caches_books_by_group(params):
     for metric in eng.kv_metrics():
         for name, labels, child in metric.samples():
             series[name + labels] = (metric.kind, child.value)
-    bound = window_group_blocks(8, 4, 4, 2048, 1)
+    bound = window_group_blocks(8, 4, 4, 2048)
     assert series['dlti_kv_pool_blocks{group="full"}'] == ("gauge", 320)
     assert series['dlti_kv_pool_blocks{group="window"}'] == ("gauge", bound)
     assert series['dlti_kv_blocks_in_use{group="full"}'] == ("gauge", 0)

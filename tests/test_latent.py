@@ -413,7 +413,6 @@ SCENARIOS = {
     "unequal_batch": dict(lengths=[33, 5, 19], engine={}),
     "chunked_prefill": dict(
         lengths=[45, 23], engine=dict(max_prefill_tokens_per_step=16)),
-    "multi_step": dict(lengths=[12, 30], engine=dict(steps_per_sync=4)),
     # decode through the Pallas kernel (interpreted), not the gather path
     "decode_kernel": dict(
         lengths=[40, 9], engine=dict(model=dict(paged_attention_impl="kernel"))),

@@ -209,12 +209,10 @@ def test_the_engine_agrees_with_the_full_forward(tiny, monkeypatch, impl,
     assert eng.stats["moe_held_assignments"] > 0
 
 
-@pytest.mark.parametrize("mode", ["multi_step", "chunked"])
-def test_windows_of_decode_steps_and_chunked_prefill(tiny, mode):
+def test_chunked_prefill_over_the_window_group(tiny):
     ec = EngineConfig(max_seqs=4, block_size=4, num_blocks=320,
                       max_model_len=256, cache_dtype="float32",
-                      **({"steps_per_sync": 4} if mode == "multi_step"
-                         else {"max_prefill_tokens_per_step": 48}))
+                      max_prefill_tokens_per_step=48)
     eng = InferenceEngine(TINY, tiny["params"], ec)
     asked = prompts([70, 9, 130, 33, 52], seed=3)
     out = eng.generate(asked, SamplingParams(temperature=0.0, max_tokens=13))
@@ -289,13 +287,11 @@ def sha(lowered):
 PARENT = {
     "mistral_7b": {
         "decode": "d0e054da3edad2d30a120025da7855a16df9fd42e2d5cdf4e895f1f03802c261",
-        "decode_multi": "dbc42ee45cfa69e3bcdf2b3db41032cd1f4bbff09b3910ee968dcb67fabd3261",
         "prefill": "e889ce67c7b9a98a15f6eb6f644f4e7bf0d78cc27f6523ab96edc567a6888a83",
         "train": "5f78927809fbbcc96d06477e3005b3bf4de6a7178f8a1dc8e459b8fd1ab5e04d",
         "tree": "32578896e834d2de20a200433bb5a739413e8ffe7ea44a53800ff6c7a97006d8"},
     "qwen2_7b": {
         "decode": "a9fc0cc93eacc5901a5b912890850f62713ad4d446d91f65d2b3c02861955204",
-        "decode_multi": "0db3bb2266960676ce8664b1c860315a6356be07a99941e4698ae1515c3834b9",
         "prefill": "71e824a8e45bd7ac4c9b9cf5aab0c7208b29b69673df3a5a3bc0476627faa370",
         "train": "521295162228289d68ffc08c3aeaad2d86a531dc72fe77416d345530eecc5562",
         "tree": "a2cfbee9d3dc1b2803a9c0cdc6ea85523b8383fd965943d0e7528e8d2d948eb3"},
@@ -304,28 +300,25 @@ PARENT = {
 
 @pytest.mark.parametrize("name", ["mistral_7b", "qwen2_7b"])
 def test_the_dense_presets_lower_to_the_programs_they_were(name):
-    """The parameter tree, the decode programs, a prefill program (the
+    """The parameter tree, the decode program, a prefill program (the
     gather stays for a table of 4,096 keys) and the LoRA training step of
     ``mistral_7b`` and ``qwen2_7b`` at test widths: hashes taken at the
     parent commit with this function under this suite's conftest (a change
     that means to change their programs re-pins them: PR 47 re-took
     ``"prefill"`` alone, whose program now heads one position a row; the
-    other four are ``c1c8496``'s and say nothing else moved)."""
+    other three are ``c1c8496``'s and say nothing else moved)."""
     from dlti_tpu.training.step import causal_lm_loss
 
     cfg = narrow(name)
     model, params = init(cfg)
     ex = InferenceEngine(cfg, params, EngineConfig(
-        max_seqs=4, block_size=4, num_blocks=64, max_model_len=128,
-        steps_per_sync=2)).executor
+        max_seqs=4, block_size=4, num_blocks=64, max_model_len=128)).executor
     pk = ex.round_packing
     packed = jnp.zeros((pk.num_slots, pk.width), jnp.int32)
     ids = jnp.zeros((2, 32), jnp.int32)
     got = {
         "decode": sha(ex._decode_fn.lower(ex.params, ex.cache, ex._no_prev,
                                           packed)),
-        "decode_multi": sha(ex._multi_decode_fn(2).lower(
-            ex.params, ex.cache, packed)),
         "prefill": sha(ex._prefill_fn(32).lower(
             ex.params, ex.cache, ids, ids, jnp.zeros((2, 16), jnp.int32),
             jnp.zeros((2,), jnp.int32))),

@@ -306,15 +306,13 @@ def test_the_cache_has_an_entry_a_pass_of_each_layer(tiny):
     ("plain", {}),
     ("kernel", {}),                                  # the decode kernel
     ("two_prefill_calls", {"max_prefill_tokens_per_step": 48}),
-    ("multi_step", {"steps_per_sync": 4}),
     ("int8_cache", {"cache_dtype": "int8"}),
 ])
 def test_prefill_then_decode_through_the_cache_agrees_with_the_reference(
         tiny, mode, ec):
     """Rows of unequal length in one batch, a prompt split over two prefill
     calls (the later over what the earlier wrote, in every pass's entry),
-    rounds of four decode steps, the interpreted kernel, int8 keys and
-    values: the engine's log-probs against the reference's full forward at
+    the interpreted kernel, int8 keys and values: the engine's log-probs against the reference's full forward at
     every position it sampled at."""
     cfg = dataclasses.replace(
         TINY, paged_attention_impl="kernel" if mode == "kernel" else "gather")

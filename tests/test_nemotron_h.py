@@ -310,8 +310,6 @@ SCENARIOS = {
     # prompts fed 16 tokens a step: the state crosses chunk boundaries
     "chunked_prefill": dict(
         lengths=[45, 23], engine=dict(max_prefill_tokens_per_step=16)),
-    # four decode steps a program call: the states ride the scan's carry
-    "multi_step": dict(lengths=[12, 30], engine=dict(steps_per_sync=4)),
 }
 
 

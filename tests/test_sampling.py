@@ -243,15 +243,13 @@ def test_counter_stays_zero_under_unrestricted_requests(tiny_params):
     assert eng.stats["decode_steps_sorted_sampling"] == 0
 
 
-@pytest.mark.parametrize("speculative,steps_per_sync", [
-    ("none", 1), ("none", 4), ("ngram", 1)])
+@pytest.mark.parametrize("speculative", ["none", "ngram"])
 def test_counter_equals_the_steps_a_restricted_request_was_live(
-        tiny_params, speculative, steps_per_sync):
+        tiny_params, speculative):
     """A long unrestricted request and a short ``top_p < 1`` one: the steps
-    counted are those dispatched while the short one held a slot, in plain,
-    multi-step and speculative rounds."""
-    eng = _engine(tiny_params, speculative=speculative,
-                  steps_per_sync=steps_per_sync)
+    counted are those dispatched while the short one held a slot, in plain
+    and speculative rounds."""
+    eng = _engine(tiny_params, speculative=speculative)
     long = eng.submit([1, 2, 3], SamplingParams(temperature=1.0, max_tokens=12,
                                                 seed=4))
     short = eng.submit([4, 5], SamplingParams(temperature=1.0, top_p=0.9,

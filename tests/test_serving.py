@@ -352,76 +352,33 @@ def test_engine_tp_mesh_validation(tiny_model_and_params):
                                         devices=jax.devices()[:4]))
 
 
-def test_multi_step_decode_matches_single_step(tiny_model_and_params):
-    """steps_per_sync=4 produces identical tokens (greedy AND seeded
-    sampling) to single-step decode, including mid-window EOS handling."""
-    model, params = tiny_model_and_params
-
-    def mk(steps):
-        ec = EngineConfig(max_seqs=2, block_size=8, num_blocks=64,
-                          max_model_len=64, cache_dtype="float32",
-                          eos_token_id=-1, steps_per_sync=steps)
-        return InferenceEngine(CFG, params, ec)
-
-    prompts = [[3, 1, 4, 1, 5, 9], [2, 7, 1, 8, 2, 8]]
-    for sp in (SamplingParams(temperature=0.0, max_tokens=11),
-               SamplingParams(temperature=0.8, top_k=20, seed=7, max_tokens=11)):
-        want = mk(1).generate(prompts, sp)
-        got = mk(4).generate(prompts, sp)
-        for g, w in zip(got, want):
-            assert g.output_token_ids == w.output_token_ids
-            assert g.finish_reason == w.finish_reason
-
-
 def test_warmup_ladder_aot_dispatch_matches_cold(tiny_model_and_params):
-    """warmup_decode_ladder pre-compiles the decode ladder AND keeps the
-    AOT executables on the dispatch path (r04 advisor: lower().compile()
+    """warmup_decode_ladder pre-compiles the decode program AND keeps the
+    AOT executable on the dispatch path (r04 advisor: lower().compile()
     results were discarded, so with the persistent cache disabled the
     warmup silently did nothing). Tokens must match a cold engine, and
     the AOT path must still be live afterwards (no silent fallback)."""
     model, params = tiny_model_and_params
 
-    def mk(steps):
+    def mk():
         ec = EngineConfig(max_seqs=2, block_size=8, num_blocks=64,
                           max_model_len=64, cache_dtype="float32",
-                          eos_token_id=-1, steps_per_sync=steps)
+                          eos_token_id=-1)
         return InferenceEngine(CFG, params, ec)
 
     prompts = [[3, 1, 4, 1, 5, 9], [2, 7, 1, 8]]
     sp = SamplingParams(temperature=0.0, max_tokens=9)
-    want = mk(4).generate(prompts, sp)
+    want = mk().generate(prompts, sp)
 
-    warm = mk(4)
+    warm = mk()
     warm.warmup_decode_ladder()
     warm.warmup_decode_ladder()  # idempotent: re-warm must not crash
     assert hasattr(warm.executor._decode_fn, "_aot_state")
     got = warm.generate(prompts, sp)
     for g, w in zip(got, want):
         assert g.output_token_ids == w.output_token_ids
-    # Every ladder program dispatched through its compiled executable.
+    # The one program dispatched through its compiled executable.
     assert warm.executor._decode_fn._aot_state["aot"]
-    for k, fn in warm.executor._multi_decode_fns.items():
-        assert getattr(fn, "_aot_state", {"aot": True})["aot"], k
-
-
-def test_multi_step_decode_respects_stop_tokens(tiny_model_and_params):
-    """A stop token hit mid-window finishes the request there; later
-    window tokens are discarded."""
-    model, params = tiny_model_and_params
-    ec = EngineConfig(max_seqs=1, block_size=8, num_blocks=32,
-                      max_model_len=64, cache_dtype="float32",
-                      eos_token_id=-1, steps_per_sync=4)
-    engine = InferenceEngine(CFG, params, ec)
-    # Find what greedy generates, then stop on its 2nd token.
-    [probe] = engine.generate([[5, 4, 3]], SamplingParams(temperature=0.0,
-                                                          max_tokens=8))
-    stop_tok = probe.output_token_ids[1]
-    [r] = engine.generate([[5, 4, 3]], SamplingParams(
-        temperature=0.0, max_tokens=8, stop_token_ids=(stop_tok,)))
-    assert r.output_token_ids[-1] == stop_tok
-    assert len(r.output_token_ids) == 2
-    assert r.finish_reason == "stop"
-    assert engine.num_active == 0
 
 
 def test_speculative_ngram_matches_plain_greedy(tiny_model_and_params):
@@ -487,44 +444,15 @@ def test_speculative_mixed_batch_per_slot_gating(tiny_model_and_params):
     assert r2.output_token_ids == p2.output_token_ids
 
 
-def test_speculative_composes_with_multi_step(tiny_model_and_params):
-    """speculative="ngram" + steps_per_sync=4 chains 4 propose→verify
-    rounds in ONE compiled program: emissions match plain greedy exactly
-    and the host syncs far less than once per token."""
-    model, params = tiny_model_and_params
-
-    def mk(spec, steps):
-        return InferenceEngine(CFG, params, EngineConfig(
-            max_seqs=2, block_size=8, num_blocks=128, max_model_len=192,
-            cache_dtype="float32", eos_token_id=-1,
-            speculative="ngram" if spec else "none",
-            steps_per_sync=steps, num_draft_tokens=4, ngram_size=2))
-
-    prompts = [[7, 8, 9, 7, 8, 9, 7, 8], [4, 5, 4, 5, 4, 5, 4]]
-    sp = SamplingParams(temperature=0.0, max_tokens=24)
-    want = mk(False, 1).generate(prompts, sp)
-    eng = mk(True, 4)
-    got = eng.generate(prompts, sp)
-    for g, w in zip(got, want):
-        assert g.output_token_ids == w.output_token_ids
-        np.testing.assert_allclose(g.output_logprobs, w.output_logprobs,
-                                   atol=1e-4)
-    assert eng.stats["spec_accepted"] > 0
-    # 4 rounds/sync and multi-token acceptance: model calls well under
-    # one per emitted token.
-    total = sum(len(r.output_token_ids) for r in got)
-    assert eng.stats["decode_steps"] < total
-
-
 def test_speculative_adaptive_gate_stays_exact(tiny_model_and_params):
     """With an unreachably high acceptance threshold the gate pauses
-    proposing (plain multi-step rounds) and periodically re-probes —
+    proposing (plain rounds) and periodically re-probes —
     outputs stay exactly greedy throughout."""
     model, params = tiny_model_and_params
     ec = EngineConfig(max_seqs=2, block_size=8, num_blocks=128,
                       max_model_len=192, cache_dtype="float32",
                       eos_token_id=-1, speculative="ngram",
-                      steps_per_sync=2, spec_min_acceptance=100.0,
+                      spec_min_acceptance=100.0,
                       spec_probe_window=2, spec_cooldown=3)
     prompts = [[7, 8, 9, 7, 8, 9, 7, 8], [4, 5, 4, 5, 4, 5, 4]]
     sp = SamplingParams(temperature=0.0, max_tokens=24)
@@ -771,82 +699,3 @@ def test_decode_slot_occupancy_stat(tiny_model_and_params):
     assert st["decode_slot_steps"] <= ec.max_seqs * st["decode_steps"]
     assert st["generated_tokens"] <= st["decode_slot_steps"] + len(
         eng.finished)  # +1 prefill-sampled token per request
-
-
-def test_budget_clamped_window_full_occupancy(tiny_model_and_params):
-    """The r03 occupancy lever: with uniform max_tokens, multi-step windows
-    clamp to the smallest remaining budget (halving ladder), so no slot
-    ever idles inside a window — 100% decode-slot occupancy — and the
-    emitted tokens are identical to the unclamped/single-step stream."""
-    model, params = tiny_model_and_params
-    prompts = [[3, 1, 4], [1, 5, 9, 2], [6, 5], [8, 9, 7]]
-
-    def run(sync):
-        ec = EngineConfig(max_seqs=4, block_size=8, num_blocks=64,
-                          max_model_len=48, cache_dtype="float32",
-                          eos_token_id=-1, steps_per_sync=sync)
-        eng = InferenceEngine(CFG, params, ec)
-        res = eng.generate(prompts,
-                           SamplingParams(temperature=0.0, max_tokens=10))
-        return eng, [r.output_token_ids for r in res]
-
-    eng, toks = run(sync=8)
-    ref_eng, ref_toks = run(sync=1)
-    assert toks == ref_toks, "clamped windows changed the token stream"
-
-    st = eng.stats
-    # All 4 slots admitted together with budget 9 after the prefill token:
-    # windows 8 then 1 (ladder), zero dead slot-steps -> 100% occupancy.
-    assert st["decode_slot_steps"] == 4 * st["decode_steps"], st
-
-
-def test_window_never_exceeds_kv_room_near_model_len(tiny_model_and_params):
-    """Round-up windows must round back DOWN under hard KV room: a slot
-    near max_model_len with a large max_tokens budget must finish with a
-    length stop, not overflow its block table (regression: round-up clamp
-    picked k past max_blocks_per_seq)."""
-    model, params = tiny_model_and_params
-    ec = EngineConfig(max_seqs=2, block_size=8, num_blocks=32,
-                      max_model_len=32, cache_dtype="float32",
-                      eos_token_id=-1, steps_per_sync=8)
-    eng = InferenceEngine(CFG, params, ec)
-    prompt = list(range(1, 27))  # 26 tokens, 6 from the model-length stop
-    [res] = eng.generate([prompt], SamplingParams(temperature=0.0,
-                                                  max_tokens=100))
-    assert res.finish_reason == "length"
-    assert len(prompt) + len(res.output_token_ids) <= ec.max_model_len
-
-
-def test_mixed_budget_windows_identical_stream(tiny_model_and_params):
-    """A short-budget request joining a long cohort shrinks the shared
-    window while it lives (round-up ladder) and the engine returns to
-    full windows after it retires — with a token stream identical to
-    single-step decode."""
-    model, params = tiny_model_and_params
-    prompts = [[3, 1, 4], [1, 5, 9, 2], [6, 5]]
-    budgets = [40, 6, 40]
-
-    def run(sync):
-        ec = EngineConfig(max_seqs=3, block_size=8, num_blocks=64,
-                          max_model_len=64, cache_dtype="float32",
-                          eos_token_id=-1, steps_per_sync=sync)
-        eng = InferenceEngine(CFG, params, ec)
-        reqs = [eng.submit(p, SamplingParams(temperature=0.0, max_tokens=b))
-                for p, b in zip(prompts, budgets)]
-        while eng.has_work:
-            eng.step()
-        return eng, [r.output_token_ids for r in reqs]
-
-    eng, toks = run(sync=16)
-    _, ref_toks = run(sync=1)
-    assert toks == ref_toks
-    assert [len(t) for t in toks] == budgets
-    st = eng.stats
-    # Windows shrank for the short slot then recovered: strictly fewer
-    # rounds than single-step decode would need.
-    assert st["decode_steps"] < sum(budgets)
-    # Zero wasted LIVE slot-steps: every counted slot-step produced a
-    # token (prefill supplies each request's first token). Mean occupancy
-    # vs max_seqs is NOT asserted — this workload drains with no waiting
-    # queue, so slots legitimately sit empty at the tail.
-    assert st["decode_slot_steps"] == sum(budgets) - len(prompts), st

@@ -152,6 +152,41 @@ def test_adaptive_ladder_shrinks_draft_len(tiny_params):
     assert fks - {0} == {ec.num_draft_tokens}
 
 
+@pytest.mark.parametrize("k", [4, 2])
+def test_a_speculative_call_is_one_round(tiny_params, k):
+    """One propose -> verify -> accept a program call: what the call hands
+    back has no axis of rounds (tokens and log-probs ``(S, k + 1)``, the
+    three counts ``(S,)``), a slot gets at most k + 1 tokens of it, and a
+    call is one decode step in the books."""
+    ec = _ec(max_seqs=2, num_draft_tokens=k, spec_adaptive=False,
+             spec_min_acceptance=0.0)
+    eng = InferenceEngine(CFG, tiny_params, ec)
+    shapes, launch = [], eng.executor.launch_spec
+
+    def spy(staged, draft):
+        out = launch(staged, draft)
+        shapes.append((draft, [tuple(x.shape) for x in out]))
+        return out
+
+    eng.executor.launch_spec = spy
+    reqs = [eng.submit(p, SamplingParams(temperature=0.0, max_tokens=24))
+            for p in (CYCLIC, ACYCLIC)]
+    grew = 0
+    while eng.has_work:
+        before = [len(r.output_token_ids) for r in reqs]
+        eng.step()
+        grew = max([grew] + [len(r.output_token_ids) - n
+                             for r, n in zip(reqs, before)])
+    S = ec.max_seqs
+    assert shapes and all(
+        s == (k, [(S, k + 1), (S, k + 1), (S,), (S,), (S,)]) for s in shapes)
+    assert 1 < grew <= k + 1          # several tokens a call, one round's
+    assert eng.stats["decode_steps"] == eng.stats["decode_program_calls"]
+    assert eng.stats["decode_steps"] >= len(shapes)
+    assert [r.output_token_ids for r in reqs] == _plain_outputs(
+        tiny_params, [CYCLIC, ACYCLIC], reqs[0].params, max_seqs=2)
+
+
 # ----------------------------------------------------------------------
 # Handoff carry: the controller rides the envelope
 # ----------------------------------------------------------------------
