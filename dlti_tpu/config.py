@@ -48,6 +48,10 @@ class ZeROStage(enum.IntEnum):
     ZERO3 = 3
 
 
+# The mixer kinds of the decoder-hybrid-decoder family's ``layer_pattern``.
+SAMBAY_KINDS = "SDGX"
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     """Llama-family architecture hyperparameters."""
@@ -146,6 +150,15 @@ class ModelConfig:
     # residual, named by character i of the pattern: "M" Mamba-2, "E" routed
     # experts, "*" attention. "" = the Llama block in every layer. A
     # patterned model is built by dlti_tpu.models.build_model.
+    # The decoder-hybrid-decoder family (SambaY, models.sambay) has a mixer
+    # AND a gated MLP a layer, both behind a LayerNorm with bias, and its
+    # own kinds, never mixed with the three above: "S" Mamba-1, "D"
+    # differential attention over the layer's own keys and values (under
+    # the layer's ``layer_windows`` entry), "G" a gated memory unit that
+    # reads the scan output of the last "S" before it
+    # (``shared_memory_layer``), "X" differential cross-attention with a
+    # query projection alone over the pool of the last "D" before it
+    # (``shared_kv_layer``, which sees every key).
     layer_pattern: str = ""
     rope: bool = True  # False: attention applies no rotary embedding
     # Mamba-2 mixer ("M"): d_inner = heads * head_dim; B and C are shared by
@@ -158,6 +171,13 @@ class ModelConfig:
     mamba_conv_kernel: int = 4
     mamba_chunk_size: int = 128  # the prefill scan's block; changes no result
     mamba_state_dtype: str = "float32"
+    # Mamba-1 mixer ("S", models.mamba1): d_inner = ``mamba_expand`` x
+    # hidden_size channels, each with a state of ``mamba_state_size``
+    # values and a time step projected through ``mamba_dt_rank`` values;
+    # ``mamba_conv_kernel`` as above. The gated memory unit ("G") is as
+    # wide as d_inner.
+    mamba_expand: int = 0
+    mamba_dt_rank: int = 0
     # Dropless routed experts ("E", models.moe.HeldExpertsMLP): the router
     # scores all ``moe_num_experts``; this process holds (and computes)
     # experts [moe_held_start, moe_held_start + moe_held_count) — 0 = all —
@@ -252,12 +272,31 @@ class ModelConfig:
                 f"early_exit_threshold {self.early_exit_threshold}: every "
                 f"token runs every pass here (rows that leave at different "
                 f"passes are not implemented); state 1.0")
+        kinds = set(self.layer_pattern)
         if self.layer_pattern and (
                 len(self.layer_pattern) != self.num_layers
-                or set(self.layer_pattern) - set("ME*")):
+                or not (kinds <= set("ME*") or kinds <= set(SAMBAY_KINDS))):
             raise ValueError(
                 f"layer_pattern {self.layer_pattern!r} must name one mixer "
-                f"(M, E or *) for each of num_layers={self.num_layers}")
+                f"(M, E or *; or S, D, G or X, the two sets never mixed) "
+                f"for each of num_layers={self.num_layers}")
+        if self.is_sambay:
+            pattern = self.layer_pattern
+            if "G" in pattern and "S" not in pattern[:pattern.index("G")]:
+                raise ValueError(
+                    f"layer_pattern {pattern!r}: a gated memory unit (G) "
+                    f"reads the scan output of a Mamba-1 layer (S) before it")
+            if "X" in pattern and (
+                    "D" not in pattern[:pattern.index("X")]
+                    or self.window_of_layer(self.shared_kv_layer)):
+                raise ValueError(
+                    f"layer_pattern {pattern!r}: a cross-attention layer "
+                    f"(X) reads the pool of the last D layer before it, "
+                    f"which has to see every key (layer_windows 0 there)")
+            if any(w and k != "D" for w, k in zip(windows, pattern)):
+                raise ValueError(
+                    f"layer_windows {windows}: only a D layer of "
+                    f"{pattern!r} keeps keys of its own under a window")
 
     @property
     def resolved_head_dim(self) -> int:
@@ -305,7 +344,10 @@ class ModelConfig:
 
     @property
     def mamba_inner_size(self) -> int:
-        return self.mamba_num_heads * self.mamba_head_dim
+        """Channels of a state-space mixer: Mamba-2's heads x head_dim,
+        Mamba-1's ``mamba_expand`` x hidden_size."""
+        return (self.mamba_expand * self.hidden_size if self.mamba_expand
+                else self.mamba_num_heads * self.mamba_head_dim)
 
     @property
     def mamba_conv_dim(self) -> int:
@@ -320,7 +362,27 @@ class ModelConfig:
 
     @property
     def has_recurrent_state(self) -> bool:
-        return "M" in self.layer_pattern
+        return "M" in self.layer_pattern or "S" in self.layer_pattern
+
+    @property
+    def is_sambay(self) -> bool:
+        """The decoder-hybrid-decoder family (``models.sambay``)."""
+        return bool(self.layer_pattern) \
+            and set(self.layer_pattern) <= set(SAMBAY_KINDS)
+
+    @property
+    def shared_memory_layer(self) -> Optional[int]:
+        """The "S" layer whose scan output every "G" layer reads: the last
+        before the first "G" (None: no memory unit)."""
+        upto = self.layer_pattern.find("G")
+        return self.layer_pattern.rfind("S", 0, upto) if upto > 0 else None
+
+    @property
+    def shared_kv_layer(self) -> Optional[int]:
+        """The "D" layer whose pool every "X" layer reads: the last before
+        the first "X" (None: no cross-attention layer)."""
+        upto = self.layer_pattern.find("X")
+        return self.layer_pattern.rfind("D", 0, upto) if upto > 0 else None
 
     @property
     def latent_dim(self) -> int:
@@ -391,6 +453,27 @@ class ModelConfig:
             dense = min(self.first_k_dense, self.num_layers)
             total = (v * h + h + self.num_layers * (attn + 2 * h)
                      + dense * 3 * h * m + (self.num_layers - dense) * experts)
+            if include_lm_head and not self.tie_embeddings:
+                total += h * v
+            return total
+        if self.is_sambay:
+            # A mixer, a gated MLP (fc1 holds gate and up) and two
+            # LayerNorms with bias a layer; a final LayerNorm; a tied head.
+            d_in, n, r = (self.mamba_inner_size, self.mamba_state_size,
+                          self.mamba_dt_rank)
+            heads = self.num_heads * hd
+            diff = 4 * hd + 2 * hd  # four lambda vectors, the head norm
+            per_kind = {
+                "S": (h * 2 * d_in + d_in * (self.mamba_conv_kernel + 1)
+                      + d_in * (r + 2 * n) + r * d_in + d_in
+                      + d_in * n + d_in + d_in * h),
+                "D": (h * (heads + 2 * self.num_kv_heads * hd) + heads
+                      + 2 * self.num_kv_heads * hd + heads * h + h + diff),
+                "G": h * d_in + d_in * h,
+                "X": h * heads + heads + heads * h + h + diff,
+            }
+            total = v * h + 2 * h + sum(per_kind[c] + 3 * h * m + 4 * h
+                                        for c in self.layer_pattern)
             if include_lm_head and not self.tie_embeddings:
                 total += h * v
             return total
@@ -1289,6 +1372,20 @@ MODEL_PRESETS: dict = {
         mamba_state_size=16, mamba_chunk_size=8,
         moe_num_experts=8, num_experts_per_tok=3, moe_intermediate_size=48,
         moe_shared_intermediate_size=96, moe_routed_scaling=2.5,
+    ),
+    # Test-scale decoder-hybrid-decoder (structurally phi4flash / SambaY):
+    # Mamba-1, differential attention under a window and over every key,
+    # then gated memory units and cross-attention over layer 7's pool; the
+    # kinds in the published order for 12 layers (mb_per_layer 2, the split
+    # at num_layers // 2).
+    "sambay_tiny": ModelConfig(
+        vocab_size=512, hidden_size=64, intermediate_size=96, num_layers=12,
+        num_heads=8, num_kv_heads=4, max_seq_len=256, remat=False,
+        dtype="float32", param_dtype="float32", tie_embeddings=True,
+        layer_pattern="SDSDSDSDGXGX",
+        layer_windows=(0, 16, 0, 16, 0, 16, 0, 0, 0, 0, 0, 0),
+        rope=False, attention_bias=True, mamba_expand=2,
+        mamba_state_size=8, mamba_dt_rank=4,
     ),
     # Test-scale latent attention (structurally deepseek_v3: MLA over a
     # latent cache, one dense layer, then gated held experts).

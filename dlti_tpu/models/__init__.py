@@ -1,5 +1,7 @@
 """Model zoo: Llama-family transformer in Flax + LoRA grafting, the
-patterned ``nemotron_h`` family (Mamba-2, routed experts, attention) and
+patterned ``nemotron_h`` family (Mamba-2, routed experts, attention), the
+decoder-hybrid-decoder family (``phi4flash``: Mamba-1, differential
+attention, gated memory units and cross-attention over one shared pool) and
 the latent-attention family (``deepseek_v3``: MLA, held gated experts;
 ``xing4_0``: the same round hyper-connected residual streams)."""
 
@@ -11,7 +13,8 @@ def build_model(cfg, lora=None, mesh=None):
     the model class (the trainer, the engine, ``serve.py --random-init``,
     the fleet worker and the benchmark's check all come through here). A
     configuration with a ``kv_lora_rank`` is the latent-attention family;
-    one with neither that nor a ``layer_pattern`` is the Llama family.
+    one with neither that nor a ``layer_pattern`` is the Llama family; the
+    pattern's kinds say which patterned family (``ModelConfig.is_sambay``).
     Hyper-connected residual streams (``hc_mult``, ``models.hyper``) are
     wired into the latent-attention family alone."""
     if cfg.hc_mult and not cfg.kv_lora_rank:
@@ -22,6 +25,10 @@ def build_model(cfg, lora=None, mesh=None):
         from dlti_tpu.models.latent import LatentForCausalLM
 
         return LatentForCausalLM(cfg, lora, mesh)
+    if cfg.is_sambay:
+        from dlti_tpu.models.sambay import SambaYForCausalLM
+
+        return SambaYForCausalLM(cfg, lora, mesh)
     if cfg.layer_pattern:
         from dlti_tpu.models.nemotron_h import NemotronHForCausalLM
 

@@ -133,6 +133,64 @@ EXIT_GATE_BIAS_STD = 1.0
 PREFILL_CALL_TOKENS = 2048
 
 
+def over_paged_cache(cfg: ModelConfig, mesh, window, q, k, v, cache,
+                     positions):
+    """The serving engine's paged cache: this call's keys and values
+    into the layer's pool (``k`` None: the pool is another layer's, which
+    wrote this call's tokens already), then its queries over each row's
+    table. ``(out (b, s, heads, head_dim), the layer's new cache)``."""
+    s = q.shape[1]
+    # Stale/unallocated slots are at logical positions > the query
+    # position, so the explicit-position causal mask hides them.
+    from dlti_tpu.ops.attention import attend_over_cache, walks_cache
+    from dlti_tpu.ops.kv_cache import paged_gather, paged_update, slot_mapping
+
+    nb, blk_size = cache["k"].shape[0], cache["k"].shape[1]
+    if "table_base" in cache:
+        # A window group's table (ops.kv_cache): column 0 is the block that
+        # holds token ``table_base`` of the row, what lies before it has
+        # been released. Keys are stored rotated and the mask reads
+        # differences of positions, so the cache is addressed, and attended
+        # over, in positions counted from there.
+        positions = jnp.where(
+            positions >= 0, positions - cache["table_base"][:, None], -1)
+    slots = slot_mapping(cache["block_tables"], positions, blk_size, nb)
+    new_cache = cache if k is None else paged_update(cache, k, v, slots)
+    # The kernel for decode steps (s == 1); a prefill call walks the
+    # cache in blocks or gathers the row's window (``walks_cache``).
+    path, _ = resolve_paged_decode(
+        cfg.paged_attention_impl,
+        tp_sharded=mesh is not None and mesh.shape.get("tensor", 1) > 1)
+    use_kernel = s == 1 and path != "xla"
+    if use_kernel:
+        # Pallas kernel: reads K/V blocks in place via the block table (no
+        # O(batch*max_len) gather); decode steps only.
+        from dlti_tpu.ops.pallas.paged_attention import (
+            paged_decode_attention,
+        )
+
+        out = paged_decode_attention(
+            q, new_cache["k"], new_cache["v"],
+            cache["block_tables"], positions[:, 0] + 1,
+            k_scale=new_cache.get("k_scale"),
+            v_scale=new_cache.get("v_scale"),
+            window=window,
+            interpret=path == "pallas-interpret",
+        ).astype(q.dtype)
+    elif walks_cache(s, cache["block_tables"].shape[1] * blk_size,
+                     "table_base" in cache):
+        out = attend_over_cache(q, new_cache, cache["block_tables"],
+                                positions, window)
+    else:
+        ck, cv = paged_gather(new_cache, cache["block_tables"], q.shape[-1])
+        out = reference_attention(
+            q, ck.astype(q.dtype), cv.astype(q.dtype),
+            causal=True, q_positions=positions,
+            window=window,
+        )
+    return out, new_cache
+
+
 class LlamaAttention(nn.Module):
     cfg: ModelConfig
     lora: Optional[LoRAConfig] = None
@@ -150,62 +208,8 @@ class LlamaAttention(nn.Module):
         return self.cfg.window_of_layer(self.layer)
 
     def _over_paged_cache(self, q, k, v, cache, positions):
-        """The serving engine's paged cache: this call's keys and values
-        into the layer's pool, then its queries over each row's table.
-        ``(out (b, s, heads, head_dim), the layer's new cache)``."""
-        cfg, window, s = self.cfg, self.window, q.shape[1]
-        # Stale/unallocated slots are at logical positions > the query
-        # position, so the explicit-position causal mask hides them.
-        from dlti_tpu.ops.attention import attend_over_cache, walks_cache
-        from dlti_tpu.ops.kv_cache import paged_gather, paged_update, slot_mapping
-
-        nb, blk_size = cache["k"].shape[0], cache["k"].shape[1]
-        if "table_base" in cache:
-            # A window group's table (ops.kv_cache): column 0 is the
-            # block that holds token ``table_base`` of the row, what
-            # lies before it has been released. Keys are stored
-            # rotated and the mask reads differences of positions, so
-            # the cache is addressed, and attended over, in positions
-            # counted from there.
-            positions = jnp.where(
-                positions >= 0,
-                positions - cache["table_base"][:, None], -1)
-        slots = slot_mapping(cache["block_tables"], positions, blk_size, nb)
-        new_cache = paged_update(cache, k, v, slots)
-        # The kernel for decode steps (s == 1); a prefill call walks the
-        # cache in blocks or gathers the row's window (``walks_cache``).
-        path, _ = resolve_paged_decode(
-            cfg.paged_attention_impl,
-            tp_sharded=(self.mesh is not None
-                        and self.mesh.shape.get("tensor", 1) > 1))
-        use_kernel = s == 1 and path != "xla"
-        if use_kernel:
-            # Pallas kernel: reads K/V blocks in place via the block
-            # table (no O(batch*max_len) gather); decode steps only.
-            from dlti_tpu.ops.pallas.paged_attention import (
-                paged_decode_attention,
-            )
-
-            out = paged_decode_attention(
-                q, new_cache["k"], new_cache["v"],
-                cache["block_tables"], positions[:, 0] + 1,
-                k_scale=new_cache.get("k_scale"),
-                v_scale=new_cache.get("v_scale"),
-                window=window,
-                interpret=path == "pallas-interpret",
-            ).astype(q.dtype)
-        elif walks_cache(s, cache["block_tables"].shape[1] * blk_size,
-                         "table_base" in cache):
-            out = attend_over_cache(q, new_cache, cache["block_tables"],
-                                    positions, window)
-        else:
-            ck, cv = paged_gather(new_cache, cache["block_tables"])
-            out = reference_attention(
-                q, ck.astype(q.dtype), cv.astype(q.dtype),
-                causal=True, q_positions=positions,
-                window=window,
-            )
-        return out, new_cache
+        return over_paged_cache(self.cfg, self.mesh, self.window, q, k, v,
+                                cache, positions)
 
     def _effective_window(self, segment_ids) -> Optional[int]:
         """Sliding window combined with the packed doc-length bound.

@@ -37,6 +37,7 @@ class LoRADense(nn.Module):
     param_dtype: Any = jnp.bfloat16
     lora_param_dtype: Any = jnp.float32
     kernel_init: Callable = nn.initializers.lecun_normal()
+    bias_init: Callable = nn.initializers.zeros
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, deterministic: bool = True,
@@ -55,7 +56,7 @@ class LoRADense(nn.Module):
         y = jnp.dot(x.astype(self.dtype), kernel.astype(self.dtype),
                     preferred_element_type=self.dtype)
         if self.use_bias:
-            bias = self.param("bias", nn.initializers.zeros, (self.features,), self.param_dtype)
+            bias = self.param("bias", self.bias_init, (self.features,), self.param_dtype)
             y = y + bias.astype(self.dtype)
 
         if self.lora_r > 0:

@@ -134,6 +134,50 @@ def _write_rows(pool, slots, rows):
     return pool
 
 
+def _per_row(flags, like):
+    """``flags`` (b,) shaped to broadcast over ``like`` (b, ...)."""
+    return flags.reshape((-1,) + (1,) * (like.ndim - 1))
+
+
+def read_state(cache: dict, positions):
+    """Each row's ``(convolution tail, state)`` out of a recurrent layer's
+    bound entry (``{"conv", "ssm", "state_slots", "own_rows"}``): the pool
+    itself in a decode call (``own_rows``: row i is slot i), else the rows'
+    slots, zero for a row that starts at position 0."""
+    if cache["own_rows"]:
+        return cache["conv"], cache["ssm"]
+    fresh = positions[:, 0] == 0
+    at = jnp.clip(cache["state_slots"], 0, cache["ssm"].shape[0] - 1)
+
+    def rows(pool):
+        got = _read_rows(pool, at)
+        return jnp.where(_per_row(fresh, got), 0, got)
+
+    return rows(cache["conv"]), rows(cache["ssm"])
+
+
+def write_state(cache: dict, tail, state) -> dict:
+    """The entry's pools with each row's new tail and state at its slot; a
+    slot out of range writes nothing."""
+    slots = cache["state_slots"]
+    new = {"conv": tail.astype(cache["conv"].dtype),
+           "ssm": state.astype(cache["ssm"].dtype)}
+    if cache["own_rows"]:
+        live = slots == jnp.arange(slots.shape[0])
+        return {k: jnp.where(_per_row(live, v), v, cache[k])
+                for k, v in new.items()}
+    return {k: _write_rows(cache[k], slots, v) for k, v in new.items()}
+
+
+def last_inputs(full, valid, k: int):
+    """The last ``k`` real inputs of the convolution: rows of ``full``
+    (b, k + s, channels: the old tail, then this call's inputs) from each
+    row's count of real tokens on (padding trails)."""
+    n_real = jnp.sum(valid, axis=1)
+    return jnp.take_along_axis(
+        full, (n_real[:, None] + jnp.arange(k))[:, :, None], axis=1)
+
+
 class Mamba2Mixer(nn.Module):
     cfg: ModelConfig
 
@@ -174,19 +218,8 @@ class Mamba2Mixer(nn.Module):
         dt = jax.nn.softplus(dt.astype(f32) + dt_bias) * valid[..., None]
         dt = dt.reshape(b, s, G, R)
 
-        slots = own = None
         if cache is not None:
-            slots, own = cache["state_slots"], cache["own_rows"]
-            n_slots = cache["ssm"].shape[0]
-            fresh = positions[:, 0] == 0
-            if own:
-                tail, h0 = cache["conv"], cache["ssm"]
-            else:
-                at = jnp.clip(slots, 0, n_slots - 1)
-                tail = jnp.where(fresh[:, None, None], 0,
-                                 _read_rows(cache["conv"], at))
-                h0 = jnp.where(fresh[:, None, None, None], 0,
-                               _read_rows(cache["ssm"], at))
+            tail, h0 = read_state(cache, positions)
             h0 = h0.astype(f32).reshape(b, G, R, P, N)
         else:
             tail = jnp.zeros((b, K - 1, cd), dtype)
@@ -201,10 +234,7 @@ class Mamba2Mixer(nn.Module):
         xs = xs.reshape(b, s, G, R, P)
         b_in = b_in.reshape(b, s, G, N)
         c_in = c_in.reshape(b, s, G, N)
-        # The last K-1 real inputs: rows of `full` from index n_real on.
-        n_real = jnp.sum(valid, axis=1)
-        new_tail = jnp.take_along_axis(
-            full, (n_real[:, None] + jnp.arange(K - 1))[:, :, None], axis=1)
+        new_tail = last_inputs(full, valid, K - 1)
 
         if s == 1:
             dt1, x1 = dt[:, 0], xs[:, 0]                   # (b,G,R) (b,G,R,P)
@@ -225,19 +255,6 @@ class Mamba2Mixer(nn.Module):
         y = (y.reshape(b, s, d_in) * norm_w).astype(dtype)
         out = dense("out_proj", cfg.hidden_size, kernel_init=_OUT_INIT)(y)
 
-        new_cache = None
-        if cache is not None:
-            h = h.reshape(b, H, P, N).astype(cache["ssm"].dtype)
-            new_tail = new_tail.astype(cache["conv"].dtype)
-            if own:
-                live = slots == jnp.arange(b)
-                new_cache = {
-                    "conv": jnp.where(live[:, None, None], new_tail,
-                                      cache["conv"]),
-                    "ssm": jnp.where(live[:, None, None, None], h,
-                                     cache["ssm"])}
-            else:
-                new_cache = {
-                    "conv": _write_rows(cache["conv"], slots, new_tail),
-                    "ssm": _write_rows(cache["ssm"], slots, h)}
+        new_cache = None if cache is None else write_state(
+            cache, new_tail, h.reshape(b, H, P, N))
         return out, new_cache

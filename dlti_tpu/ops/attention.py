@@ -150,10 +150,11 @@ def attend_over_cache(q, layer_cache, block_tables, positions,
     (their lowest position less the window; block 0 without one) to their
     highest position: the trip count is data, so one program serves a
     (rows, tokens) shape whatever the cached context."""
-    from dlti_tpu.ops.kv_cache import paged_gather
+    from dlti_tpu.ops.kv_cache import paged_gather, pool_kv_heads
 
     b, s, num_heads, d = q.shape
-    block_size, kv_heads = layer_cache["k"].shape[1:3]
+    block_size = layer_cache["k"].shape[1]
+    kv_heads = pool_kv_heads(layer_cache, d)
     blocks = max(1, WALK_KEYS // block_size)
     keys = blocks * block_size
     tables = jnp.pad(block_tables,
@@ -172,7 +173,7 @@ def attend_over_cache(q, layer_cache, block_tables, positions,
         def step(j, carry):
             m, l, acc = carry
             k, v = paged_gather(layer_cache, jax.lax.dynamic_slice_in_dim(
-                tables, j * blocks, blocks, axis=1))
+                tables, j * blocks, blocks, axis=1), d)
             k, v = k.astype(q.dtype), v.astype(q.dtype)
             scores = jnp.einsum("bngqd,bkgd->bgqnk", qb, k,
                                 preferred_element_type=jnp.float32) * scale

@@ -16,10 +16,15 @@ kv_lora_rank + qk_rope_head_dim`` rounded up to whole 128-value lanes``)``
 — for latent-attention layers (:func:`init_latent_cache`: a token's row is
 its normed latent and its rotated shared key, written by one scatter and
 addressed by the same block tables, so the allocator and the prefix cache
-see a block id like any other), ``{"conv", "ssm"}`` of shape ``(slots, ...)`` for Mamba-2 layers
-(:func:`init_recurrent_state`; a sequence's state has a fixed size, so it
-is addressed by the decode slot that serves it and needs no allocator),
-and ``{}`` for layers that keep nothing (routed experts).
+see a block id like any other), ``{"conv", "ssm"}`` of shape ``(slots, ...)`` for state-space layers
+(Mamba-2 and Mamba-1; :func:`init_recurrent_state`; a sequence's state has
+a fixed size, so it is addressed by the decode slot that serves it and
+needs no allocator), and ``{}`` for layers that keep nothing (routed
+experts, gated memory units, and cross-attention layers, which read
+another layer's pool as the model hands it to them inside a call). The
+three may stand side by side in one model (``models.sambay``: recurrent
+entries, a window group, a full group and empty entries), and a pool may be
+FUSED (:func:`init_paged_cache`).
 
 ## Paged keys and values
 
@@ -70,14 +75,28 @@ def init_paged_cache(
     num_kv_heads: int,
     head_dim: int,
     dtype=jnp.bfloat16,
+    fused: bool = False,
 ) -> List[dict]:
     """Allocate the physical block pools, one ``{"k", "v"}`` dict per layer.
+
+    ``fused``: pools of ``(num_blocks, block_size, kv_heads * head_dim)``, a
+    token's kv heads side by side in one row. The TPU tiles an array's last
+    two dimensions (16 x 128 for bfloat16), so a 4-D pool of 10 kv heads
+    lies padded to 16 in HBM, and the decode kernel cannot copy a block of
+    it by hand; a fused row of 10 x 128 values is whole lanes and
+    ``block_size`` whole sublanes. :func:`paged_update`, :func:`paged_gather` and the
+    decode kernel tell the layout by the pool's rank.
 
     ``dtype="int8"`` (the string, or ``jnp.int8``) selects the quantized
     pool layout: int8 payloads plus ``{"k_scale", "v_scale"}`` fp32 arrays
     of shape ``(num_blocks, block_size, kv_heads)``.
     """
     shape = (num_blocks, block_size, num_kv_heads, head_dim)
+    if fused:
+        if dtype == "int8" or dtype == jnp.int8:
+            raise ValueError("a fused pool has no int8 layout (a scale a "
+                             "(token, kv head) has no place in a fused row)")
+        shape = (num_blocks, block_size, num_kv_heads * head_dim)
     if dtype == "int8" or dtype == jnp.int8:
         sshape = (num_blocks, block_size, num_kv_heads)
         return [
@@ -94,15 +113,15 @@ def init_paged_cache(
 
 
 def init_recurrent_state(num_slots: int, conv_kernel: int, conv_dim: int,
-                         num_heads: int, head_dim: int, state_size: int,
-                         conv_dtype=jnp.bfloat16,
+                         state_shape: tuple, conv_dtype=jnp.bfloat16,
                          state_dtype=jnp.float32) -> dict:
-    """One Mamba-2 layer's state for every decode slot: the last
-    ``conv_kernel - 1`` inputs of the convolution and the SSM state."""
+    """One state-space layer's state for every decode slot: the last
+    ``conv_kernel - 1`` inputs of the convolution and the SSM state, a
+    sequence's ``state_shape`` (Mamba-2: heads, head_dim, state size;
+    Mamba-1: channels, state size)."""
     return {"conv": jnp.zeros((num_slots, conv_kernel - 1, conv_dim),
                               conv_dtype),
-            "ssm": jnp.zeros((num_slots, num_heads, head_dim, state_size),
-                             state_dtype)}
+            "ssm": jnp.zeros((num_slots, *state_shape), state_dtype)}
 
 
 # Values in one lane row of the TPU's tiled layouts.
@@ -188,30 +207,45 @@ def init_cache(model_cfg, num_blocks: int, block_size: int, num_slots: int,
                                   model_cfg.latent_dim, dtype)
                 for _ in range(model_cfg.num_layers)]
 
-    groups = model_cfg.kv_group_windows
-    if len(groups) > 1:
-        sizes = [num_blocks if not w else window_group_blocks(
-            w, block_size, num_slots, call_tokens)
-            for w in groups]
-        return [init_paged_cache(
-            1, sizes[model_cfg.kv_group_of_layer(i)], block_size,
-            model_cfg.num_kv_heads, model_cfg.resolved_head_dim, dtype)[0]
-            for i in range(model_cfg.num_layers)]
+    # Pools of attention layers, by group: the group that sees every key
+    # (and every model's only group) ``num_blocks`` a pass, a window
+    # group's by its shapes.
+    blocks = [model_cfg.ut_steps * num_blocks if not w or i == 0
+              else window_group_blocks(w, block_size, num_slots, call_tokens)
+              for i, w in enumerate(model_cfg.kv_group_windows)]
+    sambay = model_cfg.is_sambay
+    # (the decoder-hybrid-decoder family pairs its heads: half as many
+    # key-value heads, twice as wide, models.sambay; 10 of them at the
+    # published sizes, so its pools are fused)
+    kv_heads, head_dim = (
+        (model_cfg.num_kv_heads // 2, 2 * model_cfg.resolved_head_dim)
+        if sambay else (model_cfg.num_kv_heads, model_cfg.resolved_head_dim))
 
-    def paged():
-        return init_paged_cache(1, model_cfg.ut_steps * num_blocks, block_size,
-                                model_cfg.num_kv_heads,
-                                model_cfg.resolved_head_dim, dtype)[0]
+    def paged(layer):
+        return init_paged_cache(
+            1, blocks[model_cfg.kv_group_of_layer(layer)], block_size,
+            kv_heads, head_dim, dtype, fused=sambay)[0]
 
-    def recurrent():
+    def recurrent(layer):
+        conv_dim, state = (
+            (model_cfg.mamba_inner_size,
+             (model_cfg.mamba_inner_size, model_cfg.mamba_state_size))
+            if sambay else
+            (model_cfg.mamba_conv_dim,
+             (model_cfg.mamba_num_heads, model_cfg.mamba_head_dim,
+              model_cfg.mamba_state_size)))
         return init_recurrent_state(
-            num_slots, model_cfg.mamba_conv_kernel, model_cfg.mamba_conv_dim,
-            model_cfg.mamba_num_heads, model_cfg.mamba_head_dim,
-            model_cfg.mamba_state_size, resolve_dtype(model_cfg.dtype),
+            num_slots, model_cfg.mamba_conv_kernel, conv_dim, state,
+            resolve_dtype(model_cfg.dtype),
             resolve_dtype(model_cfg.mamba_state_dtype))
 
+    def nothing(layer):
+        return {}
+
     kinds = model_cfg.layer_pattern or "*" * model_cfg.num_layers
-    return [{"*": paged, "M": recurrent, "E": dict}[k]() for k in kinds]
+    make = {"*": paged, "D": paged, "M": recurrent, "S": recurrent,
+            "E": nothing, "G": nothing, "X": nothing}
+    return [make[k](i) for i, k in enumerate(kinds)]
 
 
 # What one program call adds to the layers' entries (:func:`bind_call`).
@@ -231,11 +265,12 @@ def bind_call(cache: List[dict], block_tables, state_slots=None,
     sees every key, ``{"block_tables", "table_base"}`` for a window group,
     whose table starts at the block that holds token ``table_base`` (rows,)
     of each row (what lies before has been released)."""
-    if groups is not None:
-        return [{**c, **block_tables[g]} for c, g in zip(cache, groups)]
-    return [{**c, "block_tables": block_tables,
-             **({"state_slots": state_slots, "own_rows": own_rows}
-                if "ssm" in c else {})} for c in cache]
+    recurrent = {"state_slots": state_slots, "own_rows": own_rows}
+    return [{**c,
+             **({"block_tables": block_tables} if groups is None
+                else block_tables[groups[i]]),
+             **(recurrent if "ssm" in c else {})}
+            for i, c in enumerate(cache)]
 
 
 def unbind_call(cache: List[dict]) -> List[dict]:
@@ -276,9 +311,16 @@ def paged_update(layer_cache: dict, k_new: jnp.ndarray, v_new: jnp.ndarray,
     flat physical slot ids from :func:`slot_mapping`.
     """
     k_pool, v_pool = layer_cache["k"], layer_cache["v"]
-    nb, bs, kvh, hd = k_pool.shape
     flat = slots.reshape(-1)
     out = dict(layer_cache)
+    if k_pool.ndim == 3:  # fused: a token's row is its kv heads side by side
+        nb, bs, width = k_pool.shape
+        for name, pool, new in (("k", k_pool, k_new), ("v", v_pool, v_new)):
+            out[name] = pool.reshape(nb * bs, width).at[flat].set(
+                new.reshape(-1, width).astype(pool.dtype),
+                mode="drop").reshape(nb, bs, width)
+        return out
+    nb, bs, kvh, hd = k_pool.shape
     if k_pool.dtype == jnp.int8:
         kq, ks = _quantize_rows(k_new)
         vq, vs = _quantize_rows(v_new)
@@ -300,8 +342,16 @@ def paged_update(layer_cache: dict, k_new: jnp.ndarray, v_new: jnp.ndarray,
     return out
 
 
-def paged_gather(layer_cache: dict, block_tables: jnp.ndarray):
-    """Gather each sequence's logical KV window from the pool.
+def pool_kv_heads(layer_cache: dict, head_dim: int) -> int:
+    """Key-value heads of a layer's pool, fused or not."""
+    pool = layer_cache["k"]
+    return pool.shape[2] // head_dim if pool.ndim == 3 else pool.shape[2]
+
+
+def paged_gather(layer_cache: dict, block_tables: jnp.ndarray,
+                 head_dim: int = 0):
+    """Gather each sequence's logical KV window from the pool
+    (``head_dim``: the heads' width, for a fused pool to be read by).
 
     Returns (k, v) of shape (batch, max_blocks*block_size, kv_heads, head_dim)
     in logical order; garbage beyond a sequence's written length is masked by
@@ -310,8 +360,12 @@ def paged_gather(layer_cache: dict, block_tables: jnp.ndarray):
     fp32 paths don't pay an extra bf16 rounding step on the way through.
     """
     k_pool, v_pool = layer_cache["k"], layer_cache["v"]
-    nb, bs, kvh, hd = k_pool.shape
     b, max_blk = block_tables.shape
+    if k_pool.ndim == 3:
+        return tuple(pool[block_tables].reshape(
+            b, max_blk * pool.shape[1], -1, head_dim)
+            for pool in (k_pool, v_pool))
+    nb, bs, kvh, hd = k_pool.shape
     k = k_pool[block_tables].reshape(b, max_blk * bs, kvh, hd)
     v = v_pool[block_tables].reshape(b, max_blk * bs, kvh, hd)
     if k_pool.dtype == jnp.int8:

@@ -138,7 +138,7 @@ def live_tiles(seq_lens, keys: int, window: int, steps: int):
 def _decode_kernel(seq_lens_ref, block_tables_ref, row_ref, tile_ref,
                    total_ref, q_ref, k_hbm, v_hbm, *rest,
                    scale: float, block_size: int, tile: int, window: int,
-                   kv_heads: int, quantized: bool):
+                   kv_heads: int, quantized: bool, fused: bool = False):
     if quantized:
         # int8 pools travel with their fp32 scales, already gathered by the
         # table and laid flat: one (1, keys * kv_heads) row a tile, in the
@@ -193,15 +193,21 @@ def _decode_kernel(seq_lens_ref, block_tables_ref, row_ref, tile_ref,
         start(0, 0)
 
     # Rows of a tile are (token, kv head) pairs, as the pool lays them out:
-    # query head h meets the rows of its own kv head alone.
+    # query head h meets the rows of its own kv head alone. (A fused pool
+    # lays a token's kv heads side by side in one row: its tile is read a
+    # head's lanes at a time, one below the other, rows (kv head, token).)
     shape = (num_heads, keys * kv_heads)
     col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    own_head = col % kv_heads == \
-        jax.lax.broadcasted_iota(jnp.int32, shape, 0) // hpg
-    token = col // kv_heads
+    group = jax.lax.broadcasted_iota(jnp.int32, shape, 0) // hpg
+    own_head = (col // keys if fused else col % kv_heads) == group
+    token = col % keys if fused else col // kv_heads
 
     def flat(buf, slot):
-        x = buf[slot].astype(jnp.float32)                  # (keys, kvh, d)
+        x = buf[slot].astype(jnp.float32)        # (keys, kvh, d) or fused
+        if fused:
+            d = x.shape[-1] // kv_heads
+            return jnp.concatenate(
+                [x[:, h * d:(h + 1) * d] for h in range(kv_heads)], axis=0)
         return x.reshape(keys * kv_heads, x.shape[-1])
 
     def step(i, carry):
@@ -277,7 +283,11 @@ def paged_decode_attention(
 
     Args:
       q: ``(batch, 1, num_heads, head_dim)`` current-step queries.
-      k_pool / v_pool: ``(num_blocks, block_size, kv_heads, head_dim)``.
+      k_pool / v_pool: ``(num_blocks, block_size, kv_heads, head_dim)``, or
+        FUSED ``(num_blocks, block_size, kv_heads * head_dim)``: a token's
+        kv heads side by side in one row (``ops.kv_cache.init_paged_cache``:
+        the layout of a model whose count of kv heads the 4-D tiling would
+        pad; ``head_dim`` is the queries').
       block_tables: ``(batch, max_blocks_per_seq)`` int32; entries for
         unallocated logical blocks may be any value (they are never read:
         only live blocks of a row are copied).
@@ -294,7 +304,9 @@ def paged_decode_attention(
     """
     batch, s1, num_heads, head_dim = q.shape
     assert s1 == 1, f"decode kernel takes single-token queries, got s={s1}"
-    num_blocks, block_size, kv_heads, _ = k_pool.shape
+    num_blocks, block_size = k_pool.shape[:2]
+    fused = k_pool.ndim == 3
+    kv_heads = k_pool.shape[2] // head_dim if fused else k_pool.shape[2]
     max_blocks = block_tables.shape[1]
     window = window or 0
     T = tile_blocks(block_size, max_blocks,
@@ -318,9 +330,11 @@ def paged_decode_attention(
     in_hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
     qo = (batch, num_heads, head_dim)
     operands = [q[:, 0], k_pool, v_pool]
-    buffers = [pltpu.VMEM((2, keys, kv_heads, head_dim), k_pool.dtype),
-               pltpu.VMEM((2, keys, kv_heads, head_dim), v_pool.dtype)]
+    buffers = [pltpu.VMEM((2, keys, *k_pool.shape[2:]), k_pool.dtype),
+               pltpu.VMEM((2, keys, *v_pool.shape[2:]), v_pool.dtype)]
     if quantized:
+        if fused:
+            raise ValueError("a fused pool has no int8 layout")
         # The scales of every row's tiles, gathered by the table here: a
         # (block_size, kv_heads) block of them is no shape to copy by hand
         # (its minor dimension is padded to 128 lanes in HBM).
@@ -332,7 +346,8 @@ def paged_decode_attention(
 
     kernel = functools.partial(_decode_kernel, scale=head_dim ** -0.5,
                                block_size=block_size, tile=T, window=window,
-                               kv_heads=kv_heads, quantized=quantized)
+                               kv_heads=kv_heads, quantized=quantized,
+                               fused=fused)
     call = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

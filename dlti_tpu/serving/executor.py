@@ -93,6 +93,9 @@ def refuse_unsupported(model_cfg: ModelConfig, engine_cfg: "EngineConfig",
         return
     ec = engine_cfg
     what = (f"a model with layer_pattern {model_cfg.layer_pattern!r}"
+            + (" (Mamba-1 layers, paired heads for differential attention, "
+               "one pool that several layers read)"
+               if model_cfg.is_sambay else "")
             if model_cfg.layer_pattern else
             f"a model with latent attention (kv_lora_rank "
             f"{model_cfg.kv_lora_rank})")
@@ -112,6 +115,12 @@ def refuse_unsupported(model_cfg: ModelConfig, engine_cfg: "EngineConfig",
                 f"(serving/prefix_tiers.py). Serve it with "
                 f"--enable-prefix-caching alone (the HBM tier treats a "
                 f"latent block like any other)")
+    if model_cfg.is_sambay and ec.cache_dtype == "int8":
+        raise ValueError(
+            f"{what} keeps a key row as two heads' keys side by side, which "
+            f"one int8 scale a row would round together, and no reference "
+            f"has been held against that; serve it with --kv-cache-dtype "
+            f"bfloat16")
     if model_cfg.has_recurrent_state:
         why = (f"{what} keeps a recurrent state per decode slot beside its "
                f"k/v blocks, and ")
@@ -132,13 +141,13 @@ def refuse_unsupported(model_cfg: ModelConfig, engine_cfg: "EngineConfig",
             f"reference). Serve it with --speculative none")
     if mesh is not None:
         raise ValueError(
-            f"{what} has no tensor-parallel sharding rules (Mamba-2, "
-            f"latent-attention and held-expert layers); serve it on one "
-            f"chip per replica")
+            f"{what} has no tensor-parallel sharding rules (state-space, "
+            f"latent-attention and held-expert layers, tables a group of "
+            f"layers); serve it on one chip per replica")
     if ec.quantization != "none":
         raise ValueError(
             f"{what} is served in its own precision: weight-only "
-            f"{ec.quantization} is not implemented for Mamba-2, "
+            f"{ec.quantization} is not implemented for state-space, "
             f"latent-attention and expert layers")
     if ec.adapter_slots > 0:
         raise ValueError(

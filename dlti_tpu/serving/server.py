@@ -1357,6 +1357,10 @@ class _Handler(BaseHTTPRequestHandler):
         emitted = ""
         finish = None
         meter = _HandlerMeter.of_this_thread()
+        # The whole list is decoded at every token unless the tokenizer
+        # can extend its own text by one token (``decode_appended``).
+        append = getattr(self.tokenizer, "decode_appended", None)
+        decoded = ""
         try:
             if chat:
                 chunk(json.dumps({
@@ -1375,7 +1379,9 @@ class _Handler(BaseHTTPRequestHandler):
                         # the final usage chunk reads a settled request.
                         continue
                     token_ids.append(ev[1])
-                    text = self.tokenizer.decode(token_ids)
+                    decoded = text = append(decoded, token_ids) \
+                        if append is not None \
+                        else self.tokenizer.decode(token_ids)
                     if stops:
                         # Stop strings: emit only up to the earliest match
                         # (the stop string itself is never streamed), and

@@ -114,10 +114,44 @@ def adapter_pool_bytes(cfg: ModelConfig, num_slots: int, rank: int = 16,
     return (num_slots + 1) * cfg.num_layers * per_row * 4
 
 
+def _pool_layers(cfg: ModelConfig, group: int) -> int:
+    """Layers that keep a block pool in ``group`` of ``kv_group_windows``
+    (0: the group whose pools have ``--num-blocks``): attention layers with
+    keys of their own. A looped stack keeps an entry a pass."""
+    kinds = cfg.layer_pattern or "*" * cfg.num_layers
+    return cfg.ut_steps * sum(
+        k in "*D" and cfg.kv_group_of_layer(i) == group
+        for i, k in enumerate(kinds))
+
+
+def _kv_row_bytes(cfg: ModelConfig, kv_dtype: str) -> int:
+    """K + V bytes of one token in ONE pool (the decoder-hybrid-decoder
+    family's paired heads are the same count of values, fused)."""
+    return 2 * cfg.num_kv_heads * cfg.resolved_head_dim \
+        * _dtype_bytes(kv_dtype)
+
+
 def kv_bytes_per_token(cfg: ModelConfig, kv_dtype: str = "bfloat16") -> int:
-    """K + V bytes one token holds resident across all layers."""
-    return (2 * cfg.num_layers * cfg.num_kv_heads * cfg.resolved_head_dim
-            * _dtype_bytes(kv_dtype))
+    """K + V bytes one token holds resident across the pools of
+    ``--num-blocks``: every attention layer with keys of its own that sees
+    every key (layers that read another layer's pool, state-space layers,
+    memory units and experts hold none; a window group's pools apart)."""
+    if cfg.latent_dim:
+        return cfg.num_layers * cfg.latent_dim * _dtype_bytes(kv_dtype)
+    return _pool_layers(cfg, 0) * _kv_row_bytes(cfg, kv_dtype)
+
+
+def recurrent_state_bytes_per_slot(cfg: ModelConfig) -> int:
+    """One sequence's recurrent state over the state-space layers: the
+    convolution tail in the compute dtype, the state in
+    ``mamba_state_dtype``."""
+    tail, state = _dtype_bytes(cfg.dtype), _dtype_bytes(cfg.mamba_state_dtype)
+    k = cfg.mamba_conv_kernel - 1
+    d_in, n = cfg.mamba_inner_size, cfg.mamba_state_size
+    return (cfg.layer_pattern.count("M") * (k * cfg.mamba_conv_dim * tail
+                                            + d_in * n * state)
+            + cfg.layer_pattern.count("S") * (k * d_in * tail
+                                              + d_in * n * state))
 
 
 def plan_training(cfg: ModelConfig, param_dtype: Optional[str] = None,
@@ -154,6 +188,7 @@ def plan_training(cfg: ModelConfig, param_dtype: Optional[str] = None,
 def plan_serving(cfg: ModelConfig, param_dtype: Optional[str] = None,
                  kv_dtype: str = "bfloat16", num_blocks: int = 256,
                  block_size: int = 16, max_model_len: int = 0,
+                 max_seqs: int = 0, call_tokens: int = 2048,
                  budget_bytes: int = 0, adapter_slots: int = 0,
                  adapter_rank: int = 16,
                  adapter_targets: tuple = ("q_proj", "k_proj",
@@ -170,6 +205,21 @@ def plan_serving(cfg: ModelConfig, param_dtype: Optional[str] = None,
         "params": (n + cfg.held_pad_params) * pbytes,
         "kv_block_pool": per_tok * block_size * num_blocks,
     }
+    # What is sized by the decode slots (``max_seqs``, the engine's
+    # --max-seqs): a window group's pools (ops.kv_cache.window_group_blocks;
+    # ``call_tokens``: the family's widest prefill call) and the recurrent
+    # state of state-space layers.
+    groups = cfg.kv_group_windows
+    if max_seqs and len(groups) > 1:
+        from dlti_tpu.ops.kv_cache import window_group_blocks
+
+        owners["window_block_pools"] = (
+            _pool_layers(cfg, 1) * _kv_row_bytes(cfg, kv_dtype) * block_size
+            * window_group_blocks(groups[1], block_size, max_seqs,
+                                  call_tokens))
+    if max_seqs and cfg.has_recurrent_state:
+        owners["recurrent_state_pool"] = \
+            max_seqs * recurrent_state_bytes_per_slot(cfg)
     if adapter_slots > 0:
         owners["lora_adapters"] = adapter_pool_bytes(
             cfg, adapter_slots, adapter_rank, adapter_targets)
@@ -230,6 +280,10 @@ def main() -> None:
     ap.add_argument("--num-blocks", type=int, default=256)
     ap.add_argument("--block-size", type=int, default=16)
     ap.add_argument("--max-model-len", type=int, default=0)
+    ap.add_argument("--max-seqs", type=int, default=0,
+                    help="decode slots (engine --max-seqs): sizes a window "
+                         "group's pools and the recurrent state (0 = leave "
+                         "both out)")
     ap.add_argument("--lora-r", type=int, default=0,
                     help="LoRA rank: trainable = adapters only "
                          "(0 = full fine-tune)")
@@ -254,7 +308,7 @@ def main() -> None:
                          kv_dtype=args.kv_dtype, num_blocks=args.num_blocks,
                          block_size=args.block_size,
                          max_model_len=args.max_model_len,
-                         budget_bytes=budget,
+                         max_seqs=args.max_seqs, budget_bytes=budget,
                          adapter_slots=args.adapter_slots,
                          adapter_rank=args.adapter_rank,
                          adapter_targets=tuple(

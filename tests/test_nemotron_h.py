@@ -125,7 +125,7 @@ def _state(cfg, slots=4):
 
     return init_recurrent_state(
         slots, cfg.mamba_conv_kernel, cfg.mamba_conv_dim,
-        cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.mamba_state_size,
+        (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.mamba_state_size),
         jnp.float32, jnp.float32)
 
 

@@ -448,6 +448,64 @@ def test_loadgen_against_live_server(id_tok_server):
     assert report.output_tokens_per_s > 0
 
 
+@pytest.mark.parametrize("n", [1, 2, 17, 448])
+def test_id_tokenizer_extends_its_text_a_token_at_a_time(n):
+    """``decode_appended`` is what lets a stream pay one piece a token:
+    folded over an answer it has to be ``decode`` of every prefix."""
+    from dlti_tpu.data.tokenizer import ByteTokenizer, IdTokenizer
+
+    tok = IdTokenizer(vocab_size=200064)
+    ids = [(7919 * i + 3) % 200064 for i in range(n)]
+    text = ""
+    for k in range(1, n + 1):
+        text = tok.decode_appended(text, ids[:k])
+        assert text == tok.decode(ids[:k])
+    # a character of the byte tokenizer may span tokens: it offers none
+    assert not hasattr(ByteTokenizer(), "decode_appended")
+
+
+def _streamed(host, port, body):
+    conn = http.client.HTTPConnection(host, port, timeout=120)
+    conn.request("POST", "/v1/completions",
+                 json.dumps({**body, "stream": True}),
+                 {"Content-Type": "application/json"})
+    raw = conn.getresponse().read().decode()
+    conn.close()
+    events = [json.loads(l[6:]) for l in raw.splitlines()
+              if l.startswith("data: ") and l != "data: [DONE]"]
+    return ([e["choices"][0]["text"] for e in events],
+            [e["choices"][0]["finish_reason"] for e in events][-1])
+
+
+@pytest.mark.parametrize("with_stop", [False, True], ids=["plain", "stop"])
+def test_a_stream_extended_by_the_token_is_the_whole_answers_text(
+        id_tok_server, with_stop):
+    """The handler extends the id tokenizer's text by one token an event
+    (no decode of the whole list): the deltas still add up to the text
+    the whole answer decodes to, a delta a token, and a stop string cuts
+    where it cuts the whole text."""
+    host, port = id_tok_server
+    body = {"prompt": "<5> <9> <11>", "max_tokens": 12, "temperature": 0.0}
+    status, data = _post(host, port, "/v1/completions", body)
+    assert status == 200
+    full = json.loads(data)["choices"][0]["text"]
+    pieces = full.split(" ")
+    assert len(pieces) == 12 and all(p.startswith("<") for p in pieces)
+    if not with_stop:
+        deltas, finish = _streamed(host, port, body)
+        assert [d for d in deltas if d] == \
+            [pieces[0]] + [" " + p for p in pieces[1:]]
+        assert finish == "length"
+        return
+    # the first piece that did not occur before it, from its middle on and
+    # over the token boundary: held back, then matched across two events
+    at = next(i for i in range(3, 11) if pieces[i] not in pieces[:i])
+    stop = pieces[at][2:] + " " + pieces[at + 1][:2]
+    deltas, finish = _streamed(host, port, {**body, "stop": stop})
+    assert "".join(deltas) == full[:full.find(stop)]
+    assert finish == "stop"
+
+
 def test_native_allocator_contract(tmp_path):
     """C++ allocator obeys the same contract as the Python fallback; the
     loader builds it from native/*.cc when it is missing or stale."""

@@ -237,6 +237,12 @@ DEVICE_SCOPE_CATALOG = frozenset({
     # scans: the loop index is traced, so the passes share the one scope
     # and a profile shows it ``ut_steps`` times a step (PR 49).
     "dlti_loop_pass_u",
+    # The decoder-hybrid-decoder family's mixers (models.sambay, PR 53): a
+    # Mamba-1 layer, the differential combine after the attention kernel
+    # (subtraction, head norm, 1 - lam0), a gated memory unit, and a
+    # cross-attention layer over the shared pool (its kernel call and its
+    # combine inside it).
+    "dlti_mamba1", "dlti_diff_attention", "dlti_gmu", "dlti_cross_attention",
 })
 
 
@@ -263,6 +269,8 @@ def _scope_literals():
 def test_every_device_scope_name_is_pinned():
     found = _scope_literals()
     assert "dlti_loop_pass_u" in found and "dlti_attn_full" in found
+    assert {"dlti_mamba1", "dlti_diff_attention", "dlti_gmu",
+            "dlti_cross_attention"} <= found
     assert found == DEVICE_SCOPE_CATALOG, (
         sorted(found - DEVICE_SCOPE_CATALOG),
         sorted(DEVICE_SCOPE_CATALOG - found))
