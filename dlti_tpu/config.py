@@ -124,6 +124,14 @@ class ModelConfig:
     # gradient-checkpointing default). Spends HBM headroom to cut the
     # recompute forward: stride k removes 1/k of it.
     remat_stride: int = 1
+    # How many blocks (the LAST ones: the backward frees theirs first, so
+    # the step's peak stays at the loss) keep their activations although
+    # ``remat`` is on. Not a user's knob. None: nobody has said, so a model
+    # built from this keeps none and the trainer builds its own with the
+    # count the device has room for (``training.remat_plan``); a number is
+    # held to (scripts/train.py states 0 beside a stated --remat-policy or
+    # --remat-stride).
+    remat_keep_blocks: Optional[int] = None
     attention_impl: str = "auto"  # "auto" | "reference" | "flash"
     # Flash kernel tiles. flash_block_q counts query ROWS across the GQA
     # group (the kernel flattens a kv head's query heads into the row

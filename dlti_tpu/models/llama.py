@@ -672,10 +672,12 @@ class LlamaModel(nn.Module):
             # Selective remat: every remat_stride-th block keeps its
             # activations instead of recomputing them in the backward —
             # stride k trades ~1/k of the recompute forward for that
-            # fraction of saved activations in HBM.
+            # fraction of saved activations in HBM. So do the last
+            # remat_keep_blocks (the trainer's count of what fits).
             cls_i = block_cls
-            if (cfg.remat and cache is None and cfg.remat_stride > 1
-                    and i % cfg.remat_stride == 0):
+            if cfg.remat and cache is None and (
+                    (cfg.remat_stride > 1 and i % cfg.remat_stride == 0)
+                    or i >= cfg.num_layers - (cfg.remat_keep_blocks or 0)):
                 cls_i = LlamaBlock
             layer_cache = cache[i] if cache is not None else None
             x, layer_new_cache, *counted = cls_i(

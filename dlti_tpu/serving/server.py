@@ -277,7 +277,8 @@ def build_registry(async_engine: "AsyncEngine") -> MetricsRegistry:
     from dlti_tpu.telemetry import memledger as _ml
 
     for metric in (_ml.hbm_bytes_gauge, _ml.hbm_peak_gauge,
-                   _ml.hbm_headroom_gauge, _ml.hbm_untracked_gauge):
+                   _ml.hbm_headroom_gauge, _ml.hbm_untracked_gauge,
+                   _ml.remat_kept_blocks_gauge):
         registry.register(metric)
     # SLO engine (telemetry.slo): compliance / error-budget / burn-rate
     # gauges — module-level like the watchdog/flight counters, populated

@@ -98,6 +98,10 @@ hbm_headroom_gauge = Gauge(
 hbm_untracked_gauge = Gauge(
     MEMLEDGER_METRIC_NAMES[3],
     help="live device bytes owned by no registered owner")
+# What the trainer spends its headroom on (training.remat_plan).
+remat_kept_blocks_gauge = Gauge(
+    "dlti_remat_kept_blocks",
+    help="blocks whose activations the train step keeps (the rest remat)")
 
 
 # ----------------------------------------------------------------------
@@ -244,6 +248,7 @@ class MemoryLedger:
         self._peak = 0
         self._owner_peaks: Dict[str, int] = {}
         self._activation: Dict[str, int] = {}
+        self._remat_plan: Dict[str, int] = {}
 
     # -- wiring ---------------------------------------------------------
     def register(self, owner: str, handle: Any) -> None:
@@ -292,6 +297,14 @@ class MemoryLedger:
             for k, v in info.items():
                 if isinstance(v, int):
                     self._activation[k] = max(self._activation.get(k, 0), v)
+
+    def note_remat_plan(self, fields: Dict[str, int]) -> None:
+        """The trainer's kept-block plan (``training.remat_plan``: blocks
+        kept, planned, limit and a block's bytes), as it stands now."""
+        if not self.enabled:
+            return
+        with self._lock:
+            self._remat_plan = dict(fields)
 
     # -- snapshot -------------------------------------------------------
     def _materialize(self) -> Dict[str, List[Any]]:
@@ -419,6 +432,7 @@ class MemoryLedger:
                                            d["bytes"])
             owner_peaks = dict(self._owner_peaks)
             activation = dict(self._activation)
+            remat = dict(self._remat_plan)
 
         snap = {
             "source": source,
@@ -434,6 +448,7 @@ class MemoryLedger:
             "owner_peak_bytes": owner_peaks,
             "buckets": buckets,
             "activation_peak": activation,
+            "remat_plan": remat,
             "device_stats": dev_stats,
             "num_live_arrays": len(live),
         }

@@ -15,6 +15,7 @@ One class drives what the reference spreads across four scripts
 
 from __future__ import annotations
 
+import dataclasses
 import json
 import os
 import sys
@@ -44,6 +45,7 @@ from dlti_tpu.telemetry import memledger as memledger_mod
 from dlti_tpu.telemetry.memledger import (
     MemoryLedger, executable_memory_analysis, is_oom_error,
 )
+from dlti_tpu.training import remat_plan
 from dlti_tpu.training.optimizer import build_optimizer
 from dlti_tpu.training.state import TrainState, create_train_state
 from dlti_tpu.training.step import make_train_step
@@ -213,9 +215,13 @@ class Trainer:
         # The model needs the mesh for sequence parallelism: with
         # parallel.sequence > 1 attention runs the ring schedule
         # (dlti_tpu.parallel.ring_attention) over the 'sequence' axis.
+        # A model of the caller's is trained as it was built; ours is
+        # rebuilt once the kept-block count is known (plan_remat).
+        self._own_model = model is None
         self.model = model or build_model(
             cfg.model, cfg.lora if cfg.lora.enabled else None, self.mesh
         )
+        self.remat_plan: Optional[remat_plan.RematPlan] = None
         self._step_fn = None
         self._ckpt_mgr = None
         # Preemption flag: set by SIGTERM (cluster eviction) or
@@ -415,6 +421,46 @@ class Trainer:
             scaler=(jax.tree_util.tree_map(lambda _: repl, state.scaler)
                     if state.scaler is not None else None))
 
+    def plan_remat(self, state) -> remat_plan.RematPlan:
+        """How many blocks may keep their activations on one device that
+        holds its share of ``state`` (arrays, or shapes with shardings):
+        ``training.remat_plan``'s arithmetic against the device's limit
+        (the telemetry budget where one is stated)."""
+        if not self._own_model:
+            return remat_plan.RematPlan(
+                0, self.cfg.model.num_layers, why_not="the caller's model")
+
+        def on_a_device(leaf) -> int:
+            shape = leaf.shape
+            sharding = getattr(leaf, "sharding", None)
+            if sharding is not None:
+                shape = sharding.shard_shape(shape)
+            return int(np.prod(shape, dtype=np.int64))
+
+        leaves = [x for x in jax.tree_util.tree_leaves(state)
+                  if hasattr(x, "shape") and hasattr(x, "dtype")]
+        held = sum(on_a_device(x) * x.dtype.itemsize for x in leaves)
+        # float32 gradients of what trains, beside the state
+        held += 4 * sum(on_a_device(x) for x in jax.tree_util.tree_leaves(
+            state.trainable_and_frozen()[0]))
+        limit = self.cfg.telemetry.hbm_budget_bytes or next(
+            (d["bytes_limit"] for d in
+             memledger_mod.device_bytes_in_use().values()
+             if d.get("bytes_limit")), 0)
+        return remat_plan.plan(self.cfg, held, limit)
+
+    def adopt_remat_plan(self, plan: remat_plan.RematPlan) -> None:
+        """Build the model that keeps ``plan.keep_blocks`` blocks (the
+        tree of parameters is the same whatever the count)."""
+        self.remat_plan = plan
+        memledger_mod.remat_kept_blocks_gauge.set(plan.keep_blocks)
+        if self._own_model:
+            cfg = self.cfg
+            self.model = build_model(
+                dataclasses.replace(cfg.model,
+                                    remat_keep_blocks=plan.keep_blocks),
+                cfg.lora if cfg.lora.enabled else None, self.mesh)
+
     def _build_step(self, state: TrainState):
         if self.mesh is not None and self.cfg.parallel.pipe > 1:
             from dlti_tpu.parallel.pipeline import make_pipeline_train_step
@@ -574,6 +620,20 @@ class Trainer:
                         "not match the original run's",
                         resume_meta.get("seed"), cfg.train.seed)
 
+        # How many blocks keep their activations, from what this device
+        # holds now and its limit; one compile in the normal case. A
+        # program the compiler then refuses for memory steps the count
+        # down (stepped_down_after, at the program's first call).
+        first_row: dict = {}  # joins the step log's first row
+
+        def adopt(plan):
+            self.adopt_remat_plan(plan)
+            memledger.note_remat_plan(plan.scalars())
+            first_row.update(plan.scalars())
+
+        adopt(self.plan_remat(state))
+        if is_main_process():
+            self.logger.info(self.remat_plan.line())
         step_fn = self._build_step(state)
         sync_k = max(1, int(cfg.train.steps_per_sync))
         multi_fn = None
@@ -990,6 +1050,41 @@ class Trainer:
                 return
             memledger.note_activation_peak(info)
 
+        def stepped_down_after(exc, state) -> bool:
+            """A program refused for memory before it ran, with blocks
+            kept: keep one fewer, build the programs again, say so. False
+            for any other fault (and for one that took the donated state
+            with it: nothing is left to train)."""
+            nonlocal step_fn, multi_fn
+            plan = self.remat_plan
+            if not (plan.keep_blocks and is_oom_error(exc)) or any(
+                    x.is_deleted() for x in jax.tree_util.tree_leaves(state)
+                    if isinstance(x, jax.Array)):
+                return False
+            adopt(plan.stepped_down())
+            self.logger.warning(
+                "remat: the step that keeps %d blocks was refused its "
+                "memory (%s); keeping %d", plan.keep_blocks,
+                str(exc).strip().splitlines()[0][:200],
+                self.remat_plan.keep_blocks)
+            step_fn = self._build_step(state)
+            if multi_fn is not None:
+                from dlti_tpu.training.step import make_multi_step
+
+                multi_fn = make_multi_step(step_fn)
+            return True
+
+        def run_stepping_down(program, state, *inputs):
+            """``program()(state, *inputs)``; the thunk because a program
+            the compiler refuses for memory is built again with a block
+            fewer kept."""
+            while True:
+                try:
+                    return program()(state, *inputs)
+                except Exception as exc:
+                    if not stepped_down_after(exc, state):
+                        raise
+
         def exec_steps(state, items):
             """Classic path: one compiled call + host sync per step."""
             executed = []
@@ -1002,7 +1097,8 @@ class Trainer:
                 fnote(phase="step_dispatch")
                 ledger.enter("step_compute")
                 with tracer.span("train/step_dispatch", cat="train"):
-                    state, m = step_fn(state, gb, r)
+                    state, m = run_stepping_down(lambda: step_fn, state,
+                                                 gb, r)
                 fnote(phase="device_sync")
                 ledger.enter("device_sync")
                 with tracer.span("train/device_sync", cat="train"):
@@ -1032,7 +1128,8 @@ class Trainer:
                 ledger.enter("step_compute")
                 with tracer.span("train/step_dispatch", cat="train",
                                  window=k):
-                    state, mstack = multi_fn(state, stacked, rngs)
+                    state, mstack = run_stepping_down(
+                        lambda: multi_fn, state, stacked, rngs)
                 fnote(phase="device_sync")
                 ledger.enter("device_sync")
                 with tracer.span("train/device_sync", cat="train"):
@@ -1211,7 +1308,9 @@ class Trainer:
                             mem_scalars.get(
                                 "hbm_headroom_bytes",
                                 -1 if memledger.enabled else 0)),
+                        **first_row,
                     )
+                    first_row.clear()
                 if global_step % cfg.train.logging_steps == 0 and is_main_process():
                     self.logger.info(
                         "step %d | loss %.4f | grad_norm %.3f | %.2f steps/s | %.0f tok/s/chip",

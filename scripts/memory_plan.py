@@ -40,7 +40,9 @@ if os.path.isdir(os.path.join(_repo_root, "dlti_tpu")):
     sys.path.insert(0, _repo_root)
 del _repo_root
 
-from dlti_tpu.config import MODEL_PRESETS, ModelConfig  # noqa: E402
+from dlti_tpu.config import (  # noqa: E402
+    MODEL_PRESETS, Config, DataConfig, LoRAConfig, ModelConfig, TrainConfig,
+)
 
 # Storage bytes per element (matches dlti_tpu.utils.dtypes resolution).
 DTYPE_BYTES = {
@@ -156,9 +158,14 @@ def recurrent_state_bytes_per_slot(cfg: ModelConfig) -> int:
 
 def plan_training(cfg: ModelConfig, param_dtype: Optional[str] = None,
                   trainable_params: Optional[int] = None,
-                  budget_bytes: int = 0) -> dict:
+                  budget_bytes: int = 0, micro_batch_size: int = 0,
+                  seq_len: int = 0, lora_r: int = 0) -> dict:
     """Owner-bucket prediction for one training process (no sharding —
-    divide by the data/tensor-parallel factor externally)."""
+    divide by the data/tensor-parallel factor externally). With a
+    microbatch's rows and length and a budget, also the step's
+    activations and how many blocks the trainer would keep under that
+    budget: ``dlti_tpu.training.remat_plan.plan``, the function the
+    trainer calls before its first step."""
     pbytes = _dtype_bytes(param_dtype or cfg.param_dtype)
     n = cfg.num_params()
     trainable = n if trainable_params is None else trainable_params
@@ -178,6 +185,20 @@ def plan_training(cfg: ModelConfig, param_dtype: Optional[str] = None,
         "owners": owners,
         "total_bytes": total,
     }
+    if micro_batch_size and seq_len and budget_bytes:
+        from dlti_tpu.training import remat_plan
+
+        plan = remat_plan.plan(Config(
+            model=cfg,
+            lora=LoRAConfig(enabled=lora_r > 0, r=max(lora_r, 1)),
+            data=DataConfig(max_seq_len=seq_len),
+            train=TrainConfig(micro_batch_size=micro_batch_size)),
+            total, budget_bytes)
+        out["remat_plan"] = {**plan.scalars(), "line": plan.line()}
+        if not plan.why_not:
+            # what the step holds beside the owners above, as planned
+            owners["activations"] = plan.planned_bytes - total
+            total = out["total_bytes"] = plan.planned_bytes
     if budget_bytes:
         out["budget_bytes"] = budget_bytes
         out["headroom_bytes"] = budget_bytes - total
@@ -253,6 +274,8 @@ def render(p: dict) -> str:
     for k, v in sorted(p["owners"].items(), key=lambda kv: -kv[1]):
         out.append(f"    {k:20s} {v / gib:9.3f} GiB  {100 * v / total:5.1f}%")
     out.append(f"    {'total':20s} {total / gib:9.3f} GiB")
+    if "remat_plan" in p:
+        out.append("    " + p["remat_plan"]["line"])
     if "budget_bytes" in p:
         verdict = "FITS" if p["fits"] else "DOES NOT FIT"
         out.append(f"    budget {p['budget_bytes'] / gib:.2f} GiB -> "
@@ -287,6 +310,13 @@ def main() -> None:
     ap.add_argument("--lora-r", type=int, default=0,
                     help="LoRA rank: trainable = adapters only "
                          "(0 = full fine-tune)")
+    ap.add_argument("--micro-batch-size", type=int, default=0,
+                    help="training: rows of a microbatch on one device; "
+                         "with --seq-len and --budget-gb the plan adds the "
+                         "step's activations and the blocks the trainer "
+                         "would keep (0 = leave them out)")
+    ap.add_argument("--seq-len", type=int, default=0,
+                    help="training: tokens a row (with --micro-batch-size)")
     ap.add_argument("--adapter-slots", type=int, default=0,
                     help="multi-LoRA serving pool slots (engine "
                          "--adapter-slots); adds the lora_adapters owner "
@@ -318,7 +348,9 @@ def main() -> None:
         trainable = (lora_trainable_params(cfg, r=args.lora_r)
                      if args.lora_r else None)
         p = plan_training(cfg, param_dtype=args.param_dtype,
-                          trainable_params=trainable, budget_bytes=budget)
+                          trainable_params=trainable, budget_bytes=budget,
+                          micro_batch_size=args.micro_batch_size,
+                          seq_len=args.seq_len, lora_r=args.lora_r)
     if args.json:
         print(json.dumps(p, indent=2))
     else:

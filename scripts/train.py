@@ -118,7 +118,10 @@ def parse_args():
                             "save_attn_out"],
                    help="activation-saving policy for jax.checkpoint "
                         "('none' disables remat entirely — fits at 7B bs4 "
-                        "once the base is int8; default: preset's)")
+                        "once the base is int8; default: preset's). With "
+                        "neither this nor --remat-stride stated the trainer "
+                        "keeps the activations of as many blocks as the "
+                        "device has room for (its 'remat:' log line)")
     p.add_argument("--remat-stride", type=int, default=0,
                    help="keep every Nth block's activations (selective "
                         "remat; 0 = preset)")
@@ -382,6 +385,10 @@ def build_config(args):
     if args.remat_stride:
         model_cfg = dataclasses.replace(model_cfg,
                                         remat_stride=args.remat_stride)
+    if args.remat_policy or args.remat_stride:
+        # A stated remat is held to: the trainer's own count of blocks
+        # that keep their activations (training.remat_plan) stands aside.
+        model_cfg = dataclasses.replace(model_cfg, remat_keep_blocks=0)
 
     return cfg.replace(
         model=model_cfg,

@@ -102,6 +102,37 @@ def test_train_cli_pipe_composes_with_zero_preset(workdir, prepared_data):
     assert (workdir / "pipe_zero1.csv").exists()
 
 
+def test_train_cli_plans_remat_unless_the_user_stated_one(workdir,
+                                                          prepared_data):
+    """With a budget to plan against (the CPU states no limit) the trainer
+    keeps blocks and says so; ``--remat-stride`` is held to instead."""
+    def run(name, *extra):
+        log = workdir / f"{name}.jsonl"
+        proc = _run([
+            "scripts/train.py", "--preset", "baseline", "--num-devices", "1",
+            "--model", "llama_debug", "--tokenizer", "byte",
+            "--dataset-path", str(prepared_data),
+            "--max-steps", "2", "--max-seq-len", "64", "--lora-r", "4",
+            "--per-device-batch-size", "1", "--warmup-steps", "1",
+            "--save-strategy", "no", "--hbm-budget-bytes", str(1 << 30),
+            "--step-log", str(log),
+            "--metrics-csv", str(workdir / f"{name}.csv"),
+            "--output-dir", str(workdir / f"ckpt_{name}"), *extra])
+        assert proc.returncode == 0, proc.stderr[-3000:]
+        with open(log) as fh:
+            rows = [json.loads(line) for line in fh]
+        return proc.stderr + proc.stdout, next(
+            r for r in rows if r.get("type") == "step")
+
+    out, first = run("remat_planned")
+    assert "remat: 4 of 4 blocks keep their activations; planned" in out
+    assert first["remat_kept_blocks"] == 4
+    assert 0 < first["remat_planned_bytes"] <= first["remat_limit_bytes"]
+    out, first = run("remat_stated", "--remat-stride", "2")
+    assert "unplanned (the kept-block count is stated)" in out
+    assert first["remat_kept_blocks"] == 0
+
+
 def test_train_cli_writes_reference_schema(trained_csv):
     import pandas as pd
 

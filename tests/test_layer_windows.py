@@ -342,6 +342,28 @@ def test_the_dense_presets_lower_to_the_programs_they_were(name):
     assert pk.window_blocks == 0 and len(ex.kv_groups) == 1
 
 
+@pytest.mark.parametrize("keep", [None, 0, 1, 2])
+def test_a_call_over_the_cache_is_the_program_it_was_whatever_is_kept(keep):
+    """``remat_keep_blocks`` is the trainer's count of blocks that keep
+    their activations; a call with a cache has no backward, so the decode
+    and prefill programs of a configuration with remat on are the ones
+    pinned above for remat off, whatever the count says."""
+    cfg = dataclasses.replace(narrow("mistral_7b"), remat=True,
+                              remat_keep_blocks=keep)
+    _, params = init(cfg)
+    ex = InferenceEngine(cfg, params, EngineConfig(
+        max_seqs=4, block_size=4, num_blocks=64, max_model_len=128)).executor
+    pk = ex.round_packing
+    ids = jnp.zeros((2, 32), jnp.int32)
+    assert sha(ex._decode_fn.lower(
+        ex.params, ex.cache, ex._no_prev,
+        jnp.zeros((pk.num_slots, pk.width), jnp.int32))) \
+        == PARENT["mistral_7b"]["decode"]
+    assert sha(ex._prefill_fn(32).lower(
+        ex.params, ex.cache, ids, ids, jnp.zeros((2, 16), jnp.int32),
+        jnp.zeros((2,), jnp.int32))) == PARENT["mistral_7b"]["prefill"]
+
+
 # -- the other held-expert families keep their grouped prefill -------------------
 
 YARN = {"beta_fast": 32, "beta_slow": 1, "factor": 64, "mscale": 1,
