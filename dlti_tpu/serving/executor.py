@@ -24,6 +24,9 @@ from dlti_tpu.models import build_model
 from dlti_tpu.ops.kv_cache import (
     bind_call, init_cache, unbind_call, window_blocks,
 )
+from dlti_tpu.ops.pallas.latent_attention import (
+    tile_tokens as latent_tile_tokens,
+)
 from dlti_tpu.ops.pallas.paged_attention import tile_tokens
 from dlti_tpu.serving.decode_state import RoundPacking
 from dlti_tpu.serving.sampling import sample_tokens
@@ -412,13 +415,16 @@ class EngineExecutor:
             if pool is not None
             and pool.shape[0] == model_cfg.ut_steps * ec.num_blocks
         ) // (ec.num_blocks * ec.block_size)
-        # Keys a step of the paged decode kernel (of keys and values, or of
-        # latents) covers at this engine's shapes (the scheduler's
-        # decode_kernel_tile_tokens counts in it).
+        # Keys a step of the paged decode kernel covers at this engine's
+        # shapes (the scheduler's decode_kernel_tile_tokens counts in it):
+        # by the rule of the kernel the pool is read by, of latents or of
+        # keys and values.
         pool = next((p for p in pools if p is not None), None)
         token_bytes = 0 if pool is None \
             else math.prod(pool.shape[2:]) * pool.dtype.itemsize
-        self.decode_tile_tokens = tile_tokens(
+        rule = latent_tile_tokens if any("latent" in c for c in self.cache) \
+            else tile_tokens
+        self.decode_tile_tokens = rule(
             ec.block_size, ec.max_blocks_per_seq, token_bytes)
 
         self._restore_fn = None  # lazily-jitted tier/handoff restore scatter

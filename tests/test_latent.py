@@ -288,30 +288,51 @@ def test_prefill_through_the_flash_kernel_equals_the_loop_and_the_full_forward(
     assert float(jnp.abs(pool["latent"][0]).max()) == 0.0
 
 
-# contexts of 1, tile - 1, tile, tile + 1 and several tiles; a tile is 256 keys
-# (32 blocks of 8) at these shapes
+# contexts of 1, tile - 1, tile, tile + 1 and several tiles, in keys of a
+# tile as ``ring_shape`` gives it at these shapes (384: 48 blocks of 8). The
+# ``ring_`` cases are counted in tiles against the ring's depth D: a schedule
+# of no tile, one, D - 1 (all sent before the loop; every step's own copies
+# are the schedule's last tile again), D (one sent inside the loop), and
+# 2D + 1 tiles of one row (every slot used three times) with short rows and
+# empty rows after it.
 KERNEL_CASES = {
-    "one_row_one_key": [1],
-    "one_row_under_a_tile": [255],
-    "one_row_a_tile": [256],
-    "rows_round_a_tile": [255, 256, 257],
-    "rows_of_several_tiles": [0, 17, 600, 513],
+    "one_row_one_key": lambda d, keys: [1],
+    "one_row_under_a_tile": lambda d, keys: [keys - 1],
+    "one_row_a_tile": lambda d, keys: [keys],
+    "rows_round_a_tile": lambda d, keys: [keys - 1, keys, keys + 1],
+    "rows_of_several_tiles": lambda d, keys: [
+        0, 17, 2 * keys + 88, 2 * keys + 1],
+    "ring_no_tile": lambda d, keys: [0, 0],
+    "ring_one_tile": lambda d, keys: [0, 5],
+    "ring_a_tile_short_of_the_depth": lambda d, keys: [keys * (d - 1) - 3],
+    "ring_a_tile_short_over_rows": lambda d, keys: [9] * (d - 1),
+    "ring_exactly_the_depth": lambda d, keys: [keys, 1, keys * (d - 2)],
+    "ring_several_wraps": lambda d, keys: [
+        keys * (2 * d + 1) - 7, 3, 0, keys + 1, 0, 40],
 }
+KERNEL_BLOCK, KERNEL_WIDTH = 8, 128
 
 
 @pytest.mark.parametrize("name", sorted(KERNEL_CASES))
 def test_kernel_interpreted_equals_the_gather_path(name):
-    from dlti_tpu.ops.pallas.latent_attention import latent_decode_attention
+    from dlti_tpu.ops.pallas.latent_attention import (
+        latent_decode_attention, ring_shape,
+    )
 
-    lens = jnp.asarray(KERNEL_CASES[name], jnp.int32)
-    rows, heads, dim, value_dim, block, blocks = len(lens), 4, 40, 32, 8, 80
-    pool = jax.random.normal(jax.random.PRNGKey(0), (128, block, 128))
+    blocks = 480  # a table wide enough for the longest row a case asks for
+    tile, depth = ring_shape(KERNEL_BLOCK, blocks, 4 * KERNEL_WIDTH)
+    lens = KERNEL_CASES[name](depth, tile * KERNEL_BLOCK)
+    assert max(lens) <= blocks * KERNEL_BLOCK and depth >= 3
+    lens = jnp.asarray(lens, jnp.int32)
+    rows, heads, dim, value_dim, block = len(lens), 4, 40, 32, KERNEL_BLOCK
+    pool = jax.random.normal(jax.random.PRNGKey(0),
+                             (128, block, KERNEL_WIDTH))
     pool = pool.at[..., dim:].set(0.0)
     q = jax.random.normal(jax.random.PRNGKey(1), (rows, heads, dim))
     tables = jax.random.randint(jax.random.PRNGKey(2), (rows, blocks), 0, 128)
     got = latent_decode_attention(q, pool, tables, lens, value_dim=value_dim,
                                   scale=0.2, interpret=True)
-    window = pool[tables].reshape(rows, blocks * block, 128)
+    window = pool[tables].reshape(rows, blocks * block, KERNEL_WIDTH)
     s = jnp.einsum("bhd,bkd->bhk", q, window[..., :dim]) * 0.2
     live = jnp.arange(blocks * block)[None, None, :] < lens[:, None, None]
     p = jax.nn.softmax(jnp.where(live, s, -1e30), -1) \
@@ -319,6 +340,44 @@ def test_kernel_interpreted_equals_the_gather_path(name):
     want = jnp.einsum("bhk,bkd->bhd", p, window[..., :value_dim])
     # float32 both ways; the kernel adds a tile at a time
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
+
+
+# The cells' pools: rows of 640 bf16 values (1,280 B), blocks of 16; blocks a
+# row of the table. What the rule gives there is what the sweep on the chip
+# chose (PERF.md section 6, PR 55); a float32 pool's rows are twice as wide.
+RING_SHAPES = {
+    "doc_turns": (16, 544, 1280, (24, 4)),
+    "fresh_docs": (16, 512, 1280, (24, 4)),
+    "float32_rows": (16, 544, 2560, (24, 4)),
+    "a_table_shorter_than_a_tile": (16, 8, 1280, (8, 4)),
+    "rows_too_wide_for_three_whole_tiles": (16, 544, 8192, (6, 4)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(RING_SHAPES))
+def test_ring_shape_at_the_cells_shapes_fits_its_budget(name):
+    from dlti_tpu.ops.pallas import latent_attention as kernel
+
+    block, max_blocks, row_bytes, want = RING_SHAPES[name]
+    tile, depth = kernel.ring_shape(block, max_blocks, row_bytes)
+    assert (tile, depth) == want
+    assert 3 <= depth <= kernel.RING_DEPTH
+    assert tile * block <= kernel.TILE_KEYS and tile <= max_blocks
+    assert depth * tile * block * row_bytes <= kernel.VMEM_RING_BUDGET
+    assert kernel.tile_tokens(block, max_blocks, row_bytes) == tile * block
+
+
+def test_a_latent_pools_engine_counts_tiles_by_the_latent_rule(tiny):
+    """``decode_kernel_tile_tokens`` counts in the keys a step of the kernel
+    that reads the pool covers: observed from the cache, not from a name."""
+    from dlti_tpu.ops.pallas import latent_attention, paged_attention
+
+    eng = _engine(tiny, block_size=8, max_model_len=8 * 64, num_blocks=128)
+    pool = eng.executor.cache[0]["latent"]
+    row_bytes = pool.shape[-1] * pool.dtype.itemsize
+    assert eng.executor.decode_tile_tokens \
+        == latent_attention.tile_tokens(8, 64, row_bytes) == 384
+    assert paged_attention.tile_tokens(8, 64, row_bytes) == 256
 
 
 def test_decode_through_the_kernel_equals_the_gather_path(layer):

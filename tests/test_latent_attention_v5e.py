@@ -5,6 +5,8 @@ runs: the TPU compiler installed here compiles for a chip that is described,
 not attached. The topology is described inside a module-scoped fixture and
 never at import."""
 
+import math
+
 import pytest
 
 
@@ -33,9 +35,14 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-# (rows, blocks in the pool, blocks a row): the cell's engine, and a short
-# table whose one tile is narrower than 256 keys
-GEOMETRIES = {"doc_turns": (32, 16384, 544), "short_rows": (8, 512, 8)}
+# (rows, blocks in the pool, blocks a row): the cell's engine, a short table
+# whose one tile is narrower than the rule's 384 keys, and one whose whole
+# schedule (two rows of one tile each at most) is shorter than the ring
+GEOMETRIES = {"doc_turns": (32, 16384, 544), "short_rows": (8, 512, 8),
+              "under_the_ring": (2, 64, 16)}
+# What a kernel may take of VMEM on this chip without asking for more (the
+# compiler's default scoped limit on a v5e).
+SCOPED_VMEM_BYTES = 16 * 1024 * 1024
 
 
 @pytest.mark.parametrize("name", sorted(GEOMETRIES))
@@ -44,7 +51,9 @@ def test_latent_decode_kernel_compiles_for_the_v5e(one_chip, name):
     import jax.numpy as jnp
 
     from dlti_tpu.ops.kv_cache import init_latent_cache
-    from dlti_tpu.ops.pallas.latent_attention import latent_decode_attention
+    from dlti_tpu.ops.pallas.latent_attention import (
+        latent_decode_attention, ring_shape,
+    )
 
     rows, blocks, max_blocks = GEOMETRIES[name]
     heads, latent_dim, value_dim, block = 32, 576, 512, 16
@@ -59,14 +68,38 @@ def test_latent_decode_kernel_compiles_for_the_v5e(one_chip, name):
         return latent_decode_attention(q, pool, tables, lens,
                                        value_dim=value_dim, scale=192 ** -0.5)
 
-    compiled = jax.jit(decode).lower(
-        shape((rows, heads, latent_dim), jnp.bfloat16),
-        shape((blocks, block, width), jnp.bfloat16),
-        shape((rows, max_blocks), jnp.int32),
-        shape((rows,), jnp.int32)).compile()
-    text = compiled.as_text()
+    shapes = (shape((rows, heads, latent_dim), jnp.bfloat16),
+              shape((blocks, block, width), jnp.bfloat16),
+              shape((rows, max_blocks), jnp.int32),
+              shape((rows,), jnp.int32))
+    text = jax.jit(decode).lower(*shapes).compile().as_text()
     assert "tpu_custom_call" in text
     assert "dlti_latent_attention_decode" in text
+    # The scratch the call asks for, read off the call itself: the ring's
+    # slots and the softmax state, each laid in whole (8, 128) words. (The
+    # compiler would have refused a kernel over the chip's scoped VMEM; this
+    # says the ring alone leaves the body's float32 tiles their room.)
+    tile, depth = ring_shape(block, max_blocks, width * 2)
+    assert name != "under_the_ring" or rows * -(-max_blocks // tile) < depth
+    call, = _pallas_calls(jax.make_jaxpr(decode)(*shapes).jaxpr)
+    scratch = [v.aval for v in call.params["jaxpr"].invars[
+        -call.params["grid_mapping"].num_scratch_operands:]]
+    slots = [a for a in scratch if str(a.memory_space) == "vmem"]
+    assert slots[0].shape == (depth, tile * block, width)
+    asked = sum(math.prod(a.shape[:-2]) * -(-a.shape[-2] // 8) * 8
+                * -(-a.shape[-1] // 128) * 128 * a.dtype.itemsize
+                for a in slots)
+    assert asked <= SCOPED_VMEM_BYTES // 2
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            if hasattr(inner, "eqns"):
+                yield from _pallas_calls(inner)
 
 
 # (rows, tokens) of the prefill programs the latent cells warm that take the
