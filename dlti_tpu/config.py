@@ -50,6 +50,9 @@ class ZeROStage(enum.IntEnum):
 
 # The mixer kinds of the decoder-hybrid-decoder family's ``layer_pattern``.
 SAMBAY_KINDS = "SDGX"
+# ... and of the jamba family's (``models.jamba``): "S" Mamba-1 with its
+# inner norms, "A" plain attention; a pattern of this family has an "A".
+JAMBA_KINDS = "SA"
 
 
 @dataclass(frozen=True)
@@ -167,6 +170,9 @@ class ModelConfig:
     # (``shared_memory_layer``), "X" differential cross-attention with a
     # query projection alone over the pool of the last "D" before it
     # (``shared_kv_layer``, which sees every key).
+    # The jamba family (models.jamba) has a mixer and a gate/up/down MLP a
+    # layer behind RMSNorms: "S" Mamba-1, "A" plain causal attention over
+    # the layer's own keys and values (no window, no rotation).
     layer_pattern: str = ""
     rope: bool = True  # False: attention applies no rotary embedding
     # Mamba-2 mixer ("M"): d_inner = heads * head_dim; B and C are shared by
@@ -186,6 +192,14 @@ class ModelConfig:
     # wide as d_inner.
     mamba_expand: int = 0
     mamba_dt_rank: int = 0
+    # RMSNorms with learned weights on the time step's ``mamba_dt_rank``
+    # values and on B and C inside the Mamba-1 mixer (jamba's own).
+    mamba_inner_norms: bool = False
+    # The projections that carry LoRA adapters in this family, where they
+    # are not ``LoRAConfig.target_modules`` (the configuration states them:
+    # every place that builds a LoRAConfig with the default targets then
+    # builds the same adapter tree). (): the LoRAConfig's.
+    lora_targets: tuple = ()
     # Dropless routed experts ("E", models.moe.HeldExpertsMLP): the router
     # scores all ``moe_num_experts``; this process holds (and computes)
     # experts [moe_held_start, moe_held_start + moe_held_count) — 0 = all —
@@ -248,6 +262,8 @@ class ModelConfig:
                 sorted((str(k), v) for k, v in pairs)))
         windows = tuple(int(w or 0) for w in self.layer_windows)
         object.__setattr__(self, "layer_windows", windows)
+        object.__setattr__(self, "lora_targets",
+                           tuple(str(t) for t in self.lora_targets))
         if windows and len(windows) != self.num_layers:
             raise ValueError(
                 f"layer_windows states {len(windows)} windows for "
@@ -283,11 +299,18 @@ class ModelConfig:
         kinds = set(self.layer_pattern)
         if self.layer_pattern and (
                 len(self.layer_pattern) != self.num_layers
-                or not (kinds <= set("ME*") or kinds <= set(SAMBAY_KINDS))):
+                or not (kinds <= set("ME*") or kinds <= set(SAMBAY_KINDS)
+                        or kinds <= set(JAMBA_KINDS))):
             raise ValueError(
                 f"layer_pattern {self.layer_pattern!r} must name one mixer "
-                f"(M, E or *; or S, D, G or X, the two sets never mixed) "
-                f"for each of num_layers={self.num_layers}")
+                f"(M, E or *; or S, D, G or X; or S and A; the three sets "
+                f"never mixed) for each of num_layers={self.num_layers}")
+        if self.is_jamba and (self.layer_windows or self.sliding_window
+                              or self.rope):
+            raise ValueError(
+                f"layer_pattern {self.layer_pattern!r}: an A layer sees "
+                f"every key of its document and rotates nothing (state "
+                f"rope false, no sliding_window and no layer_windows)")
         if self.is_sambay:
             pattern = self.layer_pattern
             if "G" in pattern and "S" not in pattern[:pattern.index("G")]:
@@ -377,6 +400,18 @@ class ModelConfig:
         """The decoder-hybrid-decoder family (``models.sambay``)."""
         return bool(self.layer_pattern) \
             and set(self.layer_pattern) <= set(SAMBAY_KINDS)
+
+    @property
+    def is_jamba(self) -> bool:
+        """The jamba family (``models.jamba``): Mamba-1 and plain
+        attention layers."""
+        return "A" in self.layer_pattern \
+            and set(self.layer_pattern) <= set(JAMBA_KINDS)
+
+    def lora_targets_of(self, lora) -> tuple:
+        """The projections that carry adapters under ``lora``
+        (a LoRAConfig): this family's own where it states them."""
+        return self.lora_targets or tuple(lora.target_modules)
 
     @property
     def shared_memory_layer(self) -> Optional[int]:
@@ -482,6 +517,23 @@ class ModelConfig:
             }
             total = v * h + 2 * h + sum(per_kind[c] + 3 * h * m + 4 * h
                                         for c in self.layer_pattern)
+            if include_lm_head and not self.tie_embeddings:
+                total += h * v
+            return total
+        if self.is_jamba:
+            # A mixer and a gate/up/down MLP behind an RMSNorm each; a
+            # final RMSNorm; the inner norms on dt, B and C where stated.
+            d_in, n, r = (self.mamba_inner_size, self.mamba_state_size,
+                          self.mamba_dt_rank)
+            per_kind = {
+                "S": (h * 2 * d_in + d_in * (self.mamba_conv_kernel + 1)
+                      + d_in * (r + 2 * n) + r * d_in + d_in
+                      + d_in * n + d_in + d_in * h
+                      + (r + 2 * n if self.mamba_inner_norms else 0)),
+                "A": attn,
+            }
+            total = v * h + h + sum(per_kind[c] + 3 * h * m + 2 * h
+                                    for c in self.layer_pattern)
             if include_lm_head and not self.tie_embeddings:
                 total += h * v
             return total
@@ -1394,6 +1446,34 @@ MODEL_PRESETS: dict = {
         layer_windows=(0, 16, 0, 16, 0, 16, 0, 0, 0, 0, 0, 0),
         rope=False, attention_bias=True, mamba_expand=2,
         mamba_state_size=8, mamba_dt_rank=4,
+    ),
+    # AI21-Jamba2-3B (`jamba`): 26 Mamba-1 layers with inner norms, plain
+    # attention (20 query heads over one key-value head, no positions) in
+    # layers 7 and 21, a dense MLP in every layer, tied 65,536-row head;
+    # the sizes of benchmark/configs/jamba2_3b.json (a test holds them equal).
+    "jamba2_3b": ModelConfig(
+        vocab_size=65536, hidden_size=2560, intermediate_size=8192,
+        num_layers=28, num_heads=20, num_kv_heads=1, head_dim=128,
+        max_seq_len=262144, rms_norm_eps=1e-6, tie_embeddings=True,
+        layer_pattern="SSSSSSSASSSSSSSSSSSSSASSSSSS", rope=False,
+        mamba_expand=2, mamba_state_size=16, mamba_conv_kernel=4,
+        mamba_dt_rank=160, mamba_inner_norms=True,
+        lora_targets=("q_proj", "k_proj", "v_proj", "o_proj",
+                      "in_proj", "x_proj", "out_proj"),
+    ),
+    # Test-scale jamba (structurally AI21-Jamba2): Mamba-1 with inner norms
+    # and plain one-key-value-head attention without rotation, an attention
+    # layer every fourth (attn_layer_period 4, attn_layer_offset 2),
+    # adapters on the attention and the state-space projections.
+    "jamba_tiny": ModelConfig(
+        vocab_size=512, hidden_size=64, intermediate_size=128, num_layers=4,
+        num_heads=4, num_kv_heads=1, head_dim=16, max_seq_len=256,
+        rms_norm_eps=1e-6, dtype="float32", param_dtype="float32",
+        tie_embeddings=True, layer_pattern="SSAS", rope=False,
+        mamba_expand=2, mamba_state_size=8, mamba_dt_rank=4,
+        mamba_inner_norms=True,
+        lora_targets=("q_proj", "k_proj", "v_proj", "o_proj",
+                      "in_proj", "x_proj", "out_proj"),
     ),
     # Test-scale latent attention (structurally deepseek_v3: MLA over a
     # latent cache, one dense layer, then gated held experts).

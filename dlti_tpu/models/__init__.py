@@ -1,8 +1,9 @@
 """Model zoo: Llama-family transformer in Flax + LoRA grafting, the
 patterned ``nemotron_h`` family (Mamba-2, routed experts, attention), the
 decoder-hybrid-decoder family (``phi4flash``: Mamba-1, differential
-attention, gated memory units and cross-attention over one shared pool) and
-the latent-attention family (``deepseek_v3``: MLA, held gated experts;
+attention, gated memory units and cross-attention over one shared pool), the
+jamba family (Mamba-1 with inner norms and plain attention, trained with
+LoRA over packed rows) and the latent-attention family (``deepseek_v3``: MLA, held gated experts;
 ``xing4_0``: the same round hyper-connected residual streams)."""
 
 from dlti_tpu.models.llama import LlamaForCausalLM, LlamaModel  # noqa: F401
@@ -14,7 +15,8 @@ def build_model(cfg, lora=None, mesh=None):
     the fleet worker and the benchmark's check all come through here). A
     configuration with a ``kv_lora_rank`` is the latent-attention family;
     one with neither that nor a ``layer_pattern`` is the Llama family; the
-    pattern's kinds say which patterned family (``ModelConfig.is_sambay``).
+    pattern's kinds say which patterned family (``ModelConfig.is_sambay``,
+    ``is_jamba``).
     Hyper-connected residual streams (``hc_mult``, ``models.hyper``) are
     wired into the latent-attention family alone."""
     if cfg.hc_mult and not cfg.kv_lora_rank:
@@ -29,6 +31,10 @@ def build_model(cfg, lora=None, mesh=None):
         from dlti_tpu.models.sambay import SambaYForCausalLM
 
         return SambaYForCausalLM(cfg, lora, mesh)
+    if cfg.is_jamba:
+        from dlti_tpu.models.jamba import JambaForCausalLM
+
+        return JambaForCausalLM(cfg, lora, mesh)
     if cfg.layer_pattern:
         from dlti_tpu.models.nemotron_h import NemotronHForCausalLM
 

@@ -437,12 +437,14 @@ def save_peft_adapter(directory: str, params: Mapping[str, Any],
 
     p = _unwrap(params)
     sd: Dict[str, jnp.ndarray] = {}
+    found = set()
 
     def walk(tree, path):
         if not isinstance(tree, Mapping):
             return
         if "lora_a" in tree and "lora_b" in tree:
             hf_path = _our_path_to_hf(path)
+            found.add(path[-1])
             sd[f"{_PEFT_PREFIX}{hf_path}.lora_A.weight"] = jnp.asarray(tree["lora_a"]).T
             sd[f"{_PEFT_PREFIX}{hf_path}.lora_B.weight"] = jnp.asarray(tree["lora_b"]).T
             return
@@ -460,7 +462,11 @@ def save_peft_adapter(directory: str, params: Mapping[str, Any],
             "r": lora.r,
             "lora_alpha": lora.alpha,
             "lora_dropout": lora.dropout,
-            "target_modules": list(lora.target_modules),
+            # the projections that carry factors: the LoRAConfig's, or the
+            # family's own (ModelConfig.lora_targets: jamba's in_proj,
+            # x_proj and out_proj beside q/k/v/o)
+            "target_modules": [t for t in lora.target_modules if t in found]
+            + sorted(found - set(lora.target_modules)),
             "bias": "none",
             "task_type": "CAUSAL_LM",
         }, f, indent=2)
@@ -498,14 +504,24 @@ def load_peft_adapter(directory: str, params: Dict[str, Any]) -> Dict[str, Any]:
     return params
 
 
+_ATTENTION_PROJECTIONS = ("q_proj", "k_proj", "v_proj", "o_proj")
+
+
 def _our_path_to_hf(path: tuple) -> str:
-    """('model','layers_3','attn','q_proj') -> 'model.layers.3.self_attn.q_proj'."""
-    out = []
+    """('model','layers_3','attn','q_proj') -> 'model.layers.3.self_attn.q_proj'.
+    The jamba family's tree has no ``model`` level and calls a layer's mixer
+    ``mixer``: ('layers_3','mixer','in_proj') -> 'model.layers.3.mamba.in_proj'
+    and ('layers_7','mixer','q_proj') -> 'model.layers.7.self_attn.q_proj',
+    the published names."""
+    out = [] if path[0] == "model" else ["model"]
     for part in path:
         if part.startswith("layers_"):
             out.append(f"layers.{part.split('_', 1)[1]}")
         elif part == "attn":
             out.append("self_attn")
+        elif part == "mixer":
+            out.append("self_attn" if path[-1] in _ATTENTION_PROJECTIONS
+                       else "mamba")
         else:
             out.append(part)
     return ".".join(out)
@@ -514,6 +530,8 @@ def _our_path_to_hf(path: tuple) -> str:
 def _hf_path_to_node(tree: Dict[str, Any], hf_path: str) -> Dict[str, Any]:
     """'model.layers.3.self_attn.q_proj' -> the q_proj dict in our tree."""
     parts = hf_path.split(".")
+    if parts[0] == "model" and "model" not in tree:
+        parts = parts[1:]  # the jamba family's tree has no such level
     node: Any = tree
     i = 0
     while i < len(parts):
@@ -521,7 +539,9 @@ def _hf_path_to_node(tree: Dict[str, Any], hf_path: str) -> Dict[str, Any]:
         if part == "layers" and i + 1 < len(parts) and parts[i + 1].isdigit():
             part, i = f"layers_{parts[i + 1]}", i + 1
         elif part == "self_attn":
-            part = "attn"
+            part = "attn" if "attn" in node else "mixer"
+        elif part == "mamba" and "mixer" in node:
+            part = "mixer"
         if part not in node:
             raise KeyError(f"{hf_path}: no '{part}' in tree level "
                            f"(have {sorted(node)[:8]})")

@@ -82,7 +82,8 @@ class RMSNorm(nn.Module):
 
 def _lora_kwargs(cfg: ModelConfig, lora: Optional[LoRAConfig], name: str) -> dict:
     """LoRA hyperparams for projection ``name``, or r=0 when untargeted."""
-    if lora is not None and lora.enabled and name in lora.target_modules:
+    if lora is not None and lora.enabled \
+            and name in cfg.lora_targets_of(lora):
         return dict(lora_r=lora.r, lora_alpha=lora.alpha, lora_dropout=lora.dropout)
     return dict(lora_r=0)
 

@@ -94,7 +94,9 @@ class NemotronHForCausalLM(nn.Module):
         if segment_ids is not None:
             raise NotImplementedError(
                 "packed rows through Mamba-2 layers are not supported: the "
-                "recurrence would carry one document's state into the next")
+                "recurrence would carry one document's state into the next "
+                "(models.mamba1 resets its state at a document's start; "
+                "models.mamba2's chunked scan does not yet)")
         dtype, pdtype = _dtype(cfg.dtype), _dtype(cfg.param_dtype)
         b, s = input_ids.shape
         # Seeded at unit scale: the residual stream carries the token, and

@@ -4,7 +4,8 @@ Every layer is a mixer and a gated MLP, each behind a LayerNorm with bias:
 ``x = x + Mixer_i(LN(x))``, then ``x = x + MLP_i(LN'(x))``, the mixer named
 by character ``i`` of ``ModelConfig.layer_pattern``:
 
-* ``S`` Mamba-1 (``models.mamba1``); the one at
+* ``S`` Mamba-1 (``models.mamba1``, without the inner norms that
+  ``models.jamba`` turns on); the one at
   ``cfg.shared_memory_layer`` also hands its scan output ``y`` (before the
   gate), THE MEMORY, to every ``G`` layer of the same forward pass;
 * ``D`` differential attention (arXiv:2410.05258) over the layer's own keys
@@ -39,6 +40,11 @@ The cache is a list with one entry a layer (``ops.kv_cache.init_cache``):
 ``{"conv", "ssm"}`` by decode slot for ``S``, ``{"k", "v"}`` block pools for
 ``D`` (the window group's or the full group's), ``{}`` for ``G`` and ``X``.
 The memory is never cached: it is this call's activations.
+
+The family is served, not trained: packed rows and LoRA are refused here
+(``models.jamba`` trains the same Mamba-1 mixer over packed rows with
+adapters; a forward pass without a cache goes through that mixer's chunked
+scan, whose results are the token-a-trip scan's).
 """
 
 from __future__ import annotations
@@ -314,12 +320,17 @@ class SambaYForCausalLM(nn.Module):
         cfg = self.cfg
         if segment_ids is not None:
             raise NotImplementedError(
-                "packed rows through Mamba-1 layers are not supported: the "
-                "recurrence would carry one document's state into the next")
+                "packed rows through the decoder-hybrid-decoder family are "
+                "not wired: its Mamba-1 layers could start every document "
+                "anew (models.mamba1 does, for models.jamba), but the "
+                "memory units and the attention under a window or over the "
+                "shared pool have been held to no reference under packing")
         if self.lora is not None and self.lora.enabled:
             raise NotImplementedError(
                 "LoRA through the decoder-hybrid-decoder family's layers "
-                "(a per-channel scan, fused projections) is not implemented")
+                "(fused and paired projections, memory units) is not "
+                "implemented; the Mamba-1 mixer carries adapters, for "
+                "models.jamba")
         dtype, pdtype = _dtype(cfg.dtype), _dtype(cfg.param_dtype)
         b, s = input_ids.shape
         embed = self.param("embed_tokens",

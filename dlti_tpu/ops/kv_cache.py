@@ -214,6 +214,7 @@ def init_cache(model_cfg, num_blocks: int, block_size: int, num_slots: int,
               else window_group_blocks(w, block_size, num_slots, call_tokens)
               for i, w in enumerate(model_cfg.kv_group_windows)]
     sambay = model_cfg.is_sambay
+    kinds = model_cfg.layer_pattern or "*" * model_cfg.num_layers
     # (the decoder-hybrid-decoder family pairs its heads: half as many
     # key-value heads, twice as wide, models.sambay; 10 of them at the
     # published sizes, so its pools are fused)
@@ -221,16 +222,21 @@ def init_cache(model_cfg, num_blocks: int, block_size: int, num_slots: int,
         (model_cfg.num_kv_heads // 2, 2 * model_cfg.resolved_head_dim)
         if sambay else (model_cfg.num_kv_heads, model_cfg.resolved_head_dim))
 
+    # (... and the jamba family's ONE key-value head: a 4-D pool of one
+    # head lies padded to two and the chip's compiler refuses the decode
+    # kernel's block copy, "must be aligned to tiling (2), but is 1")
+    fused = sambay or model_cfg.is_jamba
+
     def paged(layer):
         return init_paged_cache(
             1, blocks[model_cfg.kv_group_of_layer(layer)], block_size,
-            kv_heads, head_dim, dtype, fused=sambay)[0]
+            kv_heads, head_dim, dtype, fused=fused)[0]
 
     def recurrent(layer):
         conv_dim, state = (
             (model_cfg.mamba_inner_size,
              (model_cfg.mamba_inner_size, model_cfg.mamba_state_size))
-            if sambay else
+            if kinds[layer] == "S" else
             (model_cfg.mamba_conv_dim,
              (model_cfg.mamba_num_heads, model_cfg.mamba_head_dim,
               model_cfg.mamba_state_size)))
@@ -242,9 +248,8 @@ def init_cache(model_cfg, num_blocks: int, block_size: int, num_slots: int,
     def nothing(layer):
         return {}
 
-    kinds = model_cfg.layer_pattern or "*" * model_cfg.num_layers
-    make = {"*": paged, "D": paged, "M": recurrent, "S": recurrent,
-            "E": nothing, "G": nothing, "X": nothing}
+    make = {"*": paged, "D": paged, "A": paged, "M": recurrent,
+            "S": recurrent, "E": nothing, "G": nothing, "X": nothing}
     return [make[k](i) for i, k in enumerate(kinds)]
 
 

@@ -98,7 +98,9 @@ def refuse_unsupported(model_cfg: ModelConfig, engine_cfg: "EngineConfig",
     what = (f"a model with layer_pattern {model_cfg.layer_pattern!r}"
             + (" (Mamba-1 layers, paired heads for differential attention, "
                "one pool that several layers read)"
-               if model_cfg.is_sambay else "")
+               if model_cfg.is_sambay else
+               " (Mamba-1 layers with inner norms, plain attention layers "
+               "with their own pools)" if model_cfg.is_jamba else "")
             if model_cfg.layer_pattern else
             f"a model with latent attention (kv_lora_rank "
             f"{model_cfg.kv_lora_rank})")
@@ -118,11 +120,14 @@ def refuse_unsupported(model_cfg: ModelConfig, engine_cfg: "EngineConfig",
                 f"(serving/prefix_tiers.py). Serve it with "
                 f"--enable-prefix-caching alone (the HBM tier treats a "
                 f"latent block like any other)")
-    if model_cfg.is_sambay and ec.cache_dtype == "int8":
+    if (model_cfg.is_sambay or model_cfg.is_jamba) \
+            and ec.cache_dtype == "int8":
         raise ValueError(
-            f"{what} keeps a key row as two heads' keys side by side, which "
-            f"one int8 scale a row would round together, and no reference "
-            f"has been held against that; serve it with --kv-cache-dtype "
+            f"{what} keeps its keys and values in fused pools (a token's "
+            f"row is its key-value heads side by side: two heads' keys in "
+            f"one row of the decoder-hybrid-decoder family, the jamba "
+            f"family's one head), which have no int8 scale a row and no "
+            f"reference held against one; serve it with --kv-cache-dtype "
             f"bfloat16")
     if model_cfg.has_recurrent_state:
         why = (f"{what} keeps a recurrent state per decode slot beside its "

@@ -49,7 +49,10 @@ from dlti_tpu.utils.metrics import MetricsRecord
 # this step's bookkeeping boundary — the per-step twins of the goodput
 # phase fields, on the bytes axis. hbm_headroom_bytes is -1 when
 # capacity is unknown (CPU runs without a configured budget); both are 0
-# when the memory ledger is disabled.
+# when the memory ledger is disabled. A model that counts in a training
+# pass (``train_counters``: models.jamba's ``recurrent_state_resets``) adds
+# its counters to its own rows under their names; other models' rows are
+# these fields alone.
 STEP_RECORD_FIELDS = (
     "type", "step", "loss", "grad_norm", "lr",
     "tokens_per_second_per_chip", "mfu_percent",

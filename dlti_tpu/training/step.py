@@ -215,6 +215,10 @@ def make_train_step(
         raise ValueError(
             "loss_chunk does not compose with MoE aux-loss collection; "
             "set train.loss_chunk=0 for MoE models")
+    # What the model counts in a training pass (``models.jamba``: the
+    # documents that start inside the rows), summed over the microbatches
+    # into the step's metrics under the counters' own names.
+    counted = tuple(getattr(model, "train_counters", ()))
 
     def microbatch_loss(trainable, frozen, micro, rng):
         params = combine_params(trainable, frozen)
@@ -228,6 +232,8 @@ def make_train_step(
             deterministic=False,
             rngs={"dropout": rng},
         )
+        if counted:
+            apply_kwargs["return_counters"] = True
         if moe_coef and loss_mask is not None and micro.get("segment_ids") is None:
             # Keep padding tokens out of expert capacity/aux statistics.
             # Only for unpacked batches, where loss_mask IS the padding
@@ -246,12 +252,15 @@ def make_train_step(
 
             aux = collect_aux_loss(variables.get("intermediates", {}))
         elif loss_chunk:  # MoE+loss_chunk rejected at build time above
-            hidden, _ = model.apply({"params": params}, input_ids,
-                                    return_hidden=True, **apply_kwargs)
+            hidden, *rest = model.apply({"params": params}, input_ids,
+                                        return_hidden=True, **apply_kwargs)
             aux = 0.0
         else:
-            logits, _ = model.apply({"params": params}, input_ids, **apply_kwargs)
+            logits, *rest = model.apply({"params": params}, input_ids,
+                                        **apply_kwargs)
             aux = 0.0
+        counts = {name: rest[1][name].astype(jnp.float32)
+                  for name in counted}
         if loss_chunk:
             loss_sum, n_tok = chunked_causal_lm_loss(
                 hidden, model.head_matrix(params, hidden),
@@ -264,7 +273,7 @@ def make_train_step(
         # keep CE and aux separate so logged losses stay comparable with
         # dense runs and the reference's pure-CE trajectory.
         objective = loss_sum + moe_coef * aux * n_tok
-        return objective, (loss_sum, aux * n_tok, n_tok)
+        return objective, (loss_sum, aux * n_tok, n_tok, counts)
 
     def train_step(state: TrainState, batch: dict, rng: jax.Array):
         trainable, frozen = state.trainable_and_frozen()
@@ -274,35 +283,37 @@ def make_train_step(
 
         def accum_body(carry, micro_with_rng):
             # One fused fwd+bwd per microbatch via value_and_grad.
-            grads_acc, loss_acc, aux_acc, tok_acc = carry
+            grads_acc, loss_acc, aux_acc, tok_acc, counts_acc = carry
             micro, micro_rng = micro_with_rng
 
             def scaled_loss(trainable, frozen, micro, rng):
                 objective, parts = microbatch_loss(trainable, frozen, micro, rng)
                 return objective * loss_scale, parts
 
-            (_, (loss_sum, aux_sum, n_tok)), grads = jax.value_and_grad(
-                scaled_loss, argnums=0, has_aux=True
-            )(trainable, frozen, micro, micro_rng)
+            (_, (loss_sum, aux_sum, n_tok, counts)), grads = \
+                jax.value_and_grad(scaled_loss, argnums=0, has_aux=True)(
+                    trainable, frozen, micro, micro_rng)
             grads_acc = jax.tree_util.tree_map(
                 lambda a, g: a + g.astype(jnp.float32), grads_acc, grads
             )
             return (grads_acc, loss_acc + loss_sum, aux_acc + aux_sum,
-                    tok_acc + n_tok), None
+                    tok_acc + n_tok,
+                    {k: counts_acc[k] + v for k, v in counts.items()}), None
 
         zero_grads = jax.tree_util.tree_map(
             lambda p: jnp.zeros(p.shape, jnp.float32), trainable
         )
         zero_carry = (zero_grads, jnp.float32(0.0), jnp.float32(0.0),
-                      jnp.float32(0.0))
+                      jnp.float32(0.0), dict.fromkeys(counted,
+                                                      jnp.float32(0.0)))
         rngs = jax.random.split(rng, accum_steps)
         if accum_steps == 1:
             micro = jax.tree_util.tree_map(lambda x: x[0], batch)
-            (grads, loss_sum, aux_sum, n_tok), _ = accum_body(
+            (grads, loss_sum, aux_sum, n_tok, counts), _ = accum_body(
                 zero_carry, (micro, rngs[0])
             )
         else:
-            (grads, loss_sum, aux_sum, n_tok), _ = jax.lax.scan(
+            (grads, loss_sum, aux_sum, n_tok, counts), _ = jax.lax.scan(
                 accum_body, zero_carry, (batch, rngs),
             )
 
@@ -326,6 +337,7 @@ def make_train_step(
         }
         if moe_coef:
             metrics["aux_loss"] = aux_sum / n_tok
+        metrics.update(counts)
 
         new_scaler = state.scaler
         if state.scaler is not None:

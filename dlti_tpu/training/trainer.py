@@ -1256,6 +1256,12 @@ class Trainer:
                     # global array's shards span other hosts' devices
                     # and cannot be fetched here.
                     recorder.record(global_step, hb, r, m)
+                # what the model counted in this step (models.jamba: the
+                # documents that started inside its rows), by its own names
+                counted = {k: int(float(m[k])) for k in getattr(
+                    self.model, "train_counters", ()) if k in m}
+                self._live.update(
+                    {"train_" + k: v for k, v in counted.items()})
                 if steplog is not None:
                     # Per-step JSONL telemetry (rank-0): the MegaScale-
                     # style in-framework stream. Window-executed steps
@@ -1308,6 +1314,7 @@ class Trainer:
                             mem_scalars.get(
                                 "hbm_headroom_bytes",
                                 -1 if memledger.enabled else 0)),
+                        **counted,
                         **first_row,
                     )
                     first_row.clear()

@@ -65,7 +65,10 @@ def lora_trainable_params(cfg: ModelConfig, r: int = 16,
                                                    "v_proj", "o_proj"),
                           ) -> int:
     """Adapter parameter count for the reference LoRA graft: per layer and
-    per targeted projection, two factors of shape (in, r) and (r, out)."""
+    per targeted projection, two factors of shape (in, r) and (r, out). A
+    family that states its own targets (``ModelConfig.lora_targets``: the
+    jamba family's attention and state-space projections) is counted by
+    those, each layer by the projections its mixer has."""
     h = cfg.hidden_size
     hd = cfg.resolved_head_dim
     dims = {
@@ -74,8 +77,18 @@ def lora_trainable_params(cfg: ModelConfig, r: int = 16,
         "v_proj": (h, cfg.num_kv_heads * hd),
         "o_proj": (cfg.num_heads * hd, h),
     }
+    targets = cfg.lora_targets or target_modules
+    if cfg.is_jamba:
+        d_in = cfg.mamba_inner_size
+        by_kind = {"A": dims, "S": {
+            "in_proj": (h, 2 * d_in),
+            "x_proj": (d_in, cfg.mamba_dt_rank + 2 * cfg.mamba_state_size),
+            "dt_proj": (cfg.mamba_dt_rank, d_in),
+            "out_proj": (d_in, h)}}
+        return sum(r * (i + o) for kind in cfg.layer_pattern
+                   for m, (i, o) in by_kind[kind].items() if m in targets)
     per_layer = sum(r * (i + o) for m, (i, o) in dims.items()
-                    if m in target_modules)
+                    if m in targets)
     return cfg.num_layers * per_layer
 
 
@@ -122,7 +135,7 @@ def _pool_layers(cfg: ModelConfig, group: int) -> int:
     keys of their own. A looped stack keeps an entry a pass."""
     kinds = cfg.layer_pattern or "*" * cfg.num_layers
     return cfg.ut_steps * sum(
-        k in "*D" and cfg.kv_group_of_layer(i) == group
+        k in "*DA" and cfg.kv_group_of_layer(i) == group
         for i, k in enumerate(kinds))
 
 

@@ -49,6 +49,9 @@ GEOMETRIES = {
     # builder, PR 53), so its pools are FUSED, a row of 1,280 values a token
     "fused_40q_10kv": (40, 10, None, "bfloat16"),
     "fused_40q_10kv_windowed": (40, 10, 512, "bfloat16"),
+    # jamba2_3b's two attention layers: twenty query heads over ONE
+    # key-value head (served at test size alone: no cell)
+    "fused_jamba2_3b_20q_1kv": (20, 1, None, "bfloat16"),
 }
 
 
@@ -128,3 +131,30 @@ def test_grouped_expert_kernel_compiles_for_the_v5e(one_chip, name):
     made = re.findall(r"= bf16\[%d,(?:%d,%d|%d,%d)\]\S* ([\w-]+)\("
                       % (held, h, f, f, h), text)
     assert made and set(made) == {"parameter"}, made
+
+
+@pytest.mark.parametrize("rows", [1, 2])
+def test_selective_scan_kernels_compile_for_the_v5e(one_chip, rows):
+    """The Mamba-1 training scan at jamba2_3b's sizes (rows of 8,192 tokens,
+    5,120 channels of 16 states): the forward kernel and the backward one
+    with its 128 kept states a chunk in VMEM."""
+    import jax
+    import jax.numpy as jnp
+
+    from dlti_tpu.ops.pallas import selective_scan as scan
+
+    length, d, n = 8192, 5120, 16
+    assert d % scan.CHANNELS == 0 and d % scan.BWD_CHANNELS == 0
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    tokens, states = shape(rows, length, d), shape(rows, length, n)
+    inputs = (tokens, tokens, shape(n, d), states, states,
+              shape(rows, length))
+    fwd = scan.selective_scan_fwd.lower(*inputs).compile().as_text()
+    assert "tpu_custom_call" in fwd and "dlti_selective_scan_fwd" in fwd
+    bwd = scan.selective_scan_bwd.lower(
+        *inputs, shape(rows, length // scan.CHUNK, n, d),
+        tokens).compile().as_text()
+    assert "tpu_custom_call" in bwd and "dlti_selective_scan_bwd" in bwd

@@ -243,6 +243,10 @@ DEVICE_SCOPE_CATALOG = frozenset({
     # cross-attention layer over the shared pool (its kernel call and its
     # combine inside it).
     "dlti_mamba1", "dlti_diff_attention", "dlti_gmu", "dlti_cross_attention",
+    # The Mamba-1 training scan and its own backward pass (models.mamba1,
+    # PR 56): the Pallas kernels' names on the TPU, the scopes round XLA's
+    # loops elsewhere; what the ``ssm_scan_*`` readers sum.
+    "dlti_selective_scan_fwd", "dlti_selective_scan_bwd",
 })
 
 
